@@ -223,8 +223,10 @@ def _auto_num_blocks(args, model, mesh) -> int:
 
 
 def _make_engine(args):
+    from ..mesh import configure_compile_cache
     from ..serving import EngineConfig, InferenceEngine
 
+    configure_compile_cache()  # before the backend's first compile
     mesh = None
     if getattr(args, "mesh", False):
         from ..mesh import build_mesh
@@ -602,7 +604,10 @@ def serve_command(args) -> int:
             f"served {stats['completed']} requests, "
             f"{stats['tokens_emitted']} tokens "
             f"({stats.get('tokens_per_sec', 0.0):.1f} tok/s), "
-            f"decode compiles {stats['decode_compiles']}{drained}",
+            f"decode compiles {stats['decode_compiles']}, "
+            f"paged route {stats['paged_attention_impl']}, "
+            f"device bytes in use "
+            f"{stats.get('hbm_used_bytes_per_device', 'not reported')}{drained}",
             file=sys.stderr,
         )
         return 0

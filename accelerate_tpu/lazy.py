@@ -444,8 +444,7 @@ PROFILE_COST_STATS: list = []
 _COLLECT_COSTS = False
 #: (label, signature) → (AOT-compiled executable, cost facts), so each
 #: signature compiles ONCE (the executable both serves the calls and
-#: answers cost_analysis); a (None, None) entry marks a backend where AOT
-#: lowering is unavailable, so the plain jit path serves without re-probing
+#: answers cost_analysis)
 _AOT_CACHE: dict = {}
 #: signatures already appended to PROFILE_COST_STATS this collection session
 _COST_SEEN: set = set()
@@ -477,7 +476,8 @@ def get_compile_callback():
 def _compile_facts(jitted, args, label: str) -> tuple:
     """AOT-compile one signature, timing trace+lower and compile separately
     and extracting the program's static cost facts: XLA-cost-model FLOPs /
-    bytes accessed, and collective bytes parsed from the compiled HLO.
+    bytes accessed, and collective bytes and the number of Mosaic (Pallas)
+    custom calls parsed from the compiled HLO.
 
     The phases are wrapped in diagnostics spans (``compile/trace_lower``,
     ``compile/compile``) and the facts carry the phases' raw *monotonic*
@@ -505,23 +505,27 @@ def _compile_facts(jitted, args, label: str) -> tuple:
         "bytes_accessed": stats.get("bytes accessed"),
         "collective_bytes": None,
     }
+    text = compiled.as_text()
+    #: Pallas kernels in the program as compiled for a TPU (0 elsewhere,
+    #: and 0 on a TPU means a kernel route was not taken)
+    facts["mosaic_custom_calls"] = text.count('custom_call_target="tpu_custom_call"')
     try:
         from .utils.hlo import total_collective_bytes
 
-        facts["collective_bytes"] = total_collective_bytes(compiled.as_text())
+        facts["collective_bytes"] = total_collective_bytes(text)
     except Exception:
         pass
     return compiled, facts
 
 
-def _cost_aware_jit(fn, donate_argnums=(), label="", arg_names=()):
+def _cost_aware_jit(fn, donate_argnums=(), label="", arg_names=(), out_shardings=None):
     """``jax.jit`` that, while instrumentation is active (a profile session
     with ``with_flops``, or a telemetry recorder's compile callback),
     AOT-compiles each new signature explicitly — timing trace+lower+compile
     and recording the program's cost analysis once. The executable is kept
     and serves the calls, so instrumentation never compiles a program
     twice. Zero overhead when both are off (one global read per call)."""
-    jitted = jax.jit(fn, donate_argnums=donate_argnums)
+    jitted = jax.jit(fn, donate_argnums=donate_argnums, out_shardings=out_shardings)
 
     def call(*args):
         callback = _COMPILE_CALLBACK
@@ -555,69 +559,67 @@ def _cost_aware_jit(fn, donate_argnums=(), label="", arg_names=()):
         )
         entry = _AOT_CACHE.get(sig)
         if entry is None:
-            try:
-                entry = _compile_facts(jitted, args, label)
-            except Exception:  # AOT path unavailable on this backend
-                entry = (None, None)
+            # a compile failure (a Mosaic refusal, an OOM) surfaces here
+            # with its own message, exactly as it would from the plain jit
+            entry = _compile_facts(jitted, args, label)
             _AOT_CACHE[sig] = entry
-            if entry[1] is not None:
-                # recompile fingerprint: hash the abstract signature with
-                # leaf PATHS attached, so a later compile of the same label
-                # can NAME the argument whose shape/dtype changed. Shared
-                # global history — the telemetry record, the sanitizer's
-                # stderr report, and the serving engine's assertion all
-                # diff against the same baseline.
-                from .analysis.compiled import (
-                    format_signature_diff,
-                    note_signature,
-                    signature_entries,
-                )
+            # recompile fingerprint: hash the abstract signature with
+            # leaf PATHS attached, so a later compile of the same label
+            # can NAME the argument whose shape/dtype changed. Shared
+            # global history — the telemetry record, the sanitizer's
+            # stderr report, and the serving engine's assertion all
+            # diff against the same baseline.
+            from .analysis.compiled import (
+                format_signature_diff,
+                note_signature,
+                signature_entries,
+            )
 
+            try:
+                # leaf paths read as ['inputs'][0] instead of [3][0]
+                # when the call site named its positional args
+                if arg_names and len(args) <= len(arg_names):
+                    named = dict(zip(arg_names, args))
+                else:
+                    named = args
+                entries = signature_entries(named)
+                fingerprint, diff = note_signature(label, entries)
+                entry[1]["fingerprint"] = fingerprint
+                if diff is not None:
+                    entry[1]["changed_args"] = format_signature_diff(diff)
+            except Exception:
+                entries, diff = (), None
+            if sanitizer:
+                # predicted-vs-actual per-device arg bytes: the static
+                # shard-plan model (global bytes / sharding extents)
+                # against the real shard buffers — a drift means the
+                # placement the planner promised is not the placement
+                # the program got
                 try:
-                    # leaf paths read as ['inputs'][0] instead of [3][0]
-                    # when the call site named its positional args
-                    if arg_names and len(args) <= len(arg_names):
-                        named = dict(zip(arg_names, args))
-                    else:
-                        named = args
-                    entries = signature_entries(named)
-                    fingerprint, diff = note_signature(label, entries)
-                    entry[1]["fingerprint"] = fingerprint
-                    if diff is not None:
-                        entry[1]["changed_args"] = format_signature_diff(diff)
-                except Exception:
-                    entries, diff = (), None
-                if sanitizer:
-                    # predicted-vs-actual per-device arg bytes: the static
-                    # shard-plan model (global bytes / sharding extents)
-                    # against the real shard buffers — a drift means the
-                    # placement the planner promised is not the placement
-                    # the program got
-                    try:
-                        from .analysis.shardplan import arg_bytes_report
+                    from .analysis.shardplan import arg_bytes_report
 
-                        predicted, actual = arg_bytes_report(args)
-                        entry[1]["arg_bytes_predicted"] = predicted
-                        entry[1]["arg_bytes_actual"] = actual
-                    except Exception:
-                        pass
-                    # the digest also rides the compile record so the
-                    # telemetry trail carries cross-host-comparable state;
-                    # observe_compile already computed it for the host
-                    # digest file — reuse it rather than rendering the
-                    # (multi-MB) HLO text a second time
-                    digest = sanitizer.observe_compile(
-                        label,
-                        entries,
-                        diff,
-                        fn=fn,
-                        args=args,
-                        donate_argnums=donate_argnums,
-                        compiled=entry[0],
-                    )
-                    if digest is not None:
-                        entry[1]["collective_digest"] = digest
-            if entry[1] is not None and callback is not None:
+                    predicted, actual = arg_bytes_report(args)
+                    entry[1]["arg_bytes_predicted"] = predicted
+                    entry[1]["arg_bytes_actual"] = actual
+                except Exception:
+                    pass
+                # the digest also rides the compile record so the
+                # telemetry trail carries cross-host-comparable state;
+                # observe_compile already computed it for the host
+                # digest file — reuse it rather than rendering the
+                # (multi-MB) HLO text a second time
+                digest = sanitizer.observe_compile(
+                    label,
+                    entries,
+                    diff,
+                    fn=fn,
+                    args=args,
+                    donate_argnums=donate_argnums,
+                    compiled=entry[0],
+                )
+                if digest is not None:
+                    entry[1]["collective_digest"] = digest
+            if callback is not None:
                 # the human-readable shape key: label + the leaf signature
                 # (the part of the cache key a batch-shape change perturbs).
                 # A big step's args include every param/opt-state leaf, so
@@ -632,8 +634,6 @@ def _cost_aware_jit(fn, donate_argnums=(), label="", arg_names=()):
                     key = f"{key[:480]}...#{digest}"
                 callback(dict(entry[1], static_key=key))
         compiled, facts = entry
-        if compiled is None:
-            return jitted(*args)
         if _COLLECT_COSTS and sig not in _COST_SEEN:
             _COST_SEEN.add(sig)
             PROFILE_COST_STATS.append(
@@ -778,10 +778,8 @@ def ddp_compressed_vag(loss_fn, mesh, input_values, hook: str):
     input_specs = [_spec_for(x) for x in input_values]
 
     def vag(params, frozen_params, inputs, *rest):
-        from .utils.compat import shard_map
-
         @functools.partial(
-            shard_map,
+            jax.shard_map,
             mesh=mesh,
             in_specs=(P(), P(), input_specs) + (P(),) * len(rest),
             out_specs=((P(), P()), P()),
@@ -817,6 +815,7 @@ def fused_step_fn_for(
     clip_norm: bool = False,
     grad_scaler=None,  # optimizer.LossScaler | None
     comm_hook: tuple | None = None,  # (hook_str, mesh) → ddp_compressed_vag
+    opt_state=None,
 ):
     loss_scale = 1.0  # fusion only engages without accumulation in flight
     """One donated, jitted train step for the common single-model loop:
@@ -829,6 +828,11 @@ def fused_step_fn_for(
       (params, opt_state, frozen_params, inputs, max_norm, scaler_state)
         -> (new_params, new_opt_state, loss, grad_norm, step_ok,
             new_scaler_state)
+    The new params and optimizer state are pinned to the placement the
+    old ones (``model.params``, ``opt_state``) arrive under: left to
+    itself GSPMD hands small replicated leaves back sharded over ``fsdp``,
+    the donated buffers cannot be reused for them, and step 2 meets new
+    input shardings and compiles the whole step a second time.
     ``step_ok`` is False when fp16 grads were non-finite (update skipped).
     With fp16, ``scaler_state`` is the LossScaler's (scale, good_steps)
     device pair: the scale is a traced INPUT (growth/backoff never
@@ -901,6 +905,10 @@ def fused_step_fn_for(
                 new_opt_state = keep(new_opt_state, opt_state)
             return new_params, new_opt_state, loss_value, norm, step_ok, new_scaler_state
 
+        placement = [
+            jax.tree.map(lambda x: getattr(x, "sharding", None), tree)
+            for tree in (model.params, opt_state)
+        ]
         entry = (
             _cost_aware_jit(
                 step,
@@ -910,6 +918,7 @@ def fused_step_fn_for(
                     "params", "opt_state", "frozen_params", "inputs",
                     "max_norm", "scaler_state",
                 ),
+                out_shardings=(*placement, None, None, None, None),
             ),
             frozen,
         )

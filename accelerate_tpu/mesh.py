@@ -31,6 +31,29 @@ logger = logging.getLogger(__name__)
 
 P = PartitionSpec
 
+#: where compiled programs persist when ``JAX_COMPILATION_CACHE_DIR`` names
+#: no place: one fixed path under the checkout, derived from this file's
+#: location and never from ``tempfile``, a pid or a time — so every process
+#: of a run (``launch`` children, ``serve`` replicas, ``bench.py`` modes,
+#: ``chip_smoke.py`` phases) reads what the others compiled without being
+#: told, and a later run finds it again (the path is part of the cache key)
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".compile_cache"
+)
+
+
+def configure_compile_cache() -> str:
+    """Give JAX's persistent compilation cache a directory before the first
+    compile, and return the one in use. Where ``JAX_COMPILATION_CACHE_DIR``
+    is set the cache is placed from outside: JAX reads the variable itself
+    and no code sets another directory. Called where each program first
+    reaches the backend (``PartialState``, the ``serve`` engine factory)."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
+
 
 def device_topology() -> dict:
     """Probe the attached JAX topology (reference analog: the env-var rank
@@ -50,8 +73,8 @@ def build_mesh(plugin: MeshPlugin | None = None, devices: Sequence | None = None
     """Build the named mesh from a :class:`MeshPlugin` shape declaration.
 
     Uses ``mesh_utils.create_device_mesh`` so the physical ICI torus is
-    respected where possible; falls back to a plain reshape for host
-    platforms / odd shapes.
+    respected where possible; falls back to a plain reshape, with a
+    warning, for shapes it cannot map.
     """
     plugin = plugin or MeshPlugin()
     if devices is None:
@@ -66,8 +89,9 @@ def build_mesh(plugin: MeshPlugin | None = None, devices: Sequence | None = None
             shape, devices=np.asarray(devices),
             allow_split_physical_axes=plugin.allow_split_physical_axes,
         )
-    except (ValueError, AssertionError, TypeError) as e:  # host platform / exotic shapes
-        logger.debug("create_device_mesh failed (%s); falling back to reshape", e)
+    except (ValueError, AssertionError, TypeError) as e:  # exotic shapes
+        # on a TPU this loses the ICI-aware device order: say so
+        logger.warning("create_device_mesh failed (%s); falling back to reshape", e)
         dev_array = np.asarray(devices).reshape(shape)
     return Mesh(dev_array, MESH_AXIS_ORDER)
 
@@ -153,11 +177,11 @@ def initialize_distributed(
         )
         if not (num_processes and num_processes > 1 and on_tpu_vm):
             return
-        if jax._src.distributed.global_state.client is not None:  # already up
+        if jax.distributed.is_initialized():
             return
         jax.distributed.initialize()
         return
-    if jax._src.distributed.global_state.client is not None:  # already up
+    if jax.distributed.is_initialized():
         return
     jax.distributed.initialize(
         coordinator_address=coordinator_address,

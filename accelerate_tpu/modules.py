@@ -109,7 +109,6 @@ class PreparedModel:
         self._accelerator = accelerator
         self.compute_dtype = compute_dtype
         self.param_sharding = param_sharding
-        self.params = model.params  # (re)sharded by prepare
         self.training = True
         self._pending_grads = None  # grads for optimizer-less models
         self.fp8_recipe = None  # set by prepare when mixed_precision='fp8'
@@ -124,8 +123,19 @@ class PreparedModel:
     def partition_rules(self):
         return self._model.partition_rules
 
+    @property
+    def params(self):
+        """The one live copy of the parameters, kept on the wrapped model:
+        ``prepare`` re-shards it and every optimizer step replaces it. A
+        second reference on the wrapper would keep the unsharded originals
+        alive — under fsdp, the whole model on device 0."""
+        return self._model.params
+
+    @params.setter
+    def params(self, value):
+        self._model.params = value
+
     def unwrap(self) -> Model:
-        self._model.params = self.params
         return self._model
 
     def num_parameters(self) -> int:

@@ -33,7 +33,6 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from .diagnostics.tracing import traced
-from .utils.compat import axis_size
 
 P = PartitionSpec
 
@@ -463,7 +462,7 @@ class jops:
     @staticmethod
     def ring_shift(x, axis_name: str, shift: int = 1):
         """Rotate shards around the ring (KV rotation for ring attention)."""
-        n = axis_size(axis_name)
+        n = jax.lax.axis_size(axis_name)
         perm = [(i, (i + shift) % n) for i in range(n)]
         return lax.ppermute(x, axis_name, perm)
 

@@ -24,8 +24,6 @@ from typing import Literal
 import jax
 from jax.sharding import PartitionSpec as P
 
-from ..utils.compat import shard_map
-
 from .flash_attention import blockwise_attention, flash_attention
 from .layers import causal_attention
 
@@ -136,7 +134,7 @@ def _flash_sharded(q, k, v, segment_mask, causal, scale, ctx: AttentionContext):
     in_specs = (qkv_spec,) * 3 + ((mask_spec,) if has_mask else ())
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=in_specs, out_specs=qkv_spec, check_vma=False
+        jax.shard_map, mesh=mesh, in_specs=in_specs, out_specs=qkv_spec, check_vma=False
     )
     def _inner(q_, k_, v_, *mask_):
         return flash_attention(

@@ -31,13 +31,7 @@ def _compiler_params(n_grid: int):
     """Mark every grid dim except the (sequential, accumulating) last one as
     parallel so Mosaic can reorder freely."""
     sem = ("parallel",) * (n_grid - 1) + ("arbitrary",)
-    try:
-        return pltpu.CompilerParams(dimension_semantics=sem)
-    except Exception:  # param renamed/absent on this jax version
-        try:
-            return pltpu.TPUCompilerParams(dimension_semantics=sem)
-        except Exception:
-            return None
+    return pltpu.CompilerParams(dimension_semantics=sem)
 
 
 # ---------------------------------------------------------------------------
@@ -315,10 +309,6 @@ def _fwd_call(q, k, v, bias, scale, causal, block_q, block_kv, interpret):
         pl.BlockSpec((1, 1, block_q, d), qmap),
         pl.BlockSpec((1, 1, block_q, 1), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
     ]
-    kwargs = {}
-    cp = _compiler_params(len(grid))
-    if cp is not None and not interpret:
-        kwargs["compiler_params"] = cp
     o, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -330,8 +320,9 @@ def _fwd_call(q, k, v, bias, scale, causal, block_q, block_kv, interpret):
             pltpu.VMEM((block_q, _MIN_LANE), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
+        compiler_params=_compiler_params(len(grid)),
         interpret=interpret,
-        **kwargs,
+        name="flash_attention_fwd",
     )(*args)
     return o, lse
 
@@ -386,10 +377,6 @@ def _bwd_call(q, k, v, bias, o, lse, do, scale, causal, block_q, block_kv, inter
                 num_kv_blocks=nkv,
             )
 
-    kwargs = {}
-    cp = _compiler_params(4)
-    if cp is not None and not interpret:
-        kwargs["compiler_params"] = cp
     dq = pl.pallas_call(
         dq_kernel,
         grid=(b, h, nq, nkv),
@@ -397,8 +384,9 @@ def _bwd_call(q, k, v, bias, o, lse, do, scale, causal, block_q, block_kv, inter
         out_specs=pl.BlockSpec((1, 1, block_q, d), qmap4),
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        compiler_params=_compiler_params(4),
         interpret=interpret,
-        **kwargs,
+        name="flash_attention_bwd_dq",
     )(*args)
 
     # --- dk/dv: grid (b, h, nkv, nq) ---
@@ -460,8 +448,9 @@ def _bwd_call(q, k, v, bias, o, lse, do, scale, causal, block_q, block_kv, inter
             pltpu.VMEM((block_kv, d), jnp.float32),
             pltpu.VMEM((block_kv, d), jnp.float32),
         ],
+        compiler_params=_compiler_params(4),
         interpret=interpret,
-        **kwargs,
+        name="flash_attention_bwd_dkv",
     )(*args)
     return dq, dk, dv
 

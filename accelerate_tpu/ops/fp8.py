@@ -151,8 +151,7 @@ KV_QUANTIZED_DTYPES = ("int8", "fp8")
 
 def kv_storage_dtype(name: str):
     """Resolve a ``kv_dtype`` policy name to ``(jnp dtype, quantized)``.
-    Raises on unknown names and on ``fp8`` where the stack can't cast f8
-    (:func:`utils.compat.has_fp8_storage`)."""
+    Raises on unknown names."""
     if name == "bf16":
         return jnp.bfloat16, False
     if name == "f32":
@@ -160,14 +159,6 @@ def kv_storage_dtype(name: str):
     if name == "int8":
         return jnp.int8, True
     if name == "fp8":
-        from ..utils.compat import has_fp8_storage
-
-        if not has_fp8_storage():
-            raise ValueError(
-                "kv_dtype='fp8' needs float8_e4m3fn storage, which this "
-                "jax/jaxlib pair cannot cast — use kv_dtype='int8' (same "
-                "bytes per token) or upgrade jax"
-            )
         return jnp.float8_e4m3fn, True
     raise ValueError(
         f"unknown kv_dtype {name!r}: expected one of "

@@ -29,8 +29,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..utils.compat import axis_size
-
 from .flash_attention import NEG_INF, _bwd_call, _fit_block, _fwd_call, _pad_to
 
 
@@ -81,7 +79,7 @@ def _chunk_fwd(q, k_cur, v_cur, bias_cur, src, idx, *, scale, causal, bq, bkv, i
 
 
 def _ring_fwd_impl(q, k, v, bias, idxf, axis_name, scale, causal, block_q, block_kv, interpret):
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = (
         idxf.reshape(()).astype(jnp.int32)
         if idxf is not None
@@ -115,7 +113,7 @@ def _ring_flash_fwd(q, k, v, bias, idxf, axis_name, scale, causal, block_q, bloc
 
 def _ring_flash_bwd(axis_name, scale, causal, block_q, block_kv, interpret, res, do):
     q, k, v, bias, idxf, o, lse = res
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = (
         idxf.reshape(()).astype(jnp.int32)
         if idxf is not None

@@ -247,6 +247,7 @@ class AcceleratedOptimizer:
             clip_norm=clip is not None,
             grad_scaler=self.scaler,
             comm_hook=self.comm_hook,
+            opt_state=self.opt_state,
         )
         frozen_params = [m.params for m in frozen]
         scaler_state = self.scaler.state() if self.scaler is not None else ()

@@ -34,8 +34,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.flash_attention import NEG_INF, blockwise_attention, flash_attention
 
-from ..utils.compat import axis_size, shard_map
-
 
 # ---------------------------------------------------------------------------
 # per-device building block: one Q-chunk × one KV-chunk online-softmax update
@@ -106,7 +104,7 @@ def ring_attention_local(
             q, k, v, kv_valid, axis_name=axis_name, causal=causal, scale=scale,
             cp_index=cp_index,
         )
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = (
         cp_index.reshape(()).astype(jnp.int32)
         if cp_index is not None
@@ -171,7 +169,7 @@ def allgather_attention_local(
 ):
     """Baseline: gather all KV chunks, run dense attention on the local Q
     chunk with the right global offset."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = (
         cp_index.reshape(()).astype(jnp.int32)
         if cp_index is not None
@@ -258,27 +256,20 @@ def context_parallel_attention(
     mask_spec = P(batch_entry, cp_axis)
     body = _LOCAL_BODIES[mode]
 
-    # claim ONLY the axes this shard_map actually uses: every other mesh
-    # axis stays auto, which is what lets the cp attention nest inside the
-    # GPipe stage body (gpipe's shard_map is manual over 'pp' alone — a
-    # nested map claiming 'pp' again would be rejected)
-    used: set = {cp_axis}
-    for entry in (batch_entry, head_entry):
-        if entry is None:
-            continue
-        used.update(entry if isinstance(entry, tuple) else (entry,))
-
-    # when tracing inside another manual region (the GPipe stage body is
+    # claim every mesh axis that is not manual already: Mosaic refuses to
+    # lower inside a region that leaves any axis to GSPMD ("cannot be
+    # automatically partitioned"), so the flash-kernel ring body needs them
+    # all; the axes the specs do not name carry replicated data anyway.
+    # When tracing inside another manual region (the GPipe stage body is
     # shard_map'd over 'pp'), the nested map must be built on the CURRENT
     # abstract mesh — the one where 'pp' is already Manual — not the
-    # concrete mesh, or jax rejects the mismatch
+    # concrete mesh, and must not claim 'pp' again, or jax rejects it
     mesh_arg = mesh
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if getattr(am, "shape", None):
-            mesh_arg = am
-    except Exception:
-        pass
+    claimed = set(mesh.axis_names)
+    am = jax.sharding.get_abstract_mesh()
+    if am.shape:
+        mesh_arg = am
+        claimed -= set(am.manual_axes)
 
     # this shard's ring position as DATA (a cp-sharded iota): inside a
     # nested manual region jax.lax.axis_index's lowering claims the
@@ -286,11 +277,11 @@ def context_parallel_attention(
     cp_pos = jnp.arange(cp_extent, dtype=jnp.float32)
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh_arg,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, mask_spec, P(cp_axis)),
         out_specs=qkv_spec,
-        axis_names=used,
+        axis_names=claimed,
         check_vma=False,
     )
     def _sharded(q_, k_, v_, valid_, cp_pos_):

@@ -31,8 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec
 
-from ..utils.compat import shard_map
-
 P = PartitionSpec
 
 
@@ -325,7 +323,7 @@ def pipeline_cached_stack(
         return out, kc, vc
 
     n_b = len(broadcast)
-    y, k2, v2 = shard_map(
+    y, k2, v2 = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P()) + (P(),) * n_b,
@@ -592,7 +590,7 @@ def gpipe(
 
     n_rest = len(aligned_mb) + len(broadcast)
     out_specs = (P(), P()) if with_aux else P()
-    res = shard_map(
+    res = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis), P()) + (P(),) * n_rest,

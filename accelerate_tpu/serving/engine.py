@@ -64,6 +64,7 @@ from ..diagnostics.tracing import ensure_trace_id, get_tracer, trace_span, valid
 from ..generation import _pick_traced
 from ..metrics.ingest import observe_flight
 from ..metrics.registry import get_active_registry
+from ..ops.paged_attention import default_paged_attention_impl
 from ..telemetry import get_active_recorder
 from .blocks import NULL_BLOCK, BlockAllocator, blocks_needed
 from .flight import ITERATION_PHASES, FlightRecorder, set_active_flight_recorder
@@ -262,6 +263,29 @@ class _InFlightRound:
     harvest_lp: bool = False
 
 
+def _under_mesh(apply_fn, mesh):
+    """``apply_fn`` traced with ``mesh`` on the attention context: that is
+    where the paged Pallas kernel finds the head axis it must be
+    partitioned over (``ops/paged_attention.py`` — GSPMD cannot split a
+    Mosaic call, and JAX refuses to lower a bare one on a sharded mesh)."""
+    if mesh is None:
+        return apply_fn
+    from ..ops.attention import attention_context
+
+    def apply(params, **kw):
+        with attention_context(mesh=mesh):
+            return apply_fn(params, **kw)
+
+    return apply
+
+
+def _abstract(x):
+    """Shape, dtype and sharding of one dispatched operand."""
+    return jax.ShapeDtypeStruct(
+        np.shape(x), x.dtype, sharding=getattr(x, "sharding", None)
+    )
+
+
 class InferenceEngine:
     """Slot-scheduled continuous-batching engine over a paged-KV model.
 
@@ -288,7 +312,7 @@ class InferenceEngine:
                 "declare supports_paged_kv: the engine needs the block-table "
                 "KV decode path (models/llama.py _llama_paged_step)"
             )
-        self._apply_fn = inner.apply_fn
+        self._apply_fn = _under_mesh(inner.apply_fn, mesh)
         self._params = model.params
         mcfg = inner.config
         if cfg.max_seq_len > mcfg.max_position_embeddings:
@@ -333,7 +357,7 @@ class InferenceEngine:
                     "needs the early-exit draft path (models/llama.py "
                     "llama_early_exit_apply)"
                 )
-            self._draft_apply = factory(self._spec.layers)
+            self._draft_apply = _under_mesh(factory(self._spec.layers), mesh)
         #: cache positions one decode dispatch may write past context_len —
         #: the block-growth lookahead (a spec round writes k+1 positions;
         #: a plain dispatch writes decode_burst)
@@ -559,10 +583,16 @@ class InferenceEngine:
             )
         )
 
-        self._decode_fn = (
-            self._build_spec_decode_fn() if self._spec else self._build_decode_fn()
+        #: program name -> (jitted fn, abstract operands of its first
+        #: dispatch): what compiled_text() lowers against
+        self._dispatched: dict[str, tuple] = {}
+        self._decode_fn = self._remember_first_dispatch(
+            "decode",
+            self._build_spec_decode_fn() if self._spec else self._build_decode_fn(),
         )
-        self._prefill_fn = self._build_prefill_fn()
+        self._prefill_fn = self._remember_first_dispatch(
+            "prefill", self._build_prefill_fn()
+        )
         # block-granular pool edits for CoW copies and swap restores:
         # donated so XLA aliases the pool buffer instead of copying the
         # whole pool per block. These are *separate* tiny executables —
@@ -709,6 +739,24 @@ class InferenceEngine:
             )
 
     # -- compiled programs ---------------------------------------------------
+
+    def _remember_first_dispatch(self, program: str, jitted):
+        def dispatch(*args):
+            if program not in self._dispatched:
+                self._dispatched[program] = (jitted, jax.tree.map(_abstract, args))
+            return jitted(*args)
+
+        return dispatch
+
+    def compiled_text(self, program: str) -> str:
+        """Optimised HLO of the ``"decode"`` or ``"prefill"`` executable,
+        compiled for the operands of its first dispatch — how
+        ``chip_smoke.py`` checks that the Pallas kernels were reached
+        (``tpu_custom_call``) and on which per-device shapes. This compiles
+        the program again: a read where a persistent compile cache is
+        configured, a full compile otherwise."""
+        jitted, operands = self._dispatched[program]
+        return jitted.lower(*operands).compile().as_text()
 
     def _paged_kv_dict(self, kp, vp, ks, vs) -> dict:
         pages = {"k": kp, "v": vp}
@@ -1560,6 +1608,9 @@ class InferenceEngine:
             "tokens_emitted": self._tokens_emitted,
             "decode_compiles": self._decode_traces,
             "prefill_compiles": self._prefill_traces,
+            # the route the compiled steps took through ops/paged_attention
+            # (a static choice by platform; the engine forces none)
+            "paged_attention_impl": default_paged_attention_impl(),
             # kv_dtype policy: bytes one cached token moves/holds (K+V
             # payload + scales across layers) and how many max-length
             # requests the pool can hold concurrently — the capacity rows
@@ -1592,6 +1643,12 @@ class InferenceEngine:
         out.update(self._spec_stats())
         out.update(self._sampling_stats())
         out.update(self._hbm_watermarks())
+        if out["hbm_bytes_source"] == "memory_stats":
+            # every local device, not only the first: a program that sits
+            # on device 0 of a four-chip host shows here
+            out["hbm_used_bytes_per_device"] = [
+                int(d.memory_stats()["bytes_in_use"]) for d in jax.local_devices()
+            ]
         if self.usage is not None:
             # totals + capped by_tenant + heavy hitters + the conservation
             # partner totals (device_wait_seconds / pool_block_seconds)

@@ -34,6 +34,7 @@ import jax
 from .mesh import (
     batch_axis_size,
     build_mesh,
+    configure_compile_cache,
     device_topology,
     initialize_distributed,
     single_device_mesh,
@@ -84,6 +85,7 @@ class PartialState:
         )
         if cpu or parse_flag_from_env("ACCELERATE_USE_CPU"):
             os.environ.setdefault("JAX_PLATFORMS", "cpu")
+        configure_compile_cache()  # before the backend's first compile
         topo = device_topology()
         self.num_processes = topo["process_count"]
         self.process_index = topo["process_index"]

@@ -461,6 +461,7 @@ class TelemetryRecorder:
             "flops": facts.get("flops"),
             "bytes_accessed": facts.get("bytes_accessed"),
             "collective_bytes": facts.get("collective_bytes"),
+            "mosaic_custom_calls": facts.get("mosaic_custom_calls"),
             "recompiles": self.recompile_count,
         }
         # analysis/compiled.py fingerprint: present whenever the AOT path

@@ -41,7 +41,7 @@ class BaseEnum(str, enum.Enum):
 
 class DistributedType(BaseEnum):
     """Execution environment (reference analog: ``DistributedType`` in
-    ``utils/dataclasses.py``; here the taxonomy is JAX-shaped)."""
+    ``utils/dataclasses.py``; here the kinds are JAX-shaped)."""
 
     NO = "NO"  # single device (1 chip or CPU), no mesh axes > 1
     TPU = "TPU"  # single-process JAX driving all local devices via a Mesh

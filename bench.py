@@ -12,10 +12,9 @@ Prints ONE JSON line:
                  shapes, on the attached backend
 
 Measurement hygiene: every measurement runs in its own subprocess (clean
-HBM, no cross-bench compilation-cache or allocator interference), and the
-parent process NEVER initialises a JAX backend — on a shared chip, backend
-init can fail transiently with UNAVAILABLE, so every subprocess is retried
-with backoff.
+HBM, no cross-bench allocator interference), and the parent process NEVER
+initialises a JAX backend — a chip belongs to one process at a time, so a
+parent that touched JAX would hold it and every mode would fail to start.
 """
 
 from __future__ import annotations
@@ -75,7 +74,10 @@ def _peak_flops(device_kind: str) -> float:
     for key, peak in _PEAK_FLOPS:
         if key in kind:
             return peak
-    return 197e12  # assume v5e-class if unrecognised
+    raise ValueError(
+        f"no peak FLOP/s on record for device kind {device_kind!r}: add it to "
+        "_PEAK_FLOPS with its source rather than assuming another chip's"
+    )
 
 
 def _train_flops_per_step(n_params: int, config, bsz: int, seq: int) -> float:
@@ -105,8 +107,7 @@ def causal_attn_fwd_bwd_flops(b: int, nh: int, seq: int, d: int) -> float:
 
 def _timed_steps(step_fn, n_warmup: int, n_steps: int) -> float:
     """Time chained steps. ``step_fn`` returns a device scalar; we fetch the
-    final one to the host, which (unlike ``block_until_ready`` on remote
-    backends) reliably fences the whole data-dependent chain."""
+    final one to the host, which fences the whole data-dependent chain."""
     import numpy as np
 
     for _ in range(n_warmup):
@@ -128,7 +129,7 @@ def _make_batch(config, bsz, seq):
 
 
 def _mode_probe() -> None:
-    """Print the backend platform + device kind (run first, with retries)."""
+    """Print the backend platform + device kind (run first)."""
     import jax
 
     dev = jax.devices()[0]
@@ -1538,45 +1539,34 @@ def _mode_commhook(platform: str) -> None:
 # ---------------------------------------------------------------------------
 
 
-def _run_subprocess(mode: str, platform: str, attempts: int = 5, extra_args: tuple = ()) -> dict:
-    """Run one measurement mode in a fresh process, retrying with backoff on
-    transient backend-init failures (shared-chip contention shows up as
-    ``UNAVAILABLE`` / ``ALREADY_EXISTS`` during client creation)."""
-    delay = 10.0
-    last_err = ""
-    for attempt in range(attempts):
-        try:
-            out = subprocess.run(
-                [sys.executable, __file__, mode, platform, *extra_args],
-                capture_output=True,
-                text=True,
-                timeout=1800,
-            )
-        except subprocess.TimeoutExpired as e:
-            last_err = f"timeout: {e}"
-            if attempt < attempts - 1:
-                time.sleep(delay)
-                delay = min(delay * 2, 120.0)
-            continue
-        results: dict = {}
-        for line in out.stdout.splitlines():
-            if line.startswith("BENCH_"):
-                key, *vals = line.split()
-                results[key] = vals
-        if out.returncode == 0 and results:
-            return results
-        last_err = f"rc={out.returncode}\n{out.stdout[-2000:]}\n{out.stderr[-2000:]}"
-        if attempt < attempts - 1:
-            time.sleep(delay)
-            delay = min(delay * 2, 120.0)
-    raise RuntimeError(f"bench mode {mode} failed after {attempts} attempts:\n{last_err}")
+def _run_subprocess(mode: str, platform: str, extra_args: tuple = ()) -> dict:
+    """Run one measurement mode in a fresh process, once: the chip is this
+    process tree's alone, so a failure is a failure of the mode, and trying
+    an 1800 s mode again only spends the chip budget."""
+    out = subprocess.run(
+        [sys.executable, __file__, mode, platform, *extra_args],
+        capture_output=True,
+        text=True,
+        timeout=1800,
+    )
+    results: dict = {}
+    for line in out.stdout.splitlines():
+        if line.startswith("BENCH_"):
+            key, *vals = line.split()
+            results[key] = vals
+    if out.returncode == 0 and results:
+        return results
+    raise RuntimeError(
+        f"bench mode {mode} failed: rc={out.returncode}\n"
+        f"{out.stdout[-2000:]}\n{out.stderr[-2000:]}"
+    )
 
 
 def _seq_row(platform: str, device_kind: str, n_dev: int, seq: int) -> dict | None:
     """One long-context framework row (tokens/s + MFU at the given seq).
-    Best-effort: a contended chip must not sink the whole bench."""
+    Best-effort: one failed row must not sink the whole bench."""
     try:
-        fw = _run_subprocess("framework", platform, attempts=2, extra_args=("-", str(seq)))
+        fw = _run_subprocess("framework", platform, extra_args=("-", str(seq)))
     except Exception:
         return None
     t = float(fw["BENCH_RESULT"][0])
@@ -1730,7 +1720,7 @@ def main():
         # raw couldn't fit the commanded setting: re-match the framework run
         fw = _run_subprocess("framework", platform, extra_args=("1",))
     try:
-        attn = _run_subprocess("attn", platform, attempts=2)
+        attn = _run_subprocess("attn", platform)
         t_flash, t_block = (float(x) for x in attn["BENCH_ATTN"])
         flash_speedup = round(t_block / t_flash, 3)
     except Exception:
@@ -1754,7 +1744,7 @@ def main():
             if row:
                 extra_rows.append(row)
             try:  # per-seq kernel micro-row at the flagship head shape
-                micro = _run_subprocess("attn", platform, attempts=2, extra_args=(str(s),))
+                micro = _run_subprocess("attn", platform, extra_args=(str(s),))
                 t_f, t_b = (float(x) for x in micro["BENCH_ATTN"])
                 extra_rows.append(
                     {
@@ -1770,41 +1760,24 @@ def main():
             except Exception:
                 pass
         try:
-            # fp8 vs bf16 (VERDICT r5 #1: the r5 artifact's 3.68 was a
-            # contended bf16 leg). Interleaved A/B/A/B legs in THIS parent,
-            # SAME program variant (full remat: the f8 custom-vjp residuals
-            # exceed HBM under dots_saveable), median-of-3 per side, legs
-            # slower than 1.5x the flagship step rejected as contended and
-            # re-run; both leg medians ride into the row and compact line.
+            # fp8 vs bf16: interleaved A/B/A/B legs in THIS parent, SAME
+            # program variant (full remat: the f8 custom-vjp residuals
+            # exceed HBM under dots_saveable), median-of-3 per side; both
+            # leg medians ride into the row and compact line.
             b16_raw: list[float] = []
             fp8_raw: list[float] = []
             for _ in range(3):  # 3 interleaved A/B pairs
                 b = _run_subprocess(
-                    "framework", platform, attempts=2, extra_args=("1", "1024", "bf16")
+                    "framework", platform, extra_args=("1", "1024", "bf16")
                 )
                 b16_raw.append(float(b["BENCH_RESULT"][0]))
                 f = _run_subprocess(
-                    "framework", platform, attempts=2, extra_args=("1", "1024", "fp8")
+                    "framework", platform, extra_args=("1", "1024", "fp8")
                 )
                 fp8_raw.append(float(f["BENCH_RESULT"][0]))
 
-            def clean(raw):
-                # contention bar: 1.5x the flagship step OR 1.5x the side's
-                # own best leg, whichever is larger — these legs run FULL
-                # remat (and fp8 its quantize overhead), legitimately slower
-                # than the dots_saveable flagship, so anchoring on the
-                # flagship alone could reject every clean leg and silently
-                # drop the row. The side minimum always accepts itself, so
-                # the filtered list is never empty.
-                bar = 1.5 * max(t_framework, min(raw))
-                kept = [t for t in raw if t <= bar]
-                return kept, len(raw) - len(kept)
-
-            b16_legs, rej_b = clean(b16_raw)
-            fp8_legs, rej_f = clean(fp8_raw)
-            rejected = rej_b + rej_f
-            b16_med = float(statistics.median(b16_legs))
-            fp8_med = float(statistics.median(fp8_legs))
+            b16_med = float(statistics.median(b16_raw))
+            fp8_med = float(statistics.median(fp8_raw))
             extra_rows.append(
                 {
                     "metric": "fp8_vs_bf16_train_step_speedup",
@@ -1812,15 +1785,11 @@ def main():
                     "unit": "x",
                     "bf16_leg_s_median": round(b16_med, 4),
                     "fp8_leg_s_median": round(fp8_med, 4),
-                    "bf16_legs_s": [round(t, 4) for t in b16_legs],
-                    "fp8_legs_s": [round(t, 4) for t in fp8_legs],
-                    "contended_legs_rejected": int(rejected),
+                    "bf16_legs_s": [round(t, 4) for t in b16_raw],
+                    "fp8_legs_s": [round(t, 4) for t in fp8_raw],
                     "note": "scaled-float8 dense projections (ops/fp8.py, "
                     "TE HYBRID recipe) vs bf16, same model/remat; "
-                    "interleaved A/B legs, median-of-3 per side, legs "
-                    ">1.5x max(flagship step, side's best leg) rejected "
-                    "as contended (these legs run full remat, legitimately "
-                    "slower than the dots_saveable flagship). v5e "
+                    "interleaved A/B legs, median-of-3 per side. v5e "
                     "has no native fp8 MXU — the f8 operands upcast to "
                     "bf16, so the quantize overhead makes this <1.0 here "
                     "(expect ~0.87); the recipe pays on fp8-capable "
@@ -1832,7 +1801,7 @@ def main():
         except Exception:
             pass
     try:
-        mrpc = _run_subprocess("mrpc", platform, attempts=2)
+        mrpc = _run_subprocess("mrpc", platform)
         extra_rows.append(
             {
                 "metric": "mrpc_train_steps_per_sec",
@@ -1844,14 +1813,13 @@ def main():
                 "params, nlp_example.py:91), batch 16, pad-to-128 collate. "
                 "Per-step HOST overhead (deferred-graph replay + dispatch) "
                 "measures ~1.6 ms — 30% of a 2-layer toy's 5.4 ms step "
-                "(185 steps/s uncontended; r3's 52 steps/s toy reading was "
-                "chip contention), immaterial at BERT-base step times",
+                "(185 steps/s), immaterial at BERT-base step times",
             }
         )
     except Exception:
         pass
     try:
-        cv = _run_subprocess("cv", platform, attempts=2)
+        cv = _run_subprocess("cv", platform)
         extra_rows.append(
             {
                 "metric": "cv_train_steps_per_sec",
@@ -1868,7 +1836,7 @@ def main():
         pass
     if platform == "tpu":
         try:
-            dec = _run_subprocess("decode", platform, attempts=2)
+            dec = _run_subprocess("decode", platform)
             extra_rows.append(
                 {
                     "metric": "llama_decode_tokens_per_sec_kv_cache",
@@ -1885,7 +1853,7 @@ def main():
         except Exception:
             pass
     try:
-        srv = _run_subprocess("serve", platform, attempts=2)
+        srv = _run_subprocess("serve", platform)
         (s_tok, s_static, s_ratio, s_p50, s_p99, s_tpot, s_occ, s_compiles,
          s_nreq), s_legs = srv["BENCH_SERVE"][:9], srv["BENCH_SERVE"][9:]
         n_legs = len(s_legs) // 2
@@ -1921,7 +1889,7 @@ def main():
     except Exception:
         pass
     try:
-        rt = _run_subprocess("route", platform, attempts=2)
+        rt = _run_subprocess("route", platform)
         vals = rt["BENCH_ROUTE"]
         fleet_tok, single_tok, ratio, requeues = vals[:4]
         occ_pairs = vals[4:]
@@ -1951,7 +1919,7 @@ def main():
     except Exception:
         pass
     try:
-        rx = _run_subprocess("radix", platform, attempts=2)
+        rx = _run_subprocess("radix", platform)
         (ratio, hit, share_tok, cold_tok, ttft_share, ttft_cold, compiles,
          nreq), rx_legs = rx["BENCH_RADIX"][:8], rx["BENCH_RADIX"][8:]
         n_legs = len(rx_legs) // 2
@@ -1983,7 +1951,7 @@ def main():
     except Exception:
         pass
     try:
-        ch = _run_subprocess("chaos", platform, attempts=2)
+        ch = _run_subprocess("chaos", platform)
         (ratio, recovery, respawns, requeues, clean_tok, fault_tok) = (
             float(v) for v in ch["BENCH_CHAOS"]
         )
@@ -2012,7 +1980,7 @@ def main():
     except Exception:
         pass
     try:
-        flt = _run_subprocess("fleet", platform, attempts=2)
+        flt = _run_subprocess("fleet", platform)
         (sl_guard, sl_step, sl_ident, sl_dec, sl_req, sl_err, sl_c0, sl_c1,
          sl_agree) = (float(v) for v in flt["BENCH_FLEET"])
         extra_rows.append(
@@ -2046,7 +2014,7 @@ def main():
     except Exception:
         pass
     try:
-        kv = _run_subprocess("kv", platform, attempts=2)
+        kv = _run_subprocess("kv", platform)
         (b_bf16, b_int8, cap_ratio, blk_bf16, blk_int8, attn_ratio,
          fused_s, gather_s, trunc_bf16, trunc_int8) = (
             float(v) for v in kv["BENCH_KVQ"]
@@ -2083,7 +2051,7 @@ def main():
     except Exception:
         pass
     try:
-        sp = _run_subprocess("spec", platform, attempts=2)
+        sp = _run_subprocess("spec", platform)
         plain_tok, k4_tok, k4_acc, k8_tok, k8_acc = (float(v) for v in sp["BENCH_SPEC"])
         best_k, best_tok, best_acc = (4, k4_tok, k4_acc) if k4_tok >= k8_tok else (8, k8_tok, k8_acc)
         extra_rows.append(
@@ -2115,7 +2083,7 @@ def main():
     except Exception:
         pass
     try:
-        ss = _run_subprocess("spec-serve", platform, attempts=2)
+        ss = _run_subprocess("spec-serve", platform)
         (tpot_ratio, acc, good_ratio, ss_k, ss_spec_compiles, ss_off_compiles,
          ss_spec_tpot, ss_off_tpot) = (float(v) for v in ss["BENCH_SPEC_SERVE"])
         extra_rows.append(
@@ -2148,7 +2116,7 @@ def main():
     except Exception:
         pass
     try:
-        asy = _run_subprocess("async", platform, attempts=2)
+        asy = _run_subprocess("async", platform)
         (a_ratio, a_hf, s_hf, a_good, a_compiles, s_compiles,
          a_tpot, s_tpot) = (float(v) for v in asy["BENCH_ASYNC"])
         extra_rows.append(
@@ -2177,7 +2145,7 @@ def main():
     except Exception:
         pass
     try:
-        tel = _run_subprocess("telemetry", platform, attempts=2)
+        tel = _run_subprocess("telemetry", platform)
         t_off, t_on = (float(v) for v in tel["BENCH_TELEMETRY"])
         extra_rows.append(
             {
@@ -2197,7 +2165,7 @@ def main():
     except Exception:
         pass
     try:
-        wdr = _run_subprocess("watchdog", platform, attempts=2)
+        wdr = _run_subprocess("watchdog", platform)
         w_off, w_on = (float(v) for v in wdr["BENCH_WATCHDOG"])
         extra_rows.append(
             {
@@ -2217,7 +2185,7 @@ def main():
     except Exception:
         pass
     try:
-        met = _run_subprocess("metrics", platform, attempts=2)
+        met = _run_subprocess("metrics", platform)
         guard_s, emit_off, emit_on, step_s = (float(v) for v in met["BENCH_METRICS"])
         extra_rows.append(
             {
@@ -2244,7 +2212,7 @@ def main():
     except Exception:
         pass
     try:
-        rt = _run_subprocess("reqtrace", platform, attempts=2)
+        rt = _run_subprocess("reqtrace", platform)
         rt_guard_s, rt_event_s, rt_step_s = (
             float(v) for v in rt["BENCH_REQTRACE"]
         )
@@ -2271,7 +2239,7 @@ def main():
     except Exception:
         pass
     try:
-        fli = _run_subprocess("flight", platform, attempts=2)
+        fli = _run_subprocess("flight", platform)
         fl_guard_s, fl_off_s, fl_on_s, fl_hf = (
             float(v) for v in fli["BENCH_FLIGHT"]
         )
@@ -2306,7 +2274,7 @@ def main():
     except Exception:
         pass
     try:
-        usg = _run_subprocess("usage", platform, attempts=2)
+        usg = _run_subprocess("usage", platform)
         us_guard_s, us_off_s, us_on_s = (float(v) for v in usg["BENCH_USAGE"])
         extra_rows.append(
             {
@@ -2338,7 +2306,7 @@ def main():
     except Exception:
         pass
     try:
-        smp = _run_subprocess("sampling", platform, attempts=2)
+        smp = _run_subprocess("sampling", platform)
         sm_off, sm_on, sm_rate = (float(v) for v in smp["BENCH_SAMPLING"])
         extra_rows.append(
             {
@@ -2375,7 +2343,7 @@ def main():
     except Exception:
         pass
     try:
-        san = _run_subprocess("sanitize", platform, attempts=2)
+        san = _run_subprocess("sanitize", platform)
         sg_s, s_off, s_on = (float(v) for v in san["BENCH_SANITIZE"])
         extra_rows.append(
             {
@@ -2400,7 +2368,7 @@ def main():
     except Exception:
         pass
     try:
-        rc = _run_subprocess("race", platform, attempts=2)
+        rc = _run_subprocess("race", platform)
         rg_s, rraw_s, rwatched_s, rstep_s = (float(v) for v in rc["BENCH_RACE"])
         extra_rows.append(
             {
@@ -2429,7 +2397,7 @@ def main():
     except Exception:
         pass
     try:
-        sh = _run_subprocess("shard", platform, attempts=2)
+        sh = _run_subprocess("shard", platform)
         shard_s = float(sh["BENCH_SHARD"][0])
         extra_rows.append(
             {
@@ -2448,7 +2416,7 @@ def main():
     except Exception:
         pass
     try:
-        gp = _run_subprocess("goodput", platform, attempts=2)
+        gp = _run_subprocess("goodput", platform)
         gp_pct, gp_elapsed = (float(v) for v in gp["BENCH_GOODPUT"][:2])
         gp_buckets = {
             name: float(value)
@@ -2474,7 +2442,7 @@ def main():
     except Exception:
         pass
     try:
-        ck = _run_subprocess("ckpt", platform, attempts=2)
+        ck = _run_subprocess("ckpt", platform)
         t_save, t_restore, ck_bytes = ck["BENCH_CKPT"]
         ck_note = (
             "~64 MB synthetic sharded model through the resilience "
@@ -2505,7 +2473,7 @@ def main():
     except Exception:
         pass
     try:
-        ch = _run_subprocess("commhook", platform, attempts=2)
+        ch = _run_subprocess("commhook", platform)
         hook_bytes, base_bytes = (int(v) for v in ch["BENCH_COMMHOOK"])
         extra_rows.append(
             {
@@ -2524,7 +2492,7 @@ def main():
     except Exception:
         pass
     try:
-        off = _run_subprocess("offload", platform, attempts=2)
+        off = _run_subprocess("offload", platform)
         disk_raw = float(off.get("BENCH_DISKRAW", ["0"])[0]) or None
         for key in ("BENCH_OFFLOAD_FP32", "BENCH_OFFLOAD_INT8", "BENCH_OFFLOAD_NF4"):
             if key not in off:

@@ -1,0 +1,638 @@
+"""chip_smoke.py — does today's code start, compile and finish on the chip?
+
+Runs the repo's two hot programs once each on the attached TPU, through the
+entry points a user calls, at the full width of ``LlamaConfig.flagship_700m``
+with seeded random weights:
+
+* the trainer: the five-line ``Accelerator`` loop at batch 8 x seq 1024 in
+  bf16, started by ``python -m accelerate_tpu.commands.launch``;
+* the server: ``accelerate-tpu serve --preset flagship`` in stdin/JSONL
+  mode, once with a bf16 KV pool and once with an int8 pool;
+
+and checks the Pallas kernels under them against the repo's pure-JAX
+references on the chip (paged attention vs ``impl="gather"``, flash attention
+forward and gradients vs ``blockwise_attention``). On a host with four chips
+it also trains under ``fsdp=2,tp=2`` and ``fsdp=4`` and serves under
+``--mesh`` with ``tp=4``.
+
+It is chip-or-fail and measurement-free: it exits non-zero when JAX finds no
+TPU, when any phase fails, and in a directory that holds nothing else of the
+repo. The seconds it prints are set-up facts (how long compiling and running
+took here), never a rate. The last line of standard output is one JSON
+object, ``{"ok": true, "device": {"platform", "kind", "count"}}``, naming the
+device as JAX reports it and holding nothing else; the per-phase facts are on
+the ``SUMMARY`` line before it.
+
+A chip belongs to one process at a time, so this parent never initialises a
+JAX backend: every phase is a child, run one after another, and a child's
+non-zero exit, an error row, a missing answer or a timeout fails the script.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import re
+import signal
+import subprocess
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.abspath(__file__))
+LOG_DIR = os.path.join(ROOT, "chiprun_out", "chip_smoke")
+
+#: the driver allows 1200 s on one chip, compilation included
+DEADLINE_S = 1150.0
+#: what one phase may take; a hung child is killed with its process group
+PHASE_TIMEOUT_S = 600.0
+
+TRAIN_BATCH, TRAIN_SEQ, TRAIN_STEPS = 8, 1024, 6
+
+SERVE_ARGS = [
+    "--preset", "flagship", "--dtype", "bf16", "--num-slots", "16",
+    "--max-seq-len", "512", "--prefill-chunk", "128",
+]
+#: enough requests to reuse every slot; prompts longer than one prefill
+#: chunk, so chunked prefill, slot reuse and a full decode batch all happen
+SERVE_REQUESTS, PROMPT_LEN, NEW_TOKENS = 40, (32, 160), (16, 64)
+
+#: |kernel - reference| ceilings on the chip, for unit-variance inputs.
+#: Paged attention: same stored pool bytes on both sides, outputs rounded to
+#: bf16 (2^-8 relative), the reference's f32 einsums at the TPU's default
+#: (single bf16 pass) matmul precision. Flash attention: bf16 inputs and
+#: outputs (magnitudes up to ~4, where three bf16 steps are 0.047), f32
+#: softmax on both sides, probabilities rounded to bf16 before the kernel's
+#: second matmul; gradients accumulate over the sequence, so they get a
+#: bound relative to the largest reference entry.
+PAGED_ATOL = 2e-2
+FLASH_FWD_ATOL = 5e-2
+FLASH_GRAD_RTOL = 3e-2
+
+_COMPILED = re.compile(r"Finished XLA compilation of (\S+) in ([0-9.]+) sec")
+
+
+class PhaseFailed(Exception):
+    pass
+
+
+# ---------------------------------------------------------------------------
+# the parent: no JAX here
+# ---------------------------------------------------------------------------
+
+
+def _child_env(extra: dict | None = None) -> dict:
+    env = dict(os.environ)
+    # JAX itself refuses to start rather than fall back to the CPU
+    env["JAX_PLATFORMS"] = "tpu"
+    # one log line per executable: the phase's compile seconds and counts
+    env["JAX_LOG_COMPILES"] = "1"
+    env["PYTHONUNBUFFERED"] = "1"
+    env["PYTHONPATH"] = os.pathsep.join(
+        [ROOT] + [p for p in env.get("PYTHONPATH", "").split(os.pathsep) if p]
+    )
+    env.update(extra or {})
+    return env
+
+
+def _run_child(name: str, cmd: list[str], deadline: float, *, env=None,
+               stdin_text: str | None = None) -> dict:
+    """One phase in its own process group; raises PhaseFailed unless it
+    exits 0 inside its time. Returns stdout, stderr, wall seconds and the
+    compile seconds JAX logged."""
+    left = deadline - time.monotonic()
+    if left <= 5:
+        raise PhaseFailed(f"{name}: no time left before the {DEADLINE_S:.0f} s limit")
+    t0 = time.monotonic()
+    proc = subprocess.Popen(
+        cmd, cwd=ROOT, env=_child_env(env), text=True, start_new_session=True,
+        stdin=subprocess.PIPE if stdin_text is not None else subprocess.DEVNULL,
+        stdout=subprocess.PIPE, stderr=subprocess.PIPE,
+    )
+    try:
+        out, err = proc.communicate(stdin_text, timeout=min(PHASE_TIMEOUT_S, left))
+    except subprocess.TimeoutExpired:
+        raise PhaseFailed(f"{name}: timed out") from None
+    finally:
+        # the group, not only the child: `launch` and `serve` spawn too
+        try:
+            os.killpg(proc.pid, signal.SIGKILL)
+        except ProcessLookupError:
+            pass
+        proc.wait()
+    wall = time.monotonic() - t0
+    # the whole of both streams, for whoever has to read a failure: the
+    # tool brings chiprun_out/ back from the machine with the chip
+    os.makedirs(LOG_DIR, exist_ok=True)
+    for stream, text in (("out", out), ("err", err)):
+        with open(os.path.join(LOG_DIR, f"{re.sub(r'[^a-z0-9]+', '_', name.lower())}.{stream}"), "w") as f:
+            f.write(text)
+    if proc.returncode != 0:
+        raise PhaseFailed(
+            f"{name}: exit code {proc.returncode}\n--- stdout\n{out[-3000:]}"
+            f"\n--- stderr\n{err[-6000:]}"
+        )
+    compiles = [(m.group(1), float(m.group(2))) for m in _COMPILED.finditer(err)]
+    return {
+        "out": out, "err": err, "wall_s": wall, "compiles": compiles,
+        "compile_s": sum(s for _, s in compiles),
+    }
+
+
+def _report(name: str, res: dict, facts: str = "") -> dict:
+    """Print one phase's set-up facts and return them for the summary."""
+    run_s = res["wall_s"] - res["compile_s"]
+    print(
+        f"[{name}] ok — compile {res['compile_s']:.1f} s "
+        f"({len(res['compiles'])} executables, cache reads included), "
+        f"everything else {run_s:.1f} s{facts}",
+        flush=True,
+    )
+    return {"compile_s": round(res["compile_s"], 1), "run_s": round(run_s, 1)}
+
+
+def _json_lines(text: str, tag: str) -> list[dict]:
+    return [
+        json.loads(line[len(tag):]) for line in text.splitlines()
+        if line.startswith(tag)
+    ]
+
+
+def _self(phase: str, *args: str) -> list[str]:
+    return [sys.executable, os.path.join(ROOT, "chip_smoke.py"), phase, *args]
+
+
+def _serve_requests() -> list[dict]:
+    import random
+
+    rng = random.Random(0)
+    return [
+        {
+            "id": i,
+            "prompt": [rng.randrange(32000) for _ in range(rng.randint(*PROMPT_LEN))],
+            "max_new_tokens": rng.randint(*NEW_TOKENS),
+        }
+        for i in range(SERVE_REQUESTS)
+    ]
+
+
+def _train_leg(name: str, deadline: float, mesh_flags: list[str]) -> dict:
+    res = _run_child(
+        name,
+        [sys.executable, "-m", "accelerate_tpu.commands.launch",
+         "--mixed_precision", "bf16", *mesh_flags, *_self("_train")[1:]],
+        deadline,
+    )
+    (facts,) = _json_lines(res["out"], "TRAIN ") or [None]
+    if facts is None:
+        raise PhaseFailed(f"{name}: the train script printed no result\n{res['out'][-2000:]}")
+    losses = facts["losses"]
+    bad = []
+    if len(losses) < 5 or not all(l == l and abs(l) != float("inf") for l in losses):
+        bad.append(f"losses not finite over >= 5 steps: {losses}")
+    elif not losses[-1] < losses[0]:
+        bad.append(f"loss did not fall: {losses[0]} -> {losses[-1]}")
+    logged = sum(1 for name, _ in res["compiles"] if name == "jit(step)")
+    if facts["fused_step_compiles"] != 1 or logged != 1:
+        bad.append(f"fused step compiled {facts['fused_step_compiles']} times by its own "
+                   f"count and {logged} by JAX's log, not once")
+    if facts["mosaic_custom_calls"] < 1:
+        bad.append("the compiled train step holds no Mosaic custom call (flash kernel not reached)")
+    bytes_in_use = facts["bytes_in_use_after_prepare"]
+    if max(bytes_in_use) > 1.05 * min(bytes_in_use):
+        bad.append(f"devices do not hold equal shares after prepare(): {bytes_in_use}")
+    if bad:
+        raise PhaseFailed(f"{name}: " + "; ".join(bad))
+    out = _report(
+        name, res,
+        f"; loss {losses[0]:.4f} -> {losses[-1]:.4f} over {len(losses)} steps, "
+        f"fused step compiled once with {facts['mosaic_custom_calls']} Mosaic "
+        f"calls, mesh {facts['mesh']}, bytes in use per device after "
+        f"prepare() {bytes_in_use}",
+    )
+    out.update(mesh=facts["mesh"], bytes_in_use=bytes_in_use)
+    return out
+
+
+def _serve_leg(name: str, deadline: float, extra_args: list[str],
+               env: dict | None = None, sharded: bool = False) -> dict:
+    requests = _serve_requests()
+    res = _run_child(
+        name,
+        [sys.executable, "-m", "accelerate_tpu.commands.accelerate_cli", "serve",
+         *SERVE_ARGS, *extra_args],
+        deadline, env=env,
+        stdin_text="".join(json.dumps(r) + "\n" for r in requests),
+    )
+    rows = [json.loads(line) for line in res["out"].splitlines() if line.startswith("{")]
+    bad = []
+    errors = [r for r in rows if "error" in r]
+    if errors:
+        bad.append(f"{len(errors)} error rows, first: {errors[0]}")
+    answered = {r["id"]: r for r in rows if "error" not in r}
+    for req in requests:
+        row = answered.get(req["id"])
+        if row is None:
+            bad.append(f"request {req['id']} was not answered")
+        elif len(row["tokens"]) != req["max_new_tokens"]:
+            bad.append(
+                f"request {req['id']} asked for {req['max_new_tokens']} tokens, "
+                f"got {len(row['tokens'])} ({row.get('finish_reason')})"
+            )
+    closing = [l for l in res["err"].splitlines() if l.startswith("served ")]
+    m = closing and re.search(
+        r"decode compiles (\d+), paged route (\w+), device bytes in use (\[.*?\]|not reported)",
+        closing[-1],
+    )
+    if not m:
+        bad.append(f"no closing line on stderr: {res['err'][-1500:]}")
+    elif m.group(1) != "1":
+        bad.append(f"decode compiles {m.group(1)}, not 1")
+    bytes_in_use = json.loads(m.group(3)) if m and m.group(3).startswith("[") else None
+    if sharded and m:
+        if not bytes_in_use or max(bytes_in_use) > 1.05 * min(bytes_in_use):
+            bad.append(f"devices do not hold equal shares: {bytes_in_use}")
+    if bad:
+        raise PhaseFailed(f"{name}: " + "; ".join(bad[:5]))
+    out = _report(
+        name, res,
+        f"; {len(requests)} requests answered in full, no error row, decode "
+        f"compiles 1, paged route: {m.group(2)}, bytes in use per device "
+        f"at exit {bytes_in_use}",
+    )
+    out.update(paged_route=m.group(2), bytes_in_use=bytes_in_use)
+    return out
+
+
+def main() -> int:
+    if not os.path.isdir(os.path.join(ROOT, "accelerate_tpu")):
+        print("chip_smoke: the program is not here — no accelerate_tpu/ beside "
+              "this script, nothing to run", file=sys.stderr)
+        return 2
+    named = os.environ.get("JAX_PLATFORMS", "")
+    if named and "tpu" not in named.lower().split(","):
+        print(f"chip_smoke: found no chip — JAX_PLATFORMS={named} holds JAX to "
+              "another platform, and this script runs on a TPU or not at all",
+              file=sys.stderr)
+        return 3
+
+    started = time.monotonic()
+    deadline = started + DEADLINE_S
+    phases: dict[str, dict] = {}
+    try:
+        res = _run_child("probe", _self("_probe"), deadline)
+        (device,) = _json_lines(res["out"], "PROBE ")
+        if device["platform"] != "tpu":
+            raise PhaseFailed(f"probe: JAX found no chip, platform is {device['platform']!r}")
+        print(
+            f"[probe] platform={device['platform']} device_kind={device['kind']!r} "
+            f"count={device['count']} jax={device['jax']} jaxlib={device['jaxlib']} "
+            f"libtpu={device['libtpu']}; compile cache: {device['compile_cache']}",
+            flush=True,
+        )
+        four = device["count"] == 4
+        if four:  # twice the legs, run by the builder: twice the time
+            deadline += DEADLINE_S
+
+        res = _run_child("kernels", _self("_kernels"), deadline)
+        for row in _json_lines(res["out"], "KERNEL "):
+            print(f"[kernels] {row['check']}: max |diff| {row['err']:.3g} "
+                  f"(bound {row['bound']:.3g})", flush=True)
+        phases["kernels"] = _report("kernels", res)
+
+        legs = [("train", _train_leg, dict(mesh_flags=[])),
+                ("serve bf16", _serve_leg, dict(extra_args=[])),
+                ("serve int8", _serve_leg, dict(extra_args=["--kv-dtype", "int8"]))]
+        if four:
+            legs += [
+                ("train fsdp=2,tp=2", _train_leg,
+                 dict(mesh_flags=["--mesh_fsdp", "2", "--mesh_tp", "2"])),
+                ("train fsdp=4", _train_leg, dict(mesh_flags=["--mesh_fsdp", "4"])),
+                ("serve --mesh tp=4", _serve_leg,
+                 dict(extra_args=["--mesh"], env={"ACCELERATE_MESH_TP": "4"}, sharded=True)),
+            ]
+        for name, leg, kwargs in legs:
+            phases[name] = leg(name, deadline, **kwargs)
+
+        res = _run_child("engine-check", _self("_engine_check"), deadline)
+        for row in _json_lines(res["out"], "ENGINE "):
+            print(
+                f"[engine-check] {row['engine']}: paged route: {row['paged_route']}; "
+                f"Mosaic calls decode {row['decode_mosaic_calls']} prefill "
+                f"{row['prefill_mosaic_calls']}; kernel pool operand per device "
+                f"{row['kernel_pool_shape']}; greedy tokens equal to generate(): "
+                f"{row['agree']}/{row['compared']} (reported, not gated)",
+                flush=True,
+            )
+        phases["engine-check"] = _report("engine-check", res)
+    except PhaseFailed as e:
+        print(f"chip_smoke: FAILED — {e}", file=sys.stderr)
+        return 1
+
+    ran = [name for name, _, _ in legs]
+    print(f"legs run: {', '.join(ran)}; wall {time.monotonic() - started:.0f} s "
+          "(set-up facts, not performance)", flush=True)
+    print("SUMMARY " + json.dumps({"legs": ran, "phases": phases, "claim": None}),
+          flush=True)
+    print(_result_line(device), flush=True)
+    return 0
+
+
+def _result_line(device: dict) -> str:
+    """The last line of standard output, which is the driver's: these keys
+    and no other."""
+    return json.dumps({
+        "ok": True,
+        "device": {"platform": str(device["platform"]), "kind": str(device["kind"]),
+                   "count": int(device["count"])},
+    })
+
+
+# ---------------------------------------------------------------------------
+# the children: each one process, each the chip's only holder while it runs
+# ---------------------------------------------------------------------------
+
+
+def _probe() -> None:
+    import jax
+    import jaxlib
+
+    from accelerate_tpu.mesh import configure_compile_cache
+
+    try:
+        from importlib.metadata import version
+
+        libtpu = version("libtpu")
+    except Exception:  # noqa: BLE001 — the version is reported, not needed
+        libtpu = "unknown"
+    dev = jax.devices()[0]
+    print("PROBE " + json.dumps({
+        "platform": dev.platform, "kind": dev.device_kind,
+        "count": len(jax.devices()), "jax": jax.__version__,
+        "jaxlib": jaxlib.__version__, "libtpu": libtpu,
+        "compile_cache": configure_compile_cache(),
+    }))
+
+
+def _kernel_row(check: str, got, want, bound: float, relative: bool = False) -> bool:
+    import numpy as np
+
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    err = float(np.abs(got - want).max())
+    if relative:
+        bound = bound * float(np.abs(want).max())
+    ok = bool(np.isfinite(got).all()) and err <= bound
+    print("KERNEL " + json.dumps({"check": check, "err": err, "bound": bound, "ok": ok}),
+          flush=True)
+    return ok
+
+
+def _paged_case(rng, b, s, nh, hd, bs, mb, store):
+    """Pools written through real block tables (quantize-on-scatter for
+    int8/fp8), rows at different depths, the last query at each row's end."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from accelerate_tpu.ops.fp8 import kv_storage_dtype
+    from accelerate_tpu.ops.layers import write_paged_kv
+
+    dtype, quantized = kv_storage_dtype(store)
+    nb = b * mb + 1
+    tables = (1 + np.arange(b * mb, dtype=np.int32)).reshape(b, mb)
+    depth = rng.integers(s, mb * bs + 1, size=b).astype(np.int32)
+    depth[0] = mb * bs  # one row fills its whole table
+    pools = [jnp.zeros((nb, bs, nh, hd), dtype)] * 2
+    scales = [jnp.ones((nb, bs, nh), jnp.float32)] * 2 if quantized else []
+    k, v = (
+        jnp.asarray(rng.normal(size=(b, mb * bs, nh, hd)), jnp.bfloat16)
+        for _ in range(2)
+    )
+    positions = np.broadcast_to(np.arange(mb * bs, dtype=np.int32), (b, mb * bs))
+    written = write_paged_kv(
+        *pools, k, v, tables, positions, write_mask=positions < depth[:, None],
+        **(dict(k_scale_l=scales[0], v_scale_l=scales[1]) if quantized else {}),
+    )
+    q = jnp.asarray(rng.normal(size=(b, s, nh, hd)), jnp.bfloat16)
+    return q, written[:2], tables, depth - s, written[2:]
+
+
+def _kernels() -> None:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from accelerate_tpu.mesh import configure_compile_cache
+    from accelerate_tpu.ops.flash_attention import flash_attention
+    from accelerate_tpu.ops.paged_attention import paged_attention
+
+    configure_compile_cache()
+    rng = np.random.default_rng(0)
+    ok = True
+
+    # paged attention at the flagship's decode and prefill-chunk shapes
+    for store in ("bf16", "int8", "fp8"):
+        for b, s in ((16, 1), (1, 128)):
+            q, pools, tables, idx, scales = _paged_case(
+                rng, b, s, nh=12, hd=128, bs=16, mb=32, store=store)
+            outs = {
+                impl: jax.jit(
+                    lambda q, kp, vp, *sc, impl=impl: paged_attention(
+                        q, kp, vp, tables, idx, *sc, impl=impl)
+                )(q, *pools, *scales)
+                for impl in ("pallas", "gather")
+            }
+            ok &= _kernel_row(
+                f"paged attention [{b},{s},12,128] block 16, {store} pool, "
+                "pallas vs gather", outs["pallas"], outs["gather"], PAGED_ATOL)
+
+    # flash attention forward and gradients at the train shapes
+    for b, s in ((8, 1024), (1, 8192)):
+        qkv = [jnp.asarray(rng.normal(size=(b, s, 12, 128)), jnp.bfloat16) for _ in range(3)]
+        probe = jnp.asarray(rng.normal(size=(b, s, 12, 128)), jnp.float32)
+        ok &= _against_blockwise(f"flash attention %s [{b},{s},12,128] vs blockwise",
+                                 flash_attention, qkv, probe)
+
+    if len(jax.devices()) == 4:
+        ok &= _ring_flash_check(rng)
+    if not ok:
+        sys.exit("a kernel disagrees with its reference beyond the stated bound")
+
+
+def _out_and_grads(fn):
+    """Jitted ``(q, k, v, probe) -> (out, dq, dk, dv)`` for the scalar
+    ``sum(fn(q, k, v) * probe)``."""
+    import jax
+    import jax.numpy as jnp
+
+    def scalar(q, k, v, probe):
+        out = fn(q, k, v)
+        return jnp.sum(out.astype(jnp.float32) * probe), out
+
+    grad = jax.value_and_grad(scalar, argnums=(0, 1, 2), has_aux=True)
+
+    def run(q, k, v, probe):
+        (_, out), grads = grad(q, k, v, probe)
+        return (out, *grads)
+
+    return jax.jit(run)
+
+
+def _against_blockwise(label: str, fn, qkv, probe) -> bool:
+    """``fn``'s output and q/k/v gradients against ``blockwise_attention``.
+    The reference runs three heads at a time (heads are independent, and the
+    scan's saved residuals at seq 8192 would not fit the chip for twelve)."""
+    import jax.numpy as jnp
+
+    from accelerate_tpu.ops.flash_attention import blockwise_attention
+
+    got = _out_and_grads(fn)(*qkv, probe)
+    reference = _out_and_grads(blockwise_attention)
+    groups = [
+        reference(*(x[:, :, h:h + 3] for x in (*qkv, probe)))
+        for h in range(0, qkv[0].shape[2], 3)
+    ]
+    want = [jnp.concatenate(parts, axis=2) for parts in zip(*groups)]
+    ok = _kernel_row(label % "fwd", got[0], want[0], FLASH_FWD_ATOL)
+    for name, g, w in zip("qkv", got[1:], want[1:]):
+        ok &= _kernel_row(label % f"d{name}", g, w, FLASH_GRAD_RTOL, relative=True)
+    return ok
+
+
+def _ring_flash_check(rng) -> bool:
+    """Ring attention with flash-kernel chunks over cp=4 against blockwise
+    attention on the whole sequence: the one kernel that needs several chips."""
+    import jax
+    import jax.numpy as jnp
+
+    from accelerate_tpu.mesh import build_mesh
+    from accelerate_tpu.parallel.context import context_parallel_attention
+    from accelerate_tpu.utils.dataclasses import MeshPlugin
+
+    mesh = build_mesh(MeshPlugin(dp=1, cp=4))
+    b, s = 1, 8192
+    qkv = [jnp.asarray(rng.normal(size=(b, s, 12, 128)), jnp.bfloat16) for _ in range(3)]
+    probe = jnp.asarray(rng.normal(size=(b, s, 12, 128)), jnp.float32)
+
+    def ring(q, k, v):
+        return context_parallel_attention(q, k, v, None, mesh=mesh, mode="ring", causal=True)
+
+    return _against_blockwise(f"ring flash %s [{b},{s},12,128] cp=4 vs blockwise",
+                              ring, qkv, probe)
+
+
+def _bytes_in_use() -> list[int]:
+    import jax
+
+    return [int(d.memory_stats()["bytes_in_use"]) for d in jax.local_devices()]
+
+
+def _train() -> None:
+    """The training script `launch` starts: the five-line loop."""
+    import numpy as np
+    import optax
+
+    from accelerate_tpu import Accelerator
+    from accelerate_tpu.lazy import set_compile_callback
+    from accelerate_tpu.mesh import mesh_axis_sizes
+    from accelerate_tpu.models import LlamaConfig, LlamaForCausalLM
+    from accelerate_tpu.test_utils.training import SimpleLoader
+
+    accelerator = Accelerator(mixed_precision="bf16")
+    # compile facts of the fused step (a new Accelerator clears the hook,
+    # so it is set after)
+    compiles: list[dict] = []
+    set_compile_callback(compiles.append)
+    config = LlamaConfig.flagship_700m(
+        max_position_embeddings=TRAIN_SEQ, remat="dots_saveable")
+    model = LlamaForCausalLM.from_config(config, seed=0)
+    ids = np.random.default_rng(0).integers(
+        0, config.vocab_size, size=(TRAIN_BATCH, TRAIN_SEQ)).astype(np.int32)
+    rows = [{"input_ids": row, "labels": row} for row in ids] * TRAIN_STEPS
+    model, optimizer, loader = accelerator.prepare(
+        model, optax.adamw(3e-4), SimpleLoader(rows, TRAIN_BATCH))
+    bytes_in_use = _bytes_in_use()
+
+    losses = []
+    for batch in loader:
+        out = model(**batch)
+        accelerator.backward(out.loss)
+        optimizer.step()
+        optimizer.zero_grad()
+        losses.append(float(out.loss))
+
+    fused = [c for c in compiles if c["label"] == "fused_step"]
+    print("TRAIN " + json.dumps({
+        "losses": losses,
+        "fused_step_compiles": len(fused),
+        "mosaic_custom_calls": sum(c["mosaic_custom_calls"] for c in fused),
+        "mesh": mesh_axis_sizes(accelerator.mesh),
+        "bytes_in_use_after_prepare": bytes_in_use,
+    }))
+
+
+def _engine_check() -> None:
+    """The engines `serve` built, built again the same way, to read what
+    the serving process cannot hand over a pipe: the compiled text of the
+    decode and prefill executables (Mosaic calls, and the per-device shape
+    of the pool the kernel reads), and greedy agreement with generate()."""
+    import jax
+    import numpy as np
+
+    from accelerate_tpu.commands import serve
+    from accelerate_tpu.generation import generate
+
+    cli = argparse.ArgumentParser()
+    serve.add_parser(cli.add_subparsers())
+
+    engines = [("bf16 pool", [], {}), ("int8 pool", ["--kv-dtype", "int8"], {})]
+    if len(jax.devices()) == 4:
+        engines.append(("--mesh tp=4", ["--mesh"], {"ACCELERATE_MESH_TP": "4"}))
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(0, 32000, size=160).astype(np.int32) for _ in range(4)]
+    new = 16
+    ok = True
+    reference = None
+    for name, extra, env in engines:
+        os.environ.update(env)
+        args = cli.parse_args(["serve", *SERVE_ARGS, *extra])
+        engine = serve._make_engine(args)
+        requests = [engine.add_request(p, new) for p in prompts]
+        engine.run_until_idle()
+        texts = {p: engine.compiled_text(p) for p in ("decode", "prefill")}
+        calls = {p: t.count('custom_call_target="tpu_custom_call"') for p, t in texts.items()}
+        # the pool operand of the kernel, as the compiled program holds it on
+        # one device: [num_blocks, block, kv_heads_on_this_device * head_dim]
+        kernel_lines = [l for l in texts["decode"].splitlines() if "tpu_custom_call" in l]
+        pool = kernel_lines and re.search(
+            r"operand_layout_constraints=\{.*?(\w+\[\d+,16,\d+\])", kernel_lines[0])
+        route = engine.stats()["paged_attention_impl"]
+        if reference is None:  # generate() on the same seeded weights
+            model = serve._build_model(args)
+            reference = np.asarray(generate(
+                model, np.stack(prompts), max_new_tokens=new, use_cache=True,
+            ))[:, -new:]
+        got = np.asarray([r.output_tokens for r in requests])
+        print("ENGINE " + json.dumps({
+            "engine": name, "paged_route": route,
+            "decode_mosaic_calls": calls["decode"],
+            "prefill_mosaic_calls": calls["prefill"],
+            "kernel_pool_shape": pool.group(1) if pool else None,
+            "agree": int((got == reference).sum()), "compared": int(got.size),
+        }), flush=True)
+        if route == "pallas" and min(calls.values()) < 1:
+            ok = False
+        del engine
+    if not ok:
+        sys.exit("paged route is pallas but an executable holds no Mosaic custom call")
+
+
+_CHILDREN = {
+    "_probe": _probe, "_kernels": _kernels, "_train": _train,
+    "_engine_check": _engine_check,
+}
+
+if __name__ == "__main__":
+    if len(sys.argv) > 1:
+        _CHILDREN[sys.argv[1]]()
+    else:
+        sys.exit(main())

@@ -15,51 +15,24 @@ import sys
 #: tests (e.g. the bf16-over-ICI GPipe smoke) actually execute.
 _TEST_BACKEND = os.environ.get("ACCELERATE_TEST_BACKEND", "cpu").lower()
 
-def _xla_flag_supported(flag: str) -> bool:
-    """XLA ABORTS the process on unknown flags in XLA_FLAGS (no exception to
-    catch), and older jaxlibs lack the CPU collective-timeout flag — probe in
-    a throwaway subprocess so an unsupported flag degrades to 'not set'
-    instead of killing the whole pytest session at collection."""
-    import subprocess
-
-    env = dict(os.environ, XLA_FLAGS=flag, JAX_PLATFORMS="cpu")
-    try:
-        return (
-            subprocess.run(
-                [sys.executable, "-c", "import jax; jax.devices()"],
-                env=env,
-                capture_output=True,
-                timeout=120,
-            ).returncode
-            == 0
-        )
-    except Exception:
-        return False
-
-
 if _TEST_BACKEND == "cpu":
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         flags = (flags + " --xla_force_host_platform_device_count=8").strip()
-    if "collective_call_terminate_timeout" not in flags and _xla_flag_supported(
-        "--xla_cpu_collective_call_terminate_timeout_seconds=600"
-    ):
+    if "collective_call_terminate_timeout" not in flags:
         # single-core machines time-slice all 8 device threads: a heavy
         # program can exceed XLA CPU's default 40s collective rendezvous
         # window, which ABORTS the process. Give the scheduler room.
         flags = (flags + " --xla_cpu_collective_call_terminate_timeout_seconds=600").strip()
     os.environ["XLA_FLAGS"] = flags
 
+# hermetic runs: no test (and no child a test starts) reads or fills the
+# persistent compile cache, so a run never depends on what an earlier one
+# left behind and the checkout stays small enough to copy to the chip
+os.environ.setdefault("JAX_ENABLE_COMPILATION_CACHE", "0")
+
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-# The env var alone is not enough when a site plugin (e.g. an out-of-tree TPU
-# backend) registers itself and rewrites platform selection — the config
-# update below always wins as long as it runs before backend init.
-import jax  # noqa: E402
-
-if _TEST_BACKEND == "cpu":
-    jax.config.update("jax_platforms", "cpu")
 
 import pytest  # noqa: E402
 

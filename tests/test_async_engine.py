@@ -34,14 +34,6 @@ from accelerate_tpu.serving import EngineConfig, InferenceEngine, RequestState
 KV_DTYPES = ("bf16", "int8", "fp8")
 
 
-def _skip_without_fp8(kv_dtype: str) -> None:
-    if kv_dtype == "fp8":
-        from accelerate_tpu.utils.compat import has_fp8_storage
-
-        if not has_fp8_storage():
-            pytest.skip("float8_e4m3fn storage unsupported on this jax stack")
-
-
 @pytest.fixture(scope="module")
 def tiny_model():
     from accelerate_tpu.models import LlamaConfig, LlamaForCausalLM
@@ -198,7 +190,6 @@ _SCENARIOS = {
 @pytest.mark.parametrize("kv_dtype", KV_DTYPES)
 @pytest.mark.parametrize("scenario", sorted(_SCENARIOS))
 def test_async_token_parity(tiny_model, scenario, kv_dtype):
-    _skip_without_fp8(kv_dtype)
     drive, cfg_kw = _SCENARIOS[scenario]
     a_eng, s_eng, a_reqs, s_reqs = _pair(
         tiny_model, drive, kv_dtype=kv_dtype, **cfg_kw

@@ -106,8 +106,6 @@ def test_send_to_device_sharding():
 def test_jops_psum_inside_shard_map():
     state = PartialState()
     mesh = state.mesh
-    from accelerate_tpu.utils.compat import shard_map
-
     x = jax.device_put(
         jnp.arange(8.0).reshape(8, 1), NamedSharding(mesh, P(("dp",), None))
     )
@@ -115,7 +113,7 @@ def test_jops_psum_inside_shard_map():
     def body(x):
         return ops.jops.psum(x, "dp")
 
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh, in_specs=P(("dp",), None), out_specs=P(("dp",), None)
     )(x)
     np.testing.assert_allclose(np.asarray(out), np.full((8, 1), 28.0))
@@ -124,14 +122,12 @@ def test_jops_psum_inside_shard_map():
 def test_jops_ring_shift():
     state = PartialState()
     mesh = state.mesh
-    from accelerate_tpu.utils.compat import shard_map
-
     x = jax.device_put(jnp.arange(8.0).reshape(8, 1), NamedSharding(mesh, P(("dp",), None)))
 
     def body(x):
         return ops.jops.ring_shift(x, "dp", shift=1)
 
-    out = shard_map(body, mesh=mesh, in_specs=P(("dp",), None), out_specs=P(("dp",), None))(x)
+    out = jax.shard_map(body, mesh=mesh, in_specs=P(("dp",), None), out_specs=P(("dp",), None))(x)
     # shard i receives shard i-1's value: [7, 0, 1, ..., 6]
     np.testing.assert_allclose(np.asarray(out).ravel(), np.r_[7.0, np.arange(7.0)])
 

@@ -22,26 +22,12 @@ from accelerate_tpu.ops.fp8 import (
     quantize_kv_rows,
 )
 from accelerate_tpu.ops.layers import cached_attention, write_paged_kv
-from accelerate_tpu.ops.paged_attention import (
-    paged_attention,
-    pallas_paged_attention_available,
-)
+from accelerate_tpu.ops.paged_attention import paged_attention
 
 #: ops-level |fused_quantized - f32_reference| ceilings on attention
 #: outputs (unit-variance inputs). int8 carries ~0.4% relative error per
 #: row (7-bit mantissa + rounding), fp8 e4m3 ~3% (3-bit mantissa).
 KV_ATOL = {"int8": 0.05, "fp8": 0.12}
-
-
-def _skip_without_fp8(name: str) -> None:
-    """fp8 storage is a documented graceful-degradation path
-    (kv_storage_dtype raises a guidance error where f8 casts don't
-    lower) — its test legs must skip there, not fail."""
-    if name == "fp8":
-        from accelerate_tpu.utils.compat import has_fp8_storage
-
-        if not has_fp8_storage():
-            pytest.skip("float8_e4m3fn storage unsupported on this jax stack")
 
 
 def _filled_pools(rng, *, b=3, n_kv=4, hd=16, bs=4, nb=12, mb=5, idx=(9, 6, 14),
@@ -94,15 +80,51 @@ def test_fused_lax_matches_gather_reference():
 
 
 def test_pallas_kernel_matches_gather_reference():
-    """The Pallas block-table kernel (interpret mode off-TPU) computes the
-    same attention as the gather reference."""
-    if not pallas_paged_attention_available():
-        pytest.skip("pallas paged-attention kernel unavailable on this stack")
+    """The Pallas block-table kernel (in the Pallas interpreter off-TPU)
+    computes the same attention as the gather reference — decode and
+    prefill-chunk query shapes, GQA heads."""
     rng = np.random.default_rng(1)
     bt, idx, (kpf, vpf), _ = _filled_pools(rng)
+    for s, offs in ((1, 0), (4, 3)):
+        q = jnp.asarray(rng.normal(size=(3, s, 8, 16)).astype(np.float32))
+        qi = np.maximum(idx - offs, 0)
+        ref = paged_attention(q, kpf, vpf, bt, qi, impl="gather")
+        out = paged_attention(q, kpf, vpf, bt, qi, impl="pallas", interpret=True)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-5)
+
+
+def test_pallas_kernel_runs_per_head_shard_under_a_tp_mesh():
+    """With the pool's kv heads sharded over ``tp`` the kernel runs under
+    ``shard_map`` on each device's own heads (GSPMD cannot partition a
+    Mosaic call): same numbers as the gather reference, and the output
+    keeps the head sharding instead of coming back replicated."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from accelerate_tpu.mesh import build_mesh
+    from accelerate_tpu.ops.attention import attention_context
+    from accelerate_tpu.utils.dataclasses import MeshPlugin
+
+    mesh = build_mesh(MeshPlugin(dp=1, tp=4), devices=jax.devices()[:4])
+    rng = np.random.default_rng(5)
+    dtype, _ = kv_storage_dtype("int8")
+    bt, idx, (kpf, vpf), pools = _filled_pools(rng, dtype=dtype)
     q = jnp.asarray(rng.normal(size=(3, 1, 8, 16)).astype(np.float32))
-    ref = paged_attention(q, kpf, vpf, bt, idx, impl="gather")
-    out = paged_attention(q, kpf, vpf, bt, idx, impl="pallas")
+    ref = paged_attention(q, *pools[:2], bt, idx, *pools[2:], impl="gather")
+    heads = NamedSharding(mesh, PartitionSpec(None, None, "tp", None))
+    scales = NamedSharding(mesh, PartitionSpec(None, None, "tp"))
+    placed = [jax.device_put(x, heads) for x in (q, *pools[:2])]
+    placed_scales = [jax.device_put(x, scales) for x in pools[2:]]
+
+    @jax.jit
+    def run(q, kp, vp, ks, vs):
+        return paged_attention(q, kp, vp, bt, idx, ks, vs, impl="pallas",
+                               interpret=True)
+
+    with attention_context(mesh=mesh):
+        out = run(*placed, *placed_scales)
+    assert out.sharding.spec == heads.spec
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
 
@@ -113,7 +135,6 @@ def test_quantized_pool_within_tolerance(name):
     with the f32 reference within the documented per-dtype ceiling, and
     the quantized impls agree with each other much tighter (same stored
     bytes, same math)."""
-    _skip_without_fp8(name)
     dtype, quantized = kv_storage_dtype(name)
     assert quantized
     rng = np.random.default_rng(2)
@@ -121,12 +142,10 @@ def test_quantized_pool_within_tolerance(name):
     q = jnp.asarray(rng.normal(size=(3, 1, 8, 16)).astype(np.float32))
     ref = np.asarray(paged_attention(q, kpf, vpf, bt, idx, impl="gather"))
     outs = {}
-    impls = ["lax", "gather"]
-    if pallas_paged_attention_available():
-        impls.append("pallas")
-    for impl in impls:
+    for impl in ("lax", "gather", "pallas"):
         out = np.asarray(paged_attention(
-            q, kp, vp, bt, idx, k_scale_l=ks, v_scale_l=vs, impl=impl
+            q, kp, vp, bt, idx, k_scale_l=ks, v_scale_l=vs, impl=impl,
+            interpret=True,
         ))
         assert np.abs(out - ref).max() < KV_ATOL[name], (
             f"{name}/{impl} exceeded the documented tolerance"
@@ -165,11 +184,9 @@ def test_quantized_write_respects_mask_and_drop():
 
 
 def test_quantize_round_trip_and_zero_rows():
-    from accelerate_tpu.utils.compat import has_fp8_storage
-
     rng = np.random.default_rng(3)
     x = jnp.asarray(rng.normal(size=(5, 7, 16)).astype(np.float32)) * 3.0
-    for name in ("int8", "fp8") if has_fp8_storage() else ("int8",):
+    for name in ("int8", "fp8"):
         dtype, _ = kv_storage_dtype(name)
         q, scale = quantize_kv_rows(x, dtype)
         back = np.asarray(dequantize_kv(q, scale))

@@ -427,14 +427,6 @@ def _cfg(**kw):
     return EngineConfig(**base)
 
 
-def _skip_without_fp8(kv_dtype: str) -> None:
-    if kv_dtype == "fp8":
-        from accelerate_tpu.utils.compat import has_fp8_storage
-
-        if not has_fp8_storage():
-            pytest.skip("float8_e4m3fn storage unsupported on this jax stack")
-
-
 def _prompts(seed, sizes=(5, 11, 17, 3, 9)):
     rng = np.random.default_rng(seed)
     return [rng.integers(0, 64, size=n).astype(np.int32) for n in sizes]
@@ -446,7 +438,6 @@ def test_greedy_token_identity_lanes_vs_legacy(tiny_model, kv_dtype):
     """The headline bar: arming the lanes changes NOTHING for greedy
     traffic — token-identical to the ``per_slot_sampling=False`` engine
     (the PR 16 executables) at every kv_dtype, one executable each side."""
-    _skip_without_fp8(kv_dtype)
     prompts = _prompts(0)
     budgets = [3 + 4 * i for i in range(5)]
 

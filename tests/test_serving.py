@@ -440,16 +440,6 @@ KV_LOGIT_ATOL = {"bf16": 0.06, "int8": 0.12, "fp8": 0.35}
 KV_DTYPES = ("bf16", "int8", "fp8")
 
 
-def _skip_without_fp8(kv_dtype: str) -> None:
-    """fp8 is a documented graceful-degradation path (the engine raises a
-    guidance error where f8 casts don't lower) — skip its legs there."""
-    if kv_dtype == "fp8":
-        from accelerate_tpu.utils.compat import has_fp8_storage
-
-        if not has_fp8_storage():
-            pytest.skip("float8_e4m3fn storage unsupported on this jax stack")
-
-
 def test_engine_kv_stats_and_capacity_math(tiny_model):
     """stats() carries the kv_dtype policy rows, and the byte math is the
     documented formula: 2 pools x layers x n_kv x (hd x itemsize + 4-byte
@@ -512,7 +502,6 @@ def test_kv_dtype_paged_logits_match_dense(tiny_model, kv_dtype):
     """Dense-equivalence leg: chunk-prefilling through a quantized pool
     yields last-token logits within the documented tolerance of the dense
     one-shot prefill (the acceptance bar's logit contract)."""
-    _skip_without_fp8(kv_dtype)
     import jax.numpy as jnp
 
     from accelerate_tpu.ops.fp8 import kv_storage_dtype
@@ -594,7 +583,6 @@ def test_kv_dtype_prefix_hit_and_cow_parity(tiny_model, kv_dtype):
     as a cold engine at the same kv_dtype — adopted quantized blocks and
     CoW copies reuse the exact stored bytes + scales, so within one
     kv_dtype the cache is invisible."""
-    _skip_without_fp8(kv_dtype)
     def run(warm):
         eng = InferenceEngine(
             tiny_model,
@@ -625,7 +613,6 @@ def test_kv_dtype_swap_round_trip_parity(tiny_model, kv_dtype):
     requests complete un-truncated and token-identical to a
     full-residency run at the same kv_dtype — quantized payload + scale
     rows survived swap-out -> swap-in exactly."""
-    _skip_without_fp8(kv_dtype)
     geom = dict(num_slots=2, block_size=8, max_seq_len=64, prefill_chunk=8,
                 prefix_cache=False, kv_dtype=kv_dtype)
     prompts = [np.arange(8, dtype=np.int32), np.arange(8, dtype=np.int32) + 1]

@@ -58,9 +58,7 @@ def test_smoke_pipeline_pp2_loss_matches_dense():
 
     dense = float(loss_fn(params))
     mesh = build_mesh(MeshPlugin(pp=2))  # dp absorbs the remaining devices
-    from accelerate_tpu.utils.compat import set_mesh
-
-    with attention_context(mesh=mesh), set_mesh(mesh):
+    with attention_context(mesh=mesh), jax.set_mesh(mesh):
         piped = float(jax.jit(loss_fn)(params))
     assert piped == pytest.approx(dense, rel=1e-4)
 

@@ -184,14 +184,6 @@ def test_spec_metrics_round_trip_render_parse():
 KV_DTYPES = ("bf16", "int8", "fp8")
 
 
-def _skip_without_fp8(kv_dtype: str) -> None:
-    if kv_dtype == "fp8":
-        from accelerate_tpu.utils.compat import has_fp8_storage
-
-        if not has_fp8_storage():
-            pytest.skip("float8_e4m3fn storage unsupported on this jax stack")
-
-
 def _run_trace(model, spec_k, prompts, budgets, **cfg_kw):
     eng = InferenceEngine(
         model,
@@ -210,7 +202,6 @@ def test_spec_token_parity_across_kv_dtypes(tiny_model, kv_dtype):
     token, at every kv_dtype — on a mixed-length trace whose prompts force
     chunked prefill (17 > prefill_chunk 8) and whose budgets finish
     mid-round. One decode executable each side."""
-    _skip_without_fp8(kv_dtype)
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, 64, size=n).astype(np.int32) for n in (5, 11, 17, 3, 9)]
     budgets = [3 + 4 * i for i in range(5)]

@@ -499,14 +499,6 @@ def _prompts(seed, sizes=(5, 11, 17, 3, 9)):
     return [rng.integers(0, 64, size=n).astype(np.int32) for n in sizes]
 
 
-def _skip_without_fp8(kv_dtype):
-    if kv_dtype == "fp8":
-        from accelerate_tpu.utils.compat import has_fp8_storage
-
-        if not has_fp8_storage():
-            pytest.skip("float8_e4m3fn storage unsupported on this jax stack")
-
-
 def _drive_mixed(eng):
     return [
         eng.add_request(p, 3 + 4 * i, tenant=f"t{i % 3}")
@@ -593,7 +585,6 @@ def _run_and_assert_conserved(model, drive, **cfg_kw):
 @pytest.mark.parametrize("kv_dtype", KV_DTYPES)
 @pytest.mark.parametrize("scenario", sorted(_SCENARIOS))
 def test_conservation_matrix(tiny_model, scenario, kv_dtype):
-    _skip_without_fp8(kv_dtype)
     drive, cfg_kw = _SCENARIOS[scenario]
     snaps = _run_and_assert_conserved(
         tiny_model, drive, kv_dtype=kv_dtype, **cfg_kw
