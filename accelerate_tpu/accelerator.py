@@ -1127,8 +1127,6 @@ class Accelerator:
             wrapped.comm_hook = (self._grad_comm_hook, self.mesh)
         if self.telemetry:
             wrapped.telemetry = self.telemetry
-        if self.tracer:
-            wrapped.tracer = self.tracer
         if self.watchdog is not None:
             wrapped.watchdog = self.watchdog
         self._optimizers.append(wrapped)

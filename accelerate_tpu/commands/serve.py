@@ -315,6 +315,18 @@ def _result_dict(req, req_id) -> dict:
     return out
 
 
+def _at_the_door(inbox, payload, cb=None) -> None:
+    """Every front end's way into the engine loop's inbox: the payload is
+    stamped with the moment it was received (``perf_counter`` seconds),
+    which ``_engine_loop`` hands to ``add_request(arrival_time=)`` — the
+    loop drains the inbox once per iteration, and without the stamp a
+    server-side ``ttft_s`` would start up to an iteration late. The key is
+    the server's own: a client's value is overwritten."""
+    if isinstance(payload, dict):
+        payload["_arrival"] = time.perf_counter()
+    inbox.put((payload, cb))
+
+
 def _engine_loop(engine, inbox, emit, stop, health=None, handler=None,
                  max_queue=None):
     """Drain inbox → step → deliver completion dicts; idle-sleep when empty
@@ -372,6 +384,9 @@ def _engine_loop(engine, inbox, emit, stop, health=None, handler=None,
                 try:
                     req = engine.add_request(
                         payload["prompt"], payload.get("max_new_tokens"),
+                        # the door's stamp (absent when a caller fills the
+                        # inbox itself: the clock then starts here)
+                        arrival_time=payload.get("_arrival"),
                         priority=payload.get("priority", "interactive"),
                         deadline_ms=payload.get("deadline_ms"),
                         trace_id=payload.get("trace_id"),
@@ -569,7 +584,7 @@ def serve_command(args) -> int:
                     req_id = payload.get("id") if isinstance(payload, dict) else None
                     emit({"id": req_id, "error": "draining: admission stopped"})
                     continue
-                inbox.put((payload, None))
+                _at_the_door(inbox, payload)
             stop.set()
 
         if trace_schedule is not None:
@@ -583,7 +598,7 @@ def serve_command(args) -> int:
             def feed_trace():
                 run_schedule(
                     trace_schedule,
-                    lambda payload: inbox.put((payload, None)),
+                    lambda payload: _at_the_door(inbox, payload),
                     should_stop=lambda: health.draining or stop.is_set(),
                 )
                 stop.set()
@@ -647,7 +662,7 @@ def _serve_http(engine, inbox, stop, port, health=None, handler=None,
     health = health or ServeHealth()
     box = {"engine": None if callable(engine) else engine}
     frontend = OpenAIFrontend(
-        lambda payload, cb: inbox.put((payload, cb)), streaming="delta"
+        lambda payload, cb: _at_the_door(inbox, payload, cb), streaming="delta"
     )
 
     class Handler(BaseHTTPRequestHandler):
@@ -830,7 +845,7 @@ def _serve_http(engine, inbox, stop, port, health=None, handler=None,
                 answer["result"] = result
                 done.set()
 
-            inbox.put((payload, cb))
+            _at_the_door(inbox, payload, cb)
             done.wait()
             result = answer["result"]
             self._send(400 if "error" in result else 200, result)

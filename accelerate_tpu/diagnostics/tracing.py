@@ -23,9 +23,19 @@ File contract (crash-safety first, like the telemetry JSONL):
   offset so the merge tool can place all hosts on one wall-clock axis
   (host-clock-offset correction).
 
-The disabled path is a single module-global read returning a shared no-op
-context manager — cheap enough to leave ``trace_span`` calls in every hot
-path unconditionally.
+One span stream, two sinks. Every span also opens a
+``jax.profiler.TraceAnnotation`` of the same name, so whenever a
+``jax.profiler`` session records (``Accelerator.profile()``,
+``accelerate-tpu profile``, ``serve``'s ``/profile``, a benchmark's own
+``start_trace``) the program's spans are host events in the same xplane as
+the device operations — the profiler stamps them with the wall clock
+(``time.time_ns()``) less the session's ``profile_start_time``, which the
+xplane's ``Task Environment`` plane holds. jax is never imported from here:
+a process that has not imported it cannot be recording.
+
+With no tracer and no session the cost of ``trace_span`` is two global
+reads and an inactive TraceMe — cheap enough to leave the calls in every
+hot path unconditionally.
 """
 
 from __future__ import annotations
@@ -33,6 +43,7 @@ from __future__ import annotations
 import json
 import os
 import re
+import sys
 import threading
 import time
 import uuid
@@ -116,7 +127,7 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
-    def set_attr(self, **attrs):
+    def set_metadata(self, **attrs):
         pass
 
 
@@ -179,11 +190,28 @@ def set_active_tracer(tracer) -> None:
     _ACTIVE_TRACER = tracer if tracer is not None else NULL_TRACER
 
 
+def _annotation(name: str, attrs: dict):
+    """The profiler-side half of a span: a ``TraceAnnotation`` (inactive,
+    and nearly free, unless a ``jax.profiler`` session records; a context
+    manager with ``set_metadata`` like every span here), or None in a
+    process that never imported jax."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    try:
+        return jax.profiler.TraceAnnotation(name, **attrs)
+    except Exception:  # a half-imported jax, or attrs TraceMe cannot encode
+        return None
+
+
 class _Span:
     """One open span: records entry on ``__enter__``, emits a complete
-    Chrome ``ph:"X"`` event on ``__exit__``."""
+    Chrome ``ph:"X"`` event on ``__exit__``; the same interval is a
+    ``TraceAnnotation`` for a recording profiler session. ``t0``/``t1``
+    let a caller that already read the clock at the boundary (the serving
+    engine's phase switch) stamp the span with that read."""
 
-    __slots__ = ("_tracer", "name", "attrs", "_t0", "_tid")
+    __slots__ = ("_tracer", "name", "attrs", "_t0", "_tid", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
@@ -191,22 +219,36 @@ class _Span:
         self.attrs = attrs
         self._t0 = 0.0
         self._tid = 0
+        self._ann = _annotation(name, attrs)
 
-    def set_attr(self, **attrs):
+    def set_metadata(self, **attrs):
         self.attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
 
-    def __enter__(self):
-        self._t0 = time.perf_counter()
+    def enter(self, t0: float | None = None):
+        self._t0 = time.perf_counter() if t0 is None else t0
         self._tid = threading.get_ident()
         self._tracer._push(self)
+        if self._ann is not None:
+            self._ann.__enter__()
         return self
 
-    def __exit__(self, exc_type, exc, tb):
-        t1 = time.perf_counter()
+    def exit(self, t1: float | None = None, exc_type=None):
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        if t1 is None:
+            t1 = time.perf_counter()
         self._tracer._pop(self)
         if exc_type is not None:
             self.attrs["error"] = exc_type.__name__
         self._tracer._emit_complete(self.name, self._t0, t1 - self._t0, self.attrs)
+
+    def __enter__(self):
+        return self.enter()
+
+    def __exit__(self, exc_type, exc, tb):
+        self.exit(exc_type=exc_type)
         return False
 
 
@@ -483,9 +525,10 @@ class Tracer:
 
 
 def _active_watchdog():
-    from .watchdog import get_active_watchdog
-
-    return get_active_watchdog()
+    # a process that never imported the watchdog module has none armed
+    # (and an import statement here costs more than the rest of the span)
+    mod = sys.modules.get(__package__ + ".watchdog")
+    return None if mod is None else mod.get_active_watchdog()
 
 
 class _TouchSpan:
@@ -507,23 +550,41 @@ class _TouchSpan:
         self._wd.touch(None)
         return False
 
-    def set_attr(self, **attrs):
+    def set_metadata(self, **attrs):
         pass
 
 
 def trace_span(name: str, **attrs):
     """Module-level span entry point for the instrumented hot paths:
     ``with trace_span("collective/gather"): ...``. Routes through the
-    process-wide active tracer; with only the watchdog active the span
-    still feeds it progress/phase signals; fully disabled this is two
-    global reads returning a shared no-op context manager."""
+    process-wide active tracer (whose spans are profiler annotations too);
+    with only the watchdog active the span still feeds it progress/phase
+    signals; with neither it is a bare profiler annotation, inactive
+    unless a ``jax.profiler`` session records."""
     tracer = _ACTIVE_TRACER
     if tracer:
         return tracer.span(name, **attrs)
     wd = _active_watchdog()
     if wd is not None:
         return _TouchSpan(wd, name)
-    return _NULL_SPAN
+    return _annotation(name, attrs) or _NULL_SPAN
+
+
+def span_enter(span, t0: float | None = None):
+    """Enter a :func:`trace_span` by hand (a phase that does not nest in a
+    ``with``), stamping a tracer's span with the caller's own boundary read
+    ``t0`` (``perf_counter`` seconds) when it has one."""
+    if isinstance(span, _Span):
+        return span.enter(t0)
+    span.__enter__()
+    return span
+
+
+def span_exit(span, t1: float | None = None) -> None:
+    if isinstance(span, _Span):
+        span.exit(t1)
+    else:
+        span.__exit__(None, None, None)
 
 
 def trace_instant(name: str, **attrs):

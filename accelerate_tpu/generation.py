@@ -86,6 +86,7 @@ def scale_logits(logits, temperature):
     return logits / jnp.maximum(temperature, TEMPERATURE_FLOOR)
 
 
+@jax.named_scope("sample")
 def pick_next_token(logits, key, finished, eos_id, temperature, do_sample, has_eos):
     """THE decode-step token pick (temperature floor, categorical key-split
     order, eos masking) — the single source of sampling semantics. Every

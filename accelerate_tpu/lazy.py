@@ -648,6 +648,21 @@ def _cost_aware_jit(fn, donate_argnums=(), label="", arg_names=(), out_shardings
     return call
 
 
+def scope_table(label: str) -> dict:
+    """``{instruction name: (result shape, scope stack)}`` over every
+    program compiled under ``label`` (``"fused_step"``, ...) while a
+    compile callback, a tracer or a cost collection kept its executable —
+    what puts the device operations of a trace under the model's
+    ``jax.named_scope``s (:func:`accelerate_tpu.utils.hlo.op_scopes`)."""
+    from .utils.hlo import op_scopes
+
+    table: dict = {}
+    for compiled, facts in _AOT_CACHE.values():
+        if facts["label"] == label:
+            table.update(op_scopes(compiled.as_text()))
+    return table
+
+
 def clear_caches():
     _FORCE_CACHE.clear()
     _GRAD_CACHE.clear()
@@ -873,9 +888,10 @@ def fused_step_fn_for(
 
         def step(params, opt_state, frozen_params, input_values, max_norm, scaler_state):
             scale = scaler_state[0] if grad_scaler is not None else jnp.float32(1.0)
-            (_, loss_value), grads = _vag(
-                params, frozen_params, input_values, scale
-            )
+            with jax.named_scope("loss"):  # forward and backward of the model
+                (_, loss_value), grads = _vag(
+                    params, frozen_params, input_values, scale
+                )
             step_ok = jnp.bool_(True)
             new_scaler_state = scaler_state
             if grad_scaler is not None:
@@ -894,8 +910,9 @@ def fused_step_fn_for(
                 # no clip requested: don't pay a full reduction pass over the
                 # grads just to report a norm nobody asked for
                 norm = jnp.asarray(0.0, jnp.float32)
-            updates, new_opt_state = tx.update(grads, opt_state, params)
-            new_params = optax.apply_updates(params, updates)
+            with jax.named_scope("optimizer"):
+                updates, new_opt_state = tx.update(grads, opt_state, params)
+                new_params = optax.apply_updates(params, updates)
             # fp16 non-finite: keep old state (structure-preserving select)
             if grad_scaler is not None:
                 keep = lambda new, old: jax.tree.map(

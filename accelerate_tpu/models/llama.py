@@ -155,6 +155,34 @@ def init_llama_params(key: jax.Array, config: LlamaConfig, dtype=jnp.float32):
     return params
 
 
+@jax.named_scope("mlp")
+def _swiglu_mlp(c, layer, x):
+    """The block's SwiGLU MLP with its residual."""
+    y = rms_norm(x, layer["mlp_norm"], c.rms_norm_eps)
+    gated = jax.nn.silu(dense(y, layer["w_gate"])) * dense(y, layer["w_up"])
+    return x + dense(gated, layer["w_down"])
+
+
+#: the vocabulary product, under ``head`` wherever it is traced (the fused
+#: cross-entropy calls it once per sequence chunk)
+_head_dense = jax.named_scope("head")(dense)
+
+
+@jax.named_scope("head")
+def _final_norm_and_head(c, params, x):
+    """``(normed hidden states, head matrix, logits)``."""
+    x = rms_norm(x, params["norm"], c.rms_norm_eps)
+    head = params.get("lm_head")
+    if head is None:
+        head = params["embed_tokens"].T
+    return x, head, dense(x, head)
+
+
+@jax.named_scope("embed")
+def _embed(params, input_ids):
+    return params["embed_tokens"][input_ids]
+
+
 def llama_layer_apply(
     config: LlamaConfig, layer, x, cos, sin, positions, attention_mask,
     return_kv: bool = False,
@@ -166,22 +194,23 @@ def llama_layer_apply(
     c = config
     nh, nkv, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
     b, s, h = x.shape
-    # attention
-    y = rms_norm(x, layer["attn_norm"], c.rms_norm_eps)
-    q = dense(y, layer["wq"]).reshape(b, s, nh, hd)
-    k = dense(y, layer["wk"]).reshape(b, s, nkv, hd)
-    v = dense(y, layer["wv"]).reshape(b, s, nkv, hd)
-    q = apply_rope(q, cos, sin, positions)
-    k = apply_rope(k, cos, sin, positions)
-    q = _constrain(q, P(("dp", "fsdp"), "cp", "tp", None))
-    k = _constrain(k, P(("dp", "fsdp"), "cp", "tp", None))
-    attn = attention(q, k, v, segment_mask=attention_mask, causal=True)
-    x = x + dense(attn.reshape(b, s, nh * hd), layer["wo"])
-    x = _constrain(x, residual_spec())
-    # mlp (SwiGLU)
-    y = rms_norm(x, layer["mlp_norm"], c.rms_norm_eps)
-    gated = jax.nn.silu(dense(y, layer["w_gate"])) * dense(y, layer["w_up"])
-    x = x + dense(gated, layer["w_down"])
+    # attention (the scope names are the trace vocabulary of
+    # docs/source/usage_guides/monitoring.md, shared with the paged step)
+    with jax.named_scope("attn_proj"):
+        y = rms_norm(x, layer["attn_norm"], c.rms_norm_eps)
+        q = dense(y, layer["wq"]).reshape(b, s, nh, hd)
+        k = dense(y, layer["wk"]).reshape(b, s, nkv, hd)
+        v = dense(y, layer["wv"]).reshape(b, s, nkv, hd)
+        q = apply_rope(q, cos, sin, positions)
+        k = apply_rope(k, cos, sin, positions)
+        q = _constrain(q, P(("dp", "fsdp"), "cp", "tp", None))
+        k = _constrain(k, P(("dp", "fsdp"), "cp", "tp", None))
+    with jax.named_scope("attn_kernel"):
+        attn = attention(q, k, v, segment_mask=attention_mask, causal=True)
+    with jax.named_scope("attn_proj"):
+        x = x + dense(attn.reshape(b, s, nh * hd), layer["wo"])
+        x = _constrain(x, residual_spec())
+    x = _swiglu_mlp(c, layer, x)
     x = _constrain(x, residual_spec())
     if return_kv:
         return x, (k, v)
@@ -308,8 +337,7 @@ def llama_apply(
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
 
-    x = params["embed_tokens"][input_ids]
-    x = _constrain(x, residual_spec())
+    x = _constrain(_embed(params, input_ids), residual_spec())
 
     if use_cache:
         max_cache = int(max_cache_len or c.max_position_embeddings)
@@ -330,25 +358,23 @@ def llama_apply(
             )
             return out, (jnp.pad(k, pad), jnp.pad(v, pad))
 
-        x, caches = prefill_layer_stack(
-            prefill_layer, params["layers"], x,
-            (c.num_hidden_layers, b, max_cache, c.num_key_value_heads, c.head_dim),
-            positions=positions, mask=attention_mask, rope=(cos, sin),
-        )
+        with jax.named_scope("layers"):
+            x, caches = prefill_layer_stack(
+                prefill_layer, params["layers"], x,
+                (c.num_hidden_layers, b, max_cache, c.num_key_value_heads, c.head_dim),
+                positions=positions, mask=attention_mask, rope=(cos, sin),
+            )
     else:
         pp_mesh = _pipeline_mesh()
-        if pp_mesh is not None:
-            x = _pipeline_stack(c, params["layers"], x, cos, sin, positions,
-                                attention_mask, pp_mesh)
-        else:
-            body = _block(c, cos, sin, positions, attention_mask)
-            x, _ = jax.lax.scan(body, x, params["layers"])
+        with jax.named_scope("layers"):
+            if pp_mesh is not None:
+                x = _pipeline_stack(c, params["layers"], x, cos, sin, positions,
+                                    attention_mask, pp_mesh)
+            else:
+                body = _block(c, cos, sin, positions, attention_mask)
+                x, _ = jax.lax.scan(body, x, params["layers"])
 
-    x = rms_norm(x, params["norm"], c.rms_norm_eps)
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embed_tokens"].T
-    logits = dense(x, head)
+    x, head, logits = _final_norm_and_head(c, params, x)
     logits = _constrain(logits, P(("dp", "fsdp"), "cp", "tp"))
 
     out = ModelOutput(logits=logits)
@@ -370,7 +396,8 @@ def llama_apply(
         if cp_active:
             out["loss"] = cross_entropy_loss(logits[:, :-1, :], labels[:, 1:])
         else:
-            out["loss"] = fused_cross_entropy(x, head, shift_labels(labels), dense_fn=dense)
+            out["loss"] = fused_cross_entropy(
+                x, head, shift_labels(labels), dense_fn=_head_dense)
     return out
 
 
@@ -382,10 +409,7 @@ def _llama_decode_layer(c, layer, x, k_cache_l, v_cache_l, cos, sin, idx, pp_man
         c.num_attention_heads, c.num_key_value_heads, c.head_dim,
         c.rms_norm_eps, pp_manual=pp_manual,
     )
-    y = rms_norm(x, layer["mlp_norm"], c.rms_norm_eps)
-    gated = jax.nn.silu(dense(y, layer["w_gate"])) * dense(y, layer["w_up"])
-    x = x + dense(gated, layer["w_down"])
-    return x, k_cache_l, v_cache_l
+    return _swiglu_mlp(c, layer, x), k_cache_l, v_cache_l
 
 
 def _llama_decode_step(c, params, input_ids, kv_cache, cache_index, cos, sin):
@@ -397,19 +421,16 @@ def _llama_decode_step(c, params, input_ids, kv_cache, cache_index, cos, sin):
 
     b, s = input_ids.shape
     idx = jnp.asarray(cache_index, jnp.int32).reshape(b)
-    x = params["embed_tokens"][input_ids]
+    x = _embed(params, input_ids)
 
-    x, kv = decode_stack(
-        lambda layer, h, kc_l, vc_l, idx_b, cos_b, sin_b, pp_manual: _llama_decode_layer(
-            c, layer, h, kc_l, vc_l, cos_b, sin_b, idx_b, pp_manual=pp_manual
-        ),
-        params["layers"], kv_cache, x, broadcast=(idx, cos, sin),
-    )
-    x = rms_norm(x, params["norm"], c.rms_norm_eps)
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embed_tokens"].T
-    logits = dense(x, head)
+    with jax.named_scope("layers"):
+        x, kv = decode_stack(
+            lambda layer, h, kc_l, vc_l, idx_b, cos_b, sin_b, pp_manual: _llama_decode_layer(
+                c, layer, h, kc_l, vc_l, cos_b, sin_b, idx_b, pp_manual=pp_manual
+            ),
+            params["layers"], kv_cache, x, broadcast=(idx, cos, sin),
+        )
+    _, _, logits = _final_norm_and_head(c, params, x)
     return ModelOutput(logits=logits, kv_cache=kv)
 
 
@@ -430,7 +451,7 @@ def _llama_paged_step(
 
     b, s = input_ids.shape
     idx = jnp.asarray(cache_positions, jnp.int32).reshape(b)
-    x = params["embed_tokens"][input_ids]
+    x = _embed(params, input_ids)
     quantized = "k_scale" in paged_kv
 
     def body(x, layer_pages):
@@ -444,21 +465,14 @@ def _llama_paged_step(
             c.rms_norm_eps, write_mask=paged_write_mask,
             k_scale_l=ks_l, v_scale_l=vs_l,
         )
-        x, pages = out[0], out[1:]
-        y = rms_norm(x, layer["mlp_norm"], c.rms_norm_eps)
-        gated = jax.nn.silu(dense(y, layer["w_gate"])) * dense(y, layer["w_up"])
-        x = x + dense(gated, layer["w_down"])
-        return x, pages
+        return _swiglu_mlp(c, layer, out[0]), out[1:]
 
     xs = (params["layers"], paged_kv["k"], paged_kv["v"])
     if quantized:
         xs = xs + (paged_kv["k_scale"], paged_kv["v_scale"])
-    x, pages = jax.lax.scan(body, x, xs)
-    x = rms_norm(x, params["norm"], c.rms_norm_eps)
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embed_tokens"].T
-    logits = dense(x, head)
+    with jax.named_scope("layers"):
+        x, pages = jax.lax.scan(body, x, xs)
+    _, _, logits = _final_norm_and_head(c, params, x)
     out_pages = {"k": pages[0], "v": pages[1]}
     if quantized:
         out_pages["k_scale"], out_pages["v_scale"] = pages[2], pages[3]

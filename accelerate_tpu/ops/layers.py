@@ -389,28 +389,33 @@ def rope_paged_attention_block(
     b, s, _ = x.shape
     idx = jnp.asarray(idx, jnp.int32).reshape(b)
     positions = idx[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]  # [b, s]
-    y = rms_norm(x, layer["attn_norm"], eps)
-    q = apply_rope(
-        dense(y, layer["wq"]).reshape(b, s, n_heads, head_dim), cos, sin, positions
-    )
-    k = apply_rope(
-        dense(y, layer["wk"]).reshape(b, s, n_kv_heads, head_dim), cos, sin, positions
-    )
-    v = dense(y, layer["wv"]).reshape(b, s, n_kv_heads, head_dim)
+    # scopes: the same names llama_layer_apply gives the training block
+    with jax.named_scope("attn_proj"):
+        y = rms_norm(x, layer["attn_norm"], eps)
+        q = apply_rope(
+            dense(y, layer["wq"]).reshape(b, s, n_heads, head_dim), cos, sin, positions
+        )
+        k = apply_rope(
+            dense(y, layer["wk"]).reshape(b, s, n_kv_heads, head_dim), cos, sin, positions
+        )
+        v = dense(y, layer["wv"]).reshape(b, s, n_kv_heads, head_dim)
     quantized = k_scale_l is not None
-    written = write_paged_kv(
-        k_pages_l, v_pages_l, k, v, block_tables, positions,
-        write_mask=write_mask, k_scale_l=k_scale_l, v_scale_l=v_scale_l,
-    )
+    with jax.named_scope("kv_write"):
+        written = write_paged_kv(
+            k_pages_l, v_pages_l, k, v, block_tables, positions,
+            write_mask=write_mask, k_scale_l=k_scale_l, v_scale_l=v_scale_l,
+        )
     if quantized:
         k_pages_l, v_pages_l, k_scale_l, v_scale_l = written
     else:
         k_pages_l, v_pages_l = written
-    attn = paged_attention(
-        q, k_pages_l, v_pages_l, block_tables, idx,
-        k_scale_l=k_scale_l, v_scale_l=v_scale_l, impl=attn_impl,
-    )
-    x = x + dense(attn.reshape(b, s, n_heads * head_dim), layer["wo"])
+    with jax.named_scope("attn_kernel"):
+        attn = paged_attention(
+            q, k_pages_l, v_pages_l, block_tables, idx,
+            k_scale_l=k_scale_l, v_scale_l=v_scale_l, impl=attn_impl,
+        )
+    with jax.named_scope("attn_proj"):
+        x = x + dense(attn.reshape(b, s, n_heads * head_dim), layer["wo"])
     if quantized:
         return x, k_pages_l, v_pages_l, k_scale_l, v_scale_l
     return x, k_pages_l, v_pages_l

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import optax
 
 from .analysis.sanitizer import get_active_sanitizer as _get_sanitizer
+from .diagnostics.tracing import trace_span
 from .state import AcceleratorState, GradientState
 
 
@@ -140,7 +141,7 @@ class AcceleratedOptimizer:
         self._step_ok_device = None  # fp16: lazily-fetched finite flag
         self.comm_hook = None  # (hook_str, mesh): compressed dp grad reduction
         self.telemetry = None  # TelemetryRecorder, wired by prepare_optimizer
-        self.tracer = None     # diagnostics Tracer, wired by prepare_optimizer
+        self._fused_steps = 0  # numbers the profiler's ``train`` steps
         self.watchdog = None   # diagnostics Watchdog, wired by prepare_optimizer
 
     # -- initialisation (called by Accelerator.prepare) ----------------------
@@ -251,10 +252,14 @@ class AcceleratedOptimizer:
         )
         frozen_params = [m.params for m in frozen]
         scaler_state = self.scaler.state() if self.scaler is not None else ()
-        new_params, new_opt_state, loss_value, norm, step_ok, new_scaler_state = jitted(
-            self.model.params, self.opt_state, frozen_params, inputs,
-            clip if clip is not None else 0.0, scaler_state,
-        )
+        self._fused_steps += 1
+        # a numbered step in a profiler capture (XProf's step view groups
+        # the device operations of the dispatch under it)
+        with jax.profiler.StepTraceAnnotation("train", step_num=self._fused_steps):
+            new_params, new_opt_state, loss_value, norm, step_ok, new_scaler_state = jitted(
+                self.model.params, self.opt_state, frozen_params, inputs,
+                clip if clip is not None else 0.0, scaler_state,
+            )
         self.model.params = new_params
         self.opt_state = new_opt_state
         if self.scaler is not None:
@@ -273,16 +278,14 @@ class AcceleratedOptimizer:
         tel = self.telemetry
         tel_on = tel is not None and tel.enabled
         wd = self.watchdog
-        tracer = self.tracer
-        if not tel_on and wd is None and tracer is None:
-            return self._step_inner(closure)
+        dispatch = trace_span("step/dispatch", sync=self.gradient_state.sync_gradients)
+        if not tel_on and wd is None:
+            with dispatch:
+                return self._step_inner(closure)
         import time
 
         t0 = time.perf_counter()
-        if tracer is not None:
-            with tracer.span("step/dispatch", sync=self.gradient_state.sync_gradients):
-                self._step_inner(closure)
-        else:
+        with dispatch:
             self._step_inner(closure)
         t1 = time.perf_counter()
         device_s = None
@@ -296,10 +299,7 @@ class AcceleratedOptimizer:
             # into host dispatch vs device-blocked (costs the host-runahead
             # pipelining; the recorder's sync_device=False keeps full async)
             try:
-                if tracer is not None:
-                    with tracer.span("step/device_wait"):
-                        jax.block_until_ready(self.model.params)
-                else:
+                with trace_span("step/device_wait"):
                     jax.block_until_ready(self.model.params)
                 device_s = time.perf_counter() - t1
             except Exception:

@@ -60,7 +60,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..analysis.sanitizer import get_active_sanitizer as _get_sanitizer
-from ..diagnostics.tracing import ensure_trace_id, get_tracer, trace_span, valid_trace_id
+from ..diagnostics.tracing import (
+    ensure_trace_id,
+    get_tracer,
+    span_enter,
+    span_exit,
+    trace_span,
+    valid_trace_id,
+)
 from ..generation import _pick_traced
 from ..metrics.ingest import observe_flight
 from ..metrics.registry import get_active_registry
@@ -139,8 +146,10 @@ class EngineConfig:
     #: asserted to sum to the measured wall time — the host-vs-device
     #: attribution ``stats()['host_fraction']``, ``trace tail
     #: --iterations``, ``/profile`` windows, and HANG_REPORT forensics
-    #: all read. Stamps are five perf_counter reads per iteration; the
-    #: disabled path is one ``is None`` check.
+    #: all read. One perf_counter read per phase boundary (and one
+    #: ``time.time_ns()`` per iteration, the anchor on the profiler's
+    #: clock); disabled, the boundaries read no clock and only open the
+    #: ``serve/<phase>`` spans.
     flight_history: int = 256
     #: finished :class:`Request` objects retained for ``stats()``
     #: percentiles — a *ring*, not a list: a long-lived serve process must
@@ -261,6 +270,17 @@ class _InFlightRound:
     tvals: object = None
     tids: object = None
     harvest_lp: bool = False
+
+
+#: keys of a flight entry that place it on a clock; the ``serve/flight``
+#: Chrome instant carries the rest (its own ``ts`` places it, and the
+#: phases are ``serve/<phase>`` events of their own in that file)
+_FLIGHT_CLOCK_KEYS = frozenset({"t_start", "t_start_unix_ns", "intervals"})
+
+#: the span names of an iteration and of its phases (children of it), as a
+#: profiler capture and the Chrome trace show them
+_ITERATION_SPAN = "serve/iteration"
+_PHASE_SPANS = {phase: "serve/" + phase for phase in ITERATION_PHASES}
 
 
 def _under_mesh(apply_fn, mesh):
@@ -562,12 +582,31 @@ class InferenceEngine:
         # so that host time is off the critical path. The open-time rule
         # makes sync-mode overlap exactly 0.0 (dispatch opens with
         # nothing in flight) and keeps device_wait pure residual sync.
+        # The same switch is the ONE stamper of a phase boundary: it also
+        # closes the open ``serve/<phase>`` span and opens the next (the
+        # Tracer's Chrome events and the profiler's host rows are both fed
+        # from there, children of one ``serve/iteration`` span) and keeps
+        # the iteration's (phase, start, end) intervals for the recorder.
         self._fl_t0 = 0.0
         self._fl_last = 0.0
+        self._fl_unix_ns = 0
         self._fl_cur = "idle"
         self._fl_phases: dict | None = None
+        self._fl_intervals: list = []
         self._fl_overlap = 0.0
         self._fl_hidden = False
+        self._fl_span = None
+        self._fl_iter_span = None
+        # time-to-first-token, decomposed at the boundaries a request
+        # crosses (monotone totals, reset with the measurement window):
+        # arrival -> admission (queue), time inside its own prefill chunks,
+        # and the iterations those took; what remains of ttft_sum_s is time
+        # admitted but waiting behind decode rounds and other prompts
+        self._first_tokens_total = 0
+        self._ttft_sum_s = 0.0
+        self._ttft_queue_sum_s = 0.0
+        self._ttft_own_prefill_sum_s = 0.0
+        self._ttft_prefill_iterations_sum = 0
         # static HBM model for the hbm watermark fallback: params + the
         # paged pools (+ scales), the same inventory the PR 8 preflight
         # prices — used verbatim when the backend has no memory_stats()
@@ -757,6 +796,16 @@ class InferenceEngine:
         configured, a full compile otherwise."""
         jitted, operands = self._dispatched[program]
         return jitted.lower(*operands).compile().as_text()
+
+    def scope_table(self, program: str) -> dict:
+        """``{instruction name: (result shape, scope stack)}`` of the
+        ``"decode"`` or ``"prefill"`` executable
+        (:func:`accelerate_tpu.utils.hlo.op_scopes` of its compiled text):
+        a TPU trace names a device operation by its instruction, and this
+        is the way back to the ``jax.named_scope`` it was traced under."""
+        from ..utils.hlo import op_scopes
+
+        return op_scopes(self.compiled_text(program))
 
     def _paged_kv_dict(self, kp, vp, ks, vs) -> dict:
         pages = {"k": kp, "v": vp}
@@ -1315,47 +1364,41 @@ class InferenceEngine:
         finished: list[Request] = []
 
         fl = self._flight
+        tokens_before = self._tokens_emitted
         self._fl_begin()
 
         deferred_deadline: list[Request] = []
-        with trace_span("serve/schedule"):
-            if sched.deadline_live:  # guarded: deadline-free = one int check
-                now = time.perf_counter()
-                inflight_slots = None
-                if self._inflight is not None:
-                    # an expired member of the in-flight round still has a
-                    # token landing at this step's harvest — the token the
-                    # synchronous engine emitted LAST step. Defer its
-                    # expiry to just after the harvest point so the two
-                    # loops stay token-identical.
-                    inflight_slots = {r.slot for r in self._inflight.live}
-                for req in sched.expire_deadlines(now, skip_slots=inflight_slots):
-                    if req.slot is None:
-                        self._release_expired_queued(req)
-                    self._deadline_expired += 1
-                    finished.append(req)
-                if inflight_slots:
-                    deferred_deadline = [
-                        r for r in self._inflight.live
-                        if r.deadline is not None and now > r.deadline
-                    ]
-            sched.evict_finished()
-            self._admit_and_place()
+        if sched.deadline_live:  # guarded: deadline-free = one int check
+            now = time.perf_counter()
+            inflight_slots = None
+            if self._inflight is not None:
+                # an expired member of the in-flight round still has a
+                # token landing at this step's harvest — the token the
+                # synchronous engine emitted LAST step. Defer its
+                # expiry to just after the harvest point so the two
+                # loops stay token-identical.
+                inflight_slots = {r.slot for r in self._inflight.live}
+            for req in sched.expire_deadlines(now, skip_slots=inflight_slots):
+                if req.slot is None:
+                    self._release_expired_queued(req)
+                self._deadline_expired += 1
+                finished.append(req)
+            if inflight_slots:
+                deferred_deadline = [
+                    r for r in self._inflight.live
+                    if r.deadline is not None and now > r.deadline
+                ]
+        sched.evict_finished()
+        self._admit_and_place()
 
         self._fl_switch("prefill")
-        with trace_span("serve/prefill"):
-            # one chunk per PREFILLING SLOT per iteration: slot turnover is
-            # never throttled to one admission per decode burst, while any
-            # single prompt still advances at most one chunk between decode
-            # steps — the TTFT/stall bound chunked prefill exists for
-            u = self.usage
-            for req in sched.active(RequestState.PREFILL):
-                if u is not None:
-                    t0_pf = time.perf_counter()
-                    self._prefill_one_chunk(req, finished)
-                    u.accrue_prefill(req, time.perf_counter() - t0_pf)
-                else:
-                    self._prefill_one_chunk(req, finished)
+        # one chunk per PREFILLING SLOT per iteration: slot turnover is
+        # never throttled to one admission per decode burst, while any
+        # single prompt still advances at most one chunk between decode
+        # steps — the TTFT/stall bound chunked prefill exists for
+        prefilling = sched.active(RequestState.PREFILL)
+        for req in prefilling:
+            self._prefill_one_chunk(req, finished)
 
         # harvest point: the previous iteration's round lands here,
         # exactly one iteration late. Backlog entries were force-harvested
@@ -1379,8 +1422,7 @@ class InferenceEngine:
         self._fl_switch("dispatch")
         decoding = sched.active(RequestState.DECODE)
         if decoding:
-            with trace_span("serve/decode", slots=len(decoding)):
-                self._dispatch_decode(decoding, finished)
+            self._dispatch_decode(decoding, finished)
         if not self.config.async_dispatch:
             # synchronous escape hatch: harvest the round we just
             # dispatched before leaving the iteration (the pre-item-5 loop)
@@ -1415,12 +1457,17 @@ class InferenceEngine:
                 if summary is not None:  # exactly-once across re-lists
                     req.usage = summary
         self._emit_telemetry(finished)
+        self._fl_iter_span.set_metadata(
+            decoding=len(decoding), prefill_chunks=len(prefilling),
+            tokens=self._tokens_emitted - tokens_before,
+        )
         rec = self._fl_finish()
         if rec is not None:
             t0, wall, phases, overlap = rec
             entry = fl.record(
                 self._iterations, t0, wall,
-                overlap_hidden_s=overlap, **phases,
+                overlap_hidden_s=overlap, intervals=self._fl_intervals,
+                t_start_unix_ns=self._fl_unix_ns, **phases,
             )
             fl.current_phase = "idle"
             reg = get_active_registry()
@@ -1434,7 +1481,7 @@ class InferenceEngine:
                 self._tr.counter("serve/iteration", fl.host_fraction())
                 self._tr.instant(
                     "serve/flight",
-                    **{k: v for k, v in entry.items() if k != "t_start"},
+                    **{k: v for k, v in entry.items() if k not in _FLIGHT_CLOCK_KEYS},
                 )
         return finished
 
@@ -1495,6 +1542,9 @@ class InferenceEngine:
         self._grammar_masked_steps = 0
         self._rej_drafted = 0
         self._rej_accepted = 0
+        self._first_tokens_total = 0
+        self._ttft_sum_s = self._ttft_queue_sum_s = self._ttft_own_prefill_sum_s = 0.0
+        self._ttft_prefill_iterations_sum = 0
         # hit accounting restarts with the measurement window; the trie and
         # its cached blocks deliberately stay warm (steady-state behaviour
         # is what a warmed bench leg measures)
@@ -1639,6 +1689,16 @@ class InferenceEngine:
             "swapped_in_blocks": self._swapped_in_blocks,
             "out_of_blocks_total": self._out_of_blocks_total,
             "deadline_expired_total": self._deadline_expired,
+            # time to first token by where it went (monotone totals over
+            # the first tokens emitted since reset): queue = arrival ->
+            # first admission; own prefill = time inside this request's
+            # _prefill_one_chunk calls; the rest of ttft_sum_s was spent
+            # admitted, waiting behind decode rounds and other prompts
+            "first_tokens_total": self._first_tokens_total,
+            "ttft_sum_s": self._ttft_sum_s,
+            "ttft_queue_sum_s": self._ttft_queue_sum_s,
+            "ttft_own_prefill_sum_s": self._ttft_own_prefill_sum_s,
+            "ttft_prefill_iterations_sum": self._ttft_prefill_iterations_sum,
         }
         out.update(self._spec_stats())
         out.update(self._sampling_stats())
@@ -1709,61 +1769,90 @@ class InferenceEngine:
     # -- iteration internals -------------------------------------------------
 
     def _fl_begin(self) -> None:
-        """Open the iteration's flight accounting in the "schedule" phase
-        (no-op when the recorder is disabled)."""
-        if self._flight is None:
+        """Open the iteration: its ``serve/iteration`` span, the
+        ``serve/schedule`` span under it, and — when the recorder is on —
+        the flight accounting in the "schedule" phase, all on one clock
+        read (plus the iteration's anchor on the profiler's clock)."""
+        fl = self._flight
+        if self._fl_span is not None:
+            # the last iteration raised between two boundaries: its spans
+            # end here, not in the open-span registry of a hang report
+            span_exit(self._fl_span)
+            span_exit(self._fl_iter_span)
+        if fl is None:
+            t = None
+        else:  # the two clocks read back to back: one instant, two names
+            t, self._fl_unix_ns = time.perf_counter(), time.time_ns()
+        self._fl_iter_span = span_enter(
+            trace_span(_ITERATION_SPAN, iteration=self._iterations + 1), t
+        )
+        self._fl_span = span_enter(trace_span(_PHASE_SPANS["schedule"]), t)
+        self._fl_cur = "schedule"
+        if fl is None:
             self._fl_phases = None
             return
-        t = time.perf_counter()
         self._fl_t0 = self._fl_last = t
         self._fl_phases = dict.fromkeys(ITERATION_PHASES, 0.0)
+        self._fl_intervals = []
         self._fl_overlap = 0.0
-        self._fl_cur = "schedule"
         # hidden-overlap rule: an interval counts as hidden iff a round
         # was in flight when it OPENED (and it is not device_wait) — the
         # schedule work at the top of an async steady-state iteration runs
         # entirely under the previous round
         self._fl_hidden = self._inflight is not None
-        self._flight.current_phase = "schedule"
+        fl.current_phase = "schedule"
 
-    def _fl_switch(self, phase: str) -> float | None:
-        """Close the open interval into its phase bucket and open
-        ``phase``. Phases may be re-entered (the async loop visits
-        "harvest" both at the harvest point and for bookkeeping) — the
-        buckets accumulate, and their sum telescopes to the iteration
-        wall exactly, which ``FlightRecorder.record`` asserts. Returns
-        the closed interval's duration (None when the recorder is off) —
-        the usage ledger accrues the EXACT ``device_wait`` float the
-        flight recorder does, which is what makes Σ per-request decode
-        shares == flight ``device_wait`` an identity, not an estimate."""
+    def _fl_close(self) -> tuple:
+        """Close the open interval into its phase bucket and the interval
+        list; returns ``(stamp, duration)`` — ``(None, None)`` when the
+        recorder is off, where a boundary reads no clock."""
         if self._fl_phases is None:
-            return None
+            return None, None
         t = time.perf_counter()
         dt = t - self._fl_last
         self._fl_phases[self._fl_cur] += dt
+        self._fl_intervals.append(
+            (self._fl_cur, self._fl_last - self._fl_t0, t - self._fl_t0)
+        )
         if self._fl_hidden:
             self._fl_overlap += dt
         self._fl_last = t
+        return t, dt
+
+    def _fl_switch(self, phase: str) -> float | None:
+        """THE phase boundary: close the open interval (bucket, interval
+        list, ``serve/<phase>`` span) and open ``phase`` on the same clock
+        read. Phases may be re-entered (the async loop visits "harvest"
+        both at the harvest point and for bookkeeping) — the buckets
+        accumulate, and their sum telescopes to the iteration wall
+        exactly, which ``FlightRecorder.record`` asserts. Returns the
+        closed interval's duration (None when the recorder is off) — the
+        usage ledger accrues the EXACT ``device_wait`` float the flight
+        recorder does, which is what makes Σ per-request decode shares ==
+        flight ``device_wait`` an identity, not an estimate."""
+        t, dt = self._fl_close()
+        span_exit(self._fl_span, t)
+        self._fl_span = span_enter(trace_span(_PHASE_SPANS[phase]), t)
         self._fl_cur = phase
-        # decided at OPEN time: device_wait is by definition the residual
-        # the host could NOT hide, so it never accrues overlap
-        self._fl_hidden = self._inflight is not None and phase != "device_wait"
-        self._flight.current_phase = phase
+        if dt is not None:
+            # decided at OPEN time: device_wait is by definition the
+            # residual the host could NOT hide, so it never accrues overlap
+            self._fl_hidden = self._inflight is not None and phase != "device_wait"
+            self._flight.current_phase = phase
         return dt
 
     def _fl_finish(self):
-        """Close the last interval; returns ``(t0, wall_s, phases,
-        overlap_hidden_s)`` for ``FlightRecorder.record`` (None when the
-        recorder is disabled)."""
-        if self._fl_phases is None:
-            return None
-        t = time.perf_counter()
-        dt = t - self._fl_last
-        self._fl_phases[self._fl_cur] += dt
-        if self._fl_hidden:
-            self._fl_overlap += dt
-        phases, self._fl_phases = self._fl_phases, None
+        """Close the last interval and both spans; returns ``(t0, wall_s,
+        phases, overlap_hidden_s)`` for ``FlightRecorder.record`` (None
+        when the recorder is disabled)."""
+        t, _ = self._fl_close()
+        span_exit(self._fl_span, t)
+        span_exit(self._fl_iter_span, t)
+        self._fl_span = self._fl_iter_span = None
         self._fl_cur = "idle"
+        if t is None:
+            return None
+        phases, self._fl_phases = self._fl_phases, None
         return self._fl_t0, t - self._fl_t0, phases, self._fl_overlap
 
     def _harvest_inflight(self, finished: list[Request]) -> None:
@@ -1894,8 +1983,10 @@ class InferenceEngine:
         sched = self.scheduler
         while True:
             for req in sched.admit():
+                now = time.perf_counter()
+                if req.admit_time is None:  # a re-admission keeps the first
+                    req.admit_time = now
                 if self._tr is not None:
-                    now = time.perf_counter()
                     self._tr.request_instant(
                         req.trace_id, "req/admit", ts=now, slot=req.slot,
                         queued_s=now - req.arrival_time,
@@ -2103,6 +2194,12 @@ class InferenceEngine:
         row[: len(req.blocks)] = req.blocks
 
     def _prefill_one_chunk(self, req: Request, finished: list[Request]) -> None:
+        """One chunk of one prompt. Its two clock reads are the request's
+        own-prefill stamps: the usage ledger's prefill accrual, the
+        ``req/prefill_chunk`` event and — on the final chunk, whose read
+        follows the blocking first-token fetch — ``first_token_time`` all
+        take them instead of reading the clock again."""
+        t0 = time.perf_counter()
         cfg = self.config
         c = cfg.prefill_chunk
         start = req.prefill_pos
@@ -2131,11 +2228,25 @@ class InferenceEngine:
                 self._key, self._temp,
             )
         req.prefill_pos = end
+        req.prefill_iterations += 1
+        lp_entry = None
+        if is_final:
+            if self._psampling:
+                # re-pick from the returned prompt-final logits through the
+                # SAME lane transform decode uses (position 0 of the
+                # request's derived key stream); on an inert request this
+                # is the same argmax the executable's own pick took
+                tok, lp_entry = self._first_token_pick(req, _logits)
+            tok = int(tok)  # blocks until the chunk (and all before it) ran
+        t1 = time.perf_counter()
+        req.own_prefill_s += t1 - t0
+        if self.usage is not None:
+            self.usage.accrue_prefill(req, t1 - t0)
         if self._tr is not None:
             # one event per CHUNK (bounded by prompt_len / prefill_chunk),
             # never per token
             self._tr.request_instant(
-                req.trace_id, "req/prefill_chunk", start=start, end=end,
+                req.trace_id, "req/prefill_chunk", ts=t1, start=start, end=end,
                 final=is_final,
             )
         if is_final:
@@ -2144,14 +2255,7 @@ class InferenceEngine:
                 # into the prefix trie (refcount+1 = the cache's reference)
                 # so later admissions with the same leading tokens map them
                 self.radix.insert(req.prompt, req.blocks)
-            lp_entry = None
-            if self._psampling:
-                # re-pick from the returned prompt-final logits through the
-                # SAME lane transform decode uses (position 0 of the
-                # request's derived key stream); on an inert request this
-                # is the same argmax the executable's own pick took
-                tok, lp_entry = self._first_token_pick(req, _logits)
-            self._emit_token(req, int(tok), finished, lp_entry)
+            self._emit_token(req, tok, finished, lp_entry, now=t1)
             if req.state is not RequestState.FINISHED:
                 req.state = RequestState.DECODE
 
@@ -2508,9 +2612,11 @@ class InferenceEngine:
             self._row_lru[self._row_grammar[row].hash] = row
 
     def _emit_token(
-        self, req: Request, tok: int, finished: list[Request], lp_entry=None
+        self, req: Request, tok: int, finished: list[Request], lp_entry=None,
+        now: float | None = None,
     ) -> None:
-        now = time.perf_counter()
+        if now is None:
+            now = time.perf_counter()
         req.output_tokens.append(tok)
         self._pending_tok[req.slot] = tok
         self._tokens_emitted += 1
@@ -2527,6 +2633,13 @@ class InferenceEngine:
             req.logprobs.append(lp_entry)
         if req.first_token_time is None:
             req.first_token_time = now
+            self._first_tokens_total += 1
+            self._ttft_sum_s += now - req.arrival_time
+            self._ttft_queue_sum_s += (
+                req.admit_time if req.admit_time is not None else now
+            ) - req.arrival_time
+            self._ttft_own_prefill_sum_s += req.own_prefill_s
+            self._ttft_prefill_iterations_sum += req.prefill_iterations
             if self._tr is not None:
                 self._tr.request_instant(
                     req.trace_id, "req/first_token", ts=now,

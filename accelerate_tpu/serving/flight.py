@@ -72,6 +72,29 @@ def set_active_flight_recorder(recorder) -> None:
     _active_flight_recorder = recorder
 
 
+def _checked_intervals(intervals, wall_s: float, phases: dict) -> list:
+    """``[(phase, start_s, end_s), ...]`` as given, once they are seen to
+    tile ``[0, wall_s]`` without a hole or an overlap and to add up to the
+    phase buckets (each boundary is ONE clock read shared by the interval
+    it closes and the one it opens, so the joins are exact)."""
+    out = [(str(p), float(a), float(b)) for p, a, b in intervals]
+    sums = dict.fromkeys(phases, 0.0)
+    edge = 0.0
+    for phase, start, end in out:
+        if start != edge or end < start or phase not in sums:
+            raise AssertionError(f"flight intervals do not tile: {out!r}")
+        sums[phase] += end - start
+        edge = end
+    if not math.isclose(edge, wall_s, rel_tol=1e-9, abs_tol=1e-9):
+        raise AssertionError(
+            f"flight intervals end at {edge!r}, iteration wall {wall_s!r}")
+    for phase, total in sums.items():
+        if not math.isclose(total, phases[phase], rel_tol=1e-9, abs_tol=1e-6):
+            raise AssertionError(
+                f"flight intervals give {phase} {total!r}, bucket {phases[phase]!r}")
+    return out
+
+
 class FlightRecorder:
     """Bounded ring of per-iteration phase breakdowns + cumulative
     totals. Ring entries answer "what were the last K iterations doing"
@@ -100,7 +123,8 @@ class FlightRecorder:
         self.current_phase = "idle"
 
     def record(self, iteration: int, t_start: float, wall_s: float,
-               overlap_hidden_s: float = 0.0, **phases: float) -> dict:
+               overlap_hidden_s: float = 0.0, intervals=None,
+               t_start_unix_ns: int | None = None, **phases: float) -> dict:
         """Append one iteration. ``phases`` must cover exactly
         :data:`ITERATION_PHASES` and sum to ``wall_s`` — the stamps
         telescope (each phase is the diff of consecutive perf_counter
@@ -110,7 +134,15 @@ class FlightRecorder:
         ``overlap_hidden_s`` is *not* a sixth phase: it re-counts the
         portion of the host phases that ran under an in-flight dispatch
         (double-buffered engine), so it is bounded by
-        ``wall_s − device_wait`` — also asserted."""
+        ``wall_s − device_wait`` — also asserted.
+
+        ``intervals`` is the same iteration as ``(phase, start_s, end_s)``
+        in stamp order, seconds from ``t_start``; a phase may repeat. They
+        must tile ``[0, wall_s]`` and add up, per phase, to the buckets —
+        asserted too. ``t_start_unix_ns`` is ``t_start`` on the clock
+        ``jax.profiler`` stamps host events with (``time.time_ns()``; an
+        xplane's times count from its ``profile_start_time``), so the
+        intervals can be laid over a device trace's idle gaps."""
         if set(phases) != set(ITERATION_PHASES):
             raise AssertionError(
                 f"flight phases {sorted(phases)} != {sorted(ITERATION_PHASES)}"
@@ -133,6 +165,10 @@ class FlightRecorder:
         entry = {"iteration": int(iteration), "t_start": float(t_start),
                  "wall_s": float(wall_s),
                  "overlap_hidden_s": overlap_hidden_s}
+        if intervals is not None:
+            entry["intervals"] = _checked_intervals(intervals, wall_s, phases)
+        if t_start_unix_ns is not None:
+            entry["t_start_unix_ns"] = int(t_start_unix_ns)
         for p in ITERATION_PHASES:
             entry[f"{p}_s"] = float(phases[p])
             self.phase_totals_s[p] += float(phases[p])

@@ -316,6 +316,7 @@ def dist_logprobs(filtered, lanes):
     )
 
 
+@jax.named_scope("sample")  # the pick and the log-probability harvest
 def pick_tokens(logits, lanes, dfa_state, step, gmask, base_key, *, eos_id, logprobs_topn):
     """The per-slot decode-step pick.  Returns ``(tok [S], logp_tok [S],
     top_vals [S,N], top_ids [S,N])`` with ``N = max(logprobs_topn, 1)``

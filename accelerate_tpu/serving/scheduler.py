@@ -117,6 +117,13 @@ class Request:
     prefill_pos: int = 0  # prompt tokens whose K/V are already cached
     first_token_time: float | None = None
     finish_time: float | None = None
+    #: the stamps time-to-first-token is decomposed from (engine
+    #: ``stats()`` ``ttft_*_sum_s``): first admission to a slot, seconds
+    #: inside this request's own prefill chunks, and how many iterations
+    #: ran one
+    admit_time: float | None = None
+    own_prefill_s: float = 0.0
+    prefill_iterations: int = 0
     matched_tokens: int = 0  # prefix-cache hit length at admission
     cow: tuple[int, int] | None = None  # (src, dst) pending device copy
     swap_plan: list[tuple[int, int]] = field(default_factory=list)

@@ -96,3 +96,48 @@ def stablehlo_allreduce_bytes(text: str) -> dict[str, int]:
 def hlo_allreduce_bytes(text: str) -> dict[str, int]:
     """{dtype: result bytes} over every compiled-HLO ``all-reduce`` op."""
     return hlo_collective_bytes(text).get("all-reduce", {})
+
+
+#: ``%fusion.12 = bf16[64,4096]{1,0:T(8,128)(2,1)} fusion(...), calls=%f.3,
+#: metadata={op_name="jit(decode)/.../mlp/dot_general" ...}`` — one
+#: instruction of a compiled module: name, first result array, the rest
+_HLO_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.-]+) = \(*([a-z]\w*\[[0-9,]*\])?(.*)$")
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.-]+) \(")
+_HLO_OP_NAME = re.compile(r'\bop_name="([^"]+)"')
+_HLO_CALLS = re.compile(r"\bcalls=%?([\w.-]+)")
+
+
+def op_scopes(text: str) -> dict[str, tuple[str, str]]:
+    """``{instruction name: (result shape, scope stack)}`` of a compiled
+    module's text: what a device trace needs to put an executed operation
+    under the ``jax.named_scope`` it came from — the TPU profiler names an
+    event by its instruction, not by its ``op_name``. The result shape
+    (``bf16[64,4096]``, the first array of a tuple) tells apart the
+    instructions of two programs that share a name. A fusion the compiler
+    left without an ``op_name`` takes the deepest stack among the
+    instructions it fused (a callee is printed before its caller);
+    instructions that are the compiler's own throughout (layout copies)
+    are left out."""
+    table: dict[str, tuple[str, str]] = {}
+    deepest: dict[str, str] = {}  # computation -> the deepest stack inside it
+    computation = ""
+    for line in text.splitlines():
+        head = _HLO_COMPUTATION.match(line)
+        if head:
+            computation = head.group(1)
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if not m:
+            continue
+        name, shape, rest = m.groups()
+        own = _HLO_OP_NAME.search(rest)
+        if own:
+            stack = own.group(1)
+        else:
+            callee = _HLO_CALLS.search(rest)
+            stack = deepest.get(callee.group(1), "") if callee else ""
+        if stack:
+            table[name] = (shape or "", stack)
+            if stack.count("/") >= deepest.get(computation, "").count("/"):
+                deepest[computation] = stack
+    return table
