@@ -451,9 +451,12 @@ def plan_kv_pool(
     dtype: str = "float32",
 ) -> list[LeafPlan]:
     """Placement plan for the serving engine's two paged pools, mirroring
-    :func:`parallel.sharding.paged_kv_sharding`: kv-head dim over ``tp``
-    when it divides, else replicated. ``num_blocks`` defaults to the
-    engine's full-residency default (slots × per-slot max + null block).
+    :func:`parallel.sharding.paged_kv_sharding`: the pools are stored
+    lane-folded (``[layers, num_blocks, block_size, n_kv*head_dim]``) and
+    the folded dim goes over ``tp`` — whole kv heads per shard — when
+    ``tp`` divides ``num_kv_heads``, else replicated. ``num_blocks``
+    defaults to the engine's full-residency default (slots × per-slot max
+    + null block).
 
     Quantized storage (``dtype`` of ``int8``/``fp8``/``float8_e4m3fn`` —
     the engine's ``kv_dtype`` policy) adds the two f32 amax scale arrays
@@ -466,19 +469,22 @@ def plan_kv_pool(
     quantized = str(dtype) in _KV_QUANTIZED_DTYPES
     if str(dtype) == "fp8":
         dtype = "float8_e4m3fn"
-    shape = (num_layers, num_blocks, block_size, num_kv_heads, head_dim)
+    shape = (num_layers, num_blocks, block_size, num_kv_heads * head_dim)
     tp = mesh_sizes.get("tp", 1)
     sharded = tp > 1 and num_kv_heads % tp == 0
     divisor = tp if sharded else 1
 
-    def _leaf(name, shape, dtype, spec_sharded):
+    # pool lanes and scale heads are both the last of four dimensions
+    spec = "PartitionSpec(None, None, None, 'tp')" if sharded else "PartitionSpec()"
+
+    def _leaf(name, shape, dtype):
         nbytes = _leaf_nbytes(shape, dtype)
         return LeafPlan(
             path=f"kv_pool.{name}",
             shape=shape,
             dtype=str(dtype),
             tier="kv_pool",
-            spec=spec_sharded if sharded else "PartitionSpec()",
+            spec=spec,
             source="rule" if sharded else "replicated",
             rule_index=None,
             dropped=(),
@@ -486,15 +492,10 @@ def plan_kv_pool(
             bytes_per_device=nbytes // divisor,
         )
 
-    pool_spec = "PartitionSpec(None, None, None, 'tp', None)"
-    leaves = [_leaf(name, shape, dtype, pool_spec) for name in ("k", "v")]
+    leaves = [_leaf(name, shape, dtype) for name in ("k", "v")]
     if quantized:
         scale_shape = (num_layers, num_blocks, block_size, num_kv_heads)
-        scale_spec = "PartitionSpec(None, None, None, 'tp')"
-        leaves += [
-            _leaf(name, scale_shape, "float32", scale_spec)
-            for name in ("k_scale", "v_scale")
-        ]
+        leaves += [_leaf(name, scale_shape, "float32") for name in ("k_scale", "v_scale")]
     return leaves
 
 
@@ -1018,6 +1019,7 @@ def engine_preflight(
     rules,
     mesh,
     pool_shape: tuple[int, ...],
+    num_kv_heads: int,
     pool_dtype,
     hbm_budget_gb: float,
     swap_gb: float | None = None,
@@ -1026,7 +1028,9 @@ def engine_preflight(
 ) -> dict:
     """The serving engine's capacity check, run BEFORE the pools allocate:
     predicted per-device bytes of params (under the same planner
-    ``_place_on_mesh`` uses) + the two paged pools, vs the budget.
+    ``_place_on_mesh`` uses) + the two paged pools (``pool_shape`` is the
+    engine's stored, lane-folded ``[layers, num_blocks, block_size,
+    num_kv_heads*head_dim]``), vs the budget.
 
     Returns ``{params_bytes, pool_bytes, total_bytes, budget_bytes,
     headroom_bytes, over}`` — the engine raises on ``over`` (the SP004
@@ -1052,8 +1056,8 @@ def engine_preflight(
         num_layers=pool_shape[0],
         num_blocks=pool_shape[1],
         block_size=pool_shape[2],
-        num_kv_heads=pool_shape[3],
-        head_dim=pool_shape[4],
+        num_kv_heads=num_kv_heads,
+        head_dim=pool_shape[3] // num_kv_heads,
         num_slots=1,  # num_blocks is explicit; slots only feed the default
         max_seq_len=pool_shape[2],
         mesh_sizes=sizes,
@@ -1076,8 +1080,8 @@ def engine_preflight(
     if swap_gb:
         report["swap_pool_host_bytes"] = plan_swap_pool(
             num_layers=pool_shape[0],
-            num_kv_heads=pool_shape[3],
-            head_dim=pool_shape[4],
+            num_kv_heads=num_kv_heads,
+            head_dim=pool_shape[3] // num_kv_heads,
             block_size=pool_shape[2],
             swap_gb=swap_gb,
             dtype=str(np.dtype(pool_dtype)),

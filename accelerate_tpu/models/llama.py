@@ -289,7 +289,7 @@ def llama_apply(
     kv_cache=None,  # {"k","v"}: [L, b, max_cache, n_kv, hd] (decode step)
     cache_index: jax.Array | None = None,  # [b] per-row write position
     max_cache_len: int | None = None,
-    paged_kv=None,  # {"k","v"}: [L, num_blocks, block_size, n_kv, hd]
+    paged_kv=None,  # {"k","v"}: [L, num_blocks, block_size, n_kv*hd]
     block_tables: jax.Array | None = None,  # [b, max_blocks] pool block ids
     cache_positions: jax.Array | None = None,  # [b] first new token position
     paged_write_mask: jax.Array | None = None,  # [b, s] real-token mask
@@ -307,7 +307,8 @@ def llama_apply(
     * **paged decode/prefill-chunk** (``paged_kv=`` + ``block_tables=`` +
       ``cache_positions=``) — the serving engine's block-paged cache path
       (``supports_paged_kv``): K/V scatter through each slot's block table
-      into a shared pool, attention against the gathered logical prefix.
+      into the stacked pool, which the step addresses in place by layer
+      and block (:func:`_llama_paged_step`).
       One compiled ``[num_slots, 1]`` program serves every decode iteration
       for the lifetime of the engine; ``s > 1`` with a ``paged_write_mask``
       is a chunked-prefill slice of one prompt.
@@ -440,43 +441,50 @@ def _llama_paged_step(
 ):
     """One step against the block-paged KV pool: ``s == 1`` token per slot
     (the engine's single compiled decode program) or an ``s``-token prefill
-    chunk of one prompt. K/V land in pool blocks through each slot's block
-    table (:func:`ops.layers.write_paged_kv` — quantize-on-scatter when
-    ``paged_kv`` carries ``k_scale``/``v_scale`` arrays, the engine's
-    ``kv_dtype`` policy); attention is the fused block-table walk
-    (:mod:`ops.paged_attention`), never a materialised span gather. The
-    layer loop is a plain scan — the serving engine is a single-host path
-    (no pp stage pipeline)."""
+    chunk of one prompt. ``paged_kv`` holds the stacked pools ``k`` / ``v``
+    (``[pool_layers, num_blocks, block_size, n_kv*hd]``, heads folded into
+    lanes) and, quantized, ``k_scale`` / ``v_scale`` (``[pool_layers,
+    num_blocks, block_size, n_kv]``).
+
+    **The pool stays where it is.** The layer loop scans over the layer
+    weights and a layer index only; the pools travel in the carry. K/V land
+    as a row scatter at ``(layer, block, offset)`` through each slot's
+    block table (:func:`ops.layers.write_paged_kv` — quantize-on-scatter
+    when the scale arrays ride along, the engine's ``kv_dtype`` policy);
+    attention is the fused block-table walk at ``(layer, block)``
+    (:mod:`ops.paged_attention`), never a materialised span gather, and
+    never a slice of one layer's slab. The loop runs over the layers of
+    the ``params`` it was given, which may be fewer than the pool's: the
+    early-exit draft (:func:`llama_early_exit_apply`) reads and writes
+    layers ``0..N-1`` of the target's own pool by index. The layer loop is
+    a plain scan — the serving engine is a single-host path (no pp stage
+    pipeline)."""
     from ..ops.layers import rope_paged_attention_block
 
     b, s = input_ids.shape
     idx = jnp.asarray(cache_positions, jnp.int32).reshape(b)
     x = _embed(params, input_ids)
-    quantized = "k_scale" in paged_kv
+    names = ("k", "v", "k_scale", "v_scale") if "k_scale" in paged_kv else ("k", "v")
+    n_layers = jax.tree.leaves(params["layers"])[0].shape[0]
 
-    def body(x, layer_pages):
-        if quantized:
-            layer, kp_l, vp_l, ks_l, vs_l = layer_pages
-        else:
-            (layer, kp_l, vp_l), ks_l, vs_l = layer_pages, None, None
-        out = rope_paged_attention_block(
-            layer, x, kp_l, vp_l, cos, sin, block_tables, idx,
+    def body(carry, layer_and_index):
+        x, pools = carry
+        layer, layer_idx = layer_and_index
+        x, *pools = rope_paged_attention_block(
+            layer, x, pools[0], pools[1], layer_idx, cos, sin, block_tables, idx,
             c.num_attention_heads, c.num_key_value_heads, c.head_dim,
             c.rms_norm_eps, write_mask=paged_write_mask,
-            k_scale_l=ks_l, v_scale_l=vs_l,
+            **dict(zip(("k_scale", "v_scale"), pools[2:])),
         )
-        return _swiglu_mlp(c, layer, out[0]), out[1:]
+        return (_swiglu_mlp(c, layer, x), tuple(pools)), None
 
-    xs = (params["layers"], paged_kv["k"], paged_kv["v"])
-    if quantized:
-        xs = xs + (paged_kv["k_scale"], paged_kv["v_scale"])
     with jax.named_scope("layers"):
-        x, pages = jax.lax.scan(body, x, xs)
+        (x, pools), _ = jax.lax.scan(
+            body, (x, tuple(paged_kv[n] for n in names)),
+            (params["layers"], jnp.arange(n_layers, dtype=jnp.int32)),
+        )
     _, _, logits = _final_norm_and_head(c, params, x)
-    out_pages = {"k": pages[0], "v": pages[1]}
-    if quantized:
-        out_pages["k_scale"], out_pages["v_scale"] = pages[2], pages[3]
-    return ModelOutput(logits=logits, paged_kv=out_pages)
+    return ModelOutput(logits=logits, paged_kv=dict(zip(names, pools)))
 
 
 def llama_early_exit_apply(config: LlamaConfig, draft_layers: int):
@@ -493,10 +501,11 @@ def llama_early_exit_apply(config: LlamaConfig, draft_layers: int):
     the compiled program (shard-check prices it as the ``draft_params``
     tier). Because the draft's layers are byte-identical to the target's
     prefix, its K/V at any cached position equal the target's for those
-    layers: the serving engine exploits this by pointing the draft at the
-    first ``draft_layers`` layers of the target's own paged pool — no
-    separate draft cache, and prefix sharing / CoW / swap maintain the
-    draft state for free."""
+    layers: the serving engine exploits this by handing the draft the
+    target's own paged pool, whole — the paged step indexes the pool by
+    layer, so the draft reads and writes layers ``0..draft_layers-1`` of it
+    in place, with no ``pool[:N]`` slice — no separate draft cache, and
+    prefix sharing / CoW / swap maintain the draft state for free."""
     if not 1 <= draft_layers < config.num_hidden_layers:
         raise ValueError(
             f"early-exit draft needs 1 <= layers < {config.num_hidden_layers} "

@@ -280,109 +280,95 @@ def cached_attention(q, k_cache, v_cache, idx):
 # ---------------------------------------------------------------------------
 # Block-paged KV cache (serving engine)
 #
-# The serving engine's cache is one pool of fixed-size blocks per layer
-# (``[num_blocks, block_size, n_kv, hd]``) plus a per-slot **block table**
-# mapping each slot's logical block index to a pool block — the
-# PagedAttention layout (vLLM, SOSP '23). Block 0 is the reserved *null
-# block*: free slots and unfilled table entries point at it, so the static
-# ``[num_slots, 1]`` decode step needs no dynamic shapes, and garbage
-# written/read there is always masked out by the per-slot valid prefix.
+# The serving engine's cache is ONE stacked pool of fixed-size blocks per
+# K and per V, stored lane-folded: ``[layers, num_blocks, block_size,
+# n_kv*hd]`` (head ``n`` is the lane slice ``[n*hd, (n+1)*hd)`` — the view
+# the Pallas kernel reads is the view that is stored), plus a per-slot
+# **block table** mapping each slot's logical block index to a pool block —
+# the PagedAttention layout (vLLM, SOSP '23). The pool never moves: the
+# step programs take it donated, carry it through the layer loop, write
+# new rows at ``(layer, block, offset)`` and read blocks at ``(layer,
+# block)``; nothing of a layer's slab size is ever sliced out or written
+# back. Block 0 is the reserved *null block*: free slots and unfilled
+# table entries point at it, so the static ``[num_slots, 1]`` decode step
+# needs no dynamic shapes, and garbage written/read there is always masked
+# out by the per-slot valid prefix.
 # ---------------------------------------------------------------------------
 
 
 def write_paged_kv(
-    k_pages_l, v_pages_l, k, v, block_tables, positions, write_mask=None,
-    k_scale_l=None, v_scale_l=None,
+    k_pool, v_pool, layer, k, v, block_tables, positions, write_mask=None,
+    k_scale=None, v_scale=None,
 ):
-    """Scatter a chunk's K/V (``[b, s, n_kv, hd]``) into block-paged caches
-    ``[num_blocks, block_size, n_kv, hd]`` at absolute token ``positions``
-    ``[b, s]`` through each row's ``block_tables`` row ``[b, max_blocks]``.
+    """Scatter a chunk's K/V (``[b, s, n_kv, hd]``) into layer ``layer`` of
+    the stacked block-paged pools ``[layers, num_blocks, block_size,
+    n_kv*hd]`` at absolute token ``positions`` ``[b, s]`` through each
+    row's ``block_tables`` row ``[b, max_blocks]``: row ``(b, s)`` lands at
+    ``(layer, block_tables[b, pos // bs], pos % bs)``. ``layer`` may be a
+    traced scalar (the layer loop's index); the whole pool is the scatter's
+    operand, so a loop that carries the pool updates it in place.
 
     ``write_mask`` ``[b, s]`` (optional) marks real tokens; masked lanes
-    (the padded tail of a final prefill chunk) are routed out of range and
-    dropped — the pool never sees them. Positions past the table span
-    (post-budget burst lane-steps at a slot's maximum) gather an
+    (the padded tail of a final prefill chunk) get an out-of-range block id
+    and are dropped — the pool never sees them. Positions past the table
+    span (post-budget burst lane-steps at a slot's maximum) gather an
     out-of-range block id via ``mode="fill"`` and are likewise dropped —
-    never clamped into the slot's own final block. Distinct live slots own
-    disjoint blocks, so the flattened scatter has no cross-slot
-    collisions; only the null block (0) absorbs free-slot writes, and it
-    is never attended.
+    never clamped into the slot's own final block, and never carried into
+    another layer's rows (the index is ``(layer, block, offset)``, not a
+    flattened row number). Distinct live slots own disjoint blocks, so the
+    scatter has no cross-slot collisions; only the null block (0) absorbs
+    free-slot writes, and it is never attended.
 
-    **Quantize-on-scatter** (``k_scale_l``/``v_scale_l`` given, shape
-    ``[num_blocks, bs, n_kv]`` f32): K/V are amax-quantized per written
-    row into the pool's storage dtype (int8/fp8 — ``ops/fp8.py``) and each
-    row's scale is scattered through the *same* flat indices, so payload
-    and scale stay atomic under the identical drop/masking rules. Returns
-    4 arrays in that case."""
-    nb, bs = k_pages_l.shape[0], k_pages_l.shape[1]
+    **Quantize-on-scatter** (``k_scale``/``v_scale`` given, shape
+    ``[layers, num_blocks, bs, n_kv]`` f32): K/V are amax-quantized per
+    written row into the pool's storage dtype (int8/fp8 — ``ops/fp8.py``)
+    and each row's scale is scattered through the *same* indices, so
+    payload and scale stay atomic under the identical drop/masking rules.
+    Returns 4 arrays in that case."""
+    nb, bs = k_pool.shape[1], k_pool.shape[2]
     b, s = k.shape[0], k.shape[1]
     positions = jnp.asarray(positions, jnp.int32)
     blk = jnp.take_along_axis(
         jnp.asarray(block_tables, jnp.int32), positions // bs, axis=1,
         mode="fill", fill_value=nb,
-    )  # [b, s]; fill → flat lands past the pool and the scatter drops it
-    flat = blk * bs + positions % bs
+    )  # [b, s]; fill → a block id past the pool, which the scatter drops
     if write_mask is not None:
-        flat = jnp.where(write_mask, flat, nb * bs)  # out of range → dropped
-    flat = flat.reshape(b * s)
-    if k_scale_l is not None:
+        blk = jnp.where(write_mask, blk, nb)  # out of range → dropped
+    blk = blk.reshape(b * s)
+    off = (positions % bs).reshape(b * s)
+    layer = jnp.asarray(layer, jnp.int32)
+
+    def put(pool, rows):
+        return pool.at[layer, blk, off].set(
+            rows.reshape(b * s, pool.shape[-1]), mode="drop"
+        )
+
+    if k_scale is not None:
         from .fp8 import quantize_kv_rows
 
-        store = k_pages_l.dtype
-        k, k_sc = quantize_kv_rows(k, store)   # [b,s,n_kv,hd] + [b,s,n_kv]
-        v, v_sc = quantize_kv_rows(v, store)
-        ksf = k_scale_l.reshape(nb * bs, *k_scale_l.shape[2:])
-        vsf = v_scale_l.reshape(nb * bs, *v_scale_l.shape[2:])
-        ksf = ksf.at[flat].set(k_sc.reshape(b * s, *k_sc.shape[2:]), mode="drop")
-        vsf = vsf.at[flat].set(v_sc.reshape(b * s, *v_sc.shape[2:]), mode="drop")
-        k_scale_l = ksf.reshape(nb, bs, *k_scale_l.shape[2:])
-        v_scale_l = vsf.reshape(nb, bs, *v_scale_l.shape[2:])
-    else:
-        k = k.astype(k_pages_l.dtype)  # e.g. bf16 storage under f32 compute
-        v = v.astype(v_pages_l.dtype)
-    kf = k_pages_l.reshape(nb * bs, *k_pages_l.shape[2:])
-    vf = v_pages_l.reshape(nb * bs, *v_pages_l.shape[2:])
-    kf = kf.at[flat].set(k.reshape(b * s, *k.shape[2:]), mode="drop")
-    vf = vf.at[flat].set(v.reshape(b * s, *v.shape[2:]), mode="drop")
-    if k_scale_l is not None:
-        return (
-            kf.reshape(k_pages_l.shape), vf.reshape(v_pages_l.shape),
-            k_scale_l, v_scale_l,
-        )
-    return kf.reshape(k_pages_l.shape), vf.reshape(v_pages_l.shape)
-
-
-def gather_paged_kv(k_pages_l, v_pages_l, block_tables):
-    """Materialise each slot's logical cache from the block pool:
-    ``[num_blocks, bs, n_kv, hd]`` gathered through ``[b, max_blocks]`` →
-    ``[b, max_blocks*bs, n_kv, hd]``. Logical position ``p`` lands at
-    gathered index ``p`` (tables are ordered), so the result feeds
-    :func:`cached_attention` unchanged — paged decode shares the dense decode
-    path's masking/softmax/dtype policy by construction."""
-    bt = jnp.asarray(block_tables, jnp.int32)
-    k = k_pages_l[bt]  # [b, max_blocks, bs, n_kv, hd]
-    v = v_pages_l[bt]
-    b, mb, bs = k.shape[0], k.shape[1], k.shape[2]
-    return (
-        k.reshape(b, mb * bs, *k.shape[3:]),
-        v.reshape(b, mb * bs, *v.shape[3:]),
-    )
+        k, k_sc = quantize_kv_rows(k, k_pool.dtype)   # [b,s,n_kv,hd] + [b,s,n_kv]
+        v, v_sc = quantize_kv_rows(v, v_pool.dtype)
+        return put(k_pool, k), put(v_pool, v), put(k_scale, k_sc), put(v_scale, v_sc)
+    # e.g. bf16 storage under f32 compute
+    return put(k_pool, k.astype(k_pool.dtype)), put(v_pool, v.astype(v_pool.dtype))
 
 
 def rope_paged_attention_block(
-    layer, x, k_pages_l, v_pages_l, cos, sin, block_tables, idx,
+    layer, x, k_pool, v_pool, layer_idx, cos, sin, block_tables, idx,
     n_heads: int, n_kv_heads: int, head_dim: int, eps: float,
-    write_mask=None, k_scale_l=None, v_scale_l=None, attn_impl=None,
+    write_mask=None, k_scale=None, v_scale=None, attn_impl=None,
 ):
     """Paged twin of :func:`rope_cached_attention_block`: RMSNorm → q/k/v →
-    RoPE at each slot's absolute position → block-table scatter
+    RoPE at each slot's absolute position → row scatter into layer
+    ``layer_idx`` of the stacked pools through the block table
     (quantize-on-scatter when scale arrays ride along) → **fused paged
-    attention** walking the block table directly
-    (:func:`ops.paged_attention.paged_attention` — the gathered
-    ``[b, max_blocks*bs, ...]`` span is never materialised) → output
-    projection residual. ``s == 1`` is the engine's decode step; ``s > 1``
-    a prefill chunk (``write_mask`` drops its padded tail). Returns the
-    scale arrays too when quantized."""
+    attention** walking the block table directly at ``(layer_idx, block)``
+    (:func:`ops.paged_attention.paged_attention` — neither the layer's slab
+    nor the gathered ``[b, max_blocks*bs, ...]`` span is ever
+    materialised) → output projection residual. ``s == 1`` is the engine's
+    decode step; ``s > 1`` a prefill chunk (``write_mask`` drops its padded
+    tail). Returns ``(x, k_pool, v_pool)`` — the whole pools, updated —
+    and the scale arrays too when quantized."""
     from .fp8 import dense
     from .paged_attention import paged_attention
 
@@ -399,23 +385,16 @@ def rope_paged_attention_block(
             dense(y, layer["wk"]).reshape(b, s, n_kv_heads, head_dim), cos, sin, positions
         )
         v = dense(y, layer["wv"]).reshape(b, s, n_kv_heads, head_dim)
-    quantized = k_scale_l is not None
     with jax.named_scope("kv_write"):
-        written = write_paged_kv(
-            k_pages_l, v_pages_l, k, v, block_tables, positions,
-            write_mask=write_mask, k_scale_l=k_scale_l, v_scale_l=v_scale_l,
+        pools = write_paged_kv(
+            k_pool, v_pool, layer_idx, k, v, block_tables, positions,
+            write_mask=write_mask, k_scale=k_scale, v_scale=v_scale,
         )
-    if quantized:
-        k_pages_l, v_pages_l, k_scale_l, v_scale_l = written
-    else:
-        k_pages_l, v_pages_l = written
     with jax.named_scope("attn_kernel"):
         attn = paged_attention(
-            q, k_pages_l, v_pages_l, block_tables, idx,
-            k_scale_l=k_scale_l, v_scale_l=v_scale_l, impl=attn_impl,
+            q, pools[0], pools[1], layer_idx, block_tables, idx,
+            *pools[2:], impl=attn_impl,
         )
     with jax.named_scope("attn_proj"):
         x = x + dense(attn.reshape(b, s, n_heads * head_dim), layer["wo"])
-    if quantized:
-        return x, k_pages_l, v_pages_l, k_scale_l, v_scale_l
-    return x, k_pages_l, v_pages_l
+    return (x, *pools)

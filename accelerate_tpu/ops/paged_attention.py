@@ -7,13 +7,16 @@ decode step moves scale with the *maximum* context and the GQA expansion,
 not the valid prefix. This module computes attention **block-by-block**
 straight off the block table:
 
-* one pool block ``[bs, n_kv, hd]`` is loaded per table entry, dequantized
-  in registers when the pool is int8/fp8 (``ops/fp8.py`` scales), and
-  consumed by an **online softmax** (running max / sum / accumulator — the
-  flash-attention recurrence), so no ``[b, max_blocks*bs, ...]`` buffer
-  ever exists;
+* one pool block ``[bs, n_kv*hd]`` is loaded per table entry, straight out
+  of the stacked pool at ``(layer, block)`` — the pool is stored
+  lane-folded (``[layers, num_blocks, bs, n_kv*hd]``), which is the view
+  the kernel reads, so neither a layer's slab nor a relayout of it is
+  ever produced — dequantized in registers when the pool is int8/fp8
+  (``ops/fp8.py`` scales), and consumed by an **online softmax** (running
+  max / sum / accumulator — the flash-attention recurrence), so no
+  ``[b, max_blocks*bs, ...]`` buffer ever exists;
 * GQA uses a **grouped-head einsum** (``[b, s, n_kv, rep, hd]`` against
-  ``[b, bs, n_kv, hd]``) — repeated KV heads are never materialised;
+  ``[b, bs, n_kv, hd]``, a reshape of the small gathered block) — repeated KV heads are never materialised;
 * positions past each row's valid prefix are masked inside the recurrence
   (same policy as ``cached_attention``), and the Pallas kernel skips the
   compute of fully-invalid table entries.
@@ -48,8 +51,10 @@ def default_paged_attention_impl() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "lax"
 
 
-def _dequant_block(block, scale_rows):
-    """One gathered pool block → f32, applying per-row scales if present."""
+def _dequant_block(block, scale_rows, n_kv):
+    """Gathered pool blocks ``[..., bs, n_kv*hd]`` → f32 ``[..., bs, n_kv,
+    hd]``, applying per-row scales if present."""
+    block = block.reshape(*block.shape[:-1], n_kv, block.shape[-1] // n_kv)
     if scale_rows is None:
         return block.astype(jnp.float32)
     return dequantize_kv(block, scale_rows)
@@ -57,41 +62,50 @@ def _dequant_block(block, scale_rows):
 
 def paged_attention(
     q,                      # [b, s, n_heads, hd]
-    k_pages_l,              # [num_blocks, bs, n_kv, hd] (storage dtype)
-    v_pages_l,              # [num_blocks, bs, n_kv, hd]
+    k_pool,                 # [layers, num_blocks, bs, n_kv*hd] (storage dtype)
+    v_pool,                 # [layers, num_blocks, bs, n_kv*hd]
+    layer,                  # int32 scalar (may be traced): the pool layer read
     block_tables,           # [b, max_blocks] int32
     idx,                    # [b] int32 — first query's cache position
-    k_scale_l=None,         # [num_blocks, bs, n_kv] f32 (quantized pools)
-    v_scale_l=None,
+    k_scale=None,           # [layers, num_blocks, bs, n_kv] f32 (quantized pools)
+    v_scale=None,
     impl: str | None = None,
     interpret: bool = False,
 ):
-    """Attention of ``q`` against each row's block-table span. Query ``j``
-    of row ``b`` attends logical cache positions ``<= idx[b]+j`` — the
-    same per-row valid-prefix + intra-chunk causal policy as
-    :func:`ops.layers.cached_attention`, so paged decode keeps matching
-    dense decode. ``impl``: ``None`` routes via
-    :func:`default_paged_attention_impl`;
+    """Attention of ``q`` against each row's block-table span in layer
+    ``layer`` of the stacked pools. Query ``j`` of row ``b`` attends
+    logical cache positions ``<= idx[b]+j`` — the same per-row valid-prefix
+    + intra-chunk causal policy as :func:`ops.layers.cached_attention`, so
+    paged decode keeps matching dense decode. Every route addresses the
+    pool at ``(layer, block)``; none slices the layer out first. ``impl``:
+    ``None`` routes via :func:`default_paged_attention_impl`;
     ``"lax"``/``"pallas"``/``"gather"`` force a path (``"gather"`` is the
     PR 4 materialise-the-span reference, kept for parity tests and the
     fused-vs-gather bench ratio). ``interpret`` runs the Pallas kernel in
     the Pallas interpreter — how tests exercise it off-TPU."""
     if impl is None:
         impl = default_paged_attention_impl()
+    layer = jnp.asarray(layer, jnp.int32)
     if impl == "lax":
         return _paged_attention_lax(
-            q, k_pages_l, v_pages_l, block_tables, idx, k_scale_l, v_scale_l
+            q, k_pool, v_pool, layer, block_tables, idx, k_scale, v_scale
         )
     if impl == "pallas":
         return _paged_attention_pallas_sharded(
-            q, k_pages_l, v_pages_l, block_tables, idx, k_scale_l, v_scale_l,
+            q, k_pool, v_pool, layer, block_tables, idx, k_scale, v_scale,
             interpret=interpret,
         )
     if impl == "gather":
         return _paged_attention_gather(
-            q, k_pages_l, v_pages_l, block_tables, idx, k_scale_l, v_scale_l
+            q, k_pool, v_pool, layer, block_tables, idx, k_scale, v_scale
         )
     raise ValueError(f"unknown paged attention impl {impl!r}")
+
+
+def _kv_heads(q, k_pool) -> int:
+    """kv heads in the (possibly per-shard) pool: the folded lane width
+    over the query's head size."""
+    return k_pool.shape[-1] // q.shape[-1]
 
 
 # ---------------------------------------------------------------------------
@@ -99,9 +113,10 @@ def paged_attention(
 # ---------------------------------------------------------------------------
 
 
-def _paged_attention_lax(q, k_pages_l, v_pages_l, block_tables, idx, k_scale_l, v_scale_l):
+def _paged_attention_lax(q, k_pool, v_pool, layer, block_tables, idx, k_scale, v_scale):
     b, s, nh, hd = q.shape
-    _, bs, n_kv, _ = k_pages_l.shape
+    bs = k_pool.shape[2]
+    n_kv = _kv_heads(q, k_pool)
     rep = nh // n_kv
     mb = block_tables.shape[1]
     bt = jnp.asarray(block_tables, jnp.int32)
@@ -114,8 +129,12 @@ def _paged_attention_lax(q, k_pages_l, v_pages_l, block_tables, idx, k_scale_l, 
     def body(carry, j):
         m, l, acc = carry
         blk = bt[:, j]                                   # [b]
-        kb = _dequant_block(k_pages_l[blk], None if k_scale_l is None else k_scale_l[blk])
-        vb = _dequant_block(v_pages_l[blk], None if v_scale_l is None else v_scale_l[blk])
+        kb = _dequant_block(
+            k_pool[layer, blk], None if k_scale is None else k_scale[layer, blk], n_kv
+        )
+        vb = _dequant_block(
+            v_pool[layer, blk], None if v_scale is None else v_scale[layer, blk], n_kv
+        )
         # [b, n_kv, rep, s, bs]: contraction over hd, batched over kv head
         sc = jnp.einsum("bsnrd,btnd->bnrst", qg, kb)
         pos = j * bs + jnp.arange(bs, dtype=jnp.int32)   # logical positions
@@ -146,20 +165,28 @@ def _paged_attention_lax(q, k_pages_l, v_pages_l, block_tables, idx, k_scale_l, 
 # ---------------------------------------------------------------------------
 
 
-def _paged_attention_gather(q, k_pages_l, v_pages_l, block_tables, idx, k_scale_l, v_scale_l):
-    from .layers import cached_attention, gather_paged_kv
+def _paged_attention_gather(q, k_pool, v_pool, layer, block_tables, idx, k_scale, v_scale):
+    """Materialise each row's logical cache — ``[b, max_blocks*bs, n_kv,
+    hd]`` gathered through the table; logical position ``p`` lands at
+    gathered index ``p`` (tables are ordered) — and feed
+    :func:`ops.layers.cached_attention` unchanged, so the reference shares
+    the dense decode path's masking/softmax/dtype policy by construction."""
+    from .layers import cached_attention
 
-    if k_scale_l is not None:
-        bt = jnp.asarray(block_tables, jnp.int32)
-        b, mb = bt.shape
-        bs = k_pages_l.shape[1]
-        k_g = dequantize_kv(k_pages_l[bt], k_scale_l[bt])
-        v_g = dequantize_kv(v_pages_l[bt], v_scale_l[bt])
-        k_g = k_g.reshape(b, mb * bs, *k_g.shape[3:])
-        v_g = v_g.reshape(b, mb * bs, *v_g.shape[3:])
-    else:
-        k_g, v_g = gather_paged_kv(k_pages_l, v_pages_l, block_tables)
-    return cached_attention(q, k_g, v_g, jnp.asarray(idx, jnp.int32).reshape(q.shape[0]))
+    bt = jnp.asarray(block_tables, jnp.int32)
+    b, mb = bt.shape
+    n_kv = _kv_heads(q, k_pool)
+
+    def span(pool, scale):
+        g = _dequant_block(                      # [b, mb, bs, n_kv, hd]
+            pool[layer, bt], None if scale is None else scale[layer, bt], n_kv
+        )
+        return g.reshape(b, mb * g.shape[2], *g.shape[3:])
+
+    return cached_attention(
+        q, span(k_pool, k_scale), span(v_pool, v_scale),
+        jnp.asarray(idx, jnp.int32).reshape(b),
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -167,17 +194,19 @@ def _paged_attention_gather(q, k_pages_l, v_pages_l, block_tables, idx, k_scale_
 # ---------------------------------------------------------------------------
 
 
-def _pallas_kernel(bt_ref, idx_ref, q_ref, k_ref, v_ref, *rest,
+def _pallas_kernel(bt_ref, idx_ref, layer_ref, q_ref, k_ref, v_ref, *rest,
                    bs, n_kv, rep, hd, quantized):
     """Grid ``(b, max_blocks)``: step ``(i, j)`` consumes row ``i``'s
     ``j``-th table entry — the BlockSpec index maps already steered the
-    right pool block into VMEM via the prefetched block table. Online
+    right pool block of the right layer into VMEM via the prefetched block
+    table and layer index (``layer_ref`` is read by the index maps only). Online
     softmax state lives in VMEM scratch across the ``j`` steps (the last
     grid axis iterates fastest); entries wholly past the row's valid
     prefix skip their compute.
 
     Every operand is 2-D inside the kernel: heads are folded into the lane
-    dimension outside (``[.., n*hd]``), and head ``h`` is the static lane
+    dimension (``[.., n*hd]`` — how the pool is stored; a reshape of the
+    small query outside), and head ``h`` is the static lane
     slice ``[h*hd, (h+1)*hd)`` — Mosaic tiles the two minor dimensions, so
     a head axis kept second-minor (12 rows padded to 16) and a 4-D
     batched-in-the-middle einsum cost a prefill chunk 119 MB of VMEM."""
@@ -238,38 +267,37 @@ def _pallas_kernel(bt_ref, idx_ref, q_ref, k_ref, v_ref, *rest,
             out_ref[0, :, lanes] = out.astype(out_ref.dtype)
 
 
-def _paged_attention_pallas(q, k_pages_l, v_pages_l, block_tables, idx,
-                            k_scale_l, v_scale_l, *, interpret):
+def _paged_attention_pallas(q, k_pool, v_pool, layer, block_tables, idx,
+                            k_scale, v_scale, *, interpret):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, s, nh, hd = q.shape
-    nb, bs, n_kv, _ = k_pages_l.shape
+    bs, width = k_pool.shape[2], k_pool.shape[3]
+    n_kv = width // hd
     mb = block_tables.shape[1]
-    quantized = k_scale_l is not None
+    quantized = k_scale is not None
 
-    def row(i, j, bt, ix):
+    def row(i, j, bt, ix, ly):
         return (i, 0, 0)
 
-    def block(i, j, bt, ix):
-        return (bt[i, j], 0, 0)
+    def block(i, j, bt, ix, ly):
+        return (ly[0], bt[i, j], 0, 0)
 
+    # the pool operand is the stored pool, whole: its BlockSpec squeezes the
+    # layer dimension and the index map picks (layer, block), so the kernel
+    # body sees the same [1, bs, n_kv*hd] block as ever and no slab exists
     in_specs = [
         pl.BlockSpec((1, s, nh * hd), row),
-        pl.BlockSpec((1, bs, n_kv * hd), block),
-        pl.BlockSpec((1, bs, n_kv * hd), block),
+        pl.BlockSpec((None, 1, bs, width), block),
+        pl.BlockSpec((None, 1, bs, width), block),
     ]
-    # heads fold into lanes: free reshapes of contiguous minor dimensions
-    args = [
-        q.reshape(b, s, nh * hd),
-        k_pages_l.reshape(nb, bs, n_kv * hd),
-        v_pages_l.reshape(nb, bs, n_kv * hd),
-    ]
+    args = [q.reshape(b, s, nh * hd), k_pool, v_pool]
     if quantized:
-        in_specs += [pl.BlockSpec((1, bs, n_kv), block)] * 2
-        args += [k_scale_l, v_scale_l]
+        in_specs += [pl.BlockSpec((None, 1, bs, n_kv), block)] * 2
+        args += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # block_tables + idx steer the index maps
+        num_scalar_prefetch=3,  # block_tables + idx + layer steer the index maps
         grid=(b, mb),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, s, nh * hd), row),
@@ -294,41 +322,43 @@ def _paged_attention_pallas(q, k_pages_l, v_pages_l, block_tables, idx,
     )(
         jnp.asarray(block_tables, jnp.int32),
         jnp.asarray(idx, jnp.int32).reshape(b),
+        jnp.asarray(layer, jnp.int32).reshape(1),
         *args,
     )
     return out.reshape(b, s, nh, hd)
 
 
-def _paged_attention_pallas_sharded(q, k_pages_l, v_pages_l, block_tables, idx,
-                                    k_scale_l, v_scale_l, *, interpret):
+def _paged_attention_pallas_sharded(q, k_pool, v_pool, layer, block_tables, idx,
+                                    k_scale, v_scale, *, interpret):
     """The kernel under the active mesh: GSPMD treats a Mosaic call as
-    opaque, so with the pool's kv heads sharded over the head axis
-    (``parallel.sharding.paged_kv_sharding``) the call must run under
-    ``shard_map`` with the heads partitioned explicitly — each device
-    walks the block table over its own heads' slice of the pool; a bare
-    call on a sharded mesh is refused at lowering ("Mosaic kernels cannot
-    be automatically partitioned"). The mesh is the one the engine (or
-    ``prepare``) set on the attention context; heads the axis does not
-    divide stay replicated, like the pool."""
+    opaque, so with the pool's folded kv-head lanes sharded over the head
+    axis (``parallel.sharding.paged_kv_sharding`` — whole heads per shard)
+    the call must run under ``shard_map`` with the heads partitioned
+    explicitly — each device walks the block table over its own heads'
+    lanes of the pool; a bare call on a sharded mesh is refused at lowering
+    ("Mosaic kernels cannot be automatically partitioned"). The mesh is
+    the one the engine (or ``prepare``) set on the attention context;
+    heads the axis does not divide stay replicated, like the pool."""
     from .attention import get_attention_context
 
     ctx = get_attention_context()
     kernel = functools.partial(_paged_attention_pallas, interpret=interpret)
     extent = 1 if ctx.mesh is None else dict(ctx.mesh.shape).get(ctx.head_axis, 1)
-    if extent == 1 or q.shape[2] % extent or k_pages_l.shape[2] % extent:
-        return kernel(q, k_pages_l, v_pages_l, block_tables, idx, k_scale_l, v_scale_l)
+    if extent == 1 or q.shape[2] % extent or _kv_heads(q, k_pool) % extent:
+        return kernel(q, k_pool, v_pool, layer, block_tables, idx, k_scale, v_scale)
     heads = P(None, None, ctx.head_axis, None)
+    lanes = P(None, None, None, ctx.head_axis)   # pool lanes and scale heads alike
     operands = [
-        q, k_pages_l, v_pages_l,
+        q, k_pool, v_pool, layer,
         jnp.asarray(block_tables, jnp.int32), jnp.asarray(idx, jnp.int32),
     ]
-    in_specs = [heads, heads, heads, P(), P()]
-    if k_scale_l is not None:
-        operands += [k_scale_l, v_scale_l]
-        in_specs += [P(None, None, ctx.head_axis)] * 2
+    in_specs = [heads, lanes, lanes, P(), P(), P()]
+    if k_scale is not None:
+        operands += [k_scale, v_scale]
+        in_specs += [lanes, lanes]
 
-    def per_shard(q_, k_, v_, bt_, idx_, *scales):
-        return kernel(q_, k_, v_, bt_, idx_, *(scales or (None, None)))
+    def per_shard(q_, k_, v_, layer_, bt_, idx_, *scales):
+        return kernel(q_, k_, v_, layer_, bt_, idx_, *(scales or (None, None)))
 
     return jax.shard_map(
         per_shard, mesh=ctx.mesh, in_specs=tuple(in_specs), out_specs=heads,

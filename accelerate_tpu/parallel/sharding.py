@@ -215,23 +215,28 @@ def shard_params(params: Any, shardings: Any):
 
 def paged_kv_sharding(mesh: Mesh, num_kv_heads: int) -> NamedSharding:
     """Sharding for the serving engine's block-paged KV pools
-    (``[layers, num_blocks, block_size, n_kv, head_dim]``): the kv-head dim
-    over ``tp`` — K/V are *produced* tp-sharded by the wk/wv projections
-    (see ``LLAMA_PARTITION_RULES``), so storing the pool the same way keeps
-    the block scatter/gather collective-free. Falls back to replicated when
-    ``tp`` doesn't divide the head count (GQA models with few kv heads)."""
-    tp = mesh.shape["tp"]
-    if tp > 1 and num_kv_heads % tp == 0:
-        return NamedSharding(mesh, P(None, None, None, "tp", None))
-    return NamedSharding(mesh, P())
+    (``[layers, num_blocks, block_size, n_kv*head_dim]`` — heads folded
+    into the lane dimension, head ``n`` at lanes ``[n*hd, (n+1)*hd)``): the
+    folded dimension over ``tp``, which is whole kv heads per shard when
+    ``tp`` divides ``num_kv_heads`` — K/V are *produced* tp-sharded by the
+    wk/wv projections (see ``LLAMA_PARTITION_RULES``), so storing the pool
+    the same way keeps the block scatter/gather collective-free. Falls
+    back to replicated when ``tp`` doesn't divide the head count (GQA
+    models with few kv heads)."""
+    return _paged_heads_sharding(mesh, num_kv_heads)
 
 
 def paged_kv_scale_sharding(mesh: Mesh, num_kv_heads: int) -> NamedSharding:
     """Sharding for the quantized pool's amax scale arrays
     (``[layers, num_blocks, block_size, n_kv]``): the kv-head dim follows
     :func:`paged_kv_sharding` exactly — a scale row must live with the
-    payload rows it dequantizes, or every fused-attention block read
+    payload lanes it dequantizes, or every fused-attention block read
     becomes a collective."""
+    return _paged_heads_sharding(mesh, num_kv_heads)
+
+
+def _paged_heads_sharding(mesh: Mesh, num_kv_heads: int) -> NamedSharding:
+    # pool lanes and scale heads are both the last of four dimensions
     tp = mesh.shape["tp"]
     if tp > 1 and num_kv_heads % tp == 0:
         return NamedSharding(mesh, P(None, None, None, "tp"))

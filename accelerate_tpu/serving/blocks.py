@@ -1,7 +1,7 @@
 """KV block pool accounting for the serving engine.
 
-The device-side cache is one pool of fixed-size blocks per layer
-(``[num_blocks, block_size, n_kv, hd]``); this module owns the *host-side*
+The device-side cache is one stacked pool of fixed-size blocks
+(``[layers, num_blocks, block_size, n_kv*hd]``); this module owns the *host-side*
 bookkeeping: which pool blocks are free, which belong to which request.
 Pure Python, no JAX — the engine translates the per-request block lists
 into the dense ``[num_slots, max_blocks]`` block-table array the compiled

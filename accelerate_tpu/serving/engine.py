@@ -429,10 +429,13 @@ class InferenceEngine:
             else cfg.num_slots * self._mb + 1
         )
 
-        # device state: per-layer page pools in the kv_dtype policy's
+        # device state: the stacked page pools in the kv_dtype policy's
         # storage dtype ("auto" = the params' compute dtype, the PR 4
-        # behaviour; int8/fp8 add per-row amax scale arrays beside them)
+        # behaviour; int8/fp8 add per-row amax scale arrays beside them).
+        # Stored lane-folded — [layers, num_blocks, block_size, n_kv*hd] —
+        # the view the paged kernel reads, so no step program relayouts it
         n_kv = getattr(mcfg, "num_key_value_heads", None) or mcfg.num_attention_heads
+        self._kv_heads = n_kv
         embed = jax.tree.leaves(self._params)[0]
         dtype = embed.dtype if jnp.issubdtype(embed.dtype, jnp.floating) else jnp.float32
         if cfg.kv_dtype in (None, "auto"):
@@ -443,7 +446,7 @@ class InferenceEngine:
             store_dtype, quantized = kv_storage_dtype(cfg.kv_dtype)
         self._quantized = quantized
         self.kv_dtype = str(np.dtype(store_dtype))
-        shape = (mcfg.num_hidden_layers, num_blocks, cfg.block_size, n_kv, mcfg.head_dim)
+        shape = (mcfg.num_hidden_layers, num_blocks, cfg.block_size, n_kv * mcfg.head_dim)
         scale_shape = (mcfg.num_hidden_layers, num_blocks, cfg.block_size, n_kv)
         #: bytes one cached token costs across all layers (K + V payload
         #: plus the f32 scales when quantized) — the decode-bandwidth and
@@ -460,7 +463,7 @@ class InferenceEngine:
         self.kv_slot_capacity = (num_blocks - 1) // cfg.blocks_per_slot
         self.hbm_preflight: dict | None = None
         if cfg.hbm_budget_gb is not None:
-            self._hbm_preflight(inner, shape, store_dtype, mesh)
+            self._hbm_preflight(inner, shape, n_kv, store_dtype, mesh)
 
         self.allocator = BlockAllocator(num_blocks)
         self.radix = (
@@ -633,8 +636,9 @@ class InferenceEngine:
             "prefill", self._build_prefill_fn()
         )
         # block-granular pool edits for CoW copies and swap restores:
-        # donated so XLA aliases the pool buffer instead of copying the
-        # whole pool per block. These are *separate* tiny executables —
+        # donated, and the pool is the in-place operand of one scatter, so
+        # XLA aliases the pool buffer instead of copying the whole pool
+        # per block. These are *separate* tiny executables —
         # the one-compiled-DECODE-executable contract is about
         # ``_decode_fn``, whose trace counter they never touch. Block ids
         # ride as traced int32 scalars so every block reuses one compile.
@@ -698,13 +702,13 @@ class InferenceEngine:
             self._params, mesh, FullyShardedDataParallelPlugin(), rules
         )
         self._params = shard_params(self._params, shardings)
-        pool_sharding = paged_kv_sharding(mesh, self._kp.shape[3])
+        pool_sharding = paged_kv_sharding(mesh, self._kv_heads)
         self._kp = jax.device_put(self._kp, pool_sharding)
         self._vp = jax.device_put(self._vp, pool_sharding)
         if self._ks is not None:
             from ..parallel.sharding import paged_kv_scale_sharding
 
-            scale_sharding = paged_kv_scale_sharding(mesh, self._ks.shape[3])
+            scale_sharding = paged_kv_scale_sharding(mesh, self._kv_heads)
             self._ks = jax.device_put(self._ks, scale_sharding)
             self._vs = jax.device_put(self._vs, scale_sharding)
         # scheduler-adjacent scalars must live on the SAME device set as the
@@ -739,7 +743,7 @@ class InferenceEngine:
             self._lanes_idle = lanes
         return self._lanes_idle
 
-    def _hbm_preflight(self, inner, pool_shape, pool_dtype, mesh) -> None:
+    def _hbm_preflight(self, inner, pool_shape, num_kv_heads, pool_dtype, mesh) -> None:
         """shard-check's SP004 at the serving seam: predicted per-device
         bytes of params (under the placement ``_place_on_mesh`` would pick)
         plus both paged pools — plus, with speculation armed, the
@@ -753,6 +757,7 @@ class InferenceEngine:
             getattr(inner, "partition_rules", None),
             mesh,
             pool_shape,
+            num_kv_heads,
             pool_dtype,
             self.config.hbm_budget_gb,
             swap_gb=self.config.swap_gb or None,
@@ -787,15 +792,21 @@ class InferenceEngine:
 
         return dispatch
 
-    def compiled_text(self, program: str) -> str:
-        """Optimised HLO of the ``"decode"`` or ``"prefill"`` executable,
-        compiled for the operands of its first dispatch — how
-        ``chip_smoke.py`` checks that the Pallas kernels were reached
-        (``tpu_custom_call``) and on which per-device shapes. This compiles
-        the program again: a read where a persistent compile cache is
+    def compiled(self, program: str):
+        """The ``"decode"`` or ``"prefill"`` executable, compiled for the
+        operands of its first dispatch: ``as_text()`` is its optimised
+        HLO, ``memory_analysis()`` its temporaries. This compiles the
+        program again: a read where a persistent compile cache is
         configured, a full compile otherwise."""
         jitted, operands = self._dispatched[program]
-        return jitted.lower(*operands).compile().as_text()
+        return jitted.lower(*operands).compile()
+
+    def compiled_text(self, program: str) -> str:
+        """Optimised HLO of the ``"decode"`` or ``"prefill"`` executable —
+        how ``chip_smoke.py`` checks that the Pallas kernels were reached
+        (``tpu_custom_call``), on which per-device shapes, and that the KV
+        pool stays where it is (:func:`accelerate_tpu.utils.hlo.buffers_moved`)."""
+        return self.compiled(program).as_text()
 
     def scope_table(self, program: str) -> dict:
         """``{instruction name: (result shape, scope stack)}`` of the
@@ -940,16 +951,18 @@ class InferenceEngine:
 
         1. **draft scan**: ``k`` greedy steps of the early-exit draft (the
            target's first ``draft_layers`` layers), autoregressing through
-           a sliced view of the target pool's first layers — identical
-           weights make its K/V a strict subset of the target's, so the
-           draft needs no cache of its own;
+           the target's own pool, in place — the paged step addresses the
+           pool by layer, so the draft writes layers ``0..draft_layers-1``
+           of the one donated buffer and no slice of it is ever made;
+           identical weights make its K/V a strict subset of the target's,
+           so the draft needs no cache of its own;
         2. **one verify forward** of static shape ``[num_slots, k+1]`` over
            ``[pending, d_1 .. d_k]`` through the fused paged-attention
            kernel (quantize-on-scatter + in-register dequant ride along at
            every ``kv_dtype``). The verify re-scatters ALL layers at the
-           round's positions — including the draft layers, which makes the
-           draft scan's own pool writes disposable (they are discarded, not
-           written back);
+           round's positions — including the draft layers, so the rows
+           the draft scan left in the pool are overwritten, from the same
+           tokens and weights, before anything but the draft reads them;
         3. **greedy acceptance** via the shared
            :func:`~accelerate_tpu.generation.spec_accept_tokens` helper —
            the single source of acceptance semantics with ``generate()``.
@@ -966,7 +979,6 @@ class InferenceEngine:
 
         apply_fn, cfg = self._apply_fn, self.config
         draft_apply = self._draft_apply
-        dl = self._spec.layers
         k = cfg.spec_k
         quantized = self._quantized
 
@@ -994,17 +1006,13 @@ class InferenceEngine:
                     nxt[:, None], pos + 1,
                 ), nxt
 
-            # the draft autoregresses through a sliced copy of the target
-            # pool's first dl layers; its writes only feed its OWN next
-            # steps — the verify below regenerates those rows from the same
-            # tokens/weights, so the scan carry is dropped, not merged back
-            d0 = (
-                kp[:dl], vp[:dl],
-                ks[:dl] if quantized else None,
-                vs[:dl] if quantized else None,
-                toks, pos0,
+            # the draft autoregresses through the pool itself (its own
+            # layers, by index): its rows only feed its OWN next steps —
+            # the verify below writes the same positions of every layer
+            # again, from the same tokens/weights, under the same mask
+            (kp, vp, ks, vs, _, _), d = jax.lax.scan(
+                dstep, (kp, vp, ks, vs, toks, pos0), None, length=k
             )
-            _, d = jax.lax.scan(dstep, d0, None, length=k)
             d = d.T  # [num_slots, k] draft proposals
 
             # ONE verify forward over [pending, d_1 .. d_k]: scatters k+1
@@ -1071,7 +1079,6 @@ class InferenceEngine:
 
         apply_fn, cfg = self._apply_fn, self.config
         draft_apply = self._draft_apply
-        dl = self._spec.layers
         k = cfg.spec_k
         quantized = self._quantized
         eos_id = cfg.eos_token_id
@@ -1111,13 +1118,10 @@ class InferenceEngine:
                     nxt[:, None], pos + 1, gtrans[row, dfa, nxt],
                 ), (nxt, jnp.exp(logq))
 
-            d0 = (
-                kp[:dl], vp[:dl],
-                ks[:dl] if quantized else None,
-                vs[:dl] if quantized else None,
-                toks, pos0, lanes["dfa_state"],
+            (kp, vp, ks, vs, _, _, _), (d, q) = jax.lax.scan(
+                dstep, (kp, vp, ks, vs, toks, pos0, lanes["dfa_state"]),
+                jnp.arange(k),
             )
-            _, (d, q) = jax.lax.scan(dstep, d0, jnp.arange(k))
             d = d.T  # [num_slots, k] draft proposals; q: [k, slots, vocab]
 
             chunk = jnp.concatenate([toks, d], axis=1)  # [num_slots, k+1]
@@ -2015,20 +2019,21 @@ class InferenceEngine:
             # device_get), padded with null-block zero rows
             n = len(req.swap_plan)
             m = 1 << max(0, (n - 1).bit_length())
-            layers, _, bs, kv, hd = self._kp.shape
+            layers, _, bs, width = self._kp.shape
             dtype = np.dtype(self._kp.dtype)
             ids = np.full((m,), NULL_BLOCK, np.int32)
-            k_rows = np.zeros((layers, m, bs, kv, hd), dtype)
+            k_rows = np.zeros((layers, m, bs, width), dtype)
             v_rows = np.zeros_like(k_rows)
             ks_rows = vs_rows = None
             if self._quantized:
-                ks_rows = np.ones((layers, m, bs, kv), np.float32)
+                ks_rows = np.ones((layers, m, bs, self._kv_heads), np.float32)
                 vs_rows = np.ones_like(ks_rows)
             for j, (idx, handle) in enumerate(req.swap_plan):
                 ids[j] = req.blocks[idx]
                 k, v, ksc, vsc = self._swap.load(handle)
-                k_rows[:, j] = k
-                v_rows[:, j] = v
+                # the host mirror keeps heads apart; the pool folds them
+                k_rows[:, j] = k.reshape(layers, bs, width)
+                v_rows[:, j] = v.reshape(layers, bs, width)
                 if self._quantized:
                     ks_rows[:, j] = ksc
                     vs_rows[:, j] = vsc
@@ -2124,7 +2129,7 @@ class InferenceEngine:
             if self._quantized:
                 gathers += [self._ks[:, idx], self._vs[:, idx]]
             rows = jax.device_get(tuple(gathers))
-            k_rows, v_rows = rows[0], rows[1]  # [layers, m, bs, kv, hd]
+            k_rows, v_rows = rows[0], rows[1]  # [layers, m, bs, kv*hd]
             ks_rows = vs_rows = None
             if self._quantized:
                 ks_rows, vs_rows = rows[2], rows[3]  # [layers, m, bs, kv]
@@ -2132,7 +2137,8 @@ class InferenceEngine:
                 plan.append((
                     i,
                     self._swap.store(
-                        k_rows[:, j], v_rows[:, j],
+                        k_rows[:, j].reshape(self._swap.block_shape),
+                        v_rows[:, j].reshape(self._swap.block_shape),
                         None if ks_rows is None else ks_rows[:, j],
                         None if vs_rows is None else vs_rows[:, j],
                     ),

@@ -141,3 +141,79 @@ def op_scopes(text: str) -> dict[str, tuple[str, str]]:
             if stack.count("/") >= deepest.get(computation, "").count("/"):
                 deepest[computation] = stack
     return table
+
+
+#: opcodes through which a buffer passes without a byte of it moving: the
+#: entry and loop parameters, tuple plumbing, and a reinterpretation
+_HLO_PLUMBING = frozenset({
+    "parameter", "get-tuple-element", "tuple", "while", "conditional", "call",
+    "bitcast", "optimization-barrier",
+})
+#: opcodes that write part of their first operand and hand the same buffer
+#: on (buffer assignment updates a loop-carried or donated operand in place)
+_HLO_IN_PLACE = frozenset({"scatter", "dynamic-update-slice"})
+_HLO_RESULT = re.compile(r"^\s+(ROOT )?%?([\w.-]+) = (.*?)\s([a-z][\w-]*)\(")
+_HLO_ALIASED_PARAM = re.compile(r"\}:\s*\((\d+),")
+_HLO_PARAM_NUMBER = re.compile(r"\bparameter\((\d+)\)")
+
+
+def buffers_moved(text: str, numels) -> dict:
+    """Does a compiled module leave its big buffers where they are?
+    ``numels`` are element counts to watch (the serving engine's KV pool,
+    one layer's slab of it). Returns ``{"moved": [(instruction, opcode,
+    result shape), ...], "unaliased": [entry parameter numbers]}``:
+
+    * ``moved`` — every instruction that PRODUCES an array of a watched
+      size other than by passing it on (parameters, tuple plumbing,
+      bitcasts) or by updating it in place (``scatter`` /
+      ``dynamic-update-slice``, bare or as the root of a fusion): a
+      ``copy``, a ``dynamic-slice`` of a slab, a ``reshape`` or
+      ``transpose`` that was materialised, an async ``copy-start``;
+    * ``unaliased`` — entry parameters of a watched size that the module
+      header's ``input_output_alias`` does not map onto an output, i.e.
+      donated buffers the program could not reuse.
+
+    Both empty is the compiled-text form of "the pool never moves"."""
+    watched = {int(n) for n in numels}
+    roots: dict[str, str] = {}  # computation -> opcode of its ROOT
+    moved: list[tuple[str, str, str]] = []
+    fusions: list[tuple[str, str, str]] = []  # (name, shape, callee)
+    entry_params: list[int] = []
+    computation, in_entry = "", False
+    lines = text.splitlines()
+    header = next((l for l in lines if l.startswith("HloModule")), "")
+    alias = header.partition("input_output_alias={")[2].partition("entry_computation_layout")[0]
+    aliased = {int(n) for n in _HLO_ALIASED_PARAM.findall(alias)}
+    for line in lines:
+        head = _HLO_COMPUTATION.match(line)
+        if head:
+            computation, in_entry = head.group(1), line.startswith("ENTRY")
+            continue
+        m = _HLO_RESULT.match(line)
+        if not m:
+            continue
+        is_root, name, result, opcode = m.groups()
+        if is_root:
+            roots[computation] = opcode
+        hit = [
+            f"{t.group(1)}[{t.group(2)}]" for t in _HLO_SHAPE.finditer(result)
+            if _numel(t.group(2), ",") in watched
+        ]
+        if not hit or opcode in _HLO_PLUMBING:
+            if hit and opcode == "parameter" and in_entry:
+                entry_params.append(int(_HLO_PARAM_NUMBER.search(line).group(1)))
+            continue
+        if opcode in _HLO_IN_PLACE:
+            continue
+        callee = _HLO_CALLS.search(line) if opcode == "fusion" else None
+        if callee:
+            fusions.append((name, hit[0], callee.group(1)))
+        else:
+            moved.append((name, opcode, hit[0]))
+    for name, shape, callee in fusions:  # a callee is printed before its caller
+        if roots.get(callee) not in _HLO_IN_PLACE:
+            moved.append((name, f"fusion:{roots.get(callee)}", shape))
+    return {
+        "moved": moved,
+        "unaliased": sorted(p for p in entry_params if p not in aliased),
+    }

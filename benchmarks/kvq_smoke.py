@@ -70,15 +70,15 @@ def _paged_attn_ratio() -> dict:
     nb = b * mb + 1
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.normal(size=(b, 1, nh, hd)).astype(np.float32))
-    kp = jnp.asarray(rng.normal(size=(nb, bs, n_kv, hd)).astype(np.float32))
-    vp = jnp.asarray(rng.normal(size=(nb, bs, n_kv, hd)).astype(np.float32))
+    kp = jnp.asarray(rng.normal(size=(1, nb, bs, n_kv * hd)).astype(np.float32))
+    vp = jnp.asarray(rng.normal(size=(1, nb, bs, n_kv * hd)).astype(np.float32))
     bt = np.arange(1, nb, dtype=np.int32).reshape(b, mb)
     idx = np.full((b,), mb * bs - 1, np.int32)
 
     legs = {}
     for impl in ("lax", "gather"):
         fn = jax.jit(lambda q, kp, vp, impl=impl: paged_attention(
-            q, kp, vp, bt, idx, impl=impl
+            q, kp, vp, 0, bt, idx, impl=impl
         ))
         fn(q, kp, vp).block_until_ready()  # compile + warm outside the timer
         legs[impl] = min(
@@ -173,24 +173,24 @@ def run(platform: str) -> dict:
 
     rng = np.random.default_rng(1)
     nb_, bs_, n_kv_, hd_ = 6, 8, 4, 16
-    kp = jnp.zeros((nb_, bs_, n_kv_, hd_), jnp.int8)
+    kp = jnp.zeros((1, nb_, bs_, n_kv_ * hd_), jnp.int8)
     vp = jnp.zeros_like(kp)
-    ks = jnp.ones((nb_, bs_, n_kv_), jnp.float32)
+    ks = jnp.ones((1, nb_, bs_, n_kv_), jnp.float32)
     vs = jnp.ones_like(ks)
     bt = np.asarray([[1, 2, 3, 4, 5]], np.int32)
     for p in range(30):
         k = jnp.asarray(rng.normal(size=(1, 1, n_kv_, hd_)).astype(np.float32))
         v = jnp.asarray(rng.normal(size=(1, 1, n_kv_, hd_)).astype(np.float32))
         kp, vp, ks, vs = write_paged_kv(
-            kp, vp, k, v, bt, np.asarray([[p]], np.int32),
-            k_scale_l=ks, v_scale_l=vs,
+            kp, vp, 0, k, v, bt, np.asarray([[p]], np.int32),
+            k_scale=ks, v_scale=vs,
         )
     q = jnp.asarray(rng.normal(size=(1, 1, 8, hd_)).astype(np.float32))
     idx = np.asarray([29], np.int32)
-    fused = np.asarray(paged_attention(q, kp, vp, bt, idx, k_scale_l=ks,
-                                       v_scale_l=vs, impl="lax"))
-    gathered = np.asarray(paged_attention(q, kp, vp, bt, idx, k_scale_l=ks,
-                                          v_scale_l=vs, impl="gather"))
+    fused = np.asarray(paged_attention(q, kp, vp, 0, bt, idx, k_scale=ks,
+                                       v_scale=vs, impl="lax"))
+    gathered = np.asarray(paged_attention(q, kp, vp, 0, bt, idx, k_scale=ks,
+                                          v_scale=vs, impl="gather"))
     agree = float(np.abs(fused - gathered).max())
     assert agree < 1e-4, f"fused and gather diverged on the same bytes: {agree}"
 

@@ -320,7 +320,9 @@ def main() -> int:
                 f"[engine-check] {row['engine']}: paged route: {row['paged_route']}; "
                 f"Mosaic calls decode {row['decode_mosaic_calls']} prefill "
                 f"{row['prefill_mosaic_calls']}; kernel pool operand per device "
-                f"{row['kernel_pool_shape']}; greedy tokens equal to generate(): "
+                f"{row['kernel_pool_shape']}; pool-sized instructions besides the "
+                f"in-place scatters: {row['pool_moved'] or 'none'} (scale arrays: "
+                f"{row['scales_moved'] or 'none'}); greedy tokens equal to generate(): "
                 f"{row['agree']}/{row['compared']} (reported, not gated)",
                 flush=True,
             )
@@ -388,8 +390,9 @@ def _kernel_row(check: str, got, want, bound: float, relative: bool = False) -> 
 
 
 def _paged_case(rng, b, s, nh, hd, bs, mb, store):
-    """Pools written through real block tables (quantize-on-scatter for
-    int8/fp8), rows at different depths, the last query at each row's end."""
+    """Two-layer stacked pools whose layer 1 is written through real block
+    tables (quantize-on-scatter for int8/fp8), rows at different depths,
+    the last query at each row's end."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -401,16 +404,16 @@ def _paged_case(rng, b, s, nh, hd, bs, mb, store):
     tables = (1 + np.arange(b * mb, dtype=np.int32)).reshape(b, mb)
     depth = rng.integers(s, mb * bs + 1, size=b).astype(np.int32)
     depth[0] = mb * bs  # one row fills its whole table
-    pools = [jnp.zeros((nb, bs, nh, hd), dtype)] * 2
-    scales = [jnp.ones((nb, bs, nh), jnp.float32)] * 2 if quantized else []
+    pools = [jnp.zeros((2, nb, bs, nh * hd), dtype)] * 2
+    scales = [jnp.ones((2, nb, bs, nh), jnp.float32)] * 2 if quantized else []
     k, v = (
         jnp.asarray(rng.normal(size=(b, mb * bs, nh, hd)), jnp.bfloat16)
         for _ in range(2)
     )
     positions = np.broadcast_to(np.arange(mb * bs, dtype=np.int32), (b, mb * bs))
     written = write_paged_kv(
-        *pools, k, v, tables, positions, write_mask=positions < depth[:, None],
-        **(dict(k_scale_l=scales[0], v_scale_l=scales[1]) if quantized else {}),
+        *pools, 1, k, v, tables, positions, write_mask=positions < depth[:, None],
+        **(dict(k_scale=scales[0], v_scale=scales[1]) if quantized else {}),
     )
     q = jnp.asarray(rng.normal(size=(b, s, nh, hd)), jnp.bfloat16)
     return q, written[:2], tables, depth - s, written[2:]
@@ -437,7 +440,7 @@ def _kernels() -> None:
             outs = {
                 impl: jax.jit(
                     lambda q, kp, vp, *sc, impl=impl: paged_attention(
-                        q, kp, vp, tables, idx, *sc, impl=impl)
+                        q, kp, vp, 1, tables, idx, *sc, impl=impl)
                 )(q, *pools, *scales)
                 for impl in ("pallas", "gather")
             }
@@ -573,13 +576,15 @@ def _train() -> None:
 def _engine_check() -> None:
     """The engines `serve` built, built again the same way, to read what
     the serving process cannot hand over a pipe: the compiled text of the
-    decode and prefill executables (Mosaic calls, and the per-device shape
-    of the pool the kernel reads), and greedy agreement with generate()."""
+    decode and prefill executables (Mosaic calls, the per-device shape of
+    the pool the kernel reads, and whether the pool stays where it is:
+    ``utils.hlo.buffers_moved``), and greedy agreement with generate()."""
     import jax
     import numpy as np
 
     from accelerate_tpu.commands import serve
     from accelerate_tpu.generation import generate
+    from accelerate_tpu.utils.hlo import buffers_moved
 
     cli = argparse.ArgumentParser()
     serve.add_parser(cli.add_subparsers())
@@ -601,11 +606,25 @@ def _engine_check() -> None:
         texts = {p: engine.compiled_text(p) for p in ("decode", "prefill")}
         calls = {p: t.count('custom_call_target="tpu_custom_call"') for p, t in texts.items()}
         # the pool operand of the kernel, as the compiled program holds it on
-        # one device: [num_blocks, block, kv_heads_on_this_device * head_dim]
+        # one device — the stacked pool itself, not a layer's slab:
+        # [layers, num_blocks, block, kv_heads_on_this_device * head_dim]
         kernel_lines = [l for l in texts["decode"].splitlines() if "tpu_custom_call" in l]
         pool = kernel_lines and re.search(
-            r"operand_layout_constraints=\{.*?(\w+\[\d+,16,\d+\])", kernel_lines[0])
+            r"operand_layout_constraints=\{.*?(\w+\[\d+,\d+,16,\d+\])", kernel_lines[0])
         route = engine.stats()["paged_attention_impl"]
+        # on one device: the K/V pool or one layer's slab of it must be no
+        # instruction's result but parameters, plumbing and the row scatters
+        moved = {}
+        for kind, arr in (("pool", engine._kp), ("scales", engine._ks)):
+            if arr is None:
+                moved[kind] = []
+                continue
+            n = arr.addressable_shards[0].data.size
+            found = [buffers_moved(t, [n, n // engine._kp.shape[0]]) for t in texts.values()]
+            moved[kind] = sorted(
+                {f"{op} {shape}" for f in found for _, op, shape in f["moved"]}
+                | {f"parameter({i}) not aliased" for f in found for i in f["unaliased"]}
+            )
         if reference is None:  # generate() on the same seeded weights
             model = serve._build_model(args)
             reference = np.asarray(generate(
@@ -617,13 +636,15 @@ def _engine_check() -> None:
             "decode_mosaic_calls": calls["decode"],
             "prefill_mosaic_calls": calls["prefill"],
             "kernel_pool_shape": pool.group(1) if pool else None,
+            "pool_moved": moved["pool"], "scales_moved": moved["scales"],
             "agree": int((got == reference).sum()), "compared": int(got.size),
         }), flush=True)
-        if route == "pallas" and min(calls.values()) < 1:
+        if (route == "pallas" and min(calls.values()) < 1) or moved["pool"]:
             ok = False
         del engine
     if not ok:
-        sys.exit("paged route is pallas but an executable holds no Mosaic custom call")
+        sys.exit("paged route is pallas but an executable holds no Mosaic custom "
+                 "call, or the KV pool moves inside a step program")
 
 
 _CHILDREN = {

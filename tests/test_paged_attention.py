@@ -30,68 +30,144 @@ from accelerate_tpu.ops.paged_attention import paged_attention
 KV_ATOL = {"int8": 0.05, "fp8": 0.12}
 
 
+#: the stacked pools of these tests hold three layers; the layer under
+#: test is addressed by index and the others are filled with noise, so a
+#: read or a write that strays into another layer's rows changes a result
+LAYERS = 3
+
+
+def _noise_pool(rng, shape, dtype):
+    if jnp.issubdtype(dtype, jnp.integer):
+        return jnp.asarray(rng.integers(-100, 100, size=shape), dtype)
+    return jnp.asarray(rng.normal(size=shape) * 4.0, jnp.float32).astype(dtype)
+
+
 def _filled_pools(rng, *, b=3, n_kv=4, hd=16, bs=4, nb=12, mb=5, idx=(9, 6, 14),
-                  dtype=None):
-    """Pools written position-by-position through real block tables: the
-    f32 pools are ground truth; quantized pools (dtype given) are written
-    through the same scatter with scale arrays."""
+                  dtype=None, layer=0):
+    """Stacked pools (``[LAYERS, nb, bs, n_kv*hd]``) whose layer ``layer``
+    is written position-by-position through real block tables: the f32
+    pools are ground truth; quantized pools (dtype given) are written
+    through the same scatter with scale arrays. Every other layer holds
+    noise, and must come out of the writes as it went in."""
     bt = np.zeros((b, mb), np.int32)
     used = iter(range(1, nb))
     for i, ix in enumerate(idx):
         for j in range((ix // bs) + 1):
             bt[i, j] = next(used)
     idx = np.asarray(idx, np.int32)
-    kpf = jnp.zeros((nb, bs, n_kv, hd), jnp.float32)
-    vpf = jnp.zeros_like(kpf)
+    shape = (LAYERS, nb, bs, n_kv * hd)
+    kpf = _noise_pool(rng, shape, jnp.float32).at[layer].set(0.0)
+    vpf = _noise_pool(rng, shape, jnp.float32).at[layer].set(0.0)
+    before = [np.asarray(kpf), np.asarray(vpf)]
     q_pools = None
     if dtype is not None:
-        kp = jnp.zeros((nb, bs, n_kv, hd), dtype)
-        vp = jnp.zeros_like(kp)
-        ks = jnp.ones((nb, bs, n_kv), jnp.float32)
-        vs = jnp.ones_like(ks)
+        kp = _noise_pool(rng, shape, dtype).at[layer].set(0)
+        vp = _noise_pool(rng, shape, dtype).at[layer].set(0)
+        ks = jnp.asarray(rng.random(shape[:-1] + (n_kv,)) + 0.5, jnp.float32)
+        ks = ks.at[layer].set(1.0)
+        vs = ks + 0.25
+        vs = vs.at[layer].set(1.0)
         q_pools = (kp, vp, ks, vs)
+        before += [np.asarray(x) for x in q_pools]
     for p in range(int(idx.max()) + 1):
         k = jnp.asarray(rng.normal(size=(b, 1, n_kv, hd)).astype(np.float32))
         v = jnp.asarray(rng.normal(size=(b, 1, n_kv, hd)).astype(np.float32))
         mask = np.asarray([[p <= ix] for ix in idx])
         pos = np.full((b, 1), p, np.int32)
-        kpf, vpf = write_paged_kv(kpf, vpf, k, v, bt, pos, write_mask=mask)
+        kpf, vpf = write_paged_kv(kpf, vpf, layer, k, v, bt, pos, write_mask=mask)
         if q_pools is not None:
             q_pools = write_paged_kv(
-                *q_pools[:2], k, v, bt, pos, write_mask=mask,
-                k_scale_l=q_pools[2], v_scale_l=q_pools[3],
+                *q_pools[:2], layer, k, v, bt, pos, write_mask=mask,
+                k_scale=q_pools[2], v_scale=q_pools[3],
             )
+    others = [i for i in range(LAYERS) if i != layer]
+    for was, now in zip(before, (kpf, vpf, *(q_pools or ()))):
+        np.testing.assert_array_equal(np.asarray(now)[others], was[others])
     return bt, idx, (kpf, vpf), q_pools
 
 
-def test_fused_lax_matches_gather_reference():
+def _dense_reference(q, kpf, vpf, layer, bt, qi, n_kv):
+    """The span of layer ``layer`` gathered in numpy (no code of the paged
+    routes), through :func:`cached_attention`."""
+    kh, vh = np.asarray(kpf)[layer], np.asarray(vpf)[layer]
+    nb, bs, width = kh.shape
+    b, mb = bt.shape
+    span = lambda pool: pool[bt].reshape(b, mb * bs, n_kv, width // n_kv)
+    return cached_attention(q, jnp.asarray(span(kh)), jnp.asarray(span(vh)), qi)
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+def test_fused_lax_matches_gather_reference(layer):
     """The scan-over-blocks online softmax equals the PR 4
     gather-then-``cached_attention`` path to f32 noise — decode (s=1) and
-    prefill-chunk (s>1) query shapes, GQA heads."""
+    prefill-chunk (s>1) query shapes, GQA heads — on the stacked pool at
+    the layer it is asked for, and the gather route equals a span taken
+    out of that layer by hand."""
     rng = np.random.default_rng(0)
-    bt, idx, (kpf, vpf), _ = _filled_pools(rng)
+    bt, idx, (kpf, vpf), _ = _filled_pools(rng, layer=layer)
     for s, offs in ((1, 0), (4, 3)):
         q = jnp.asarray(rng.normal(size=(3, s, 8, 16)).astype(np.float32))
         qi = np.maximum(idx - offs, 0)
-        ref = paged_attention(q, kpf, vpf, bt, qi, impl="gather")
-        fused = paged_attention(q, kpf, vpf, bt, qi, impl="lax")
+        ref = paged_attention(q, kpf, vpf, layer, bt, qi, impl="gather")
+        fused = paged_attention(q, kpf, vpf, layer, bt, qi, impl="lax")
         np.testing.assert_allclose(np.asarray(fused), np.asarray(ref),
                                    rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(
+            np.asarray(ref), np.asarray(_dense_reference(q, kpf, vpf, layer, bt, qi, 4)),
+            rtol=1e-6, atol=1e-6,
+        )
 
 
-def test_pallas_kernel_matches_gather_reference():
+@pytest.mark.parametrize("layer", [0, 1])
+def test_pallas_kernel_matches_gather_reference(layer):
     """The Pallas block-table kernel (in the Pallas interpreter off-TPU)
     computes the same attention as the gather reference — decode and
-    prefill-chunk query shapes, GQA heads."""
+    prefill-chunk query shapes, GQA heads — reading layer ``layer`` of the
+    stacked pool through its index map."""
     rng = np.random.default_rng(1)
-    bt, idx, (kpf, vpf), _ = _filled_pools(rng)
+    bt, idx, (kpf, vpf), _ = _filled_pools(rng, layer=layer)
     for s, offs in ((1, 0), (4, 3)):
         q = jnp.asarray(rng.normal(size=(3, s, 8, 16)).astype(np.float32))
         qi = np.maximum(idx - offs, 0)
-        ref = paged_attention(q, kpf, vpf, bt, qi, impl="gather")
-        out = paged_attention(q, kpf, vpf, bt, qi, impl="pallas", interpret=True)
+        ref = paged_attention(q, kpf, vpf, layer, bt, qi, impl="gather")
+        out = paged_attention(q, kpf, vpf, layer, bt, qi, impl="pallas", interpret=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("name", [None, "int8"])
+def test_three_routes_agree_on_a_traced_layer_of_the_stacked_pool(name):
+    """Pallas-interpret against ``lax`` against ``gather`` with the layer
+    index a traced scalar, as the model's layer loop hands it over: one
+    compiled function serves every layer, and each layer's answer is its
+    own (the pool's layers hold different rows)."""
+    import jax
+
+    dtype = None if name is None else kv_storage_dtype(name)[0]
+    rng = np.random.default_rng(7)
+    bt, idx, pools, q_pools = _filled_pools(rng, dtype=dtype, layer=1)
+    pools = q_pools or pools
+    q = jnp.asarray(rng.normal(size=(3, 2, 8, 16)).astype(np.float32))
+    qi = np.maximum(idx - 1, 0)
+
+    def route(impl):
+        return jax.jit(lambda layer: paged_attention(
+            q, pools[0], pools[1], layer, bt, qi, *pools[2:], impl=impl,
+            interpret=True,
+        ))
+
+    outs = {impl: route(impl) for impl in ("gather", "lax", "pallas")}
+    per_layer = []
+    for layer in range(LAYERS):
+        got = {impl: np.asarray(fn(jnp.int32(layer))) for impl, fn in outs.items()}
+        np.testing.assert_allclose(got["lax"], got["gather"], rtol=1e-4, atol=1e-4)
+        np.testing.assert_allclose(got["pallas"], got["gather"], rtol=1e-4, atol=1e-4)
+        per_layer.append(got["gather"])
+    if name is None:
+        hand = _dense_reference(q, *pools[:2], 1, bt, qi, 4)
+        np.testing.assert_allclose(per_layer[1], np.asarray(hand), rtol=1e-6, atol=1e-6)
+    assert np.abs(per_layer[0] - per_layer[1]).max() > 1e-2
+    assert np.abs(per_layer[2] - per_layer[1]).max() > 1e-2
 
 
 def test_pallas_kernel_runs_per_head_shard_under_a_tp_mesh():
@@ -109,21 +185,31 @@ def test_pallas_kernel_runs_per_head_shard_under_a_tp_mesh():
     mesh = build_mesh(MeshPlugin(dp=1, tp=4), devices=jax.devices()[:4])
     rng = np.random.default_rng(5)
     dtype, _ = kv_storage_dtype("int8")
-    bt, idx, (kpf, vpf), pools = _filled_pools(rng, dtype=dtype)
+    from accelerate_tpu.parallel.sharding import (
+        paged_kv_scale_sharding,
+        paged_kv_sharding,
+    )
+
+    bt, idx, (kpf, vpf), pools = _filled_pools(rng, dtype=dtype, layer=2)
     q = jnp.asarray(rng.normal(size=(3, 1, 8, 16)).astype(np.float32))
-    ref = paged_attention(q, *pools[:2], bt, idx, *pools[2:], impl="gather")
+    ref = paged_attention(q, *pools[:2], 2, bt, idx, *pools[2:], impl="gather")
     heads = NamedSharding(mesh, PartitionSpec(None, None, "tp", None))
-    scales = NamedSharding(mesh, PartitionSpec(None, None, "tp"))
-    placed = [jax.device_put(x, heads) for x in (q, *pools[:2])]
-    placed_scales = [jax.device_put(x, scales) for x in pools[2:]]
+    placed = [jax.device_put(q, heads)] + [
+        jax.device_put(x, paged_kv_sharding(mesh, 4)) for x in pools[:2]
+    ]
+    placed_scales = [
+        jax.device_put(x, paged_kv_scale_sharding(mesh, 4)) for x in pools[2:]
+    ]
+    # one kv head's lanes, whole, on each of the four devices
+    assert placed[1].addressable_shards[0].data.shape == (LAYERS, 12, 4, 16)
 
     @jax.jit
-    def run(q, kp, vp, ks, vs):
-        return paged_attention(q, kp, vp, bt, idx, ks, vs, impl="pallas",
+    def run(q, kp, vp, ks, vs, layer):
+        return paged_attention(q, kp, vp, layer, bt, idx, ks, vs, impl="pallas",
                                interpret=True)
 
     with attention_context(mesh=mesh):
-        out = run(*placed, *placed_scales)
+        out = run(*placed, *placed_scales, jnp.int32(2))
     assert out.sharding.spec == heads.spec
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
@@ -140,11 +226,11 @@ def test_quantized_pool_within_tolerance(name):
     rng = np.random.default_rng(2)
     bt, idx, (kpf, vpf), (kp, vp, ks, vs) = _filled_pools(rng, dtype=dtype)
     q = jnp.asarray(rng.normal(size=(3, 1, 8, 16)).astype(np.float32))
-    ref = np.asarray(paged_attention(q, kpf, vpf, bt, idx, impl="gather"))
+    ref = np.asarray(paged_attention(q, kpf, vpf, 0, bt, idx, impl="gather"))
     outs = {}
     for impl in ("lax", "gather", "pallas"):
         out = np.asarray(paged_attention(
-            q, kp, vp, bt, idx, k_scale_l=ks, v_scale_l=vs, impl=impl,
+            q, kp, vp, 0, bt, idx, k_scale=ks, v_scale=vs, impl=impl,
             interpret=True,
         ))
         assert np.abs(out - ref).max() < KV_ATOL[name], (
@@ -154,14 +240,15 @@ def test_quantized_pool_within_tolerance(name):
     np.testing.assert_allclose(outs["lax"], outs["gather"], rtol=1e-4, atol=1e-4)
 
 
-def test_quantized_write_respects_mask_and_drop():
+@pytest.mark.parametrize("layer", [0, 1])
+def test_quantized_write_respects_mask_and_drop(layer):
     """Masked lanes and out-of-range positions drop payload AND scale
     writes — the scale array can never disagree with the pool about which
-    rows are real."""
+    rows are real — and nothing lands in any layer but the one addressed."""
     nb, bs, n_kv, hd = 4, 4, 2, 8
-    kp = jnp.zeros((nb, bs, n_kv, hd), jnp.int8)
+    kp = jnp.zeros((LAYERS, nb, bs, n_kv * hd), jnp.int8)
     vp = jnp.zeros_like(kp)
-    ks = jnp.ones((nb, bs, n_kv), jnp.float32)
+    ks = jnp.ones((LAYERS, nb, bs, n_kv), jnp.float32)
     vs = jnp.ones_like(ks)
     bt = np.asarray([[1, 2]], np.int32)
     k = jnp.full((1, 2, n_kv, hd), 5.0)
@@ -169,18 +256,62 @@ def test_quantized_write_respects_mask_and_drop():
     # lane 0 real at position 1, lane 1 masked; then a position past the
     # table span (must drop, not clamp)
     kp, vp, ks, vs = write_paged_kv(
-        kp, vp, k, v, bt, np.asarray([[1, 2]], np.int32),
-        write_mask=np.asarray([[True, False]]), k_scale_l=ks, v_scale_l=vs,
+        kp, vp, layer, k, v, bt, np.asarray([[1, 2]], np.int32),
+        write_mask=np.asarray([[True, False]]), k_scale=ks, v_scale=vs,
     )
     kp, vp, ks, vs = write_paged_kv(
-        kp, vp, k, v, bt, np.asarray([[98, 99]], np.int32),
-        write_mask=np.asarray([[True, True]]), k_scale_l=ks, v_scale_l=vs,
+        kp, vp, layer, k, v, bt, np.asarray([[98, 99]], np.int32),
+        write_mask=np.asarray([[True, True]]), k_scale=ks, v_scale=vs,
     )
-    kp_h, ks_h = np.asarray(kp), np.asarray(ks)
+    kp_h, ks_h = np.asarray(kp)[layer], np.asarray(ks)[layer]
     assert kp_h[1, 1].any() and ks_h[1, 1, 0] != 1.0   # the real write landed
     assert not kp_h[1, 2].any() and ks_h[1, 2, 0] == 1.0  # masked lane dropped
     assert not kp_h[2].any() and (ks_h[2] == 1.0).all()   # past-span dropped
     assert not kp_h[0].any() and not kp_h[3].any()
+    others = [i for i in range(LAYERS) if i != layer]
+    for pool in (kp, vp):
+        assert not np.asarray(pool)[others].any()
+    for scale in (ks, vs):
+        assert (np.asarray(scale)[others] == 1.0).all()
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+def test_dropped_rows_never_land_in_the_next_layer(quantized):
+    """The drop rule is by ``(layer, block, offset)``: a masked lane and a
+    position past the table get block id ``num_blocks``, which as a
+    flattened row number (``(layer*nb + blk)*bs + off``) would be block 0
+    of the NEXT layer — in range there, so written. Writing layers 0 and 1
+    of three, under jit with the layer traced, leaves exactly the one real
+    row of each written layer and every other row of the pool as it was."""
+    import jax
+
+    nb, bs, n_kv, hd = 3, 4, 2, 8
+    dtype = jnp.int8 if quantized else jnp.float32
+    pools = [jnp.zeros((LAYERS, nb, bs, n_kv * hd), dtype)] * 2
+    if quantized:
+        pools += [jnp.ones((LAYERS, nb, bs, n_kv), jnp.float32)] * 2
+    bt = np.asarray([[1, 2], [0, 0]], np.int32)
+    k = jnp.full((2, 3, n_kv, hd), 3.0)
+    # row 0: position 2 real, position 3 masked, position 8 past the
+    # 2-block table; row 1: a free slot (table all null block), masked
+    pos = np.asarray([[2, 3, 8], [0, 1, 2]], np.int32)
+    mask = np.asarray([[True, False, True], [False, False, False]])
+
+    @jax.jit
+    def write(pools, layer):
+        scales = dict(zip(("k_scale", "v_scale"), pools[2:]))
+        return write_paged_kv(*pools[:2], layer, k, k, bt, pos, write_mask=mask, **scales)
+
+    for layer in (0, 1):
+        pools = list(write(pools, jnp.int32(layer)))
+    for pool in pools[:2]:
+        h = np.asarray(pool)
+        touched = np.argwhere(h.any(axis=-1))
+        np.testing.assert_array_equal(touched, [[0, 1, 2], [1, 1, 2]])
+    for scale in pools[2:]:
+        h = np.asarray(scale)
+        touched = np.argwhere((h != 1.0).any(axis=-1))
+        np.testing.assert_array_equal(touched, [[0, 1, 2], [1, 1, 2]])
 
 
 def test_quantize_round_trip_and_zero_rows():
@@ -239,7 +370,7 @@ def test_cached_attention_gqa_grouped_einsum_matches_repeat():
 
 def test_paged_attention_unknown_impl_raises():
     q = jnp.zeros((1, 1, 2, 4))
-    kp = jnp.zeros((3, 2, 1, 4))
+    kp = jnp.zeros((1, 3, 2, 4))
     with pytest.raises(ValueError, match="unknown paged attention impl"):
-        paged_attention(q, kp, kp, np.zeros((1, 2), np.int32),
+        paged_attention(q, kp, kp, 0, np.zeros((1, 2), np.int32),
                         np.zeros((1,), np.int32), impl="cuda")

@@ -1,0 +1,271 @@
+"""The KV pool stays where it is (ISSUE 25): the compiled decode and
+prefill programs take the stacked pool donated, update it by row scatters
+at ``(layer, block, offset)``, read it at ``(layer, block)``, and hand the
+same buffer back. Nothing of the pool's size, and nothing of one layer's
+slab, is produced inside them — no slice, no reshape, no write-back, no
+copy — which :func:`accelerate_tpu.utils.hlo.buffers_moved` reads off the
+compiled text. ``chip_smoke.py`` runs the same reading on the chip.
+
+All tier-1 and cheap: on the CPU a three-layer tiny model, one prefill
+chunk and one decode burst per engine, f32 and int8 pools (XLA's CPU
+backend has no bf16 scatter: it converts a bf16 pool to f32 and back around
+each one, which is that compiler's doing and says nothing of the chip); and
+the paged step at Mistral-7B widths compiled for a described v5e chip, bf16
+and int8 pools, with the Pallas kernel in it — no chip is needed to compile.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from accelerate_tpu.models import LlamaConfig, LlamaForCausalLM
+from accelerate_tpu.serving import EngineConfig, InferenceEngine
+from accelerate_tpu.utils.hlo import buffers_moved
+
+
+@pytest.fixture(scope="module")
+def tiny_model():
+    config = LlamaConfig.tiny(vocab_size=64, hidden_size=32, layers=3, heads=4, seq=96)
+    return LlamaForCausalLM.from_config(config, seed=0)
+
+
+#: 401 blocks: one layer's slab of the pool is larger than all the
+#: activations of the tiny model together, and no weight or activation
+#: shares an element count with the pool, a slab of it, or their scale arrays
+GEOM = dict(num_slots=3, block_size=8, max_seq_len=64, prefill_chunk=8,
+            decode_burst=2, num_blocks=401)
+
+
+def _served_engine(model, kv_dtype, draft):
+    spec = dict(spec_k=2, draft=draft) if draft else {}
+    engine = InferenceEngine(model, EngineConfig(kv_dtype=kv_dtype, **GEOM, **spec))
+    rng = np.random.default_rng(0)
+    request = engine.add_request(rng.integers(0, 64, size=11).astype(np.int32), 5)
+    engine.run_until_idle(max_iterations=200)
+    assert len(request.output_tokens) == 5
+    return engine
+
+
+def _watched(engine):
+    """Element counts of each pool array and of one layer's slab of it."""
+    pools = [p for p in (engine._kp, engine._ks) if p is not None]
+    return sorted({n for p in pools for n in (p.size, p.size // p.shape[0])})
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("draft", [None, "early_exit:1"])
+@pytest.mark.parametrize("kv_dtype", ["f32", "int8"])
+def test_compiled_step_leaves_the_pool_in_place(tiny_model, kv_dtype, draft, program):
+    engine = _served_engine(tiny_model, kv_dtype, draft)
+    compiled = engine.compiled(program)
+    text = compiled.as_text()
+    assert text.startswith(f"HloModule jit_{'spec' if draft and program == 'decode' else program}")
+    found = buffers_moved(text, _watched(engine))
+    # (a) nothing of pool or slab size but parameters, plumbing and the
+    # in-place row scatters; (b) every pool parameter aliased to an output
+    assert found["moved"] == []
+    assert found["unaliased"] == []
+    # the scatters are there (two per pool array and layer loop), and the
+    # whole pool is what they update
+    pool_shape = "[" + ",".join(map(str, engine._kp.shape)) + "]"
+    assert sum(
+        1 for line in text.splitlines()
+        if " scatter(" in line and pool_shape in line.split(" scatter(")[0]
+    ) >= 2
+    # the executable holds no second pool: its temporaries are smaller than
+    # one layer's slab of one pool
+    slab_bytes = engine._kp.nbytes // engine._kp.shape[0]
+    assert compiled.memory_analysis().temp_size_in_bytes < slab_bytes
+
+
+def test_pool_is_allocated_in_the_layout_the_kernel_reads(tiny_model):
+    """Heads fold into lanes in storage, so the kernel's view is the stored
+    view and no program relayouts the pool."""
+    engine = InferenceEngine(tiny_model, EngineConfig(kv_dtype="int8", **GEOM))
+    cfg = tiny_model.config
+    assert engine._kp.shape == (
+        3, 401, 8, cfg.num_key_value_heads * cfg.head_dim
+    )
+    assert engine._ks.shape == (3, 401, 8, cfg.num_key_value_heads)
+
+
+def test_the_check_sees_a_pool_scanned_as_xs_and_ys():
+    """The negative control: the carriage this PR removed — the stacked
+    pool as a scan's ``xs`` and ``ys`` — slices a slab out per layer and
+    writes it back into a second buffer, and ``buffers_moved`` says so."""
+    pool = jnp.zeros((3, 101, 8, 32), jnp.float32)
+    rows = jnp.ones((3, 4, 32), jnp.float32)
+    blk = jnp.asarray([1, 2, 3, 4], jnp.int32)
+
+    def scanned(pool, rows):
+        def body(carry, xs):
+            slab, r = xs
+            slab = slab.at[blk, 0].set(r)
+            return carry + slab[blk, 0].sum(), slab
+        return jax.lax.scan(body, jnp.float32(0), (pool, rows))
+
+    def carried(pool, rows):
+        def body(carry, xs):
+            acc, pool = carry
+            r, layer = xs
+            pool = pool.at[layer, blk, 0].set(r)
+            return (acc + pool[layer, blk, 0].sum(), pool), None
+        (acc, pool), _ = jax.lax.scan(
+            body, (jnp.float32(0), pool), (rows, jnp.arange(3, dtype=jnp.int32))
+        )
+        return acc, pool
+
+    watched = [pool.size, pool.size // 3]
+    text = lambda fn: jax.jit(fn, donate_argnums=(0,)).lower(pool, rows).compile().as_text()
+    old = buffers_moved(text(scanned), watched)
+    assert old["moved"], "the scanned pool should show slab-sized instructions"
+    new = buffers_moved(text(carried), watched)
+    assert new == {"moved": [], "unaliased": []}
+
+
+_MODULE = """HloModule jit_step, is_scheduled=true, input_output_alias={ {0}: (0, {}, may-alias) }, entry_computation_layout={(f32[4,8]{1,0}, f32[4,8]{1,0})->(f32[4,8]{1,0}, f32[4,8]{1,0})}
+
+%fused_scatter (p0: f32[4,8], p1: s32[2], p2: f32[2,8]) -> f32[4,8] {
+  %p0 = f32[4,8]{1,0} parameter(0)
+  %p1 = s32[2]{0} parameter(1)
+  %p2 = f32[2,8]{1,0} parameter(2)
+  ROOT %scatter.1 = f32[4,8]{1,0} scatter(%p0, %p1, %p2), to_apply=%add
+}
+
+%fused_slice (p0: f32[4,8]) -> f32[2,8] {
+  %p0.1 = f32[4,8]{1,0} parameter(0)
+  ROOT %dynamic-slice.1 = f32[2,8]{1,0} dynamic-slice(%p0.1, %c, %c), dynamic_slice_sizes={2,8}
+}
+
+%fused_slab (p0: f32[4,8]) -> f32[1,8] {
+  %p0.2 = f32[4,8]{1,0} parameter(0)
+  ROOT %slice.1 = f32[1,8]{1,0} slice(%p0.2), slice={[0:1], [0:8]}
+}
+
+ENTRY %main (a: f32[4,8], b: f32[4,8]) -> (f32[4,8], f32[4,8]) {
+  %a = f32[4,8]{1,0:T(8,128)} parameter(0)
+  %b = f32[4,8]{1,0:T(8,128)} parameter(1)
+  %fusion.1 = f32[4,8]{1,0:T(8,128)} fusion(%a, %i, %u), kind=kLoop, calls=%fused_scatter
+EXTRA
+  ROOT %tuple.1 = (f32[4,8]{1,0}, f32[4,8]{1,0}) tuple(%fusion.1, %b)
+}
+"""
+
+
+@pytest.mark.parametrize(
+    "line, moved",
+    [
+        ("", []),
+        ("  %copy.3 = f32[4,8]{0,1:T(8,128)} copy(%b)", [("copy.3", "copy", "f32[4,8]")]),
+        ("  %fusion.2 = f32[2,8]{1,0} fusion(%b), kind=kLoop, calls=%fused_slice", []),
+        (
+            "  %fusion.3 = f32[1,8]{1,0} fusion(%b), kind=kLoop, calls=%fused_slab",
+            [("fusion.3", "fusion:slice", "f32[1,8]")],
+        ),
+        (
+            "  %copy-start.1 = (f32[4,8]{1,0:T(8,128)S(1)}, f32[4,8]{1,0}, u32[]{:S(2)}) copy-start(%b)",
+            [("copy-start.1", "copy-start", "f32[4,8]")],
+        ),
+        ("  %reshape.9 = f32[8,4]{1,0} reshape(%b)", [("reshape.9", "reshape", "f32[8,4]")]),
+        ("  %bitcast.2 = f32[32]{0} bitcast(%b)", []),
+        ("  %dus.1 = f32[4,8]{1,0} dynamic-update-slice(%b, %u, %c, %c)", []),
+        ("  %small.1 = f32[2,8]{1,0} copy(%u)", []),
+    ],
+)
+def test_buffers_moved_reads_compiled_text(line, moved):
+    """The reader on a module written out by hand: a whole-buffer size (32
+    elements) and a slab's (8) are watched; parameter 0 is aliased in the
+    header and parameter 1 is not."""
+    found = buffers_moved(_MODULE.replace("EXTRA", line), [32, 8])
+    # the slab-sized slice inside %fused_slab is itself a finding, whether
+    # or not the entry computation calls it
+    assert found["moved"] == [("slice.1", "slice", "f32[1,8]")] + moved
+    assert found["unaliased"] == [1]
+
+
+# ---------------------------------------------------------------------------
+# the same reading of the program the TPU's compiler makes (no chip needed)
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_v5e_compiled_step_leaves_the_pool_in_place(one_chip, monkeypatch, kv_dtype, program):
+    """Mistral-7B widths (GQA 32/8 heads of 128, block 16), two layers, 64
+    slots x 256 table entries as the benchmark's chat cell dispatches them,
+    or a 128-token chunk (the cell's 256 sits at the edge of the kernel's
+    VMEM, and whether it fits is not this test's matter): compiled for the v5e, the Pallas kernel is in the
+    program, the K and V pools are aliased through it, nothing of their
+    size or of one layer's slab is produced, and the temporaries are far
+    smaller than a slab (the parent held a second copy of both pools)."""
+    import sys
+
+    from accelerate_tpu.models.llama import init_llama_params, llama_apply
+
+    monkeypatch.setattr(
+        sys.modules["accelerate_tpu.ops.paged_attention"],
+        "default_paged_attention_impl", lambda: "pallas",
+    )
+    # 3000 blocks: a slab of 49,152,000 elements, which no weight has
+    layers, blocks, bs, slots, table, chunk = 2, 3000, 16, 64, 256, 128
+    c = LlamaConfig(
+        vocab_size=32768, hidden_size=4096, intermediate_size=14336,
+        num_hidden_layers=layers, num_attention_heads=32, num_key_value_heads=8,
+        max_position_embeddings=32768, rope_theta=1e6, tie_word_embeddings=False,
+    )
+    shaped = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: shaped(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: init_llama_params(jax.random.PRNGKey(0), c)),
+    )
+    quantized = kv_dtype == "int8"
+    pool = shaped((layers, blocks, bs, 8 * 128), jnp.int8 if quantized else jnp.bfloat16)
+    pools = {"k": pool, "v": pool}
+    if quantized:
+        pools["k_scale"] = pools["v_scale"] = shaped((layers, blocks, bs, 8), jnp.float32)
+
+    def step(params, pools, tables, pos, toks, mask):
+        out = llama_apply(
+            c, params, input_ids=toks, paged_kv=pools, block_tables=tables,
+            cache_positions=pos, paged_write_mask=mask,
+        )
+        return out["paged_kv"], jnp.argmax(out["logits"][:, -1, :], -1).astype(jnp.int32)
+
+    def decode(params, pools, tables, pos, toks, mask):
+        def one(carry, _):
+            pools, toks, pos = carry
+            pools, tok = step(params, pools, tables, pos, toks, mask)
+            return (pools, tok[:, None], pos + 1), tok
+        (pools, _, _), out = jax.lax.scan(one, (pools, toks, pos), None, length=4)
+        return pools, out
+
+    b, s, fn = (slots, 1, decode) if program == "decode" else (1, chunk, step)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, pools, shaped((b, table), jnp.int32), shaped((b,), jnp.int32),
+        shaped((b, s), jnp.int32), shaped((b, s), jnp.bool_),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    numel = layers * blocks * bs * 8 * 128
+    found = buffers_moved(text, [numel, numel // layers])
+    assert found == {"moved": [], "unaliased": []}
+    # a quarter of one layer's bf16 slab; for int8 only "less than the two
+    # pools", the second copy the parent held: its scale arrays are another
+    # matter - the compiler keeps them in a layout of its own inside the
+    # loop and relayouts them, lanes padded 8 -> 128, for the kernel
+    # (PERF.md section 7)
+    limit = 2 * numel if quantized else numel // layers * 2 // 4
+    assert compiled.memory_analysis().temp_size_in_bytes < limit

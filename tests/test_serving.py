@@ -162,16 +162,15 @@ def _mesh4():
 
 
 def test_paged_kv_sharding_policy():
-    """The pool shards its kv-head dim over tp (K/V are produced tp-sharded
-    by wk/wv) and falls back to replicated when tp doesn't divide."""
+    """The pool shards its folded head lanes over tp, whole kv heads per
+    shard (K/V are produced tp-sharded by wk/wv), and falls back to
+    replicated when tp doesn't divide the head count."""
     from jax.sharding import PartitionSpec as P
 
     from accelerate_tpu.parallel.sharding import paged_kv_sharding
 
     mesh = _mesh4()
-    assert paged_kv_sharding(mesh, num_kv_heads=4).spec == P(
-        None, None, None, "tp", None
-    )
+    assert paged_kv_sharding(mesh, num_kv_heads=4).spec == P(None, None, None, "tp")
     assert paged_kv_sharding(mesh, num_kv_heads=3).spec == P()
 
 
@@ -210,7 +209,7 @@ def test_sharded_engine_matches_single_device(tiny_model):
     # the pool really is distributed: each device holds 1/tp of the kv heads
     shard_shapes = {s.data.shape for s in sharded_engine._kp.addressable_shards}
     full = sharded_engine._kp.shape
-    assert shard_shapes == {(*full[:3], full[3] // 2, full[4])}
+    assert shard_shapes == {(*full[:3], full[3] // 2)}
 
 
 # ---------------------------------------------------------------------------
@@ -295,7 +294,7 @@ def test_chunked_prefill_matches_one_shot_logits(tiny_model):
     ref = np.asarray(dense["logits"][:, -1, :])
 
     bs, nb, mb = 8, 6, 4
-    shape = (cfg.num_hidden_layers, nb, bs, cfg.num_key_value_heads, cfg.head_dim)
+    shape = (cfg.num_hidden_layers, nb, bs, cfg.num_key_value_heads * cfg.head_dim)
     pages = {"k": jnp.zeros(shape), "v": jnp.zeros(shape)}
     bt = np.zeros((1, mb), np.int32)
     bt[0, :2] = [1, 2]
@@ -515,11 +514,12 @@ def test_kv_dtype_paged_logits_match_dense(tiny_model, kv_dtype):
 
     store_dtype, quantized = kv_storage_dtype(kv_dtype)
     bs, nb, mb = 8, 6, 4
-    shape = (cfg.num_hidden_layers, nb, bs, cfg.num_key_value_heads, cfg.head_dim)
+    shape = (cfg.num_hidden_layers, nb, bs, cfg.num_key_value_heads * cfg.head_dim)
     pages = {"k": jnp.zeros(shape, store_dtype), "v": jnp.zeros(shape, store_dtype)}
     if quantized:
-        pages["k_scale"] = jnp.ones(shape[:-1], jnp.float32)
-        pages["v_scale"] = jnp.ones(shape[:-1], jnp.float32)
+        scale_shape = (*shape[:-1], cfg.num_key_value_heads)
+        pages["k_scale"] = jnp.ones(scale_shape, jnp.float32)
+        pages["v_scale"] = jnp.ones(scale_shape, jnp.float32)
     bt = np.zeros((1, mb), np.int32)
     bt[0, :2] = [1, 2]
     got = None

@@ -155,6 +155,9 @@ class TestAnalyzer:
         assert all(l.bytes_per_device == l.bytes_global for l in repl)
         # default pool = full residency: slots * ceil(seq/block) + null
         assert sharded[0].shape[1] == 8 * 32 + 1
+        # stored lane-folded, as the engine allocates it; tp on the lanes
+        assert sharded[0].shape == (16, 8 * 32 + 1, 16, 12 * 128)
+        assert sharded[0].spec == "PartitionSpec(None, None, None, 'tp')"
 
     def test_mesh_spec_parsing(self):
         from accelerate_tpu.analysis.shardplan import parse_mesh_spec
