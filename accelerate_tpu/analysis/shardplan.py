@@ -1025,6 +1025,7 @@ def engine_preflight(
     swap_gb: float | None = None,
     draft_layers: int | None = None,
     stacked_prefix: str = "layers",
+    state_bytes: int = 0,
 ) -> dict:
     """The serving engine's capacity check, run BEFORE the pools allocate:
     predicted per-device bytes of params (under the same planner
@@ -1040,7 +1041,10 @@ def engine_preflight(
     swapped block lives in host memory, not HBM), so the HBM pre-flight
     stays truthful with swap on. ``draft_layers`` (speculative decoding
     armed) adds the ``draft_params`` tier — :func:`plan_draft_params` —
-    into ``total_bytes`` and reports it as ``draft_bytes``."""
+    into ``total_bytes`` and reports it as ``draft_bytes``. ``state_bytes``
+    is a model's per-slot state beside the pool (``models/cache.py``:
+    fixed per slot, replicated), counted into ``total_bytes`` and reported
+    as such."""
     sizes = mesh_sizes_of(mesh) if mesh is not None else {ax: 1 for ax in MESH_AXES}
     param_plans = plan_params(params, sizes, rules=rules)
     params_bytes = sum(p.bytes_per_device for p in param_plans)
@@ -1065,10 +1069,11 @@ def engine_preflight(
     )
     pool_bytes = sum(p.bytes_per_device for p in pool_plans)
     budget = int(hbm_budget_gb * (1 << 30))
-    total = params_bytes + draft_bytes + pool_bytes
+    total = params_bytes + draft_bytes + pool_bytes + int(state_bytes)
     report = {
         "params_bytes": params_bytes,
         "pool_bytes": pool_bytes,
+        "state_bytes": int(state_bytes),
         "total_bytes": total,
         "budget_bytes": budget,
         "headroom_bytes": budget - total,

@@ -127,6 +127,14 @@ def _build_model(args):
 
     from ..models import LlamaConfig, LlamaForCausalLM
 
+    dtype = jnp.bfloat16 if args.dtype == "bf16" else jnp.float32
+    if getattr(args, "model_config", None):
+        # a published config.json: the zoo's own mapping by model_type (an
+        # unknown one is refused with the list of known ones), random weights
+        from ..models import config_from_hf_json, model_factory_for_config
+
+        config = config_from_hf_json(args.model_config)
+        return model_factory_for_config(config)(config, seed=args.seed, dtype=dtype)
     presets = {
         "tiny": lambda: LlamaConfig.tiny(
             vocab_size=256, hidden_size=64, layers=2, heads=4, seq=max(args.max_seq_len, 128)
@@ -137,7 +145,6 @@ def _build_model(args):
         ),
     }
     config = presets[args.preset]()
-    dtype = jnp.bfloat16 if args.dtype == "bf16" else jnp.float32
     return LlamaForCausalLM.from_config(config, seed=args.seed, dtype=dtype)
 
 
@@ -165,6 +172,7 @@ def _auto_num_blocks(args, model, mesh) -> int:
     attached device's reported HBM; raises ValueError (the SP004 refusal)
     when neither is known or even one request's blocks don't fit."""
     from ..analysis.shardplan import auto_num_blocks, mesh_sizes_of, plan_kv_pool, plan_params
+    from ..models.cache import cache_spec_of
     from ..mesh import device_hbm_bytes
     from ..serving.blocks import blocks_needed
 
@@ -177,7 +185,6 @@ def _auto_num_blocks(args, model, mesh) -> int:
             "reports no device memory limit — pass --hbm-gb"
         )
     inner = getattr(model, "_model", None) or model
-    cfg = inner.config
     sizes = (
         mesh_sizes_of(mesh) if mesh is not None
         else {ax: 1 for ax in ("dp", "pp", "fsdp", "ep", "cp", "tp")}
@@ -186,13 +193,19 @@ def _auto_num_blocks(args, model, mesh) -> int:
     params_bytes = sum(
         p.bytes_per_device for p in plan_params(model.params, sizes, rules=rules)
     )
-    n_kv = getattr(cfg, "num_key_value_heads", None) or cfg.num_attention_heads
+    # which layers hold paged K/V and what is kept per slot beside them: the
+    # model's own declaration (models/cache.py). The slot state is a fixed
+    # cost like the parameters — it does not grow with the blocks — and is
+    # replicated (the engine refuses a mesh for such a model)
+    spec = cache_spec_of(inner).with_state_dtype(getattr(args, "state_dtype", "auto"))
+    state_bytes = args.num_slots * spec.state_bytes_per_slot(
+        "bfloat16" if args.dtype == "bf16" else "float32")
     per_block = sum(
         p.bytes_per_device
         for p in plan_kv_pool(
-            num_layers=cfg.num_hidden_layers,
-            num_kv_heads=n_kv,
-            head_dim=cfg.head_dim,
+            num_layers=spec.paged_layers,
+            num_kv_heads=spec.kv_heads,
+            head_dim=spec.head_dim,
             num_slots=1,
             block_size=args.block_size,
             max_seq_len=args.max_seq_len,
@@ -205,7 +218,7 @@ def _auto_num_blocks(args, model, mesh) -> int:
     full_residency = args.num_slots * blocks_per_slot + 1
     num_blocks, headroom = auto_num_blocks(
         budget_bytes,
-        params_bytes,
+        params_bytes + state_bytes,
         per_block,
         full_residency_blocks=full_residency,
         min_blocks=blocks_per_slot + 1,  # one full request + the null block
@@ -214,7 +227,8 @@ def _auto_num_blocks(args, model, mesh) -> int:
     print(
         f"auto-blocks: {num_blocks} blocks "
         f"({per_block / 1e6:.2f} MB/block/device; full residency "
-        f"{full_residency}) — params {params_bytes / gib:.3f} GiB/device, "
+        f"{full_residency}) — params {params_bytes / gib:.3f} GiB/device"
+        f"{f' + slot state {state_bytes / gib:.3f}' if state_bytes else ''}, "
         f"predicted headroom {headroom / gib:.3f} GiB under the "
         f"{budget_bytes / gib:.3f} GiB budget",
         file=sys.stderr,
@@ -254,6 +268,7 @@ def _make_engine(args):
             prefix_cache=args.prefix_cache,
             swap_gb=args.swap_gb,
             kv_dtype=args.kv_dtype,
+            state_dtype=getattr(args, "state_dtype", "auto"),
             spec_k=args.spec_k,
             draft=args.draft,
             flight_history=args.flight_history,
@@ -885,6 +900,10 @@ def add_parser(subparsers):
     )
     p.add_argument("--preset", choices=("tiny", "flagship"), default="tiny",
                    help="model shape (random weights; prompts are token ids)")
+    p.add_argument("--model-config", default=None, metavar="CONFIG_JSON",
+                   help="serve the model a published config.json describes "
+                   "(by its model_type, through the zoo's config_from_hf_json; "
+                   "random weights) instead of a --preset")
     p.add_argument("--dtype", choices=("f32", "bf16"), default="f32")
     p.add_argument("--num-slots", type=int, default=8,
                    help="decode batch slots (the compiled step's static dim)")
@@ -977,6 +996,12 @@ def add_parser(subparsers):
                    "compute dtype; env ACCELERATE_SERVE_KV_DTYPE): int8/fp8 "
                    "quantize on scatter with per-row amax scales — half the "
                    "decode bytes, ~2x the slot capacity at equal --hbm-gb")
+    p.add_argument("--state-dtype", choices=("auto", "bf16"), default="auto",
+                   help="storage of the per-slot recurrent state of a model that "
+                   "keeps one (default auto = what the model declares, float32 for "
+                   "Granite-4.0-H): bf16 halves the bytes a slot holds and a decode "
+                   "step streams, at one more rounding of the state per decoded "
+                   "token; refused for a model whose cache is blocks only")
     try:
         spec_k_default = int(os.environ.get("ACCELERATE_SERVE_SPEC_K", "0") or 0)
     except ValueError:

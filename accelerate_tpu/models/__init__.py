@@ -48,6 +48,14 @@ def _zoo():
     except ImportError:
         pass
     try:
+        from .granite_hybrid import GraniteHybridConfig, GraniteHybridForCausalLM
+
+        z["granite-4.0-h-micro"] = (
+            GraniteHybridConfig(), lambda c: GraniteHybridForCausalLM.from_config(c)
+        )
+    except ImportError:
+        pass
+    try:
         from .t5 import T5Config, T5ForConditionalGeneration
 
         z["t5-small"] = (T5Config.t5_small(), lambda c: T5ForConditionalGeneration.from_config(c))
@@ -110,9 +118,18 @@ def __getattr__(name):
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 
+#: the ``model_type`` values :func:`config_from_hf_json` maps
+KNOWN_MODEL_TYPES = (
+    "llama", "mistral", "gpt2", "bert", "vit", "opt", "gpt_neox", "gptj", "mixtral",
+    "t5", "mt5", "granitemoehybrid",
+)
+
+
 def config_from_hf_json(path: str):
     """Map an HF-transformers ``config.json`` onto a zoo config by
-    ``model_type`` (keeps the reference's 'point at any checkpoint' UX)."""
+    ``model_type`` (keeps the reference's 'point at any checkpoint' UX).
+    What the zoo cannot build as published is refused, not approximated."""
+    import dataclasses
     import json
 
     with open(path) as f:
@@ -120,6 +137,7 @@ def config_from_hf_json(path: str):
     mt = d.get("model_type", "llama")
     if mt in ("llama", "mistral"):
         return LlamaConfig(
+            head_dim=d.get("head_dim"),
             vocab_size=d.get("vocab_size", 32000),
             hidden_size=d.get("hidden_size", 4096),
             intermediate_size=d.get("intermediate_size", 11008),
@@ -246,13 +264,42 @@ def config_from_hf_json(path: str):
             ),
             tie_word_embeddings=d.get("tie_word_embeddings", True),
         )
-    raise ValueError(f"unsupported model_type {mt!r}")
+    if mt == "granitemoehybrid":
+        from .granite_hybrid import GraniteHybridConfig
+
+        if d.get("num_local_experts", 0):
+            raise ValueError(
+                f"granitemoehybrid with num_local_experts {d['num_local_experts']}: the "
+                "expert layer is not built here, only the dense shared MLP "
+                "(num_local_experts 0, as granite-4.0-h-micro)"
+            )
+        heads, groups = d.get("mamba_n_heads", 64), d.get("mamba_n_groups", 1)
+        if groups < 1 or heads % groups:
+            raise ValueError(
+                f"mamba_n_groups {groups} does not divide mamba_n_heads {heads}"
+            )
+        if d.get("position_embedding_type", "nope") != "nope":
+            raise ValueError(
+                f"position_embedding_type {d['position_embedding_type']!r}: the attention "
+                "layers are built without a position term ('nope'), as published"
+            )
+        fields = {f.name for f in dataclasses.fields(GraniteHybridConfig)} - {"remat"}
+        return GraniteHybridConfig(**{k: d[k] for k in fields if d.get(k) is not None})
+    raise ValueError(
+        f"unsupported model_type {mt!r} (known: {', '.join(KNOWN_MODEL_TYPES)})"
+    )
 
 
 def model_factory_for_config(config):
+    """``factory(config)`` for a zoo config; the causal LMs that can be
+    served also take ``from_config``'s keywords (``seed``, ``dtype``)."""
     name = type(config).__name__
     if name == "LlamaConfig":
-        return lambda c: LlamaForCausalLM.from_config(c)
+        return lambda c, **kw: LlamaForCausalLM.from_config(c, **kw)
+    if name == "GraniteHybridConfig":
+        from .granite_hybrid import GraniteHybridForCausalLM
+
+        return lambda c, **kw: GraniteHybridForCausalLM.from_config(c, **kw)
     if name == "GPT2Config":
         from .gpt2 import GPT2LMHeadModel
 

@@ -58,10 +58,12 @@ class LlamaConfig:
     #: GPipe microbatch count when the mesh has a pp axis > 1
     #: (0 = auto: smallest batch divisor >= number of stages)
     pipeline_microbatches: int = 0
+    #: a published ``head_dim``; None = hidden_size // num_attention_heads
+    head_dim: int | None = None
 
-    @property
-    def head_dim(self) -> int:
-        return self.hidden_size // self.num_attention_heads
+    def __post_init__(self):
+        if self.head_dim is None:
+            self.head_dim = self.hidden_size // self.num_attention_heads
 
     @classmethod
     def llama2_7b(cls):

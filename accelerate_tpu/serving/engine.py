@@ -71,6 +71,7 @@ from ..diagnostics.tracing import (
 from ..generation import _pick_traced
 from ..metrics.ingest import observe_flight
 from ..metrics.registry import get_active_registry
+from ..models.cache import cache_spec_of
 from ..ops.paged_attention import default_paged_attention_impl
 from ..telemetry import get_active_recorder
 from .blocks import NULL_BLOCK, BlockAllocator, blocks_needed
@@ -188,6 +189,14 @@ class EngineConfig:
     #: holds at every setting (scales are just two more donated pool
     #: operands of the same single executable).
     kv_dtype: str = "auto"
+    #: storage policy of the per-slot state of a model that keeps one
+    #: (``models/cache.py``): ``"auto"`` stores every leaf as the model's
+    #: spec declares it; ``"bf16"`` stores the leaves the spec keeps at a
+    #: precision of their own (a recurrent state) at that width — half the
+    #: bytes a slot holds and a decode step streams, at the cost
+    #: of one more rounding of the state per decoded token (the arithmetic
+    #: stays float32). Refused for a model that keeps no such state.
+    state_dtype: str = "auto"
     #: speculative decoding (0 = off, the plain burst decode). ``spec_k > 0``
     #: replaces the decode step with ONE compiled spec round per dispatch:
     #: every active slot drafts ``spec_k`` tokens from the cheap draft, a
@@ -345,6 +354,30 @@ class InferenceEngine:
                 "prefill_chunk, block_size, num_slots, decode_burst must be >= 1"
             )
 
+        # a model that keeps per-slot state beside its blocks: what assumes
+        # that blocks are the whole of a request's past is refused here,
+        # like every other geometry error (the prefix cache, which defaults
+        # to on, is left out below with its reason in stats())
+        spec = cache_spec_of(inner).with_state_dtype(cfg.state_dtype)
+        if spec.slot_state:
+            name = getattr(inner, "name", type(inner).__name__)
+            kept = f"per-slot state ({', '.join(spec.slot_state)}) in {spec.state_layers} layers"
+            for armed, what, missing in (
+                (cfg.swap_gb and cfg.swap_gb > 0, f"swap_gb={cfg.swap_gb}",
+                 "_swap_out moves blocks only, and a request swapped back in would "
+                 "resume from a zeroed state"),
+                (cfg.spec_k, f"spec_k={cfg.spec_k}",
+                 "a rejected draft token is rolled back by position alone, and the "
+                 "state has already moved past it"),
+                (mesh is not None, "mesh=",
+                 "_place_on_mesh has no placement for the slot state"),
+            ):
+                if armed:
+                    raise ValueError(
+                        f"{what} is not supported for {name!r}, which keeps {kept}: "
+                        f"{missing} (ROADMAP Reach 5)"
+                    )
+
         # speculative decoding (spec_k > 0): parse the draft policy and
         # bind the early-exit draft apply BEFORE anything allocates — a bad
         # spec must refuse at bring-up, like every other geometry error
@@ -429,12 +462,17 @@ class InferenceEngine:
             else cfg.num_slots * self._mb + 1
         )
 
-        # device state: the stacked page pools in the kv_dtype policy's
-        # storage dtype ("auto" = the params' compute dtype, the PR 4
-        # behaviour; int8/fp8 add per-row amax scale arrays beside them).
-        # Stored lane-folded — [layers, num_blocks, block_size, n_kv*hd] —
-        # the view the paged kernel reads, so no step program relayouts it
-        n_kv = getattr(mcfg, "num_key_value_heads", None) or mcfg.num_attention_heads
+        # device state, as the model's cache spec declares it (models/cache.py):
+        # the stacked page pools of the layers that attend, in the kv_dtype
+        # policy's storage dtype ("auto" = the params' compute dtype, the PR 4
+        # behaviour; int8/fp8 add per-row amax scale arrays beside them),
+        # stored lane-folded — [paged layers, num_blocks, block_size,
+        # n_kv*hd], the view the paged kernel reads, so no step program
+        # relayouts it — and, where the model keeps one, a fixed-size state
+        # per slot ([layers, num_slots, ...]: a recurrent state, a
+        # convolution's tail), which has no blocks
+        self._cache_spec = spec
+        n_kv = spec.kv_heads
         self._kv_heads = n_kv
         embed = jax.tree.leaves(self._params)[0]
         dtype = embed.dtype if jnp.issubdtype(embed.dtype, jnp.floating) else jnp.float32
@@ -446,33 +484,50 @@ class InferenceEngine:
             store_dtype, quantized = kv_storage_dtype(cfg.kv_dtype)
         self._quantized = quantized
         self.kv_dtype = str(np.dtype(store_dtype))
-        shape = (mcfg.num_hidden_layers, num_blocks, cfg.block_size, n_kv * mcfg.head_dim)
-        scale_shape = (mcfg.num_hidden_layers, num_blocks, cfg.block_size, n_kv)
-        #: bytes one cached token costs across all layers (K + V payload
-        #: plus the f32 scales when quantized) — the decode-bandwidth and
-        #: slot-capacity headline number
+        shape = (spec.paged_layers, num_blocks, cfg.block_size, n_kv * spec.head_dim)
+        scale_shape = (spec.paged_layers, num_blocks, cfg.block_size, n_kv)
+        #: bytes one cached token costs across the layers that hold paged KV
+        #: (K + V payload plus the f32 scales when quantized) — the
+        #: decode-bandwidth and slot-capacity headline number
         self.kv_bytes_per_token = (
             2
-            * mcfg.num_hidden_layers
+            * spec.paged_layers
             * n_kv
-            * (mcfg.head_dim * np.dtype(store_dtype).itemsize + (4 if quantized else 0))
+            * (spec.head_dim * np.dtype(store_dtype).itemsize + (4 if quantized else 0))
         )
+        #: bytes of per-slot state one slot costs, whatever its sequence's
+        #: length (0 for a model whose blocks are all of a request's past)
+        self.state_bytes_per_slot = spec.state_bytes_per_slot(dtype)
         #: max-length requests the pool holds concurrently (num_blocks is
         #: fixed for the engine's lifetime — computed once, reported by
-        #: stats() and every telemetry step row)
+        #: stats() and every telemetry step row); a slot-state model also
+        #: holds no more requests than it has slots of state
         self.kv_slot_capacity = (num_blocks - 1) // cfg.blocks_per_slot
+        if spec.slot_state:
+            self.kv_slot_capacity = min(self.kv_slot_capacity, cfg.num_slots)
         self.hbm_preflight: dict | None = None
         if cfg.hbm_budget_gb is not None:
             self._hbm_preflight(inner, shape, n_kv, store_dtype, mesh)
 
         self.allocator = BlockAllocator(num_blocks)
+        #: why no prefix cache was built although one was asked for (None:
+        #: it was built, or not asked for)
+        self.prefix_cache_off_reason = None
+        if cfg.prefix_cache and spec.slot_state:
+            self.prefix_cache_off_reason = (
+                f"the model keeps per-slot state ({', '.join(spec.slot_state)}) in "
+                f"{spec.state_layers} layers: a block-boundary hit would map K/V for "
+                f"{spec.paged_layers} layers and no state for the rest (state "
+                "snapshots at block boundaries are not built, ROADMAP Reach 5)"
+            )
         self.radix = (
-            RadixCache(self.allocator, cfg.block_size) if cfg.prefix_cache else None
+            RadixCache(self.allocator, cfg.block_size)
+            if cfg.prefix_cache and not spec.slot_state else None
         )
         self._swap = (
             SwapPool(
                 num_layers=shape[0], block_size=cfg.block_size,
-                num_kv_heads=n_kv, head_dim=mcfg.head_dim,
+                num_kv_heads=n_kv, head_dim=spec.head_dim,
                 dtype=store_dtype, capacity_gb=cfg.swap_gb,
                 quantized=quantized,
             )
@@ -486,11 +541,18 @@ class InferenceEngine:
             cfg.num_slots, self.allocator, cfg.block_size, cfg.max_seq_len,
             radix=self.radix, usage=self.usage,
         )
-        self._kp = jnp.zeros(shape, store_dtype)
-        self._vp = jnp.zeros(shape, store_dtype)
-        # all-ones init: a never-written row dequantizes to exactly 0
-        self._ks = jnp.ones(scale_shape, jnp.float32) if quantized else None
-        self._vs = jnp.ones(scale_shape, jnp.float32) if quantized else None
+        #: everything the step programs keep for the sequences, in ONE dict
+        #: that every one of them takes donated and hands back whole: "k" /
+        #: "v" (and "k_scale" / "v_scale", all-ones so that a never-written
+        #: row dequantizes to exactly 0), then the model's slot-state leaves
+        self._cache = {"k": jnp.zeros(shape, store_dtype), "v": jnp.zeros(shape, store_dtype)}
+        if quantized:
+            self._cache["k_scale"] = jnp.ones(scale_shape, jnp.float32)
+            self._cache["v_scale"] = jnp.ones(scale_shape, jnp.float32)
+        for name, leaf in spec.slot_state.items():
+            self._cache[name] = jnp.zeros(
+                leaf.array_shape(cfg.num_slots), leaf.dtype or dtype)
+        self._state_resets = 0
         self._key = jax.random.PRNGKey(cfg.seed)
         self._temp = jnp.float32(cfg.temperature)
         #: per-slot draw root: never split/threaded — every draw derives
@@ -618,11 +680,7 @@ class InferenceEngine:
                 np.size(x) * np.dtype(getattr(x, "dtype", np.float32)).itemsize
                 for x in jax.tree_util.tree_leaves(self._params)
             )
-            + sum(
-                p.size * np.dtype(p.dtype).itemsize
-                for p in (self._kp, self._vp, self._ks, self._vs)
-                if p is not None
-            )
+            + sum(p.size * np.dtype(p.dtype).itemsize for p in self._cache.values())
         )
 
         #: program name -> (jitted fn, abstract operands of its first
@@ -653,6 +711,11 @@ class InferenceEngine:
         self._write_blocks_fn = jax.jit(
             lambda pool, ids, rows: pool.at[:, ids].set(rows),
             donate_argnums=(0,),
+        )
+        # a slot's state is zeroed when a request is placed in it: one
+        # donated row-set per leaf, the slot a traced scalar
+        self._zero_slot_fn = jax.jit(
+            lambda leaf, slot: leaf.at[:, slot].set(0), donate_argnums=(0,)
         )
         # grammar-row install: one donated row-set per table, the row id a
         # traced scalar so every grammar reuses one compile — same tiny-
@@ -763,6 +826,7 @@ class InferenceEngine:
             swap_gb=self.config.swap_gb or None,
             draft_layers=self._spec.layers if self._spec else None,
             stacked_prefix=getattr(inner, "stacked_params_prefix", "layers"),
+            state_bytes=self.state_bytes_per_slot * self.config.num_slots,
         )
         self.hbm_preflight = report
         if report["over"]:
@@ -771,11 +835,15 @@ class InferenceEngine:
                 f" + draft {report['draft_bytes'] / gib:.3f}"
                 if report.get("draft_bytes") else ""
             )
+            state = (
+                f" + slot state {report['state_bytes'] / gib:.3f}"
+                if report["state_bytes"] else ""
+            )
             raise ValueError(
                 f"SP004: engine refuses to start — predicted "
                 f"{report['total_bytes'] / gib:.3f} GiB/device "
                 f"(params {report['params_bytes'] / gib:.3f}{draft} + "
-                f"kv pools {report['pool_bytes'] / gib:.3f}) exceeds the "
+                f"kv pools {report['pool_bytes'] / gib:.3f}{state}) exceeds the "
                 f"{self.config.hbm_budget_gb:.3f} GiB budget. Lower "
                 f"num_blocks/max_seq_len (or use serve --auto-blocks), shard "
                 f"over a larger mesh, shrink the draft (or spec_k=0), or "
@@ -818,27 +886,35 @@ class InferenceEngine:
 
         return op_scopes(self.compiled_text(program))
 
-    def _paged_kv_dict(self, kp, vp, ks, vs) -> dict:
-        pages = {"k": kp, "v": vp}
-        if self._quantized:
-            pages["k_scale"], pages["v_scale"] = ks, vs
-        return pages
+    # the pool's leaves by their old names: the block-granular edits (CoW,
+    # swap) and the tests address one pool at a time
+    def _cache_leaf(name):
+        def get(self):
+            return self._cache.get(name)
+
+        def put(self, value):
+            self._cache[name] = value
+
+        return property(get, put)
+
+    _kp, _vp = _cache_leaf("k"), _cache_leaf("v")
+    _ks, _vs = _cache_leaf("k_scale"), _cache_leaf("v_scale")
+    del _cache_leaf
 
     def _build_decode_fn(self):
         if self._psampling:
             return self._build_lane_decode_fn()
         apply_fn, cfg = self._apply_fn, self.config
-        quantized = self._quantized
 
-        def decode(params, kp, vp, ks, vs, block_tables, pos0, toks, active, key, temp):
+        def decode(params, cache, block_tables, pos0, toks, active, key, temp):
             self._decode_traces += 1  # traced-body side effect: cache misses only
 
             def one_step(carry, _):
-                kp, vp, ks, vs, toks, pos, key = carry
+                cache, toks, pos, key = carry
                 out = apply_fn(
                     params,
                     input_ids=toks,
-                    paged_kv=self._paged_kv_dict(kp, vp, ks, vs),
+                    paged_kv=cache,
                     block_tables=block_tables,
                     cache_positions=pos,
                     paged_write_mask=active,  # PREFILL/free lanes must not scribble
@@ -848,34 +924,16 @@ class InferenceEngine:
                     logits, key, jnp.zeros(logits.shape[:1], bool), jnp.int32(0),
                     temp, cfg.do_sample, has_eos=False,  # eos is host-side state
                 )
-                pages = out["paged_kv"]
-                ks2 = pages.get("k_scale", ks)
-                vs2 = pages.get("v_scale", vs)
-                return (
-                    pages["k"], pages["v"], ks2, vs2, tok[:, None], pos + 1, key
-                ), tok
+                return (out["paged_kv"], tok[:, None], pos + 1, key), tok
 
-            (kp, vp, ks, vs, _, _, key), toks_out = jax.lax.scan(
-                one_step, (kp, vp, ks, vs, toks, pos0, key), None,
-                length=cfg.decode_burst,
+            (cache, _, _, key), toks_out = jax.lax.scan(
+                one_step, (cache, toks, pos0, key), None, length=cfg.decode_burst,
             )
-            return kp, vp, ks, vs, toks_out, key  # toks_out: [burst, num_slots]
+            return cache, toks_out, key  # toks_out: [burst, num_slots]
 
-        # scale arrays are donated pool operands exactly like the pools —
-        # at kv_dtype="auto"/"bf16"/"f32" they are None-free placeholders
-        # that never reach the jit (see _dispatch_decode)
-        donate = (1, 2, 3, 4) if quantized else (1, 2)
-        if quantized:
-            return jax.jit(decode, donate_argnums=donate)
-
-        def decode_plain(params, kp, vp, block_tables, pos0, toks, active, key, temp):
-            kp, vp, _, _, toks_out, key = decode(
-                params, kp, vp, None, None, block_tables, pos0, toks, active,
-                key, temp,
-            )
-            return kp, vp, toks_out, key
-
-        return jax.jit(decode_plain, donate_argnums=donate)
+        # the cache — pools, scale arrays when quantized, slot state where
+        # the model keeps one — is one donated operand of every step program
+        return jax.jit(decode, donate_argnums=(1,))
 
     def _build_lane_decode_fn(self):
         """Per-slot twin of the legacy burst decode: the sampling lanes
@@ -891,20 +949,19 @@ class InferenceEngine:
         never corrupt it.  The per-step top-N logprob harvest rides the
         scan outputs through the one existing device_get."""
         apply_fn, cfg = self._apply_fn, self.config
-        quantized = self._quantized
         eos_id = cfg.eos_token_id
         topn = cfg.logprobs_topn
 
-        def decode(params, kp, vp, ks, vs, block_tables, pos0, toks, active,
+        def decode(params, cache, block_tables, pos0, toks, active,
                    lanes, gmask, gtrans, base_key):
             self._decode_traces += 1  # traced-body side effect: cache misses only
 
             def one_step(carry, t):
-                kp, vp, ks, vs, toks, pos, dfa = carry
+                cache, toks, pos, dfa = carry
                 out = apply_fn(
                     params,
                     input_ids=toks,
-                    paged_kv=self._paged_kv_dict(kp, vp, ks, vs),
+                    paged_kv=cache,
                     block_tables=block_tables,
                     cache_positions=pos,
                     paged_write_mask=active,  # PREFILL/free lanes must not scribble
@@ -915,34 +972,17 @@ class InferenceEngine:
                     eos_id=eos_id, logprobs_topn=topn,
                 )
                 dfa = gtrans[lanes["grammar_row"], dfa, tok]
-                pages = out["paged_kv"]
-                ks2 = pages.get("k_scale", ks)
-                vs2 = pages.get("v_scale", vs)
-                return (
-                    pages["k"], pages["v"], ks2, vs2, tok[:, None], pos + 1, dfa
-                ), (tok, logp_tok, top_vals, top_ids)
+                return (out["paged_kv"], tok[:, None], pos + 1, dfa), (
+                    tok, logp_tok, top_vals, top_ids)
 
-            (kp, vp, ks, vs, _, _, _), (toks_out, logps, tvals, tids) = jax.lax.scan(
-                one_step,
-                (kp, vp, ks, vs, toks, pos0, lanes["dfa_state"]),
+            (cache, _, _, _), (toks_out, logps, tvals, tids) = jax.lax.scan(
+                one_step, (cache, toks, pos0, lanes["dfa_state"]),
                 jnp.arange(cfg.decode_burst),
             )
             # toks_out: [burst, num_slots]; logprob outputs [burst, slots(, N)]
-            return kp, vp, ks, vs, toks_out, logps, tvals, tids
+            return cache, toks_out, logps, tvals, tids
 
-        donate = (1, 2, 3, 4) if quantized else (1, 2)
-        if quantized:
-            return jax.jit(decode, donate_argnums=donate)
-
-        def decode_plain(params, kp, vp, block_tables, pos0, toks, active,
-                         lanes, gmask, gtrans, base_key):
-            kp, vp, _, _, toks_out, logps, tvals, tids = decode(
-                params, kp, vp, None, None, block_tables, pos0, toks, active,
-                lanes, gmask, gtrans, base_key,
-            )
-            return kp, vp, toks_out, logps, tvals, tids
-
-        return jax.jit(decode_plain, donate_argnums=donate)
+        return jax.jit(decode, donate_argnums=(1,))
 
     def _build_spec_decode_fn(self):
         """Speculative twin of ``_build_decode_fn`` — when ``spec_k`` is
@@ -980,39 +1020,28 @@ class InferenceEngine:
         apply_fn, cfg = self._apply_fn, self.config
         draft_apply = self._draft_apply
         k = cfg.spec_k
-        quantized = self._quantized
 
-        def spec_decode(params, kp, vp, ks, vs, block_tables, pos0, toks, active):
+        def spec_decode(params, cache, block_tables, pos0, toks, active):
             self._decode_traces += 1  # traced-body side effect: cache misses only
 
             def dstep(carry, _):
-                dkp, dvp, dks, dvs, tok, pos = carry
-                pages_in = {"k": dkp, "v": dvp}
-                if quantized:
-                    pages_in["k_scale"], pages_in["v_scale"] = dks, dvs
+                cache, tok, pos = carry
                 out = draft_apply(
                     params,
                     input_ids=tok,
-                    paged_kv=pages_in,
+                    paged_kv=cache,
                     block_tables=block_tables,
                     cache_positions=pos,
                     paged_write_mask=active,  # PREFILL/free lanes must not scribble
                 )
-                pages = out["paged_kv"]
                 nxt = jnp.argmax(out["logits"][:, -1, :], axis=-1).astype(jnp.int32)
-                return (
-                    pages["k"], pages["v"],
-                    pages.get("k_scale", dks), pages.get("v_scale", dvs),
-                    nxt[:, None], pos + 1,
-                ), nxt
+                return (out["paged_kv"], nxt[:, None], pos + 1), nxt
 
             # the draft autoregresses through the pool itself (its own
             # layers, by index): its rows only feed its OWN next steps —
             # the verify below writes the same positions of every layer
             # again, from the same tokens/weights, under the same mask
-            (kp, vp, ks, vs, _, _), d = jax.lax.scan(
-                dstep, (kp, vp, ks, vs, toks, pos0), None, length=k
-            )
+            (cache, _, _), d = jax.lax.scan(dstep, (cache, toks, pos0), None, length=k)
             d = d.T  # [num_slots, k] draft proposals
 
             # ONE verify forward over [pending, d_1 .. d_k]: scatters k+1
@@ -1023,31 +1052,16 @@ class InferenceEngine:
             out = apply_fn(
                 params,
                 input_ids=chunk,
-                paged_kv=self._paged_kv_dict(kp, vp, ks, vs),
+                paged_kv=cache,
                 block_tables=block_tables,
                 cache_positions=pos0,
                 paged_write_mask=vmask,
             )
-            pages = out["paged_kv"]
             preds = jnp.argmax(out["logits"], axis=-1).astype(jnp.int32)  # [slots, k+1]
             accept, tok_seq = spec_accept_tokens(d, preds)
-            return (
-                pages["k"], pages["v"],
-                pages.get("k_scale", ks), pages.get("v_scale", vs),
-                tok_seq, accept,
-            )
+            return out["paged_kv"], tok_seq, accept
 
-        donate = (1, 2, 3, 4) if quantized else (1, 2)
-        if quantized:
-            return jax.jit(spec_decode, donate_argnums=donate)
-
-        def spec_plain(params, kp, vp, block_tables, pos0, toks, active):
-            kp, vp, _, _, tok_seq, accept = spec_decode(
-                params, kp, vp, None, None, block_tables, pos0, toks, active
-            )
-            return kp, vp, tok_seq, accept
-
-        return jax.jit(spec_plain, donate_argnums=donate)
+        return jax.jit(spec_decode, donate_argnums=(1,))
 
     def _build_lane_spec_decode_fn(self):
         """Per-slot spec round: the draft proposes through the SAME lane
@@ -1080,28 +1094,23 @@ class InferenceEngine:
         apply_fn, cfg = self._apply_fn, self.config
         draft_apply = self._draft_apply
         k = cfg.spec_k
-        quantized = self._quantized
         eos_id = cfg.eos_token_id
 
-        def spec_decode(params, kp, vp, ks, vs, block_tables, pos0, toks, active,
+        def spec_decode(params, cache, block_tables, pos0, toks, active,
                         lanes, gmask, gtrans, base_key):
             self._decode_traces += 1  # traced-body side effect: cache misses only
             row = lanes["grammar_row"]
 
             def dstep(carry, t):
-                dkp, dvp, dks, dvs, tok, pos, dfa = carry
-                pages_in = {"k": dkp, "v": dvp}
-                if quantized:
-                    pages_in["k_scale"], pages_in["v_scale"] = dks, dvs
+                cache, tok, pos, dfa = carry
                 out = draft_apply(
                     params,
                     input_ids=tok,
-                    paged_kv=pages_in,
+                    paged_kv=cache,
                     block_tables=block_tables,
                     cache_positions=pos,
                     paged_write_mask=active,  # PREFILL/free lanes must not scribble
                 )
-                pages = out["paged_kv"]
                 filt = apply_filters(
                     out["logits"][:, -1, :], lanes, dfa, lanes["pos"] + t,
                     gmask, eos_id,
@@ -1112,15 +1121,11 @@ class InferenceEngine:
                 nxt = jnp.where(
                     lanes["sample"], categorical_per_slot(keys, logq), greedy
                 ).astype(jnp.int32)
-                return (
-                    pages["k"], pages["v"],
-                    pages.get("k_scale", dks), pages.get("v_scale", dvs),
-                    nxt[:, None], pos + 1, gtrans[row, dfa, nxt],
-                ), (nxt, jnp.exp(logq))
+                return (out["paged_kv"], nxt[:, None], pos + 1, gtrans[row, dfa, nxt]), (
+                    nxt, jnp.exp(logq))
 
-            (kp, vp, ks, vs, _, _, _), (d, q) = jax.lax.scan(
-                dstep, (kp, vp, ks, vs, toks, pos0, lanes["dfa_state"]),
-                jnp.arange(k),
+            (cache, _, _, _), (d, q) = jax.lax.scan(
+                dstep, (cache, toks, pos0, lanes["dfa_state"]), jnp.arange(k),
             )
             d = d.T  # [num_slots, k] draft proposals; q: [k, slots, vocab]
 
@@ -1129,12 +1134,12 @@ class InferenceEngine:
             out = apply_fn(
                 params,
                 input_ids=chunk,
-                paged_kv=self._paged_kv_dict(kp, vp, ks, vs),
+                paged_kv=cache,
                 block_tables=block_tables,
                 cache_positions=pos0,
                 paged_write_mask=vmask,
             )
-            pages = out["paged_kv"]
+            cache = out["paged_kv"]
             tlogits = out["logits"]  # [num_slots, k+1, vocab]
 
             # DFA states along the draft path (k is small and static): the
@@ -1172,40 +1177,28 @@ class InferenceEngine:
             sample = lanes["sample"]
             accept = jnp.where(sample, accept_s, accept_g).astype(jnp.int32)
             tok_seq = jnp.where(sample[:, None], seq_s, seq_g).astype(jnp.int32)
-            return (
-                pages["k"], pages["v"],
-                pages.get("k_scale", ks), pages.get("v_scale", vs),
-                tok_seq, accept,
-            )
+            return cache, tok_seq, accept
 
-        donate = (1, 2, 3, 4) if quantized else (1, 2)
-        if quantized:
-            return jax.jit(spec_decode, donate_argnums=donate)
-
-        def spec_plain(params, kp, vp, block_tables, pos0, toks, active,
-                       lanes, gmask, gtrans, base_key):
-            kp, vp, _, _, tok_seq, accept = spec_decode(
-                params, kp, vp, None, None, block_tables, pos0, toks, active,
-                lanes, gmask, gtrans, base_key,
-            )
-            return kp, vp, tok_seq, accept
-
-        return jax.jit(spec_plain, donate_argnums=donate)
+        return jax.jit(spec_decode, donate_argnums=(1,))
 
     def _build_prefill_fn(self):
         apply_fn, cfg = self._apply_fn, self.config
-        quantized = self._quantized
+        has_state = bool(self._cache_spec.slot_state)
 
-        def prefill(params, kp, vp, ks, vs, block_table, start, chunk, valid,
-                    last_idx, key, temp):
+        def prefill(params, cache, block_table, start, chunk, valid,
+                    last_idx, slot, key, temp):
             self._prefill_traces += 1
+            # a model that keeps per-slot state is told which slot's rows
+            # this prompt's chunk continues from and leaves
+            state_kw = {"state_slots": slot} if has_state else {}
             out = apply_fn(
                 params,
                 input_ids=chunk,  # [1, prefill_chunk]
-                paged_kv=self._paged_kv_dict(kp, vp, ks, vs),
+                paged_kv=cache,
                 block_tables=block_table,  # [1, mb]
                 cache_positions=start,  # [1]
                 paged_write_mask=valid,  # drops the padded tail
+                **state_kw,
             )
             # first-token pick from the prompt's last real position — only
             # meaningful on the final chunk; the host ignores it otherwise
@@ -1214,21 +1207,9 @@ class InferenceEngine:
                 logits, key, jnp.zeros((1,), bool), jnp.int32(0),
                 temp, cfg.do_sample, has_eos=False,
             )
-            pages = out["paged_kv"]
-            ks2 = pages.get("k_scale", ks)
-            vs2 = pages.get("v_scale", vs)
-            return pages["k"], pages["v"], ks2, vs2, tok[0], logits[0], key
+            return out["paged_kv"], tok[0], logits[0], key
 
-        if quantized:
-            return jax.jit(prefill, donate_argnums=(1, 2, 3, 4))
-
-        def prefill_plain(params, kp, vp, block_table, start, chunk, valid,
-                          last_idx, key, temp):
-            out = prefill(params, kp, vp, None, None, block_table, start, chunk,
-                          valid, last_idx, key, temp)
-            return out[0], out[1], out[4], out[5], out[6]
-
-        return jax.jit(prefill_plain, donate_argnums=(1, 2))
+        return jax.jit(prefill, donate_argnums=(1,))
 
     # -- public API ----------------------------------------------------------
 
@@ -1535,6 +1516,7 @@ class InferenceEngine:
         self._last_stats_t = None
         self._last_stats_tokens = 0
         self._preemptions = 0
+        self._state_resets = 0
         self._swapped_out_blocks = 0
         self._swapped_in_blocks = 0
         self._out_of_blocks_total = 0
@@ -1673,6 +1655,17 @@ class InferenceEngine:
             "kv_bytes_per_token": self.kv_bytes_per_token,
             "kv_bytes_per_block": self.kv_bytes_per_token * self.config.block_size,
             "kv_slot_capacity": self.kv_slot_capacity,
+            # which layers keep what (models/cache.py): paged K/V by token,
+            # slot state by slot, whatever the sequence's length
+            "kv_layers": self._cache_spec.paged_layers,
+            "state_layers": self._cache_spec.state_layers,
+            "state_dtype": next(
+                (leaf.dtype for leaf in self._cache_spec.slot_state.values() if leaf.dtype),
+                None),
+            "state_bytes_per_slot": self.state_bytes_per_slot,
+            "state_bytes_total": self.state_bytes_per_slot * self.config.num_slots,
+            "state_resets_total": self._state_resets,
+            "prefix_cache": self.radix is not None,
             "free_blocks": self.allocator.free_count,
             # blocks live requests hold (shared prefix blocks included);
             # blocks held ONLY by the radix cache are reported separately —
@@ -1721,6 +1714,8 @@ class InferenceEngine:
             # host_fraction + iteration p50/p99 + per-phase breakdowns
             # over the ring window (empty until an iteration records)
             out.update(self._flight.summary())
+        if self.prefix_cache_off_reason is not None:
+            out["prefix_cache_off_reason"] = self.prefix_cache_off_reason
         if self.radix is not None:
             out["radix_inserted_blocks"] = self.radix.inserted_blocks
             out["radix_evicted_blocks"] = self.radix.evicted_blocks
@@ -2012,7 +2007,14 @@ class InferenceEngine:
     def _place_admitted(self, req: Request) -> None:
         """The device half of admission: restore a preempted request's
         swapped rows into its freshly allocated blocks, or run the pending
-        copy-on-write block copy for a partial-prefix hit."""
+        copy-on-write block copy for a partial-prefix hit. A model's
+        per-slot state starts from zero for whoever is placed in the slot —
+        a new request, or one preempted and now recomputed."""
+        if self._cache_spec.slot_state:
+            slot = np.int32(req.slot)
+            for name in self._cache_spec.slot_state:
+                self._cache[name] = self._zero_slot_fn(self._cache[name], slot)
+            self._state_resets += 1
         if req.swap_plan:
             swap_t0 = time.perf_counter() if self._tr is not None else 0.0
             # one gathered scatter per pool (mirrors _swap_out's batched
@@ -2164,6 +2166,30 @@ class InferenceEngine:
             )
         return True
 
+    def _preempt_by_recompute(self, victim: Request) -> bool:
+        """Preempt ``victim`` with nothing kept: its blocks go back to the
+        pool and it re-queues at the front of its class; on re-admission its
+        slot's state is zeroed and prompt plus emitted tokens are prefilled
+        again (:meth:`_prefill_one_chunk`). For a model whose past is not
+        all in blocks — there is no host copy of a recurrent state to
+        restore. Always succeeds."""
+        # fence first, as _swap_out does: the in-flight round holds a token
+        # that must land on the victim before it re-queues
+        if self._fence_inflight() and (
+            victim.state is RequestState.FINISHED or victim.slot is None
+        ):
+            return True
+        self.allocator.decref(victim.blocks)
+        victim.blocks = []
+        victim.prefill_pos = 0
+        self.scheduler.requeue_preempted(victim, recompute=True)
+        self._preemptions += 1
+        if self.usage is not None:
+            self.usage.update_blocks(victim)
+        if self._tr is not None:
+            self._tr.request_instant(victim.trace_id, "req/preempt", blocks=0, recompute=True)
+        return True
+
     def _release_expired_queued(self, req: Request) -> None:
         """A request that expired while *queued* holds no slot, but a
         preempted one still owns swap handles (host DRAM) and references on
@@ -2209,34 +2235,41 @@ class InferenceEngine:
         cfg = self.config
         c = cfg.prefill_chunk
         start = req.prefill_pos
-        end = min(start + c, req.prompt_len)
+        # a request preempted by recomputation comes back with the tokens it
+        # had emitted: all but the last (still pending) are prefilled again
+        # behind the prompt, and nothing is emitted for them a second time
+        replay = req.recompute and bool(req.output_tokens)
+        seq = list(req.prompt) + req.output_tokens[:-1] if replay else req.prompt
+        total = len(seq)
+        end = min(start + c, total)
         chunk = np.zeros((1, c), np.int32)
-        chunk[0, : end - start] = req.prompt[start:end]
+        chunk[0, : end - start] = seq[start:end]
         valid = np.zeros((1, c), bool)
         valid[0, : end - start] = True
         self._sync_block_table(req)
-        is_final = end == req.prompt_len
-        last_idx = np.int32((req.prompt_len - 1) - start if is_final else 0)
+        is_final = end == total
+        last_idx = np.int32((total - 1) - start if is_final else 0)
 
-        if self._quantized:
-            (self._kp, self._vp, self._ks, self._vs, tok, _logits,
-             self._key) = self._prefill_fn(
-                self._params, self._kp, self._vp, self._ks, self._vs,
-                self._block_tables[req.slot : req.slot + 1],
-                np.asarray([start], np.int32), chunk, valid, last_idx,
-                self._key, self._temp,
-            )
-        else:
-            self._kp, self._vp, tok, _logits, self._key = self._prefill_fn(
-                self._params, self._kp, self._vp,
-                self._block_tables[req.slot : req.slot + 1],
-                np.asarray([start], np.int32), chunk, valid, last_idx,
-                self._key, self._temp,
-            )
+        self._cache, tok, _logits, self._key = self._prefill_fn(
+            self._params, self._cache,
+            self._block_tables[req.slot : req.slot + 1],
+            np.asarray([start], np.int32), chunk, valid, last_idx,
+            np.asarray([req.slot], np.int32), self._key, self._temp,
+        )
         req.prefill_pos = end
         req.prefill_iterations += 1
         lp_entry = None
-        if is_final:
+        if is_final and req.recompute:
+            # recomputed: preempted before its first token or after, the
+            # request is whole again when this chunk has run
+            req.recompute = req.preempted = False
+        if is_final and replay:
+            # the cache holds prompt + fed output again: decoding resumes
+            # with the token that was pending when the request was preempted
+            req.prefill_pos = req.prompt_len
+            self._pending_tok[req.slot] = req.output_tokens[-1]
+            req.state = RequestState.DECODE
+        elif is_final:
             if self._psampling:
                 # re-pick from the returned prompt-final logits through the
                 # SAME lane transform decode uses (position 0 of the
@@ -2255,7 +2288,7 @@ class InferenceEngine:
                 req.trace_id, "req/prefill_chunk", ts=t1, start=start, end=end,
                 final=is_final,
             )
-        if is_final:
+        if is_final and not replay:
             if self.radix is not None:
                 # the prompt's full blocks now hold valid K/V: adopt them
                 # into the prefix trie (refcount+1 = the cache's reference)
@@ -2274,8 +2307,12 @@ class InferenceEngine:
         Truncation (``out_of_blocks``) is the last resort: swap disabled or
         full, or ``req`` alone in the pool with nothing left to reclaim."""
         sched = self.scheduler
+        # a model with per-slot state has no swap tier (refused at bring-up):
+        # its victim gives its blocks back and is recomputed on re-admission
+        recompute = bool(self._cache_spec.slot_state)
+        preempt = self._preempt_by_recompute if recompute else self._swap_out
         while not sched.grow_for_decode(req, tokens_ahead=self._decode_lookahead):
-            if self._swap is None:
+            if self._swap is None and not recompute:
                 # no swap tier: keep PR 4's FCFS contract — the request
                 # that failed to grow is the one truncated, never an
                 # innocent neighbor that fit its reservation
@@ -2297,7 +2334,7 @@ class InferenceEngine:
                 ):
                     self._force_finish_out_of_blocks(req, finished)
                     return
-            if not self._swap_out(victim):
+            if not preempt(victim):
                 # swap full: truncation may only roll downhill — a
                 # strictly lower-priority victim pays, equal priority
                 # keeps the requester-pays rule (no innocent neighbor
@@ -2382,7 +2419,7 @@ class InferenceEngine:
         decode_sig = None
         if _get_sanitizer() or get_active_recorder():
             args = [
-                ("kp", self._kp), ("vp", self._vp),
+                *(("cache." + name, a) for name, a in sorted(self._cache.items())),
                 ("block_tables", self._block_tables), ("pos0", pos0),
                 ("toks", toks), ("active", active),
             ]
@@ -2394,8 +2431,6 @@ class InferenceEngine:
                 ]
             elif self._spec is None:  # legacy spec round is greedy: no key/temp
                 args += [("key", self._key), ("temp", self._temp)]
-            if self._quantized:
-                args[2:2] = [("ks", self._ks), ("vs", self._vs)]
             decode_sig = tuple(
                 (name, tuple(np.shape(v)), str(getattr(v, "dtype", type(v).__name__)))
                 for name, v in args
@@ -2406,28 +2441,13 @@ class InferenceEngine:
             return
         logps = tvals = tids = None
         if self._psampling:
-            lane_args = (lanes, self._gmask, self._gtrans, self._base_key)
-            if self._quantized:
-                (self._kp, self._vp, self._ks, self._vs, next_toks,
-                 logps, tvals, tids) = self._decode_fn(
-                    self._params, self._kp, self._vp, self._ks, self._vs,
-                    self._block_tables, pos0, toks, active, *lane_args,
-                )
-            else:
-                (self._kp, self._vp, next_toks, logps, tvals,
-                 tids) = self._decode_fn(
-                    self._params, self._kp, self._vp, self._block_tables,
-                    pos0, toks, active, *lane_args,
-                )
-        elif self._quantized:
-            (self._kp, self._vp, self._ks, self._vs, next_toks,
-             self._key) = self._decode_fn(
-                self._params, self._kp, self._vp, self._ks, self._vs,
-                self._block_tables, pos0, toks, active, self._key, self._temp,
+            self._cache, next_toks, logps, tvals, tids = self._decode_fn(
+                self._params, self._cache, self._block_tables, pos0, toks, active,
+                lanes, self._gmask, self._gtrans, self._base_key,
             )
         else:
-            self._kp, self._vp, next_toks, self._key = self._decode_fn(
-                self._params, self._kp, self._vp, self._block_tables, pos0, toks,
+            self._cache, next_toks, self._key = self._decode_fn(
+                self._params, self._cache, self._block_tables, pos0, toks,
                 active, self._key, self._temp,
             )
         self._check_one_executable(decode_sig)
@@ -2465,17 +2485,10 @@ class InferenceEngine:
             if self._psampling
             else ()
         )
-        if self._quantized:
-            (self._kp, self._vp, self._ks, self._vs, tok_seq,
-             accept) = self._decode_fn(
-                self._params, self._kp, self._vp, self._ks, self._vs,
-                self._block_tables, pos0, toks, active, *lane_args,
-            )
-        else:
-            self._kp, self._vp, tok_seq, accept = self._decode_fn(
-                self._params, self._kp, self._vp, self._block_tables,
-                pos0, toks, active, *lane_args,
-            )
+        self._cache, tok_seq, accept = self._decode_fn(
+            self._params, self._cache, self._block_tables, pos0, toks, active,
+            *lane_args,
+        )
         self._check_one_executable(decode_sig)
         # the round's [num_slots, k+1] token matrix and [num_slots]
         # accepted-prefix vector stay device futures; the serve/spec_round

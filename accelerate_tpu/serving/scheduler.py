@@ -128,6 +128,11 @@ class Request:
     cow: tuple[int, int] | None = None  # (src, dst) pending device copy
     swap_plan: list[tuple[int, int]] = field(default_factory=list)
     preempted: bool = False
+    #: preempted with nothing kept (a model whose past is not all in
+    #: blocks): re-admitted like a new request, prompt and emitted tokens
+    #: prefilled again; the engine clears it, with ``preempted``, when the
+    #: last of those chunks has run
+    recompute: bool = False
     preemptions: int = 0
     #: final cost summary (device_time_s / kv_block_seconds / swap_bytes)
     #: stamped by the usage ledger when the engine processes completion;
@@ -244,14 +249,16 @@ class SlotScheduler:
         self.waiting[request.priority].append(request)
         return request
 
-    def requeue_preempted(self, request: Request) -> None:
+    def requeue_preempted(self, request: Request, recompute: bool = False) -> None:
         """A swapped-out victim goes back to the *front* of its class: it
         already waited its turn once, and its swap handles hold host DRAM
-        that should drain as soon as capacity returns."""
+        that should drain as soon as capacity returns. ``recompute``: the
+        victim kept nothing (its blocks are already given back)."""
         self.slots[request.slot] = None
         request.slot = None
         request.state = RequestState.QUEUED
         request.preempted = True
+        request.recompute = recompute
         request.preemptions += 1
         self.waiting[request.priority].appendleft(request)
 
@@ -349,7 +356,7 @@ class SlotScheduler:
             req = self.peek_head()
             if req is None:
                 break
-            if req.preempted:
+            if req.preempted and not req.recompute:
                 need = len(req.swap_plan)
                 if not self._ensure_free(need):
                     break
@@ -362,8 +369,14 @@ class SlotScheduler:
                     else RequestState.DECODE
                 )
             else:
+                # a request preempted by recomputation (no blocks kept) is
+                # admitted like a new one, with room for what it had emitted
                 total_need = max(
-                    blocks_needed(req.prompt_len + 1, self.block_size), 1
+                    blocks_needed(
+                        req.prompt_len + max(len(req.output_tokens) - 1, 0) + 1,
+                        self.block_size,
+                    ),
+                    1,
                 )
                 shared, matched, cow_src = [], 0, None
                 if self.radix is not None:
@@ -381,8 +394,9 @@ class SlotScheduler:
                     # the engine copies src -> the first private block
                     # before this request's first prefill chunk
                     req.cow = (cow_src, fresh[0])
-                self.prompt_tokens_admitted += req.prompt_len
-                self.prefix_hit_tokens += matched
+                if not req.recompute:  # a recomputed prompt was counted already
+                    self.prompt_tokens_admitted += req.prompt_len
+                    self.prefix_hit_tokens += matched
                 req.state = RequestState.PREFILL
             self.waiting[req.priority].popleft()
             req.slot = free_slots.pop(0)

@@ -68,6 +68,13 @@ SERVE_REQUESTS, PROMPT_LEN, NEW_TOKENS = 40, (32, 160), (16, 64)
 PAGED_ATOL = 2e-2
 FLASH_FWD_ATOL = 5e-2
 FLASH_GRAD_RTOL = 3e-2
+#: the state update: float32 arithmetic at ``highest`` on both sides, unit
+#: inputs, so the state agrees to float32 rounding of values near 4 and
+#: ``y`` (a sum of N of them) to N times that. The hybrid step: bf16
+#: activations on both sides, the kernels' and the plain routes' sums in
+#: another order — relative to the largest entry, as the flash gradients
+SSM_ATOL = 1e-5
+HYBRID_RTOL = 3e-2
 
 _COMPILED = re.compile(r"Finished XLA compilation of (\S+) in ([0-9.]+) sec")
 
@@ -455,10 +462,109 @@ def _kernels() -> None:
         ok &= _against_blockwise(f"flash attention %s [{b},{s},12,128] vs blockwise",
                                  flash_attention, qkv, probe)
 
+    ok &= _hybrid_check(rng)
     if len(jax.devices()) == 4:
         ok &= _ring_flash_check(rng)
     if not ok:
         sys.exit("a kernel disagrees with its reference beyond the stated bound")
+
+
+def _hybrid_check(rng) -> bool:
+    """The state-space path: ``ssm_state_update`` against its ``jnp`` twin at
+    Granite-4.0-H-Micro's widths (64 heads of 64, state 128), and the hybrid
+    paged step — a prefill chunk into one slot, then a decode step of every
+    slot with two lanes masked — with both kernels against the same step on
+    the plain routes (``lax`` paged attention at head 64, the ``jnp`` state
+    update); a masked lane keeps its state bit for bit."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from accelerate_tpu.models import granite_hybrid as gh
+    from accelerate_tpu.ops import ssm
+
+    ok = True
+    slots, h, p, n = 16, 64, 64, 128
+    state32 = jnp.asarray(rng.normal(size=(2, slots, h, p, n)), jnp.float32)
+    x = jnp.asarray(rng.normal(size=(slots, h, p)), jnp.bfloat16)
+    dt = jax.nn.softplus(jnp.asarray(rng.normal(size=(slots, h)), jnp.float32) - 4)
+    a = -jnp.exp(jnp.asarray(rng.normal(size=(h,)), jnp.float32) * 0.3)
+    b_vec, c_vec = (jnp.asarray(rng.normal(size=(slots, n)), jnp.bfloat16) for _ in range(2))
+    active = jnp.asarray(rng.random(slots) < 0.7)
+    off = ~np.asarray(active)
+    # the state as the model declares it, and as --state-dtype bf16 stores it:
+    # there both routes round the same float32 value once, so they differ by
+    # one step of bfloat16 (2**-8 of a value near 4) where its order of
+    # summation put it on the other side of a rounding edge
+    for name, dtype, bound in (("f32", jnp.float32, SSM_ATOL), ("bf16", jnp.bfloat16, 2.0 ** -6)):
+        state = state32.astype(dtype)
+        outs = {
+            impl: jax.jit(lambda st, impl=impl: ssm.ssm_state_update(
+                st, 1, x, dt, a, b_vec, c_vec, active, impl=impl))(state)
+            for impl in ("pallas", "jnp")
+        }
+        label = f"ssm_state_update [{slots},{h},{p},{n}] {name} state, pallas vs jnp"
+        ok &= _kernel_row(label + ", state", outs["pallas"][0], outs["jnp"][0], bound)
+        # the twin's y under bfloat16 is no reference on the chip: XLA drops its
+        # float32 -> bfloat16 -> float32 round trip (excess precision is allowed)
+        # and multiplies the unrounded state, 0.1 off (my chip run, PR 27); the
+        # kernel's y has to be what it STORED times C
+        want_y = outs["jnp"][1] if name == "f32" else jax.jit(lambda st: jnp.where(
+            active[:, None, None], jnp.einsum(
+                "bhpn,bn->bhp", st[1].astype(jnp.float32), c_vec.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST), 0.0))(outs["pallas"][0])
+        ok &= _kernel_row(label + ", y", outs["pallas"][1], want_y, SSM_ATOL * n)
+        kept = np.array_equal(np.asarray(outs["pallas"][0].astype(jnp.float32))[1][off],
+                              np.asarray(state.astype(jnp.float32))[1][off])
+        print("KERNEL " + json.dumps({"check": f"ssm_state_update, {name} state: masked lanes bit-identical",
+                                      "err": 0.0 if kept else 1.0, "bound": 0.0, "ok": kept}), flush=True)
+        ok &= kept
+
+    # the hybrid paged step at published widths, four layers, a small vocabulary
+    c = gh.GraniteHybridConfig(
+        vocab_size=8192, num_hidden_layers=4, layer_types=("mamba", "mamba", "attention", "mamba"))
+    params = jax.jit(lambda k: gh.init_granite_hybrid_params(k, c, jnp.bfloat16))(jax.random.PRNGKey(0))
+    spec, n_slots, blocks, chunk = gh.cache_spec(c), 4, 64, 128
+    tables = np.zeros((n_slots, 16), np.int32)
+    tables[1], tables[3] = np.arange(1, 17), np.arange(17, 33)
+    ids = rng.integers(0, c.vocab_size, size=(1, chunk)).astype(np.int32)
+    toks = rng.integers(0, c.vocab_size, size=(n_slots, 1)).astype(np.int32)
+    lanes = np.asarray([[False], [True], [False], [True]])
+
+    def run():
+        cache = {"k": jnp.zeros((1, blocks, 16, 512), jnp.bfloat16),
+                 "v": jnp.zeros((1, blocks, 16, 512), jnp.bfloat16)}
+        for name, leaf in spec.slot_state.items():
+            cache[name] = jnp.full(leaf.array_shape(n_slots), 0.5, leaf.dtype or jnp.bfloat16)
+            cache[name] = cache[name].at[:, 1].set(0)
+        step = jax.jit(lambda cache, **kw: gh.granite_hybrid_apply(c, params, paged_kv=cache, **kw))
+        pre = step(cache, input_ids=ids, block_tables=tables[1:2],
+                   cache_positions=np.zeros((1,), np.int32),
+                   paged_write_mask=np.ones((1, chunk), bool), state_slots=np.asarray([1], np.int32))
+        dec = step(pre["paged_kv"], input_ids=toks, block_tables=tables,
+                   cache_positions=np.asarray([0, chunk, 0, 0], np.int32), paged_write_mask=lanes)
+        return pre["logits"][0], dec["logits"][lanes[:, 0], 0], dec["paged_kv"]
+
+    kernels = run()
+    routes = (sys.modules["accelerate_tpu.ops.paged_attention"], "default_paged_attention_impl",
+              "lax"), (ssm, "default_ssm_impl", "jnp")
+    saved = [getattr(mod, name) for mod, name, _ in routes]
+    for mod, name, route in routes:
+        setattr(mod, name, lambda route=route: route)
+    try:
+        plain = run()
+    finally:
+        for (mod, name, _), fn in zip(routes, saved):
+            setattr(mod, name, fn)
+    label = "hybrid paged step (2048 wide, mamba mamba attention mamba, head 64), kernels vs plain"
+    ok &= _kernel_row(f"{label}: prefill chunk logits", kernels[0], plain[0], HYBRID_RTOL, relative=True)
+    ok &= _kernel_row(f"{label}: decode logits", kernels[1], plain[1], HYBRID_RTOL, relative=True)
+    ok &= _kernel_row(f"{label}: state", kernels[2]["ssm"], plain[2]["ssm"], HYBRID_RTOL, relative=True)
+    idle = np.asarray(kernels[2]["ssm"])[:, [0, 2]]
+    kept = bool((idle == 0.5).all())
+    print("KERNEL " + json.dumps({"check": "hybrid decode step: masked lanes' state bit-identical",
+                                  "err": 0.0 if kept else 1.0, "bound": 0.0, "ok": kept}), flush=True)
+    return ok and kept
 
 
 def _out_and_grads(fn):

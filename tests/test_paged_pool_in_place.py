@@ -269,3 +269,71 @@ def test_v5e_compiled_step_leaves_the_pool_in_place(one_chip, monkeypatch, kv_dt
     # (PERF.md section 7)
     limit = 2 * numel if quantized else numel // layers * 2 // 4
     assert compiled.memory_analysis().temp_size_in_bytes < limit
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_v5e_compiled_hybrid_step_leaves_the_slot_state_in_place(one_chip, monkeypatch, program):
+    """Granite-4.0-H-Micro's widths (Mamba-2: 64 heads of 64, state 128;
+    attention: 32/8 heads of **64**, the paged kernel's half-vreg lane
+    slices), six layers ``mamba x 5, attention``, 48 slots (with 64 a slot's
+    rows of one layer are as many elements as one MLP matrix), the vocabulary
+    cut to 8192: compiled for the v5e, both kernels are in the program, the
+    float32 state ``[5, 48, 64, 64, 128]`` is aliased through
+    ``ssm_state_update`` at ``(layer, slot)``, no other instruction
+    produces anything of its size or of one layer's slab of it, and the
+    temporaries are far smaller than a slab (503 MB)."""
+    import sys
+
+    from accelerate_tpu.models import granite_hybrid as gh
+
+    monkeypatch.setattr(
+        sys.modules["accelerate_tpu.ops.paged_attention"],
+        "default_paged_attention_impl", lambda: "pallas",
+    )
+    monkeypatch.setattr(sys.modules["accelerate_tpu.ops.ssm"], "default_ssm_impl", lambda: "pallas")
+    slots, blocks, bs, table, chunk = 48, 3000, 16, 256, 256
+    c = gh.GraniteHybridConfig(
+        vocab_size=8192, num_hidden_layers=6, layer_types=("mamba",) * 5 + ("attention",))
+    shaped = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: shaped(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: gh.init_granite_hybrid_params(jax.random.PRNGKey(0), c)),
+    )
+    pool = shaped((1, blocks, bs, 8 * 64), jnp.bfloat16)
+    cache = {"k": pool, "v": pool}
+    for name, leaf in gh.cache_spec(c).slot_state.items():
+        cache[name] = shaped(leaf.array_shape(slots), leaf.dtype or jnp.bfloat16)
+    assert cache["ssm"].shape == (5, 48, 64, 64, 128) and cache["ssm"].dtype == jnp.float32
+
+    def step(params, cache, tables, pos, toks, mask, state_slots=None):
+        out = gh.granite_hybrid_apply(
+            c, params, toks, paged_kv=cache, block_tables=tables,
+            cache_positions=pos, paged_write_mask=mask, state_slots=state_slots,
+        )
+        return out["paged_kv"], jnp.argmax(out["logits"][:, -1, :], -1).astype(jnp.int32)
+
+    def decode(params, cache, tables, pos, toks, mask):
+        def one(carry, _):
+            cache, toks, pos = carry
+            cache, tok = step(params, cache, tables, pos, toks, mask)
+            return (cache, tok[:, None], pos + 1), tok
+        (cache, _, _), out = jax.lax.scan(one, (cache, toks, pos), None, length=4)
+        return cache, out
+
+    b, s = (slots, 1) if program == "decode" else (1, chunk)
+    operands = [params, cache, shaped((b, table), jnp.int32), shaped((b,), jnp.int32),
+                shaped((b, s), jnp.int32), shaped((b, s), jnp.bool_)]
+    if program == "prefill":
+        operands.append(shaped((1,), jnp.int32))
+    compiled = jax.jit(decode if program == "decode" else step, donate_argnums=(1,)).lower(
+        *operands).compile()
+    text = compiled.as_text()
+    # one run of Mamba layers and one attention layer: a kernel each in the
+    # decode program; a prefill chunk scans in plain einsums (scope ssm_scan)
+    assert text.count('custom_call_target="tpu_custom_call"') == (2 if program == "decode" else 1)
+    state = 5 * 48 * 64 * 64 * 128
+    found = buffers_moved(text, [state, state // 5])
+    in_place = {"custom-call", "scatter", "fusion:scatter"}
+    assert [m for m in found["moved"] if m[1] not in in_place] == [], found["moved"]
+    assert found["unaliased"] == []
+    assert compiled.memory_analysis().temp_size_in_bytes < state * 4 // 5 // 4
