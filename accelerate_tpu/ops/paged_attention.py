@@ -18,14 +18,27 @@ straight off the block table:
 * GQA uses a **grouped-head einsum** (``[b, s, n_kv, rep, hd]`` against
   ``[b, bs, n_kv, hd]``, a reshape of the small gathered block) — repeated KV heads are never materialised;
 * positions past each row's valid prefix are masked inside the recurrence
-  (same policy as ``cached_attention``), and the Pallas kernel skips the
-  compute of fully-invalid table entries.
+  (same policy as ``cached_attention``).
 
 Two implementations behind one dispatcher
 (:func:`default_paged_attention_impl` — the Pallas kernel on TPU, the
 pure-lax ``scan``-over-blocks everywhere else; the gather-then-dense
 reference survives as the parity/bench baseline). Both run in f32
 scores/softmax like every attention in this codebase.
+
+**The Pallas kernel walks a row's own blocks.** Its grid runs over the rows
+of the call; inside a row's step a loop whose trip count is data —
+``(idx[row] + s - 1) // block_size + 1``, read from the prefetched ``idx`` —
+visits the table entries that hold a block some query of the row attends,
+and no others. So a call costs what is live: a free slot one entry, a short
+row its length, whatever ``max_blocks`` is (a grid over ``(row, entry)``
+cost every layer of every decode step 64 x 256 visits at 5 % of them live).
+The pools stay in HBM as they are stored; the kernel copies block
+``(layer, block_tables[row, j])`` into VMEM itself, a few entries ahead of
+the one its online softmax consumes (``_IN_FLIGHT``). The trip count is an
+operand, not a shape: one executable serves every occupancy. ``serving/engine.py``
+counts the same entries on the host (``stats()``
+``paged_entries_walked_total`` against ``paged_entries_table_total``).
 """
 
 from __future__ import annotations
@@ -190,19 +203,27 @@ def _paged_attention_gather(q, k_pool, v_pool, layer, block_tables, idx, k_scale
 
 
 # ---------------------------------------------------------------------------
-# Pallas TPU kernel: block-table-indexed BlockSpecs via scalar prefetch
+# Pallas TPU kernel: a grid over rows, each walking its own table entries
 # ---------------------------------------------------------------------------
 
+#: pool blocks of a row on their way from HBM while the kernel consumes one
+#: (so ``_IN_FLIGHT + 1`` VMEM buffers a pool). Chosen on the v5e (PERF.md
+#: section 6, PR 29); a constant of the kernel, not an option of its callers
+_IN_FLIGHT = 1
 
-def _pallas_kernel(bt_ref, idx_ref, layer_ref, q_ref, k_ref, v_ref, *rest,
+
+def _pallas_kernel(bt_ref, idx_ref, layer_ref, q_ref, *rest,
                    bs, n_kv, rep, hd, quantized):
-    """Grid ``(b, max_blocks)``: step ``(i, j)`` consumes row ``i``'s
-    ``j``-th table entry — the BlockSpec index maps already steered the
-    right pool block of the right layer into VMEM via the prefetched block
-    table and layer index (``layer_ref`` is read by the index maps only). Online
-    softmax state lives in VMEM scratch across the ``j`` steps (the last
-    grid axis iterates fastest); entries wholly past the row's valid
-    prefix skip their compute.
+    """Grid ``(b,)``: step ``i`` is row ``i``, and a loop inside it walks
+    the row's own table entries ``0 .. n_i - 1``, ``n_i = (idx[i] + s - 1)
+    // bs + 1`` (at most the table's width) — a trip count read from the
+    prefetched ``idx``, so a dead slot (``idx`` 0) costs one entry and a
+    short row costs its length, whatever the table could hold. The pools
+    (and a quantized pool's scales) stay in HBM, whole; the kernel copies
+    block ``(layer_ref[0], bt_ref[i, j])`` into one of its VMEM buffers
+    itself, ``_IN_FLIGHT`` entries ahead of the one it consumes. The online
+    softmax state lives in VMEM scratch across the loop; entries are
+    consumed in table order, one block a softmax step.
 
     Every operand is 2-D inside the kernel: heads are folded into the lane
     dimension (``[.., n*hd]`` — how the pool is stored; a reshape of the
@@ -211,34 +232,59 @@ def _pallas_kernel(bt_ref, idx_ref, layer_ref, q_ref, k_ref, v_ref, *rest,
     a head axis kept second-minor (12 rows padded to 16) and a 4-D
     batched-in-the-middle einsum cost a prefill chunk 119 MB of VMEM."""
     import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    if quantized:
-        ks_ref, vs_ref, out_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        out_ref, m_ref, l_ref, acc_ref = rest
+    n_pools = 4 if quantized else 2
+    pools, (out_ref, *rest) = rest[:n_pools], rest[n_pools:]
+    bufs, (sems, m_ref, l_ref, acc_ref) = rest[:n_pools], rest[n_pools:]
+    k_buf, v_buf, *scale_bufs = bufs
     i = pl.program_id(0)
-    j = pl.program_id(1)
+    mb = bt_ref.shape[1]
     s = q_ref.shape[1]
+    depth = _IN_FLIGHT + 1
+    # the pools at this call's layer; the scales come in as the one layer's
+    # already (``_scale_blocks``)
+    layers = [p.at[layer_ref[0]] for p in pools[:2]] + [p.at[0] for p in pools[2:]]
+
+    def copies(j):
+        """The DMAs of the row's ``j``-th entry, one a pool operand."""
+        slot, blk = j % depth, bt_ref[i, j]
+        return [
+            pltpu.make_async_copy(pool.at[blk], buf.at[slot], sems.at[a, slot])
+            for a, (pool, buf) in enumerate(zip(layers, bufs))
+        ]
+
     first = idx_ref[i]
+    live = jnp.minimum((first + s - 1) // bs + 1, mb)   # the row's own entries
+    for j in range(min(_IN_FLIGHT, mb)):
+        @pl.when(j < live)
+        def _first():
+            for dma in copies(j):
+                dma.start()
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(j * bs <= first + s - 1)             # any position valid?
-    def _step():
+    def _step(j, carry):
+        @pl.when(j + _IN_FLIGHT < live)
+        def _ahead():
+            for dma in copies(j + _IN_FLIGHT):
+                dma.start()
+
+        for dma in copies(j):
+            dma.wait()
+        slot = j % depth
         q_pos = first + jax.lax.broadcasted_iota(jnp.int32, (s, bs), 0)
         k_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (s, bs), 1)
         valid = k_pos <= q_pos
         for n in range(n_kv):
             kv_lanes = slice(n * hd, (n + 1) * hd)
-            kb = k_ref[0, :, kv_lanes].astype(jnp.float32)   # [bs, hd]
-            vb = v_ref[0, :, kv_lanes].astype(jnp.float32)
+            kb = k_buf[slot, :, kv_lanes].astype(jnp.float32)   # [bs, hd]
+            vb = v_buf[slot, :, kv_lanes].astype(jnp.float32)
             if quantized:
-                kb = kb * ks_ref[0, :, n:n + 1]
-                vb = vb * vs_ref[0, :, n:n + 1]
+                kb = kb * scale_bufs[0][slot, :, n:n + 1]
+                vb = vb * scale_bufs[1][slot, :, n:n + 1]
             for h in range(n * rep, (n + 1) * rep):
                 lanes = slice(h * hd, (h + 1) * hd)
                 qh = q_ref[0, :, lanes].astype(jnp.float32) / np.sqrt(float(hd))
@@ -258,13 +304,25 @@ def _pallas_kernel(bt_ref, idx_ref, layer_ref, q_ref, k_ref, v_ref, *rest,
                 acc_ref[:, lanes] = acc_ref[:, lanes] * alpha + jnp.dot(
                     p, vb, preferred_element_type=jnp.float32
                 )
+        return carry
 
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _finish():
-        for h in range(n_kv * rep):
-            lanes = slice(h * hd, (h + 1) * hd)
-            out = acc_ref[:, lanes] / jnp.maximum(l_ref[h], 1e-30)
-            out_ref[0, :, lanes] = out.astype(out_ref.dtype)
+    jax.lax.fori_loop(0, live, _step, 0)
+
+    for h in range(n_kv * rep):
+        lanes = slice(h * hd, (h + 1) * hd)
+        out = acc_ref[:, lanes] / jnp.maximum(l_ref[h], 1e-30)
+        out_ref[0, :, lanes] = out.astype(out_ref.dtype)
+
+
+def _scale_blocks(scale, layer):
+    """Layer ``layer`` of a quantized pool's scales ``[layers, num_blocks,
+    bs, n_kv]`` with the kv heads padded to a vreg's 128 lanes, ``[1,
+    num_blocks, bs, 128]``: Mosaic copies no slice out of HBM whose minor
+    dimension is narrower than that, and ``n_kv`` is. XLA keeps the scales
+    in that padded layout already (PERF.md section 7), so this is one pass
+    over the layer's; a pool that stored them lane-dense would make it a view."""
+    blocks = jax.lax.dynamic_index_in_dim(scale, layer, 0)
+    return jnp.pad(blocks, [(0, 0)] * 3 + [(0, -scale.shape[-1] % 128)])
 
 
 def _paged_attention_pallas(q, k_pool, v_pool, layer, block_tables, idx,
@@ -275,33 +333,26 @@ def _paged_attention_pallas(q, k_pool, v_pool, layer, block_tables, idx,
     b, s, nh, hd = q.shape
     bs, width = k_pool.shape[2], k_pool.shape[3]
     n_kv = width // hd
-    mb = block_tables.shape[1]
     quantized = k_scale is not None
 
-    def row(i, j, bt, ix, ly):
+    def row(i, bt, ix, ly):
         return (i, 0, 0)
 
-    def block(i, j, bt, ix, ly):
-        return (ly[0], bt[i, j], 0, 0)
-
-    # the pool operand is the stored pool, whole: its BlockSpec squeezes the
-    # layer dimension and the index map picks (layer, block), so the kernel
-    # body sees the same [1, bs, n_kv*hd] block as ever and no slab exists
-    in_specs = [
-        pl.BlockSpec((1, s, nh * hd), row),
-        pl.BlockSpec((None, 1, bs, width), block),
-        pl.BlockSpec((None, 1, bs, width), block),
-    ]
-    args = [q.reshape(b, s, nh * hd), k_pool, v_pool]
+    # the pool operands are the stored pools, whole and left in HBM: the
+    # kernel addresses them at (layer, block) itself, so no slab exists
+    pools = [k_pool, v_pool]
     if quantized:
-        in_specs += [pl.BlockSpec((None, 1, bs, n_kv), block)] * 2
-        args += [k_scale, v_scale]
+        pools += [_scale_blocks(k_scale, layer), _scale_blocks(v_scale, layer)]
+    buffers = _IN_FLIGHT + 1
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # block_tables + idx + layer steer the index maps
-        grid=(b, mb),
-        in_specs=in_specs,
+        num_scalar_prefetch=3,  # block_tables + idx + layer steer the walk
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, s, nh * hd), row)]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
         out_specs=pl.BlockSpec((1, s, nh * hd), row),
-        scratch_shapes=[
+        scratch_shapes=[pltpu.VMEM((buffers, *p.shape[2:]), p.dtype) for p in pools]
+        + [
+            pltpu.SemaphoreType.DMA((len(pools), buffers)),
             pltpu.VMEM((nh, s, 1), jnp.float32),
             pltpu.VMEM((nh, s, 1), jnp.float32),
             pltpu.VMEM((s, nh * hd), jnp.float32),
@@ -314,16 +365,15 @@ def _paged_attention_pallas(q, k_pool, v_pool, layer, block_tables, idx,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, s, nh * hd), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
-        ),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
         name="paged_attention",
     )(
         jnp.asarray(block_tables, jnp.int32),
         jnp.asarray(idx, jnp.int32).reshape(b),
         jnp.asarray(layer, jnp.int32).reshape(1),
-        *args,
+        q.reshape(b, s, nh * hd),
+        *pools,
     )
     return out.reshape(b, s, nh, hd)
 

@@ -672,6 +672,12 @@ class InferenceEngine:
         self._ttft_queue_sum_s = 0.0
         self._ttft_own_prefill_sum_s = 0.0
         self._ttft_prefill_iterations_sum = 0
+        # what the paged kernel's walk follows (monotone totals, counted at
+        # dispatch from the rows' lengths): the table entries that hold a
+        # block some query of the call attends, and the entries the tables
+        # have — their ratio is the share of a (row, entry) grid that is live
+        self._paged_entries_walked = 0
+        self._paged_entries_table = 0
         # static HBM model for the hbm watermark fallback: params + the
         # paged pools (+ scales), the same inventory the PR 8 preflight
         # prices — used verbatim when the backend has no memory_stats()
@@ -1531,6 +1537,7 @@ class InferenceEngine:
         self._first_tokens_total = 0
         self._ttft_sum_s = self._ttft_queue_sum_s = self._ttft_own_prefill_sum_s = 0.0
         self._ttft_prefill_iterations_sum = 0
+        self._paged_entries_walked = self._paged_entries_table = 0
         # hit accounting restarts with the measurement window; the trie and
         # its cached blocks deliberately stay warm (steady-state behaviour
         # is what a warmed bench leg measures)
@@ -1696,6 +1703,11 @@ class InferenceEngine:
             "ttft_queue_sum_s": self._ttft_queue_sum_s,
             "ttft_own_prefill_sum_s": self._ttft_own_prefill_sum_s,
             "ttft_prefill_iterations_sum": self._ttft_prefill_iterations_sum,
+            # paged attention's work: table entries walked (each row's own,
+            # ceil of its length over the block size, dead slots one) against
+            # the entries its tables hold, both x the layers that were run
+            "paged_entries_walked_total": self._paged_entries_walked,
+            "paged_entries_table_total": self._paged_entries_table,
         }
         out.update(self._spec_stats())
         out.update(self._sampling_stats())
@@ -2225,6 +2237,17 @@ class InferenceEngine:
         row[:] = 0
         row[: len(req.blocks)] = req.blocks
 
+    def _count_paged_entries(self, first, queries: int, layers: int) -> None:
+        """Book one dispatch's paged-attention calls: ``first`` holds the
+        first query's cache position of every row of every step (any
+        shape), each row asks ``queries`` positions, ``layers`` layers run
+        it. A row walks the entries up to its last query's block — the trip
+        count ``ops/paged_attention.py`` reads from the same positions."""
+        last = np.asarray(first, np.int64) + queries - 1
+        walked = np.minimum(last // self.config.block_size + 1, self._mb)
+        self._paged_entries_walked += int(walked.sum()) * layers
+        self._paged_entries_table += walked.size * self._mb * layers
+
     def _prefill_one_chunk(self, req: Request, finished: list[Request]) -> None:
         """One chunk of one prompt. Its two clock reads are the request's
         own-prefill stamps: the usage ledger's prefill accrual, the
@@ -2250,6 +2273,7 @@ class InferenceEngine:
         is_final = end == total
         last_idx = np.int32((total - 1) - start if is_final else 0)
 
+        self._count_paged_entries([start], c, self._cache_spec.paged_layers)
         self._cache, tok, _logits, self._key = self._prefill_fn(
             self._params, self._cache,
             self._block_tables[req.slot : req.slot + 1],
@@ -2439,6 +2463,9 @@ class InferenceEngine:
         if self._spec is not None:
             self._spec_decode_dispatch(pos0, toks, active, lanes, live, decode_sig)
             return
+        self._count_paged_entries(
+            pos0 + np.arange(cfg.decode_burst)[:, None], 1, self._cache_spec.paged_layers
+        )
         logps = tvals = tids = None
         if self._psampling:
             self._cache, next_toks, logps, tvals, tids = self._decode_fn(
@@ -2485,6 +2512,11 @@ class InferenceEngine:
             if self._psampling
             else ()
         )
+        # the draft's k single-query steps through its own layers, then the
+        # one verify forward of k + 1 queries through all of them
+        k = self.config.spec_k
+        self._count_paged_entries(pos0 + np.arange(k)[:, None], 1, self._spec.layers)
+        self._count_paged_entries(pos0, k + 1, self._cache_spec.paged_layers)
         self._cache, tok_seq, accept = self._decode_fn(
             self._params, self._cache, self._block_tables, pos0, toks, active,
             *lane_args,

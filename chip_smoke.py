@@ -396,10 +396,13 @@ def _kernel_row(check: str, got, want, bound: float, relative: bool = False) -> 
     return ok
 
 
-def _paged_case(rng, b, s, nh, hd, bs, mb, store):
+def _paged_case(rng, b, s, nh, hd, bs, mb, store, n_kv=None, live=None, deepest=None):
     """Two-layer stacked pools whose layer 1 is written through real block
     tables (quantize-on-scatter for int8/fp8), rows at different depths,
-    the last query at each row's end."""
+    the last query at each row's end. With ``live``, only that many rows
+    hold a context (between ``s`` and ``deepest`` positions, the first of
+    them ``deepest``); the others are free slots as the engine dispatches
+    them: position 0, every table entry the null block."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -407,23 +410,26 @@ def _paged_case(rng, b, s, nh, hd, bs, mb, store):
     from accelerate_tpu.ops.layers import write_paged_kv
 
     dtype, quantized = kv_storage_dtype(store)
+    n_kv, live, deepest = n_kv or nh, b if live is None else live, deepest or mb * bs
     nb = b * mb + 1
     tables = (1 + np.arange(b * mb, dtype=np.int32)).reshape(b, mb)
-    depth = rng.integers(s, mb * bs + 1, size=b).astype(np.int32)
-    depth[0] = mb * bs  # one row fills its whole table
-    pools = [jnp.zeros((2, nb, bs, nh * hd), dtype)] * 2
-    scales = [jnp.ones((2, nb, bs, nh), jnp.float32)] * 2 if quantized else []
+    depth = rng.integers(s, deepest + 1, size=b).astype(np.int32)
+    depth[0] = deepest  # one row fills its whole table, or the cell's longest context
+    depth[live:] = 0
+    tables[live:] = 0
+    pools = [jnp.zeros((2, nb, bs, n_kv * hd), dtype)] * 2
+    scales = [jnp.ones((2, nb, bs, n_kv), jnp.float32)] * 2 if quantized else []
     k, v = (
-        jnp.asarray(rng.normal(size=(b, mb * bs, nh, hd)), jnp.bfloat16)
+        jnp.asarray(rng.normal(size=(b, deepest, n_kv, hd)), jnp.bfloat16)
         for _ in range(2)
     )
-    positions = np.broadcast_to(np.arange(mb * bs, dtype=np.int32), (b, mb * bs))
+    positions = np.broadcast_to(np.arange(deepest, dtype=np.int32), (b, deepest))
     written = write_paged_kv(
         *pools, 1, k, v, tables, positions, write_mask=positions < depth[:, None],
         **(dict(k_scale=scales[0], v_scale=scales[1]) if quantized else {}),
     )
     q = jnp.asarray(rng.normal(size=(b, s, nh, hd)), jnp.bfloat16)
-    return q, written[:2], tables, depth - s, written[2:]
+    return q, written[:2], tables, np.maximum(depth - s, 0), written[2:]
 
 
 def _kernels() -> None:
@@ -439,21 +445,30 @@ def _kernels() -> None:
     rng = np.random.default_rng(0)
     ok = True
 
-    # paged attention at the flagship's decode and prefill-chunk shapes
-    for store in ("bf16", "int8", "fp8"):
-        for b, s in ((16, 1), (1, 128)):
-            q, pools, tables, idx, scales = _paged_case(
-                rng, b, s, nh=12, hd=128, bs=16, mb=32, store=store)
-            outs = {
-                impl: jax.jit(
-                    lambda q, kp, vp, *sc, impl=impl: paged_attention(
-                        q, kp, vp, 1, tables, idx, *sc, impl=impl)
-                )(q, *pools, *scales)
-                for impl in ("pallas", "gather")
-            }
-            ok &= _kernel_row(
-                f"paged attention [{b},{s},12,128] block 16, {store} pool, "
-                "pallas vs gather", outs["pallas"], outs["gather"], PAGED_ATOL)
+    # paged attention at the flagship's decode and prefill-chunk shapes, and
+    # at the benchmark's two chat cells' decode shapes as their traffic fills
+    # them: 64 slots x 256 table entries, GQA 32 / 8, 10 rows live to 1,280
+    # positions at head 128 (Mistral) and 30 to 640 at head 64 (the hybrid)
+    cases = [
+        (store, dict(b=b, s=s, nh=12, hd=128, mb=32))
+        for store in ("bf16", "int8", "fp8") for b, s in ((16, 1), (1, 128))
+    ] + [
+        ("bf16", dict(b=64, s=1, nh=32, hd=128, mb=256, n_kv=8, live=10, deepest=1280)),
+        ("bf16", dict(b=64, s=1, nh=32, hd=64, mb=256, n_kv=8, live=30, deepest=640)),
+    ]
+    for store, shape in cases:
+        q, pools, tables, idx, scales = _paged_case(rng, bs=16, store=store, **shape)
+        outs = {
+            impl: jax.jit(
+                lambda q, kp, vp, *sc, impl=impl: paged_attention(
+                    q, kp, vp, 1, tables, idx, *sc, impl=impl)
+            )(q, *pools, *scales)
+            for impl in ("pallas", "gather")
+        }
+        live = f", {shape['live']} rows live" if "live" in shape else ""
+        ok &= _kernel_row(
+            f"paged attention {list(q.shape)} block 16, {store} pool{live}, "
+            "pallas vs gather", outs["pallas"], outs["gather"], PAGED_ATOL)
 
     # flash attention forward and gradients at the train shapes
     for b, s in ((8, 1024), (1, 8192)):

@@ -135,6 +135,92 @@ def test_pallas_kernel_matches_gather_reference(layer):
                                    rtol=1e-5, atol=1e-5)
 
 
+#: the row walk's geometry: a table of four entries of sixteen tokens, so a
+#: row's context ends inside the first block, on its last row, on the
+#: first row of the next, or fills the table; ``None`` is a free slot as the
+#: engine dispatches it (position 0, every entry the null block)
+RAGGED = (1, 15, 16, 17, 64, None)
+WALK_BS, WALK_MB = 16, 4
+
+
+def _ragged_case(rng, contexts, s, hd, store, tail):
+    """Random stacked pools (two kv heads, GQA x 2) and one block table a
+    row: a row whose last query sits at position ``context - 1`` holds
+    blocks for entries ``0 .. (context - 1) // 16``. Every later entry —
+    which no query of the row attends — points at ``tail``: the null block
+    0, or block 1, which is filled with NaN (its scales, for an int8 pool)."""
+    n_kv, nh, nb = 2, 4, 2 + len(contexts) * WALK_MB
+    shape = (LAYERS, nb, WALK_BS, n_kv * hd)
+    scales = []
+    if store == "int8":
+        pools = [jnp.asarray(rng.integers(-127, 128, size=shape), jnp.int8) for _ in range(2)]
+        scales = [jnp.asarray(rng.random(shape[:-1] + (n_kv,)) * 0.02 + 0.005, jnp.float32)
+                  for _ in range(2)]
+        scales = [x.at[:, 1].set(jnp.nan) for x in scales]
+    else:
+        pools = [jnp.asarray(rng.normal(size=shape), jnp.float32).at[:, 1].set(jnp.nan)
+                 for _ in range(2)]
+    bt = np.full((len(contexts), WALK_MB), tail, np.int32)
+    idx = np.zeros((len(contexts),), np.int32)
+    used = iter(range(2, nb))
+    for i, context in enumerate(contexts):
+        if context is None:
+            bt[i, 0] = 0        # the walk visits entry 0 of a free slot
+            continue
+        idx[i] = context - s
+        for j in range((context - 1) // WALK_BS + 1):
+            bt[i, j] = next(used)
+    q = jnp.asarray(rng.normal(size=(len(contexts), s, nh, hd)), jnp.float32)
+    return q, pools, bt, idx, scales
+
+
+def _walk_shapes():
+    """(b 8, s 1): the ragged rows of one decode call, a free slot among
+    them; (b 1, s 32): a chunk whose last query ends each ragged context
+    that a chunk of 32 can end (a first chunk: 32; a later one: 33, 47, 48,
+    49; the table's last: 64)."""
+    yield pytest.param(1, RAGGED + (33, 47), id="b8-s1")
+    for context in (32, 33, 47, 48, 49, 64):
+        yield pytest.param(32, (context,), id=f"b1-s32-ends-{context}")
+
+
+@pytest.mark.parametrize("store", [None, "int8"])
+@pytest.mark.parametrize("hd", [64, 128])
+@pytest.mark.parametrize("s, contexts", _walk_shapes())
+def test_pallas_row_walk_matches_gather_on_ragged_rows(s, contexts, hd, store):
+    """The kernel's loop over a row's own table entries (its trip count read
+    from ``idx``) against the gather reference, at both head sizes the
+    benchmark's cells run and both kinds of pool: a context that ends
+    anywhere in a block, a full table, and a free slot."""
+    rng = np.random.default_rng(29)
+    q, pools, bt, idx, scales = _ragged_case(rng, contexts, s, hd, store, tail=0)
+    ref = paged_attention(q, *pools, 1, bt, idx, *scales, impl="gather")
+    out = paged_attention(q, *pools, 1, bt, idx, *scales, impl="pallas", interpret=True)
+    tol = 1e-5 if store is None else 1e-4
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=tol, atol=tol)
+    assert np.isfinite(np.asarray(out)).all()
+
+
+@pytest.mark.parametrize("store", [None, "int8"])
+@pytest.mark.parametrize("s, contexts", [
+    pytest.param(1, RAGGED[:4] + (None, 33), id="b6-s1"),
+    pytest.param(32, (33,), id="b1-s32"),
+])
+def test_pallas_row_walk_stops_where_the_row_does(s, contexts, store):
+    """Poisoned tail: every table entry past a row's last live one points at
+    a block of NaN, and the output is bit-equal to the run where they point
+    at the null block — the walk never touches what no query attends."""
+    outs = []
+    for tail in (0, 1):
+        q, pools, bt, idx, scales = _ragged_case(
+            np.random.default_rng(7), contexts, s, 64, store, tail)
+        outs.append(np.asarray(paged_attention(
+            q, *pools, 2, bt, idx, *scales, impl="pallas", interpret=True)))
+    assert (bt == 1).any(), "no entry was poisoned"
+    assert np.isfinite(outs[1]).all()
+    np.testing.assert_array_equal(outs[1], outs[0])
+
+
 @pytest.mark.parametrize("name", [None, "int8"])
 def test_three_routes_agree_on_a_traced_layer_of_the_stacked_pool(name):
     """Pallas-interpret against ``lax`` against ``gather`` with the layer

@@ -467,6 +467,73 @@ def test_engine_kv_stats_and_capacity_math(tiny_model):
         )
 
 
+@pytest.mark.parametrize("spec_k", [0, 2])
+def test_paged_entries_counters_follow_the_rows_lengths(tiny_model, spec_k):
+    """``paged_entries_walked_total`` / ``paged_entries_table_total`` move, at
+    every dispatch, by what the rows' lengths give: a prefill chunk walks its
+    one row up to the chunk's last position, a decode burst walks each live
+    row up to its context at each step and one entry of every free slot, all
+    times the model's layers — while contexts grow over block edges across
+    several dispatches of the ONE decode executable. A speculative round is
+    ``spec_k`` single-query steps of the one-layer draft and one verify
+    forward of ``spec_k + 1`` queries through both layers."""
+    bs, chunk, burst, slots, layers = 8, 8, 2, 3, 2
+    engine = InferenceEngine(tiny_model, EngineConfig(
+        num_slots=slots, block_size=bs, max_seq_len=64, prefill_chunk=chunk,
+        decode_burst=burst, prefix_cache=False, spec_k=spec_k, draft="early_exit:1",
+    ))
+    mb = engine.config.blocks_per_slot
+    assert mb == 8 and engine.stats()["paged_entries_table_total"] == 0
+
+    # what was dispatched, read off the executables' own operands
+    seen = {"prefill": [], "decode": []}
+
+    def recorded(kind, fn, position_arg):
+        def call(*args):
+            seen[kind].append(np.array(args[position_arg]))
+            return fn(*args)
+        return call
+
+    engine._prefill_fn = recorded("prefill", engine._prefill_fn, 3)
+    engine._decode_fn = recorded("decode", engine._decode_fn, 3)
+    rng = np.random.default_rng(3)
+    requests = [
+        engine.add_request(rng.integers(0, 64, size=n).astype(np.int32), new)
+        for n, new in ((11, 9), (5, 14))
+    ]
+    engine.run_until_idle(max_iterations=500)
+    assert [len(r.output_tokens) for r in requests] == [9, 14]
+
+    # the prompts' chunks: 11 tokens start at 0 and 8, 5 tokens at 0
+    assert sorted(int(p[0]) for p in seen["prefill"]) == [0, 0, 8]
+    walked = layers * sum((int(p[0]) + chunk - 1) // bs + 1 for p in seen["prefill"])
+    table = layers * len(seen["prefill"]) * mb
+    # several decode dispatches, a request's context growing by the burst
+    # from its prompt's length, over a block's edge (11 -> 17: entries 2 -> 3)
+    firsts = [[int(pos0[slot]) for pos0 in seen["decode"] if pos0[slot]]
+              for slot in range(slots)]
+    if not spec_k:
+        assert sorted(firsts) == [[], [5, 7, 9, 11, 13, 15, 17], [11, 13, 15, 17]]
+    assert all(len(f) > 2 and f == sorted(set(f)) for f in firsts if f)
+    # (single-query steps, layers they run through), then (queries, layers)
+    # of one more call from the same positions
+    calls = [(spec_k, 1, 1), (1, spec_k + 1, layers)] if spec_k else [(burst, 1, layers)]
+    for pos0 in seen["decode"]:
+        assert pos0.shape == (slots,)
+        for steps, queries, depth in calls:
+            for step in range(steps):
+                walked += depth * sum(          # a free slot: 1
+                    int(p + step + queries - 1) // bs + 1 for p in pos0)
+            table += depth * steps * slots * mb
+    stats = engine.stats()
+    assert stats["decode_compiles"] == 1 and stats["prefill_compiles"] == 1
+    assert stats["paged_entries_walked_total"] == walked
+    assert stats["paged_entries_table_total"] == table
+    assert 0 < walked < table
+    engine.reset_stats()
+    assert engine.stats()["paged_entries_walked_total"] == 0
+
+
 def test_swap_pool_quantized_scales_byte_exact():
     """A quantized SwapPool round-trips payload AND f32 scale rows
     byte-exactly (a quantized block without its exact scales is garbage),
