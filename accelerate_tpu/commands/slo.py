@@ -9,8 +9,8 @@ straight into ``trace tail``/``trace merge``), and the supervisor's
 
 Given a dir that is itself a traced run (it has a ``WORKLOAD.json``
 manifest, or any trails at all) the scorecard has one scenario row; given
-a suite dir whose immediate children are traced runs (``bench.py fleet``
-lays scenarios out this way), one row per child. ``--json`` emits the
+a suite dir whose immediate children are traced runs (one scenario
+each), one row per child. ``--json`` emits the
 same scorecard machine-readably — the smoke pins that the two agree.
 
 Pure file reads, no jax — like ``monitor``, it runs anywhere the logging
@@ -87,7 +87,7 @@ def scorecard_for_run(logging_dir: str) -> dict:
 
 def build_report(logging_dir: str) -> dict:
     """The full scorecard: the dir itself when it is a traced run, else
-    every immediate child that is one (a ``bench.py fleet`` suite dir)."""
+    every immediate child that is one (a suite dir)."""
     from ..serving.workload import WORKLOAD_FILENAME
 
     def is_run(d: str) -> bool:
@@ -206,7 +206,7 @@ def add_parser(subparsers):
     report.add_argument(
         "logging_dir",
         help="a traced run's logging dir, or a suite dir whose children are "
-        "traced runs (bench.py fleet layout)",
+        "traced runs",
     )
     report.add_argument("--json", action="store_true",
                         help="machine-readable scorecard instead of the table")

@@ -98,7 +98,7 @@ def report_for_run(logging_dir: str) -> dict:
 def build_report(logging_dir: str, by: str = "tenant") -> dict:
     """The full report: the dir itself when it is a traced run, plus every
     immediate child that is one — covering a plain ``serve`` run, a
-    ``bench.py fleet`` suite dir, and a routed fleet's layout (router
+    suite dir of traced runs, and a routed fleet's layout (router
     trail at the root, one telemetry trail per ``replica_<i>/`` child).
     ``pass`` requires every run with a ledger snapshot to conserve both
     resources."""

@@ -242,7 +242,7 @@ def collect_status(logging_dir: str, now: float | None = None) -> dict[str, Any]
             "spec_drafted_tokens": last_step.get("spec_drafted_tokens"),
             "spec_accepted_tokens": last_step.get("spec_accepted_tokens"),
             # per-slot sampling + constrained decoding (cumulative step-row
-            # counters — absent on a per_slot_sampling=False engine)
+            # counters)
             "sampled_tokens_greedy": last_step.get("sampled_tokens_greedy"),
             "sampled_tokens_sample": last_step.get("sampled_tokens_sample"),
             "grammar_masked_steps": last_step.get("grammar_masked_steps"),

@@ -34,8 +34,8 @@ P = PartitionSpec
 #: where compiled programs persist when ``JAX_COMPILATION_CACHE_DIR`` names
 #: no place: one fixed path under the checkout, derived from this file's
 #: location and never from ``tempfile``, a pid or a time — so every process
-#: of a run (``launch`` children, ``serve`` replicas, ``bench.py`` modes,
-#: ``chip_smoke.py`` phases) reads what the others compiled without being
+#: of a run (``launch`` children, ``serve`` replicas, ``chip_smoke.py``
+#: phases) reads what the others compiled without being
 #: told, and a later run finds it again (the path is part of the cache key)
 COMPILE_CACHE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".compile_cache"

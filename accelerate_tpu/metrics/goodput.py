@@ -26,9 +26,8 @@ carries a structural invariant the tests assert:
 
     sum(buckets) == elapsed wall-clock, exactly.
 
-Consumed three ways: ``accelerate-tpu monitor``'s goodput panel, the
-sidecar exporter's ``accelerate_goodput_*`` gauges, and ``bench.py``'s
-``goodput_pct`` row.
+Consumed two ways: ``accelerate-tpu monitor``'s goodput panel and the
+sidecar exporter's ``accelerate_goodput_*`` gauges.
 """
 
 from __future__ import annotations

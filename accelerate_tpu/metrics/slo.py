@@ -149,7 +149,7 @@ class SloEngine:
     then ask for the verdict (:meth:`evaluate`) or the full per-objective
     scorecard (:meth:`report`). When nothing is armed every ``observe_*``
     is a single attribute-check no-op — the disabled path costs one
-    ``if`` (the bench's ``slo_overhead_pct`` row pins this).
+    ``if``.
 
     Args:
         objectives: explicit objective table (tests inject synthetic

@@ -71,11 +71,11 @@ class LlamaConfig:
 
     @classmethod
     def flagship_700m(cls, max_position_embeddings: int = 1024, remat: bool | str = False):
-        """The ~700M bench flagship slice (hidden 1536, 12 heads × 128,
+        """The ~700M flagship slice (hidden 1536, 12 heads × 128,
         ff 4h, 16 layers) — the largest credible-aspect-ratio shape whose
-        fp32 adam state fits one v5e chip (sweep: benchmarks/sweep_mfu.py).
-        Single source of truth for bench.py, benchmarks/serve_bench.py and
-        the serve CLI's ``--preset flagship`` so they measure one model."""
+        fp32 adam state fits one v5e chip. Single source of truth for
+        ``chip_smoke.py``, ``benchmarks/serve_bench.py`` and the serve CLI's
+        ``--preset flagship`` so they run one model."""
         return cls(
             vocab_size=32000,
             hidden_size=1536,
@@ -493,9 +493,8 @@ def llama_early_exit_apply(config: LlamaConfig, draft_layers: int):
     """Early-exit draft for speculative decoding: an apply fn running only
     the target's first ``draft_layers`` transformer blocks, closed with the
     target's own final norm + head — the cheapest draft that shares the
-    target's representation space (the bench ``spec`` mode's construction,
-    here as a reusable factory the serving engine arms via
-    ``EngineConfig(draft="early_exit:N")``).
+    target's representation space, as a factory the serving engine arms via
+    ``EngineConfig(draft="early_exit:N")``.
 
     The returned fn takes the FULL model's params and slices the stacked
     layer leaves **in-trace** (``a[:draft_layers]``), so no persistent

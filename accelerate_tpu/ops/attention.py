@@ -37,8 +37,7 @@ class AttentionContext:
     head_axis: str = "tp"
     impl: Literal["auto", "flash", "blockwise", "reference"] = "auto"
     #: flash-kernel tile sizes; None = auto (512/1024 at short seq, a
-    #: 1024-row q tile from seq 2048 up — measured +2% train throughput at
-    #: seq 2048 on v5e, benchmarks/ablate_blocks.py). Explicit values win.
+    #: 1024-row q tile from seq 2048 up). Explicit values win.
     block_q: int | None = None
     block_kv: int | None = None
     #: session default for the GPipe microbatch count (0 = auto), carried
@@ -99,10 +98,10 @@ def resolve_flash_blocks(seq_len: int, ctx: AttentionContext) -> tuple[int, int]
     """Effective (block_q, block_kv) for the flash kernel: the context's
     explicit values win; auto picks 512 q-rows below seq 2048 and 1024
     from there (the deeper grid amortises the online-softmax bookkeeping
-    once there are enough kv blocks per q tile). Confirmed optimal for the
-    flagship d=128 head at seq 2048/4096 by the round-5 sweep
-    (benchmarks/ablate_blocks.py): every larger tile (1024x2048, 2048x*)
-    exceeds Mosaic's scoped VMEM at d=128, and 512x1024 is ~1-2% slower."""
+    once there are enough kv blocks per q tile). Every larger tile
+    (1024x2048, 2048x*) exceeds Mosaic's scoped VMEM at d=128; the choice
+    among those that fit predates the ledger and is not one of the
+    benchmark's measurements (the train cell runs 1024x1024 at seq 4096)."""
     block_q = ctx.block_q if ctx.block_q is not None else (1024 if seq_len >= 2048 else 512)
     block_kv = ctx.block_kv if ctx.block_kv is not None else 1024
     return block_q, block_kv

@@ -32,10 +32,10 @@ vLLM-style block-paged cache):
   the residual sync the host could not hide. Dispatch *i+1* still happens
   strictly after harvest *i*, so output is token-identical to the
   synchronous loop (``async_dispatch=False`` / ``serve --sync-engine``);
-* **per-slot sampling + constrained decoding** (``per_slot_sampling``,
-  the default): temperature / top-k / top-p / repetition penalty / seed /
-  grammar-DFA state ride as fixed-shape *lane inputs* of the same ONE
-  decode executable (:mod:`.sampling`, :mod:`.grammar`) — per-request
+* **per-slot sampling + constrained decoding**: temperature / top-k /
+  top-p / repetition penalty / seed / grammar-DFA state ride as
+  fixed-shape *lane inputs* of the same ONE decode executable
+  (:mod:`.sampling`, :mod:`.grammar`) — per-request
   variation never recompiles, greedy slots take a ``lax.cond`` fast path
   that is bit-identical argmax, and the spec verify round accepts sampled
   slots by rejection sampling.
@@ -68,7 +68,6 @@ from ..diagnostics.tracing import (
     trace_span,
     valid_trace_id,
 )
-from ..generation import _pick_traced
 from ..metrics.ingest import observe_flight
 from ..metrics.registry import get_active_registry
 from ..models.cache import cache_spec_of
@@ -213,7 +212,7 @@ class EngineConfig:
     #: (accept draft token with prob min(1, p_target/p_draft), resample
     #: the clamped residual otherwise) while greedy slots keep the exact
     #: longest-agreeing-prefix path — so speculation composes with
-    #: ``do_sample`` when ``per_slot_sampling=True``.
+    #: ``do_sample``.
     spec_k: int = 0
     #: draft policy when ``spec_k > 0`` (see :mod:`.spec`):
     #: ``"early_exit:N"`` runs the target's own first N layers (+ its final
@@ -222,14 +221,6 @@ class EngineConfig:
     #: strict subset of the target's, so prefix sharing, copy-on-write and
     #: swap preemption maintain the draft state with zero extra machinery.
     draft: str = "early_exit:2"
-    #: per-request sampling + constrained decoding (:mod:`.sampling`,
-    #: :mod:`.grammar`): temperature / top-k / top-p / repetition penalty /
-    #: seed / stop / min_tokens and a grammar DFA ride as fixed-shape
-    #: *traced lane inputs* of the ONE compiled decode executable, so
-    #: per-request variation never recompiles. ``False`` rebuilds the
-    #: pre-lane executables byte-for-byte (the ``bench.py sampling``
-    #: overhead baseline) and refuses per-request params at add_request.
-    per_slot_sampling: bool = True
     #: top-N per-step logprobs harvested through the existing device_get
     #: (0 disables — the harvest shape is static, so this is engine
     #: geometry; requests opt in *up to* this cap). Unsupported with
@@ -386,12 +377,6 @@ class InferenceEngine:
         if cfg.spec_k:
             if cfg.spec_k < 1:
                 raise ValueError("spec_k must be >= 1 (0 disables speculation)")
-            if cfg.do_sample and not cfg.per_slot_sampling:
-                raise ValueError(
-                    "spec_k with do_sample=True needs per_slot_sampling=True "
-                    "(the rejection-sampling verify path); the legacy "
-                    "per_slot_sampling=False executables are greedy-only"
-                )
             if cfg.logprobs_topn:
                 raise ValueError(
                     "logprobs_topn with spec_k > 0 is not supported: the "
@@ -416,10 +401,9 @@ class InferenceEngine:
         #: a plain dispatch writes decode_burst)
         self._decode_lookahead = (cfg.spec_k + 1) if self._spec else cfg.decode_burst
 
-        # per-slot sampling + grammar state (the tentpole lanes). The
-        # engine-wide do_sample/temperature survive as the DEFAULT
-        # SamplingParams a request inherits when it supplies none.
-        self._psampling = bool(cfg.per_slot_sampling)
+        # per-slot sampling + grammar state (the lanes). The engine-wide
+        # do_sample/temperature/seed are the DEFAULT SamplingParams a
+        # request inherits when it supplies none.
         if min(cfg.logprobs_topn, cfg.grammar_slots, cfg.rep_window - 1,
                cfg.grammar_states - 1) < 0:
             raise ValueError(
@@ -430,13 +414,13 @@ class InferenceEngine:
             do_sample=cfg.do_sample, temperature=cfg.temperature,
             seed=cfg.seed,
         ).validate()
-        if cfg.do_sample and self._psampling:
+        if cfg.do_sample:
             warnings.warn(
                 "EngineConfig(do_sample=True) + temperature are superseded by "
                 "per-request sampling params: they now only set the default "
                 "SamplingParams a request inherits when it supplies none "
-                "(sampled draws use the per-slot derived keys, not the "
-                "legacy threaded key)",
+                "(every sampled draw uses a key derived per slot from the "
+                "request's seed and output position)",
                 stacklevel=2,
             )
         self._vocab_size = int(mcfg.vocab_size)
@@ -553,21 +537,16 @@ class InferenceEngine:
             self._cache[name] = jnp.zeros(
                 leaf.array_shape(cfg.num_slots), leaf.dtype or dtype)
         self._state_resets = 0
-        self._key = jax.random.PRNGKey(cfg.seed)
-        self._temp = jnp.float32(cfg.temperature)
         #: per-slot draw root: never split/threaded — every draw derives
         #: from it by fold_in(tag, request seed, output position), which is
         #: what makes (seed, prompt) reproducible across admission orders
         #: and preempt/swap/resume (sampling.slot_keys)
         self._base_key = jax.random.PRNGKey(cfg.seed)
-        if self._psampling:
-            g = cfg.grammar_slots + 1
-            self._gmask = jnp.ones((g, cfg.grammar_states, self._vocab_size), bool)
-            self._gtrans = jnp.zeros(
-                (g, cfg.grammar_states, self._vocab_size), jnp.int32
-            )
-        else:
-            self._gmask = self._gtrans = None
+        g = cfg.grammar_slots + 1
+        self._gmask = jnp.ones((g, cfg.grammar_states, self._vocab_size), bool)
+        self._gtrans = jnp.zeros(
+            (g, cfg.grammar_states, self._vocab_size), jnp.int32
+        )
         #: device-committed all-inert lane dict, built lazily: the
         #: all-greedy dispatch fast path reuses these buffers verbatim, so
         #: plain traffic never pays the per-iteration lane rebuild/upload
@@ -577,7 +556,11 @@ class InferenceEngine:
         if mesh is not None:
             self._place_on_mesh(inner)
 
-        # host mirrors the compiled step reads every iteration
+        # host mirrors the compiled step reads every iteration. A program
+        # is handed a COPY of the block tables, never the mirror: the CPU
+        # backend takes an aligned numpy operand without copying it, and a
+        # chunk or a round still in flight would read the rows the host
+        # rewrites for the next one (_sync_block_table zeroes a row first)
         self._block_tables = np.zeros((cfg.num_slots, self._mb), np.int32)
         self._pending_tok = np.zeros((cfg.num_slots,), np.int32)
 
@@ -731,22 +714,21 @@ class InferenceEngine:
             lambda tab, row, data: tab.at[row].set(data),
             donate_argnums=(0,),
         )
-        # first-token pick for the per-slot path: the prefill executable
-        # already returns the prompt-final logits, so the lane transform
-        # runs on them as a [1, vocab] slice of the SAME pick_tokens the
-        # decode scan uses — one tiny extra executable, zero extra model
-        # forwards, and exact key parity with decode (position 0)
-        if self._psampling:
-            eos_id = cfg.eos_token_id
-            topn = cfg.logprobs_topn
+        # first-token pick: the prefill executable returns the
+        # prompt-final logits, and the lane transform runs on them as a
+        # [1, vocab] slice of the SAME pick_tokens the decode scan uses —
+        # one tiny extra executable, zero extra model forwards, and exact
+        # key parity with decode (position 0)
+        eos_id = cfg.eos_token_id
+        topn = cfg.logprobs_topn
 
-            def first_pick(logits, lanes, gmask, base_key):
-                return pick_tokens(
-                    logits, lanes, lanes["dfa_state"], jnp.int32(0), gmask,
-                    base_key, eos_id=eos_id, logprobs_topn=topn,
-                )
+        def first_pick(logits, lanes, gmask, base_key):
+            return pick_tokens(
+                logits, lanes, lanes["dfa_state"], jnp.int32(0), gmask,
+                base_key, eos_id=eos_id, logprobs_topn=topn,
+            )
 
-            self._first_pick_fn = jax.jit(first_pick)
+        self._first_pick_fn = jax.jit(first_pick)
 
     def _place_on_mesh(self, inner) -> None:
         """GSPMD placement over ``self.mesh``: every device-side input to
@@ -784,13 +766,10 @@ class InferenceEngine:
         # sharded params — a single-device-committed leaf among mesh-committed
         # ones is an incompatible-devices error at dispatch
         rep = NamedSharding(mesh, PartitionSpec())
-        self._key = jax.device_put(self._key, rep)
-        self._temp = jax.device_put(self._temp, rep)
         self._base_key = jax.device_put(self._base_key, rep)
-        if self._gmask is not None:
-            # grammar tables are read-gathered per slot — tiny, replicated
-            self._gmask = jax.device_put(self._gmask, rep)
-            self._gtrans = jax.device_put(self._gtrans, rep)
+        # grammar tables are read-gathered per slot — tiny, replicated
+        self._gmask = jax.device_put(self._gmask, rep)
+        self._gtrans = jax.device_put(self._gtrans, rep)
 
     def _idle_lanes(self) -> dict:
         """The cached device-committed blank lane dict for all-inert
@@ -908,52 +887,17 @@ class InferenceEngine:
     del _cache_leaf
 
     def _build_decode_fn(self):
-        if self._psampling:
-            return self._build_lane_decode_fn()
-        apply_fn, cfg = self._apply_fn, self.config
-
-        def decode(params, cache, block_tables, pos0, toks, active, key, temp):
-            self._decode_traces += 1  # traced-body side effect: cache misses only
-
-            def one_step(carry, _):
-                cache, toks, pos, key = carry
-                out = apply_fn(
-                    params,
-                    input_ids=toks,
-                    paged_kv=cache,
-                    block_tables=block_tables,
-                    cache_positions=pos,
-                    paged_write_mask=active,  # PREFILL/free lanes must not scribble
-                )
-                logits = out["logits"][:, -1, :]
-                tok, key, _ = _pick_traced(
-                    logits, key, jnp.zeros(logits.shape[:1], bool), jnp.int32(0),
-                    temp, cfg.do_sample, has_eos=False,  # eos is host-side state
-                )
-                return (out["paged_kv"], tok[:, None], pos + 1, key), tok
-
-            (cache, _, _, key), toks_out = jax.lax.scan(
-                one_step, (cache, toks, pos0, key), None, length=cfg.decode_burst,
-            )
-            return cache, toks_out, key  # toks_out: [burst, num_slots]
-
-        # the cache — pools, scale arrays when quantized, slot state where
-        # the model keeps one — is one donated operand of every step program
-        return jax.jit(decode, donate_argnums=(1,))
-
-    def _build_lane_decode_fn(self):
-        """Per-slot twin of the legacy burst decode: the sampling lanes
+        """The burst decode: the sampling lanes
         (:func:`sampling.blank_lanes` schema), the grammar tables, and the
-        derived-key root ride as extra traced inputs of the SAME single
+        derived-key root ride as traced inputs of the ONE decode
         executable — their shapes/dtypes are engine geometry, so
         per-request variation is data, never a retrace. Each burst step
         runs :func:`sampling.pick_tokens` (which drops to a bare argmax
-        under ``lax.cond`` when every lane is inert — greedy parity with
-        the legacy executable is exact) and advances the per-slot DFA
-        state in-trace for mid-burst masking; the host re-derives the
-        authoritative state per emitted token, so discarded burst tails
-        never corrupt it.  The per-step top-N logprob harvest rides the
-        scan outputs through the one existing device_get."""
+        under ``lax.cond`` when every lane is inert) and advances the
+        per-slot DFA state in-trace for mid-burst masking; the host
+        re-derives the authoritative state per emitted token, so discarded
+        burst tails never corrupt it.  The per-step top-N logprob harvest
+        rides the scan outputs through the one existing device_get."""
         apply_fn, cfg = self._apply_fn, self.config
         eos_id = cfg.eos_token_id
         topn = cfg.logprobs_topn
@@ -988,96 +932,37 @@ class InferenceEngine:
             # toks_out: [burst, num_slots]; logprob outputs [burst, slots(, N)]
             return cache, toks_out, logps, tvals, tids
 
+        # the cache — pools, scale arrays when quantized, slot state where
+        # the model keeps one — is one donated operand of every step program
         return jax.jit(decode, donate_argnums=(1,))
 
     def _build_spec_decode_fn(self):
-        """Speculative twin of ``_build_decode_fn`` — when ``spec_k`` is
-        armed this IS the engine's one decode executable. One dispatch runs
-        the whole round:
+        """The speculative round — when ``spec_k`` is armed this IS the
+        engine's one decode executable. One dispatch runs the whole round:
 
-        1. **draft scan**: ``k`` greedy steps of the early-exit draft (the
+        1. **draft scan**: ``k`` steps of the early-exit draft (the
            target's first ``draft_layers`` layers), autoregressing through
            the target's own pool, in place — the paged step addresses the
            pool by layer, so the draft writes layers ``0..draft_layers-1``
            of the one donated buffer and no slice of it is ever made;
            identical weights make its K/V a strict subset of the target's,
-           so the draft needs no cache of its own;
+           so the draft needs no cache of its own. It proposes through the
+           SAME lane transform the plain decode uses (grammar mask,
+           filters, per-slot derived keys — ``TAG_DRAFT``);
         2. **one verify forward** of static shape ``[num_slots, k+1]`` over
            ``[pending, d_1 .. d_k]`` through the fused paged-attention
            kernel (quantize-on-scatter + in-register dequant ride along at
-           every ``kv_dtype``). The verify re-scatters ALL layers at the
+           every ``kv_dtype``), every position scored through the lane
+           transform again. The verify re-scatters ALL layers at the
            round's positions — including the draft layers, so the rows
            the draft scan left in the pool are overwritten, from the same
            tokens and weights, before anything but the draft reads them;
-        3. **greedy acceptance** via the shared
-           :func:`~accelerate_tpu.generation.spec_accept_tokens` helper —
-           the single source of acceptance semantics with ``generate()``.
-
-        Rollback of rejected drafts is pure position bookkeeping: the host
-        advances each slot by ``accept+1``, the next round re-writes the
-        stale rows before any query can attend them, and no pool edit
-        beyond the normal scatter ever happens. Donation discipline and the
-        traced-body compile counter are identical to the plain decode fn,
-        so ``decode_compiles == 1`` remains the asserted contract."""
-        if self._psampling:
-            return self._build_lane_spec_decode_fn()
-        from ..generation import spec_accept_tokens
-
-        apply_fn, cfg = self._apply_fn, self.config
-        draft_apply = self._draft_apply
-        k = cfg.spec_k
-
-        def spec_decode(params, cache, block_tables, pos0, toks, active):
-            self._decode_traces += 1  # traced-body side effect: cache misses only
-
-            def dstep(carry, _):
-                cache, tok, pos = carry
-                out = draft_apply(
-                    params,
-                    input_ids=tok,
-                    paged_kv=cache,
-                    block_tables=block_tables,
-                    cache_positions=pos,
-                    paged_write_mask=active,  # PREFILL/free lanes must not scribble
-                )
-                nxt = jnp.argmax(out["logits"][:, -1, :], axis=-1).astype(jnp.int32)
-                return (out["paged_kv"], nxt[:, None], pos + 1), nxt
-
-            # the draft autoregresses through the pool itself (its own
-            # layers, by index): its rows only feed its OWN next steps —
-            # the verify below writes the same positions of every layer
-            # again, from the same tokens/weights, under the same mask
-            (cache, _, _), d = jax.lax.scan(dstep, (cache, toks, pos0), None, length=k)
-            d = d.T  # [num_slots, k] draft proposals
-
-            # ONE verify forward over [pending, d_1 .. d_k]: scatters k+1
-            # positions per active slot, reads the pool through the fused
-            # block-table kernel (query j attends positions <= pos0+j)
-            chunk = jnp.concatenate([toks, d], axis=1)  # [num_slots, k+1]
-            vmask = jnp.broadcast_to(active, (cfg.num_slots, k + 1))
-            out = apply_fn(
-                params,
-                input_ids=chunk,
-                paged_kv=cache,
-                block_tables=block_tables,
-                cache_positions=pos0,
-                paged_write_mask=vmask,
-            )
-            preds = jnp.argmax(out["logits"], axis=-1).astype(jnp.int32)  # [slots, k+1]
-            accept, tok_seq = spec_accept_tokens(d, preds)
-            return out["paged_kv"], tok_seq, accept
-
-        return jax.jit(spec_decode, donate_argnums=(1,))
-
-    def _build_lane_spec_decode_fn(self):
-        """Per-slot spec round: the draft proposes through the SAME lane
-        transform the plain decode uses (grammar mask, filters, per-slot
-        derived keys — ``TAG_DRAFT``), the verify scores every position
-        through it again, and acceptance splits per slot:
+        3. **acceptance**, split per slot:
 
         * greedy slots keep the exact longest-agreeing-prefix path
-          (:func:`~accelerate_tpu.generation.spec_accept_tokens` over the
-          *filtered* target argmax — token-identical to the non-spec
+          (:func:`~accelerate_tpu.generation.spec_accept_tokens`, the
+          single source of acceptance semantics with ``generate()``, over
+          the *filtered* target argmax — token-identical to the non-spec
           engine, and the filter re-check is what keeps accepted drafts
           inside a constrained slot's language);
         * sampled slots run standard speculative rejection sampling
@@ -1092,9 +977,14 @@ class InferenceEngine:
 
         The repetition-penalty ring is held constant across the round (a
         documented approximation — consistent between ``p`` and ``q``, so
-        the acceptance identity is unaffected).  Donation discipline and
-        the traced-body compile counter are identical to the plain lane
-        decode: ``decode_compiles == 1`` stays the asserted contract."""
+        the acceptance identity is unaffected).
+
+        Rollback of rejected drafts is pure position bookkeeping: the host
+        advances each slot by ``accept+1``, the next round re-writes the
+        stale rows before any query can attend them, and no pool edit
+        beyond the normal scatter ever happens. Donation discipline and the
+        traced-body compile counter are identical to the plain decode fn,
+        so ``decode_compiles == 1`` remains the asserted contract."""
         from ..generation import spec_accept_tokens
 
         apply_fn, cfg = self._apply_fn, self.config
@@ -1188,11 +1078,11 @@ class InferenceEngine:
         return jax.jit(spec_decode, donate_argnums=(1,))
 
     def _build_prefill_fn(self):
-        apply_fn, cfg = self._apply_fn, self.config
+        apply_fn = self._apply_fn
         has_state = bool(self._cache_spec.slot_state)
 
         def prefill(params, cache, block_table, start, chunk, valid,
-                    last_idx, slot, key, temp):
+                    last_idx, slot):
             self._prefill_traces += 1
             # a model that keeps per-slot state is told which slot's rows
             # this prompt's chunk continues from and leaves
@@ -1206,14 +1096,10 @@ class InferenceEngine:
                 paged_write_mask=valid,  # drops the padded tail
                 **state_kw,
             )
-            # first-token pick from the prompt's last real position — only
-            # meaningful on the final chunk; the host ignores it otherwise
-            logits = jnp.take(out["logits"][0], last_idx, axis=0)[None]
-            tok, key, _ = _pick_traced(
-                logits, key, jnp.zeros((1,), bool), jnp.int32(0),
-                temp, cfg.do_sample, has_eos=False,
-            )
-            return out["paged_kv"], tok[0], logits[0], key
+            # the logits of the prompt's last real position, which the
+            # first token is picked from — only meaningful on the final
+            # chunk; the host ignores them otherwise
+            return out["paged_kv"], jnp.take(out["logits"][0], last_idx, axis=0)
 
         return jax.jit(prefill, donate_argnums=(1,))
 
@@ -1262,11 +1148,6 @@ class InferenceEngine:
         is taken verbatim (stripped, bounded), everything else normalizes
         to ``"default"`` — unknown-safe, never an admission gate. It is
         echoed on the answer row beside the accrued costs."""
-        if not self._psampling and (sampling is not None or grammar is not None):
-            raise ValueError(
-                "per-request sampling/grammar need per_slot_sampling=True "
-                "(this engine was built with the lanes disabled)"
-            )
         upstream = upstream_hop and valid_trace_id(trace_id)
         req = Request(
             prompt=[int(t) for t in np.asarray(prompt).reshape(-1)],
@@ -1290,24 +1171,23 @@ class InferenceEngine:
                     "number of milliseconds"
                 )
             req.deadline = time.perf_counter() + budget_ms / 1000.0
-        if self._psampling:
-            params = resolve_sampling(sampling, self._default_sampling)
-            if params.logprobs > self.config.logprobs_topn:
-                raise ValueError(
-                    f"request wants logprobs={params.logprobs} but the engine "
-                    f"compiled logprobs_topn={self.config.logprobs_topn}; raise "
-                    "EngineConfig.logprobs_topn (a traced-shape choice, so it "
-                    "is per-engine, not per-request)"
-                )
-            req.sampling = params
-            if grammar is not None:
-                g = compile_grammar(
-                    grammar, self._vocab_size,
-                    eos_id=self.config.eos_token_id,
-                    max_states=self.config.grammar_states,
-                )
-                req.grammar_row = self._acquire_grammar_row(g)
-                req.dfa_state = g.start
+        params = resolve_sampling(sampling, self._default_sampling)
+        if params.logprobs > self.config.logprobs_topn:
+            raise ValueError(
+                f"request wants logprobs={params.logprobs} but the engine "
+                f"compiled logprobs_topn={self.config.logprobs_topn}; raise "
+                "EngineConfig.logprobs_topn (a traced-shape choice, so it "
+                "is per-engine, not per-request)"
+            )
+        req.sampling = params
+        if grammar is not None:
+            g = compile_grammar(
+                grammar, self._vocab_size,
+                eos_id=self.config.eos_token_id,
+                max_states=self.config.grammar_states,
+            )
+            req.grammar_row = self._acquire_grammar_row(g)
+            req.dfa_state = g.start
         try:
             self.scheduler.submit(req)
         except BaseException:
@@ -1610,11 +1490,8 @@ class InferenceEngine:
     def _sampling_stats(self) -> dict:
         """Per-slot sampling/grammar health fields. Like ``_spec_stats``,
         the SINGLE source for both ``stats()`` and the telemetry step
-        rows; empty when the lanes are disabled. The rejection counters
-        only appear with speculation armed — they are the sampled-slot
-        analogue of the greedy accept rate."""
-        if not self._psampling:
-            return {}
+        rows. The rejection counters only appear with speculation armed —
+        they are the sampled-slot analogue of the greedy accept rate."""
         out = {
             "sampled_tokens_greedy": self._sampled_greedy,
             "sampled_tokens_sample": self._sampled_sample,
@@ -1657,7 +1534,7 @@ class InferenceEngine:
             # kv_dtype policy: bytes one cached token moves/holds (K+V
             # payload + scales across layers) and how many max-length
             # requests the pool can hold concurrently — the capacity rows
-            # `serve --auto-blocks` and `bench.py kv` report ratios of
+            # `serve --auto-blocks` reports ratios of
             "kv_dtype": self.kv_dtype,
             "kv_bytes_per_token": self.kv_bytes_per_token,
             "kv_bytes_per_block": self.kv_bytes_per_token * self.config.block_size,
@@ -2274,11 +2151,11 @@ class InferenceEngine:
         last_idx = np.int32((total - 1) - start if is_final else 0)
 
         self._count_paged_entries([start], c, self._cache_spec.paged_layers)
-        self._cache, tok, _logits, self._key = self._prefill_fn(
+        self._cache, logits = self._prefill_fn(
             self._params, self._cache,
-            self._block_tables[req.slot : req.slot + 1],
+            self._block_tables[req.slot : req.slot + 1].copy(),
             np.asarray([start], np.int32), chunk, valid, last_idx,
-            np.asarray([req.slot], np.int32), self._key, self._temp,
+            np.asarray([req.slot], np.int32),
         )
         req.prefill_pos = end
         req.prefill_iterations += 1
@@ -2294,13 +2171,10 @@ class InferenceEngine:
             self._pending_tok[req.slot] = req.output_tokens[-1]
             req.state = RequestState.DECODE
         elif is_final:
-            if self._psampling:
-                # re-pick from the returned prompt-final logits through the
-                # SAME lane transform decode uses (position 0 of the
-                # request's derived key stream); on an inert request this
-                # is the same argmax the executable's own pick took
-                tok, lp_entry = self._first_token_pick(req, _logits)
-            tok = int(tok)  # blocks until the chunk (and all before it) ran
+            # picked from the prompt-final logits through the SAME lane
+            # transform decode uses (position 0 of the request's derived
+            # key stream); blocks until the chunk (and all before it) ran
+            tok, lp_entry = self._first_token_pick(req, logits)
         t1 = time.perf_counter()
         req.own_prefill_s += t1 - t0
         if self.usage is not None:
@@ -2413,28 +2287,26 @@ class InferenceEngine:
         # When every live request is inert the cached device-resident blank
         # dict stands in — the traced lax.cond argmaxes without reading a
         # single lane value, so stale contents cannot matter
-        lanes = None
-        if self._psampling:
-            if all(
-                (req.sampling or self._default_sampling).inert
-                and not req.grammar_row
-                for req in live
-            ):
-                lanes = self._idle_lanes()
-            else:
-                lanes = blank_lanes(cfg.num_slots, cfg.rep_window)
-                for req in live:
-                    params = req.sampling or self._default_sampling
-                    set_slot_lane(
-                        lanes, req.slot, params,
-                        pos=len(req.output_tokens),
-                        grammar_row=req.grammar_row, dfa_state=req.dfa_state,
-                        recent=(
-                            req.prompt + req.output_tokens
-                            if params.repetition_penalty != 1.0
-                            else ()
-                        ),
-                    )
+        if all(
+            (req.sampling or self._default_sampling).inert
+            and not req.grammar_row
+            for req in live
+        ):
+            lanes = self._idle_lanes()
+        else:
+            lanes = blank_lanes(cfg.num_slots, cfg.rep_window)
+            for req in live:
+                params = req.sampling or self._default_sampling
+                set_slot_lane(
+                    lanes, req.slot, params,
+                    pos=len(req.output_tokens),
+                    grammar_row=req.grammar_row, dfa_state=req.dfa_state,
+                    recent=(
+                        req.prompt + req.output_tokens
+                        if params.repetition_penalty != 1.0
+                        else ()
+                    ),
+                )
 
         # signature capture costs ~8 shape/dtype formats per dispatch, so it
         # rides the same armed-instrumentation gate as every other hot-path
@@ -2446,15 +2318,10 @@ class InferenceEngine:
                 *(("cache." + name, a) for name, a in sorted(self._cache.items())),
                 ("block_tables", self._block_tables), ("pos0", pos0),
                 ("toks", toks), ("active", active),
+                *sorted(lanes.items()),
+                ("gmask", self._gmask), ("gtrans", self._gtrans),
+                ("base_key", self._base_key),
             ]
-            if self._psampling:
-                args += sorted(lanes.items())
-                args += [
-                    ("gmask", self._gmask), ("gtrans", self._gtrans),
-                    ("base_key", self._base_key),
-                ]
-            elif self._spec is None:  # legacy spec round is greedy: no key/temp
-                args += [("key", self._key), ("temp", self._temp)]
             decode_sig = tuple(
                 (name, tuple(np.shape(v)), str(getattr(v, "dtype", type(v).__name__)))
                 for name, v in args
@@ -2466,17 +2333,10 @@ class InferenceEngine:
         self._count_paged_entries(
             pos0 + np.arange(cfg.decode_burst)[:, None], 1, self._cache_spec.paged_layers
         )
-        logps = tvals = tids = None
-        if self._psampling:
-            self._cache, next_toks, logps, tvals, tids = self._decode_fn(
-                self._params, self._cache, self._block_tables, pos0, toks, active,
-                lanes, self._gmask, self._gtrans, self._base_key,
-            )
-        else:
-            self._cache, next_toks, self._key = self._decode_fn(
-                self._params, self._cache, self._block_tables, pos0, toks,
-                active, self._key, self._temp,
-            )
+        self._cache, next_toks, logps, tvals, tids = self._decode_fn(
+            self._params, self._cache, self._block_tables.copy(), pos0, toks, active,
+            lanes, self._gmask, self._gtrans, self._base_key,
+        )
         self._check_one_executable(decode_sig)
         if self._tr is not None:
             # request identity on the decode timeline WITHOUT per-token
@@ -2507,19 +2367,14 @@ class InferenceEngine:
         slot advances by ``accept+1`` positions; the rejected rows beyond
         that are re-scattered by the next round before anything can
         attend them."""
-        lane_args = (
-            (lanes, self._gmask, self._gtrans, self._base_key)
-            if self._psampling
-            else ()
-        )
         # the draft's k single-query steps through its own layers, then the
         # one verify forward of k + 1 queries through all of them
         k = self.config.spec_k
         self._count_paged_entries(pos0 + np.arange(k)[:, None], 1, self._spec.layers)
         self._count_paged_entries(pos0, k + 1, self._cache_spec.paged_layers)
         self._cache, tok_seq, accept = self._decode_fn(
-            self._params, self._cache, self._block_tables, pos0, toks, active,
-            *lane_args,
+            self._params, self._cache, self._block_tables.copy(), pos0, toks, active,
+            lanes, self._gmask, self._gtrans, self._base_key,
         )
         self._check_one_executable(decode_sig)
         # the round's [num_slots, k+1] token matrix and [num_slots]
@@ -2672,11 +2527,10 @@ class InferenceEngine:
         self._pending_tok[req.slot] = tok
         self._tokens_emitted += 1
         params = req.sampling
-        if self._psampling:
-            if params is not None and params.do_sample:
-                self._sampled_sample += 1
-            else:
-                self._sampled_greedy += 1
+        if params is not None and params.do_sample:
+            self._sampled_sample += 1
+        else:
+            self._sampled_greedy += 1
         if lp_entry is not None:
             lp_entry["token"] = tok
             if req.logprobs is None:

@@ -20,8 +20,9 @@ residual resample).
 
 The greedy fast path matters: when no live slot needs sampling, grammar
 masking, repetition penalty, or min-token suppression, ``pick_tokens``
-drops to a bare argmax under ``lax.cond`` — bit-identical to the pre-lane
-engine and within the <1 % overhead bar ``bench.py sampling`` enforces.
+drops to a bare argmax under ``lax.cond`` — the tokens a pick by argmax
+alone serves (``tests/test_sampling_serving.py`` holds them recorded); what
+the pick costs on the chip is ``scope.sample_pct.chat`` in ``PERF.md``.
 
 Host-side bookkeeping (stop sequences, min/max tokens, the authoritative
 DFA state) lives on the request object; this module only supplies the
@@ -133,7 +134,7 @@ class SamplingParams:
 def resolve_sampling(obj, default=None):
     """Coerce ``None`` / dict / :class:`SamplingParams` into validated
     params.  ``None`` inherits the engine default (itself derived from the
-    legacy engine-wide ``do_sample``/``temperature`` config)."""
+    engine-wide ``do_sample``/``temperature``/``seed`` config)."""
     if obj is None:
         return default if default is not None else SamplingParams()
     if isinstance(obj, SamplingParams):
@@ -177,8 +178,8 @@ _LANE_SPECS = (
 
 
 def blank_lanes(num_slots, rep_window):
-    """All-inert lanes: every slot behaves exactly like the pre-lane
-    greedy engine until :func:`set_slot_lane` arms it."""
+    """All-inert lanes: every slot picks by a bare argmax until
+    :func:`set_slot_lane` arms it."""
     lanes = {
         name: np.full((num_slots,), default, dtype=dtype)
         for name, dtype, default in _LANE_SPECS
@@ -323,8 +324,7 @@ def pick_tokens(logits, lanes, dfa_state, step, gmask, base_key, *, eos_id, logp
     (zeros when harvesting is off — the shapes must be static).
 
     When every lane is inert a ``lax.cond`` routes the whole batch to a
-    bare argmax — token-identical to the pre-lane greedy engine and the
-    reason the armed-but-idle overhead stays under the bench bar.
+    bare argmax, so greedy traffic pays for none of the filters.
     """
     num_slots, vocab = logits.shape
     n = max(int(logprobs_topn), 1)

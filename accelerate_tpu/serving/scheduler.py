@@ -100,8 +100,8 @@ class Request:
     finish_reason: str | None = None
     # "eos" | "length" | "stop" | "out_of_blocks" | "deadline_exceeded"
     slot: int | None = None
-    #: resolved :class:`~.sampling.SamplingParams` (None on a
-    #: per_slot_sampling=False engine). Lives on the request — not the
+    #: resolved :class:`~.sampling.SamplingParams` (``add_request`` sets
+    #: it; None reads as the engine's default). Lives on the request — not the
     #: slot — so preemption/swap/re-admission carries it for free and the
     #: lanes are rebuilt from it on every dispatch.
     sampling: object = None

@@ -139,8 +139,8 @@ def telemetry_segments(jsonl_path: str) -> list[str]:
         segments.append(jsonl_path)
     return segments
 
-#: Peak dense bf16 FLOPs/s per chip by device kind (public spec sheets;
-#: same table the bench harness uses). Override per-run with
+#: Peak dense bf16 FLOPs/s per chip by device kind (public spec sheets).
+#: Override per-run with
 #: ``TelemetryRecorder(peak_flops=...)`` or ``ACCELERATE_TELEMETRY_PEAK_FLOPS``.
 PEAK_FLOPS_TABLE: tuple[tuple[str, float], ...] = (
     ("v6e", 918e12),

@@ -1,8 +1,7 @@
 """Tiny HLO/StableHLO text introspection helpers.
 
-Used by the comm-hook wire-bytes proof (tests), the bench's
-``dp_grad_compression_wire_bytes_ratio`` row, and the telemetry
-recorder's per-compile collective accounting: all need "how many bytes do
+Used by the comm-hook wire-bytes proof (tests) and the telemetry
+recorder's per-compile collective accounting: both need "how many bytes do
 the collective ops in this module move, by dtype" — one parser so the
 regexes can't drift apart. Matched ops: ``all-reduce``, ``all-gather``,
 ``reduce-scatter`` (the FSDP pair — a sharded step's traffic is mostly

@@ -12,7 +12,7 @@ the invariants that make the robustness story honest:
   goodput on the identical trace — never an absolute wall-clock gate,
   per the timing-noise rule (this box's clock swings ±5x).
 
-Run directly (``make chaos-smoke``) or via ``bench.py chaos``.
+Run directly (``make chaos-smoke``).
 """
 
 import json

@@ -15,9 +15,7 @@ captured mid-traffic, and then every observability surface must agree:
   still 1 (profiling never perturbs the compiled executable);
 * the HBM watermarks ride ``stats()`` (estimate-labelled on CPU).
 
-Run directly (``make flight-smoke``) or via ``bench.py flight`` (which
-additionally prices the disabled-path guard — bar <1% of an engine
-iteration).
+Run directly (``make flight-smoke``).
 """
 
 import json

@@ -20,7 +20,7 @@ Proves the kv_dtype policy's contracts end-to-end on CPU-sized shapes:
    never gated (the ±5x box rule): the credible number is the TPU run,
    where the Pallas kernel replaces the lax scan.
 
-Run via ``make kvq-smoke``; ``bench.py kv`` consumes :func:`run`.
+Run via ``make kvq-smoke``.
 """
 
 from __future__ import annotations
@@ -58,8 +58,8 @@ def capacity_blocks(dtype: str, budget_bytes: int, *, num_layers=16,
 
 def _paged_attn_ratio() -> dict:
     """Fused (lax walk) vs gather-reference decode attention: jitted,
-    warmed, timeit min-of-5 — the overhead-bar pattern every bench row on
-    this box uses (never a raw wall-clock gate)."""
+    warmed, timeit min-of-5, reported as a ratio (never a raw wall-clock
+    gate)."""
     import jax
     import jax.numpy as jnp
     import numpy as np

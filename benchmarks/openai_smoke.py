@@ -19,8 +19,8 @@ Asserts, over a mixed greedy/sampled/schema-constrained trace:
    trace — per-request sampling/grammar rides the ONE compiled decode
    executable.
 
-Run directly (``make openai-smoke``) or via ``bench.py`` modes that
-reuse the fleet. No absolute wall-clock gates (timing-noise rule).
+Run directly (``make openai-smoke``). No absolute wall-clock gates
+(timing-noise rule).
 """
 
 import json

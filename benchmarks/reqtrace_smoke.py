@@ -13,9 +13,7 @@ one coherent story:
 * the ``/metrics``-style exposition carries ``trace_id`` exemplars on the
   latency histograms and round-trips through the strict parser.
 
-Run directly (``make reqtrace-smoke``) or via ``bench.py reqtrace`` (which
-additionally prices the disabled-path guard — bar <1% of an engine
-iteration).
+Run directly (``make reqtrace-smoke``).
 """
 
 import json

@@ -6,7 +6,7 @@ occupancy from the fleet JSONL — ratios only, never absolute wall-clock
 gates, per the timing-noise rule (this box's clock swings ±5x; the
 credible ratio is a real multi-chip host).
 
-Run directly (``make route-smoke``) or via ``bench.py route``.
+Run directly (``make route-smoke``).
 """
 
 import json

@@ -21,8 +21,8 @@ contract).
 
 Arrivals are replayed in wall time: a request is submitted only once the
 clock passes its Poisson arrival offset, so queueing and TTFT are real,
-not simulated. Run standalone (``python benchmarks/serve_bench.py``) or
-through ``bench.py`` mode ``serve`` (the artifact row).
+not simulated. Run standalone (``python benchmarks/serve_bench.py``); the
+benchmark's chat cells are ``perfbench/``'s, not this file.
 """
 
 from __future__ import annotations

@@ -17,7 +17,7 @@ What it pins, end to end:
    answered exactly once, expiries included) and ``decode_compiles == 1``
    per replica.
 
-Run directly (``make slo-smoke``) or via ``bench.py fleet``.
+Run directly (``make slo-smoke``).
 """
 
 import json

@@ -22,7 +22,7 @@ docs/source/concept_guides/performance.md), and a smoke gates on the
 machinery's win AT a usable accept rate — the achieved rate is reported
 beside the ratio, never assumed. Trained checkpoints reach comparable
 agreement with distilled drafts; the floor case is covered by the
-``spec`` bench row and the parity matrix in tests/test_spec_serving.py.
+parity matrix in tests/test_spec_serving.py.
 """
 
 from __future__ import annotations

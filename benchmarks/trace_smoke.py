@@ -4,8 +4,7 @@ the per-host trace file exists, merges into a schema-valid Chrome trace
 containing the built-in spans, the heartbeat carries the final step count,
 the watchdog did NOT fire on a healthy loop, and the disabled-by-default
 overhead of the diagnostics call sites stays negligible (≤1% target on
-the same loop, measured off-vs-off-with-instrumentation-points; the
-definitive number is bench.py's ``watchdog_overhead_pct`` row). Exit code
+the same loop, measured off-vs-off-with-instrumentation-points). Exit code
 is the CI signal; prints a one-line OK."""
 
 from __future__ import annotations

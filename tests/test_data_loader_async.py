@@ -50,22 +50,47 @@ def test_prefetch_and_sync_paths_yield_identical_batches():
 
 
 def test_prefetch_overlaps_collate_with_consumer():
-    """With slow per-sample loading and a slow consumer, total wall time
-    must approach max(load, consume), not their sum."""
-    n, bs, delay = 24, 4, 0.01
-    per_batch = bs * delay  # 40ms of "collation" per batch
-    loader = _shard_loader(n=n, batch_size=bs, prefetch=3, delay=delay)
-    t0 = time.monotonic()
-    count = 0
-    for _ in loader:
-        time.sleep(per_batch)  # consumer work, same cost as producer
-        count += 1
-    elapsed = time.monotonic() - t0
+    """While the consumer holds batch ``i`` and asks for nothing, the
+    prefetch thread goes on collating: the dataset is read to the end of
+    batch ``i + 2``. The synchronous path, whose one batch of lookahead is
+    read only when the consumer asks, never gets that far. Judged by the
+    order of events: no wall-clock bound (the timeout below only keeps a
+    loader that stopped prefetching from hanging the suite)."""
+    import threading
+
+    n, bs = 24, 4
     n_batches = n // bs
-    serial = 2 * n_batches * per_batch
-    # overlap should cut ≥25% off the serial time (generous for CI jitter)
-    assert elapsed < 0.75 * serial, f"no overlap: {elapsed:.3f}s vs serial {serial:.3f}s"
+    read = [threading.Event() for _ in range(n)]
+
+    class _Recording(_Dataset):
+        def __getitem__(self, i):
+            read[i].set()
+            return super().__getitem__(i)
+
+    def loader(prefetch):
+        for event in read:
+            event.clear()
+        sampler = BatchSampler(SequentialSampler(n), batch_size=bs)
+        shard = BatchSamplerShard(sampler, num_processes=1, process_index=0)
+        return DataLoaderShard(
+            _Recording(n), batch_sampler=shard, sharding=None, prefetch_batches=prefetch,
+        )
+
+    def last_of(batch):
+        return read[(batch + 1) * bs - 1]
+
+    count = 0
+    for i, _ in enumerate(loader(prefetch=3)):
+        if i + 2 < n_batches:
+            assert last_of(i + 2).wait(timeout=60), (
+                f"holding batch {i}, batch {i + 2} was never collated: no overlap")
+        count += 1
     assert count == n_batches
+
+    # the control: without the thread nothing is read while the consumer works
+    for i, _ in enumerate(loader(prefetch=0)):
+        if i + 2 < n_batches:
+            assert last_of(i + 1).is_set() and not read[(i + 2) * bs].is_set()
 
 
 def test_prefetch_propagates_exceptions():

@@ -3,17 +3,18 @@
 The contract under test: per-request sampling knobs and grammar DFA
 states ride the ONE compiled decode executable as fixed-shape lane
 inputs — ``decode_compiles == 1`` with the lanes armed, including with
-speculation and on a 4-device mesh — while greedy requests stay
-token-identical to the lanes-off (``per_slot_sampling=False``) engine at
-every ``kv_dtype``, and a fixed seed reproduces the exact same tokens
+speculation and on a 4-device mesh — while greedy requests are served
+the tokens the engine without lanes served them (recorded, at every
+``kv_dtype``), and a fixed seed reproduces the exact same tokens
 regardless of admission order or preempt/swap/resume.
 
 Tier-1 (pure host / no compiles): params validation + resolution, stop
 matching, the regex→DFA compiler and JSON-schema subset, the OpenAI
 request/response translation (golden payloads, SSE framing, error
-objects) against a fake submit fn, and the metrics/monitor plumbing.
-The engine end-to-end legs and the real ``serve --http`` / ``route
---http`` subprocess tests ride the slow lane.
+objects) against a fake submit fn, the metrics/monitor plumbing, the
+recorded greedy tokens and the prefill program's operands. The other
+engine end-to-end legs and the real ``serve --http`` / ``route --http``
+subprocess tests ride the slow lane.
 """
 
 import json
@@ -407,7 +408,7 @@ def test_sampling_metrics_round_trip_both_surfaces():
 
 
 # ---------------------------------------------------------------------------
-# engine end-to-end (slow lane: compiles the tiny model)
+# engine end-to-end (compiles the tiny model; slow lane but for the first two)
 # ---------------------------------------------------------------------------
 
 KV_DTYPES = ("bf16", "int8", "fp8")
@@ -432,30 +433,65 @@ def _prompts(seed, sizes=(5, 11, 17, 3, 9)):
     return [rng.integers(0, 64, size=n).astype(np.int32) for n in sizes]
 
 
-@pytest.mark.slow
+#: what the parent commit (8754e62) served this traffic on the CPU, by pool
+#: dtype, from the executables it still kept from before the lanes: no lane
+#: operand, every token a bare argmax
+RECORDED_TOKENS = {
+    "bf16": [
+        [30, 6, 27], [8, 8, 8, 8, 8, 55, 27],
+        [14, 9, 11, 20, 38, 20, 9, 30, 9, 30, 56],
+        [27, 17, 36, 36, 57, 17, 5, 37, 37, 17, 36, 17, 5, 63, 37],
+        [27, 17, 48, 11, 14, 14, 14, 14, 11, 7, 9, 11, 11, 11, 14, 11, 11, 11, 11],
+    ],
+    "int8": [
+        [30, 6, 27], [8, 8, 8, 8, 8, 55, 27],
+        [14, 9, 11, 20, 38, 20, 9, 30, 9, 30, 38],
+        [27, 17, 36, 36, 57, 17, 5, 37, 37, 17, 36, 17, 5, 63, 37],
+        [27, 17, 48, 11, 14, 14, 14, 14, 11, 7, 9, 11, 11, 11, 14, 11, 11, 11, 11],
+    ],
+    "fp8": [
+        [30, 54, 48], [8, 8, 8, 8, 8, 55, 27],
+        [14, 9, 11, 20, 38, 20, 9, 30, 9, 11, 7],
+        [27, 17, 36, 24, 17, 37, 17, 63, 37, 17, 37, 17, 17, 59, 63],
+        [27, 17, 48, 11, 14, 14, 14, 14, 11, 7, 9, 11, 11, 11, 14, 11, 11, 11, 11],
+    ],
+}
+
+
 @pytest.mark.parametrize("kv_dtype", KV_DTYPES)
-def test_greedy_token_identity_lanes_vs_legacy(tiny_model, kv_dtype):
-    """The headline bar: arming the lanes changes NOTHING for greedy
-    traffic — token-identical to the ``per_slot_sampling=False`` engine
-    (the PR 16 executables) at every kv_dtype, one executable each side."""
-    prompts = _prompts(0)
+def test_greedy_traffic_is_served_the_recorded_tokens(tiny_model, kv_dtype):
+    """The lanes change NOTHING for greedy traffic: the tokens are those
+    of the bare-argmax executables at every kv_dtype, from one decode and
+    one prefill executable."""
     budgets = [3 + 4 * i for i in range(5)]
-
-    def run(per_slot):
-        eng = InferenceEngine(
-            tiny_model, _cfg(per_slot_sampling=per_slot, kv_dtype=kv_dtype)
-        )
-        reqs = [eng.add_request(p, b) for p, b in zip(prompts, budgets)]
-        eng.run_until_idle(max_iterations=5000)
-        return eng, [list(r.output_tokens) for r in reqs]
-
-    lanes_eng, lanes_toks = run(True)
-    _, legacy_toks = run(False)
-    assert lanes_toks == legacy_toks
-    st = lanes_eng.stats()
+    eng = InferenceEngine(tiny_model, _cfg(kv_dtype=kv_dtype))
+    reqs = [eng.add_request(p, b) for p, b in zip(_prompts(0), budgets)]
+    eng.run_until_idle(max_iterations=5000)
+    assert [list(r.output_tokens) for r in reqs] == RECORDED_TOKENS[kv_dtype]
+    st = eng.stats()
     assert st["decode_compiles"] == 1 and st["prefill_compiles"] == 1
     assert st["sampled_tokens_greedy"] == sum(budgets)
     assert st["sampled_tokens_sample"] == 0
+
+
+def test_prefill_program_takes_no_key_and_picks_no_token(tiny_model):
+    """The first token is picked once, by ``_first_token_pick``, from the
+    logits the prefill program hands back: the program itself takes the
+    eight operands below and returns the cache and one row of logits."""
+    import inspect
+
+    import jax
+
+    eng = InferenceEngine(tiny_model, _cfg())
+    eng.add_request(_prompts(0)[0], 2)
+    eng.run_until_idle(max_iterations=100)
+    jitted, operands = eng._dispatched["prefill"]
+    assert list(inspect.signature(jitted).parameters) == [
+        "params", "cache", "block_table", "start", "chunk", "valid", "last_idx", "slot",
+    ]
+    cache, logits = jax.eval_shape(jitted, *operands)
+    shapes = lambda tree: jax.tree.map(lambda a: (a.shape, a.dtype), tree)  # noqa: E731
+    assert shapes(cache) == shapes(operands[1]) and logits.shape == (64,)
 
 
 @pytest.mark.slow

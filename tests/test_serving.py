@@ -534,6 +534,34 @@ def test_paged_entries_counters_follow_the_rows_lengths(tiny_model, spec_k):
     assert engine.stats()["paged_entries_walked_total"] == 0
 
 
+@pytest.mark.parametrize("spec_k", [0, 2])
+def test_step_programs_get_a_copy_of_the_block_tables(tiny_model, spec_k):
+    """The CPU backend takes an aligned numpy operand without copying it, so
+    a chunk or a round still in flight reads what the host writes there
+    next — and ``_sync_block_table`` zeroes a row before it refills it. No
+    step program is handed the mirror itself."""
+    engine = InferenceEngine(tiny_model, EngineConfig(
+        num_slots=3, block_size=8, max_seq_len=64, prefill_chunk=8, decode_burst=2,
+        spec_k=spec_k, draft="early_exit:1",
+    ))
+    handed = []
+
+    def recorded(fn):
+        def call(*args):
+            handed.append(args[2])
+            return fn(*args)
+        return call
+
+    engine._prefill_fn = recorded(engine._prefill_fn)
+    engine._decode_fn = recorded(engine._decode_fn)
+    rng = np.random.default_rng(5)
+    for n in (19, 6):
+        engine.add_request(rng.integers(0, 64, size=n).astype(np.int32), 6)
+    engine.run_until_idle(max_iterations=500)
+    assert {t.shape for t in handed} == {(1, 8), (3, 8)}  # chunks' rows, rounds' tables
+    assert not any(np.shares_memory(t, engine._block_tables) for t in handed)
+
+
 def test_swap_pool_quantized_scales_byte_exact():
     """A quantized SwapPool round-trips payload AND f32 scale rows
     byte-exactly (a quantized block without its exact scales is garbage),

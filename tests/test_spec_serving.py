@@ -65,15 +65,6 @@ def _cfg(**kw):
 
 
 def test_engine_refuses_bad_spec_configs(tiny_model):
-    # do_sample + spec composes on the per-slot-sampling engine (rejection
-    # sampling in the verify round); only the legacy lanes-off executables
-    # are still greedy-only
-    with pytest.raises(ValueError, match="greedy-only"):
-        InferenceEngine(
-            tiny_model,
-            _cfg(spec_k=4, draft="early_exit:1", do_sample=True,
-                 per_slot_sampling=False),
-        )
     with pytest.raises(ValueError, match="logprobs"):
         InferenceEngine(
             tiny_model, _cfg(spec_k=4, draft="early_exit:1", logprobs_topn=2)
