@@ -36,9 +36,10 @@ vLLM-style block-paged cache):
   top-p / repetition penalty / seed / grammar-DFA state ride as
   fixed-shape *lane inputs* of the same ONE decode executable
   (:mod:`.sampling`, :mod:`.grammar`) — per-request
-  variation never recompiles, greedy slots take a ``lax.cond`` fast path
-  that is bit-identical argmax, and the spec verify round accepts sampled
-  slots by rejection sampling.
+  variation never recompiles, the sampler runs under a ``lax.cond`` only
+  when some slot samples (greedy slots are served the bit-identical
+  argmax either way), and the spec verify round accepts sampled slots by
+  rejection sampling.
 
 Sampling/eos semantics share one traced picker with ``generation.py``
 (:func:`accelerate_tpu.generation.pick_next_token`), so greedy engine
@@ -550,7 +551,6 @@ class InferenceEngine:
         #: device-committed all-inert lane dict, built lazily: the
         #: all-greedy dispatch fast path reuses these buffers verbatim, so
         #: plain traffic never pays the per-iteration lane rebuild/upload
-        #: (the in-trace lax.cond already argmaxes without reading them)
         self._lanes_idle = None
         self.mesh = mesh
         if mesh is not None:
@@ -661,6 +661,12 @@ class InferenceEngine:
         # have — their ratio is the share of a (row, entry) grid that is live
         self._paged_entries_walked = 0
         self._paged_entries_table = 0
+        # what the pick's sampler stage follows (monotone totals, counted at
+        # dispatch from the host's copy of the lanes): runs of pick_tokens
+        # (decode dispatches and first picks), and those in which some lane
+        # samples — the ones whose lax.cond takes the sort and the draw
+        self._pick_dispatches = 0
+        self._pick_draw_dispatches = 0
         # static HBM model for the hbm watermark fallback: params + the
         # paged pools (+ scales), the same inventory the PR 8 preflight
         # prices — used verbatim when the backend has no memory_stats()
@@ -776,9 +782,10 @@ class InferenceEngine:
         dispatches. Every value is already a (replicated, on-mesh) jax
         array, so handing it to the compiled step costs zero host work —
         no per-iteration rebuild, no numpy→device transfer. Correct for
-        any all-inert batch because the traced ``lax.cond`` in
-        ``pick_tokens`` takes the bare-argmax branch without reading a
-        single lane value."""
+        any all-inert batch because ``pick_tokens`` reads nothing of a
+        request from an inert lane: blank lanes leave the filters the
+        identity, keep the sampler's ``lax.cond`` on its greedy side, and
+        every slot is served the argmax of its logits."""
         if self._lanes_idle is None:
             lanes = blank_lanes(self.config.num_slots, self.config.rep_window)
             if self.mesh is not None:
@@ -892,8 +899,8 @@ class InferenceEngine:
         derived-key root ride as traced inputs of the ONE decode
         executable — their shapes/dtypes are engine geometry, so
         per-request variation is data, never a retrace. Each burst step
-        runs :func:`sampling.pick_tokens` (which drops to a bare argmax
-        under ``lax.cond`` when every lane is inert) and advances the
+        runs :func:`sampling.pick_tokens` (whose sampler stage sits under
+        a ``lax.cond`` on whether any lane samples) and advances the
         per-slot DFA state in-trace for mid-burst masking; the host
         re-derives the authoritative state per emitted token, so discarded
         burst tails never corrupt it.  The per-step top-N logprob harvest
@@ -1418,6 +1425,7 @@ class InferenceEngine:
         self._ttft_sum_s = self._ttft_queue_sum_s = self._ttft_own_prefill_sum_s = 0.0
         self._ttft_prefill_iterations_sum = 0
         self._paged_entries_walked = self._paged_entries_table = 0
+        self._pick_dispatches = self._pick_draw_dispatches = 0
         # hit accounting restarts with the measurement window; the trie and
         # its cached blocks deliberately stay warm (steady-state behaviour
         # is what a warmed bench leg measures)
@@ -1585,6 +1593,10 @@ class InferenceEngine:
             # the entries its tables hold, both x the layers that were run
             "paged_entries_walked_total": self._paged_entries_walked,
             "paged_entries_table_total": self._paged_entries_table,
+            # the pick's work: runs of pick_tokens, and those in which some
+            # lane samples (the only ones that sort the vocabulary and draw)
+            "pick_dispatches_total": self._pick_dispatches,
+            "pick_draw_dispatches_total": self._pick_draw_dispatches,
         }
         out.update(self._spec_stats())
         out.update(self._sampling_stats())
@@ -2125,6 +2137,15 @@ class InferenceEngine:
         self._paged_entries_walked += int(walked.sum()) * layers
         self._paged_entries_table += walked.size * self._mb * layers
 
+    def _count_pick(self, lanes) -> None:
+        """Book one run of :func:`sampling.pick_tokens` from the host's copy
+        of the lanes it is handed: its sampler stage runs when some lane
+        samples. The cached idle lanes are blank by construction and live
+        on the device; they are not read back."""
+        self._pick_dispatches += 1
+        if lanes is not self._lanes_idle and lanes["sample"].any():
+            self._pick_draw_dispatches += 1
+
     def _prefill_one_chunk(self, req: Request, finished: list[Request]) -> None:
         """One chunk of one prompt. Its two clock reads are the request's
         own-prefill stamps: the usage ledger's prefill accrual, the
@@ -2285,8 +2306,8 @@ class InferenceEngine:
         # swap, and slot reassignment can never desynchronise them); the
         # shapes/dtypes are engine geometry — one abstract signature forever.
         # When every live request is inert the cached device-resident blank
-        # dict stands in — the traced lax.cond argmaxes without reading a
-        # single lane value, so stale contents cannot matter
+        # dict stands in: such a batch's own lanes would differ from it in
+        # `pos` and `seed` alone, which only a sampling or min-token lane reads
         if all(
             (req.sampling or self._default_sampling).inert
             and not req.grammar_row
@@ -2333,6 +2354,7 @@ class InferenceEngine:
         self._count_paged_entries(
             pos0 + np.arange(cfg.decode_burst)[:, None], 1, self._cache_spec.paged_layers
         )
+        self._count_pick(lanes)
         self._cache, next_toks, logps, tvals, tids = self._decode_fn(
             self._params, self._cache, self._block_tables.copy(), pos0, toks, active,
             lanes, self._gmask, self._gtrans, self._base_key,
@@ -2439,6 +2461,7 @@ class InferenceEngine:
             dfa_state=req.dfa_state,
             recent=req.prompt if params.repetition_penalty != 1.0 else (),
         )
+        self._count_pick(lanes)
         tok, logp, tvals, tids = self._first_pick_fn(
             logits[None], lanes, self._gmask, self._base_key
         )

@@ -18,10 +18,16 @@ cycles.  The ``tag`` separates the independent draws a speculative round
 makes at the same position (draft proposal, accept/reject uniform,
 residual resample).
 
-The greedy fast path matters: when no live slot needs sampling, grammar
-masking, repetition penalty, or min-token suppression, ``pick_tokens``
-drops to a bare argmax under ``lax.cond`` — the tokens a pick by argmax
-alone serves (``tests/test_sampling_serving.py`` holds them recorded); what
+A stage of the pick runs only when a live lane asks for it, decided
+inside the program from the lanes it is handed.  The filters, the argmax
+and — when the engine harvests log-probabilities — the float32
+``log_softmax`` with its ``top_k`` are a few passes over ``[slots, vocab]``
+and run on every step.  The sampler (:func:`dist_logprobs`' whole-vocabulary
+sort, cumulative sum and scatter, and the per-slot random draw) sits under
+a ``lax.cond`` on ``any(lanes["sample"])``: a batch in which nobody samples
+pays for none of it, whether or not it harvests, and is served the token a
+pick by argmax alone serves (``tests/test_sampling_serving.py`` holds them
+recorded, and holds the pick bit-equal to the unstaged composition).  What
 the pick costs on the chip is ``scope.sample_pct.chat`` in ``PERF.md``.
 
 Host-side bookkeeping (stop sequences, min/max tokens, the authoritative
@@ -122,7 +128,7 @@ class SamplingParams:
     @property
     def inert(self):
         """True when this request is indistinguishable from bare greedy —
-        lets the engine keep the argmax fast path for the whole batch."""
+        a batch of such requests is handed the engine's cached blank lanes."""
         return (
             not self.do_sample
             and self.repetition_penalty == 1.0
@@ -323,46 +329,43 @@ def pick_tokens(logits, lanes, dfa_state, step, gmask, base_key, *, eos_id, logp
     top_vals [S,N], top_ids [S,N])`` with ``N = max(logprobs_topn, 1)``
     (zeros when harvesting is off — the shapes must be static).
 
-    When every lane is inert a ``lax.cond`` routes the whole batch to a
-    bare argmax, so greedy traffic pays for none of the filters.
+    One body, three stages.  The filters and the argmax run on every call
+    (with inert lanes :func:`apply_filters` changes no value an argmax or
+    a softmax can see).  The sampler — :func:`dist_logprobs` and the
+    per-slot draw — runs only when some lane samples: free slots hold the
+    inert default, so "any lane" is "any live lane", and with one lane
+    sampling the whole batch takes it.  The harvest runs when the engine
+    was built with ``logprobs_topn > 0``; it reads the filtered logits and
+    the served token, never the sampler's distribution, so asking for a
+    log-probability does not bring the sampler with it.
     """
-    num_slots, vocab = logits.shape
+    num_slots = logits.shape[0]
     n = max(int(logprobs_topn), 1)
     pos = lanes["pos"] + step
 
-    def plain(_):
-        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    filtered = apply_filters(logits, lanes, dfa_state, pos, gmask, eos_id)
+    greedy = jnp.argmax(filtered, axis=-1).astype(jnp.int32)
+
+    def draw():
+        logp_dist = dist_logprobs(filtered, lanes)
+        keys = slot_keys(base_key, lanes["seed"], pos, TAG_SAMPLE)
+        return categorical_per_slot(keys, logp_dist)
+
+    sampled = jax.lax.cond(jnp.any(lanes["sample"]), draw, lambda: greedy)
+    tok = jnp.where(lanes["sample"], sampled, greedy).astype(jnp.int32)
+    if logprobs_topn <= 0:
         return (
             tok,
             jnp.zeros((num_slots,), jnp.float32),
             jnp.zeros((num_slots, n), jnp.float32),
             jnp.zeros((num_slots, n), jnp.int32),
         )
-
-    def fancy(_):
-        filtered = apply_filters(logits, lanes, dfa_state, pos, gmask, eos_id)
-        greedy = jnp.argmax(filtered, axis=-1).astype(jnp.int32)
-        logp_dist = dist_logprobs(filtered, lanes)
-        keys = slot_keys(base_key, lanes["seed"], pos, TAG_SAMPLE)
-        sampled = categorical_per_slot(keys, logp_dist)
-        tok = jnp.where(lanes["sample"], sampled, greedy).astype(jnp.int32)
-        # reported logprobs are the filtered distribution at temperature 1
-        # (OpenAI semantics: the model's distribution, not the sampler's)
-        lp = jax.nn.log_softmax(jnp.asarray(filtered, jnp.float32), axis=-1)
-        logp_tok = jnp.take_along_axis(lp, tok[:, None], axis=1)[:, 0]
-        top_vals, top_ids = jax.lax.top_k(lp, n)
-        return tok, logp_tok, top_vals, top_ids.astype(jnp.int32)
-
-    if logprobs_topn > 0:
-        return fancy(None)
-
-    work = (
-        jnp.any(lanes["sample"])
-        | jnp.any(lanes["grammar_row"] > 0)
-        | jnp.any(lanes["rep"] != 1.0)
-        | jnp.any(pos < lanes["min_tokens"])
-    )
-    return jax.lax.cond(work, fancy, plain, None)
+    # reported logprobs are the filtered distribution at temperature 1
+    # (OpenAI semantics: the model's distribution, not the sampler's)
+    lp = jax.nn.log_softmax(jnp.asarray(filtered, jnp.float32), axis=-1)
+    logp_tok = jnp.take_along_axis(lp, tok[:, None], axis=1)[:, 0]
+    top_vals, top_ids = jax.lax.top_k(lp, n)
+    return tok, logp_tok, top_vals, top_ids.astype(jnp.int32)
 
 
 # --------------------------------------------------------------------------

@@ -12,7 +12,8 @@ Tier-1 (pure host / no compiles): params validation + resolution, stop
 matching, the regex→DFA compiler and JSON-schema subset, the OpenAI
 request/response translation (golden payloads, SSE framing, error
 objects) against a fake submit fn, the metrics/monitor plumbing, the
-recorded greedy tokens and the prefill program's operands. The other
+pick's stages against their unstaged composition (bit for bit) and its
+counters, the recorded greedy tokens and the prefill program's operands. The other
 engine end-to-end legs and the real ``serve --http`` / ``route --http``
 subprocess tests ride the slow lane.
 """
@@ -408,7 +409,156 @@ def test_sampling_metrics_round_trip_both_surfaces():
 
 
 # ---------------------------------------------------------------------------
-# engine end-to-end (compiles the tiny model; slow lane but for the first two)
+# the pick's stages (tier-1; pick_tokens alone, no model)
+# ---------------------------------------------------------------------------
+
+PICK_SLOTS, PICK_VOCAB, PICK_EOS, PICK_STEP = 6, 1003, 2, 1
+
+
+def _unstaged_pick(logits, lanes, dfa_state, step, gmask, base_key, *, eos_id, logprobs_topn):
+    """The body of ``fancy`` at commit 5de64b7, frozen: every stage for
+    every slot on every call. The reference ``pick_tokens`` must equal bit
+    for bit, whatever its lanes ask."""
+    import jax
+    import jax.numpy as jnp
+
+    from accelerate_tpu.serving import sampling as S
+
+    n = max(int(logprobs_topn), 1)
+    pos = lanes["pos"] + step
+    filtered = S.apply_filters(logits, lanes, dfa_state, pos, gmask, eos_id)
+    greedy = jnp.argmax(filtered, axis=-1).astype(jnp.int32)
+    logp_dist = S.dist_logprobs(filtered, lanes)
+    keys = S.slot_keys(base_key, lanes["seed"], pos, S.TAG_SAMPLE)
+    sampled = S.categorical_per_slot(keys, logp_dist)
+    tok = jnp.where(lanes["sample"], sampled, greedy).astype(jnp.int32)
+    lp = jax.nn.log_softmax(jnp.asarray(filtered, jnp.float32), axis=-1)
+    logp_tok = jnp.take_along_axis(lp, tok[:, None], axis=1)[:, 0]
+    top_vals, top_ids = jax.lax.top_k(lp, n)
+    return tok, logp_tok, top_vals, top_ids.astype(jnp.int32)
+
+
+def _pick_operands(dtype, mix):
+    """Random logits with ties planted at every row's maximum (two more
+    columns hold it, one of them ``eos``), all lanes live and greedy but
+    for slot 3, which ``mix`` arms."""
+    import jax
+    import jax.numpy as jnp
+
+    from accelerate_tpu.serving.sampling import blank_lanes, set_slot_lane
+
+    rng = np.random.default_rng(31)
+    logits = (rng.standard_normal((PICK_SLOTS, PICK_VOCAB)) * 4).astype(np.float32)
+    top = logits.max(axis=1) + 0.5
+    for col in (PICK_EOS, 517, 998):
+        logits[:, col] = top
+    lanes = blank_lanes(PICK_SLOTS, 8)
+    for slot in range(PICK_SLOTS):
+        set_slot_lane(lanes, slot, SamplingParams(seed=slot, logprobs=1), pos=slot)
+    params = {
+        "greedy": SamplingParams(logprobs=1),
+        "sample": SamplingParams(do_sample=True, temperature=0.7, top_p=0.9, seed=9),
+        "repetition": SamplingParams(repetition_penalty=1.7),
+        "min_tokens": SamplingParams(min_tokens=7),
+        "grammar": SamplingParams(),
+    }[mix]
+    set_slot_lane(
+        lanes, 3, params, pos=3, grammar_row=int(mix == "grammar"), dfa_state=2,
+        recent=(PICK_EOS, 517, 40) if mix == "repetition" else (),
+    )
+    gmask = np.ones((2, 4, PICK_VOCAB), bool)
+    gmask[1, 2, : PICK_VOCAB // 2] = False  # row 1, state 2: the upper half only
+    return (
+        jnp.asarray(logits, dtype), {k: jnp.asarray(v) for k, v in lanes.items()},
+        jnp.asarray(lanes["dfa_state"]), jnp.int32(PICK_STEP), jnp.asarray(gmask),
+        jax.random.PRNGKey(4),
+    )
+
+
+@pytest.mark.parametrize("mix", ["greedy", "sample", "repetition", "min_tokens", "grammar"])
+@pytest.mark.parametrize("topn", [1, 3])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_pick_runs_only_the_stages_its_lanes_ask_for(dtype, topn, mix):
+    """Token, its log-probability and the top-N are those of the unstaged
+    composition to the bit: staging may skip work nobody asked for and may
+    change no value. bfloat16 is what the engine hands the pick (a
+    log-probability taken in bfloat16 fails here, by dtype and by bits);
+    the vocabulary is no multiple of 128."""
+    import functools
+
+    import jax
+
+    from accelerate_tpu.serving.sampling import pick_tokens
+
+    operands = _pick_operands(dtype, mix)
+    kw = dict(eos_id=PICK_EOS, logprobs_topn=topn)
+    want = jax.jit(functools.partial(_unstaged_pick, **kw))(*operands)
+    got = jax.jit(functools.partial(pick_tokens, **kw))(*operands)
+    for name, a, b in zip(("tok", "logp_tok", "top_vals", "top_ids"), got, want):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        assert a.tobytes() == b.tobytes(), (name, a, b)
+    tok = np.asarray(got[0])
+    # the planted ties decide the greedy slots: the first column that holds
+    # the maximum, which is eos
+    assert (np.delete(tok, 3) == PICK_EOS).all()
+    if mix in ("repetition", "min_tokens"):
+        assert tok[3] == {"repetition": 998, "min_tokens": 517}[mix]
+    if mix == "grammar":
+        assert tok[3] == 517  # the first maximum the mask allows
+
+
+def _vocabulary_wide_ops(jaxpr, vocab, in_cond=False):
+    """(primitive, inside a cond branch) of every equation that sorts,
+    scans, scatters or draws over the vocabulary, through every nested
+    jaxpr."""
+    found = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        wide = (
+            name in ("sort", "cumsum", "random_bits", "threefry2x32")
+            or (name == "top_k" and eqn.params["k"] == vocab)
+            or (name.startswith("scatter") and vocab in eqn.invars[2].aval.shape)
+        )
+        if wide:
+            found.append((name, in_cond))
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)  # a ClosedJaxpr's own
+                if hasattr(sub, "eqns"):
+                    found += _vocabulary_wide_ops(sub, vocab, in_cond or name == "cond")
+    return found
+
+
+def test_pick_keeps_the_sampler_inside_a_cond():
+    """At ``logprobs_topn=1`` no sort, cumulative sum, random draw or
+    vocabulary-wide scatter sits outside a ``cond`` branch: a later edit
+    that hoists the draw back out fails here, on the CPU."""
+    import functools
+
+    import jax
+
+    from accelerate_tpu.serving.sampling import pick_tokens
+
+    operands = _pick_operands("bfloat16", "greedy")
+    jaxpr = jax.make_jaxpr(
+        functools.partial(pick_tokens, eos_id=PICK_EOS, logprobs_topn=1)
+    )(*operands).jaxpr
+    found = _vocabulary_wide_ops(jaxpr, PICK_VOCAB)
+    assert [name for name, in_cond in found if not in_cond] == []
+    inside = {name for name, in_cond in found if in_cond}
+    assert {"top_k", "cumsum", "scatter"} <= inside
+    assert inside & {"random_bits", "threefry2x32"}
+    # and the reference does hold them at the top level: the walk sees them
+    unstaged = jax.make_jaxpr(
+        functools.partial(_unstaged_pick, eos_id=PICK_EOS, logprobs_topn=1)
+    )(*operands).jaxpr
+    assert {name for name, in_cond in _vocabulary_wide_ops(unstaged, PICK_VOCAB)
+            if not in_cond} >= {"top_k", "cumsum", "scatter"}
+
+
+# ---------------------------------------------------------------------------
+# engine end-to-end (compiles the tiny model; slow lane but for the first three)
 # ---------------------------------------------------------------------------
 
 KV_DTYPES = ("bf16", "int8", "fp8")
@@ -472,6 +622,31 @@ def test_greedy_traffic_is_served_the_recorded_tokens(tiny_model, kv_dtype):
     assert st["decode_compiles"] == 1 and st["prefill_compiles"] == 1
     assert st["sampled_tokens_greedy"] == sum(budgets)
     assert st["sampled_tokens_sample"] == 0
+
+
+def test_pick_counters_follow_the_lanes(tiny_model):
+    """``pick_draw_dispatches_total`` counts the runs of the pick in which
+    some lane samples: none for greedy traffic, with or without a
+    log-probability asked for; ``reset_stats()`` zeroes both counters."""
+    eng = InferenceEngine(tiny_model, _cfg(logprobs_topn=1))
+    prompts = _prompts(4, sizes=(5, 9, 7))
+    eng.add_request(prompts[0], 6)
+    eng.add_request(prompts[1], 6, sampling={"logprobs": 1})
+    eng.run_until_idle(max_iterations=5000)
+    st = eng.stats()
+    # two first picks, and at least one burst of decode
+    assert st["pick_dispatches_total"] >= 3
+    assert st["pick_draw_dispatches_total"] == 0
+    eng.reset_stats()
+    st = eng.stats()
+    assert st["pick_dispatches_total"] == st["pick_draw_dispatches_total"] == 0
+    eng.add_request(
+        prompts[2], 6, sampling={"do_sample": True, "temperature": 0.8, "seed": 3}
+    )
+    eng.run_until_idle(max_iterations=5000)
+    st = eng.stats()
+    assert st["pick_draw_dispatches_total"] == st["pick_dispatches_total"] >= 2
+    assert st["decode_compiles"] == 1
 
 
 def test_prefill_program_takes_no_key_and_picks_no_token(tiny_model):
