@@ -13,6 +13,24 @@ itself, so a narrower type anywhere on the path — weights, activations, the
 KV pool — moves it in proportion. Each number compared has a limit of its
 own in the configuration file, set from readings on the chip (``PERF.md``
 gives them), and every run prints each number beside its limit.
+
+A configuration that states a float32 recurrent state (``check.narrow_state``
+names the narrower type its control stores) is held to that by a number
+paired with the run's own seed, requests and positions: the reference runs a
+second time over each sampled request with its state rounded to that type
+once a token and nothing else changed, which says **which way and how far a
+narrow state moves each served log-probability**, ``d = narrow - exact``.
+``narrow_state_share`` is the least-squares share of ``d`` in the program's
+signed error ``r = reported - exact``: ``sum(r d) / sum(d d)`` over every
+served position of the sample. What a narrow state does to a sequence is no
+noise (the reference's rounding and the program's move the same positions
+the same way), so a program that stores the state in the narrow type reads
+near 1 (0.86-0.99 on six seeds of the hybrid cell) and one whose state is as
+wide as stated reads about 0 or under it (-0.17..+0.04 on eight), whatever
+its rounding noise, large or small, does to the mean error. The mean absolute
+error cannot tell the two apart on a seed whose sample leans little on the
+state: there the narrow state adds less than sound seeds differ (``PERF.md``,
+PR 35).
 """
 
 from __future__ import annotations
@@ -33,10 +51,12 @@ def _bucket(n: int) -> int:
     raise ValueError(f"sequence of {n} tokens is longer than the reference's largest bucket")
 
 
-def sequence_readings(config: dict, seed: int, prompt, tokens, served_dtype: str) -> dict:
+def sequence_readings(config: dict, seed: int, prompt, tokens, served_dtype: str,
+                      **forward) -> dict:
     """The reference's reading at each served position of one request:
     ``gaps`` (best logit minus the served token's), ``logprobs`` (the
-    served token's log-probability) and the mean logit spread."""
+    served token's log-probability) and the mean logit spread. ``forward``
+    goes to the reference's ``logits_at`` (``state_dtype=``)."""
     from perfbench import common
 
     prompt = np.asarray(prompt, np.int32).reshape(-1)
@@ -50,7 +70,7 @@ def sequence_readings(config: dict, seed: int, prompt, tokens, served_dtype: str
     n_rows = -(-n_t // ROW_BLOCK) * ROW_BLOCK
     rows_p = np.concatenate([rows, np.full((n_rows - n_t,), rows[-1])])
     logits = np.asarray(common.load_reference(config).logits_at(
-        config, seed, padded, valid, rows_p, served_dtype), np.float64)[:n_t]
+        config, seed, padded, valid, rows_p, served_dtype, **forward), np.float64)[:n_t]
     best = logits.max(axis=-1)
     at_token = logits[np.arange(n_t), tokens]
     lse = best + np.log(np.exp(logits - best[:, None]).sum(axis=-1))
@@ -63,31 +83,44 @@ def served(config: dict, seed: int, sample: list, served_dtype: str) -> dict:
     ``(prompt, served tokens, reported log-probabilities or None)``.
     ``gap_max`` is in units of the reference's logit spread (the standard
     deviation over the vocabulary, averaged), so its limit means the same at
-    any width; ``logprob_err_mean`` is in nats."""
+    any width; ``logprob_err_mean`` is in nats; ``narrow_state_share`` (the
+    module's docstring) is a share, read where the configuration's check
+    names a ``narrow_state``."""
     limits = config["check"]["limits"]
+    narrow = config["check"].get("narrow_state")
     if not sample:
         return {"ok": False, "reason": "the window finished no request to compare",
                 "numbers": {}, "limits": limits}
-    gaps, errs, n_tokens = [], [], 0
+    gaps, errs, moved, n_tokens = [], [], [], 0
     for prompt, tokens, reported in sample:
         out = sequence_readings(config, seed, prompt, tokens, served_dtype)
         gaps.append(out["gaps"] / out["logit_std"])
         if reported is not None and len(reported) == len(tokens):
-            errs.append(np.abs(np.asarray(reported, np.float64) - out["logprobs"]))
+            errs.append(np.asarray(reported, np.float64) - out["logprobs"])
+            if narrow:
+                moved.append(sequence_readings(config, seed, prompt, tokens, served_dtype,
+                                               state_dtype=narrow)["logprobs"] - out["logprobs"])
         n_tokens += len(tokens)
     allg = np.concatenate(gaps)
     numbers = {"gap_max": float(allg.max())}
     beside = {"gap_mean": float(allg.mean()), "off_best_share": float(np.mean(allg > 0))}
     if len(errs) == len(sample):
         alle = np.concatenate(errs)
-        numbers["logprob_err_mean"] = float(alle.mean())
-        beside["logprob_err_max"] = float(alle.max())
+        numbers["logprob_err_mean"] = float(np.abs(alle).mean())
+        beside["logprob_err_max"] = float(np.abs(alle).max())
+        if narrow:
+            d = np.concatenate(moved)
+            beside["narrow_state_moves_mean"] = float(np.abs(d).mean())
+            if (d * d).sum() > 0:
+                numbers["narrow_state_share"] = float((alle * d).sum() / (d * d).sum())
     missing = [k for k in limits if k not in numbers]
     ok = not missing and all(numbers[k] <= limits[k] for k in limits)
     out = {"ok": bool(ok), "numbers": numbers, "limits": limits, "beside": beside,
            "requests": len(sample), "tokens": n_tokens}
     if missing:
-        out["reason"] = f"nothing to compare for {missing}: the program reported no log-probabilities"
+        out["reason"] = (f"nothing to compare for {missing}: the program reported no "
+                         "log-probabilities, or the configuration's check names no narrow_state "
+                         "that moves one")
     return out
 
 

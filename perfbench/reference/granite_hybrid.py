@@ -116,9 +116,9 @@ def mlp(cfg: dict, w: dict, x):
     return x + cfg["residual_multiplier"] * out
 
 
-def mamba_mixer(cfg: dict, w: dict, y):
+def mamba_mixer(cfg: dict, w: dict, y, state_dtype: str = "float32"):
     """``y [T, h]`` (normed) -> the mixer's output ``[T, h]``, token by
-    token from a zero state."""
+    token from a zero state; ``state_dtype`` as :func:`logits_at` has it."""
     z = _sizes(cfg)
     t = y.shape[0]
     gate, xbc = jnp.split(jnp.dot(y, w["in_proj"], precision=HI), [z["d_inner"]], axis=-1)
@@ -136,6 +136,12 @@ def mamba_mixer(cfg: dict, w: dict, y):
         x_t, b_t, c_t, d_t = inp
         state = (jnp.exp(d_t * a)[:, None, None] * state
                  + (d_t[:, None] * x_t)[:, :, None] * b_t[None, None, :])
+        if state_dtype != "float32":
+            # what is carried, as the narrower type holds it (ties to even);
+            # an operation of its own, which no compiler folds away as it
+            # may a pair of conversions
+            fi = jnp.finfo(state_dtype)
+            state = jax.lax.reduce_precision(state, fi.nexp, fi.nmant)
         return state, jnp.einsum("hpn,n->hp", state, c_t, precision=HI)
 
     state0 = jnp.zeros((z["mh"], z["p"], z["n"]), jnp.float32)
@@ -160,18 +166,21 @@ def attention_mixer(cfg: dict, w: dict, y, valid_len):
     return jnp.dot(a, w["wo"], precision=HI)
 
 
-def layer(cfg: dict, kind: str, w: dict, x, valid_len):
+def layer(cfg: dict, kind: str, w: dict, x, valid_len, state_dtype: str = "float32"):
     """One layer on ``x [T, h]`` (positions ``0..T-1``; rows ``>=
     valid_len`` are padding: causality keeps them out of every valid row)."""
     y = rms_norm(x, w["norm"], cfg["rms_norm_eps"])
-    mixed = mamba_mixer(cfg, w, y) if kind == "mamba" else attention_mixer(cfg, w, y, valid_len)
+    mixed = (mamba_mixer(cfg, w, y, state_dtype) if kind == "mamba"
+             else attention_mixer(cfg, w, y, valid_len))
     return mlp(cfg, w, x + cfg["residual_multiplier"] * mixed)
 
 
 @functools.lru_cache(maxsize=None)
-def _programs(cfg_items: tuple, layer_types: tuple, scale_items: tuple, served_dtype: str):
-    """The jitted pieces for one configuration: embed, one layer of each
-    kind (weights made inside from the key, never all resident), head."""
+def _programs(cfg_items: tuple, layer_types: tuple, scale_items: tuple, served_dtype: str,
+              state_dtype: str = "float32"):
+    """The jitted pieces for one configuration and one ``state_dtype``:
+    embed, one layer of each kind (weights made inside from the key, never
+    all resident), head."""
     cfg = dict(cfg_items, layer_types=list(layer_types))
     scales = dict(scale_items)
     shapes = leaf_shapes(cfg)
@@ -189,7 +198,7 @@ def _programs(cfg_items: tuple, layer_types: tuple, scale_items: tuple, served_d
         @jax.jit
         def run(key, l, x, valid_len):
             w = {n: get(key, f"layers.{kind}.{n}", l) for n in leaves}
-            return layer(cfg, kind, w, x, valid_len)
+            return layer(cfg, kind, w, x, valid_len, state_dtype)
         return run
 
     @jax.jit
@@ -201,13 +210,21 @@ def _programs(cfg_items: tuple, layer_types: tuple, scale_items: tuple, served_d
                    "attention": one_layer("attention", ATTENTION_LEAVES)}, head
 
 
-def logits_at(cfg: dict, seed: int, ids, valid_len: int, rows, served_dtype="bfloat16"):
+def logits_at(cfg: dict, seed: int, ids, valid_len: int, rows, served_dtype="bfloat16",
+              state_dtype="float32"):
     """Logits ``[len(rows), vocab]`` of the sequence ``ids [T]`` (padded;
-    ``valid_len`` real tokens) at positions ``rows``, layer by layer."""
+    ``valid_len`` real tokens) at positions ``rows``, layer by layer.
+    ``state_dtype`` other than float32 rounds the recurrent state to that
+    type once a token, after its update (``y_t`` reads the rounded state),
+    and changes nothing else: what a program does that keeps the state
+    narrower than the configuration states, on these very tokens. The check
+    reads from it which way and how far such a state moves each served
+    log-probability (``check.served``: ``narrow_state_share``)."""
     items = tuple(sorted((k, v) for k, v in cfg.items() if not isinstance(v, (dict, list))))
     scales = tuple(sorted(cfg.get("weight_scales", {}).items()))
     embed, layers, head = _programs(
-        items, tuple(cfg["layer_types"][: cfg["num_hidden_layers"]]), scales, str(served_dtype))
+        items, tuple(cfg["layer_types"][: cfg["num_hidden_layers"]]), scales, str(served_dtype),
+        str(jnp.dtype(state_dtype)))
     key = weights.root_key(seed)
     x = embed(key, jnp.asarray(ids, jnp.int32))
     for kind, i in _kinds(cfg):

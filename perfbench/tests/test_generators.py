@@ -6,7 +6,10 @@ import numpy as np
 from perfbench import common
 from perfbench.generators import base, closed_loop, open_loop_lognormal, train_tokens
 
-TRAFFIC = common.read_json(common.os.path.join(common.HERE, "traffic", "chat-steady.json"))
+#: the committed mix, with the seed drawing the order inside every 3 arrivals
+#: (the committed file's own group is 1: its seed draws jitter and ids only)
+TRAFFIC = dict(common.read_json(common.os.path.join(common.HERE, "traffic", "chat-steady.json")),
+               shuffle_group=3)
 BIG = 3_000_000_019  # the driver's seeds pass 2**31
 
 
@@ -58,6 +61,16 @@ def test_every_block_of_arrivals_holds_the_same_mix():
     whole = base.shuffle_groups(12, 12, np.random.default_rng(3))
     assert sorted(whole) == list(range(12)) and list(whole) != list(range(12))
     assert list(base.shuffle_groups(12, 1, np.random.default_rng(3))) == list(range(12))
+
+
+def test_a_group_of_one_leaves_the_seed_the_jitter_and_the_ids_only():
+    one = dict(TRAFFIC, shuffle_group=1)
+    a, b = (open_loop_lognormal.make(one, s, 20.0, 32768).initial() for s in (1, BIG))
+    assert [len(r.prompt) for r in a] == [len(r.prompt) for r in b]
+    assert [r.max_new_tokens for r in a] == [r.max_new_tokens for r in b]
+    moved = [abs(x.due_s - y.due_s) for x, y in zip(a, b)]
+    assert 0 < max(moved) <= 2 * one["jitter_s"]
+    assert not np.array_equal(a[0].prompt, b[0].prompt)
 
 
 def test_length_sets_follow_the_stated_distribution():

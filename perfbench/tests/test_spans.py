@@ -26,9 +26,14 @@ def _flight(at_us, intervals):
 
 KERNELS = ("paged_attention", "flash_attention_fwd", "flash_attention_bwd_dq",
            "flash_attention_bwd_dkv")
-#: every per-layer metric of the benchmark: between them the two cells list
-#: the nine scopes the program has today
-LISTED = [m["name"] for m in common.benchmark()["per_layer"]]
+#: the fixture is a trace of the Mistral-shaped programs: the cells whose
+#: configuration file names that model, and the per-layer metrics that list
+#: one of them (a cell of another architecture lists scopes of its own);
+#: between them those cells list the nine scopes of that model's programs
+BENCH = common.benchmark()
+CELLS = {w["name"] for w in BENCH["workloads"]
+         if common.find_cell(BENCH, w["name"])[1]["model_type"] == "mistral"}
+LISTED = [m["name"] for m in BENCH["per_layer"] if CELLS & set(m.get("workloads", CELLS))]
 NINE = frozenset({"embed", "attn_proj", "kv_write", "attn_kernel", "mlp", "head", "sample",
                   "loss", "optimizer"})
 
