@@ -121,7 +121,7 @@ def __getattr__(name):
 #: the ``model_type`` values :func:`config_from_hf_json` maps
 KNOWN_MODEL_TYPES = (
     "llama", "mistral", "gpt2", "bert", "vit", "opt", "gpt_neox", "gptj", "mixtral",
-    "t5", "mt5", "granitemoehybrid",
+    "t5", "mt5", "granitemoehybrid", "lfm2_moe",
 )
 
 
@@ -285,6 +285,14 @@ def config_from_hf_json(path: str):
             )
         fields = {f.name for f in dataclasses.fields(GraniteHybridConfig)} - {"remat"}
         return GraniteHybridConfig(**{k: d[k] for k in fields if d.get(k) is not None})
+    if mt == "lfm2_moe":
+        from .lfm2 import Lfm2MoeConfig
+
+        # what the file leaves out is the published model's; what it states and
+        # cannot be built (a third layer kind, more dense layers than layers, more
+        # choices than experts, conv_bias) is refused by the config itself
+        fields = {f.name for f in dataclasses.fields(Lfm2MoeConfig)} - {"remat"}
+        return Lfm2MoeConfig(**{k: d[k] for k in fields if d.get(k) is not None})
     raise ValueError(
         f"unsupported model_type {mt!r} (known: {', '.join(KNOWN_MODEL_TYPES)})"
     )
@@ -300,6 +308,10 @@ def model_factory_for_config(config):
         from .granite_hybrid import GraniteHybridForCausalLM
 
         return lambda c, **kw: GraniteHybridForCausalLM.from_config(c, **kw)
+    if name == "Lfm2MoeConfig":
+        from .lfm2 import Lfm2MoeForCausalLM
+
+        return lambda c, **kw: Lfm2MoeForCausalLM.from_config(c, **kw)
     if name == "GPT2Config":
         from .gpt2 import GPT2LMHeadModel
 

@@ -432,6 +432,7 @@ def _paged_step(c, params, input_ids, cache, block_tables, cache_positions,
         with jax.named_scope("ssm_conv"):
             conv = cache["conv"]
             tail = conv[i] if decode else conv[i, slots]
+            # Mamba-2's convolution: bias and silu (conv_with_tail's defaults)
             xbc, tail = conv_with_tail(xbc, tail, layer["conv_w"], layer["conv_b"], n_valid)
             conv = conv.at[i].set(tail) if decode else conv.at[i, slots].set(tail)
         with jax.named_scope("ssm_scan"):

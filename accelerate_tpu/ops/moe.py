@@ -1,0 +1,123 @@
+"""Routed experts without a capacity: a sigmoid router with a selection
+bias, and the dropless expert product.
+
+    s = sigmoid(x W_g)                                   [T, E], float32
+    chosen = top_k(s + expert_bias)                      selection only
+    weight = s[chosen] / (sum s[chosen] + 1e-6) * scale  the un-biased scores
+    y = sum_i weight_i * W_out[e_i](silu(g) * u),  [g | u] = x W_in[e_i]
+
+The (token, choice) pairs are grouped by expert — a stable sort of the
+``T * k`` expert ids — and each group is multiplied by its own expert's
+matrices in one grouped product (:func:`grouped_matmul`). Shapes are static
+(``T * k`` pairs whatever the routing); no pair is dropped, whatever the
+imbalance: all tokens to one expert is one group of ``T * k`` rows. A token
+that ``live`` switches off routes nowhere: its pairs sort behind every
+group, belong to none, add nothing to ``counts`` and cost no expert's
+weights a read.
+
+:mod:`..models.mixtral`'s ``moe_ffn`` (capacity-bounded buffers, a softmax
+router, the GSPMD ``ep`` layout, trained) is another layer and is left as
+it is.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+_HI = jax.lax.Precision.HIGHEST
+
+#: rows, contraction and columns of one grid step of the grouped product on
+#: the chip: a whole 128-row tile of pairs against ``[k, 512]`` of one
+#: expert (2 MB in bfloat16 at k = 2048), so that an expert's matrix passes
+#: through VMEM once for every 128 pairs it was given
+_GMM_TILING = (128, 2048, 512)
+
+
+def default_moe_impl() -> str:
+    """The Pallas grouped product on a TPU backend, ``jax.lax.ragged_dot``
+    elsewhere — a static choice by platform, like ``default_ssm_impl``."""
+    return "gmm" if jax.default_backend() == "tpu" else "ragged"
+
+
+def route(x, w_gate, expert_bias, top_k: int, norm_topk_prob: bool = True,
+          routed_scaling_factor: float = 1.0):
+    """``x [T, h]`` -> ``(experts [T, k] int32, weights [T, k] float32)``.
+    The gate's product, the sigmoid and the top-k run in float32 (in
+    bfloat16 two scores tie). ``expert_bias [E]`` moves which experts are
+    chosen and never the weights of those chosen."""
+    f32 = jnp.float32
+    scores = jax.nn.sigmoid(
+        jnp.dot(x.astype(f32), w_gate.astype(f32), precision=_HI))
+    _, experts = jax.lax.top_k(scores + expert_bias.astype(f32), top_k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if norm_topk_prob:
+        weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-6)
+    return experts.astype(jnp.int32), weights * routed_scaling_factor
+
+
+def grouped_matmul(lhs, rhs, group_sizes, impl: str | None = None,
+                   interpret: bool = False):
+    """``lhs[rows of group e] @ rhs[e]`` for every group: ``lhs [m, k]``
+    sorted by group, ``rhs [E, k, n]``, ``group_sizes [E]`` int32 summing to
+    at most ``m``. Rows behind the last group are not computed; what comes
+    back for them is unspecified."""
+    if impl is None:
+        impl = default_moe_impl()
+    if impl == "ragged":
+        return jax.lax.ragged_dot(lhs, rhs, group_sizes.astype(jnp.int32))
+    if impl == "gmm":
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+        m, k = lhs.shape
+        tm, tk, tn = _GMM_TILING
+        pad = (-m) % tm
+        if pad:
+            lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
+        out = gmm(lhs, rhs, group_sizes.astype(jnp.int32), lhs.dtype,
+                  (tm, min(tk, k), min(tn, rhs.shape[-1])), None, None, False, interpret)
+        return out[:m] if pad else out
+    raise ValueError(f"unknown grouped_matmul impl {impl!r}")
+
+
+def expert_ffn(x, experts, weights, w_in, w_out, live=None, layer: int | None = None,
+               impl: str | None = None, interpret: bool = False):
+    """The dropless expert product. ``x [T, h]``; ``experts`` / ``weights``
+    ``[T, k]`` from :func:`route`; ``w_in [E, h, 2f]`` (gate | up),
+    ``w_out [E, f, h]``; ``live [T]`` bool (``None``: every token). With
+    ``layer`` (a static index) the matrices are a model's stacks ``[layers,
+    E, ...]`` and the product addresses ``(layer, expert)`` in them: no
+    layer's 32 experts are sliced out to be multiplied. Returns ``(y [T, h],
+    counts [E] int32)``: the weighted sum of each token's experts, zero for
+    a token that is not live, and the pairs each expert was given."""
+    t, k = experts.shape
+    n_experts = w_in.shape[-3]
+    if live is not None:
+        # a dead token's pairs get an expert id past the last: they sort
+        # behind every group and belong to none
+        experts = jnp.where(live[:, None], experts, n_experts)
+    flat = experts.reshape(t * k)
+    order = jnp.argsort(flat, stable=True)                     # pairs by expert
+    counts = (flat[:, None] == jnp.arange(n_experts, dtype=flat.dtype)).sum(
+        axis=0, dtype=jnp.int32)
+    grouped = x[order // k]                                    # [T*k, h]
+    sizes = counts
+    if impl is None:
+        impl = default_moe_impl()
+    if layer is not None and impl == "ragged":
+        w_in, w_out = w_in[layer], w_out[layer]
+    elif layer is not None:
+        # every (layer, expert) is a group; only this layer's are given rows
+        n_layers = w_in.shape[0]
+        sizes = jnp.zeros((n_layers, n_experts), jnp.int32).at[layer].set(counts).reshape(-1)
+        w_in = w_in.reshape(n_layers * n_experts, *w_in.shape[2:])
+        w_out = w_out.reshape(n_layers * n_experts, *w_out.shape[2:])
+    gate, up = jnp.split(grouped_matmul(grouped, w_in, sizes, impl, interpret), 2, axis=-1)
+    out = grouped_matmul((jax.nn.silu(gate) * up).astype(x.dtype), w_out, sizes,
+                         impl, interpret)
+    in_a_group = jnp.arange(t * k) < counts.sum()
+    out = jnp.where(in_a_group[:, None], out.astype(jnp.float32), 0.0)
+    out = out * weights.reshape(t * k)[order][:, None]
+    # back to (token, choice) order, then the sum over a token's choices
+    back = jnp.argsort(order)
+    return out[back].reshape(t, k, -1).sum(axis=1).astype(x.dtype), counts

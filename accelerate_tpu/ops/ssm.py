@@ -14,7 +14,8 @@ are of relative size ``dt`` (about 1e-2) for thousands of steps, which a
 bfloat16 state rounds away.
 
 * :func:`conv_with_tail` — prefill chunk and decode step alike: the last
-  ``d_conv - 1`` *valid* inputs of a row are its tail.
+  ``d_conv - 1`` *valid* inputs of a row are its tail (bias and activation
+  optional: LFM2's short convolution has neither).
 * :func:`ssd_chunk_scan` — the chunked form (quadratic inside a chunk of
   ``chunk`` tokens, the recurrence between chunks) starting from any
   state; a token whose ``dt`` and ``x`` are zero leaves the state where it
@@ -43,20 +44,23 @@ def default_ssm_impl() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "jnp"
 
 
-def conv_with_tail(xbc, tail, weight, bias, n_valid):
+def conv_with_tail(xbc, tail, weight, bias, n_valid, activation=jax.nn.silu):
     """Causal depthwise convolution of ``xbc [b, s, c]`` continuing from
     ``tail [b, k-1, c]`` (the inputs before the chunk), taps ``weight
-    [k, c]`` (tap ``k-1`` multiplies the current token), ``bias [c]``.
-    Returns ``(silu(conv) [b, s, c], new tail)``: the new tail is the last
-    ``k-1`` inputs before position ``n_valid[b]`` — the last valid ones —
-    so a row with no valid token keeps its tail bit for bit."""
+    [k, c]`` (tap ``k-1`` multiplies the current token), ``bias [c]`` or
+    ``None``. Returns ``(activation(conv) [b, s, c], new tail)``: the new
+    tail is the last ``k-1`` inputs before position ``n_valid[b]`` — the
+    last valid ones — so a row with no valid token keeps its tail bit for
+    bit. Mamba-2 (``models/granite_hybrid.py``) adds a bias and applies
+    ``silu``, the default; LFM2's gated short convolution
+    (``models/lfm2.py``) has neither: ``bias=None, activation=None``."""
     k = weight.shape[0]
     s = xbc.shape[1]
     full = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)  # [b, k-1+s, c]
-    acc = bias.astype(jnp.float32)
+    acc = 0.0 if bias is None else bias.astype(jnp.float32)
     for j in range(k):
         acc = acc + full[:, j:j + s].astype(jnp.float32) * weight[j].astype(jnp.float32)
-    out = jax.nn.silu(acc).astype(xbc.dtype)
+    out = (acc if activation is None else activation(acc)).astype(xbc.dtype)
     new_tail = jax.vmap(
         lambda row, n: jax.lax.dynamic_slice_in_dim(row, n, k - 1, axis=0)
     )(full, jnp.asarray(n_valid, jnp.int32))

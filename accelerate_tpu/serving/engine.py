@@ -271,6 +271,8 @@ class _InFlightRound:
     tvals: object = None
     tids: object = None
     harvest_lp: bool = False
+    #: the model's step counters of this round, ``{name: [burst, ...]}``
+    counters: object = None
 
 
 #: keys of a flight entry that place it on a clock; the ``serve/flight``
@@ -538,6 +540,18 @@ class InferenceEngine:
             self._cache[name] = jnp.zeros(
                 leaf.array_shape(cfg.num_slots), leaf.dtype or dtype)
         self._state_resets = 0
+        #: small int32 counters a model's step hands back beside its logits
+        #: (``ModelOutput.step_counters``, declared by name and shape as
+        #: ``model.step_counter_shapes``; a routed model's per-expert pairs):
+        #: fetched in the harvest's one transfer and summed here, by name.
+        #: A prefill chunk's wait, as device futures, for the next harvest;
+        #: only the engine's thread touches either (``stats()`` just reads)
+        self._step_counters = {
+            name: np.zeros(shape, np.int64)
+            for name, shape in (getattr(inner, "step_counter_shapes", None) or {}).items()}
+        self._pending_counters: list = []
+        #: what the model says of itself for ``stats()`` (fixed numbers)
+        self._model_stats = dict(getattr(inner, "serve_stats", None) or {})
         #: per-slot draw root: never split/threaded — every draw derives
         #: from it by fold_in(tag, request seed, output position), which is
         #: what makes (seed, prompt) reproducible across admission orders
@@ -908,6 +922,7 @@ class InferenceEngine:
         apply_fn, cfg = self._apply_fn, self.config
         eos_id = cfg.eos_token_id
         topn = cfg.logprobs_topn
+        counted = bool(self._step_counters)
 
         def decode(params, cache, block_tables, pos0, toks, active,
                    lanes, gmask, gtrans, base_key):
@@ -929,15 +944,18 @@ class InferenceEngine:
                     eos_id=eos_id, logprobs_topn=topn,
                 )
                 dfa = gtrans[lanes["grammar_row"], dfa, tok]
-                return (out["paged_kv"], tok[:, None], pos + 1, dfa), (
-                    tok, logp_tok, top_vals, top_ids)
+                ys = (tok, logp_tok, top_vals, top_ids)
+                if counted:
+                    ys += (out["step_counters"],)
+                return (out["paged_kv"], tok[:, None], pos + 1, dfa), ys
 
-            (cache, _, _, _), (toks_out, logps, tvals, tids) = jax.lax.scan(
+            (cache, _, _, _), ys = jax.lax.scan(
                 one_step, (cache, toks, pos0, lanes["dfa_state"]),
                 jnp.arange(cfg.decode_burst),
             )
-            # toks_out: [burst, num_slots]; logprob outputs [burst, slots(, N)]
-            return cache, toks_out, logps, tvals, tids
+            # toks_out: [burst, num_slots]; logprob outputs [burst, slots(, N)];
+            # behind them the step counters of a model that has any, [burst, ...]
+            return (cache, *ys)
 
         # the cache — pools, scale arrays when quantized, slot state where
         # the model keeps one — is one donated operand of every step program
@@ -1087,6 +1105,7 @@ class InferenceEngine:
     def _build_prefill_fn(self):
         apply_fn = self._apply_fn
         has_state = bool(self._cache_spec.slot_state)
+        counted = bool(self._step_counters)
 
         def prefill(params, cache, block_table, start, chunk, valid,
                     last_idx, slot):
@@ -1106,7 +1125,10 @@ class InferenceEngine:
             # the logits of the prompt's last real position, which the
             # first token is picked from — only meaningful on the final
             # chunk; the host ignores them otherwise
-            return out["paged_kv"], jnp.take(out["logits"][0], last_idx, axis=0)
+            last = jnp.take(out["logits"][0], last_idx, axis=0)
+            if counted:
+                return out["paged_kv"], last, out["step_counters"]
+            return out["paged_kv"], last
 
         return jax.jit(prefill, donate_argnums=(1,))
 
@@ -1345,7 +1367,10 @@ class InferenceEngine:
             entry = fl.record(
                 self._iterations, t0, wall,
                 overlap_hidden_s=overlap, intervals=self._fl_intervals,
-                t_start_unix_ns=self._fl_unix_ns, **phases,
+                t_start_unix_ns=self._fl_unix_ns,
+                counters={name: int(total) for name, total in self._step_counters.items()
+                          if not total.shape},
+                **phases,
             )
             fl.current_phase = "idle"
             reg = get_active_registry()
@@ -1426,6 +1451,9 @@ class InferenceEngine:
         self._ttft_prefill_iterations_sum = 0
         self._paged_entries_walked = self._paged_entries_table = 0
         self._pick_dispatches = self._pick_draw_dispatches = 0
+        for total in self._step_counters.values():
+            total[...] = 0
+        self._pending_counters = []  # dispatched before the reset: not this window's
         # hit accounting restarts with the measurement window; the trie and
         # its cached blocks deliberately stay warm (steady-state behaviour
         # is what a warmed bench leg measures)
@@ -1598,6 +1626,11 @@ class InferenceEngine:
             "pick_dispatches_total": self._pick_dispatches,
             "pick_draw_dispatches_total": self._pick_draw_dispatches,
         }
+        if self._step_counters:
+            # summed at each harvest, by the engine's thread alone: a reading
+            # lacks the round in flight and the chunks dispatched since
+            out.update(self._model_stats)
+            out.update({name: total.tolist() for name, total in self._step_counters.items()})
         out.update(self._spec_stats())
         out.update(self._sampling_stats())
         out.update(self._hbm_watermarks())
@@ -1777,6 +1810,11 @@ class InferenceEngine:
             if u is not None and self._fl_phases is None
             else 0.0
         )
+        # the model's step counters (this round's and the prefill chunks'
+        # since the last harvest) ride the SAME device_get as the tokens (a
+        # speculative round hands none back; the chunks' are then fetched
+        # where they are summed)
+        counted = (rd.counters, self._pending_counters) if self._step_counters else ()
         if rd.kind == "spec":
             tok_seq, accept = (
                 np.asarray(x) for x in jax.device_get((rd.toks, rd.accept))
@@ -1784,12 +1822,16 @@ class InferenceEngine:
         elif rd.harvest_lp:
             # the logprob surfaces ride the SAME device_get — no second
             # dispatch, no extra sync point
+            next_toks, logps, tvals, tids, *counted = jax.device_get(
+                (rd.toks, rd.logps, rd.tvals, rd.tids, *counted))
             next_toks, logps, tvals, tids = (
-                np.asarray(x)
-                for x in jax.device_get((rd.toks, rd.logps, rd.tvals, rd.tids))
-            )
+                np.asarray(x) for x in (next_toks, logps, tvals, tids))
         else:
-            next_toks = np.asarray(jax.device_get(rd.toks))  # [burst, slots]
+            next_toks, *counted = jax.device_get((rd.toks, *counted))
+            next_toks = np.asarray(next_toks)  # [burst, slots]
+        if counted:
+            self._pending_counters = []
+            self._sum_step_counters(*counted)
         self._inflight = None
         dw = self._fl_switch("harvest")
         if u is not None and dw is None:
@@ -1850,6 +1892,15 @@ class InferenceEngine:
                 else [(r.request_id, 1) for r in rd.live]
             )
             u.accrue_decode(dw, shares)
+
+    def _sum_step_counters(self, of_round, of_chunks) -> None:
+        """Add fetched step counters to the running sums: a decode round's
+        ``{name: [burst, ...]}`` (``None`` for a round without any) and each
+        prefill chunk's ``{name: [...]}``."""
+        for counters in ([of_round] if of_round else []) + list(of_chunks):
+            for name, total in self._step_counters.items():
+                got = np.asarray(counters[name], np.int64)
+                total += got.reshape(-1, *total.shape).sum(axis=0)
 
     def _fence_inflight(self) -> bool:
         """Synchronize with the in-flight round before host code touches
@@ -2172,12 +2223,14 @@ class InferenceEngine:
         last_idx = np.int32((total - 1) - start if is_final else 0)
 
         self._count_paged_entries([start], c, self._cache_spec.paged_layers)
-        self._cache, logits = self._prefill_fn(
+        self._cache, logits, *counters = self._prefill_fn(
             self._params, self._cache,
             self._block_tables[req.slot : req.slot + 1].copy(),
             np.asarray([start], np.int32), chunk, valid, last_idx,
             np.asarray([req.slot], np.int32),
         )
+        # a chunk's step counters stay on the device until the next harvest
+        self._pending_counters.extend(counters)
         req.prefill_pos = end
         req.prefill_iterations += 1
         lp_entry = None
@@ -2355,7 +2408,7 @@ class InferenceEngine:
             pos0 + np.arange(cfg.decode_burst)[:, None], 1, self._cache_spec.paged_layers
         )
         self._count_pick(lanes)
-        self._cache, next_toks, logps, tvals, tids = self._decode_fn(
+        self._cache, next_toks, logps, tvals, tids, *counters = self._decode_fn(
             self._params, self._cache, self._block_tables.copy(), pos0, toks, active,
             lanes, self._gmask, self._gtrans, self._base_key,
         )
@@ -2374,6 +2427,7 @@ class InferenceEngine:
         self._inflight = _InFlightRound(
             kind="burst", live=live, toks=next_toks, logps=logps,
             tvals=tvals, tids=tids, harvest_lp=harvest_lp,
+            counters=counters[0] if counters else None,
         )
 
     def _spec_decode_dispatch(

@@ -124,7 +124,8 @@ class FlightRecorder:
 
     def record(self, iteration: int, t_start: float, wall_s: float,
                overlap_hidden_s: float = 0.0, intervals=None,
-               t_start_unix_ns: int | None = None, **phases: float) -> dict:
+               t_start_unix_ns: int | None = None, counters: dict | None = None,
+               **phases: float) -> dict:
         """Append one iteration. ``phases`` must cover exactly
         :data:`ITERATION_PHASES` and sum to ``wall_s`` — the stamps
         telescope (each phase is the diff of consecutive perf_counter
@@ -142,7 +143,10 @@ class FlightRecorder:
         asserted too. ``t_start_unix_ns`` is ``t_start`` on the clock
         ``jax.profiler`` stamps host events with (``time.time_ns()``; an
         xplane's times count from its ``profile_start_time``), so the
-        intervals can be laid over a device trace's idle gaps."""
+        intervals can be laid over a device trace's idle gaps. ``counters``
+        are the engine's running sums of the model's scalar step counters as
+        of this iteration's harvest (a routed model's ``moe_*_total``): two
+        entries' difference is what the device did between them, exactly."""
         if set(phases) != set(ITERATION_PHASES):
             raise AssertionError(
                 f"flight phases {sorted(phases)} != {sorted(ITERATION_PHASES)}"
@@ -169,6 +173,8 @@ class FlightRecorder:
             entry["intervals"] = _checked_intervals(intervals, wall_s, phases)
         if t_start_unix_ns is not None:
             entry["t_start_unix_ns"] = int(t_start_unix_ns)
+        if counters:
+            entry["counters"] = dict(counters)
         for p in ITERATION_PHASES:
             entry[f"{p}_s"] = float(phases[p])
             self.phase_totals_s[p] += float(phases[p])

@@ -75,6 +75,10 @@ FLASH_GRAD_RTOL = 3e-2
 #: another order — relative to the largest entry, as the flash gradients
 SSM_ATOL = 1e-5
 HYBRID_RTOL = 3e-2
+#: the expert product: bf16 operands and float32 accumulation on both
+#: sides, the hidden activation rounded to bf16 between the two products;
+#: the two routes tile the contraction differently
+MOE_RTOL = 2e-2
 
 _COMPILED = re.compile(r"Finished XLA compilation of (\S+) in ([0-9.]+) sec")
 
@@ -478,10 +482,50 @@ def _kernels() -> None:
                                  flash_attention, qkv, probe)
 
     ok &= _hybrid_check(rng)
+    ok &= _expert_product_check(rng)
     if len(jax.devices()) == 4:
         ok &= _ring_flash_check(rng)
     if not ok:
         sys.exit("a kernel disagrees with its reference beyond the stated bound")
+
+
+def _expert_product_check(rng) -> bool:
+    """The dropless expert product (``ops/moe.py``) at LFM2-8B-A1B's widths
+    (32 experts of 2048 x 1792, top 4), two layers stacked and the second
+    addressed in place: the grouped Pallas product against its
+    ``ragged_dot`` twin at the chat cell's decode shape (64 slots, 24 live)
+    and at a 256-token chunk with a padded tail; dead rows give zeros and
+    add no pair."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from accelerate_tpu.ops import moe
+
+    ok = True
+    e, h, f, k = 32, 2048, 1792, 4
+    w_in = jnp.asarray(rng.normal(size=(2, e, h, 2 * f)) / np.sqrt(h), jnp.bfloat16)
+    w_out = jnp.asarray(rng.normal(size=(2, e, f, h)) / np.sqrt(f), jnp.bfloat16)
+    gate = jnp.asarray(rng.normal(size=(h, e)) / np.sqrt(h), jnp.bfloat16)
+    for rows, live_rows in ((64, 24), (256, 200)):
+        x = jnp.asarray(rng.normal(size=(rows, h)), jnp.bfloat16)
+        live = jnp.arange(rows) < live_rows
+        experts, weights = jax.jit(lambda x: moe.route(x, gate, jnp.zeros((e,)), k))(x)
+        outs = {
+            impl: jax.jit(lambda x, impl=impl: moe.expert_ffn(
+                x, experts, weights, w_in, w_out, live=live, layer=1, impl=impl))(x)
+            for impl in ("gmm", "ragged")
+        }
+        label = f"expert product [{rows},{h}] x 32 experts top 4, {live_rows} rows live, gmm vs ragged_dot"
+        ok &= _kernel_row(label, outs["gmm"][0], outs["ragged"][0], MOE_RTOL, relative=True)
+        same = (np.array_equal(outs["gmm"][1], outs["ragged"][1])
+                and int(outs["gmm"][1].sum()) == live_rows * k
+                and not np.asarray(outs["gmm"][0], np.float32)[live_rows:].any())
+        print("KERNEL " + json.dumps({"check": label + ": pairs counted, dead rows zero",
+                                      "err": 0.0 if same else 1.0, "bound": 0.0, "ok": same}),
+              flush=True)
+        ok &= same
+    return ok
 
 
 def _hybrid_check(rng) -> bool:

@@ -1,0 +1,168 @@
+"""The routed-expert operators of ``ops/moe.py`` (ISSUE 36): the sigmoid
+router with a selection bias, and the dropless expert product, each held
+against a token-by-token loop in numpy that shares nothing with them.
+
+All on the CPU at small sizes. The expert product runs as
+``jax.lax.ragged_dot`` there (the twin the platform picks) and, in these
+tests, also as the grouped Pallas product in interpret mode.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from accelerate_tpu.ops import moe
+
+E, H, F, K, T = 8, 32, 16, 2, 12
+
+
+@pytest.fixture(scope="module")
+def layer():
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+    return {
+        "w_in": jax.random.normal(ks[0], (E, H, 2 * F)) / np.sqrt(H),
+        "w_out": jax.random.normal(ks[1], (E, F, H)) / np.sqrt(F),
+        "gate": jax.random.normal(ks[2], (H, E)),
+        "x": jax.random.normal(ks[3], (T, H)),
+    }
+
+
+def _loop(x, experts, weights, w_in, w_out, live):
+    """Every live token through each of its experts, one at a time."""
+    x, w_in, w_out = (np.asarray(a, np.float64) for a in (x, w_in, w_out))
+    out = np.zeros_like(x)
+    for t in range(len(x)):
+        for e, w in zip(np.asarray(experts[t]), np.asarray(weights[t], np.float64)):
+            if live[t]:
+                g, u = np.split(x[t] @ w_in[e], 2)
+                out[t] += w * ((g / (1 + np.exp(-g)) * u) @ w_out[e])
+    return out
+
+
+def test_the_bias_moves_which_experts_are_picked_and_not_their_weights(layer):
+    x, gate = layer["x"], layer["gate"]
+    scores = np.asarray(jax.nn.sigmoid(x @ gate))
+    plain, w_plain = moe.route(x, gate, jnp.zeros(E), K)
+    # a bias that lifts expert 5 above every score: every token picks it
+    bias = jnp.zeros(E).at[5].set(10.0)
+    chosen, w = moe.route(x, gate, bias, K)
+    assert (np.asarray(chosen) == 5).any(axis=1).all()
+    assert not (np.asarray(plain) == 5).any(axis=1).all()
+    # the weights are the un-biased scores of the experts chosen, normalised
+    picked = np.take_along_axis(scores, np.asarray(chosen), axis=1)
+    np.testing.assert_allclose(w, picked / (picked.sum(1, keepdims=True) + 1e-6), rtol=1e-6)
+    # and with no bias they are what the plain top-k of the scores gives
+    np.testing.assert_array_equal(np.asarray(plain), np.argsort(-scores, axis=1)[:, :K])
+    assert float(jnp.abs(w_plain.sum(1) - 1).max()) < 1e-5
+
+
+@pytest.mark.parametrize("norm, scale", [(True, 1.0), (True, 2.5), (False, 1.0), (False, 0.5)])
+def test_the_sum_s_epsilon_and_the_scaling_factor(layer, norm, scale):
+    x, gate = layer["x"], layer["gate"]
+    chosen, w = moe.route(x, gate, jnp.zeros(E), K, norm, scale)
+    picked = np.take_along_axis(np.asarray(jax.nn.sigmoid(x @ gate)), np.asarray(chosen), axis=1)
+    want = picked / (picked.sum(1, keepdims=True) + 1e-6) if norm else picked
+    np.testing.assert_allclose(w, want * scale, rtol=1e-6)
+    if norm:
+        # the 1e-6 is there: the weights sum to just under the factor
+        assert (np.asarray(w).sum(1) < scale).all()
+
+
+def test_the_router_runs_in_float32_whatever_it_is_given(layer):
+    """bfloat16 inputs are multiplied as float32: two scores that tie in
+    bfloat16 are told apart."""
+    x, gate = layer["x"].astype(jnp.bfloat16), layer["gate"].astype(jnp.bfloat16)
+    chosen, w = moe.route(x, gate, jnp.zeros(E, jnp.bfloat16), K)
+    want, w_want = moe.route(x.astype(jnp.float32), gate.astype(jnp.float32), jnp.zeros(E), K)
+    assert w.dtype == jnp.float32
+    np.testing.assert_array_equal(chosen, want)
+    np.testing.assert_allclose(w, w_want, rtol=1e-6)
+
+
+@pytest.mark.parametrize("impl", ["ragged", "gmm"])
+def test_the_expert_product_agrees_with_a_token_loop(layer, impl):
+    x = layer["x"]
+    experts, weights = moe.route(x, layer["gate"], jnp.zeros(E), K)
+    live = np.ones(T, bool)
+    y, counts = moe.expert_ffn(x, experts, weights, layer["w_in"], layer["w_out"],
+                               impl=impl, interpret=True)
+    np.testing.assert_allclose(
+        y, _loop(x, experts, weights, layer["w_in"], layer["w_out"], live), atol=2e-6)
+    np.testing.assert_array_equal(counts, np.bincount(np.asarray(experts).ravel(), minlength=E))
+
+
+@pytest.mark.parametrize("impl", ["ragged", "gmm"])
+def test_dropless_under_imbalance_every_token_to_one_expert(layer, impl):
+    """A router forced to one expert and its neighbour: expert 3 is given
+    every token. A capacity-bounded layer (``models/mixtral.py:moe_ffn``,
+    capacity 2 * T * k / E = 6 rows here) keeps 6 of the 12 and drops the
+    rest; this one multiplies all 12."""
+    x = layer["x"]
+    experts = jnp.tile(jnp.asarray([[3, 4]], jnp.int32), (T, 1))
+    weights = jnp.tile(jnp.asarray([[0.75, 0.25]], jnp.float32), (T, 1))
+    y, counts = moe.expert_ffn(x, experts, weights, layer["w_in"], layer["w_out"],
+                               impl=impl, interpret=True)
+    np.testing.assert_array_equal(counts, [0, 0, 0, T, T, 0, 0, 0])
+    want = _loop(x, experts, weights, layer["w_in"], layer["w_out"], np.ones(T, bool))
+    np.testing.assert_allclose(y, want, atol=2e-6)
+    assert np.abs(want).min(axis=1).max() > 0  # no token's row is empty
+
+
+@pytest.mark.parametrize("impl", ["ragged", "gmm"])
+def test_a_dead_lane_adds_no_pair_to_any_expert(layer, impl):
+    x = layer["x"]
+    experts, weights = moe.route(x, layer["gate"], jnp.zeros(E), K)
+    live = np.arange(T) % 3 != 1
+    y, counts = moe.expert_ffn(x, experts, weights, layer["w_in"], layer["w_out"],
+                               live=jnp.asarray(live), impl=impl, interpret=True)
+    assert int(counts.sum()) == live.sum() * K
+    np.testing.assert_array_equal(
+        counts, np.bincount(np.asarray(experts)[live].ravel(), minlength=E))
+    assert not np.asarray(y)[~live].any()
+    np.testing.assert_allclose(
+        y, _loop(x, experts, weights, layer["w_in"], layer["w_out"], live), atol=2e-6)
+    # nobody live: no pair, no output, whatever the implementation leaves
+    # in the rows it did not compute
+    y, counts = moe.expert_ffn(x, experts, weights, layer["w_in"], layer["w_out"],
+                               live=jnp.zeros(T, bool), impl=impl, interpret=True)
+    assert int(counts.sum()) == 0 and not np.asarray(y).any()
+
+
+@pytest.mark.parametrize("impl", ["ragged", "gmm"])
+def test_a_layer_of_the_stacked_matrices_is_addressed_in_place(layer, impl):
+    """``layer=i`` over ``[layers, E, ...]`` is the product with layer
+    ``i``'s experts, and no other layer's."""
+    ks = jax.random.split(jax.random.PRNGKey(1), 2)
+    w_in = jax.random.normal(ks[0], (3, E, H, 2 * F)) / np.sqrt(H)
+    w_out = jax.random.normal(ks[1], (3, E, F, H)) / np.sqrt(F)
+    x = layer["x"]
+    experts, weights = moe.route(x, layer["gate"], jnp.zeros(E), K)
+    for i in range(3):
+        y, counts = moe.expert_ffn(x, experts, weights, w_in, w_out, layer=i,
+                                   impl=impl, interpret=True)
+        want, want_counts = moe.expert_ffn(x, experts, weights, w_in[i], w_out[i], impl="ragged")
+        np.testing.assert_allclose(y, want, atol=2e-6)
+        np.testing.assert_array_equal(counts, want_counts)
+
+
+def test_shapes_are_static_and_the_product_can_be_differentiated(layer):
+    x = layer["x"]
+
+    def loss(w_in, w_out, gate):
+        experts, weights = moe.route(x, gate, jnp.zeros(E), K)
+        y, _ = moe.expert_ffn(x, experts, weights, w_in, w_out)
+        return (y ** 2).sum()
+
+    jitted = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+    grads = jitted(layer["w_in"], layer["w_out"], layer["gate"])
+    assert all(np.isfinite(np.asarray(g)).all() and float(jnp.abs(g).max()) > 0 for g in grads)
+    # another routing, the same executable
+    jitted(layer["w_in"], layer["w_out"], -layer["gate"])
+    assert jitted._cache_size() == 1
+
+
+def test_the_platform_picks_the_implementation():
+    assert moe.default_moe_impl() == "ragged"  # the CPU's; "gmm" on a TPU backend
+    with pytest.raises(ValueError, match="unknown grouped_matmul impl"):
+        moe.grouped_matmul(jnp.zeros((4, 8)), jnp.zeros((2, 8, 8)), jnp.asarray([2, 2]), "dense")

@@ -337,3 +337,65 @@ def test_v5e_compiled_hybrid_step_leaves_the_slot_state_in_place(one_chip, monke
     assert [m for m in found["moved"] if m[1] not in in_place] == [], found["moved"]
     assert found["unaliased"] == []
     assert compiled.memory_analysis().temp_size_in_bytes < state * 4 // 5 // 4
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_v5e_compiled_routed_step_addresses_the_expert_stacks_in_place(one_chip, monkeypatch, program):
+    """LFM2-8B-A1B's widths (32 experts of 2048 x 1792, top 4; 32 / 8 heads
+    of 64; a 3-tap convolution's two-row tail), three layers ``conv (dense),
+    full_attention, conv`` with the last two routed, 64 slots, the
+    vocabulary cut to 8192: compiled for the v5e, the grouped Pallas product
+    is in the program twice a routed layer beside the paged kernel, and no
+    instruction produces anything of the size of an expert stack ``[2, 32,
+    2048, 3584]`` / ``[2, 32, 1792, 2048]`` or of one layer's slab of it:
+    ``(layer, expert)`` is addressed through the kernel's index maps."""
+    import sys
+
+    from accelerate_tpu.models import lfm2
+
+    monkeypatch.setattr(
+        sys.modules["accelerate_tpu.ops.paged_attention"],
+        "default_paged_attention_impl", lambda: "pallas",
+    )
+    monkeypatch.setattr(sys.modules["accelerate_tpu.ops.moe"], "default_moe_impl", lambda: "gmm")
+    slots, blocks, bs, table, chunk = 64, 3000, 16, 256, 256
+    c = lfm2.Lfm2MoeConfig(
+        vocab_size=8192, num_hidden_layers=3, num_dense_layers=1,
+        layer_types=("conv", "full_attention", "conv"))
+    shaped = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: shaped(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: lfm2.init_lfm2_params(jax.random.PRNGKey(0), c)),
+    )
+    pool = shaped((1, blocks, bs, 8 * 64), jnp.bfloat16)
+    cache = {"k": pool, "v": pool}
+    for name, leaf in lfm2.cache_spec(c).slot_state.items():
+        cache[name] = shaped(leaf.array_shape(slots), leaf.dtype or jnp.bfloat16)
+    assert cache["conv"].shape == (2, 64, 2, 2048)
+
+    def step(params, cache, tables, pos, toks, mask, state_slots=None):
+        out = lfm2.lfm2_apply(
+            c, params, toks, paged_kv=cache, block_tables=tables,
+            cache_positions=pos, paged_write_mask=mask, state_slots=state_slots,
+        )
+        return (out["paged_kv"], jnp.argmax(out["logits"][:, -1, :], -1).astype(jnp.int32),
+                out["step_counters"])
+
+    b, s = (slots, 1) if program == "decode" else (1, chunk)
+    operands = [params, cache, shaped((b, table), jnp.int32), shaped((b,), jnp.int32),
+                shaped((b, s), jnp.int32), shaped((b, s), jnp.bool_)]
+    if program == "prefill":
+        operands.append(shaped((1,), jnp.int32))
+    # the serving default: another test file sets "highest" for the whole
+    # process as it is imported, which the grouped product's bfloat16
+    # kernel cannot be compiled under
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(step, donate_argnums=(1,)).lower(*operands).compile()
+    text = compiled.as_text()
+    # two routed layers x two grouped products, one attention layer's kernel
+    assert text.count('custom_call_target="tpu_custom_call"') == 5
+    w_in, w_out = 2 * 32 * 2048 * 3584, 2 * 32 * 1792 * 2048
+    found = buffers_moved(text, [w_in, w_in // 2, w_out, w_out // 2])
+    assert found["moved"] == [], found["moved"]
+    # the temporaries are far smaller than one expert's matrices
+    assert compiled.memory_analysis().temp_size_in_bytes < 2048 * 3584 * 2
