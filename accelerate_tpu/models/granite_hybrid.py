@@ -44,7 +44,7 @@ from ..ops.layers import (
     write_paged_kv,
 )
 from ..ops.paged_attention import paged_attention
-from ..ops.ssm import conv_with_tail, ssd_chunk_scan, ssm_state_update
+from ..ops.ssm import conv_with_tail, live_slots, ssd_chunk_scan, ssm_state_update
 from ..parallel.pipeline import remat_wrap
 from .cache import CacheSpec, SlotStateLeaf
 
@@ -422,6 +422,8 @@ def _paged_step(c, params, input_ids, cache, block_tables, cache_positions,
             f"[{b}, {s}] tokens for {cache['ssm'].shape[1]} slots"
         )
     slots = None if decode else jnp.asarray(state_slots, jnp.int32).reshape(b)
+    # what the state kernel walks: one mask a step, so one list for every layer
+    live = live_slots(valid[:, 0]) if decode else None
     nh, nkv, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
     quantized = "k_scale" in cache
     x = _embed(c, params, input_ids)
@@ -441,7 +443,8 @@ def _paged_step(c, params, input_ids, cache, block_tables, cache_positions,
             ssm = cache["ssm"]
             if decode:
                 ssm, y = ssm_state_update(
-                    ssm, i, xs[:, 0], dt[:, 0], a, b_mat[:, 0], c_mat[:, 0], valid[:, 0])
+                    ssm, i, xs[:, 0], dt[:, 0], a, b_mat[:, 0], c_mat[:, 0], valid[:, 0],
+                    live=live)
                 y = y[:, None]
             else:
                 xs_in = jnp.where(valid[..., None, None], xs, 0)

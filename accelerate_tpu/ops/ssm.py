@@ -20,12 +20,15 @@ bfloat16 state rounds away.
   ``chunk`` tokens, the recurrence between chunks) starting from any
   state; a token whose ``dt`` and ``x`` are zero leaves the state where it
   is, which is how a padded tail is kept out.
-* :func:`ssm_state_update` — the ``s == 1`` step for every slot at once
-  against the engine's stacked state ``[layers, slots, H, P, N]``: a Pallas
-  kernel that addresses ``(layer, slot, head block)`` through its index
-  maps and aliases the state in place (no layer's slab is sliced out or
-  written back), with an ``interpret=True`` route for the CPU tests and a
-  plain ``jnp`` twin (``impl="jnp"``) as the parity reference.
+* :func:`ssm_state_update` — the ``s == 1`` step for every live slot at
+  once against the engine's stacked state ``[layers, slots, H, P, N]``: a
+  Pallas kernel that walks the list of live slots (:func:`live_slots`, read
+  from a prefetched operand), copies block ``(layer, slot, head block)``
+  from HBM one block ahead of the one it steps and back in place while the
+  next is stepped (the state is aliased: no layer's slab is sliced out or
+  written back, and a dead slot is neither read nor written, so a call
+  costs what is live), with an ``interpret=True`` route for the CPU tests
+  and a plain ``jnp`` twin (``impl="jnp"``) as the parity reference.
 """
 
 from __future__ import annotations
@@ -135,43 +138,109 @@ def _state_update_jnp(state, layer, x, dt, a, b_vec, c_vec, active):
     return state.at[layer].set(new), y
 
 
-def _state_update_kernel(layer_ref, active_ref, s_ref, decay_ref, dtx_ref,
-                         b_ref, c_ref, so_ref, y_ref, *, hb, p):
-    """Grid ``(slots, H // hb)``: step ``(i, j)`` holds slot ``i``'s head
-    block ``j`` of the stacked state — ``[hb, P, N]``, steered there by the
-    index maps from the prefetched layer. ``decay`` and ``dt * x`` arrive
-    as ``[P, hb]`` columns, so that head ``k``'s scalar and vector are the
-    lane slice ``[:, k:k+1]`` broadcast over the ``N`` lanes of the state.
-    A slot that is not decoding copies its block through unchanged: the
-    output aliases the input, and a block that is visited is written."""
+def live_slots(active):
+    """What the kernel's walk follows, from ``active [slots]``: ``(the live
+    slots' indices in slot order [slots] int32 — the entries past the live
+    ones are 0 and never read —, how many are live [1] int32)``. A dozen
+    small operations on the device; a caller that runs many layers against
+    one mask derives it once and hands it to every call (``live=``)."""
+    on = jnp.asarray(active).reshape(-1) != 0
+    (idx,) = jnp.nonzero(on, size=on.shape[0], fill_value=0)
+    return idx.astype(jnp.int32), on.sum(dtype=jnp.int32).reshape(1)
+
+
+#: head blocks on their way from HBM while the kernel steps one (so
+#: ``_AHEAD + 1`` VMEM buffers in, and two out: block ``k - 1`` is copied
+#: out while block ``k`` is stepped). Chosen on the v5e, where 2 and 3 ahead
+#: read within 1.5 % of 1 (PERF.md section 6, PR 37); a constant of the kernel
+_AHEAD = 1
+
+
+def _state_update_kernel(layer_ref, live_ref, n_live_ref, s_hbm, cols_ref, b_ref, c_ref,
+                         so_hbm, y_ref, s_buf, so_buf, sems, *, hb, p, nblk):
+    """No grid: one loop over the ``nblk`` head blocks of each of the
+    ``n_live_ref[0]`` live slots ``live_ref[0], live_ref[1], ...`` (slot
+    order). The stacked state stays in HBM, whole; the kernel copies block
+    ``(layer, slot, head block)`` — ``[hb, P, N]`` — into one of its VMEM
+    buffers itself, ``_AHEAD`` blocks ahead of the one it steps, and the
+    stepped block back to the same place (the output aliases the input)
+    while the next is stepped. A slot that is not live is never read and
+    never written; its ``y`` row is the zero the output starts as.
+
+    ``cols_ref [slots, P, L]`` holds, lane-dense, what a head's step
+    broadcasts over the ``N`` lanes of its state: lane ``k`` of slot ``i``
+    is head ``k``'s decay (every row the same) and lane ``H + k`` its
+    ``dt * x`` column. A lane rotation brings a block's heads to static
+    offsets, so the body is traced once, not once a head block."""
     import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    i = pl.program_id(0)
+    layer, total = layer_ref[0], n_live_ref[0] * nblk
+    h, lanes = nblk * hb, cols_ref.shape[-1]
+    depth = _AHEAD + 1
 
-    @pl.when(active_ref[i] != 0)
-    def _step():
-        b_row = b_ref[...]                                   # [1, N]
+    def block(ref, t):
+        """Block ``t`` of the walk in ``ref``: head block ``t % nblk`` of
+        the ``t // nblk``-th live slot."""
+        return ref.at[layer, live_ref[t // nblk], pl.ds(t % nblk * hb, hb)]
+
+    def copy_in(t):
+        return pltpu.make_async_copy(block(s_hbm, t), s_buf.at[t % depth], sems.at[0, t % depth])
+
+    def copy_out(t):
+        return pltpu.make_async_copy(so_buf.at[t % 2], block(so_hbm, t), sems.at[1, t % 2])
+
+    y_ref[...] = jnp.zeros_like(y_ref)
+    for t in range(_AHEAD):
+        @pl.when(t < total)
+        def _first():
+            copy_in(t).start()
+
+    def _step(t, carry):
+        @pl.when(t + _AHEAD < total)
+        def _ahead():
+            copy_in(t + _AHEAD).start()
+
+        @pl.when(t >= 2)
+        def _buffer_free():
+            copy_out(t - 2).wait()
+
+        copy_in(t).wait()
+        slot, j = live_ref[t // nblk], t % nblk
+        s_ref, so_ref = s_buf.at[t % depth], so_buf.at[t % 2]
+        b_row = b_ref[slot]                                  # [1, N]
+        # this block's heads to lanes 0 .. hb-1 (decay) and H .. H+hb-1 (dt * x)
+        cols = pltpu.roll(cols_ref[slot], (lanes - j * hb) % lanes, axis=1)
         for k in range(hb):
-            so_ref[k] = (s_ref[k].astype(jnp.float32) * decay_ref[:, k:k + 1]
-                         + dtx_ref[:, k:k + 1] * b_row).astype(so_ref.dtype)
+            so_ref[k] = (s_ref[k].astype(jnp.float32) * cols[:, k:k + 1]
+                         + cols[:, h + k:h + k + 1] * b_row).astype(so_ref.dtype)
         flat = so_ref[...].astype(jnp.float32).reshape(hb * p, so_ref.shape[-1])
-        y_ref[...] = jax.lax.dot_general(                    # [1, hb*P], contract N
-            c_ref[...], flat, (((1,), (1,)), ((), ())),
-            precision=_HI, preferred_element_type=jnp.float32,
-        )
+        y_ref.at[slot][:, pl.ds(pl.multiple_of(j * (hb * p), hb * p), hb * p)] = (
+            jax.lax.dot_general(                             # [1, hb*P], contract N
+                c_ref[slot], flat, (((1,), (1,)), ((), ())),
+                precision=_HI, preferred_element_type=jnp.float32,
+            ))
+        copy_out(t).start()
+        return carry
 
-    @pl.when(active_ref[i] == 0)
-    def _keep():
-        so_ref[...] = s_ref[...]
-        y_ref[...] = jnp.zeros_like(y_ref)
+    jax.lax.fori_loop(0, total, _step, 0)
+    # the last two blocks' copies out are still on their way
+    for back in (2, 1):
+        @pl.when(total >= back)
+        def _drain():
+            copy_out(total - back).wait()
 
 
-#: heads a grid step of the kernel takes (all of them where there are
-#: fewer): at 64 x 128 float32 a head, 512 KB of state in and out
+#: heads a block of the walk takes (all of them where there are fewer): at
+#: 64 x 128 float32 a head, 512 KB of state in and out
 _HEAD_BLOCK = 16
 
 
-def _state_update_pallas(state, layer, x, dt, a, b_vec, c_vec, active, *, interpret):
+# a program calls this once for every run of state layers (five scan bodies in
+# Granite-4.0-H): under ``jit`` the kernel is traced and lowered to Mosaic
+# once for them all, which is seconds of a server's set-up on a small host
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _state_update_pallas(state, layer, x, dt, a, b_vec, c_vec, active, live, *, interpret):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -184,57 +253,45 @@ def _state_update_pallas(state, layer, x, dt, a, b_vec, c_vec, active, *, interp
     dt = dt.astype(f32)
     decay = jnp.exp(dt * a.astype(f32))                      # [slots, H]
     dtx = dt[..., None] * x.astype(f32)                      # [slots, H, P]
+    # [slots, P, decay's H lanes + dt*x's H lanes], padded to whole vregs
+    cols = jnp.concatenate(
+        [jnp.broadcast_to(decay[:, None, :], (slots, p, h)), dtx.transpose(0, 2, 1)], axis=-1)
+    cols = jnp.pad(cols, [(0, 0), (0, 0), (0, -2 * h % 128)])
+    idx, n_live = live_slots(active) if live is None else live
 
-    def columns(t):                                          # [slots, H, P] -> [slots, nblk, P, hb]
-        return t.reshape(slots, nblk, hb, p).transpose(0, 1, 3, 2)
-
-    decay_cols = columns(jnp.broadcast_to(decay[..., None], (slots, h, p)))
-
-    def block(i, j, ly, on):
-        return (ly[0], i, j, 0, 0)
-
-    def cols(i, j, ly, on):
-        return (i, j, 0, 0)
-
-    def row(i, j, ly, on):
-        return (i, 0, 0)
-
-    def out_row(i, j, ly, on):
-        return (i, 0, j)
-
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # the layer steers the index maps; the mask the body
-        grid=(slots, nblk),
-        in_specs=[
-            pl.BlockSpec((None, None, hb, p, n), block),
-            pl.BlockSpec((None, None, p, hb), cols),
-            pl.BlockSpec((None, None, p, hb), cols),
-            pl.BlockSpec((None, 1, n), row),
-            pl.BlockSpec((None, 1, n), row),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, None, hb, p, n), block),
-            pl.BlockSpec((None, 1, hb * p), out_row),
+        num_scalar_prefetch=3,  # the layer and the live list steer the walk
+        grid=(),
+        in_specs=[in_hbm, whole, whole, whole],
+        out_specs=[in_hbm, whole],
+        scratch_shapes=[
+            pltpu.VMEM((_AHEAD + 1, hb, p, n), state.dtype),
+            pltpu.VMEM((2, hb, p, n), state.dtype),
+            pltpu.SemaphoreType.DMA((2, max(_AHEAD + 1, 2))),
         ],
     )
     new_state, y = pl.pallas_call(
-        functools.partial(_state_update_kernel, hb=hb, p=p),
+        functools.partial(_state_update_kernel, hb=hb, p=p, nblk=nblk),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct(state.shape, state.dtype),
+            # ``[slots, 1, H*P]`` and ``B`` / ``C`` as ``[slots, 1, N]``: XLA lays
+            # the step's neighbouring fusions out from the call's operand
+            # shapes, and a row a slot keeps them (and the order of their
+            # float32 sums) what they were under the grid over slots
             jax.ShapeDtypeStruct((slots, 1, h * p), f32),
         ],
-        # operands count the two prefetched scalars: the state is the third
-        input_output_aliases={2: 0},
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
-        ),
+        # operands count the three prefetched scalars: the state is the fourth
+        input_output_aliases={3: 0},
         interpret=interpret,
         name="ssm_state_update",
     )(
         jnp.asarray(layer, jnp.int32).reshape(1),
-        active.reshape(slots).astype(jnp.int32),
-        state, decay_cols, columns(dtx),
+        jnp.asarray(idx, jnp.int32).reshape(slots),
+        jnp.asarray(n_live, jnp.int32).reshape(1),
+        state, cols,
         b_vec.astype(f32).reshape(slots, 1, n),
         c_vec.astype(f32).reshape(slots, 1, n),
     )
@@ -242,20 +299,23 @@ def _state_update_pallas(state, layer, x, dt, a, b_vec, c_vec, active, *, interp
 
 
 def ssm_state_update(state, layer, x, dt, a, b_vec, c_vec, active,
-                     impl: str | None = None, interpret: bool = False):
-    """One recurrence step of every slot in layer ``layer`` of the stacked
+                     impl: str | None = None, interpret: bool = False, live=None):
+    """One recurrence step of every live slot in layer ``layer`` of the stacked
     state ``[layers, slots, H, P, N]`` (float32 as the model's spec has it,
     or what the engine's ``state_dtype`` says; the arithmetic is float32
     either way): ``x [slots, H, P]``, ``dt [slots, H]`` (after the softplus),
     ``a [H]``, ``b_vec`` / ``c_vec`` ``[slots, N]``, ``active [slots]``. Returns ``(state, y [slots, H, P]``
     float32 without the ``D`` skip``)``; a slot that is not active keeps
-    its state bit for bit and reads ``y = 0``. ``layer`` may be traced."""
+    its state bit for bit and reads ``y = 0`` whatever its operands hold: the
+    kernel's work follows the live slots. ``live`` is :func:`live_slots` of
+    ``active`` where the caller has it already (one mask, many layers);
+    left out, it is derived here. ``layer`` may be traced."""
     if impl is None:
         impl = default_ssm_impl()
     layer = jnp.asarray(layer, jnp.int32)
     if impl == "jnp":
         return _state_update_jnp(state, layer, x, dt, a, b_vec, c_vec, active)
     if impl == "pallas":
-        return _state_update_pallas(state, layer, x, dt, a, b_vec, c_vec, active,
+        return _state_update_pallas(state, layer, x, dt, a, b_vec, c_vec, active, live,
                                     interpret=interpret)
     raise ValueError(f"unknown ssm_state_update impl {impl!r}")

@@ -675,6 +675,12 @@ class InferenceEngine:
         # have — their ratio is the share of a (row, entry) grid that is live
         self._paged_entries_walked = 0
         self._paged_entries_table = 0
+        # what the slot-state kernels' work follows (monotone totals, counted
+        # at the decode dispatch from the host's mask): the slot states a
+        # dispatch has to step - live lanes x steps x state layers - and
+        # those the cache holds - every slot's; 0 where no layer keeps state
+        self._state_slots_live = 0
+        self._state_slots_held = 0
         # what the pick's sampler stage follows (monotone totals, counted at
         # dispatch from the host's copy of the lanes): runs of pick_tokens
         # (decode dispatches and first picks), and those in which some lane
@@ -1450,6 +1456,7 @@ class InferenceEngine:
         self._ttft_sum_s = self._ttft_queue_sum_s = self._ttft_own_prefill_sum_s = 0.0
         self._ttft_prefill_iterations_sum = 0
         self._paged_entries_walked = self._paged_entries_table = 0
+        self._state_slots_live = self._state_slots_held = 0
         self._pick_dispatches = self._pick_draw_dispatches = 0
         for total in self._step_counters.values():
             total[...] = 0
@@ -1621,6 +1628,11 @@ class InferenceEngine:
             # the entries its tables hold, both x the layers that were run
             "paged_entries_walked_total": self._paged_entries_walked,
             "paged_entries_table_total": self._paged_entries_table,
+            # the slot state's work: states a decode dispatch had to step
+            # (live lanes x burst x state layers: what ops/ssm.py's kernel
+            # walks) against those the cache holds for every slot
+            "state_slots_live_total": self._state_slots_live,
+            "state_slots_held_total": self._state_slots_held,
             # the pick's work: runs of pick_tokens, and those in which some
             # lane samples (the only ones that sort the vocabulary and draw)
             "pick_dispatches_total": self._pick_dispatches,
@@ -2188,6 +2200,16 @@ class InferenceEngine:
         self._paged_entries_walked += int(walked.sum()) * layers
         self._paged_entries_table += walked.size * self._mb * layers
 
+    def _count_state_slots(self, active) -> None:
+        """Book one decode dispatch's slot-state steps from the mask the
+        executable is handed: every step of the burst, every layer that
+        keeps state steps the live lanes' states - the list
+        ``ops/ssm.py:live_slots`` derives from the same mask - of the
+        ``num_slots`` the cache holds."""
+        steps = self.config.decode_burst * self._cache_spec.state_layers
+        self._state_slots_live += int(active.sum()) * steps
+        self._state_slots_held += self.config.num_slots * steps
+
     def _count_pick(self, lanes) -> None:
         """Book one run of :func:`sampling.pick_tokens` from the host's copy
         of the lanes it is handed: its sampler stage runs when some lane
@@ -2407,6 +2429,7 @@ class InferenceEngine:
         self._count_paged_entries(
             pos0 + np.arange(cfg.decode_burst)[:, None], 1, self._cache_spec.paged_layers
         )
+        self._count_state_slots(active)
         self._count_pick(lanes)
         self._cache, next_toks, logps, tvals, tids, *counters = self._decode_fn(
             self._params, self._cache, self._block_tables.copy(), pos0, toks, active,

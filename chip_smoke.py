@@ -57,6 +57,11 @@ SERVE_ARGS = [
 #: chunk, so chunked prefill, slot reuse and a full decode batch all happen
 SERVE_REQUESTS, PROMPT_LEN, NEW_TOKENS = 40, (32, 160), (16, 64)
 
+#: the parent commit's kernel file, where a builder unpacked it for a timing
+#: side by side (``git archive <parent> | tar -x -C _chip_tmp/parent``; the
+#: directory is in ``.gitignore``): left out of the rows where it is not there
+PARENT_SSM = os.path.join(ROOT, "_chip_tmp", "parent", "accelerate_tpu", "ops", "ssm.py")
+
 #: |kernel - reference| ceilings on the chip, for unit-variance inputs.
 #: Paged attention: same stored pool bytes on both sides, outputs rounded to
 #: bf16 (2^-8 relative), the reference's f32 einsums at the TPU's default
@@ -579,6 +584,8 @@ def _hybrid_check(rng) -> bool:
                                       "err": 0.0 if kept else 1.0, "bound": 0.0, "ok": kept}), flush=True)
         ok &= kept
 
+    ok &= _state_update_live_rows(rng)
+
     # the hybrid paged step at published widths, four layers, a small vocabulary
     c = gh.GraniteHybridConfig(
         vocab_size=8192, num_hidden_layers=4, layer_types=("mamba", "mamba", "attention", "mamba"))
@@ -624,6 +631,85 @@ def _hybrid_check(rng) -> bool:
     print("KERNEL " + json.dumps({"check": "hybrid decode step: masked lanes' state bit-identical",
                                   "err": 0.0 if kept else 1.0, "bound": 0.0, "ok": kept}), flush=True)
     return ok and kept
+
+
+def _state_update_live_rows(rng, slots=64, h=64, p=64, n=128, lives=(11, 32, 64)) -> bool:
+    """``ssm_state_update`` at the hybrid cell's decode shape - 64 slots of
+    ``[64, 64, 128]`` float32 - with 11, 32 and 64 slots live: state and ``y``
+    against the ``jnp`` twin, dead slots bit-identical, and the milliseconds
+    a call takes (36 calls in one program, as a decode step makes them; a
+    set-up fact of this machine like the seconds of the other phases, for
+    ``PERF.md``). Where a builder has unpacked the parent commit under
+    ``_chip_tmp/parent`` (never committed), the parent's kernel is timed
+    beside it and its live rows have to be the same to the last bit."""
+    import importlib.util
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from accelerate_tpu.ops import ssm
+
+    parent = None
+    if os.path.exists(PARENT_SSM):
+        spec = importlib.util.spec_from_file_location("parent_ssm", PARENT_SSM)
+        parent = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(parent)
+
+    calls, reps = 36, 5
+    state = jnp.asarray(rng.normal(size=(2, slots, h, p, n)), jnp.float32)
+    x = jnp.asarray(rng.normal(size=(slots, h, p)), jnp.bfloat16)
+    dt = jax.nn.softplus(jnp.asarray(rng.normal(size=(slots, h)), jnp.float32) - 4)
+    a = -jnp.exp(jnp.asarray(rng.normal(size=(h,)), jnp.float32) * 0.3)
+    b_vec, c_vec = (jnp.asarray(rng.normal(size=(slots, n)), jnp.bfloat16) for _ in range(2))
+
+    def ms_a_call(update, active):
+        def many(st):
+            def one(i, carry):
+                st, acc = carry
+                st, y = update(st, i % 2, x, dt, a, b_vec, c_vec, active, impl="pallas")
+                return st, acc + y[0, 0, 0]
+            return jax.lax.fori_loop(0, calls, one, (st, jnp.float32(0)))
+
+        many = jax.jit(many, donate_argnums=0)
+        st, _ = jax.block_until_ready(many(state + 0))
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            st, _ = many(st)
+        jax.block_until_ready(st)
+        return 1e3 * (time.perf_counter() - t0) / (reps * calls)
+
+    ok, before = True, np.asarray(state)
+    routes = {"pallas": (ssm, "pallas"), "jnp": (ssm, "jnp")}
+    if parent:
+        routes["parent"] = (parent, "pallas")
+    for live in lives:
+        on = np.zeros(slots, bool)
+        on[rng.choice(slots, size=live, replace=False)] = True
+        active = jnp.asarray(on)
+        outs = {
+            name: jax.jit(lambda st, mod=mod, impl=impl: mod.ssm_state_update(
+                st, 1, x, dt, a, b_vec, c_vec, active, impl=impl))(state)
+            for name, (mod, impl) in routes.items()
+        }
+        label = f"ssm_state_update [{slots},{h},{p},{n}] f32 state, {live} of {slots} live"
+        ok &= _kernel_row(label + ", pallas vs jnp, state", outs["pallas"][0], outs["jnp"][0], SSM_ATOL)
+        ok &= _kernel_row(label + ", pallas vs jnp, y", outs["pallas"][1], outs["jnp"][1], SSM_ATOL * n)
+        new, y = np.asarray(outs["pallas"][0]), np.asarray(outs["pallas"][1])
+        kept = (np.array_equal(new[1][~on], before[1][~on])
+                and np.array_equal(new[0], before[0]) and not y[~on].any())
+        row = {"check": label + ": dead slots and the other layer bit-identical, their y 0",
+               "err": 0.0 if kept else 1.0, "bound": 0.0, "ok": kept,
+               "ms_a_call": round(ms_a_call(ssm.ssm_state_update, active), 4)}
+        if parent:
+            same = (np.array_equal(new[1][on], np.asarray(outs["parent"][0])[1][on])
+                    and np.array_equal(y[on], np.asarray(outs["parent"][1])[on]))
+            row.update(parent_ms_a_call=round(ms_a_call(parent.ssm_state_update, active), 4),
+                       live_rows_bit_equal_to_parent=same)
+            row["ok"] = kept = kept and same
+        print("KERNEL " + json.dumps(row), flush=True)
+        ok &= kept
+    return ok
 
 
 def _out_and_grads(fn):

@@ -212,6 +212,123 @@ def test_a_masked_lane_of_the_kernel_keeps_its_state_bit_for_bit(impl):
     assert not np.asarray(y)[off].any()
 
 
+#: which of five slots decode: nothing (a legal call), one at either end of
+#: the walk, some, and every one (the walk does what a grid over slots did)
+LIVE_PATTERNS = {
+    "none": [False] * 5, "first": [True, False, False, False, False],
+    "last": [False, False, False, False, True], "ragged": [False, True, True, False, True],
+    "all": [True] * 5,
+}
+
+
+def _as_f32(*arrays):
+    return [np.asarray(a.astype(jnp.float32)) for a in arrays]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("heads", [8, 32])
+@pytest.mark.parametrize("pattern", list(LIVE_PATTERNS))
+def test_the_walk_over_the_live_slots_agrees_with_the_jnp_twin(pattern, heads, dtype):
+    state, *operands, _ = _update_inputs(h=heads, dtype=dtype)
+    active = jnp.asarray(LIVE_PATTERNS[pattern])
+    on = np.asarray(active)
+    for layer in (0, 2):
+        want_state, want_y = _as_f32(*ssm.ssm_state_update(
+            state, layer, *operands, active, impl="jnp"))
+        got_state, got_y = _as_f32(*ssm.ssm_state_update(
+            state, layer, *operands, active, impl="pallas", interpret=True))
+        tol = 1e-5 if dtype == jnp.float32 else 0.05
+        np.testing.assert_allclose(got_state, want_state, atol=tol)
+        np.testing.assert_allclose(got_y, want_y, atol=tol * 16)
+        # what is not live is not touched: the dead slots of this layer, and
+        # every other layer, to the last bit; a dead slot's y is a plain 0
+        before = _as_f32(state)[0]
+        assert np.array_equal(got_state[layer][~on], before[layer][~on])
+        others = [i for i in range(3) if i != layer]
+        assert np.array_equal(got_state[others], before[others])
+        assert not got_y[~on].any()
+        assert on.any() == (not np.array_equal(got_state[layer], before[layer]))
+
+
+@pytest.mark.parametrize("poison", [np.nan, np.inf])
+@pytest.mark.parametrize("heads", [8, 32])
+@pytest.mark.parametrize("pattern", ["none", "first", "last", "ragged"])
+def test_a_dead_slot_is_never_read_whatever_its_operands_hold(pattern, heads, poison):
+    """The kernel's work follows the live list, so what a dead slot's ``x``,
+    ``dt``, ``B`` and ``C`` hold reaches nothing: its state stays, its ``y``
+    is 0, and every live slot reads what it reads beside clean operands."""
+    state, x, dt, a, b_vec, c_vec, _ = _update_inputs(h=heads)
+    active = jnp.asarray(LIVE_PATTERNS[pattern])
+    on = np.asarray(active)
+    dead = jnp.asarray(~on)
+
+    def poisoned(t):
+        return jnp.where(dead.reshape((-1,) + (1,) * (t.ndim - 1)), poison, t).astype(t.dtype)
+
+    clean = ssm.ssm_state_update(state, 1, x, dt, a, b_vec, c_vec, active,
+                                 impl="pallas", interpret=True)
+    dirty = ssm.ssm_state_update(state, 1, poisoned(x), poisoned(dt), a, poisoned(b_vec),
+                                 poisoned(c_vec), active, impl="pallas", interpret=True)
+    assert np.array_equal(np.asarray(dirty[0]), np.asarray(clean[0]))
+    assert np.array_equal(np.asarray(dirty[1]), np.asarray(clean[1]))
+    assert np.array_equal(np.asarray(dirty[0])[1][~on], np.asarray(state)[1][~on])
+    assert not np.asarray(dirty[1])[~on].any() and np.isfinite(np.asarray(dirty[0])).all()
+
+
+@pytest.mark.parametrize("pattern", list(LIVE_PATTERNS))
+def test_a_precomputed_live_list_and_the_one_derived_from_active_give_the_same_bits(pattern):
+    state, *operands, _ = _update_inputs(h=32)
+    active = jnp.asarray(LIVE_PATTERNS[pattern])
+    idx, n_live = ssm.live_slots(active)
+    on = np.asarray(active)
+    assert int(n_live[0]) == on.sum() and idx.shape == (5,) and idx.dtype == jnp.int32
+    assert np.asarray(idx)[: on.sum()].tolist() == np.flatnonzero(on).tolist()
+    derived = ssm.ssm_state_update(state, 2, *operands, active, impl="pallas", interpret=True)
+    handed = ssm.ssm_state_update(
+        state, 2, *operands, active, impl="pallas", interpret=True, live=(idx, n_live))
+    for got, want in zip(handed, derived):
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("kind", ["hybrid", "llama"])
+def test_state_slot_counters_follow_the_lanes_the_decode_executable_was_handed(tiny, kind):
+    """``state_slots_live_total`` / ``state_slots_held_total`` move, at every
+    decode dispatch, by the live lanes of the mask the ONE decode executable
+    is handed against every slot, times the burst's steps and the layers that
+    keep state: what the state kernel walks against what the cache holds. A
+    model with no such layer reads 0 in both."""
+    if kind == "hybrid":
+        model, state_layers = tiny[0], 3
+    else:
+        model = LlamaForCausalLM.from_config(
+            LlamaConfig.tiny(vocab_size=64, hidden_size=32, layers=2, heads=4, seq=128), seed=0)
+        state_layers = 0
+    engine = _engine(model, logprobs_topn=0)
+    assert engine.stats()["state_layers"] == state_layers
+    masks = []
+    decode = engine._decode_fn
+
+    def recorded(*args):
+        masks.append(np.array(args[5]))
+        return decode(*args)
+
+    engine._decode_fn = recorded
+    rng = np.random.default_rng(1)
+    for n, new in ((9, 14), (20, 6), (5, 10)):
+        engine.add_request(rng.integers(0, 64, size=n).tolist(), new)
+    engine.run_until_idle()
+    live = sum(int(m.sum()) for m in masks)
+    assert len(masks) > 2 and all(m.shape == (4, 1) for m in masks)
+    assert 0 < live < 4 * len(masks)  # three requests over four slots: never all live
+    stats = engine.stats()
+    assert stats["decode_compiles"] == 1
+    assert stats["state_slots_live_total"] == live * 4 * state_layers  # decode_burst 4
+    assert stats["state_slots_held_total"] == len(masks) * 4 * 4 * state_layers
+    engine.reset_stats()
+    stats = engine.stats()
+    assert stats["state_slots_live_total"] == stats["state_slots_held_total"] == 0
+
+
 def test_a_masked_lane_of_the_decode_step_leaves_state_and_tail_bit_identical(tiny):
     model, _ = tiny
     spec, slots = model.cache_spec, 4
