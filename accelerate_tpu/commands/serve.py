@@ -270,6 +270,7 @@ def _make_engine(args):
             kv_dtype=args.kv_dtype,
             state_dtype=getattr(args, "state_dtype", "auto"),
             spec_k=args.spec_k,
+            denoise_steps=getattr(args, "denoise_steps", None),
             draft=args.draft,
             flight_history=args.flight_history,
             stats_interval=getattr(args, "stats_interval", 32),
@@ -1019,6 +1020,14 @@ def add_parser(subparsers):
                    "token-identical to the non-speculative engine; sampled "
                    "requests verify by rejection sampling. A bad spec/draft "
                    "combination is a startup refusal (error row, exit 2)")
+    p.add_argument("--denoise-steps", type=int, default=None, metavar="T",
+                   help="denoise passes of a block round, for a model that generates by "
+                        "diffusion over blocks (sdar_moe): pass t fixes sub-block t of T, "
+                        "left to right, then one pass commits the clean block. Default: the "
+                        "block's length (one token a pass); must divide it. Fewer passes "
+                        "are fewer forwards a token and a coarser conditioning. Refused for "
+                        "a model that decodes one token a step; such a model refuses "
+                        "--spec-k, --mesh, grammars and repetition penalties")
     p.add_argument("--draft", default=os.environ.get(
                        "ACCELERATE_SERVE_DRAFT", "early_exit:2"),
                    help="draft policy when --spec-k > 0 (env "

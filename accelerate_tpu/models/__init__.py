@@ -121,7 +121,7 @@ def __getattr__(name):
 #: the ``model_type`` values :func:`config_from_hf_json` maps
 KNOWN_MODEL_TYPES = (
     "llama", "mistral", "gpt2", "bert", "vit", "opt", "gpt_neox", "gptj", "mixtral",
-    "t5", "mt5", "granitemoehybrid", "lfm2_moe",
+    "t5", "mt5", "granitemoehybrid", "lfm2_moe", "sdar_moe",
 )
 
 
@@ -293,6 +293,21 @@ def config_from_hf_json(path: str):
         # choices than experts, conv_bias) is refused by the config itself
         fields = {f.name for f in dataclasses.fields(Lfm2MoeConfig)} - {"remat"}
         return Lfm2MoeConfig(**{k: d[k] for k in fields if d.get(k) is not None})
+    if mt == "sdar_moe":
+        from .sdar_moe import SdarMoeConfig
+
+        # every layer is a routed one and attends every position: what the
+        # published file can say otherwise is refused, not approximated
+        for key, built in (("mlp_only_layers", []), ("decoder_sparse_step", 1),
+                           ("attention_bias", False), ("use_sliding_window", False),
+                           ("rope_scaling", None), ("hidden_act", "silu")):
+            if d.get(key, built) != built:
+                raise ValueError(
+                    f"sdar_moe with {key} {d[key]!r}: built as published for "
+                    f"SDAR-30B-A3B-Chat, {key} {built!r}"
+                )
+        fields = {f.name for f in dataclasses.fields(SdarMoeConfig)} - {"remat"}
+        return SdarMoeConfig(**{k: d[k] for k in fields if d.get(k) is not None})
     raise ValueError(
         f"unsupported model_type {mt!r} (known: {', '.join(KNOWN_MODEL_TYPES)})"
     )
@@ -312,6 +327,10 @@ def model_factory_for_config(config):
         from .lfm2 import Lfm2MoeForCausalLM
 
         return lambda c, **kw: Lfm2MoeForCausalLM.from_config(c, **kw)
+    if name == "SdarMoeConfig":
+        from .sdar_moe import SdarMoeForCausalLM
+
+        return lambda c, **kw: SdarMoeForCausalLM.from_config(c, **kw)
     if name == "GPT2Config":
         from .gpt2 import GPT2LMHeadModel
 

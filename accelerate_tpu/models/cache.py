@@ -76,6 +76,23 @@ class CacheSpec:
             for name, leaf in self.slot_state.items()})
 
 
+@dataclass(frozen=True)
+class BlockDecode:
+    """What a model that generates by diffusion over blocks declares beside
+    its cache spec (``model.block_decode``), and all the engine reads of it:
+    the sequence is cut into blocks of ``block_length`` positions, attention
+    is causal from block to block and bidirectional inside one, and a block
+    is generated by filling it with ``mask_token_id`` and unmasking it over
+    some forward passes, after which one pass over the clean block writes
+    the keys and values later blocks attend. The logits at a masked position
+    are the distribution of the token AT that position (no shift). The
+    model's paged step applies the visibility rule itself; the engine runs
+    the rounds (``serving/engine.py:_build_block_decode_fn``)."""
+
+    block_length: int
+    mask_token_id: int
+
+
 def cache_spec_of(model) -> CacheSpec:
     """The model's declared spec, or the one every attention-only model of
     the zoo means: every layer paged, no slot state."""

@@ -242,14 +242,31 @@ def rope_cached_attention_block(
     return x, k_cache_l, v_cache_l
 
 
-def cached_attention(q, k_cache, v_cache, idx):
+def last_visible(q_pos, block_len: int = 1):
+    """The last cache position a query at ``q_pos`` attends. ``block_len``
+    1 is the causal rule (itself); a block-diffusion model's sequence is cut
+    into blocks of ``block_len`` positions, causal from block to block and
+    bidirectional inside one: the end of the query's own block. Static in
+    ``block_len``, and at 1 no operation is traced."""
+    if block_len == 1:
+        return q_pos
+    if block_len & (block_len - 1) == 0:
+        # a power of two: the block's last position sets the low bits (an
+        # ``or`` where Mosaic would else divide a vector of integers)
+        return q_pos | (block_len - 1)
+    return (q_pos // block_len + 1) * block_len - 1
+
+
+def cached_attention(q, k_cache, v_cache, idx, block_len: int = 1):
     """Chunked attention against a KV cache with per-row valid prefix.
 
     q: ``[b, s, nh, hd]`` (``s == 1``: the token being decoded; ``s > 1``:
     a speculative-verify chunk); caches ``[b, max_cache, n_kv, hd]``
     already containing this chunk's K/V at ``idx[b] .. idx[b]+s-1``. Query
     position ``j`` of row ``b`` attends cache positions ``<= idx[b]+j`` —
-    the per-row prefix plus the causal triangle within the chunk. GQA
+    the per-row prefix plus the causal triangle within the chunk (with
+    ``block_len`` > 1, every position up to the end of the query's own
+    block of that many positions: :func:`last_visible`). GQA
     handled by repeating KV heads. f32 scores/softmax. Shared by every
     model family's decode step (no per-model drift in the masking or
     dtype policy).
@@ -265,7 +282,9 @@ def cached_attention(q, k_cache, v_cache, idx):
     qg = q.astype(jnp.float32).reshape(b, s, n_kv, rep, hd)
     max_cache = k_cache.shape[1]
     q_pos = idx[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]  # [b, s]
-    valid = jnp.arange(max_cache)[None, None, :] <= q_pos[:, :, None]  # [b, s, max]
+    valid = (  # [b, s, max]
+        jnp.arange(max_cache)[None, None, :] <= last_visible(q_pos, block_len)[:, :, None]
+    )
     scores = jnp.einsum(
         "bqnrd,bknd->bnrqk", qg, k_cache.astype(jnp.float32)
     ) / np.sqrt(float(hd))

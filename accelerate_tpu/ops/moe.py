@@ -1,7 +1,8 @@
-"""Routed experts without a capacity: a sigmoid router with a selection
-bias, and the dropless expert product.
+"""Routed experts without a capacity: a router that scores every expert
+(a sigmoid with a selection bias, or a softmax over all of them) and keeps
+the top ``k``, and the dropless expert product.
 
-    s = sigmoid(x W_g)                                   [T, E], float32
+    s = sigmoid(x W_g)   or   softmax(x W_g) over all E    [T, E], float32
     chosen = top_k(s + expert_bias)                      selection only
     weight = s[chosen] / (sum s[chosen] + 1e-6) * scale  the un-biased scores
     y = sum_i weight_i * W_out[e_i](silu(g) * u),  [g | u] = x W_in[e_i]
@@ -41,15 +42,23 @@ def default_moe_impl() -> str:
 
 
 def route(x, w_gate, expert_bias, top_k: int, norm_topk_prob: bool = True,
-          routed_scaling_factor: float = 1.0):
+          routed_scaling_factor: float = 1.0, scoring: str = "sigmoid"):
     """``x [T, h]`` -> ``(experts [T, k] int32, weights [T, k] float32)``.
-    The gate's product, the sigmoid and the top-k run in float32 (in
-    bfloat16 two scores tie). ``expert_bias [E]`` moves which experts are
-    chosen and never the weights of those chosen."""
+    The gate's product, the scores and the top-k run in float32 (in
+    bfloat16 two scores tie). ``scoring``: ``"sigmoid"``, each expert scored
+    alone, or ``"softmax"`` over all the experts. ``expert_bias [E]`` (or
+    ``None`` where the model has none) moves which experts are chosen and
+    never the weights of those chosen."""
     f32 = jnp.float32
-    scores = jax.nn.sigmoid(
-        jnp.dot(x.astype(f32), w_gate.astype(f32), precision=_HI))
-    _, experts = jax.lax.top_k(scores + expert_bias.astype(f32), top_k)
+    logits = jnp.dot(x.astype(f32), w_gate.astype(f32), precision=_HI)
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"unknown router scoring {scoring!r}: want sigmoid or softmax")
+    biased = scores if expert_bias is None else scores + expert_bias.astype(f32)
+    _, experts = jax.lax.top_k(biased, top_k)
     weights = jnp.take_along_axis(scores, experts, axis=-1)
     if norm_topk_prob:
         weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-6)
@@ -87,7 +96,7 @@ def expert_ffn(x, experts, weights, w_in, w_out, live=None, layer: int | None = 
     ``w_out [E, f, h]``; ``live [T]`` bool (``None``: every token). With
     ``layer`` (a static index) the matrices are a model's stacks ``[layers,
     E, ...]`` and the product addresses ``(layer, expert)`` in them: no
-    layer's 32 experts are sliced out to be multiplied. Returns ``(y [T, h],
+    layer's experts are sliced out to be multiplied. Returns ``(y [T, h],
     counts [E] int32)``: the weighted sum of each token's experts, zero for
     a token that is not live, and the pairs each expert was given."""
     t, k = experts.shape

@@ -51,6 +51,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from .fp8 import dequantize_kv
+from .layers import last_visible
 
 _NEG_INF = float(np.finfo(np.float32).min)
 
@@ -84,12 +85,19 @@ def paged_attention(
     v_scale=None,
     impl: str | None = None,
     interpret: bool = False,
+    block_len: int = 1,
 ):
     """Attention of ``q`` against each row's block-table span in layer
     ``layer`` of the stacked pools. Query ``j`` of row ``b`` attends
     logical cache positions ``<= idx[b]+j`` — the same per-row valid-prefix
     + intra-chunk causal policy as :func:`ops.layers.cached_attention`, so
-    paged decode keeps matching dense decode. Every route addresses the
+    paged decode keeps matching dense decode. ``block_len`` (static; not the
+    pool's block size) is a block-diffusion model's: with it above 1 the
+    query attends every position before the end of its own block of
+    ``block_len`` positions, ``< ((idx[b]+j) // block_len + 1) * block_len``
+    (:func:`ops.layers.last_visible`), on every route alike; the caller has
+    written what of that span a query may see. At 1 each route traces what
+    it traced before the parameter was there. Every route addresses the
     pool at ``(layer, block)``; none slices the layer out first. ``impl``:
     ``None`` routes via :func:`default_paged_attention_impl`;
     ``"lax"``/``"pallas"``/``"gather"`` force a path (``"gather"`` is the
@@ -101,16 +109,16 @@ def paged_attention(
     layer = jnp.asarray(layer, jnp.int32)
     if impl == "lax":
         return _paged_attention_lax(
-            q, k_pool, v_pool, layer, block_tables, idx, k_scale, v_scale
+            q, k_pool, v_pool, layer, block_tables, idx, k_scale, v_scale, block_len
         )
     if impl == "pallas":
         return _paged_attention_pallas_sharded(
             q, k_pool, v_pool, layer, block_tables, idx, k_scale, v_scale,
-            interpret=interpret,
+            interpret=interpret, block_len=block_len,
         )
     if impl == "gather":
         return _paged_attention_gather(
-            q, k_pool, v_pool, layer, block_tables, idx, k_scale, v_scale
+            q, k_pool, v_pool, layer, block_tables, idx, k_scale, v_scale, block_len
         )
     raise ValueError(f"unknown paged attention impl {impl!r}")
 
@@ -126,7 +134,8 @@ def _kv_heads(q, k_pool) -> int:
 # ---------------------------------------------------------------------------
 
 
-def _paged_attention_lax(q, k_pool, v_pool, layer, block_tables, idx, k_scale, v_scale):
+def _paged_attention_lax(q, k_pool, v_pool, layer, block_tables, idx, k_scale, v_scale,
+                         block_len=1):
     b, s, nh, hd = q.shape
     bs = k_pool.shape[2]
     n_kv = _kv_heads(q, k_pool)
@@ -138,6 +147,7 @@ def _paged_attention_lax(q, k_pool, v_pool, layer, block_tables, idx, k_scale, v
     # scale folded into q once (not per block); grouped heads for GQA
     qg = (q.astype(jnp.float32) / np.sqrt(float(hd))).reshape(b, s, n_kv, rep, hd)
     q_pos = idx[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]  # [b, s]
+    q_last = last_visible(q_pos, block_len)
 
     def body(carry, j):
         m, l, acc = carry
@@ -151,7 +161,7 @@ def _paged_attention_lax(q, k_pool, v_pool, layer, block_tables, idx, k_scale, v
         # [b, n_kv, rep, s, bs]: contraction over hd, batched over kv head
         sc = jnp.einsum("bsnrd,btnd->bnrst", qg, kb)
         pos = j * bs + jnp.arange(bs, dtype=jnp.int32)   # logical positions
-        valid = pos[None, None, :] <= q_pos[:, :, None]  # [b, s, bs]
+        valid = pos[None, None, :] <= q_last[:, :, None]  # [b, s, bs]
         vmask = valid[:, None, None, :, :]
         sc = jnp.where(vmask, sc, _NEG_INF)
         m_new = jnp.maximum(m, sc.max(axis=-1))
@@ -178,7 +188,8 @@ def _paged_attention_lax(q, k_pool, v_pool, layer, block_tables, idx, k_scale, v
 # ---------------------------------------------------------------------------
 
 
-def _paged_attention_gather(q, k_pool, v_pool, layer, block_tables, idx, k_scale, v_scale):
+def _paged_attention_gather(q, k_pool, v_pool, layer, block_tables, idx, k_scale, v_scale,
+                            block_len=1):
     """Materialise each row's logical cache — ``[b, max_blocks*bs, n_kv,
     hd]`` gathered through the table; logical position ``p`` lands at
     gathered index ``p`` (tables are ordered) — and feed
@@ -198,7 +209,7 @@ def _paged_attention_gather(q, k_pool, v_pool, layer, block_tables, idx, k_scale
 
     return cached_attention(
         q, span(k_pool, k_scale), span(v_pool, v_scale),
-        jnp.asarray(idx, jnp.int32).reshape(b),
+        jnp.asarray(idx, jnp.int32).reshape(b), block_len=block_len,
     )
 
 
@@ -213,10 +224,11 @@ _IN_FLIGHT = 1
 
 
 def _pallas_kernel(bt_ref, idx_ref, layer_ref, q_ref, *rest,
-                   bs, n_kv, rep, hd, quantized):
+                   bs, n_kv, rep, hd, quantized, block_len=1):
     """Grid ``(b,)``: step ``i`` is row ``i``, and a loop inside it walks
     the row's own table entries ``0 .. n_i - 1``, ``n_i = (idx[i] + s - 1)
-    // bs + 1`` (at most the table's width) — a trip count read from the
+    // bs + 1`` (at most the table's width; with ``block_len`` above 1 the
+    last query's last visible position takes the place of ``idx[i] + s - 1``) — a trip count read from the
     prefetched ``idx``, so a dead slot (``idx`` 0) costs one entry and a
     short row costs its length, whatever the table could hold. The pools
     (and a quantized pool's scales) stay in HBM, whole; the kernel copies
@@ -255,7 +267,9 @@ def _pallas_kernel(bt_ref, idx_ref, layer_ref, q_ref, *rest,
         ]
 
     first = idx_ref[i]
-    live = jnp.minimum((first + s - 1) // bs + 1, mb)   # the row's own entries
+    # the row's own entries: up to the one that holds its last query's last
+    # visible position
+    live = jnp.minimum(last_visible(first + s - 1, block_len) // bs + 1, mb)
     for j in range(min(_IN_FLIGHT, mb)):
         @pl.when(j < live)
         def _first():
@@ -277,7 +291,7 @@ def _pallas_kernel(bt_ref, idx_ref, layer_ref, q_ref, *rest,
         slot = j % depth
         q_pos = first + jax.lax.broadcasted_iota(jnp.int32, (s, bs), 0)
         k_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (s, bs), 1)
-        valid = k_pos <= q_pos
+        valid = k_pos <= last_visible(q_pos, block_len)
         for n in range(n_kv):
             kv_lanes = slice(n * hd, (n + 1) * hd)
             kb = k_buf[slot, :, kv_lanes].astype(jnp.float32)   # [bs, hd]
@@ -326,7 +340,7 @@ def _scale_blocks(scale, layer):
 
 
 def _paged_attention_pallas(q, k_pool, v_pool, layer, block_tables, idx,
-                            k_scale, v_scale, *, interpret):
+                            k_scale, v_scale, *, interpret, block_len=1):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -361,7 +375,7 @@ def _paged_attention_pallas(q, k_pool, v_pool, layer, block_tables, idx,
     out = pl.pallas_call(
         functools.partial(
             _pallas_kernel, bs=bs, n_kv=n_kv, rep=nh // n_kv, hd=hd,
-            quantized=quantized,
+            quantized=quantized, block_len=block_len,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, s, nh * hd), q.dtype),
@@ -379,7 +393,7 @@ def _paged_attention_pallas(q, k_pool, v_pool, layer, block_tables, idx,
 
 
 def _paged_attention_pallas_sharded(q, k_pool, v_pool, layer, block_tables, idx,
-                                    k_scale, v_scale, *, interpret):
+                                    k_scale, v_scale, *, interpret, block_len=1):
     """The kernel under the active mesh: GSPMD treats a Mosaic call as
     opaque, so with the pool's folded kv-head lanes sharded over the head
     axis (``parallel.sharding.paged_kv_sharding`` — whole heads per shard)
@@ -392,7 +406,8 @@ def _paged_attention_pallas_sharded(q, k_pool, v_pool, layer, block_tables, idx,
     from .attention import get_attention_context
 
     ctx = get_attention_context()
-    kernel = functools.partial(_paged_attention_pallas, interpret=interpret)
+    kernel = functools.partial(_paged_attention_pallas, interpret=interpret,
+                               block_len=block_len)
     extent = 1 if ctx.mesh is None else dict(ctx.mesh.shape).get(ctx.head_axis, 1)
     if extent == 1 or q.shape[2] % extent or _kv_heads(q, k_pool) % extent:
         return kernel(q, k_pool, v_pool, layer, block_tables, idx, k_scale, v_scale)

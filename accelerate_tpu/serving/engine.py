@@ -32,6 +32,13 @@ vLLM-style block-paged cache):
   the residual sync the host could not hide. Dispatch *i+1* still happens
   strictly after harvest *i*, so output is token-identical to the
   synchronous loop (``async_dispatch=False`` / ``serve --sync-engine``);
+* **block rounds** (a model that declares ``block_decode``, one that
+  generates by diffusion over blocks): the one decode executable is the
+  round — each slot's next block of ``B`` positions is filled with the
+  mask token, unmasked over ``denoise_steps`` forwards of static shape
+  ``[num_slots, B]`` and committed by one more over the clean block, so a
+  slot advances by whole blocks and a round emits up to ``B`` tokens a
+  slot (:meth:`InferenceEngine._build_block_decode_fn`);
 * **per-slot sampling + constrained decoding**: temperature / top-k /
   top-p / repetition penalty / seed / grammar-DFA state ride as
   fixed-shape *lane inputs* of the same ONE decode executable
@@ -215,6 +222,16 @@ class EngineConfig:
     #: longest-agreeing-prefix path — so speculation composes with
     #: ``do_sample``.
     spec_k: int = 0
+    #: denoise passes of a block round (``None``: the block's length, one
+    #: token a pass), for a model that declares ``block_decode``
+    #: (``models/cache.py:BlockDecode``): pass ``t`` fixes the still-masked
+    #: positions of sub-block ``t`` of the ``denoise_steps`` equal sub-blocks
+    #: of a block, left to right, each to the pick of its own logits; one
+    #: more forward over the clean block then commits it. Fewer passes are
+    #: fewer forwards a token and a coarser conditioning (a sub-block's
+    #: tokens are picked without seeing each other). Must divide the block's
+    #: length; refused for a model that decodes one token a step.
+    denoise_steps: int | None = None
     #: draft policy when ``spec_k > 0`` (see :mod:`.spec`):
     #: ``"early_exit:N"`` runs the target's own first N layers (+ its final
     #: norm/head) as the draft, reading/writing the FIRST N LAYERS of the
@@ -263,9 +280,11 @@ class _InFlightRound:
     requests, and members only finish at harvest), so ``req.slot`` still
     indexes the result arrays when the harvest lands."""
 
-    kind: str  # "burst" | "spec"
+    kind: str  # "burst" | "spec" | "block"
     live: list
-    toks: object  # [burst, slots] next-token future, or [slots, k+1] spec
+    #: [burst, slots] next-token future; [slots, k+1] of a spec round;
+    #: [burst, slots, B] of block rounds
+    toks: object
     accept: object = None  # [slots] accepted-prefix lengths (spec only)
     logps: object = None
     tvals: object = None
@@ -273,7 +292,17 @@ class _InFlightRound:
     harvest_lp: bool = False
     #: the model's step counters of this round, ``{name: [burst, ...]}``
     counters: object = None
+    #: block rounds: ``[slots]``, the positions of each slot's open block that
+    #: were known (a prompt's tail) when the dispatch was built: no round's to emit
+    known: object = None
 
+
+#: what the engine counts of its block rounds (``_block_stats`` derives the
+#: forwards from the rounds: a round is ``denoise_steps`` + 1 of them)
+_BLOCK_TOTALS = (
+    "block_rounds_total", "block_slot_forwards_total",
+    "block_positions_committed_total", "block_tokens_emitted_total",
+)
 
 #: keys of a flight entry that place it on a clock; the ``serve/flight``
 #: Chrome instant carries the rest (its own ``ts`` places it, and the
@@ -372,6 +401,21 @@ class InferenceEngine:
                         f"{missing} (ROADMAP Reach 5)"
                     )
 
+        # a model that generates by diffusion over blocks: the decode
+        # executable is the block round, and what cannot hold under parallel
+        # unmasking is refused here or at add_request, the reasons in stats()
+        blk = getattr(inner, "block_decode", None)
+        self._block = blk if blk is not None and blk.block_length > 1 else None
+        self._denoise_steps = 0
+        self.block_round_refuses: dict = {}
+        if self._block is None and cfg.denoise_steps is not None:
+            raise ValueError(
+                f"denoise_steps={cfg.denoise_steps} for a model that decodes one token "
+                "a step: only a model that declares block_decode has denoise passes"
+            )
+        if self._block is not None:
+            self._init_block_rounds(inner, mesh)
+
         # speculative decoding (spec_k > 0): parse the draft policy and
         # bind the early-exit draft apply BEFORE anything allocates — a bad
         # spec must refuse at bring-up, like every other geometry error
@@ -399,10 +443,20 @@ class InferenceEngine:
                     "llama_early_exit_apply)"
                 )
             self._draft_apply = _under_mesh(factory(self._spec.layers), mesh)
-        #: cache positions one decode dispatch may write past context_len —
-        #: the block-growth lookahead (a spec round writes k+1 positions;
-        #: a plain dispatch writes decode_burst)
-        self._decode_lookahead = (cfg.spec_k + 1) if self._spec else cfg.decode_burst
+        #: cache positions one decode dispatch may write past context_len,
+        #: which is the block-growth lookahead. The burst writes the fed
+        #: token's K/V at context_len and each later step's one further on:
+        #: decode_burst positions. A spec round writes the pending token and
+        #: its k drafts: k + 1 positions, of which the next round writes the
+        #: rejected tail again. Block rounds write whole blocks from
+        #: context_len (a block boundary: every known token is committed but
+        #: the open block's own): decode_burst rounds of block_length each
+        if self._spec:
+            self._decode_lookahead = cfg.spec_k + 1
+        elif self._block is not None:
+            self._decode_lookahead = cfg.decode_burst * self._block.block_length
+        else:
+            self._decode_lookahead = cfg.decode_burst
 
         # per-slot sampling + grammar state (the lanes). The engine-wide
         # do_sample/temperature/seed are the DEFAULT SamplingParams a
@@ -527,6 +581,7 @@ class InferenceEngine:
         self.scheduler = SlotScheduler(
             cfg.num_slots, self.allocator, cfg.block_size, cfg.max_seq_len,
             radix=self.radix, usage=self.usage,
+            prefix_granule=self._block.block_length if self._block else 1,
         )
         #: everything the step programs keep for the sequences, in ONE dict
         #: that every one of them takes donated and hands back whole: "k" /
@@ -687,6 +742,13 @@ class InferenceEngine:
         # samples — the ones whose lax.cond takes the sort and the draw
         self._pick_dispatches = 0
         self._pick_draw_dispatches = 0
+        # block rounds (monotone totals; all 0 for a model that decodes one
+        # token a step): rounds as dispatched; forwards x the lanes live in
+        # them; positions the live lanes' rounds committed that no earlier
+        # round or the prompt had fixed; and the tokens emitted from them
+        # once EOS and length cuts are taken. Nothing here divides one by
+        # another
+        self._block_totals = dict.fromkeys(_BLOCK_TOTALS, 0)
         # static HBM model for the hbm watermark fallback: params + the
         # paged pools (+ scales), the same inventory the PR 8 preflight
         # prices — used verbatim when the backend has no memory_stats()
@@ -701,10 +763,13 @@ class InferenceEngine:
         #: program name -> (jitted fn, abstract operands of its first
         #: dispatch): what compiled_text() lowers against
         self._dispatched: dict[str, tuple] = {}
-        self._decode_fn = self._remember_first_dispatch(
-            "decode",
-            self._build_spec_decode_fn() if self._spec else self._build_decode_fn(),
-        )
+        if self._spec:
+            decode_fn = self._build_spec_decode_fn()
+        elif self._block is not None:
+            decode_fn = self._build_block_decode_fn()
+        else:
+            decode_fn = self._build_decode_fn()
+        self._decode_fn = self._remember_first_dispatch("decode", decode_fn)
         self._prefill_fn = self._remember_first_dispatch(
             "prefill", self._build_prefill_fn()
         )
@@ -1108,6 +1173,139 @@ class InferenceEngine:
 
         return jax.jit(spec_decode, donate_argnums=(1,))
 
+    def _init_block_rounds(self, inner, mesh) -> None:
+        """Geometry of the block rounds, checked at bring-up, and what is
+        refused beside them (``stats()['block_round_refuses']``)."""
+        cfg, blk = self.config, self._block
+        name = getattr(inner, "name", type(inner).__name__)
+        b = blk.block_length
+        t = b if cfg.denoise_steps is None else int(cfg.denoise_steps)
+        if t < 1 or b % t:
+            raise ValueError(
+                f"denoise_steps {t} does not divide {name!r}'s block_length {b}: a pass "
+                "fixes one of denoise_steps equal sub-blocks"
+            )
+        for what, value in (("block_size", cfg.block_size), ("prefill_chunk", cfg.prefill_chunk)):
+            if value % b:
+                raise ValueError(
+                    f"{what} {value} is not a multiple of {name!r}'s block_length {b}: a "
+                    "block of the model may not straddle a page of the pool or a prefill chunk"
+                )
+        self._denoise_steps = t
+        self.block_round_refuses = {
+            "grammar": "a DFA advances one token at a time, and a denoise pass fixes "
+                       "several positions from logits that did not see each other",
+            "spec_k": "a speculative round drafts and verifies one token a position, "
+                      "causally; a block round is not causal inside its block",
+            "repetition_penalty": "the penalty's window would have to hold tokens of the "
+                                  "same pass, which are picked together",
+            "mesh": "the round has not been built under a mesh (ROADMAP Reach 8)",
+        }
+        for armed, what in ((cfg.spec_k, "spec_k"), (mesh is not None, "mesh")):
+            if armed:
+                raise ValueError(
+                    f"{what} is not supported for {name!r}, which generates by diffusion "
+                    f"over blocks of {b}: {self.block_round_refuses[what]}"
+                )
+
+    def _build_block_decode_fn(self):
+        """The block round — the one decode executable of a model that
+        declares ``block_decode``. One dispatch runs ``decode_burst`` rounds;
+        a round takes every live slot's next block ``[pos, pos + B)``:
+
+        1. **denoise passes** ``t = 0 .. T-1`` (``T = denoise_steps``): one
+           forward of static shape ``[num_slots, B]`` over the block as it
+           stands (known positions their tokens, the rest the mask token)
+           against the pool. The paged step scatters the block's keys and
+           values at its positions first and every query then attends
+           everything written before the END of its block, so the block
+           sees itself as of this pass. The pick runs over ``[num_slots *
+           B]`` rows with each slot's lanes broadcast (a sampled lane draws
+           every position from its own row, keyed by its output position);
+           pass ``t`` fixes the still-masked positions of sub-block ``t``,
+           ``[t*B/T, (t+1)*B/T)``, and a position's log-probability is
+           that of the pass that fixed it;
+        2. **the commit pass**: one forward over the clean block. What it
+           writes at ``[pos, pos + B)`` is what later blocks attend: it
+           overwrites the denoise passes' rows before any other query reads
+           them, as a speculative round's verify overwrites its draft's, so
+           nothing is rolled back and no second cache exists. Its logits
+           are not used, and no head is computed for it.
+
+        In the first round a slot's block may open with known positions (a
+        prompt's last ``n mod B`` tokens): ``known`` keeps them. Later rounds
+        of the dispatch start from a fully masked block ``B`` further on.
+        Every pass runs for every slot (a pass with nothing to fix in some
+        slot is that slot's waste; shapes are static). Outputs: the cache,
+        ``[burst, slots, B]`` tokens and log-probabilities (top-N beside
+        them), and the step counters of every forward, ``[burst, T + 1,
+        ...]``. Donation and the traced-body compile counter are the plain
+        decode's: ``decode_compiles == 1`` stays the asserted contract."""
+        apply_fn, cfg, blk = self._apply_fn, self.config, self._block
+        eos_id, topn = cfg.eos_token_id, cfg.logprobs_topn
+        n_top = max(int(topn), 1)
+        counted = bool(self._step_counters)
+        b, t_steps, slots = blk.block_length, self._denoise_steps, cfg.num_slots
+        sub = b // t_steps
+        mask_id = jnp.int32(blk.mask_token_id)
+        col = jnp.arange(b, dtype=jnp.int32)
+
+        def decode_block_rounds(params, cache, block_tables, pos0, toks, known, active,
+                                lanes, gmask, base_key):
+            self._decode_traces += 1  # traced-body side effect: cache misses only
+            rows = {name: jnp.repeat(lane, b, axis=0) for name, lane in lanes.items()}
+            write = jnp.broadcast_to(active, (slots, b))
+
+            def forward(cache, x, pos):
+                return apply_fn(
+                    params, input_ids=x, paged_kv=cache, block_tables=block_tables,
+                    cache_positions=pos,
+                    paged_write_mask=write,  # PREFILL/free lanes must not scribble
+                )
+
+            def one_round(carry, n):
+                cache, x, known, pos = carry
+                # a row's output position: lanes["pos"] counts from the
+                # block's first position (the known ones lie before it)
+                row_lanes = dict(rows, pos=(
+                    (lanes["pos"] + n * b)[:, None] + col[None, :]).reshape(slots * b))
+                logp = jnp.zeros((slots, b), jnp.float32)
+                tvals = jnp.zeros((slots, b, n_top), jnp.float32)
+                tids = jnp.zeros((slots, b, n_top), jnp.int32)
+                counters = []
+                for t in range(t_steps):
+                    with jax.named_scope("denoise_pass"):
+                        out = forward(cache, x, pos)
+                    cache = out["paged_kv"]
+                    if counted:
+                        counters.append(out["step_counters"])
+                    tok, lp, tv, ti = pick_tokens(
+                        out["logits"].reshape(slots * b, -1), row_lanes,
+                        row_lanes["dfa_state"], jnp.int32(0), gmask, base_key,
+                        eos_id=eos_id, logprobs_topn=topn,
+                    )
+                    fix = ~known & (col >= t * sub) & (col < (t + 1) * sub)
+                    x = jnp.where(fix, tok.reshape(slots, b), x)
+                    logp = jnp.where(fix, lp.reshape(slots, b), logp)
+                    tvals = jnp.where(fix[..., None], tv.reshape(slots, b, n_top), tvals)
+                    tids = jnp.where(fix[..., None], ti.reshape(slots, b, n_top), tids)
+                    known = known | fix
+                with jax.named_scope("commit_pass"):
+                    out = forward(cache, x, pos)
+                ys = (x, logp, tvals, tids)
+                if counted:
+                    counters.append(out["step_counters"])
+                    ys += (jax.tree.map(lambda *c: jnp.stack(c), *counters),)
+                nxt = (out["paged_kv"], jnp.full_like(x, mask_id), jnp.zeros_like(known),
+                       pos + b)
+                return nxt, ys
+
+            (cache, _, _, _), ys = jax.lax.scan(
+                one_round, (cache, toks, known, pos0), jnp.arange(cfg.decode_burst))
+            return (cache, *ys)
+
+        return jax.jit(decode_block_rounds, donate_argnums=(1,))
+
     def _build_prefill_fn(self):
         apply_fn = self._apply_fn
         has_state = bool(self._cache_spec.slot_state)
@@ -1192,6 +1390,7 @@ class InferenceEngine:
             priority=priority,
             trace_id=ensure_trace_id(trace_id),
             tenant=normalize_tenant(tenant),
+            block_len=self._block.block_length if self._block else 1,
         )
         if arrival_time is not None:
             req.arrival_time = arrival_time
@@ -1215,6 +1414,14 @@ class InferenceEngine:
                 "is per-engine, not per-request)"
             )
         req.sampling = params
+        if self._block is not None:
+            for armed, what in ((grammar is not None, "grammar"),
+                                (params.repetition_penalty != 1.0, "repetition_penalty")):
+                if armed:
+                    raise ValueError(
+                        f"{what} is not supported beside a block round: "
+                        f"{self.block_round_refuses[what]}"
+                    )
         if grammar is not None:
             g = compile_grammar(
                 grammar, self._vocab_size,
@@ -1374,8 +1581,11 @@ class InferenceEngine:
                 self._iterations, t0, wall,
                 overlap_hidden_s=overlap, intervals=self._fl_intervals,
                 t_start_unix_ns=self._fl_unix_ns,
-                counters={name: int(total) for name, total in self._step_counters.items()
-                          if not total.shape},
+                counters={
+                    **{name: int(total) for name, total in self._step_counters.items()
+                       if not total.shape},
+                    **self._block_stats(),
+                },
                 **phases,
             )
             fl.current_phase = "idle"
@@ -1458,6 +1668,7 @@ class InferenceEngine:
         self._paged_entries_walked = self._paged_entries_table = 0
         self._state_slots_live = self._state_slots_held = 0
         self._pick_dispatches = self._pick_draw_dispatches = 0
+        self._block_totals = dict.fromkeys(_BLOCK_TOTALS, 0)
         for total in self._step_counters.values():
             total[...] = 0
         self._pending_counters = []  # dispatched before the reset: not this window's
@@ -1510,6 +1721,21 @@ class InferenceEngine:
             out["hbm_limit_bytes"] = limit
             out["hbm_headroom_bytes"] = limit - used
         return out
+
+    def _block_stats(self) -> dict:
+        """The block rounds' totals as ``stats()`` and the flight entries
+        carry them (empty for a model that decodes one token a step):
+        forwards as run — ``denoise_steps`` denoise and one commit a round —
+        beside what the engine counts."""
+        if self._block is None:
+            return {}
+        rounds = self._block_totals["block_rounds_total"]
+        return {
+            **self._block_totals,
+            "block_denoise_forwards_total": rounds * self._denoise_steps,
+            "block_commit_forwards_total": rounds,
+            "block_forwards_total": rounds * (self._denoise_steps + 1),
+        }
 
     def _spec_stats(self) -> dict:
         """Speculative health fields (accept rate is the TPOT lever — each
@@ -1638,6 +1864,14 @@ class InferenceEngine:
             "pick_dispatches_total": self._pick_dispatches,
             "pick_draw_dispatches_total": self._pick_draw_dispatches,
         }
+        if self._block is not None:
+            # tokens are counted as emitted and forwards as run: a round is
+            # denoise_steps + 1 forwards and commits block_length positions
+            # a live lane, of which EOS and length cuts emit fewer
+            out.update(block_length=self._block.block_length,
+                       denoise_steps=self._denoise_steps,
+                       block_round_refuses=dict(self.block_round_refuses),
+                       **self._block_stats())
         if self._step_counters:
             # summed at each harvest, by the engine's thread alone: a reading
             # lacks the round in flight and the chunks dispatched since
@@ -1831,7 +2065,7 @@ class InferenceEngine:
             tok_seq, accept = (
                 np.asarray(x) for x in jax.device_get((rd.toks, rd.accept))
             )
-        elif rd.harvest_lp:
+        elif rd.harvest_lp or rd.kind == "block":
             # the logprob surfaces ride the SAME device_get — no second
             # dispatch, no extra sync point
             next_toks, logps, tvals, tids, *counted = jax.device_get(
@@ -1871,6 +2105,31 @@ class InferenceEngine:
                     if req.state is RequestState.FINISHED:
                         break  # mid-round eos/length: the run's tail is waste
                     self._emit_token(req, int(tok_seq[req.slot, t]), finished)
+        elif rd.kind == "block":
+            # [burst, slots, B]: a slot's rounds in order, a round's positions
+            # in order; the first round's known positions were emitted before
+            # (or are the prompt's). EOS or the length budget inside a block
+            # ends the request there: the rest of the block, and the later
+            # rounds, are committed and not emitted
+            before = self._tokens_emitted
+            for req in rd.live:
+                want_lp = req.sampling is not None and req.sampling.logprobs
+                first = int(rd.known[req.slot])
+                for n, j in np.ndindex(next_toks.shape[0], next_toks.shape[2]):
+                    if n == 0 and j < first:
+                        continue
+                    if req.state is RequestState.FINISHED:
+                        break
+                    entry = None
+                    if want_lp:
+                        entry = self._logprob_entry(
+                            req.sampling, float(logps[n, req.slot, j]),
+                            tvals[n, req.slot, j], tids[n, req.slot, j],
+                        )
+                    self._emit_token(
+                        req, int(next_toks[n, req.slot, j]), finished, entry
+                    )
+            self._block_totals["block_tokens_emitted_total"] += self._tokens_emitted - before
         else:
             for req in rd.live:
                 want_lp = (
@@ -2029,8 +2288,9 @@ class InferenceEngine:
                     req.trace_id, "req/swap_in", blocks=n,
                     seconds=time.perf_counter() - swap_t0,
                 )
-            if req.state is RequestState.DECODE:
-                # resume feeding the last emitted token at context_len
+            if req.state is RequestState.DECODE and req.output_tokens:
+                # resume feeding the last emitted token at context_len (a
+                # block model feeds none: its rounds start from the request)
                 self._pending_tok[req.slot] = req.output_tokens[-1]
         elif req.cow is not None:
             src, dst = req.cow
@@ -2189,16 +2449,19 @@ class InferenceEngine:
         row[:] = 0
         row[: len(req.blocks)] = req.blocks
 
-    def _count_paged_entries(self, first, queries: int, layers: int) -> None:
+    def _count_paged_entries(self, first, queries: int, layers: int, calls: int = 1) -> None:
         """Book one dispatch's paged-attention calls: ``first`` holds the
         first query's cache position of every row of every step (any
         shape), each row asks ``queries`` positions, ``layers`` layers run
-        it. A row walks the entries up to its last query's block — the trip
-        count ``ops/paged_attention.py`` reads from the same positions."""
+        it, ``calls`` times over (the forwards of a block round). A row walks
+        the entries up to its last query's block — the trip count
+        ``ops/paged_attention.py`` reads from the same positions (a block
+        model's chunks and rounds end on a block's end, where the last
+        query's last visible position is the last query)."""
         last = np.asarray(first, np.int64) + queries - 1
         walked = np.minimum(last // self.config.block_size + 1, self._mb)
-        self._paged_entries_walked += int(walked.sum()) * layers
-        self._paged_entries_table += walked.size * self._mb * layers
+        self._paged_entries_walked += int(walked.sum()) * layers * calls
+        self._paged_entries_table += walked.size * self._mb * layers * calls
 
     def _count_state_slots(self, active) -> None:
         """Book one decode dispatch's slot-state steps from the mask the
@@ -2210,14 +2473,15 @@ class InferenceEngine:
         self._state_slots_live += int(active.sum()) * steps
         self._state_slots_held += self.config.num_slots * steps
 
-    def _count_pick(self, lanes) -> None:
-        """Book one run of :func:`sampling.pick_tokens` from the host's copy
-        of the lanes it is handed: its sampler stage runs when some lane
-        samples. The cached idle lanes are blank by construction and live
-        on the device; they are not read back."""
-        self._pick_dispatches += 1
+    def _count_pick(self, lanes, runs: int = 1) -> None:
+        """Book ``runs`` runs of :func:`sampling.pick_tokens` (one a decode
+        dispatch or first pick; one a denoise pass of a block round) from the
+        host's copy of the lanes they are handed: the sampler stage runs when
+        some lane samples. The cached idle lanes are blank by construction
+        and live on the device; they are not read back."""
+        self._pick_dispatches += runs
         if lanes is not self._lanes_idle and lanes["sample"].any():
-            self._pick_draw_dispatches += 1
+            self._pick_draw_dispatches += runs
 
     def _prefill_one_chunk(self, req: Request, finished: list[Request]) -> None:
         """One chunk of one prompt. Its two clock reads are the request's
@@ -2233,26 +2497,36 @@ class InferenceEngine:
         # had emitted: all but the last (still pending) are prefilled again
         # behind the prompt, and nothing is emitted for them a second time
         replay = req.recompute and bool(req.output_tokens)
-        seq = list(req.prompt) + req.output_tokens[:-1] if replay else req.prompt
-        total = len(seq)
+        blk = self._block
+        if blk is None:
+            seq = list(req.prompt) + req.output_tokens[:-1] if replay else req.prompt
+            total = len(seq)
+        else:
+            # a block model prefills whole blocks only (a chunk's last query
+            # must see the end of its block); the last ``n mod B`` known
+            # tokens open the first round's block as clean positions. Every
+            # emitted token is committed or in that tail: none is pending
+            seq = list(req.prompt) + req.output_tokens if replay else req.prompt
+            total = len(seq) // blk.block_length * blk.block_length
         end = min(start + c, total)
         chunk = np.zeros((1, c), np.int32)
-        chunk[0, : end - start] = seq[start:end]
+        chunk[0, : max(end - start, 0)] = seq[start:end]
         valid = np.zeros((1, c), bool)
-        valid[0, : end - start] = True
+        valid[0, : max(end - start, 0)] = True
         self._sync_block_table(req)
-        is_final = end == total
+        is_final = end >= total
         last_idx = np.int32((total - 1) - start if is_final else 0)
 
-        self._count_paged_entries([start], c, self._cache_spec.paged_layers)
-        self._cache, logits, *counters = self._prefill_fn(
-            self._params, self._cache,
-            self._block_tables[req.slot : req.slot + 1].copy(),
-            np.asarray([start], np.int32), chunk, valid, last_idx,
-            np.asarray([req.slot], np.int32),
-        )
-        # a chunk's step counters stay on the device until the next harvest
-        self._pending_counters.extend(counters)
+        if end > start:  # (a block model's prompt may hold no whole block to prefill)
+            self._count_paged_entries([start], c, self._cache_spec.paged_layers)
+            self._cache, logits, *counters = self._prefill_fn(
+                self._params, self._cache,
+                self._block_tables[req.slot : req.slot + 1].copy(),
+                np.asarray([start], np.int32), chunk, valid, last_idx,
+                np.asarray([req.slot], np.int32),
+            )
+            # a chunk's step counters stay on the device until the next harvest
+            self._pending_counters.extend(counters)
         req.prefill_pos = end
         req.prefill_iterations += 1
         lp_entry = None
@@ -2260,7 +2534,13 @@ class InferenceEngine:
             # recomputed: preempted before its first token or after, the
             # request is whole again when this chunk has run
             req.recompute = req.preempted = False
-        if is_final and replay:
+        if is_final and blk is not None:
+            # no token comes of a block model's prefill (the logits at a
+            # position are that position's own token's): the first round
+            # emits the first tokens. prefill_pos stays a prompt position
+            req.prefill_pos = min(end, req.prompt_len)
+            req.state = RequestState.DECODE
+        elif is_final and replay:
             # the cache holds prompt + fed output again: decoding resumes
             # with the token that was pending when the request was preempted
             req.prefill_pos = req.prompt_len
@@ -2287,10 +2567,13 @@ class InferenceEngine:
                 # the prompt's full blocks now hold valid K/V: adopt them
                 # into the prefix trie (refcount+1 = the cache's reference)
                 # so later admissions with the same leading tokens map them
+                # (a block model's pages hold whole blocks of its own, so a
+                # page's K/V depend on no token past the page's end)
                 self.radix.insert(req.prompt, req.blocks)
-            self._emit_token(req, tok, finished, lp_entry, now=t1)
-            if req.state is not RequestState.FINISHED:
-                req.state = RequestState.DECODE
+            if blk is None:
+                self._emit_token(req, tok, finished, lp_entry, now=t1)
+                if req.state is not RequestState.FINISHED:
+                    req.state = RequestState.DECODE
 
     def _ensure_decode_capacity(self, req: Request, finished: list[Request]) -> None:
         """Growth for one decode lane, with swap preemption under pool
@@ -2303,7 +2586,10 @@ class InferenceEngine:
         sched = self.scheduler
         # a model with per-slot state has no swap tier (refused at bring-up):
         # its victim gives its blocks back and is recomputed on re-admission
-        recompute = bool(self._cache_spec.slot_state)
+        # (so does a block model's where there is no swap tier: every token it
+        # emitted is in its replayed prefill or its open block, none pending)
+        recompute = bool(self._cache_spec.slot_state) or (
+            self._block is not None and self._swap is None)
         preempt = self._preempt_by_recompute if recompute else self._swap_out
         while not sched.grow_for_decode(req, tokens_ahead=self._decode_lookahead):
             if self._swap is None and not recompute:
@@ -2375,6 +2661,18 @@ class InferenceEngine:
             live.append(req)
         if not live:
             return
+        known = n_known = None
+        if self._block is not None:
+            # every slot's open block: the tokens it already knows (a prompt's
+            # tail; nothing once a round has run), the mask token elsewhere
+            b = self._block.block_length
+            toks = np.full((cfg.num_slots, b), self._block.mask_token_id, np.int32)
+            known = np.zeros((cfg.num_slots, b), bool)
+            for req in live:
+                tail = (req.prompt + req.output_tokens)[req.context_len:]
+                toks[req.slot, : len(tail)] = tail
+                known[req.slot, : len(tail)] = True
+            n_known = known.sum(axis=1)
 
         # per-slot lanes: rebuilt from the live requests on EVERY dispatch
         # (pos/ring/DFA state re-derived from the request, so preemption,
@@ -2395,7 +2693,10 @@ class InferenceEngine:
                 params = req.sampling or self._default_sampling
                 set_slot_lane(
                     lanes, req.slot, params,
-                    pos=len(req.output_tokens),
+                    # (block rounds count output positions from the open
+                    # block's first position, known ones included)
+                    pos=len(req.output_tokens) - (
+                        0 if n_known is None else int(n_known[req.slot])),
                     grammar_row=req.grammar_row, dfa_state=req.dfa_state,
                     recent=(
                         req.prompt + req.output_tokens
@@ -2414,6 +2715,7 @@ class InferenceEngine:
                 *(("cache." + name, a) for name, a in sorted(self._cache.items())),
                 ("block_tables", self._block_tables), ("pos0", pos0),
                 ("toks", toks), ("active", active),
+                *((("known", known),) if known is not None else ()),
                 *sorted(lanes.items()),
                 ("gmask", self._gmask), ("gtrans", self._gtrans),
                 ("base_key", self._base_key),
@@ -2425,6 +2727,10 @@ class InferenceEngine:
 
         if self._spec is not None:
             self._spec_decode_dispatch(pos0, toks, active, lanes, live, decode_sig)
+            return
+        if self._block is not None:
+            self._block_decode_dispatch(
+                pos0, toks, known, n_known, active, lanes, live, decode_sig)
             return
         self._count_paged_entries(
             pos0 + np.arange(cfg.decode_burst)[:, None], 1, self._cache_spec.paged_layers
@@ -2481,6 +2787,43 @@ class InferenceEngine:
         # instant needs the accept values, so it moves to the harvest
         self._inflight = _InFlightRound(
             kind="spec", live=live, toks=tok_seq, accept=accept
+        )
+
+    def _block_decode_dispatch(
+        self, pos0, toks, known, n_known, active, lanes, live: list[Request],
+        decode_sig: tuple | None,
+    ) -> None:
+        """``decode_burst`` block rounds in one dispatch of the single
+        compiled executable; ``_harvest_inflight`` later emits each live
+        slot's new tokens, block by block in order, through the same
+        ``_emit_token`` as every other round (EOS and length budgets are
+        host state). What is counted here is what was dispatched: rounds,
+        forwards x live lanes, and the positions the live lanes' rounds
+        commit beyond those already known (``n_known [slots]``)."""
+        cfg, b, t = self.config, self._block.block_length, self._denoise_steps
+        burst, n_live = cfg.decode_burst, len(live)
+        # every forward of every round asks B queries a row from the block's start
+        self._count_paged_entries(
+            pos0 + b * np.arange(burst)[:, None], b, self._cache_spec.paged_layers,
+            calls=t + 1)
+        self._count_pick(lanes, runs=burst * t)
+        totals = self._block_totals
+        totals["block_rounds_total"] += burst
+        totals["block_slot_forwards_total"] += burst * (t + 1) * n_live
+        totals["block_positions_committed_total"] += burst * b * n_live - int(n_known.sum())
+        self._cache, next_toks, logps, tvals, tids, *counters = self._decode_fn(
+            self._params, self._cache, self._block_tables.copy(), pos0, toks, known, active,
+            lanes, self._gmask, self._base_key,
+        )
+        self._check_one_executable(decode_sig)
+        if self._tr is not None:
+            self._tr.instant(
+                "serve/block_rounds", slots=n_live, rounds=burst, block_length=b,
+                denoise_steps=t, trace_ids=[r.trace_id for r in live],
+            )
+        self._inflight = _InFlightRound(
+            kind="block", live=live, toks=next_toks, logps=logps, tvals=tvals, tids=tids,
+            harvest_lp=True, counters=counters[0] if counters else None, known=n_known,
         )
 
     def _check_one_executable(self, decode_sig: tuple | None) -> None:

@@ -134,6 +134,9 @@ class Request:
     #: last of those chunks has run
     recompute: bool = False
     preemptions: int = 0
+    #: positions of one block of a model that generates by diffusion over
+    #: blocks (1: a model that decodes one token a step); the engine sets it
+    block_len: int = 1
     #: final cost summary (device_time_s / kv_block_seconds / swap_bytes)
     #: stamped by the usage ledger when the engine processes completion;
     #: None on a usage_accounting=False engine
@@ -145,7 +148,14 @@ class Request:
 
     @property
     def context_len(self) -> int:
-        """Tokens whose K/V sit in the cache (prompt + fed output)."""
+        """Tokens whose K/V sit in the cache while the request decodes:
+        prompt + fed output (the last emitted token is pending, fed by the
+        next step). A block model has no pending token: a round commits a
+        whole block, so every known token is in the cache but those that
+        open the next block (a prompt's last ``n mod block_len``)."""
+        if self.block_len > 1:
+            known = self.prompt_len + len(self.output_tokens)
+            return known // self.block_len * self.block_len
         return self.prefill_pos + max(len(self.output_tokens) - 1, 0)
 
     @property
@@ -167,12 +177,17 @@ class SlotScheduler:
     the block allocator, and (optionally) the radix prefix cache."""
 
     def __init__(self, num_slots: int, allocator: BlockAllocator, block_size: int,
-                 max_seq_len: int, radix=None, usage=None):
+                 max_seq_len: int, radix=None, usage=None, prefix_granule: int = 1):
         self.num_slots = int(num_slots)
         self.allocator = allocator
         self.block_size = int(block_size)
         self.max_seq_len = int(max_seq_len)
         self.radix = radix
+        #: a prefix hit is cut back to a multiple of this many tokens (a
+        #: block model's block: a cached position's K/V depend on the tokens
+        #: up to its block's end, so a hit ends where a block does, and the
+        #: prefill that follows starts on a block's first position)
+        self.prefix_granule = int(prefix_granule)
         #: the engine's :class:`~.usage.UsageLedger` (None = accounting
         #: off): block-ownership edges here are where per-request KV
         #: block-seconds accrue
@@ -381,6 +396,7 @@ class SlotScheduler:
                 shared, matched, cow_src = [], 0, None
                 if self.radix is not None:
                     shared, matched, cow_src = self.radix.acquire(req.prompt)
+                    matched -= matched % self.prefix_granule
                 need = total_need - len(shared)
                 if not self._ensure_free(need):
                     if self.radix is not None:

@@ -6,7 +6,9 @@ priority class, and tenant:
 * **decode device-seconds** — each harvested decode round's ``device_wait``
   interval (the exact float the flight recorder accrues, when flight is on)
   apportioned across the round's live slots, weighted by how many tokens
-  each request actually emitted from that harvest;
+  each request actually emitted from that harvest (one a burst step, an
+  accepted prefix of a speculative round, up to a block a block round:
+  tokens are counted as emitted, never reckoned from the steps run);
 * **prefill device-seconds** — per prefill chunk, wall time around the
   chunk dispatch, attributed to the one request the chunk belongs to;
 * **KV block-seconds** — the integral of per-request *held* blocks over

@@ -31,6 +31,7 @@ non-zero exit, an error row, a missing answer or a timeout fails the script.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import re
@@ -464,17 +465,26 @@ def _kernels() -> None:
     ] + [
         ("bf16", dict(b=64, s=1, nh=32, hd=128, mb=256, n_kv=8, live=10, deepest=1280)),
         ("bf16", dict(b=64, s=1, nh=32, hd=64, mb=256, n_kv=8, live=30, deepest=640)),
+        # a block round's forward and a chunk under block_len 4 (GQA 32 / 4 of
+        # 128, 20 rows live to 1,536 positions): a row's four queries see up
+        # to the end of their block
+        ("bf16", dict(b=64, s=4, nh=32, hd=128, mb=256, n_kv=4, live=20, deepest=1536,
+                      block_len=4)),
+        ("bf16", dict(b=1, s=256, nh=32, hd=128, mb=256, n_kv=4, deepest=2048, block_len=4)),
     ]
     for store, shape in cases:
+        block_len = shape.pop("block_len", 1)
         q, pools, tables, idx, scales = _paged_case(rng, bs=16, store=store, **shape)
+        idx = idx // block_len * block_len  # a round, and a chunk, start on a block's edge
         outs = {
             impl: jax.jit(
                 lambda q, kp, vp, *sc, impl=impl: paged_attention(
-                    q, kp, vp, 1, tables, idx, *sc, impl=impl)
+                    q, kp, vp, 1, tables, idx, *sc, impl=impl, block_len=block_len)
             )(q, *pools, *scales)
             for impl in ("pallas", "gather")
         }
         live = f", {shape['live']} rows live" if "live" in shape else ""
+        live += f", block_len {block_len}" if block_len > 1 else ""
         ok &= _kernel_row(
             f"paged attention {list(q.shape)} block 16, {store} pool{live}, "
             "pallas vs gather", outs["pallas"], outs["gather"], PAGED_ATOL)
@@ -494,13 +504,28 @@ def _kernels() -> None:
         sys.exit("a kernel disagrees with its reference beyond the stated bound")
 
 
+def _experts() -> None:
+    """The expert product's rows of :func:`_kernels` alone."""
+    import numpy as np
+
+    from accelerate_tpu.mesh import configure_compile_cache
+
+    configure_compile_cache()
+    if not _expert_product_check(np.random.default_rng(0)):
+        sys.exit("the grouped expert product disagrees with its ragged_dot twin")
+
+
 def _expert_product_check(rng) -> bool:
-    """The dropless expert product (``ops/moe.py``) at LFM2-8B-A1B's widths
-    (32 experts of 2048 x 1792, top 4), two layers stacked and the second
-    addressed in place: the grouped Pallas product against its
-    ``ragged_dot`` twin at the chat cell's decode shape (64 slots, 24 live)
-    and at a 256-token chunk with a padded tail; dead rows give zeros and
-    add no pair."""
+    """The dropless expert product (``ops/moe.py``), two layers stacked and
+    the second addressed in place: the grouped Pallas product against its
+    ``ragged_dot`` twin; dead rows give zeros and add no pair. At
+    LFM2-8B-A1B's widths (32 experts of 2048 x 1792, top 4 of a sigmoid
+    router) at the chat cell's decode shape (64 slots, 24 live) and at a
+    256-token chunk with a padded tail; at SDAR-30B-A3B-Chat's (128 experts
+    of 2048 x 768, top 8 of a softmax over all of them) at a block round's
+    forward (64 slots x 4 positions, 10 and 24 slots live) and a whole
+    chunk, with the grouped product's milliseconds a call there (what
+    ``_GMM_TILING``, chosen for 32 experts of 1,792, reads at 128 of 768)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -508,29 +533,61 @@ def _expert_product_check(rng) -> bool:
     from accelerate_tpu.ops import moe
 
     ok = True
-    e, h, f, k = 32, 2048, 1792, 4
-    w_in = jnp.asarray(rng.normal(size=(2, e, h, 2 * f)) / np.sqrt(h), jnp.bfloat16)
-    w_out = jnp.asarray(rng.normal(size=(2, e, f, h)) / np.sqrt(f), jnp.bfloat16)
-    gate = jnp.asarray(rng.normal(size=(h, e)) / np.sqrt(h), jnp.bfloat16)
-    for rows, live_rows in ((64, 24), (256, 200)):
-        x = jnp.asarray(rng.normal(size=(rows, h)), jnp.bfloat16)
-        live = jnp.arange(rows) < live_rows
-        experts, weights = jax.jit(lambda x: moe.route(x, gate, jnp.zeros((e,)), k))(x)
-        outs = {
-            impl: jax.jit(lambda x, impl=impl: moe.expert_ffn(
-                x, experts, weights, w_in, w_out, live=live, layer=1, impl=impl))(x)
-            for impl in ("gmm", "ragged")
-        }
-        label = f"expert product [{rows},{h}] x 32 experts top 4, {live_rows} rows live, gmm vs ragged_dot"
-        ok &= _kernel_row(label, outs["gmm"][0], outs["ragged"][0], MOE_RTOL, relative=True)
-        same = (np.array_equal(outs["gmm"][1], outs["ragged"][1])
-                and int(outs["gmm"][1].sum()) == live_rows * k
-                and not np.asarray(outs["gmm"][0], np.float32)[live_rows:].any())
-        print("KERNEL " + json.dumps({"check": label + ": pairs counted, dead rows zero",
-                                      "err": 0.0 if same else 1.0, "bound": 0.0, "ok": same}),
-              flush=True)
-        ok &= same
+    keys = iter(jax.random.split(jax.random.PRNGKey(int(rng.integers(1 << 30))), 8))
+    for e, h, f, k, scoring, shapes in (
+        (32, 2048, 1792, 4, "sigmoid", ((64, 24), (256, 200))),
+        (128, 2048, 768, 8, "softmax", ((256, 40), (256, 96), (256, 256))),
+    ):
+        # made on the device and handed over as operands: matrices a program
+        # closes over are baked into it as constants (2.4 GB of them here)
+        w_in = (jax.random.normal(next(keys), (2, e, h, 2 * f), jnp.float32)
+                / np.sqrt(h)).astype(jnp.bfloat16)
+        w_out = (jax.random.normal(next(keys), (2, e, f, h), jnp.float32)
+                 / np.sqrt(f)).astype(jnp.bfloat16)
+        gate = jnp.asarray(rng.normal(size=(h, e)) / np.sqrt(h), jnp.bfloat16)
+        for rows, live_rows in shapes:
+            x = jnp.asarray(rng.normal(size=(rows, h)), jnp.bfloat16)
+            live = jnp.arange(rows) < live_rows
+            experts, weights = jax.jit(
+                lambda x: moe.route(x, gate, None, k, scoring=scoring))(x)
+            fns = {
+                impl: functools.partial(jax.jit(
+                    lambda x, w_in, w_out, impl=impl: moe.expert_ffn(
+                        x, experts, weights, w_in, w_out, live=live, layer=1, impl=impl)),
+                    w_in=w_in, w_out=w_out)
+                for impl in ("gmm", "ragged")
+            }
+            outs = {impl: fn(x) for impl, fn in fns.items()}
+            label = (f"expert product [{rows},{h}] x {e} experts of {f} top {k}, "
+                     f"{live_rows} rows live, gmm vs ragged_dot")
+            ok &= _kernel_row(label, outs["gmm"][0], outs["ragged"][0], MOE_RTOL, relative=True)
+            same = (np.array_equal(outs["gmm"][1], outs["ragged"][1])
+                    and int(outs["gmm"][1].sum()) == live_rows * k
+                    and not np.asarray(outs["gmm"][0], np.float32)[live_rows:].any())
+            row = {"check": label + ": pairs counted, dead rows zero",
+                   "err": 0.0 if same else 1.0, "bound": 0.0, "ok": same}
+            if e == 128:
+                touched = int((np.asarray(outs["gmm"][1]) > 0).sum())
+                row.update(experts_touched=touched, tiling=list(moe._GMM_TILING), **{
+                    impl + "_ms_a_call": round(_ms_a_call(fn, x), 4) for impl, fn in fns.items()})
+                row["gmm_touched_gb_s"] = round(
+                    touched * 3 * h * f * 2 / 1e6 / row["gmm_ms_a_call"], 1)
+            print("KERNEL " + json.dumps(row), flush=True)
+            ok &= same
     return ok
+
+
+def _ms_a_call(fn, x, calls: int = 30) -> float:
+    """Milliseconds a call of ``fn(x)``, dispatched back to back after one
+    warm call, the last one waited for."""
+    import jax
+
+    jax.block_until_ready(fn(x))
+    t0 = time.perf_counter()
+    for _ in range(calls):
+        out = fn(x)
+    jax.block_until_ready(out)
+    return 1e3 * (time.perf_counter() - t0) / calls
 
 
 def _hybrid_check(rng) -> bool:
@@ -899,7 +956,7 @@ def _engine_check() -> None:
 
 
 _CHILDREN = {
-    "_probe": _probe, "_kernels": _kernels, "_train": _train,
+    "_probe": _probe, "_kernels": _kernels, "_experts": _experts, "_train": _train,
     "_engine_check": _engine_check,
 }
 

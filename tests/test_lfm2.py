@@ -345,6 +345,11 @@ PARENT_PROGRAMS = {
               "prefill": "d4655201652ba387c4674794762519f6ba3e8cd8ba7bb5e36d8c9beeb20083cc"},
     "hybrid": {"decode": "65630f0e129fc5e7a5dc1faba0de6280350efc41bb2a1d29cc2283efae433fae",
                "prefill": "500256f0843884714b072f4827ca179432452cf4075174b910d169d88cb825e9"},
+    # taken on 2a01d1b (the parent of PR 38, which gave the paged kernel its
+    # ``block_len`` and the router its ``scoring``): at ``block_len`` 1 and
+    # ``scoring="sigmoid"`` this model's programs, counters and all, are that commit's
+    "lfm2": {"decode": "a66962a90db2b7a2b3b5764c3f9e93417827405a1aa95e95574284d18ad839c3",
+             "prefill": "19583c71ee12fee661ae51399c0d2d793d44faae5ee80458f0469fea67548f25"},
 }
 
 
@@ -352,10 +357,12 @@ _DIGEST_SCRIPT = """
 import hashlib, json, sys
 from accelerate_tpu.models import LlamaConfig, LlamaForCausalLM
 from accelerate_tpu.models.granite_hybrid import GraniteHybridConfig, GraniteHybridForCausalLM
+from accelerate_tpu.models.lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM
 from accelerate_tpu.serving import EngineConfig, InferenceEngine
 from accelerate_tpu.serving.sampling import SamplingParams
-model = (LlamaForCausalLM.from_config(LlamaConfig.tiny(), seed=0) if sys.argv[1] == "llama"
-         else GraniteHybridForCausalLM.from_config(GraniteHybridConfig.tiny(), seed=0))
+model = {"llama": lambda: LlamaForCausalLM.from_config(LlamaConfig.tiny(), seed=0),
+         "hybrid": lambda: GraniteHybridForCausalLM.from_config(GraniteHybridConfig.tiny(), seed=0),
+         "lfm2": lambda: Lfm2MoeForCausalLM.from_config(Lfm2MoeConfig.tiny(), seed=0)}[sys.argv[1]]()
 engine = InferenceEngine(model, EngineConfig(
     num_slots=4, max_seq_len=128, prefill_chunk=16, block_size=8, logprobs_topn=1, decode_burst=4))
 engine.add_request(list(range(3, 40)), 6, sampling=SamplingParams(logprobs=1))
@@ -368,7 +375,7 @@ print("DIGESTS " + json.dumps(out))
 """
 
 
-@pytest.mark.parametrize("family", ["llama", "hybrid"])
+@pytest.mark.parametrize("family", ["llama", "hybrid", "lfm2"])
 def test_a_model_without_counters_compiles_the_parents_programs(family):
     """In a process of its own: what a program lowers to also depends on
     process-wide settings other tests change (the attention context, the
@@ -380,7 +387,7 @@ def test_a_model_without_counters_compiles_the_parents_programs(family):
         timeout=600, env={**os.environ, "JAX_PLATFORMS": "cpu", "XLA_FLAGS": ""})
     assert done.returncode == 0, done.stderr[-2000:]
     got = json.loads(next(l for l in done.stdout.splitlines() if l.startswith("DIGESTS "))[8:])
-    assert got == {"counters": False, **PARENT_PROGRAMS[family]}
+    assert got == {"counters": family == "lfm2", **PARENT_PROGRAMS[family]}
 
 
 # -- the published file -> the model ------------------------------------------------
@@ -434,7 +441,7 @@ def test_the_published_config_builds_the_published_model(tmp_path):
     (dict(num_dense_layers=14), "num_dense_layers 14 of num_hidden_layers 13"),
     (dict(num_experts_per_tok=33), "num_experts_per_tok 33 of num_experts 32"),
     (dict(conv_bias=True), "conv_bias false"),
-    (dict(model_type="lfm3"), r"unsupported model_type 'lfm3' \(known: .*lfm2_moe\)"),
+    (dict(model_type="lfm3"), r"unsupported model_type 'lfm3' \(known: .*lfm2_moe.*\)"),
 ])
 def test_what_cannot_be_built_as_published_is_refused_not_guessed_at(tmp_path, changes, said):
     with pytest.raises(ValueError, match=said):

@@ -1,5 +1,6 @@
 """The routed-expert operators of ``ops/moe.py`` (ISSUE 36): the sigmoid
-router with a selection bias, and the dropless expert product, each held
+router with a selection bias, the softmax router over all the experts
+(ISSUE 38), and the dropless expert product, each held
 against a token-by-token loop in numpy that shares nothing with them.
 
 All on the CPU at small sizes. The expert product runs as
@@ -67,6 +68,48 @@ def test_the_sum_s_epsilon_and_the_scaling_factor(layer, norm, scale):
     if norm:
         # the 1e-6 is there: the weights sum to just under the factor
         assert (np.asarray(w).sum(1) < scale).all()
+
+
+@pytest.mark.parametrize("norm", [True, False])
+@pytest.mark.parametrize("k", [1, 2, 8])
+def test_the_softmax_router_scores_over_all_the_experts(layer, norm, k):
+    """``scoring="softmax"``: one softmax over all E experts, the top k of
+    it, the chosen weights renormalised (or not), no bias where the model
+    has none; against numpy."""
+    x, gate = layer["x"], layer["gate"]
+    logits = np.asarray(x, np.float64) @ np.asarray(gate, np.float64)
+    scores = np.exp(logits - logits.max(1, keepdims=True))
+    scores /= scores.sum(1, keepdims=True)
+    chosen, w = moe.route(x, gate, None, k, norm, scoring="softmax")
+    np.testing.assert_array_equal(np.asarray(chosen), np.argsort(-scores, axis=1)[:, :k])
+    picked = np.take_along_axis(scores, np.asarray(chosen), axis=1)
+    want = picked / (picked.sum(1, keepdims=True) + 1e-6) if norm else picked
+    np.testing.assert_allclose(w, want, rtol=2e-5)
+    assert w.dtype == jnp.float32 and chosen.dtype == jnp.int32
+    if k == E and not norm:
+        np.testing.assert_allclose(np.asarray(w).sum(1), 1.0, rtol=1e-5)
+
+
+def test_the_two_scorings_differ_and_an_unknown_one_is_refused(layer):
+    x, gate = layer["x"], layer["gate"]
+    _, w_sig = moe.route(x, gate, None, K, False)
+    _, w_soft = moe.route(x, gate, None, K, False, scoring="softmax")
+    assert float(jnp.abs(w_sig - w_soft).max()) > 1e-2
+    # without a bias the sigmoid router is what a zero bias gives
+    for got, want in zip(moe.route(x, gate, None, K), moe.route(x, gate, jnp.zeros(E), K)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    with pytest.raises(ValueError, match="unknown router scoring 'tanh'"):
+        moe.route(x, gate, None, K, scoring="tanh")
+
+
+def test_the_softmax_router_runs_in_float32_whatever_it_is_given(layer):
+    x, gate = layer["x"].astype(jnp.bfloat16), layer["gate"].astype(jnp.bfloat16)
+    chosen, w = moe.route(x, gate, None, K, scoring="softmax")
+    want, w_want = moe.route(x.astype(jnp.float32), gate.astype(jnp.float32), None, K,
+                             scoring="softmax")
+    assert w.dtype == jnp.float32
+    np.testing.assert_array_equal(chosen, want)
+    np.testing.assert_allclose(w, w_want, rtol=1e-6)
 
 
 def test_the_router_runs_in_float32_whatever_it_is_given(layer):

@@ -21,7 +21,7 @@ from accelerate_tpu.ops.fp8 import (
     kv_storage_dtype,
     quantize_kv_rows,
 )
-from accelerate_tpu.ops.layers import cached_attention, write_paged_kv
+from accelerate_tpu.ops.layers import cached_attention, last_visible, write_paged_kv
 from accelerate_tpu.ops.paged_attention import paged_attention
 
 #: ops-level |fused_quantized - f32_reference| ceilings on attention
@@ -219,6 +219,109 @@ def test_pallas_row_walk_stops_where_the_row_does(s, contexts, store):
     assert (bt == 1).any(), "no entry was poisoned"
     assert np.isfinite(outs[1]).all()
     np.testing.assert_array_equal(outs[1], outs[0])
+
+
+# -- block_len: causal from block to block, bidirectional inside a block ---------
+
+
+@pytest.mark.parametrize("block_len", [1, 2, 3, 4, 8])
+def test_last_visible_is_the_end_of_the_querys_own_block(block_len):
+    pos = np.arange(40, dtype=np.int32)
+    want = (pos // block_len + 1) * block_len - 1
+    np.testing.assert_array_equal(np.asarray(last_visible(jnp.asarray(pos), block_len)), want)
+    np.testing.assert_array_equal(last_visible(pos, block_len), want)
+    if block_len == 1:
+        assert last_visible(pos, 1) is pos  # the causal rule traces nothing
+
+
+def _hand_attention(q, pools, layer, bt, idx, block_len):
+    """Row by row, query by query, in numpy float64: the keys a query may
+    see are those at positions ``< (pos // block_len + 1) * block_len``."""
+    q = np.asarray(q, np.float64)
+    kp, vp = (np.asarray(p, np.float64)[layer] for p in pools)
+    b, s, nh, hd = q.shape
+    n_kv = kp.shape[-1] // hd
+    out = np.zeros_like(q)
+    for r in range(b):
+        span_k = kp[bt[r]].reshape(-1, n_kv, hd)
+        span_v = vp[bt[r]].reshape(-1, n_kv, hd)
+        for j in range(s):
+            pos = int(idx[r]) + j
+            end = (pos // block_len + 1) * block_len
+            for h in range(nh):
+                n = h // (nh // n_kv)
+                sc = span_k[:end, n] @ q[r, j, h] / np.sqrt(hd)
+                p = np.exp(sc - sc.max())
+                out[r, j, h] = (p / p.sum()) @ span_v[:end, n]
+    return out
+
+
+def _block_shapes():
+    """A round's rows (s = block_len queries from a block's first position,
+    ragged contexts, a free slot among them) and a chunk of 32 that starts
+    and ends on a block's edge; at block_len 3 blocks straddle pages."""
+    for block_len in (4, 8):
+        yield pytest.param(block_len, block_len, (block_len, 16, 16 + block_len, 48, 64, None),
+                           id=f"round-B{block_len}")
+        yield pytest.param(block_len, 32, (48,), id=f"chunk-B{block_len}")
+    yield pytest.param(3, 3, (3, 15, 18, 48, 63, None), id="round-B3")
+    yield pytest.param(4, 1, (1, 15, 16, 17, 61, None), id="single-query-B4")
+
+
+@pytest.mark.parametrize("block_len, s, contexts", _block_shapes())
+def test_block_len_all_three_routes_against_a_hand_mask(block_len, s, contexts):
+    """Query ``j`` of a row attends every position before the end of its
+    own block, on the gather, lax and Pallas (interpret) routes alike, and
+    as ``cached_attention`` does over the gathered span."""
+    rng = np.random.default_rng(38)
+    q, pools, bt, idx, _ = _ragged_case(rng, contexts, s, 64, None, tail=0)
+    want = _hand_attention(q, pools, 1, bt, idx, block_len)
+    for impl in ("gather", "lax", "pallas"):
+        out = paged_attention(q, *pools, 1, bt, idx, impl=impl, interpret=True,
+                              block_len=block_len)
+        np.testing.assert_allclose(np.asarray(out), want, rtol=2e-5, atol=2e-5, err_msg=impl)
+    causal = paged_attention(q, *pools, 1, bt, idx, impl="lax")
+    if s > 1:  # a row of several queries sees more of itself than causally
+        assert np.abs(np.asarray(causal) - want).max() > 1e-3
+
+
+@pytest.mark.parametrize("impl", ["gather", "lax", "pallas"])
+def test_block_len_1_is_the_causal_rule_bit_for_bit(impl):
+    rng = np.random.default_rng(5)
+    q, pools, bt, idx, _ = _ragged_case(rng, (17, 33, 64, None), 4, 64, None, tail=0)
+    plain = paged_attention(q, *pools, 2, bt, idx, impl=impl, interpret=True)
+    at_one = paged_attention(q, *pools, 2, bt, idx, impl=impl, interpret=True, block_len=1)
+    np.testing.assert_array_equal(np.asarray(at_one), np.asarray(plain))
+
+
+def test_block_len_the_row_walk_reaches_the_blocks_end_and_no_further():
+    """Poisoned tail at block_len 4: the walk's trip count is read from the
+    last query's last VISIBLE position, so it visits the page that holds
+    the end of the last block and none behind it."""
+    outs = []
+    for tail in (0, 1):
+        q, pools, bt, idx, _ = _ragged_case(
+            np.random.default_rng(11), (4, 16, 20, 48, None), 4, 64, None, tail)
+        outs.append(np.asarray(paged_attention(
+            q, *pools, 0, bt, idx, impl="pallas", interpret=True, block_len=4)))
+    assert (bt == 1).any() and np.isfinite(outs[1]).all()
+    np.testing.assert_array_equal(outs[1], outs[0])
+
+
+def test_cached_attention_takes_the_same_block_len():
+    rng = np.random.default_rng(2)
+    q = jnp.asarray(rng.normal(size=(2, 4, 4, 8)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(2, 24, 2, 8)), jnp.float32) for _ in range(2))
+    idx = jnp.asarray([8, 12], jnp.int32)
+    got = np.asarray(cached_attention(q, k, v, idx, block_len=4))
+    for r in range(2):
+        # every query of the block sees up to the block's end: the same keys
+        end = int(idx[r]) + 4
+        for h in range(4):
+            sc = np.asarray(q[r, :, h]) @ np.asarray(k[r, :end, h // 2]).T / np.sqrt(8.0)
+            p = np.exp(sc - sc.max(-1, keepdims=True))
+            want = (p / p.sum(-1, keepdims=True)) @ np.asarray(v[r, :end, h // 2])
+            np.testing.assert_allclose(got[r, :, h], want, rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("name", [None, "int8"])

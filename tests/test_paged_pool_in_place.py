@@ -399,3 +399,52 @@ def test_v5e_compiled_routed_step_addresses_the_expert_stacks_in_place(one_chip,
     assert found["moved"] == [], found["moved"]
     # the temporaries are far smaller than one expert's matrices
     assert compiled.memory_analysis().temp_size_in_bytes < 2048 * 3584 * 2
+
+
+@pytest.mark.parametrize("program", ["round", "prefill"])
+def test_v5e_compiled_block_step_walks_to_the_blocks_end(one_chip, monkeypatch, program):
+    """SDAR-30B-A3B-Chat's widths (128 experts of 2048 x 768, top 8 of a
+    softmax; 32 / 4 heads of 128), two layers, 64 slots, the vocabulary cut
+    to 8192: one forward of a block round (``[64, 4]`` rows, ``block_len``
+    4) and a prefill chunk compile for the v5e with the paged kernel (its
+    mask and trip count under ``block_len``) and the grouped product at
+    ``E = 128``, ``f = 768`` in the program, and no instruction produces
+    anything of the size of an expert stack or of one layer's slab of it."""
+    import sys
+
+    from accelerate_tpu.models import sdar_moe
+
+    monkeypatch.setattr(
+        sys.modules["accelerate_tpu.ops.paged_attention"],
+        "default_paged_attention_impl", lambda: "pallas",
+    )
+    monkeypatch.setattr(sys.modules["accelerate_tpu.ops.moe"], "default_moe_impl", lambda: "gmm")
+    slots, blocks, bs, table, chunk = 64, 3000, 16, 256, 256
+    c = sdar_moe.SdarMoeConfig(vocab_size=8192, num_hidden_layers=2, mask_token_id=8191)
+    shaped = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: shaped(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: sdar_moe.init_sdar_params(jax.random.PRNGKey(0), c)),
+    )
+    pool = shaped((2, blocks, bs, 4 * 128), jnp.bfloat16)
+
+    def step(params, cache, tables, pos, toks, mask):
+        out = sdar_moe.sdar_apply(
+            c, params, toks, paged_kv=cache, block_tables=tables,
+            cache_positions=pos, paged_write_mask=mask,
+        )
+        return (out["paged_kv"], jnp.argmax(out["logits"], -1).astype(jnp.int32),
+                out["step_counters"])
+
+    b, s = (slots, c.block_length) if program == "round" else (1, chunk)
+    operands = [params, {"k": pool, "v": pool}, shaped((b, table), jnp.int32),
+                shaped((b,), jnp.int32), shaped((b, s), jnp.int32), shaped((b, s), jnp.bool_)]
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(step, donate_argnums=(1,)).lower(*operands).compile()
+    text = compiled.as_text()
+    # two layers x (the paged kernel + two grouped products)
+    assert text.count('custom_call_target="tpu_custom_call"') == 6
+    w_in, w_out = 2 * 128 * 2048 * 1536, 2 * 128 * 768 * 2048
+    found = buffers_moved(text, [w_in, w_in // 2, w_out, w_out // 2, 2 * blocks * bs * 512,
+                                 blocks * bs * 512])
+    assert found["moved"] == [], found["moved"]
