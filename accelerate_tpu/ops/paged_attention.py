@@ -28,17 +28,30 @@ scores/softmax like every attention in this codebase.
 
 **The Pallas kernel walks a row's own blocks.** Its grid runs over the rows
 of the call; inside a row's step a loop whose trip count is data —
-``(idx[row] + s - 1) // block_size + 1``, read from the prefetched ``idx`` —
-visits the table entries that hold a block some query of the row attends,
-and no others. So a call costs what is live: a free slot one entry, a short
-row its length, whatever ``max_blocks`` is (a grid over ``(row, entry)``
-cost every layer of every decode step 64 x 256 visits at 5 % of them live).
-The pools stay in HBM as they are stored; the kernel copies block
-``(layer, block_tables[row, j])`` into VMEM itself, a few entries ahead of
-the one its online softmax consumes (``_IN_FLIGHT``). The trip count is an
-operand, not a shape: one executable serves every occupancy. ``serving/engine.py``
-counts the same entries on the host (``stats()``
-``paged_entries_walked_total`` against ``paged_entries_table_total``).
+``(idx[row] + s - 1) // block_size + 1`` entries, read from the prefetched
+``idx`` — visits the table entries that hold a block some query of the row
+attends, and no others. So a call costs what is live: a free slot one entry,
+a short row its length, whatever ``max_blocks`` is (a grid over ``(row,
+entry)`` cost every layer of every decode step 64 x 256 visits at 5 % of
+them live). The pools stay in HBM as they are stored; the kernel copies block
+``(layer, block_tables[row, j])`` into VMEM itself, ahead of the softmax
+step that consumes it (``_IN_FLIGHT``). The trip count is an operand, not a
+shape: one executable serves every occupancy.
+
+**A softmax step of the kernel is a tile against a query group.** The loop
+takes ``_TILE`` consecutive table entries a step (``_TILE x block_size`` key
+positions, each live entry copied into its ``block_size`` rows of one VMEM
+buffer and none past the row's last), against ALL the query heads of a kv
+head at once: the query is handed in grouped, a kv head's ``rep`` heads
+stacked along the rows. One entry against one query head at a time made a
+score tile 16 of a vreg's 1,024 elements and paid the fixed cost of two
+products, a max, an exp and a sum 32 times an entry: 1-2.4 us for the
+0.04-0.08 us an entry's bytes take. Rows of a tile that no copy wrote hold
+whatever the scratch held, so V's rows are selected by key position
+(``0 x NaN`` is NaN). ``serving/engine.py`` counts the same entries and
+tiles on the host (``stats()`` ``paged_entries_walked_total`` against
+``paged_entries_table_total``; entries over ``paged_tiles_walked_total x
+_TILE`` is how full the tiles are).
 """
 
 from __future__ import annotations
@@ -217,32 +230,67 @@ def _paged_attention_gather(q, k_pool, v_pool, layer, block_tables, idx, k_scale
 # Pallas TPU kernel: a grid over rows, each walking its own table entries
 # ---------------------------------------------------------------------------
 
-#: pool blocks of a row on their way from HBM while the kernel consumes one
-#: (so ``_IN_FLIGHT + 1`` VMEM buffers a pool). Chosen on the v5e (PERF.md
-#: section 6, PR 29); a constant of the kernel, not an option of its callers
+#: table entries a softmax step takes at once: ``_TILE x block_size`` key
+#: positions (128 at blocks of 16: a vreg's lanes, the MXU's columns), clamped
+#: to the table's width. And the tiles of a row on their way from HBM while the
+#: kernel consumes one (so ``_IN_FLIGHT + 1`` VMEM buffers a pool). Both chosen
+#: on the v5e (PERF.md section 6, PR 39); constants of the kernel, not options
+#: of its callers
+_TILE = 8
 _IN_FLIGHT = 1
+
+#: stacked query rows a grid step takes: a kv head's whole group where it is
+#: smaller (decode, a block round, a verify round), one head of a 256-token
+#: chunk where it is not - a second grid axis walks a chunk's blocks of rows,
+#: each over the row's tiles again, so the kernel's VMEM (query, output, the
+#: softmax state: lane-padded, 20 MB for a whole chunk at once) does not grow
+#: with the chunk; a score tile of 256 rows is 32 vregs
+_ROW_BLOCK = 256
+
+
+def tiles_walked(entries, table_width: int):
+    """Softmax steps the kernel takes for rows that walk ``entries`` table
+    entries each (the host's count, ``serving/engine.py``)."""
+    return -(-entries // min(_TILE, table_width))
 
 
 def _pallas_kernel(bt_ref, idx_ref, layer_ref, q_ref, *rest,
-                   bs, n_kv, rep, hd, quantized, block_len=1):
-    """Grid ``(b,)``: step ``i`` is row ``i``, and a loop inside it walks
-    the row's own table entries ``0 .. n_i - 1``, ``n_i = (idx[i] + s - 1)
+                   bs, tile, s, quantized, block_len=1):
+    """Grid ``(b, row blocks)``: step ``(i, r)`` is block ``r`` of row
+    ``i``'s stacked queries (one block but for a chunk), and a loop inside
+    it walks the row's own table entries ``0 .. n_i - 1``, ``n_i = (idx[i] + s - 1)
     // bs + 1`` (at most the table's width; with ``block_len`` above 1 the
-    last query's last visible position takes the place of ``idx[i] + s - 1``) — a trip count read from the
-    prefetched ``idx``, so a dead slot (``idx`` 0) costs one entry and a
-    short row costs its length, whatever the table could hold. The pools
-    (and a quantized pool's scales) stay in HBM, whole; the kernel copies
-    block ``(layer_ref[0], bt_ref[i, j])`` into one of its VMEM buffers
-    itself, ``_IN_FLIGHT`` entries ahead of the one it consumes. The online
-    softmax state lives in VMEM scratch across the loop; entries are
-    consumed in table order, one block a softmax step.
+    last query's last visible position takes the place of ``idx[i] + s - 1``),
+    ``tile`` entries a step: ``ceil(n_i / tile)`` steps, a trip count read
+    from the prefetched ``idx``, so a dead slot (``idx`` 0) costs one step of
+    one entry and a short row costs its length, whatever the table could
+    hold. The pools (and a quantized pool's scales) stay in HBM, whole; the
+    kernel copies block ``(layer_ref[0], bt_ref[i, j])`` into rows ``[e * bs,
+    (e + 1) * bs)`` of one of its VMEM buffers itself, for the LIVE entries
+    ``j = t * tile + e`` of a tile and no others, ``_IN_FLIGHT`` tiles ahead
+    of the one it consumes.
 
-    Every operand is 2-D inside the kernel: heads are folded into the lane
-    dimension (``[.., n*hd]`` — how the pool is stored; a reshape of the
-    small query outside), and head ``h`` is the static lane
-    slice ``[h*hd, (h+1)*hd)`` — Mosaic tiles the two minor dimensions, so
-    a head axis kept second-minor (12 rows padded to 16) and a 4-D
-    batched-in-the-middle einsum cost a prefill chunk 119 MB of VMEM."""
+    **A softmax step is a tile against a kv head's whole query group.** The
+    query comes in grouped (``[1, n_kv, rows, hd]``: the ``rep`` heads that
+    share kv head ``n`` stacked along the rows, head-major, padded to whole
+    sublanes), so a step is ``[rows, hd] x [tile * bs, hd]^T``, one max / exp
+    / sum over a score tile whose lanes are full, and ``[rows, tile * bs] x
+    [tile * bs, hd]`` a kv head - not one ``[s, hd] x [bs, hd]^T`` a query
+    head and entry, whose fixed cost was the kernel's time. The running max,
+    sum and accumulator live in VMEM scratch across the loop, a kv head's
+    group each; more than ``_ROW_BLOCK`` stacked rows (a chunk) are taken a
+    block of rows a grid step.
+
+    **V's rows are selected by key position.** The rows of a buffer that no
+    copy of this tile wrote hold what the scratch held: an earlier tile's or
+    an earlier row's blocks, or nothing at all. Their ``p`` is 0, but ``0 x
+    NaN`` is NaN in ``p @ v``, so V's rows past the row's last visible
+    position are replaced by zeros (K's are harmless: their scores are
+    replaced before the max). Nothing rests on which grid step ran first.
+
+    Every operand is 2-D where it is computed on: kv heads are folded into
+    the lane dimension (``[.., n*hd]`` - how the pool is stored) and kv head
+    ``n`` is the static lane slice ``[n*hd, (n+1)*hd)``, cut once a tile."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -252,80 +300,79 @@ def _pallas_kernel(bt_ref, idx_ref, layer_ref, q_ref, *rest,
     k_buf, v_buf, *scale_bufs = bufs
     i = pl.program_id(0)
     mb = bt_ref.shape[1]
-    s = q_ref.shape[1]
+    _, n_kv, rows, hd = q_ref.shape
     depth = _IN_FLIGHT + 1
+    span = tile * bs
     # the pools at this call's layer; the scales come in as the one layer's
     # already (``_scale_blocks``)
     layers = [p.at[layer_ref[0]] for p in pools[:2]] + [p.at[0] for p in pools[2:]]
 
-    def copies(j):
-        """The DMAs of the row's ``j``-th entry, one a pool operand."""
-        slot, blk = j % depth, bt_ref[i, j]
-        return [
-            pltpu.make_async_copy(pool.at[blk], buf.at[slot], sems.at[a, slot])
-            for a, (pool, buf) in enumerate(zip(layers, bufs))
-        ]
-
     first = idx_ref[i]
     # the row's own entries: up to the one that holds its last query's last
     # visible position
-    live = jnp.minimum(last_visible(first + s - 1, block_len) // bs + 1, mb)
-    for j in range(min(_IN_FLIGHT, mb)):
-        @pl.when(j < live)
-        def _first():
-            for dma in copies(j):
-                dma.start()
+    last = last_visible(first + s - 1, block_len)
+    live = jnp.minimum(last // bs + 1, mb)
+    row0 = pl.program_id(1) * rows
+
+    def for_live_entries(t, act):
+        """``act`` on the DMAs of tile ``t``'s live entries, one a pool
+        operand and entry; nothing for an entry past the row's last."""
+        slot = t % depth
+        for e in range(tile):
+            @pl.when(t * tile + e < live)
+            def _entry():
+                blk = bt_ref[i, t * tile + e]
+                for a, (pool, buf) in enumerate(zip(layers, bufs)):
+                    act(pltpu.make_async_copy(
+                        pool.at[blk], buf.at[slot, pl.ds(e * bs, bs)], sems.at[a, slot]))
+
+    for t in range(_IN_FLIGHT):
+        for_live_entries(t, lambda dma: dma.start())
 
     m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def _step(j, carry):
-        @pl.when(j + _IN_FLIGHT < live)
-        def _ahead():
-            for dma in copies(j + _IN_FLIGHT):
-                dma.start()
-
-        for dma in copies(j):
-            dma.wait()
-        slot = j % depth
-        q_pos = first + jax.lax.broadcasted_iota(jnp.int32, (s, bs), 0)
-        k_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (s, bs), 1)
-        valid = k_pos <= last_visible(q_pos, block_len)
+    def _step(t, carry):
+        for_live_entries(t + _IN_FLIGHT, lambda dma: dma.start())
+        for_live_entries(t, lambda dma: dma.wait())
+        slot = t % depth
+        k_pos = t * span + jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
+        # stacked row g of the group is query g % s of its head
+        g = row0 + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+        valid = k_pos <= last_visible(first + jax.lax.rem(g, s), block_len)  # [rows, span]
+        seen = t * span + jax.lax.broadcasted_iota(jnp.int32, (span, 1), 0) <= last
         for n in range(n_kv):
-            kv_lanes = slice(n * hd, (n + 1) * hd)
-            kb = k_buf[slot, :, kv_lanes].astype(jnp.float32)   # [bs, hd]
-            vb = v_buf[slot, :, kv_lanes].astype(jnp.float32)
+            lanes = slice(n * hd, (n + 1) * hd)
+            kb = k_buf[slot, :, lanes].astype(jnp.float32)      # [span, hd]
+            vb = v_buf[slot, :, lanes].astype(jnp.float32)
             if quantized:
                 kb = kb * scale_bufs[0][slot, :, n:n + 1]
                 vb = vb * scale_bufs[1][slot, :, n:n + 1]
-            for h in range(n * rep, (n + 1) * rep):
-                lanes = slice(h * hd, (h + 1) * hd)
-                qh = q_ref[0, :, lanes].astype(jnp.float32) / np.sqrt(float(hd))
-                sc = jax.lax.dot_general(            # [s, bs], contract hd
-                    qh, kb, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                sc = jnp.where(valid, sc, _NEG_INF)
-                m_prev, l_prev = m_ref[h], l_ref[h]  # [s, 1]
-                m_new = jnp.maximum(m_prev, sc.max(axis=-1, keepdims=True))
-                # while every position so far is masked, m_new == _NEG_INF
-                # and sc - m_new == 0 — the mask keeps those lanes at p = 0
-                p = jnp.where(valid, jnp.exp(sc - m_new), 0.0)
-                alpha = jnp.exp(m_prev - m_new)
-                m_ref[h] = m_new
-                l_ref[h] = l_prev * alpha + p.sum(axis=-1, keepdims=True)
-                acc_ref[:, lanes] = acc_ref[:, lanes] * alpha + jnp.dot(
-                    p, vb, preferred_element_type=jnp.float32
-                )
+            vb = jnp.where(seen, vb, 0.0)
+            qg = q_ref[0, n].astype(jnp.float32) / np.sqrt(float(hd))
+            sc = jax.lax.dot_general(                            # contract hd
+                qg, kb, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            sc = jnp.where(valid, sc, _NEG_INF)
+            m_prev, l_prev = m_ref[n], l_ref[n]                  # [rows, 1]
+            m_new = jnp.maximum(m_prev, sc.max(axis=-1, keepdims=True))
+            # while every position so far is masked, m_new == _NEG_INF
+            # and sc - m_new == 0 - the mask keeps those lanes at p = 0
+            p = jnp.where(valid, jnp.exp(sc - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            m_ref[n] = m_new
+            l_ref[n] = l_prev * alpha + p.sum(axis=-1, keepdims=True)
+            acc_ref[n] = acc_ref[n] * alpha + jnp.dot(
+                p, vb, preferred_element_type=jnp.float32
+            )
         return carry
 
-    jax.lax.fori_loop(0, live, _step, 0)
+    jax.lax.fori_loop(0, (live + tile - 1) // tile, _step, 0)
 
-    for h in range(n_kv * rep):
-        lanes = slice(h * hd, (h + 1) * hd)
-        out = acc_ref[:, lanes] / jnp.maximum(l_ref[h], 1e-30)
-        out_ref[0, :, lanes] = out.astype(out_ref.dtype)
+    out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+    out_ref[0] = out.astype(out_ref.dtype)
 
 
 def _scale_blocks(scale, layer):
@@ -347,10 +394,19 @@ def _paged_attention_pallas(q, k_pool, v_pool, layer, block_tables, idx,
     b, s, nh, hd = q.shape
     bs, width = k_pool.shape[2], k_pool.shape[3]
     n_kv = width // hd
+    rep = nh // n_kv
     quantized = k_scale is not None
+    tile = min(_TILE, block_tables.shape[1])
+    # a kv head's query group stacked along the rows, head-major, padded to whole
+    # sublanes, in blocks of at most _ROW_BLOCK rows: a reshape of the small
+    # query outside the kernel
+    block_rows = min(-(-rep * s // 8) * 8, _ROW_BLOCK)
+    rows = -(-rep * s // block_rows) * block_rows
+    grouped = q.reshape(b, s, n_kv, rep, hd).transpose(0, 2, 3, 1, 4).reshape(b, n_kv, rep * s, hd)
+    grouped = jnp.pad(grouped, [(0, 0), (0, 0), (0, rows - rep * s), (0, 0)])
 
-    def row(i, bt, ix, ly):
-        return (i, 0, 0)
+    def row(i, r, bt, ix, ly):
+        return (i, 0, r, 0)
 
     # the pool operands are the stored pools, whole and left in HBM: the
     # kernel addresses them at (layer, block) itself, so no slab exists
@@ -360,36 +416,37 @@ def _paged_attention_pallas(q, k_pool, v_pool, layer, block_tables, idx,
     buffers = _IN_FLIGHT + 1
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # block_tables + idx + layer steer the walk
-        grid=(b,),
-        in_specs=[pl.BlockSpec((1, s, nh * hd), row)]
+        grid=(b, rows // block_rows),
+        in_specs=[pl.BlockSpec((1, n_kv, block_rows, hd), row)]
         + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
-        out_specs=pl.BlockSpec((1, s, nh * hd), row),
-        scratch_shapes=[pltpu.VMEM((buffers, *p.shape[2:]), p.dtype) for p in pools]
-        + [
+        out_specs=pl.BlockSpec((1, n_kv, block_rows, hd), row),
+        scratch_shapes=[
+            pltpu.VMEM((buffers, tile * bs, p.shape[3]), p.dtype) for p in pools
+        ] + [
             pltpu.SemaphoreType.DMA((len(pools), buffers)),
-            pltpu.VMEM((nh, s, 1), jnp.float32),
-            pltpu.VMEM((nh, s, 1), jnp.float32),
-            pltpu.VMEM((s, nh * hd), jnp.float32),
+            pltpu.VMEM((n_kv, block_rows, 1), jnp.float32),
+            pltpu.VMEM((n_kv, block_rows, 1), jnp.float32),
+            pltpu.VMEM((n_kv, block_rows, hd), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(
-            _pallas_kernel, bs=bs, n_kv=n_kv, rep=nh // n_kv, hd=hd,
-            quantized=quantized, block_len=block_len,
+            _pallas_kernel, bs=bs, tile=tile, s=s, quantized=quantized, block_len=block_len,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, s, nh * hd), q.dtype),
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        out_shape=jax.ShapeDtypeStruct((b, n_kv, rows, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
         name="paged_attention",
     )(
         jnp.asarray(block_tables, jnp.int32),
         jnp.asarray(idx, jnp.int32).reshape(b),
         jnp.asarray(layer, jnp.int32).reshape(1),
-        q.reshape(b, s, nh * hd),
+        grouped,
         *pools,
     )
-    return out.reshape(b, s, nh, hd)
+    out = out[:, :, :rep * s].reshape(b, n_kv, rep, s, hd)
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, s, nh, hd)
 
 
 def _paged_attention_pallas_sharded(q, k_pool, v_pool, layer, block_tables, idx,

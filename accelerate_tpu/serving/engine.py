@@ -79,7 +79,7 @@ from ..diagnostics.tracing import (
 from ..metrics.ingest import observe_flight
 from ..metrics.registry import get_active_registry
 from ..models.cache import cache_spec_of
-from ..ops.paged_attention import default_paged_attention_impl
+from ..ops.paged_attention import default_paged_attention_impl, tiles_walked
 from ..telemetry import get_active_recorder
 from .blocks import NULL_BLOCK, BlockAllocator, blocks_needed
 from .flight import ITERATION_PHASES, FlightRecorder, set_active_flight_recorder
@@ -727,9 +727,12 @@ class InferenceEngine:
         # what the paged kernel's walk follows (monotone totals, counted at
         # dispatch from the rows' lengths): the table entries that hold a
         # block some query of the call attends, and the entries the tables
-        # have — their ratio is the share of a (row, entry) grid that is live
+        # have — their ratio is the share of a (row, entry) grid that is live;
+        # and the softmax steps the kernel takes over them, a tile of entries
+        # each: entries over (tiles x the tile's width) is how full a tile is
         self._paged_entries_walked = 0
         self._paged_entries_table = 0
+        self._paged_tiles_walked = 0
         # what the slot-state kernels' work follows (monotone totals, counted
         # at the decode dispatch from the host's mask): the slot states a
         # dispatch has to step - live lanes x steps x state layers - and
@@ -1665,7 +1668,7 @@ class InferenceEngine:
         self._first_tokens_total = 0
         self._ttft_sum_s = self._ttft_queue_sum_s = self._ttft_own_prefill_sum_s = 0.0
         self._ttft_prefill_iterations_sum = 0
-        self._paged_entries_walked = self._paged_entries_table = 0
+        self._paged_entries_walked = self._paged_entries_table = self._paged_tiles_walked = 0
         self._state_slots_live = self._state_slots_held = 0
         self._pick_dispatches = self._pick_draw_dispatches = 0
         self._block_totals = dict.fromkeys(_BLOCK_TOTALS, 0)
@@ -1854,6 +1857,10 @@ class InferenceEngine:
             # the entries its tables hold, both x the layers that were run
             "paged_entries_walked_total": self._paged_entries_walked,
             "paged_entries_table_total": self._paged_entries_table,
+            # the kernel's softmax steps over the walked entries, a tile of
+            # ops/paged_attention.py's _TILE entries each (a row's last one
+            # part full): walked / (tiles x _TILE) is the tiles' fill
+            "paged_tiles_walked_total": self._paged_tiles_walked,
             # the slot state's work: states a decode dispatch had to step
             # (live lanes x burst x state layers: what ops/ssm.py's kernel
             # walks) against those the cache holds for every slot
@@ -2457,11 +2464,13 @@ class InferenceEngine:
         the entries up to its last query's block — the trip count
         ``ops/paged_attention.py`` reads from the same positions (a block
         model's chunks and rounds end on a block's end, where the last
-        query's last visible position is the last query)."""
+        query's last visible position is the last query), a tile of them a
+        softmax step (``tiles_walked``)."""
         last = np.asarray(first, np.int64) + queries - 1
         walked = np.minimum(last // self.config.block_size + 1, self._mb)
         self._paged_entries_walked += int(walked.sum()) * layers * calls
         self._paged_entries_table += walked.size * self._mb * layers * calls
+        self._paged_tiles_walked += int(tiles_walked(walked, self._mb).sum()) * layers * calls
 
     def _count_state_slots(self, active) -> None:
         """Book one decode dispatch's slot-state steps from the mask the

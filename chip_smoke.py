@@ -58,10 +58,10 @@ SERVE_ARGS = [
 #: chunk, so chunked prefill, slot reuse and a full decode batch all happen
 SERVE_REQUESTS, PROMPT_LEN, NEW_TOKENS = 40, (32, 160), (16, 64)
 
-#: the parent commit's kernel file, where a builder unpacked it for a timing
-#: side by side (``git archive <parent> | tar -x -C _chip_tmp/parent``; the
-#: directory is in ``.gitignore``): left out of the rows where it is not there
-PARENT_SSM = os.path.join(ROOT, "_chip_tmp", "parent", "accelerate_tpu", "ops", "ssm.py")
+#: the parent commit's kernel files, where a builder unpacked them for a
+#: timing side by side (``git archive <parent> | tar -x -C _chip_tmp/parent``;
+#: the directory is in ``.gitignore``): left out of the rows where they are not
+PARENT_OPS = os.path.join(ROOT, "_chip_tmp", "parent", "accelerate_tpu", "ops")
 
 #: |kernel - reference| ceilings on the chip, for unit-variance inputs.
 #: Paged attention: same stored pool bytes on both sides, outputs rounded to
@@ -406,6 +406,21 @@ def _kernel_row(check: str, got, want, bound: float, relative: bool = False) -> 
     return ok
 
 
+def _parent_ops(name: str):
+    """``ops/<name>.py`` of the parent commit as a module of this tree's
+    ``accelerate_tpu.ops`` (its relative imports find this tree's files), or
+    ``None`` where no builder unpacked the parent."""
+    import importlib.util
+
+    path = os.path.join(PARENT_OPS, name + ".py")
+    if not os.path.exists(path):
+        return None
+    spec = importlib.util.spec_from_file_location("accelerate_tpu.ops._parent_" + name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
 def _paged_case(rng, b, s, nh, hd, bs, mb, store, n_kv=None, live=None, deepest=None):
     """Two-layer stacked pools whose layer 1 is written through real block
     tables (quantize-on-scatter for int8/fp8), rows at different depths,
@@ -456,9 +471,10 @@ def _kernels() -> None:
     ok = True
 
     # paged attention at the flagship's decode and prefill-chunk shapes, and
-    # at the benchmark's two chat cells' decode shapes as their traffic fills
-    # them: 64 slots x 256 table entries, GQA 32 / 8, 10 rows live to 1,280
-    # positions at head 128 (Mistral) and 30 to 640 at head 64 (the hybrid)
+    # at the benchmark's two older chat cells' decode shapes as their traffic
+    # filled them: 64 slots x 256 table entries, GQA 32 / 8, 10 rows live to
+    # 1,280 positions at head 128 (Mistral) and 30 to 640 at head 64 (the
+    # hybrid); the cells as they run today are ``_paged_call_times``' rows
     cases = [
         (store, dict(b=b, s=s, nh=12, hd=128, mb=32))
         for store in ("bf16", "int8", "fp8") for b, s in ((16, 1), (1, 128))
@@ -489,6 +505,8 @@ def _kernels() -> None:
             f"paged attention {list(q.shape)} block 16, {store} pool{live}, "
             "pallas vs gather", outs["pallas"], outs["gather"], PAGED_ATOL)
 
+    ok &= _paged_call_times(rng)
+
     # flash attention forward and gradients at the train shapes
     for b, s in ((8, 1024), (1, 8192)):
         qkv = [jnp.asarray(rng.normal(size=(b, s, 12, 128)), jnp.bfloat16) for _ in range(3)]
@@ -502,6 +520,87 @@ def _kernels() -> None:
         ok &= _ring_flash_check(rng)
     if not ok:
         sys.exit("a kernel disagrees with its reference beyond the stated bound")
+
+
+def _paged_ms_a_call(mod, q, pools, tables, idx, block_len, calls=24, reps=5) -> float:
+    """Milliseconds a call of ``mod``'s Pallas paged kernel: ``calls`` of them
+    in one program, layer after layer as a decode step makes them."""
+    import jax
+    import jax.numpy as jnp
+
+    def many(q, kp, vp):
+        def one(i, acc):
+            out = mod.paged_attention(q, kp, vp, i % 2, tables, idx, impl="pallas",
+                                      block_len=block_len)
+            return acc + out[0, 0, 0, 0].astype(jnp.float32)
+        return jax.lax.fori_loop(0, calls, one, jnp.float32(0))
+
+    many = jax.jit(many)
+    jax.block_until_ready(many(q, *pools))
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        out = many(q, *pools)
+    jax.block_until_ready(out)
+    return 1e3 * (time.perf_counter() - t0) / (reps * calls)
+
+
+def _paged_call_times(rng, lives=(2, 11, 22, 33, 64)) -> bool:
+    """The paged kernel at the three chat configurations' decode shapes - 64
+    rows x 256 table entries of 16, GQA 32 / 8 of 128 to 1,280 positions
+    (Mistral), 32 / 8 of 64 to 768 (LFM2, the hybrid), 32 / 4 of 128 with four
+    queries a row to 512 (SDAR) - with 2, 11, 22, 33 and 64 rows live:
+    finite, against ``gather``, and the milliseconds a call takes (a set-up
+    fact of this machine like the seconds of the other phases, for
+    ``PERF.md``). Where a builder has unpacked the parent commit under
+    ``_chip_tmp/parent`` (never committed), the parent's kernel is timed
+    beside it and has to agree within the same bound."""
+    import importlib
+
+    import jax
+
+    this = importlib.import_module("accelerate_tpu.ops.paged_attention")
+    parent = _parent_ops("paged_attention")
+    ok = True
+    for name, shape in (
+        ("mistral", dict(s=1, nh=32, hd=128, n_kv=8, deepest=1280)),
+        ("lfm2 / hybrid", dict(s=1, nh=32, hd=64, n_kv=8, deepest=768)),
+        ("sdar", dict(s=4, nh=32, hd=128, n_kv=4, deepest=512, block_len=4)),
+    ):
+        block_len = shape.pop("block_len", 1)
+        # one pool a shape, every row written; a free slot is a row whose
+        # table and position the call is handed as zeros
+        q, pools, full_tables, full_idx, _ = _paged_case(
+            rng, b=64, bs=16, mb=256, store="bf16", **shape)
+        for live in lives:
+            tables, idx = full_tables.copy(), full_idx // block_len * block_len
+            tables[live:], idx[live:] = 0, 0
+            run = lambda mod, impl: jax.jit(lambda q, kp, vp: mod.paged_attention(
+                q, kp, vp, 1, tables, idx, impl=impl, block_len=block_len))(q, *pools)
+            got = run(this, "pallas")
+            label = (f"paged attention, {name} decode shape {list(q.shape)}, {live} of 64 rows "
+                     f"live to {shape['deepest']}")
+            ok &= _kernel_row(label + ", pallas vs gather", got, run(this, "gather"), PAGED_ATOL)
+            row = {"check": label + ": ms a call", "ok": True, "tile": this._TILE,
+                   "in_flight": this._IN_FLIGHT,
+                   "ms_a_call": round(_paged_ms_a_call(this, q, pools, tables, idx, block_len), 4)}
+            if parent:
+                ok &= _kernel_row(label + ", pallas vs the parent's", got, run(parent, "pallas"),
+                                  PAGED_ATOL)
+                row["parent_ms_a_call"] = round(
+                    _paged_ms_a_call(parent, q, pools, tables, idx, block_len), 4)
+            print("KERNEL " + json.dumps(row), flush=True)
+    return ok
+
+
+def _paged_calls() -> None:
+    """The paged kernel's per-call rows of :func:`_kernels` alone."""
+    import numpy as np
+
+    from accelerate_tpu.mesh import configure_compile_cache
+
+    configure_compile_cache()
+    if not _paged_call_times(np.random.default_rng(0)):
+        sys.exit("the paged kernel disagrees with its reference beyond the stated bound")
 
 
 def _experts() -> None:
@@ -699,19 +798,13 @@ def _state_update_live_rows(rng, slots=64, h=64, p=64, n=128, lives=(11, 32, 64)
     ``PERF.md``). Where a builder has unpacked the parent commit under
     ``_chip_tmp/parent`` (never committed), the parent's kernel is timed
     beside it and its live rows have to be the same to the last bit."""
-    import importlib.util
-
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from accelerate_tpu.ops import ssm
 
-    parent = None
-    if os.path.exists(PARENT_SSM):
-        spec = importlib.util.spec_from_file_location("parent_ssm", PARENT_SSM)
-        parent = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(parent)
+    parent = _parent_ops("ssm")
 
     calls, reps = 36, 5
     state = jnp.asarray(rng.normal(size=(2, slots, h, p, n)), jnp.float32)
@@ -956,7 +1049,8 @@ def _engine_check() -> None:
 
 
 _CHILDREN = {
-    "_probe": _probe, "_kernels": _kernels, "_experts": _experts, "_train": _train,
+    "_probe": _probe, "_kernels": _kernels, "_paged_calls": _paged_calls, "_experts": _experts,
+    "_train": _train,
     "_engine_check": _engine_check,
 }
 

@@ -22,7 +22,7 @@ from accelerate_tpu.ops.fp8 import (
     quantize_kv_rows,
 )
 from accelerate_tpu.ops.layers import cached_attention, last_visible, write_paged_kv
-from accelerate_tpu.ops.paged_attention import paged_attention
+from accelerate_tpu.ops.paged_attention import _TILE, paged_attention
 
 #: ops-level |fused_quantized - f32_reference| ceilings on attention
 #: outputs (unit-variance inputs). int8 carries ~0.4% relative error per
@@ -143,13 +143,14 @@ RAGGED = (1, 15, 16, 17, 64, None)
 WALK_BS, WALK_MB = 16, 4
 
 
-def _ragged_case(rng, contexts, s, hd, store, tail):
-    """Random stacked pools (two kv heads, GQA x 2) and one block table a
-    row: a row whose last query sits at position ``context - 1`` holds
-    blocks for entries ``0 .. (context - 1) // 16``. Every later entry —
-    which no query of the row attends — points at ``tail``: the null block
-    0, or block 1, which is filled with NaN (its scales, for an int8 pool)."""
-    n_kv, nh, nb = 2, 4, 2 + len(contexts) * WALK_MB
+def _ragged_case(rng, contexts, s, hd, store, tail, mb=WALK_MB, rep=2):
+    """Random stacked pools (two kv heads, GQA x ``rep``) and one block table
+    of ``mb`` entries a row: a row whose last query sits at position
+    ``context - 1`` holds blocks for entries ``0 .. (context - 1) // 16``.
+    Every later entry — which no query of the row attends — points at
+    ``tail``: the null block 0, or block 1, which is filled with NaN (its
+    scales, for an int8 pool)."""
+    n_kv, nh, nb = 2, 2 * rep, 2 + len(contexts) * mb
     shape = (LAYERS, nb, WALK_BS, n_kv * hd)
     scales = []
     if store == "int8":
@@ -160,7 +161,7 @@ def _ragged_case(rng, contexts, s, hd, store, tail):
     else:
         pools = [jnp.asarray(rng.normal(size=shape), jnp.float32).at[:, 1].set(jnp.nan)
                  for _ in range(2)]
-    bt = np.full((len(contexts), WALK_MB), tail, np.int32)
+    bt = np.full((len(contexts), mb), tail, np.int32)
     idx = np.zeros((len(contexts),), np.int32)
     used = iter(range(2, nb))
     for i, context in enumerate(contexts):
@@ -201,24 +202,134 @@ def test_pallas_row_walk_matches_gather_on_ragged_rows(s, contexts, hd, store):
     assert np.isfinite(np.asarray(out)).all()
 
 
+#: what a tile adds to the walk's geometry: a softmax step takes ``_TILE``
+#: table entries, ``SPAN`` key positions. ``WIDE_MB`` entries are two whole
+#: tiles and half a third (the tile does not divide the table's width), so a
+#: row's context ends one position short of a tile's end, on it, one past
+#: it, in the middle of the third tile, or fills the table
+SPAN = _TILE * WALK_BS
+WIDE_MB = 2 * _TILE + _TILE // 2
+
+
+def _tile_shapes():
+    """(queries a row, block_len, the rows' contexts). A decode step; a block
+    round (four queries from a block's first position, block_len 4: every
+    context a multiple of 4); chunks of 32 that end at the same places."""
+    ends = (SPAN - 1, SPAN, SPAN + 1, 2 * SPAN + 40, WIDE_MB * WALK_BS)
+    yield pytest.param(1, 1, (1,) + ends + (None,), id="s1")
+    yield pytest.param(4, 4, (4, SPAN - 4, SPAN, SPAN + 4, 2 * SPAN + 40, WIDE_MB * WALK_BS, None),
+                       id="s4-B4")
+    yield pytest.param(32, 1, (32,) + ends, id="s32")
+
+
 @pytest.mark.parametrize("store", [None, "int8"])
-@pytest.mark.parametrize("s, contexts", [
-    pytest.param(1, RAGGED[:4] + (None, 33), id="b6-s1"),
-    pytest.param(32, (33,), id="b1-s32"),
+@pytest.mark.parametrize("hd", [64, 128])
+@pytest.mark.parametrize("rep", [1, 4, 8])
+@pytest.mark.parametrize("s, block_len, contexts", _tile_shapes())
+def test_pallas_tiles_match_gather_on_a_table_wider_than_a_tile(
+        s, block_len, contexts, rep, hd, store):
+    """A softmax step is a tile of table entries against a kv head's whole
+    query group: rows that end around a tile's edge, after several tiles and
+    at the table's end, a one-entry row and a free slot in ONE call, against
+    the gather reference — one query head a kv head, four and eight; both
+    head sizes; a decode step, a block round and a chunk; both kinds of pool."""
+    rng = np.random.default_rng(39)
+    q, pools, bt, idx, scales = _ragged_case(
+        rng, contexts, s, hd, store, tail=0, mb=WIDE_MB, rep=rep)
+    run = lambda impl: paged_attention(q, *pools, 1, bt, idx, *scales, impl=impl,
+                                       interpret=True, block_len=block_len)
+    out = np.asarray(run("pallas"))
+    tol = 1e-5 if store is None else 1e-4
+    np.testing.assert_allclose(out, np.asarray(run("gather")), rtol=tol, atol=tol)
+    assert np.isfinite(out).all()
+
+
+@pytest.mark.parametrize("store", [None, "int8"])
+def test_pallas_chunk_of_more_stacked_rows_than_a_grid_step_takes(store):
+    """A chunk whose kv heads' groups stack to more rows than ``_ROW_BLOCK``
+    (80 queries x 4 heads = 320, padded to two blocks of 256) goes a block of
+    rows a grid step, each walking the row's tiles again: first chunks and
+    later ones, ending around a tile's edge."""
+    from accelerate_tpu.ops.paged_attention import _ROW_BLOCK
+
+    s, rep = 80, 4
+    assert _ROW_BLOCK < s * rep < 2 * _ROW_BLOCK
+    rng = np.random.default_rng(13)
+    q, pools, bt, idx, scales = _ragged_case(
+        rng, (s, SPAN + 1, 2 * SPAN + 40), s, 64, store, tail=0, mb=WIDE_MB, rep=rep)
+    run = lambda impl: paged_attention(q, *pools, 1, bt, idx, *scales, impl=impl, interpret=True)
+    tol = 1e-5 if store is None else 1e-4
+    np.testing.assert_allclose(np.asarray(run("pallas")), np.asarray(run("gather")),
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("store", [None, "int8"])
+@pytest.mark.parametrize("mb", [
+    pytest.param(_TILE // 2 - 1, id="narrower-than-a-tile"),
+    pytest.param(_TILE, id="one-tile"),
+    pytest.param(2 * _TILE, id="two-tiles"),
+    pytest.param(_TILE + 3, id="a-tile-and-three"),
 ])
-def test_pallas_row_walk_stops_where_the_row_does(s, contexts, store):
+def test_pallas_tile_clamps_to_the_tables_width(mb, store):
+    """The tile is the kernel's constant or the table's width, whichever is
+    smaller, and a width it does not divide ends in a part tile: rows that
+    fill the table, end on its last entry's first row, and one entry."""
+    rng = np.random.default_rng(3)
+    contexts = (mb * WALK_BS, (mb - 1) * WALK_BS + 1, max((mb // 2) * WALK_BS, 1), 7, None)
+    q, pools, bt, idx, scales = _ragged_case(rng, contexts, 1, 64, store, tail=0, mb=mb, rep=4)
+    run = lambda impl: paged_attention(q, *pools, 2, bt, idx, *scales, impl=impl, interpret=True)
+    tol = 1e-5 if store is None else 1e-4
+    np.testing.assert_allclose(np.asarray(run("pallas")), np.asarray(run("gather")),
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("store", [None, "int8"])
+@pytest.mark.parametrize("s, contexts, mb", [
+    pytest.param(1, RAGGED[:4] + (None, 33), WALK_MB, id="b6-s1"),
+    pytest.param(32, (33,), WALK_MB, id="b1-s32"),
+    # wider than a tile: rows that end in a tile's first entry (seven poisoned
+    # entries behind it in the same tile, whole poisoned tiles after it), in
+    # its last, half-way, and a free slot
+    pytest.param(1, (1, SPAN - 1, SPAN + 1, SPAN + SPAN // 2, None, 2 * SPAN + 1), WIDE_MB,
+                 id="b6-s1-wide"),
+    pytest.param(32, (SPAN + 1,), WIDE_MB, id="b1-s32-wide"),
+])
+def test_pallas_row_walk_stops_where_the_row_does(s, contexts, mb, store):
     """Poisoned tail: every table entry past a row's last live one points at
     a block of NaN, and the output is bit-equal to the run where they point
-    at the null block — the walk never touches what no query attends."""
+    at the null block — the walk never touches what no query attends, be it
+    in the row's last, part-filled tile or in the tiles after it."""
     outs = []
     for tail in (0, 1):
         q, pools, bt, idx, scales = _ragged_case(
-            np.random.default_rng(7), contexts, s, 64, store, tail)
+            np.random.default_rng(7), contexts, s, 64, store, tail, mb=mb)
         outs.append(np.asarray(paged_attention(
             q, *pools, 2, bt, idx, *scales, impl="pallas", interpret=True)))
     assert (bt == 1).any(), "no entry was poisoned"
     assert np.isfinite(outs[1]).all()
     np.testing.assert_array_equal(outs[1], outs[0])
+
+
+@pytest.mark.parametrize("store", [None, "int8"])
+@pytest.mark.parametrize("contexts", [
+    pytest.param((1, 2 * SPAN + 40, 1, SPAN + 1, None), id="short-long-short"),
+    pytest.param((2 * SPAN + 40, 5, SPAN + 1, 1), id="long-first"),
+])
+def test_pallas_tile_rows_that_no_copy_wrote_do_not_reach_the_output(contexts, store):
+    """A tile's buffer holds ``_TILE`` entries and a short row copies one:
+    the other rows hold what the scratch held. In the Pallas interpreter
+    that memory starts as NaN (a one-entry row FIRST in the call reads it:
+    ``p`` is 0 there, and ``0 x NaN`` would be NaN in ``p @ v`` were V's rows
+    not selected by key position), and a one-entry row AFTER a long one
+    finds the long row's blocks there: neither reaches the output."""
+    rng = np.random.default_rng(11)
+    q, pools, bt, idx, scales = _ragged_case(rng, contexts, 1, 64, store, tail=0, mb=WIDE_MB, rep=4)
+    out = np.asarray(paged_attention(
+        q, *pools, 1, bt, idx, *scales, impl="pallas", interpret=True))
+    assert np.isfinite(out).all()
+    ref = np.asarray(paged_attention(q, *pools, 1, bt, idx, *scales, impl="gather"))
+    tol = 1e-5 if store is None else 1e-4
+    np.testing.assert_allclose(out, ref, rtol=tol, atol=tol)
 
 
 # -- block_len: causal from block to block, bidirectional inside a block ---------

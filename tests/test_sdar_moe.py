@@ -169,6 +169,54 @@ def test_one_decode_and_one_prefill_executable_and_what_stats_counts(served):
     assert s["prefix_cache"] is True
 
 
+@pytest.mark.parametrize("tile", [2, 8])
+def test_block_rounds_book_the_paged_kernels_tiles(tiny, tile, monkeypatch):
+    """``paged_tiles_walked_total`` under block-round dispatch, against the
+    positions the executables were handed: every forward of every round
+    (``denoise_steps`` + 1 a round) asks a block of queries a row from the
+    block's start, a chunk its whole blocks, and a row's entries are taken
+    ``tile`` a softmax step (clamped to the table's width), in every layer."""
+    import importlib
+
+    monkeypatch.setattr(importlib.import_module("accelerate_tpu.ops.paged_attention"),
+                        "_TILE", tile)
+    engine = _engine(tiny[0])
+    bs, burst, b, t = engine.config.block_size, engine.config.decode_burst, 4, 2
+    mb, layers = engine.config.blocks_per_slot, engine.stats()["kv_layers"]
+    seen = {"prefill": [], "decode": []}
+
+    def recorded(kind, fn):
+        def call(*args):
+            seen[kind].append((np.array(args[3]), np.shape(args[4])[-1]))
+            return fn(*args)
+        return call
+
+    engine._prefill_fn = recorded("prefill", engine._prefill_fn)
+    engine._decode_fn = recorded("decode", engine._decode_fn)
+    rng = np.random.default_rng(1)
+    for n in (37, 5, 50):
+        _ask(engine, rng.integers(0, 250, size=n).tolist())
+    engine.run_until_idle()
+    width = min(tile, mb)
+    walked = tiles = 0
+    for pos0, queries in seen["prefill"]:
+        rows = [min((int(pos0[0]) + queries - 1) // bs + 1, mb)]
+        walked += layers * sum(rows)
+        tiles += layers * sum(-(-n // width) for n in rows)
+    for pos0, _ in seen["decode"]:
+        for r in range(burst):
+            rows = [min((int(p) + b * r + b - 1) // bs + 1, mb) for p in pos0]
+            walked += layers * (t + 1) * sum(rows)
+            tiles += layers * (t + 1) * sum(-(-n // width) for n in rows)
+    stats = engine.stats()
+    assert seen["prefill"] and len(seen["decode"]) > 2
+    assert stats["paged_entries_walked_total"] == walked
+    assert stats["paged_tiles_walked_total"] == tiles
+    assert walked / width <= tiles < walked
+    engine.reset_stats()
+    assert engine.stats()["paged_tiles_walked_total"] == 0
+
+
 def test_a_flight_entry_carries_the_block_totals_as_of_its_harvest(tiny):
     engine = _engine(tiny[0])
     _ask(engine, range(3, 25), 9)

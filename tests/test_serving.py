@@ -468,7 +468,7 @@ def test_engine_kv_stats_and_capacity_math(tiny_model):
 
 
 @pytest.mark.parametrize("spec_k", [0, 2])
-def test_paged_entries_counters_follow_the_rows_lengths(tiny_model, spec_k):
+def test_paged_entries_counters_follow_the_rows_lengths(tiny_model, spec_k, monkeypatch):
     """``paged_entries_walked_total`` / ``paged_entries_table_total`` move, at
     every dispatch, by what the rows' lengths give: a prefill chunk walks its
     one row up to the chunk's last position, a decode burst walks each live
@@ -476,7 +476,15 @@ def test_paged_entries_counters_follow_the_rows_lengths(tiny_model, spec_k):
     times the model's layers — while contexts grow over block edges across
     several dispatches of the ONE decode executable. A speculative round is
     ``spec_k`` single-query steps of the one-layer draft and one verify
-    forward of ``spec_k + 1`` queries through both layers."""
+    forward of ``spec_k + 1`` queries through both layers.
+    ``paged_tiles_walked_total`` moves by the same rows' softmax steps: a
+    row's entries over the kernel's tile, rounded up (the tile set to two
+    entries here, so that a row of three takes two steps)."""
+    import importlib
+
+    tile = 2
+    monkeypatch.setattr(importlib.import_module("accelerate_tpu.ops.paged_attention"),
+                        "_TILE", tile)
     bs, chunk, burst, slots, layers = 8, 8, 2, 3, 2
     engine = InferenceEngine(tiny_model, EngineConfig(
         num_slots=slots, block_size=bs, max_seq_len=64, prefill_chunk=chunk,
@@ -507,6 +515,7 @@ def test_paged_entries_counters_follow_the_rows_lengths(tiny_model, spec_k):
     # the prompts' chunks: 11 tokens start at 0 and 8, 5 tokens at 0
     assert sorted(int(p[0]) for p in seen["prefill"]) == [0, 0, 8]
     walked = layers * sum((int(p[0]) + chunk - 1) // bs + 1 for p in seen["prefill"])
+    tiles = layers * sum(-(-((int(p[0]) + chunk - 1) // bs + 1) // tile) for p in seen["prefill"])
     table = layers * len(seen["prefill"]) * mb
     # several decode dispatches, a request's context growing by the burst
     # from its prompt's length, over a block's edge (11 -> 17: entries 2 -> 3)
@@ -522,16 +531,19 @@ def test_paged_entries_counters_follow_the_rows_lengths(tiny_model, spec_k):
         assert pos0.shape == (slots,)
         for steps, queries, depth in calls:
             for step in range(steps):
-                walked += depth * sum(          # a free slot: 1
-                    int(p + step + queries - 1) // bs + 1 for p in pos0)
+                rows = [int(p + step + queries - 1) // bs + 1 for p in pos0]  # a free slot: 1
+                walked += depth * sum(rows)
+                tiles += depth * sum(-(-n // tile) for n in rows)
             table += depth * steps * slots * mb
     stats = engine.stats()
     assert stats["decode_compiles"] == 1 and stats["prefill_compiles"] == 1
     assert stats["paged_entries_walked_total"] == walked
     assert stats["paged_entries_table_total"] == table
-    assert 0 < walked < table
+    assert stats["paged_tiles_walked_total"] == tiles
+    assert 0 < walked < table and walked / tile <= tiles < walked
     engine.reset_stats()
     assert engine.stats()["paged_entries_walked_total"] == 0
+    assert engine.stats()["paged_tiles_walked_total"] == 0
 
 
 @pytest.mark.parametrize("spec_k", [0, 2])
