@@ -248,10 +248,15 @@ _IN_FLIGHT = 1
 _ROW_BLOCK = 256
 
 
+def tile_entries(table_width: int) -> int:
+    """Table entries a softmax step takes of a table this wide."""
+    return min(_TILE, table_width)
+
+
 def tiles_walked(entries, table_width: int):
     """Softmax steps the kernel takes for rows that walk ``entries`` table
     entries each (the host's count, ``serving/engine.py``)."""
-    return -(-entries // min(_TILE, table_width))
+    return -(-entries // tile_entries(table_width))
 
 
 def _pallas_kernel(bt_ref, idx_ref, layer_ref, q_ref, *rest,
@@ -396,7 +401,7 @@ def _paged_attention_pallas(q, k_pool, v_pool, layer, block_tables, idx,
     n_kv = width // hd
     rep = nh // n_kv
     quantized = k_scale is not None
-    tile = min(_TILE, block_tables.shape[1])
+    tile = tile_entries(block_tables.shape[1])
     # a kv head's query group stacked along the rows, head-major, padded to whole
     # sublanes, in blocks of at most _ROW_BLOCK rows: a reshape of the small
     # query outside the kernel

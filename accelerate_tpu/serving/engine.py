@@ -79,10 +79,10 @@ from ..diagnostics.tracing import (
 from ..metrics.ingest import observe_flight
 from ..metrics.registry import get_active_registry
 from ..models.cache import cache_spec_of
-from ..ops.paged_attention import default_paged_attention_impl, tiles_walked
+from ..ops.paged_attention import default_paged_attention_impl, tile_entries, tiles_walked
 from ..telemetry import get_active_recorder
 from .blocks import NULL_BLOCK, BlockAllocator, blocks_needed
-from .flight import ITERATION_PHASES, FlightRecorder, set_active_flight_recorder
+from .flight import ITERATION_PHASES, PART_NAMES, FlightRecorder, set_active_flight_recorder
 from .grammar import compile_grammar
 from .radix import RadixCache, SwapPool
 from .sampling import (
@@ -156,8 +156,11 @@ class EngineConfig:
     #: --iterations``, ``/profile`` windows, and HANG_REPORT forensics
     #: all read. One perf_counter read per phase boundary (and one
     #: ``time.time_ns()`` per iteration, the anchor on the profiler's
-    #: clock); disabled, the boundaries read no clock and only open the
-    #: ``serve/<phase>`` spans.
+    #: clock); a named part of a phase (``prefill/operands``,
+    #: ``dispatch/call``, ...: ``flight.ITERATION_PARTS``) is one more read
+    #: where it opens or closes between two phase boundaries; disabled, the
+    #: boundaries read no clock and only open the ``serve/<phase>`` and
+    #: ``serve/<phase>/<part>`` spans.
     flight_history: int = 256
     #: finished :class:`Request` objects retained for ``stats()``
     #: percentiles — a *ring*, not a list: a long-lived serve process must
@@ -307,12 +310,15 @@ _BLOCK_TOTALS = (
 #: keys of a flight entry that place it on a clock; the ``serve/flight``
 #: Chrome instant carries the rest (its own ``ts`` places it, and the
 #: phases are ``serve/<phase>`` events of their own in that file)
-_FLIGHT_CLOCK_KEYS = frozenset({"t_start", "t_start_unix_ns", "intervals"})
+_FLIGHT_CLOCK_KEYS = frozenset({"t_start", "t_start_unix_ns", "intervals", "parts"})
 
 #: the span names of an iteration and of its phases (children of it), as a
 #: profiler capture and the Chrome trace show them
 _ITERATION_SPAN = "serve/iteration"
 _PHASE_SPANS = {phase: "serve/" + phase for phase in ITERATION_PHASES}
+#: and of the named parts of a phase (``flight.ITERATION_PARTS``), children of
+#: the phase's span
+_PART_SPANS = {name: "serve/" + name for name in PART_NAMES}
 
 
 def _under_mesh(apply_fn, mesh):
@@ -704,6 +710,9 @@ class InferenceEngine:
         # Tracer's Chrome events and the profiler's host rows are both fed
         # from there, children of one ``serve/iteration`` span) and keeps
         # the iteration's (phase, start, end) intervals for the recorder.
+        # _fl_part stamps a second level on the same stream: the named part
+        # of the open phase (a child span, a ``parts`` row), closed by the
+        # next part boundary or by the phase switch's own read.
         self._fl_t0 = 0.0
         self._fl_last = 0.0
         self._fl_unix_ns = 0
@@ -714,6 +723,9 @@ class InferenceEngine:
         self._fl_hidden = False
         self._fl_span = None
         self._fl_iter_span = None
+        self._fl_part_span = None
+        self._fl_part_at: tuple = ("", 0.0)  # the open part's (name, stamp)
+        self._fl_parts: list = []
         # time-to-first-token, decomposed at the boundaries a request
         # crosses (monotone totals, reset with the measurement window):
         # arrival -> admission (queue), time inside its own prefill chunks,
@@ -1544,7 +1556,7 @@ class InferenceEngine:
             # dispatched before leaving the iteration (the pre-item-5 loop)
             self._harvest_inflight(finished)
 
-        self._fl_switch("harvest")
+        self._fl_switch("harvest", "harvest/close")
         self._iterations += 1
         self._occupancy_sum += sched.occupancy
         for req in finished:
@@ -1583,7 +1595,7 @@ class InferenceEngine:
             entry = fl.record(
                 self._iterations, t0, wall,
                 overlap_hidden_s=overlap, intervals=self._fl_intervals,
-                t_start_unix_ns=self._fl_unix_ns,
+                parts=self._fl_parts, t_start_unix_ns=self._fl_unix_ns,
                 counters={
                     **{name: int(total) for name, total in self._step_counters.items()
                        if not total.shape},
@@ -1859,8 +1871,9 @@ class InferenceEngine:
             "paged_entries_table_total": self._paged_entries_table,
             # the kernel's softmax steps over the walked entries, a tile of
             # ops/paged_attention.py's _TILE entries each (a row's last one
-            # part full): walked / (tiles x _TILE) is the tiles' fill
+            # part full): walked / (tiles x paged_tile_entries) is the tiles' fill
             "paged_tiles_walked_total": self._paged_tiles_walked,
+            "paged_tile_entries": tile_entries(self._mb),
             # the slot state's work: states a decode dispatch had to step
             # (live lanes x burst x state layers: what ops/ssm.py's kernel
             # walks) against those the cache holds for every slot
@@ -1963,6 +1976,9 @@ class InferenceEngine:
         if self._fl_span is not None:
             # the last iteration raised between two boundaries: its spans
             # end here, not in the open-span registry of a hang report
+            if self._fl_part_span is not None:
+                span_exit(self._fl_part_span)
+                self._fl_part_span = None
             span_exit(self._fl_span)
             span_exit(self._fl_iter_span)
         if fl is None:
@@ -1980,6 +1996,7 @@ class InferenceEngine:
         self._fl_t0 = self._fl_last = t
         self._fl_phases = dict.fromkeys(ITERATION_PHASES, 0.0)
         self._fl_intervals = []
+        self._fl_parts = []
         self._fl_overlap = 0.0
         # hidden-overlap rule: an interval counts as hidden iff a round
         # was in flight when it OPENED (and it is not device_wait) — the
@@ -1990,11 +2007,14 @@ class InferenceEngine:
 
     def _fl_close(self) -> tuple:
         """Close the open interval into its phase bucket and the interval
-        list; returns ``(stamp, duration)`` — ``(None, None)`` when the
-        recorder is off, where a boundary reads no clock."""
+        list, and the open part with it, on one read; returns ``(stamp,
+        duration)`` — ``(None, None)`` when the recorder is off, where a
+        boundary reads no clock."""
         if self._fl_phases is None:
+            self._fl_part_close(None)
             return None, None
         t = time.perf_counter()
+        self._fl_part_close(t)
         dt = t - self._fl_last
         self._fl_phases[self._fl_cur] += dt
         self._fl_intervals.append(
@@ -2005,10 +2025,11 @@ class InferenceEngine:
         self._fl_last = t
         return t, dt
 
-    def _fl_switch(self, phase: str) -> float | None:
+    def _fl_switch(self, phase: str, part: str | None = None) -> float | None:
         """THE phase boundary: close the open interval (bucket, interval
-        list, ``serve/<phase>`` span) and open ``phase`` on the same clock
-        read. Phases may be re-entered (the async loop visits "harvest"
+        list, ``serve/<phase>`` span, the open part) and open ``phase`` —
+        and ``part`` of it, where the phase starts with one — on the same
+        clock read. Phases may be re-entered (the async loop visits "harvest"
         both at the harvest point and for bookkeeping) — the buckets
         accumulate, and their sum telescopes to the iteration wall
         exactly, which ``FlightRecorder.record`` asserts. Returns the
@@ -2020,12 +2041,42 @@ class InferenceEngine:
         span_exit(self._fl_span, t)
         self._fl_span = span_enter(trace_span(_PHASE_SPANS[phase]), t)
         self._fl_cur = phase
+        if part is not None:
+            self._fl_part_open(part, t)
         if dt is not None:
             # decided at OPEN time: device_wait is by definition the
             # residual the host could NOT hide, so it never accrues overlap
             self._fl_hidden = self._inflight is not None and phase != "device_wait"
             self._flight.current_phase = phase
         return dt
+
+    def _fl_part_open(self, name: str, t: float | None) -> None:
+        self._fl_part_span = span_enter(trace_span(_PART_SPANS[name]), t)
+        self._fl_part_at = (name, t)
+
+    def _fl_part_close(self, t: float | None) -> None:
+        span = self._fl_part_span
+        if span is None:
+            return
+        self._fl_part_span = None
+        span_exit(span, t)
+        if self._fl_phases is not None:
+            name, t_open = self._fl_part_at
+            self._fl_parts.append((name, t_open - self._fl_t0, t - self._fl_t0))
+
+    def _fl_part(self, name: str | None, need: bool = False) -> float | None:
+        """A part boundary inside the open phase: close the open part (its
+        ``serve/<phase>/<part>`` span, its ``parts`` row) and open ``name``
+        (``"<phase>/<part>"``; None: the phase's rest) on ONE clock read,
+        which every observer of that boundary takes. With the recorder off
+        no clock is read and None returned, as at a phase boundary — unless
+        an observer ``need``s the stamp whatever is recording (a prefill
+        chunk's own-prefill accounting): that read is then the boundary's."""
+        t = time.perf_counter() if need or self._fl_phases is not None else None
+        self._fl_part_close(t)
+        if name is not None:
+            self._fl_part_open(name, t)
+        return t
 
     def _fl_finish(self):
         """Close the last interval and both spans; returns ``(t0, wall_s,
@@ -2086,7 +2137,7 @@ class InferenceEngine:
             self._pending_counters = []
             self._sum_step_counters(*counted)
         self._inflight = None
-        dw = self._fl_switch("harvest")
+        dw = self._fl_switch("harvest", "harvest/emit")
         if u is not None and dw is None:
             dw = time.perf_counter() - t_dw
         if rd.kind == "spec":
@@ -2170,6 +2221,7 @@ class InferenceEngine:
                 else [(r.request_id, 1) for r in rd.live]
             )
             u.accrue_decode(dw, shares)
+        self._fl_part(None)
 
     def _sum_step_counters(self, of_round, of_chunks) -> None:
         """Add fetched step counters to the running sums: a decode round's
@@ -2197,7 +2249,8 @@ class InferenceEngine:
         if prev is not None:
             # resume the interrupted phase: the fence's device_wait +
             # harvest intervals were attributed; the remainder of the
-            # interrupted phase keeps telescoping
+            # interrupted phase keeps telescoping (a round is in flight
+            # only while ``schedule`` runs, which has no part to resume)
             self._fl_switch(prev)
         return True
 
@@ -2493,12 +2546,17 @@ class InferenceEngine:
             self._pick_draw_dispatches += runs
 
     def _prefill_one_chunk(self, req: Request, finished: list[Request]) -> None:
-        """One chunk of one prompt. Its two clock reads are the request's
-        own-prefill stamps: the usage ledger's prefill accrual, the
-        ``req/prefill_chunk`` event and — on the final chunk, whose read
-        follows the blocking first-token fetch — ``first_token_time`` all
-        take them instead of reading the clock again."""
-        t0 = time.perf_counter()
+        """One chunk of one prompt, in parts: ``prefill/operands`` up to the
+        compiled call, ``prefill/call`` (the host's side of the dispatch),
+        on a prompt's last chunk ``prefill/first_pick`` and
+        ``prefill/first_fetch`` (:meth:`_first_token_pick`), and
+        ``prefill/emit`` to the chunk's end. The stamp that opens the first
+        part and the one that opens ``emit`` — on the final chunk it follows
+        the blocking first-token fetch — are the request's own-prefill
+        stamps: the usage ledger's prefill accrual, the ``req/prefill_chunk``
+        event and ``first_token_time`` all take them instead of reading the
+        clock again."""
+        t0 = self._fl_part("prefill/operands", need=True)
         cfg = self.config
         c = cfg.prefill_chunk
         start = req.prefill_pos
@@ -2528,12 +2586,12 @@ class InferenceEngine:
 
         if end > start:  # (a block model's prompt may hold no whole block to prefill)
             self._count_paged_entries([start], c, self._cache_spec.paged_layers)
+            table = self._block_tables[req.slot : req.slot + 1].copy()
+            pos0, slot = np.asarray([start], np.int32), np.asarray([req.slot], np.int32)
+            self._fl_part("prefill/call")
             self._cache, logits, *counters = self._prefill_fn(
-                self._params, self._cache,
-                self._block_tables[req.slot : req.slot + 1].copy(),
-                np.asarray([start], np.int32), chunk, valid, last_idx,
-                np.asarray([req.slot], np.int32),
-            )
+                self._params, self._cache, table, pos0, chunk, valid, last_idx, slot)
+            self._fl_part(None)
             # a chunk's step counters stay on the device until the next harvest
             self._pending_counters.extend(counters)
         req.prefill_pos = end
@@ -2560,7 +2618,7 @@ class InferenceEngine:
             # transform decode uses (position 0 of the request's derived
             # key stream); blocks until the chunk (and all before it) ran
             tok, lp_entry = self._first_token_pick(req, logits)
-        t1 = time.perf_counter()
+        t1 = self._fl_part("prefill/emit", need=True)
         req.own_prefill_s += t1 - t0
         if self.usage is not None:
             self.usage.accrue_prefill(req, t1 - t0)
@@ -2583,6 +2641,7 @@ class InferenceEngine:
                 self._emit_token(req, tok, finished, lp_entry, now=t1)
                 if req.state is not RequestState.FINISHED:
                     req.state = RequestState.DECODE
+        self._fl_part(None)
 
     def _ensure_decode_capacity(self, req: Request, finished: list[Request]) -> None:
         """Growth for one decode lane, with swap preemption under pool
@@ -2645,6 +2704,7 @@ class InferenceEngine:
         them (next iteration's harvest point in async mode, immediately
         after this returns in sync mode)."""
         cfg = self.config
+        self._fl_part("dispatch/capacity")
         # pass 1 — capacity: grow every lane (evicting cached blocks,
         # preempting victims, truncating last-resort). A later lane's
         # preemption may take an *earlier* lane out of its slot, so lane
@@ -2653,6 +2713,7 @@ class InferenceEngine:
             if req.slot is None or req.state is not RequestState.DECODE:
                 continue  # preempted or force-finished by an earlier lane
             self._ensure_decode_capacity(req, finished)
+        self._fl_part("dispatch/operands")
         pos0 = np.zeros((cfg.num_slots,), np.int32)
         active = np.zeros((cfg.num_slots, 1), bool)
         toks = np.zeros((cfg.num_slots, 1), np.int32)
@@ -2669,6 +2730,7 @@ class InferenceEngine:
             active[req.slot, 0] = True
             live.append(req)
         if not live:
+            self._fl_part(None)
             return
         known = n_known = None
         if self._block is not None:
@@ -2746,11 +2808,8 @@ class InferenceEngine:
         )
         self._count_state_slots(active)
         self._count_pick(lanes)
-        self._cache, next_toks, logps, tvals, tids, *counters = self._decode_fn(
-            self._params, self._cache, self._block_tables.copy(), pos0, toks, active,
-            lanes, self._gmask, self._gtrans, self._base_key,
-        )
-        self._check_one_executable(decode_sig)
+        next_toks, logps, tvals, tids, *counters = self._call_decode(
+            decode_sig, pos0, toks, active, lanes, self._gmask, self._gtrans, self._base_key)
         if self._tr is not None:
             # request identity on the decode timeline WITHOUT per-token
             # spans: one instant per dispatch carries the whole slot batch
@@ -2767,6 +2826,18 @@ class InferenceEngine:
             tvals=tvals, tids=tids, harvest_lp=harvest_lp,
             counters=counters[0] if counters else None,
         )
+
+    def _call_decode(self, decode_sig: tuple | None, *operands) -> list:
+        """The call of the ONE decode executable (a burst, a speculative
+        round or block rounds: whichever was built), with a copy of the
+        block tables, as the ``dispatch/call`` part; keeps the cache it
+        hands back and returns the rest."""
+        tables = self._block_tables.copy()
+        self._fl_part("dispatch/call")
+        self._cache, *out = self._decode_fn(self._params, self._cache, tables, *operands)
+        self._fl_part(None)
+        self._check_one_executable(decode_sig)
+        return out
 
     def _spec_decode_dispatch(
         self, pos0, toks, active, lanes, live: list[Request],
@@ -2786,11 +2857,8 @@ class InferenceEngine:
         k = self.config.spec_k
         self._count_paged_entries(pos0 + np.arange(k)[:, None], 1, self._spec.layers)
         self._count_paged_entries(pos0, k + 1, self._cache_spec.paged_layers)
-        self._cache, tok_seq, accept = self._decode_fn(
-            self._params, self._cache, self._block_tables.copy(), pos0, toks, active,
-            lanes, self._gmask, self._gtrans, self._base_key,
-        )
-        self._check_one_executable(decode_sig)
+        tok_seq, accept = self._call_decode(
+            decode_sig, pos0, toks, active, lanes, self._gmask, self._gtrans, self._base_key)
         # the round's [num_slots, k+1] token matrix and [num_slots]
         # accepted-prefix vector stay device futures; the serve/spec_round
         # instant needs the accept values, so it moves to the harvest
@@ -2820,11 +2888,8 @@ class InferenceEngine:
         totals["block_rounds_total"] += burst
         totals["block_slot_forwards_total"] += burst * (t + 1) * n_live
         totals["block_positions_committed_total"] += burst * b * n_live - int(n_known.sum())
-        self._cache, next_toks, logps, tvals, tids, *counters = self._decode_fn(
-            self._params, self._cache, self._block_tables.copy(), pos0, toks, known, active,
-            lanes, self._gmask, self._base_key,
-        )
-        self._check_one_executable(decode_sig)
+        next_toks, logps, tvals, tids, *counters = self._call_decode(
+            decode_sig, pos0, toks, known, active, lanes, self._gmask, self._base_key)
         if self._tr is not None:
             self._tr.instant(
                 "serve/block_rounds", slots=n_live, rounds=burst, block_length=b,
@@ -2882,7 +2947,10 @@ class InferenceEngine:
         prefill executable already returns: one ``[1, vocab]`` run of the
         shared :func:`sampling.pick_tokens` at output position 0 — exact
         key parity with the decode lanes, so a preempted-and-restarted
-        request reproduces its first token too."""
+        request reproduces its first token too. ``prefill/first_pick`` is
+        the host's side of that run, ``prefill/first_fetch`` the blocking
+        reads of what it picked."""
+        self._fl_part("prefill/first_pick")
         params = req.sampling or self._default_sampling
         lanes = blank_lanes(1, self.config.rep_window)
         set_slot_lane(
@@ -2894,6 +2962,7 @@ class InferenceEngine:
         tok, logp, tvals, tids = self._first_pick_fn(
             logits[None], lanes, self._gmask, self._base_key
         )
+        self._fl_part("prefill/first_fetch")
         entry = None
         if params.logprobs:
             entry = self._logprob_entry(

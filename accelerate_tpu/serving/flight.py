@@ -22,6 +22,12 @@ rather than logging it):
 ``harvest``
     token emission, finish bookkeeping, telemetry — host work.
 
+Three phases have **named parts** (:data:`ITERATION_PARTS`), stamped by the
+same stamper on one clock read a boundary: a ``parts`` row ``[<phase>/<part>,
+start_s, end_s]`` lies inside one interval of its phase, and what a phase
+spends under no part is its ``rest``. ``schedule`` and ``device_wait`` have
+none.
+
 ``host_fraction`` = 1 − (device_wait + overlap_hidden) / wall over the
 recorded window: the ROADMAP item-5 measurement ("host-scheduling time
 leaving the per-token critical path"). Under the double-buffered engine
@@ -57,6 +63,17 @@ from collections import deque
 #: the exclusive phases, in stamp order — ``record()`` requires exactly
 #: these keyword arguments and the metrics/trace surfaces label by them
 ITERATION_PHASES = ("schedule", "prefill", "dispatch", "device_wait", "harvest")
+
+#: the named parts of a phase, in the order the engine stamps them: a
+#: ``serve/<phase>/<part>`` span each, and the ``parts`` rows of an entry
+ITERATION_PARTS = {
+    "prefill": ("operands", "call", "first_pick", "first_fetch", "emit"),
+    "dispatch": ("capacity", "operands", "call"),
+    "harvest": ("emit", "close"),
+}
+PART_NAMES = frozenset(
+    f"{phase}/{part}" for phase, parts in ITERATION_PARTS.items() for part in parts
+)
 
 _active_flight_recorder = None
 
@@ -95,6 +112,24 @@ def _checked_intervals(intervals, wall_s: float, phases: dict) -> list:
     return out
 
 
+def _checked_parts(parts, intervals: list) -> list:
+    """``[(<phase>/<part>, start_s, end_s), ...]`` as given, once they are
+    seen to be in time order, to overlap nowhere, and each to lie inside one
+    interval of its own phase (a part's first and last reads are boundaries
+    of its own or the phase's, so the comparisons are exact)."""
+    out = [(str(n), float(a), float(b)) for n, a, b in parts]
+    edge = 0.0
+    for name, start, end in out:
+        phase = name.split("/", 1)[0]
+        if name not in PART_NAMES or start < edge or end < start or not any(
+                p == phase and a <= start and end <= b for p, a, b in intervals):
+            raise AssertionError(
+                f"flight part {(name, start, end)!r} is unknown, out of order, or "
+                f"outside every interval of its phase: {out!r} in {intervals!r}")
+        edge = end
+    return out
+
+
 class FlightRecorder:
     """Bounded ring of per-iteration phase breakdowns + cumulative
     totals. Ring entries answer "what were the last K iterations doing"
@@ -125,7 +160,7 @@ class FlightRecorder:
     def record(self, iteration: int, t_start: float, wall_s: float,
                overlap_hidden_s: float = 0.0, intervals=None,
                t_start_unix_ns: int | None = None, counters: dict | None = None,
-               **phases: float) -> dict:
+               parts=None, **phases: float) -> dict:
         """Append one iteration. ``phases`` must cover exactly
         :data:`ITERATION_PHASES` and sum to ``wall_s`` — the stamps
         telescope (each phase is the diff of consecutive perf_counter
@@ -146,7 +181,10 @@ class FlightRecorder:
         intervals can be laid over a device trace's idle gaps. ``counters``
         are the engine's running sums of the model's scalar step counters as
         of this iteration's harvest (a routed model's ``moe_*_total``): two
-        entries' difference is what the device did between them, exactly."""
+        entries' difference is what the device did between them, exactly.
+        ``parts`` are the named parts of the phases (:data:`ITERATION_PARTS`)
+        on the origin of ``intervals``, which they need: in time order,
+        disjoint, each inside an interval of its phase — asserted."""
         if set(phases) != set(ITERATION_PHASES):
             raise AssertionError(
                 f"flight phases {sorted(phases)} != {sorted(ITERATION_PHASES)}"
@@ -171,6 +209,8 @@ class FlightRecorder:
                  "overlap_hidden_s": overlap_hidden_s}
         if intervals is not None:
             entry["intervals"] = _checked_intervals(intervals, wall_s, phases)
+        if parts is not None:
+            entry["parts"] = _checked_parts(parts, entry.get("intervals", []))
         if t_start_unix_ns is not None:
             entry["t_start_unix_ns"] = int(t_start_unix_ns)
         if counters:

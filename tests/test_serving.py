@@ -540,6 +540,7 @@ def test_paged_entries_counters_follow_the_rows_lengths(tiny_model, spec_k, monk
     assert stats["paged_entries_walked_total"] == walked
     assert stats["paged_entries_table_total"] == table
     assert stats["paged_tiles_walked_total"] == tiles
+    assert stats["paged_tile_entries"] == tile  # what a reader divides by: the kernel's own
     assert 0 < walked < table and walked / tile <= tiles < walked
     engine.reset_stats()
     assert engine.stats()["paged_entries_walked_total"] == 0

@@ -18,9 +18,15 @@ import pytest
 from accelerate_tpu import Accelerator
 from accelerate_tpu.diagnostics.tracing import get_tracer, NULL_TRACER
 from accelerate_tpu.serving import EngineConfig, InferenceEngine
-from accelerate_tpu.serving.flight import ITERATION_PHASES, FlightRecorder
+from accelerate_tpu.serving.flight import (
+    ITERATION_PARTS,
+    ITERATION_PHASES,
+    PART_NAMES,
+    FlightRecorder,
+)
 from accelerate_tpu.test_utils import RegressionDataset, RegressionModel, SimpleLoader
 
+PHASE_SPANS = {f"serve/{phase}" for phase in ITERATION_PHASES}  # (a part is a grandchild)
 SCOPES = ("embed", "layers", "attn_proj", "kv_write", "attn_kernel", "mlp", "head",
           "sample", "loss", "optimizer")
 
@@ -123,7 +129,7 @@ def test_capture_iterations_are_numbered_and_phases_tile_them(capture):
     assert sum(int(s["tokens"]) for *_, s in iterations) == 6
     for _, a, b, _ in iterations:
         inside = [(lo, hi) for n, lo, hi, _ in capture["line"]
-                  if n != "serve/iteration" and n.startswith("serve/") and a <= lo and hi <= b]
+                  if n in PHASE_SPANS and a <= lo and hi <= b]
         # children do not overlap, and cover the iteration but for the
         # microseconds each switch takes while a session records
         inside.sort()
@@ -144,6 +150,23 @@ def test_capture_and_flight_entries_share_the_wall_clock(capture):
         for _, lo, hi in f["intervals"]:  # (slack: a loaded test host may preempt a thread)
             assert a - 50e6 <= at + lo * 1e9 <= at + hi * 1e9 <= b + 50e6
     assert sorted(apart)[len(apart) // 2] < 200_000 and max(apart) < 50e6
+
+
+@pytest.mark.parametrize("part", sorted(PART_NAMES))
+def test_capture_holds_each_part_nested_in_its_phase_and_its_iteration(capture, part):
+    """The prompt's three chunks (the last one picks the first token), the
+    decode rounds and every iteration's tail: all ten parts are there, each
+    a grandchild of exactly one iteration through exactly one span of its
+    own phase."""
+    iterations = [e for e in capture["line"] if e[0] == "serve/iteration"]
+    phases = [e for e in capture["line"] if e[0] == "serve/" + part.split("/")[0]]
+    spans = [e for e in capture["line"] if e[0] == "serve/" + part]
+    assert spans
+    for _, lo, hi, _ in spans:
+        assert sum(1 for _, a, b, _ in phases if a <= lo and hi <= b) == 1
+        assert sum(1 for _, a, b, _ in iterations if a <= lo and hi <= b) == 1
+    rows = sum(1 for f in capture["flights"] for n, *_ in f["parts"] if n == part)
+    assert len(spans) == rows  # one span a row of the flight entries
 
 
 @pytest.mark.parametrize("name", ["train", "step/dispatch"])
@@ -172,6 +195,63 @@ def test_every_flight_entry_tiles_its_wall_time(capture):
         assert abs(e["t_start_unix_ns"] / 1e9 - time.time()) < 3600
 
 
+def test_parts_are_ordered_disjoint_inside_their_phase_and_add_up_with_the_rest(capture):
+    seen = set()
+    for e in capture["flights"]:
+        parts = e["parts"]
+        assert all(x[1] <= x[2] <= y[1] for x, y in zip(parts, parts[1:]))
+        for name, lo, hi in parts:
+            phase, part = name.split("/")
+            assert part in ITERATION_PARTS[phase]
+            assert sum(1 for q, a, b in e["intervals"] if q == phase and a <= lo and hi <= b) == 1
+        for phase in ITERATION_PHASES:
+            under = sum(hi - lo for n, lo, hi in parts if n.startswith(phase + "/"))
+            rest = e[f"{phase}_s"] - under  # what no part stamps
+            assert -1e-9 <= rest <= e[f"{phase}_s"] + 1e-9
+            assert under == 0.0 or phase in ITERATION_PARTS
+        seen |= {n for n, *_ in parts}
+        assert parts[-1][0] == "harvest/close"  # every iteration ends in its tail
+        assert parts[-1][2] == pytest.approx(e["wall_s"], abs=1e-9)  # ... on the finish's read
+    assert seen == PART_NAMES
+
+
+def test_a_chunk_that_is_not_final_picks_and_fetches_nothing(capture):
+    """20 prompt tokens in chunks of 8: three chunks, an iteration each; only
+    the last runs the first token's pick and waits for it."""
+    chunks = [[n.split("/")[1] for n, *_ in e["parts"] if n.startswith("prefill/")]
+              for e in capture["flights"]]
+    chunks = [c for c in chunks if c]
+    assert chunks == [["operands", "call", "emit"]] * 2 + [list(ITERATION_PARTS["prefill"])]
+
+
+@pytest.mark.parametrize("kind", ["spec", "block"])
+def test_every_decode_program_stamps_its_call(tiny_model, kind):
+    """A speculative round and a block model's rounds are dispatched from
+    methods of their own: each stamps ``dispatch/call`` after the shared
+    capacity and operand passes, and the harvest its emission."""
+    if kind == "spec":
+        eng = _engine(tiny_model, spec_k=2, draft="early_exit:1", flight_history=64)
+    else:
+        import accelerate_tpu.models.sdar_moe as sdar
+
+        model = sdar.SdarMoeForCausalLM.from_config(sdar.SdarMoeConfig.tiny(), seed=0)
+        eng = InferenceEngine(model, EngineConfig(
+            num_slots=2, block_size=8, max_seq_len=64, prefill_chunk=8, decode_burst=2,
+            denoise_steps=2, logprobs_topn=1, stats_interval=0, flight_history=64))
+    eng.add_request(np.arange(11, dtype=np.int32), max_new_tokens=9)
+    eng.run_until_idle(max_iterations=200)
+    rounds = [[n for n, *_ in e["parts"] if n.startswith("dispatch/")]
+              for e in eng._flight.tail(64)]
+    rounds = [r for r in rounds if r]
+    assert len(rounds) >= 2
+    assert all(r == ["dispatch/capacity", "dispatch/operands", "dispatch/call"] for r in rounds)
+    emits = sum(1 for e in eng._flight.tail(64) for n, *_ in e["parts"] if n == "harvest/emit")
+    assert emits == len(rounds)  # every round dispatched was harvested, once
+    if kind == "block":  # (its prefill yields no token: no pick, no fetch)
+        assert not any(n.startswith("prefill/first_") for e in eng._flight.tail(64)
+                       for n, *_ in e["parts"])
+
+
 PHASES_1S = dict(schedule=0.1, prefill=0.2, dispatch=0.3, device_wait=0.3, harvest=0.1)
 
 
@@ -188,6 +268,38 @@ PHASES_1S = dict(schedule=0.1, prefill=0.2, dispatch=0.3, device_wait=0.3, harve
 def test_recorder_refuses_intervals_that_do_not_tile(intervals):
     with pytest.raises(AssertionError):
         FlightRecorder(4).record(1, 10.0, 1.0, intervals=intervals, **PHASES_1S)
+
+
+TILING_1S = [("schedule", 0.0, 0.1), ("prefill", 0.1, 0.3), ("dispatch", 0.3, 0.45),
+             ("device_wait", 0.45, 0.75), ("harvest", 0.75, 0.8), ("dispatch", 0.8, 0.95),
+             ("harvest", 0.95, 1.0)]
+
+
+@pytest.mark.parametrize("parts", [
+    [("prefill/call", 0.2, 0.3), ("prefill/operands", 0.1, 0.2)],        # out of order
+    [("prefill/operands", 0.1, 0.25), ("prefill/call", 0.2, 0.3)],       # overlap
+    [("dispatch/call", 0.25, 0.4)],                                      # starts in another phase
+    [("dispatch/capacity", 0.4, 0.85)],                                  # straddles two intervals
+    [("harvest/emit", 0.45, 0.5)],                                       # inside another phase
+    [("prefill/sleep", 0.1, 0.2)],                                       # unknown part
+    [("device_wait/fetch", 0.5, 0.6)],                                   # a phase without parts
+    [("prefill/call", 0.25, 0.2)],                                       # ends before it starts
+])
+def test_recorder_refuses_parts_that_do_not_fit_their_phase(parts):
+    with pytest.raises(AssertionError):
+        FlightRecorder(4).record(1, 10.0, 1.0, intervals=TILING_1S, parts=parts, **PHASES_1S)
+
+
+def test_recorder_keeps_parts_that_fit_and_no_field_where_none_is_given():
+    parts = [("prefill/operands", 0.1, 0.15), ("prefill/call", 0.15, 0.3),
+             ("dispatch/capacity", 0.3, 0.45), ("harvest/emit", 0.75, 0.78),
+             ("dispatch/capacity", 0.8, 0.8), ("dispatch/call", 0.9, 0.95),
+             ("harvest/close", 0.95, 1.0)]
+    entry = FlightRecorder(4).record(1, 10.0, 1.0, intervals=TILING_1S, parts=parts, **PHASES_1S)
+    assert entry["parts"] == parts
+    assert FlightRecorder(4).record(1, 10.0, 1.0, intervals=TILING_1S, parts=[],
+                                    **PHASES_1S)["parts"] == []
+    assert "parts" not in FlightRecorder(4).record(1, 10.0, 1.0, intervals=TILING_1S, **PHASES_1S)
 
 
 def test_recorder_keeps_repeated_phases_and_the_wall_clock_anchor():
@@ -451,9 +563,9 @@ class _CountingClock:
 @pytest.mark.parametrize("flight_on", [True, False])
 def test_step_reads_the_clock_a_pinned_number_of_times_and_writes_no_file(
         tiny_model, monkeypatch, tmp_path, flight_on):
-    """No profiler session, no Tracer: a phase boundary is ONE clock read
-    (none with the recorder off), an iteration one wall-clock read, a
-    prefill chunk two, an emitted token one; nothing is opened for writing."""
+    """No profiler session, no Tracer: a phase or part boundary is ONE clock
+    read (none with the recorder off), an iteration one wall-clock read, an
+    emitted token one; nothing is opened for writing."""
     import builtins
 
     from accelerate_tpu.serving import engine as engine_mod
@@ -471,8 +583,90 @@ def test_step_reads_the_clock_a_pinned_number_of_times_and_writes_no_file(
     eng.step()  # a pure decode iteration: schedule, prefill (empty), dispatch, wait, harvest
     tokens = eng._tokens_emitted - tokens0
     assert tokens == 2  # decode_burst
-    # boundaries: begin, ->prefill, ->dispatch, ->device_wait, ->harvest, ->harvest, finish
+    # boundaries: begin, ->prefill, ->dispatch, ->device_wait, ->harvest, ->harvest, finish;
+    # parts between them: dispatch/capacity, /operands, /call and its end, harvest/emit's
+    # end (harvest/emit and harvest/close open on their phase's read, /close ends on finish's)
     # (recorder off: the usage ledger stamps the device wait itself, twice)
-    assert clock.reads["perf_counter"] == (7 if flight_on else 2) + tokens
+    assert clock.reads["perf_counter"] == (7 + 5 if flight_on else 2) + tokens
     assert clock.reads["time_ns"] == (1 if flight_on else 0)
     assert not opened and not os.listdir(tmp_path)
+
+
+class _TickingClock:
+    """Stands in for the engine module's ``time``: every ``perf_counter``
+    read is the next whole second, so that sums and differences of stamps are
+    exact and two observers agree only if they took the same read."""
+
+    def __init__(self):
+        self.now = 1000.0
+
+    def perf_counter(self):
+        self.now += 1.0
+        return self.now
+
+    def __getattr__(self, name):
+        return getattr(time, name)
+
+
+@pytest.mark.parametrize("flight_on", [True, False])
+def test_a_chunks_observers_take_the_part_boundaries_reads(
+        tiny_model, monkeypatch, tmp_path, flight_on):
+    """``own_prefill_s``, the usage ledger's prefill seconds, the
+    ``req/prefill_chunk`` event and ``first_token_time`` are the floats that
+    opened the chunk's first part and its ``prefill/emit``: a chunk reads the
+    clock once a part boundary (5 reads, 7 on the last chunk) and twice with
+    the recorder off, where the two reads are those observers' own."""
+    from accelerate_tpu.diagnostics.tracing import Tracer, parse_trace_file, set_active_tracer
+    from accelerate_tpu.serving import engine as engine_mod
+
+    eng = _engine(tiny_model, flight_history=8 if flight_on else 0, async_dispatch=False,
+                  max_seq_len=32)
+    eng.add_request(np.arange(12, dtype=np.int32), max_new_tokens=1)
+    eng.run_until_idle(max_iterations=50)  # compiles
+    accrued = []
+    monkeypatch.setattr(eng.usage, "accrue_prefill", lambda req, dt: accrued.append(dt))
+    clock = _TickingClock()
+    monkeypatch.setattr(engine_mod, "time", clock)
+    tracer = Tracer(logging_dir=str(tmp_path), host=0)
+    set_active_tracer(tracer)
+    try:
+        req = eng.add_request(np.arange(12, dtype=np.int32) + 1, max_new_tokens=1)
+        stamps, chunk = [], eng._prefill_one_chunk
+
+        def timed_chunk(*args):
+            before = clock.now
+            chunk(*args)
+            stamps.append((before, clock.now))
+
+        monkeypatch.setattr(eng, "_prefill_one_chunk", timed_chunk)
+        eng.step()  # a chunk of 8,
+        eng.step()  # the final chunk of 4 and the one token
+    finally:
+        tracer.close()
+        set_active_tracer(None)
+    events = parse_trace_file(tracer.path)
+    chunk_ts = [e["ts"] / 1e6 for e in events if e["name"] == "req/prefill_chunk"]
+    assert len(chunk_ts) == 2 and req.first_token_time == chunk_ts[1]
+    if not flight_on:
+        # two reads a chunk, whatever it ran: its first part's and its emit's
+        assert [hi - lo for lo, hi in stamps] == [2.0, 2.0]
+        assert chunk_ts == [hi for _, hi in stamps]
+        assert accrued == [1.0, 1.0] and req.own_prefill_s == 2.0
+        return
+    assert [hi - lo for lo, hi in stamps] == [5.0, 7.0]
+    spans = {name: [(e["ts"] / 1e6, (e["ts"] + e["dur"]) / 1e6) for e in events
+                    if e.get("ph") == "X" and e["name"] == "serve/prefill/" + name]
+             for name in ITERATION_PARTS["prefill"]}
+    assert [len(spans[n]) for n in ITERATION_PARTS["prefill"]] == [2, 2, 1, 1, 2]
+    opened = [lo for lo, _ in spans["operands"]]
+    emit = [lo for lo, _ in spans["emit"]]
+    assert chunk_ts == emit                                  # the event's ts IS emit's opening read
+    assert accrued == [e - o for o, e in zip(opened, emit)] == [3.0, 5.0]
+    assert req.own_prefill_s == sum(accrued)
+    # one read a boundary: operands, call, its end, [first_pick, first_fetch,] emit, its end
+    assert [(lo, hi) for (lo, _), (_, hi) in zip(spans["operands"], spans["emit"])] == \
+        [(lo + 1.0, hi) for lo, hi in stamps]
+    first = eng._flight.tail(8)[-2]  # the flight rows are the same reads, from the iteration's start
+    assert [(n, first["t_start"] + a) for n, a, _ in first["parts"] if n.startswith("prefill/")] \
+        == [("prefill/operands", opened[0]), ("prefill/call", opened[0] + 1.0),
+            ("prefill/emit", emit[0])]
