@@ -8,6 +8,9 @@ for XLA to fuse (VPU), and everything is static-shape.
 
 from __future__ import annotations
 
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -113,23 +116,162 @@ def shift_labels(labels: jax.Array, ignore_index: int = -100) -> jax.Array:
     return jnp.concatenate([labels[:, 1:], pad], axis=1)
 
 
+#: float32 logits one device may hold for one chunk of the sweep below
+_CE_CHUNK_LOGIT_BYTES = 256 << 20
+
+
+def _constrain(x, sharding):
+    """Sharding constraint; ``None`` (no mesh, or nothing to pin) is a no-op."""
+    return x if sharding is None else jax.lax.with_sharding_constraint(x, sharding)
+
+
+def _ce_layout(vocab: int, head_shape: tuple):
+    """How the chunk sweep lies on the mesh this trace runs under:
+    ``(devices, rows, logits, head, out)``. The vocabulary is spread over
+    the mesh's ``fsdp`` and ``tp`` axes (the links a weight's shards
+    already cross) and a chunk's rows are gathered over them, staying
+    apart over ``dp``: each device multiplies ``[rows, h]`` by the
+    ``[h, vocab / n]`` it holds, and neither the head nor its gradient
+    crosses a link inside the loop. ``devices`` is what one chunk's logits
+    are spread over; the four specs (a chunk of ``x`` in the loop, its
+    logits, ``head`` and its gradient, a chunk of ``dx`` on leaving) are
+    ``NamedSharding``s on that mesh - the program enters no mesh context, so
+    a bare ``PartitionSpec`` would pin nothing - and ``None`` outside a
+    mesh, or where ``vocab`` does not divide or ``head`` has no dimension
+    of that length."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from .attention import get_attention_context
+
+    ctx = get_attention_context()
+    sizes = dict(ctx.mesh.shape) if ctx.mesh is not None else {}
+    over = tuple(ax for ax in ("fsdp", ctx.head_axis) if sizes.get(ax, 1) > 1)
+    batch = tuple(ax for ax in ctx.batch_axes if sizes.get(ax, 1) > 1)
+    apart = tuple(ax for ax in batch if ax not in over)
+    shards = math.prod(sizes[ax] for ax in over)
+    n = shards * math.prod(sizes[ax] for ax in apart)
+    if not over or vocab % shards or len(head_shape) != 2 or vocab not in head_shape:
+        return n, None, None, None, None
+    head_spec = P(None, over) if head_shape[1] == vocab else P(over, None)
+    specs = (P(apart or None, None, None), P(apart or None, None, over), head_spec,
+             P(batch or None, None, None))
+    return (n, *(NamedSharding(ctx.mesh, spec) for spec in specs))
+
+
+def _ce_chunks(b: int, s: int, vocab: int, devices: int, chunk_tokens: int) -> int:
+    """Chunks the sequence is cut into: the fewest that divide ``s`` and
+    leave one device at most ``chunk_tokens`` rows of a chunk (fewer where
+    their float32 logits would pass ``_CE_CHUNK_LOGIT_BYTES``)."""
+    rows = max(1, min(chunk_tokens, _CE_CHUNK_LOGIT_BYTES // (4 * vocab))) * devices
+    return min((c for c in range(1, s + 1) if s % c == 0 and b * (s // c) <= rows), default=s)
+
+
+def _ce_split(x, labels, chunks: int, ignore_index):
+    """``x`` and ``labels`` chunk-major, and one over the count of valid labels."""
+    b, s = labels.shape
+    xc = jnp.moveaxis(x.reshape(b, chunks, s // chunks, x.shape[-1]), 1, 0)
+    lc = jnp.moveaxis(labels.reshape(b, chunks, s // chunks), 1, 0)
+    return xc, lc, 1.0 / jnp.maximum((labels != ignore_index).sum(), 1).astype(jnp.float32)
+
+
+def _ce_chunk_nll(logits, l_i, ignore_index):
+    """``(summed nll, float32 log-softmax, valid, safe labels)`` of a chunk."""
+    logits = logits.astype(jnp.float32)
+    valid = l_i != ignore_index
+    safe = jnp.where(valid, l_i, 0)
+    logp = logits - jax.nn.logsumexp(logits, axis=-1, keepdims=True)
+    gold = jnp.take_along_axis(logp, safe[..., None], axis=-1)[..., 0]
+    return -(gold * valid).sum(), logp, valid, safe
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _chunked_cross_entropy(dense_fn, ignore_index, chunks, layout, x, head, labels):
+    rows, logits_spec, head_spec, _ = layout
+    xc, lc, inv = _ce_split(x, labels, chunks, ignore_index)
+    head = _constrain(head, head_spec)
+
+    def body(nll, xs):
+        x_i, l_i = xs
+        logits = _constrain(dense_fn(_constrain(x_i, rows), head), logits_spec)
+        return nll + _ce_chunk_nll(logits, l_i, ignore_index)[0], None
+
+    nll, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), (xc, lc))
+    return nll * inv
+
+
+def _chunked_cross_entropy_fwd(dense_fn, ignore_index, chunks, layout, x, head, labels):
+    """One sweep for value AND gradients: a chunk's ``dlogits`` are made
+    where its logits already are and pulled straight back through
+    ``dense_fn``, so the backward rule only scales what is kept. The sums
+    stay unnormalised (``dlogits`` in [-1, 1], whatever the product's
+    dtype) until the backward rule knows the cotangent."""
+    rows, logits_spec, head_spec, out = layout
+    xc, lc, inv = _ce_split(x, labels, chunks, ignore_index)
+    head = _constrain(head, head_spec)
+
+    def body(carry, xs):
+        nll, dw = carry
+        x_i, l_i = xs
+        logits, pull = jax.vjp(dense_fn, _constrain(x_i, rows), head)
+        logits = _constrain(logits, logits_spec)
+        d_nll, logp, valid, safe = _ce_chunk_nll(logits, l_i, ignore_index)
+        onehot = jax.lax.broadcasted_iota(jnp.int32, logp.shape, logp.ndim - 1) == safe[..., None]
+        dlogits = jnp.where(valid[..., None], jnp.exp(logp) - onehot, 0.0)
+        dx_i, dw_i = pull(_constrain(dlogits.astype(logits.dtype), logits_spec))
+        dw = _constrain(dw + dw_i.astype(jnp.float32), head_spec)
+        return (nll + d_nll, dw), _constrain(dx_i, out)
+
+    dw0 = _constrain(jnp.zeros(head.shape, jnp.float32), head_spec)
+    (nll, dw), dxc = jax.lax.scan(body, (jnp.zeros((), jnp.float32), dw0), (xc, lc))
+    dx = jnp.moveaxis(dxc, 0, 1).reshape(x.shape)
+    return nll * inv, (dx, dw, inv, jnp.zeros((0,), head.dtype))
+
+
+def _chunked_cross_entropy_bwd(dense_fn, ignore_index, chunks, layout, kept, g):
+    dx, dw, inv, head_like = kept
+    scale = g.astype(jnp.float32) * inv
+    return (
+        (dx.astype(jnp.float32) * scale).astype(dx.dtype),
+        (dw * scale).astype(head_like.dtype),
+        None,
+    )
+
+
+_chunked_cross_entropy.defvjp(_chunked_cross_entropy_fwd, _chunked_cross_entropy_bwd)
+
+
 def fused_cross_entropy(
     x: jax.Array,  # [b, s, h] final hidden states (pre-head)
-    head: jax.Array,  # [h, vocab]
+    head: jax.Array,  # what ``dense_fn`` takes beside ``x``: [h, vocab], or a tied [vocab, h]
     labels: jax.Array,  # [b, s] int; -100 = ignore (already shifted)
     ignore_index: int = -100,
     chunk_tokens: int = 1024,
     dense_fn=None,
 ) -> jax.Array:
     """Token CE computed from pre-head hidden states without ever holding
-    the full ``[b, s, vocab]`` logits: the head matmul + fp32 log-softmax
-    run one sequence chunk at a time under ``lax.scan`` +
-    ``jax.checkpoint``, so forward AND backward materialise only
-    ``~chunk_tokens × vocab`` at once. The backward pass recomputes each
-    chunk's logits and the scan transpose accumulates the head gradient
-    across chunks — the standard fused-CE memory/FLOPs trade that unlocks
-    larger per-chip batches (the [b,s,V] buffer, not the matmul, is what
-    capped them).
+    the full ``[b, s, vocab]`` logits: one ``lax.scan`` over sequence chunks
+    runs the head product (``dense_fn``, default ``jnp.matmul``) and the
+    fp32 log-softmax of a chunk.
+
+    Under differentiation the same sweep also makes that chunk's gradients
+    (a ``jax.custom_vjp``): ``dlogits = (softmax - onehot) * valid`` where
+    the logits already are, pulled back through ``dense_fn`` for the chunk's
+    ``dx`` and for ``dW``, which is summed over chunks in float32. The
+    backward rule multiplies the kept ``dx`` (the size of ``x``) and ``dW``
+    (the size of ``head``) by the cotangent over the count of valid labels.
+    Three products a chunk, nothing recomputed; reverse mode only.
+
+    ``chunk_tokens`` is the most rows of one chunk that ONE device gets: a
+    chunk is the longest cut of the sequence (a divisor of ``s``) that holds
+    at most ``chunk_tokens`` x the devices of the mesh the trace runs under
+    (``dp``, ``fsdp``, ``tp``) rows of the global batch, fewer where one
+    device's float32 logits of it would pass 256 MiB. Under a mesh the
+    vocabulary is spread over ``fsdp`` and ``tp`` (the head is resharded
+    once, before the loop, where it is stored otherwise), a chunk's rows
+    are gathered over those axes, and ``dW`` is born on the head's
+    vocabulary shards: no collective inside the loop touches an array of
+    the head's size. A batch no larger than one chunk takes the plain
+    single-shot loss.
 
     Numerically identical to ``cross_entropy_loss(dense_fn(x, head),
     labels)`` (same fp32 log-softmax, same masked mean).
@@ -137,37 +279,12 @@ def fused_cross_entropy(
     if dense_fn is None:
         dense_fn = jnp.matmul
     b, s, h = x.shape
-
-    # largest divisor of s giving chunks of >= ~chunk_tokens tokens; C == 1
-    # (e.g. tiny test shapes) degenerates to the plain single-shot loss
-    rows = max(1, chunk_tokens // b)
-    C = 1
-    for c in range(1, s + 1):
-        if s % c == 0 and s // c >= rows:
-            C = c
-    if C == 1:
+    vocab = jax.eval_shape(dense_fn, jax.ShapeDtypeStruct((b, 1, h), x.dtype), head).shape[-1]
+    devices, *layout = _ce_layout(vocab, head.shape)
+    chunks = _ce_chunks(b, s, vocab, devices, chunk_tokens)
+    if chunks == 1:
         return cross_entropy_loss(dense_fn(x, head), labels, ignore_index)
-
-    xc = jnp.moveaxis(x.reshape(b, C, s // C, h), 1, 0)  # [C, b, s/C, h]
-    lc = jnp.moveaxis(labels.reshape(b, C, s // C), 1, 0)
-
-    def chunk_fn(x_i, l_i):
-        logits = dense_fn(x_i, head).astype(jnp.float32)  # [b, s/C, V]
-        valid = l_i != ignore_index
-        safe = jnp.where(valid, l_i, 0)
-        logz = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, safe[..., None], axis=-1)[..., 0]
-        return ((logz - gold) * valid).sum(), valid.sum()
-
-    def body(carry, xs):
-        nll, cnt = carry
-        d_nll, d_cnt = jax.checkpoint(chunk_fn)(*xs)
-        return (nll + d_nll, cnt + d_cnt), None
-
-    (nll, count), _ = jax.lax.scan(
-        body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32)), (xc, lc)
-    )
-    return nll / jnp.maximum(count, 1)
+    return _chunked_cross_entropy(dense_fn, ignore_index, chunks, tuple(layout), x, head, labels)
 
 
 def write_kv_cache(k_cache_l, v_cache_l, k, v, idx, pin_replicated: bool = False):

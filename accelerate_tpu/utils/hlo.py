@@ -216,3 +216,45 @@ def buffers_moved(text: str, numels) -> dict:
         "moved": moved,
         "unaliased": sorted(p for p in entry_params if p not in aliased),
     }
+
+
+_HLO_CALLEES = re.compile(r"\b(?:calls|to_apply|body|condition)=%?([\w.-]+)")
+_HLO_WHILE_BODY = re.compile(r"\bwhile\(.*\bbody=%?([\w.-]+)")
+
+
+def loop_instructions(text: str) -> dict[str, list[tuple[str, str, int, str]]]:
+    """``{while body: [(instruction, opcode, result elements, op_name)]}``
+    of a compiled module's text: every instruction that runs inside each
+    loop, those of the computations the body calls (fusions, reducers,
+    nested loops) among them. ``result elements`` counts the largest array
+    of the result. What a test reads to say "no collective over an operand
+    of that size sits inside the loop" or "a round costs three products"."""
+    computations: dict[str, list[str]] = {}
+    current: list[str] | None = None
+    for line in text.splitlines():
+        head = _HLO_COMPUTATION.match(line)
+        if head:
+            current = computations.setdefault(head.group(1), [])
+        elif current is not None and line.startswith(" "):
+            current.append(line)
+
+    def reach(name: str, seen: set) -> set:
+        if name in computations and name not in seen:
+            seen.add(name)
+            for line in computations[name]:
+                for callee in _HLO_CALLEES.findall(line):
+                    reach(callee, seen)
+        return seen
+
+    out: dict[str, list[tuple[str, str, int, str]]] = {}
+    for body in sorted(set(_HLO_WHILE_BODY.findall(text))):
+        rows = out.setdefault(body, [])
+        for name in sorted(reach(body, set())):
+            for line in computations[name]:
+                m = _HLO_RESULT.match(line)
+                if m:
+                    numel = max((_numel(t.group(2), ",") for t in _HLO_SHAPE.finditer(m.group(3))),
+                                default=0)
+                    scope = _HLO_OP_NAME.search(line)
+                    rows.append((m.group(2), m.group(4), numel, scope.group(1) if scope else ""))
+    return out

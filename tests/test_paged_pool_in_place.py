@@ -190,14 +190,19 @@ def test_buffers_moved_reads_compiled_text(line, moved):
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # no libtpu here, or another process holds it
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -448,3 +453,77 @@ def test_v5e_compiled_block_step_walks_to_the_blocks_end(one_chip, monkeypatch, 
     found = buffers_moved(text, [w_in, w_in // 2, w_out, w_out // 2, 2 * blocks * bs * 512,
                                  blocks * bs * 512])
     assert found["moved"] == [], found["moved"]
+
+
+def test_v5e4_train_step_moves_no_head_sized_array_inside_the_loss_loop(topo):
+    """The train cell's step (Mistral-7B widths, 8 x 4,096 tokens, bf16
+    compute over float32 parameters, adamw, ``fsdp=4``, ``remat``; ONE
+    layer) compiled for the four described chips the way the
+    ``Accelerator`` compiles it: parameters placed by the model's own rules
+    (``lm_head`` on its HIDDEN dimension) and no mesh context entered.
+    GSPMD left alone gathers the whole head and all-reduces its whole
+    gradient in every chunk of the loss's loop (the step before ISSUE 41,
+    and the sweep before its layout was pinned); pinned, the loop holds the
+    three products of a chunk and nothing that moves an array of the
+    head's size."""
+    import re
+
+    import optax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from accelerate_tpu.models.llama import LLAMA_PARTITION_RULES, init_llama_params, llama_apply
+    from accelerate_tpu.ops.attention import attention_context
+    from accelerate_tpu.parallel.sharding import infer_param_sharding, opt_state_sharding_like
+    from accelerate_tpu.utils.dataclasses import MESH_AXIS_ORDER, FullyShardedDataParallelPlugin
+    from accelerate_tpu.utils.hlo import loop_instructions
+
+    c = LlamaConfig(
+        vocab_size=32768, hidden_size=4096, intermediate_size=14336,
+        num_hidden_layers=1, num_attention_heads=32, num_key_value_heads=8,
+        max_position_embeddings=32768, rope_theta=1e6, tie_word_embeddings=False, remat=True,
+    )
+    mesh = Mesh(
+        np.asarray(topo.devices).reshape(tuple(4 if ax == "fsdp" else 1 for ax in MESH_AXIS_ORDER)),
+        MESH_AXIS_ORDER)
+    abstract = jax.eval_shape(lambda: init_llama_params(jax.random.PRNGKey(0), c))
+    placed = infer_param_sharding(
+        abstract, mesh, FullyShardedDataParallelPlugin(), LLAMA_PARTITION_RULES)
+    assert placed["lm_head"].spec == P("fsdp", "tp")  # tp is 1 here: the hidden dimension
+    shaped = lambda tree, shardings: jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s), tree, shardings)
+    params = shaped(abstract, placed)
+    tx = optax.adamw(3e-4)
+    opt_placed = opt_state_sharding_like(tx, params, placed, mesh)
+    opt_state = shaped(jax.eval_shape(tx.init, params), opt_placed)
+    ids = jax.ShapeDtypeStruct((8, 4096), jnp.int32,
+                               sharding=NamedSharding(mesh, P(("dp", "fsdp"), None)))
+
+    def step(params, opt_state, ids):
+        def loss_fn(p):
+            p = jax.tree.map(lambda a: a.astype(jnp.bfloat16), p)
+            return llama_apply(c, p, input_ids=ids, labels=ids)["loss"].astype(jnp.float32)
+
+        loss, grads = jax.value_and_grad(loss_fn)(params)
+        updates, opt_state = tx.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, loss
+
+    # the CPU backend would pick the blockwise attention route, which does
+    # not fit a chip at 4k tokens; the cell runs the flash kernels
+    with attention_context(mesh=mesh, impl="flash"):
+        text = jax.jit(step, donate_argnums=(0, 1), out_shardings=(placed, opt_placed, None)).lower(
+            params, opt_state, ids).compile().as_text()
+
+    collective = re.compile(r"all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute")
+    head = c.hidden_size * c.vocab_size
+    sweeps = 0
+    for body, rows in loop_instructions(text).items():
+        products = [r for r in rows if r[1] in ("dot", "convolution") and "head" in r[3]]
+        if not products:
+            continue  # a layer's or a kernel's loop
+        sweeps += 1
+        assert len(products) == 3, (body, products)
+        moved = [r for r in rows if collective.match(r[1]) and r[2] >= head]
+        assert not moved, (body, moved)
+        # a chunk is 1,024 rows a chip, 8 x 512 positions gathered: the logits are [8, 512, 32768 / 4]
+        assert max(r[2] for r in products) == 8 * 512 * 8192
+    assert sweeps == 1
