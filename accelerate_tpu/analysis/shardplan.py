@@ -449,8 +449,11 @@ def plan_kv_pool(
     mesh_sizes: dict[str, int],
     num_blocks: int | None = None,
     dtype: str = "float32",
+    pool_leaves: tuple = ("k", "v"),
 ) -> list[LeafPlan]:
-    """Placement plan for the serving engine's two paged pools, mirroring
+    """Placement plan for the serving engine's two paged pools (``pool_leaves``
+    ``("k",)``: a latent spec's one pool — ``num_kv_heads`` 1, ``head_dim``
+    its stored ``pool_width`` — which is never sharded), mirroring
     :func:`parallel.sharding.paged_kv_sharding`: the pools are stored
     lane-folded (``[layers, num_blocks, block_size, n_kv*head_dim]``) and
     the folded dim goes over ``tp`` — whole kv heads per shard — when
@@ -492,10 +495,10 @@ def plan_kv_pool(
             bytes_per_device=nbytes // divisor,
         )
 
-    leaves = [_leaf(name, shape, dtype) for name in ("k", "v")]
+    leaves = [_leaf(name, shape, dtype) for name in pool_leaves]
     if quantized:
         scale_shape = (num_layers, num_blocks, block_size, num_kv_heads)
-        leaves += [_leaf(name, scale_shape, "float32") for name in ("k_scale", "v_scale")]
+        leaves += [_leaf(name + "_scale", scale_shape, "float32") for name in pool_leaves]
     return leaves
 
 
@@ -1026,6 +1029,7 @@ def engine_preflight(
     draft_layers: int | None = None,
     stacked_prefix: str = "layers",
     state_bytes: int = 0,
+    pool_leaves: tuple = ("k", "v"),
 ) -> dict:
     """The serving engine's capacity check, run BEFORE the pools allocate:
     predicted per-device bytes of params (under the same planner
@@ -1066,6 +1070,7 @@ def engine_preflight(
         max_seq_len=pool_shape[2],
         mesh_sizes=sizes,
         dtype=str(np.dtype(pool_dtype)),
+        pool_leaves=pool_leaves,
     )
     pool_bytes = sum(p.bytes_per_device for p in pool_plans)
     budget = int(hbm_budget_gb * (1 << 30))

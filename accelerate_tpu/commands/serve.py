@@ -205,13 +205,14 @@ def _auto_num_blocks(args, model, mesh) -> int:
         for p in plan_kv_pool(
             num_layers=spec.paged_layers,
             num_kv_heads=spec.kv_heads,
-            head_dim=spec.head_dim,
+            head_dim=spec.pool_width // spec.kv_heads,
             num_slots=1,
             block_size=args.block_size,
             max_seq_len=args.max_seq_len,
             num_blocks=1,
             mesh_sizes=sizes,
             dtype=_plan_kv_dtype(args),
+            pool_leaves=spec.pool_leaves,
         )
     )
     blocks_per_slot = blocks_needed(args.max_seq_len, args.block_size)

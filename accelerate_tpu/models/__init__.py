@@ -121,7 +121,7 @@ def __getattr__(name):
 #: the ``model_type`` values :func:`config_from_hf_json` maps
 KNOWN_MODEL_TYPES = (
     "llama", "mistral", "gpt2", "bert", "vit", "opt", "gpt_neox", "gptj", "mixtral",
-    "t5", "mt5", "granitemoehybrid", "lfm2_moe", "sdar_moe",
+    "t5", "mt5", "granitemoehybrid", "lfm2_moe", "sdar_moe", "deepseek_v3",
 )
 
 
@@ -308,6 +308,16 @@ def config_from_hf_json(path: str):
                 )
         fields = {f.name for f in dataclasses.fields(SdarMoeConfig)} - {"remat"}
         return SdarMoeConfig(**{k: d[k] for k in fields if d.get(k) is not None})
+    if mt == "deepseek_v3":
+        from .deepseek_v3 import DeepseekV3Config
+
+        # the published keys by their names; what cannot be built as published (a
+        # scoring_func other than sigmoid, a topk_method other than noaux_tc, a
+        # rope_scaling type other than yarn, ...) is refused by the config itself.
+        # num_nextn_predict_layers (the multi-token-prediction block) and
+        # num_key_value_heads (latent attention has no kv head) are read by nothing
+        fields = {f.name for f in dataclasses.fields(DeepseekV3Config)} - {"remat"}
+        return DeepseekV3Config(**{k: d[k] for k in fields if d.get(k) is not None})
     raise ValueError(
         f"unsupported model_type {mt!r} (known: {', '.join(KNOWN_MODEL_TYPES)})"
     )
@@ -331,6 +341,10 @@ def model_factory_for_config(config):
         from .sdar_moe import SdarMoeForCausalLM
 
         return lambda c, **kw: SdarMoeForCausalLM.from_config(c, **kw)
+    if name == "DeepseekV3Config":
+        from .deepseek_v3 import DeepseekV3ForCausalLM
+
+        return lambda c, **kw: DeepseekV3ForCausalLM.from_config(c, **kw)
     if name == "GPT2Config":
         from .gpt2 import GPT2LMHeadModel
 

@@ -1,12 +1,16 @@
 """What a served model keeps for a sequence, declared by the model and
 allocated by the serving engine.
 
-Two kinds of cache exist: ``paged`` — K and V per token, in blocks of a
-shared pool, for the layers that attend — and ``slot_state`` — arrays of a
-fixed size per slot (a recurrent state, a convolution's tail) for the
-layers that carry one. A model sets ``model.cache_spec``; one without the
-attribute is every-layer-paged (:func:`cache_spec_of`). The engine's pool
-is ``[paged_layers, num_blocks, block_size, kv_heads * head_dim]`` and each
+Three kinds of cache exist: ``paged`` — K and V per token, in blocks of a
+shared pool, for the layers that attend —, ``latent`` — paged too, but ONE
+vector per token and layer that every query head shares and whose leading
+entries are also the values (multi-head latent attention: no kv head, no
+separate V) — and ``slot_state`` — arrays of a fixed size per slot (a
+recurrent state, a convolution's tail) for the layers that carry one. A
+model sets ``model.cache_spec``; one without the attribute is
+every-layer-paged (:func:`cache_spec_of`). The engine's pool is
+``[paged_layers, num_blocks, block_size, kv_heads * head_dim]`` — the leaves
+``"k"`` and ``"v"``, or the one leaf ``"k"`` of a latent spec — and each
 slot-state leaf ``[layers, num_slots, *shape]``; the step programs get both
 in one donated dict (``paged_kv=``) and hand it back whole.
 """
@@ -50,6 +54,42 @@ class CacheSpec:
     #: name -> leaf, the per-slot state beside the pool (none: blocks are
     #: the whole of a request's past)
     slot_state: dict = field(default_factory=dict)
+    #: above 0 the paged layers are latent: ``kv_heads`` is 1, ``head_dim``
+    #: the whole vector kept a token (DeepSeek-V3: 512 + 64 rotated = 576),
+    #: and its first ``latent_rank`` entries are what attention sums as the
+    #: values, so the pool is the one leaf ``"k"`` and nothing is kept twice
+    latent_rank: int = 0
+
+    def __post_init__(self):
+        if self.latent_rank and (self.kv_heads != 1 or not 0 < self.latent_rank <= self.head_dim):
+            raise ValueError(
+                f"a latent cache keeps one vector a token for all heads: kv_heads "
+                f"{self.kv_heads} (want 1), latent_rank {self.latent_rank} of head_dim "
+                f"{self.head_dim}")
+
+    @property
+    def pool_leaves(self) -> tuple:
+        """The pool's arrays by their names in the cache dict (a quantized
+        pool adds ``<leaf>_scale`` beside each)."""
+        return ("k",) if self.latent_rank else ("k", "v")
+
+    @property
+    def pool_width(self) -> int:
+        """The minor dimension of a pool leaf: the kv heads folded into the
+        lanes; a latent row padded with zeros to whole tiles of 128 lanes
+        (576 -> 640), which is the room a TPU gives it whatever shape it is
+        declared with, and the only width the paged kernels may slice out of
+        the pool. The padding is stored, and priced, everywhere."""
+        width = self.kv_heads * self.head_dim
+        return -(-width // 128) * 128 if self.latent_rank else width
+
+    def bytes_per_token(self, store_dtype, quantized: bool = False) -> int:
+        """Bytes one cached token costs across the paged layers: every pool
+        leaf's stored row, and a float32 scale a row and kv head where the
+        pool is quantized."""
+        return len(self.pool_leaves) * self.paged_layers * (
+            self.pool_width * np.dtype(store_dtype).itemsize
+            + (4 * self.kv_heads if quantized else 0))
 
     def state_bytes_per_slot(self, compute_dtype) -> int:
         return sum(leaf.bytes_per_slot(compute_dtype) for leaf in self.slot_state.values())

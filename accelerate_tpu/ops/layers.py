@@ -413,6 +413,51 @@ def cached_attention(q, k_cache, v_cache, idx, block_len: int = 1):
     return out.reshape(b, s, nh, hd).astype(q.dtype)
 
 
+def yarn_frequencies(dim: int, theta: float, factor: float, original: int,
+                     beta_fast: float = 32.0, beta_slow: float = 1.0) -> np.ndarray:
+    """The ``dim // 2`` rotary frequencies under YaRN scaling (Peng et al.,
+    2023, as DeepSeek-V3 publishes it): lane pair ``i`` turns at ``f_i =
+    theta^(-2i/dim)``; the pairs that turn more than ``beta_fast`` times over
+    the ``original`` context keep their frequency, those that turn fewer than
+    ``beta_slow`` times are stretched ``factor`` x, and a linear ramp over the
+    pair index joins the two. Float64 on the host: 32 numbers, no table of
+    positions (the angles are made from absolute positions in float32 where
+    they are used)."""
+    i = np.arange(dim // 2, dtype=np.float64)
+    f = theta ** (-2.0 * i / dim)
+
+    def pair_that_turns(n):
+        return dim * np.log(original / (2 * np.pi * n)) / (2 * np.log(theta))
+
+    lo = max(int(np.floor(pair_that_turns(beta_fast))), 0)
+    hi = min(int(np.ceil(pair_that_turns(beta_slow))), dim - 1)
+    ramp = np.clip((i - lo) / max(hi - lo, 1e-3), 0.0, 1.0)
+    return f / factor * ramp + f * (1.0 - ramp)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term, ``0.1 * mscale * ln(factor) + 1``
+    (1 where nothing is stretched)."""
+    return 0.1 * mscale * float(np.log(factor)) + 1.0 if factor > 1 else 1.0
+
+
+def cached_latent_attention(q, cache, idx, rank: int, scale: float):
+    """:func:`cached_attention` for a latent cache: ``q [b, s, nh, width]``
+    (each head's query already carried into the cache's coordinates: the
+    absorbed form) against ``cache [b, max_cache, width]``, one vector a
+    position for every head, whose first ``rank`` entries are also the
+    values. Scores ``q . cache * scale``, the same valid-prefix and causal
+    mask, float32 softmax; returns ``[b, s, nh, rank]``."""
+    b, s = q.shape[:2]
+    c = cache.astype(jnp.float32)
+    q_pos = idx[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
+    valid = jnp.arange(c.shape[1])[None, None, :] <= q_pos[:, :, None]      # [b, s, max]
+    scores = jnp.einsum("bqhd,bkd->bhqk", q.astype(jnp.float32), c) * scale
+    scores = jnp.where(valid[:, None], scores, jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("bhqk,bkd->bqhd", probs, c[..., :rank]).astype(q.dtype)
+
+
 # ---------------------------------------------------------------------------
 # Block-paged KV cache (serving engine)
 #
@@ -430,6 +475,50 @@ def cached_attention(q, k_cache, v_cache, idx, block_len: int = 1):
 # needs no dynamic shapes, and garbage written/read there is always masked
 # out by the per-slot valid prefix.
 # ---------------------------------------------------------------------------
+
+
+def _paged_row_scatter(pool, layer, block_tables, positions, write_mask):
+    """``put(pool, rows)`` for one step's rows ``[b, s, ...]`` of layer
+    ``layer`` at ``positions [b, s]`` through the block tables, under
+    :func:`write_paged_kv`'s drop rules; the same indices serve every array
+    that shares the pool's ``[layers, num_blocks, block_size, ...]`` shape."""
+    nb, bs = pool.shape[1], pool.shape[2]
+    b, s = positions.shape
+    positions = jnp.asarray(positions, jnp.int32)
+    blk = jnp.take_along_axis(
+        jnp.asarray(block_tables, jnp.int32), positions // bs, axis=1,
+        mode="fill", fill_value=nb,
+    )  # [b, s]; fill → a block id past the pool, which the scatter drops
+    if write_mask is not None:
+        blk = jnp.where(write_mask, blk, nb)  # out of range → dropped
+    blk = blk.reshape(b * s)
+    off = (positions % bs).reshape(b * s)
+    layer = jnp.asarray(layer, jnp.int32)
+
+    def put(pool, rows):
+        return pool.at[layer, blk, off].set(
+            rows.reshape(b * s, pool.shape[-1]), mode="drop"
+        )
+
+    return put
+
+
+def write_paged_latent(pool, layer, rows, block_tables, positions, write_mask=None,
+                       scale=None):
+    """A latent layer's twin of :func:`write_paged_kv`: ``rows [b, s, width]``
+    (the compressed vector and the rotated key of each token, one for all
+    heads) into layer ``layer`` of the ONE pool ``[layers, num_blocks,
+    block_size, width]``, same indices, same drop rules. With ``scale``
+    (``[layers, num_blocks, block_size, 1]`` float32: a quantized pool) each
+    row is amax-quantized into the pool's type and its one scale scattered
+    beside it. Returns ``(pool,)`` or ``(pool, scale)``."""
+    put = _paged_row_scatter(pool, layer, block_tables, positions, write_mask)
+    if scale is None:
+        return (put(pool, rows.astype(pool.dtype)),)
+    from .fp8 import quantize_kv_rows
+
+    rows, row_scale = quantize_kv_rows(rows, pool.dtype)        # [b, s, width] + [b, s]
+    return put(pool, rows), put(scale, row_scale[..., None])
 
 
 def write_paged_kv(
@@ -461,24 +550,7 @@ def write_paged_kv(
     and each row's scale is scattered through the *same* indices, so
     payload and scale stay atomic under the identical drop/masking rules.
     Returns 4 arrays in that case."""
-    nb, bs = k_pool.shape[1], k_pool.shape[2]
-    b, s = k.shape[0], k.shape[1]
-    positions = jnp.asarray(positions, jnp.int32)
-    blk = jnp.take_along_axis(
-        jnp.asarray(block_tables, jnp.int32), positions // bs, axis=1,
-        mode="fill", fill_value=nb,
-    )  # [b, s]; fill → a block id past the pool, which the scatter drops
-    if write_mask is not None:
-        blk = jnp.where(write_mask, blk, nb)  # out of range → dropped
-    blk = blk.reshape(b * s)
-    off = (positions % bs).reshape(b * s)
-    layer = jnp.asarray(layer, jnp.int32)
-
-    def put(pool, rows):
-        return pool.at[layer, blk, off].set(
-            rows.reshape(b * s, pool.shape[-1]), mode="drop"
-        )
-
+    put = _paged_row_scatter(k_pool, layer, block_tables, positions, write_mask)
     if k_scale is not None:
         from .fp8 import quantize_kv_rows
 

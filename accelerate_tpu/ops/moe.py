@@ -31,7 +31,10 @@ _HI = jax.lax.Precision.HIGHEST
 #: rows, contraction and columns of one grid step of the grouped product on
 #: the chip: a whole 128-row tile of pairs against ``[k, 512]`` of one
 #: expert (2 MB in bfloat16 at k = 2048), so that an expert's matrix passes
-#: through VMEM once for every 128 pairs it was given
+#: through VMEM once for every 128 pairs it was given. A contraction the tile
+#: does not divide takes the tile halved until it does (k = 7168: 1024): the
+#: product masks a part tile's remainder at every step, which read 24 %
+#: slower at DeepSeek-V3's decode shape (PERF.md section 6, PR 42)
 _GMM_TILING = (128, 2048, 512)
 
 
@@ -42,13 +45,20 @@ def default_moe_impl() -> str:
 
 
 def route(x, w_gate, expert_bias, top_k: int, norm_topk_prob: bool = True,
-          routed_scaling_factor: float = 1.0, scoring: str = "sigmoid"):
+          routed_scaling_factor: float = 1.0, scoring: str = "sigmoid",
+          n_group: int = 1, topk_group: int = 1, norm_eps: float = 1e-6):
     """``x [T, h]`` -> ``(experts [T, k] int32, weights [T, k] float32)``.
     The gate's product, the scores and the top-k run in float32 (in
     bfloat16 two scores tie). ``scoring``: ``"sigmoid"``, each expert scored
     alone, or ``"softmax"`` over all the experts. ``expert_bias [E]`` (or
     ``None`` where the model has none) moves which experts are chosen and
-    never the weights of those chosen."""
+    never the weights of those chosen. ``n_group`` above 1 limits the choice
+    to groups (DeepSeek-V3's ``noaux_tc``): the experts are ``n_group``
+    groups of consecutive ones, a group's mark is the sum of its two best
+    biased scores, and the top ``k`` are taken among the ``topk_group`` best
+    groups; the others' biased scores count as 0, not as minus infinity, as
+    the published code has it. At ``n_group`` 1 nothing of that is traced.
+    ``norm_eps`` guards the renormalisation's sum."""
     f32 = jnp.float32
     logits = jnp.dot(x.astype(f32), w_gate.astype(f32), precision=_HI)
     if scoring == "sigmoid":
@@ -58,10 +68,17 @@ def route(x, w_gate, expert_bias, top_k: int, norm_topk_prob: bool = True,
     else:
         raise ValueError(f"unknown router scoring {scoring!r}: want sigmoid or softmax")
     biased = scores if expert_bias is None else scores + expert_bias.astype(f32)
+    if n_group > 1:
+        t, e = biased.shape
+        grouped = biased.reshape(t, n_group, e // n_group)
+        marks = jax.lax.top_k(grouped, 2)[0].sum(axis=-1)                    # [T, groups]
+        _, kept = jax.lax.top_k(marks, topk_group)
+        keep = (kept[:, :, None] == jnp.arange(n_group, dtype=kept.dtype)).any(axis=1)
+        biased = jnp.where(keep[:, :, None], grouped, 0.0).reshape(t, e)
     _, experts = jax.lax.top_k(biased, top_k)
     weights = jnp.take_along_axis(scores, experts, axis=-1)
     if norm_topk_prob:
-        weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-6)
+        weights = weights / (weights.sum(axis=-1, keepdims=True) + norm_eps)
     return experts.astype(jnp.int32), weights * routed_scaling_factor
 
 
@@ -80,17 +97,21 @@ def grouped_matmul(lhs, rhs, group_sizes, impl: str | None = None,
 
         m, k = lhs.shape
         tm, tk, tn = _GMM_TILING
+        tk = min(tk, k)
+        while k % tk and tk % 256 == 0:
+            tk //= 2
         pad = (-m) % tm
         if pad:
             lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
         out = gmm(lhs, rhs, group_sizes.astype(jnp.int32), lhs.dtype,
-                  (tm, min(tk, k), min(tn, rhs.shape[-1])), None, None, False, interpret)
+                  (tm, tk, min(tn, rhs.shape[-1])), None, None, False, interpret)
         return out[:m] if pad else out
     raise ValueError(f"unknown grouped_matmul impl {impl!r}")
 
 
 def expert_ffn(x, experts, weights, w_in, w_out, live=None, layer: int | None = None,
-               impl: str | None = None, interpret: bool = False):
+               impl: str | None = None, interpret: bool = False,
+               held: tuple | None = None):
     """The dropless expert product. ``x [T, h]``; ``experts`` / ``weights``
     ``[T, k]`` from :func:`route`; ``w_in [E, h, 2f]`` (gate | up),
     ``w_out [E, f, h]``; ``live [T]`` bool (``None``: every token). With
@@ -98,9 +119,25 @@ def expert_ffn(x, experts, weights, w_in, w_out, live=None, layer: int | None = 
     E, ...]`` and the product addresses ``(layer, expert)`` in them: no
     layer's experts are sliced out to be multiplied. Returns ``(y [T, h],
     counts [E] int32)``: the weighted sum of each token's experts, zero for
-    a token that is not live, and the pairs each expert was given."""
+    a token that is not live, and the pairs each expert was given.
+
+    ``held = (first, count)``: this chip's share of an expert-parallel
+    layer. The router scored all the experts and ``experts`` names any of
+    them; the matrices are those of experts ``first .. first + count - 1``
+    alone (``w_in [count, ...]``). A pair whose expert lives elsewhere takes
+    the dead lane — it sorts behind every group, reads no weights and adds
+    nothing — and ``counts [count]`` is over the experts held: the caller
+    has the pairs routed elsewhere as its live pairs less ``counts.sum()``.
+    The sum that comes back is this chip's partial one; nothing stands in
+    for the other chips or the exchange with them."""
     t, k = experts.shape
     n_experts = w_in.shape[-3]
+    if held is not None:
+        first, count = held
+        if count != n_experts:
+            raise ValueError(f"held {held}: the matrices are of {n_experts} experts")
+        local = experts - first
+        experts = jnp.where((local >= 0) & (local < count), local, n_experts)
     if live is not None:
         # a dead token's pairs get an expert id past the last: they sort
         # behind every group and belong to none

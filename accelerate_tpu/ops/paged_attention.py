@@ -248,15 +248,16 @@ _IN_FLIGHT = 1
 _ROW_BLOCK = 256
 
 
-def tile_entries(table_width: int) -> int:
-    """Table entries a softmax step takes of a table this wide."""
-    return min(_TILE, table_width)
+def tile_entries(table_width: int, latent: bool = False) -> int:
+    """Table entries a softmax step takes of a table this wide (``latent``:
+    of the latent kernel, which has a tile of its own)."""
+    return min(_LATENT_TILE if latent else _TILE, table_width)
 
 
-def tiles_walked(entries, table_width: int):
+def tiles_walked(entries, table_width: int, latent: bool = False):
     """Softmax steps the kernel takes for rows that walk ``entries`` table
     entries each (the host's count, ``serving/engine.py``)."""
-    return -(-entries // tile_entries(table_width))
+    return -(-entries // tile_entries(table_width, latent))
 
 
 def _pallas_kernel(bt_ref, idx_ref, layer_ref, q_ref, *rest,
@@ -491,3 +492,236 @@ def _paged_attention_pallas_sharded(q, k_pool, v_pool, layer, block_tables, idx,
         per_shard, mesh=ctx.mesh, in_specs=tuple(in_specs), out_specs=heads,
         check_vma=False,
     )(*operands)
+
+
+# ---------------------------------------------------------------------------
+# latent attention: one cached vector a token for every head (MLA, absorbed)
+# ---------------------------------------------------------------------------
+
+#: table entries a softmax step of the latent kernel takes (``_TILE``'s twin:
+#: chosen on the v5e for one "kv head" of 640 lanes against 128 query heads,
+#: PERF.md section 6, PR 42)
+_LATENT_TILE = 8
+
+#: stacked query rows (heads x queries, head-major) a grid step of the latent
+#: kernel takes: every head of a decode row at once (128), four heads' worth
+#: of a 128-token chunk; what a step keeps in VMEM is this many rows of query,
+#: output and float32 accumulator (1 MB at a rank of 512)
+_LATENT_ROW_BLOCK = 512
+
+
+def latent_attention(
+    q,                      # [b, s, n_heads, width]: absorbed queries
+    pool,                   # [layers, num_blocks, bs, width] (storage dtype)
+    layer,                  # int32 scalar (may be traced)
+    block_tables,           # [b, max_blocks] int32
+    idx,                    # [b] int32 — first query's cache position
+    *,
+    rank: int,
+    scale: float,
+    pool_scale=None,        # [layers, num_blocks, bs, 1] f32 (a quantized pool)
+    impl: str | None = None,
+    interpret: bool = False,
+):
+    """Multi-head latent attention in its absorbed form, off the block table.
+    The cache keeps ONE vector of ``width`` entries a token and layer — the
+    compressed key/value ``c`` (``rank`` entries), behind it the rotated key
+    all heads share, then zeros up to whole lane tiles
+    (``models.cache.CacheSpec.pool_width``) — and each head's query has been
+    carried into those coordinates (``q_nope W_kvb_k^T`` | rotated ``q_pe`` |
+    zeros, padded by the caller to the pool's width), so head ``h`` of
+    query ``p`` scores ``q[p, h] . cache[j] * scale`` against every position
+    ``j <= idx + p`` and sums the first ``rank`` entries of the cached vectors
+    under the softmax: ``[b, s, n_heads, rank]``, which the caller carries
+    back through ``W_kvb_v``. One "kv head" of ``width`` lanes whose leading
+    lanes are also V: the pool is read once for both products, ``2 * nh *
+    (width + rank)`` operations a cached entry of ``width`` values.
+
+    Routes as :func:`paged_attention`: ``"pallas"`` (the kernel
+    ``latent_attention``: the same walk over a row's own table entries, a
+    tile of ``_TILE`` entries a softmax step, the products in the pool's
+    type with float32 accumulation), ``"lax"`` (a scan over table entries)
+    and ``"gather"`` (the span materialised, then
+    :func:`ops.layers.cached_latent_attention`). No mesh: one vector for all
+    heads leaves nothing to split over a head axis."""
+    if impl is None:
+        impl = default_paged_attention_impl()
+    layer = jnp.asarray(layer, jnp.int32)
+    bt = jnp.asarray(block_tables, jnp.int32)
+    idx = jnp.asarray(idx, jnp.int32).reshape(q.shape[0])
+    if impl == "lax":
+        return _latent_attention_lax(q, pool, layer, bt, idx, pool_scale, rank, scale)
+    if impl == "pallas":
+        return _latent_attention_pallas(q, pool, layer, bt, idx, pool_scale, rank, scale,
+                                        interpret=interpret)
+    if impl == "gather":
+        from .layers import cached_latent_attention
+
+        b, mb = bt.shape
+        span = _dequant_block(
+            pool[layer, bt], None if pool_scale is None else pool_scale[layer, bt], 1)
+        return cached_latent_attention(
+            q, span.reshape(b, mb * pool.shape[2], pool.shape[3]), idx, rank, scale)
+    raise ValueError(f"unknown latent attention impl {impl!r}")
+
+
+def _latent_attention_lax(q, pool, layer, bt, idx, pool_scale, rank, scale):
+    b, s, nh, _ = q.shape
+    bs = pool.shape[2]
+    qf = q.astype(jnp.float32) * scale
+    q_pos = idx[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]       # [b, s]
+
+    def body(carry, j):
+        m, l, acc = carry
+        blk = bt[:, j]
+        cb = _dequant_block(
+            pool[layer, blk], None if pool_scale is None else pool_scale[layer, blk], 1)[:, :, 0]
+        sc = jnp.einsum("bshd,btd->bhst", qf, cb)
+        pos = j * bs + jnp.arange(bs, dtype=jnp.int32)
+        vmask = (pos[None, None, :] <= q_pos[:, :, None])[:, None]       # [b, 1, s, bs]
+        sc = jnp.where(vmask, sc, _NEG_INF)
+        m_new = jnp.maximum(m, sc.max(axis=-1))
+        p = jnp.where(vmask, jnp.exp(sc - m_new[..., None]), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + p.sum(axis=-1)
+        acc = acc * alpha[..., None] + jnp.einsum("bhst,btd->bhsd", p, cb[..., :rank])
+        return (m_new, l, acc), None
+
+    init = (
+        jnp.full((b, nh, s), _NEG_INF, jnp.float32),
+        jnp.zeros((b, nh, s), jnp.float32),
+        jnp.zeros((b, nh, s, rank), jnp.float32),
+    )
+    (_, l, acc), _ = jax.lax.scan(body, init, jnp.arange(bt.shape[1], dtype=jnp.int32))
+    out = acc / jnp.maximum(l, 1e-30)[..., None]                         # [b, nh, s, rank]
+    return out.transpose(0, 2, 1, 3).astype(q.dtype)
+
+
+def _latent_kernel(bt_ref, idx_ref, layer_ref, q_ref, *rest, bs, tile, s, rank, scale,
+                   quantized):
+    """:func:`_pallas_kernel`'s walk for a latent pool. Grid ``(b, row
+    blocks)``; a step holds a block of the row's stacked queries ``[rows,
+    width]`` (head-major: stacked row ``g`` is query ``g % s`` of head ``g //
+    s``) and walks the row's live table entries a tile at a time, each live
+    entry copied from ``(layer, block)`` of the pool in HBM into its rows of
+    a VMEM buffer, ``_IN_FLIGHT`` tiles ahead. A softmax step is ``[rows,
+    width] x [tile * bs, width]^T`` (``width`` the pool's stored one, whole
+    lane tiles: the query's padding lanes are zeros), one max / exp / sum,
+    and ``[rows, tile * bs] x [tile * bs, rank]``: the buffer's leading lanes
+    are the values. The
+    products take their operands in the query's type (a quantized pool's
+    rows times their scale first) and accumulate in float32. Rows of the
+    buffer that no copy of this tile wrote are zeroed where they are used as
+    values (``0 x NaN``), as there."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n_pools = 2 if quantized else 1
+    pools, (out_ref, *rest) = rest[:n_pools], rest[n_pools:]
+    bufs, (sems, m_ref, l_ref, acc_ref) = rest[:n_pools], rest[n_pools:]
+    i = pl.program_id(0)
+    mb = bt_ref.shape[1]
+    rows = q_ref.shape[1]
+    depth = _IN_FLIGHT + 1
+    span = tile * bs
+    layers = [pools[0].at[layer_ref[0]]] + [p.at[0] for p in pools[1:]]
+
+    first = idx_ref[i]
+    last = first + s - 1
+    live = jnp.minimum(last // bs + 1, mb)
+    row0 = pl.program_id(1) * rows
+
+    def for_live_entries(t, act):
+        slot = t % depth
+        for e in range(tile):
+            @pl.when(t * tile + e < live)
+            def _entry():
+                blk = bt_ref[i, t * tile + e]
+                for a, (pool, buf) in enumerate(zip(layers, bufs)):
+                    act(pltpu.make_async_copy(
+                        pool.at[blk], buf.at[slot, pl.ds(e * bs, bs)], sems.at[a, slot]))
+
+    for t in range(_IN_FLIGHT):
+        for_live_entries(t, lambda dma: dma.start())
+
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    q = q_ref[0]                                                 # [rows, width]
+    contract = (((1,), (1,)), ((), ()))
+
+    def _step(t, carry):
+        for_live_entries(t + _IN_FLIGHT, lambda dma: dma.start())
+        for_live_entries(t, lambda dma: dma.wait())
+        slot = t % depth
+        k_pos = t * span + jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
+        if s == 1:  # a decode row: every head's one query stands at ``first``
+            valid = jnp.broadcast_to(k_pos <= first, (rows, span))
+        else:
+            g = row0 + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+            valid = k_pos <= first + jax.lax.rem(g, s)            # [rows, span]
+        seen = t * span + jax.lax.broadcasted_iota(jnp.int32, (span, 1), 0) <= last
+        cb = bufs[0][slot]                                        # [span, width]
+        if quantized:
+            cb = (cb.astype(jnp.float32) * bufs[1][slot, :, 0:1]).astype(q.dtype)
+        cb = cb.astype(q.dtype)
+        sc = jax.lax.dot_general(q, cb, contract, preferred_element_type=jnp.float32)
+        sc = jnp.where(valid, sc * scale, _NEG_INF)
+        m_prev, l_prev = m_ref[...], l_ref[...]                   # [rows, 1]
+        m_new = jnp.maximum(m_prev, sc.max(axis=-1, keepdims=True))
+        p = jnp.where(valid, jnp.exp(sc - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        m_ref[...] = m_new
+        l_ref[...] = l_prev * alpha + p.sum(axis=-1, keepdims=True)
+        vb = jnp.where(seen, cb[:, :rank], jnp.zeros((), cb.dtype))
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            p.astype(cb.dtype), vb, preferred_element_type=jnp.float32)
+        return carry
+
+    jax.lax.fori_loop(0, (live + tile - 1) // tile, _step, 0)
+    out_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(out_ref.dtype)
+
+
+def _latent_attention_pallas(q, pool, layer, bt, idx, pool_scale, rank, scale, *, interpret):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, s, nh, width = q.shape
+    bs = pool.shape[2]
+    quantized = pool_scale is not None
+    tile = tile_entries(bt.shape[1], latent=True)
+    block_rows = min(-(-nh * s // 8) * 8, _LATENT_ROW_BLOCK)
+    rows = -(-nh * s // block_rows) * block_rows
+    stacked = q.transpose(0, 2, 1, 3).reshape(b, nh * s, width)
+    stacked = jnp.pad(stacked, [(0, 0), (0, rows - nh * s), (0, 0)])
+
+    def row(i, r, bt_, ix, ly):
+        return (i, r, 0)
+
+    pools = [pool] + ([_scale_blocks(pool_scale, layer)] if quantized else [])
+    buffers = _IN_FLIGHT + 1
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, rows // block_rows),
+        in_specs=[pl.BlockSpec((1, block_rows, width), row)]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+        out_specs=pl.BlockSpec((1, block_rows, rank), row),
+        scratch_shapes=[
+            pltpu.VMEM((buffers, tile * bs, p.shape[3]), p.dtype) for p in pools
+        ] + [
+            pltpu.SemaphoreType.DMA((len(pools), buffers)),
+            pltpu.VMEM((block_rows, 1), jnp.float32),
+            pltpu.VMEM((block_rows, 1), jnp.float32),
+            pltpu.VMEM((block_rows, rank), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_latent_kernel, bs=bs, tile=tile, s=s, rank=rank,
+                          scale=float(scale), quantized=quantized),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, rows, rank), q.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+        name="latent_attention",
+    )(bt, idx, layer.reshape(1), stacked, *pools)
+    return out[:, :nh * s].reshape(b, nh, s, rank).transpose(0, 2, 1, 3)

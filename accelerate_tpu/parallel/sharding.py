@@ -213,7 +213,7 @@ def shard_params(params: Any, shardings: Any):
     return jax.tree.map(lambda p, s: jax.device_put(p, s), params, shardings)
 
 
-def paged_kv_sharding(mesh: Mesh, num_kv_heads: int) -> NamedSharding:
+def paged_kv_sharding(mesh: Mesh, num_kv_heads: int, latent: bool = False) -> NamedSharding:
     """Sharding for the serving engine's block-paged KV pools
     (``[layers, num_blocks, block_size, n_kv*head_dim]`` — heads folded
     into the lane dimension, head ``n`` at lanes ``[n*hd, (n+1)*hd)``): the
@@ -222,7 +222,14 @@ def paged_kv_sharding(mesh: Mesh, num_kv_heads: int) -> NamedSharding:
     wk/wv projections (see ``LLAMA_PARTITION_RULES``), so storing the pool
     the same way keeps the block scatter/gather collective-free. Falls
     back to replicated when ``tp`` doesn't divide the head count (GQA
-    models with few kv heads)."""
+    models with few kv heads). A latent pool (``models.cache.CacheSpec``
+    ``latent_rank``: one vector a token for every head) has no kv head and so
+    nothing to put over ``tp``: asking for its placement is refused."""
+    if latent:
+        raise ValueError(
+            "a latent pool keeps one vector a token for every head: there is no kv head "
+            "to put over tp, and no placement of it under sharded query heads is built "
+            "(ROADMAP Reach 3)")
     return _paged_heads_sharding(mesh, num_kv_heads)
 
 

@@ -407,6 +407,30 @@ class InferenceEngine:
                         f"{missing} (ROADMAP Reach 5)"
                     )
 
+        # a latent pool (one vector a token for every head, models/cache.py):
+        # blocks are still the whole of a request's past, so the prefix cache,
+        # copy-on-write and preemption by recompute work on them as they are;
+        # what reads a K and a V row per kv head is refused with its reason
+        if spec.latent_rank:
+            name = getattr(inner, "name", type(inner).__name__)
+            kept = (f"one latent vector of {spec.head_dim} values a token and layer "
+                    f"(stored {spec.pool_width} wide) for all heads")
+            for armed, what, missing in (
+                (cfg.swap_gb and cfg.swap_gb > 0, f"swap_gb={cfg.swap_gb}",
+                 "the host swap pool mirrors a K and a V row per kv head, and this pool "
+                 "has neither; a preempted request is recomputed instead"),
+                (mesh is not None, "mesh=",
+                 "the pool has no kv head to shard over tp, and a replicated latent pool "
+                 "under tp-sharded query heads is not placed yet (ROADMAP Reach 3)"),
+                (cfg.kv_dtype == "int8", "kv_dtype=int8",
+                 "one amax scale a row would cover the compressed entries and the rotated "
+                 "key together in 255 steps; fp8 (ops/fp8.py, one scale a row) is the "
+                 "quantized latent pool that is built"),
+            ):
+                if armed:
+                    raise ValueError(f"{what} is not supported for {name!r}, which keeps "
+                                     f"{kept}: {missing}")
+
         # a model that generates by diffusion over blocks: the decode
         # executable is the block round, and what cannot hold under parallel
         # unmasking is refused here or at add_request, the reasons in stats()
@@ -531,17 +555,13 @@ class InferenceEngine:
             store_dtype, quantized = kv_storage_dtype(cfg.kv_dtype)
         self._quantized = quantized
         self.kv_dtype = str(np.dtype(store_dtype))
-        shape = (spec.paged_layers, num_blocks, cfg.block_size, n_kv * spec.head_dim)
+        shape = (spec.paged_layers, num_blocks, cfg.block_size, spec.pool_width)
         scale_shape = (spec.paged_layers, num_blocks, cfg.block_size, n_kv)
         #: bytes one cached token costs across the layers that hold paged KV
-        #: (K + V payload plus the f32 scales when quantized) — the
-        #: decode-bandwidth and slot-capacity headline number
-        self.kv_bytes_per_token = (
-            2
-            * spec.paged_layers
-            * n_kv
-            * (spec.head_dim * np.dtype(store_dtype).itemsize + (4 if quantized else 0))
-        )
+        #: (K + V payload, or a latent pool's one stored row, plus the f32
+        #: scales when quantized) — the decode-bandwidth and slot-capacity
+        #: headline number
+        self.kv_bytes_per_token = spec.bytes_per_token(store_dtype, quantized)
         #: bytes of per-slot state one slot costs, whatever its sequence's
         #: length (0 for a model whose blocks are all of a request's past)
         self.state_bytes_per_slot = spec.state_bytes_per_slot(dtype)
@@ -591,12 +611,16 @@ class InferenceEngine:
         )
         #: everything the step programs keep for the sequences, in ONE dict
         #: that every one of them takes donated and hands back whole: "k" /
-        #: "v" (and "k_scale" / "v_scale", all-ones so that a never-written
-        #: row dequantizes to exactly 0), then the model's slot-state leaves
-        self._cache = {"k": jnp.zeros(shape, store_dtype), "v": jnp.zeros(shape, store_dtype)}
+        #: "v" (a latent spec: "k" alone), and "k_scale" / "v_scale" beside
+        #: them (all-ones so that a never-written row dequantizes to exactly
+        #: 0), then the model's slot-state leaves
+        self._cache = {name: jnp.zeros(shape, store_dtype) for name in spec.pool_leaves}
         if quantized:
-            self._cache["k_scale"] = jnp.ones(scale_shape, jnp.float32)
-            self._cache["v_scale"] = jnp.ones(scale_shape, jnp.float32)
+            self._cache.update({name + "_scale": jnp.ones(scale_shape, jnp.float32)
+                                for name in spec.pool_leaves})
+        #: the pool's arrays by name, payload and scales: what a block-granular
+        #: edit (copy-on-write) has to touch, all of it
+        self._pool_arrays = tuple(self._cache)
         for name, leaf in spec.slot_state.items():
             self._cache[name] = jnp.zeros(
                 leaf.array_shape(cfg.num_slots), leaf.dtype or dtype)
@@ -919,6 +943,7 @@ class InferenceEngine:
             draft_layers=self._spec.layers if self._spec else None,
             stacked_prefix=getattr(inner, "stacked_params_prefix", "layers"),
             state_bytes=self.state_bytes_per_slot * self.config.num_slots,
+            pool_leaves=self._cache_spec.pool_leaves,
         )
         self.hbm_preflight = report
         if report["over"]:
@@ -1826,6 +1851,12 @@ class InferenceEngine:
             # which layers keep what (models/cache.py): paged K/V by token,
             # slot state by slot, whatever the sequence's length
             "kv_layers": self._cache_spec.paged_layers,
+            # a latent pool (0: K and V per kv head): the entries of a row that
+            # are also the values, and what a token's rows cost as stored
+            # (576 values kept 640 wide: kv_bytes_per_token says the same)
+            "latent_rank": self._cache_spec.latent_rank,
+            "latent_bytes_per_token": (
+                self.kv_bytes_per_token if self._cache_spec.latent_rank else 0),
             "state_layers": self._cache_spec.state_layers,
             "state_dtype": next(
                 (leaf.dtype for leaf in self._cache_spec.slot_state.values() if leaf.dtype),
@@ -1873,7 +1904,7 @@ class InferenceEngine:
             # ops/paged_attention.py's _TILE entries each (a row's last one
             # part full): walked / (tiles x paged_tile_entries) is the tiles' fill
             "paged_tiles_walked_total": self._paged_tiles_walked,
-            "paged_tile_entries": tile_entries(self._mb),
+            "paged_tile_entries": tile_entries(self._mb, bool(self._cache_spec.latent_rank)),
             # the slot state's work: states a decode dispatch had to step
             # (live lanes x burst x state layers: what ops/ssm.py's kernel
             # walks) against those the cache holds for every slot
@@ -2354,13 +2385,12 @@ class InferenceEngine:
                 self._pending_tok[req.slot] = req.output_tokens[-1]
         elif req.cow is not None:
             src, dst = req.cow
-            self._kp = self._copy_block_fn(self._kp, np.int32(src), np.int32(dst))
-            self._vp = self._copy_block_fn(self._vp, np.int32(src), np.int32(dst))
-            if self._quantized:
-                # the CoW copy is byte-exact for payload AND scales: the
-                # private copy dequantizes identically to the cached block
-                self._ks = self._copy_block_fn(self._ks, np.int32(src), np.int32(dst))
-                self._vs = self._copy_block_fn(self._vs, np.int32(src), np.int32(dst))
+            # every leaf of the pool, payload AND scales: the CoW copy is
+            # byte-exact, so the private copy dequantizes identically to the
+            # cached block
+            for leaf in self._pool_arrays:
+                self._cache[leaf] = self._copy_block_fn(
+                    self._cache[leaf], np.int32(src), np.int32(dst))
             self.allocator.decref([src])  # drop the eviction pin
             req.cow = None
 
@@ -2523,7 +2553,8 @@ class InferenceEngine:
         walked = np.minimum(last // self.config.block_size + 1, self._mb)
         self._paged_entries_walked += int(walked.sum()) * layers * calls
         self._paged_entries_table += walked.size * self._mb * layers * calls
-        self._paged_tiles_walked += int(tiles_walked(walked, self._mb).sum()) * layers * calls
+        self._paged_tiles_walked += int(tiles_walked(
+            walked, self._mb, bool(self._cache_spec.latent_rank)).sum()) * layers * calls
 
     def _count_state_slots(self, active) -> None:
         """Book one decode dispatch's slot-state steps from the mask the
@@ -2656,7 +2687,8 @@ class InferenceEngine:
         # its victim gives its blocks back and is recomputed on re-admission
         # (so does a block model's where there is no swap tier: every token it
         # emitted is in its replayed prefill or its open block, none pending)
-        recompute = bool(self._cache_spec.slot_state) or (
+        # and so does a latent pool's, whose rows the swap tier cannot mirror
+        recompute = bool(self._cache_spec.slot_state or self._cache_spec.latent_rank) or (
             self._block is not None and self._swap is None)
         preempt = self._preempt_by_recompute if recompute else self._swap_out
         while not sched.grow_for_decode(req, tokens_ahead=self._decode_lookahead):

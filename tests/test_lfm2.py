@@ -350,6 +350,12 @@ PARENT_PROGRAMS = {
     # ``scoring="sigmoid"`` this model's programs, counters and all, are that commit's
     "lfm2": {"decode": "a66962a90db2b7a2b3b5764c3f9e93417827405a1aa95e95574284d18ad839c3",
              "prefill": "19583c71ee12fee661ae51399c0d2d793d44faae5ee80458f0469fea67548f25"},
+    # taken on 6756b7a (the parent of PR 42, which gave the cache spec a latent
+    # kind, the router its groups and the expert product its held range): the
+    # block round and the chunk of the fourth served family; the llama digests
+    # above are the Mistral cell's programs (one model class)
+    "sdar": {"decode": "4a440ed4b36f0295b667c14fadca5ec50035888714bb9a666df461abeb79af01",
+             "prefill": "39e3a380ab93198d18faae1c80665d01905300cac146cb515ace4326dae3ab13"},
 }
 
 
@@ -358,11 +364,13 @@ import hashlib, json, sys
 from accelerate_tpu.models import LlamaConfig, LlamaForCausalLM
 from accelerate_tpu.models.granite_hybrid import GraniteHybridConfig, GraniteHybridForCausalLM
 from accelerate_tpu.models.lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM
+from accelerate_tpu.models.sdar_moe import SdarMoeConfig, SdarMoeForCausalLM
 from accelerate_tpu.serving import EngineConfig, InferenceEngine
 from accelerate_tpu.serving.sampling import SamplingParams
 model = {"llama": lambda: LlamaForCausalLM.from_config(LlamaConfig.tiny(), seed=0),
          "hybrid": lambda: GraniteHybridForCausalLM.from_config(GraniteHybridConfig.tiny(), seed=0),
-         "lfm2": lambda: Lfm2MoeForCausalLM.from_config(Lfm2MoeConfig.tiny(), seed=0)}[sys.argv[1]]()
+         "lfm2": lambda: Lfm2MoeForCausalLM.from_config(Lfm2MoeConfig.tiny(), seed=0),
+         "sdar": lambda: SdarMoeForCausalLM.from_config(SdarMoeConfig.tiny(), seed=0)}[sys.argv[1]]()
 engine = InferenceEngine(model, EngineConfig(
     num_slots=4, max_seq_len=128, prefill_chunk=16, block_size=8, logprobs_topn=1, decode_burst=4))
 engine.add_request(list(range(3, 40)), 6, sampling=SamplingParams(logprobs=1))
@@ -375,7 +383,7 @@ print("DIGESTS " + json.dumps(out))
 """
 
 
-@pytest.mark.parametrize("family", ["llama", "hybrid", "lfm2"])
+@pytest.mark.parametrize("family", ["llama", "hybrid", "lfm2", "sdar"])
 def test_a_model_without_counters_compiles_the_parents_programs(family):
     """In a process of its own: what a program lowers to also depends on
     process-wide settings other tests change (the attention context, the
@@ -387,7 +395,7 @@ def test_a_model_without_counters_compiles_the_parents_programs(family):
         timeout=600, env={**os.environ, "JAX_PLATFORMS": "cpu", "XLA_FLAGS": ""})
     assert done.returncode == 0, done.stderr[-2000:]
     got = json.loads(next(l for l in done.stdout.splitlines() if l.startswith("DIGESTS "))[8:])
-    assert got == {"counters": family == "lfm2", **PARENT_PROGRAMS[family]}
+    assert got == {"counters": family in ("lfm2", "sdar"), **PARENT_PROGRAMS[family]}
 
 
 # -- the published file -> the model ------------------------------------------------

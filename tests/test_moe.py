@@ -209,3 +209,141 @@ def test_the_platform_picks_the_implementation():
     assert moe.default_moe_impl() == "ragged"  # the CPU's; "gmm" on a TPU backend
     with pytest.raises(ValueError, match="unknown grouped_matmul impl"):
         moe.grouped_matmul(jnp.zeros((4, 8)), jnp.zeros((2, 8, 8)), jnp.asarray([2, 2]), "dense")
+
+
+# -- a chip's share of the experts, and the grouped choice (ISSUE 42) ---------------
+
+
+@pytest.mark.parametrize("impl", ["ragged", "gmm"])
+@pytest.mark.parametrize("first", [0, 2, 5])
+def test_a_held_range_computes_its_own_experts_part_and_leaves_the_rest_out(layer, impl, first):
+    """Experts ``first .. first + 2`` of the 8 are held: the router chose
+    among all 8, the pairs of the other experts take the dead lane, the
+    counts are over the 3 held, and the sum is the token loop's over the
+    pairs whose expert is held."""
+    x = layer["x"]
+    experts, weights = moe.route(x, layer["gate"], jnp.zeros(E), 4)
+    live = np.arange(T) % 4 != 2
+    held = slice(first, first + 3)
+    y, counts = moe.expert_ffn(
+        x, experts, weights, layer["w_in"][held], layer["w_out"][held],
+        live=jnp.asarray(live), impl=impl, interpret=True, held=(first, 3))
+    e = np.asarray(experts)
+    here = (e >= first) & (e < first + 3)
+    np.testing.assert_array_equal(
+        counts, np.bincount(e[live][here[live]] - first, minlength=3))
+    want = _loop(x, e, np.where(here, np.asarray(weights), 0.0), layer["w_in"],
+                 layer["w_out"], live)
+    np.testing.assert_allclose(y, want, atol=2e-6)
+    # the pairs routed elsewhere are the live pairs less those counted
+    assert live.sum() * 4 - int(counts.sum()) == (~here)[live].sum()
+    # every range's part adds up to the whole layer's
+    if first == 0:
+        whole, _ = moe.expert_ffn(x, experts, weights, layer["w_in"], layer["w_out"],
+                                  live=jnp.asarray(live), impl=impl, interpret=True)
+        parts = [moe.expert_ffn(x, experts, weights, layer["w_in"][a:a + 4],
+                                layer["w_out"][a:a + 4], live=jnp.asarray(live), impl=impl,
+                                interpret=True, held=(a, 4))[0] for a in (0, 4)]
+        np.testing.assert_allclose(parts[0] + parts[1], whole, atol=2e-6)
+
+
+def test_a_held_range_of_the_stacked_matrices_and_a_wrong_count(layer):
+    x = layer["x"]
+    experts, weights = moe.route(x, layer["gate"], jnp.zeros(E), K)
+    stack_in = jnp.stack([layer["w_in"][4:] * 0, layer["w_in"][4:]])
+    stack_out = jnp.stack([layer["w_out"][4:] * 0, layer["w_out"][4:]])
+    y, counts = moe.expert_ffn(x, experts, weights, stack_in, stack_out, layer=1, held=(4, 4))
+    alone, alone_counts = moe.expert_ffn(x, experts, weights, layer["w_in"][4:],
+                                         layer["w_out"][4:], held=(4, 4))
+    np.testing.assert_allclose(y, alone, atol=1e-6)
+    np.testing.assert_array_equal(counts, alone_counts)
+    with pytest.raises(ValueError, match=r"held \(4, 3\): the matrices are of 4 experts"):
+        moe.expert_ffn(x, experts, weights, layer["w_in"][4:], layer["w_out"][4:], held=(4, 3))
+
+
+def test_the_groups_limit_the_choice_and_a_dropped_groups_score_counts_as_zero(layer):
+    """8 experts in 4 groups of 2, the best 2 groups kept, top 3: every
+    chosen expert lies in a kept group (a group's mark is the sum of its two
+    biased scores), and the third choice of a token comes from the kept
+    groups even where a dropped group's expert scores higher."""
+    x, gate = layer["x"], layer["gate"]
+    bias = jnp.asarray(np.linspace(-0.2, 0.2, E), jnp.float32)
+    experts, weights = moe.route(x, gate, bias, 3, True, 1.0, n_group=4, topk_group=2)
+    scores = np.asarray(jax.nn.sigmoid(x @ gate))
+    biased = scores + np.asarray(bias)
+    marks = biased.reshape(T, 4, 2).sum(-1)
+    kept = np.argsort(-marks, axis=1)[:, :2]
+    e = np.asarray(experts)
+    assert all(set(row // 2) <= set(k) for row, k in zip(e, kept))
+    free, _ = moe.route(x, gate, bias, 3, True, 1.0)
+    assert not np.array_equal(np.asarray(free), e)  # somewhere a dropped group scored higher
+    picked = np.take_along_axis(scores, e, axis=1)
+    np.testing.assert_allclose(weights, picked / (picked.sum(1, keepdims=True) + 1e-6),
+                               rtol=1e-6)
+
+
+#: sha256 of the jaxpr of both routers and the expert product (flat and
+#: stacked) at their defaults, taken on the parent commit (6756b7a): the
+#: groups, ``norm_eps`` and ``held`` are static branches, and at ``n_group`` 1
+#: without ``held`` the operators trace what they traced before they were there
+PARENT_JAXPR = "9879b33ff22be777c9becb025c8abd349753a533a47862f4beae887a06137962"
+
+
+_JAXPR_SCRIPT = """
+import hashlib
+import jax, jax.numpy as jnp
+from accelerate_tpu.ops import moe
+x, gate, bias = jnp.zeros((12, 32)), jnp.zeros((32, 8)), jnp.zeros((8,))
+w_in, w_out = jnp.zeros((8, 32, 32)), jnp.zeros((8, 16, 32))
+def program(x, gate, bias, w_in, w_out, live):
+    e, w = moe.route(x, gate, bias, 2, True, 2.5)
+    e2, w2 = moe.route(x, gate, None, 2, True, 1.0, scoring="softmax")
+    y, c = moe.expert_ffn(x, e, w, w_in, w_out, live=live, impl="ragged")
+    y2, c2 = moe.expert_ffn(x, e2, w2, w_in[None], w_out[None], layer=0, impl="ragged")
+    return y, c, y2, c2
+text = str(jax.make_jaxpr(program)(x, gate, bias, w_in, w_out, jnp.ones((12,), bool)))
+print("DIGEST " + hashlib.sha256(text.encode()).hexdigest())
+"""
+
+
+def test_at_one_group_and_nothing_held_the_operators_trace_the_parents_program():
+    """In a process of its own, as ``tests/test_lfm2.py``'s digests: what a
+    program traces to also depends on process-wide settings other tests
+    change, and the digest was taken in a fresh one."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    done = subprocess.run(
+        [sys.executable, "-c", _JAXPR_SCRIPT], cwd=root, capture_output=True, text=True,
+        timeout=300, env={**os.environ, "JAX_PLATFORMS": "cpu", "XLA_FLAGS": ""})
+    assert done.returncode == 0, done.stderr[-2000:]
+    got = next(l for l in done.stdout.splitlines() if l.startswith("DIGEST "))[7:]
+    assert got == PARENT_JAXPR
+
+
+def test_a_contraction_the_tile_does_not_divide_takes_the_tile_halved(monkeypatch):
+    """``k`` = 384 under a tile of 256: the grouped product runs at 128 (no
+    masked part tile) and agrees with ``ragged_dot``; a ``k`` the tile divides
+    keeps it."""
+    seen = []
+    import jax.experimental.pallas.ops.tpu.megablox as megablox
+
+    real = megablox.gmm
+
+    def spy(lhs, rhs, sizes, dtype, tiling, *rest):
+        seen.append(tiling)
+        return real(lhs, rhs, sizes, dtype, tiling, *rest)
+
+    monkeypatch.setattr(moe, "_GMM_TILING", (128, 256, 128))
+    monkeypatch.setattr(megablox, "gmm", spy)
+    ks = jax.random.split(jax.random.PRNGKey(3), 2)
+    sizes = jnp.asarray([100, 0, 156], jnp.int32)
+    for k in (384, 512):
+        lhs = jax.random.normal(ks[0], (256, k))
+        rhs = jax.random.normal(ks[1], (3, k, 128)) / np.sqrt(k)
+        got = moe.grouped_matmul(lhs, rhs, sizes, impl="gmm", interpret=True)
+        np.testing.assert_allclose(got, moe.grouped_matmul(lhs, rhs, sizes, impl="ragged"),
+                                   atol=2e-5)
+    assert [t[1] for t in seen] == [128, 256]

@@ -455,6 +455,70 @@ def test_v5e_compiled_block_step_walks_to_the_blocks_end(one_chip, monkeypatch, 
     assert found["moved"] == [], found["moved"]
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill", "decode_fp8"])
+def test_v5e_compiled_latent_step_leaves_the_one_pool_in_place(one_chip, monkeypatch, program):
+    """DeepSeek-V3's widths (128 heads absorbed over a 576-value latent row
+    stored 640 wide; 16 held experts of 7168 x 2048 under the 256-wide grouped
+    router, a shared expert), one dense and one routed layer, 40 slots x 1,024
+    table entries as the benchmark's cell dispatches them, the vocabulary cut
+    to 8192: a decode step, a 512-token chunk and a decode step over an fp8
+    pool compile for the v5e with the ``latent_attention`` kernel and the
+    grouped product (``k`` = 7168 at a contraction tile that divides it) in
+    the program, the ONE pool aliased through it - nothing of its size or of a
+    layer's slab is produced - and no instruction the size of an expert
+    stack."""
+    import sys
+
+    from accelerate_tpu.models import deepseek_v3 as ds
+
+    monkeypatch.setattr(
+        sys.modules["accelerate_tpu.ops.paged_attention"],
+        "default_paged_attention_impl", lambda: "pallas",
+    )
+    monkeypatch.setattr(sys.modules["accelerate_tpu.ops.moe"], "default_moe_impl", lambda: "gmm")
+    slots, blocks, bs, table, chunk = 40, 3000, 16, 1024, 512
+    c = ds.DeepseekV3Config(
+        vocab_size=8192, num_hidden_layers=2, first_k_dense_replace=1, n_routed_experts=16,
+        router_experts=256,
+        rope_scaling={"type": "yarn", "factor": 40, "original_max_position_embeddings": 4096,
+                      "beta_fast": 32, "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1})
+    spec = ds.cache_spec(c)
+    assert (spec.pool_leaves, spec.pool_width) == (("k",), 640)
+    shaped = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: shaped(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: ds.init_deepseek_params(jax.random.PRNGKey(0), c)),
+    )
+    quantized = program == "decode_fp8"
+    cache = {"k": shaped((2, blocks, bs, 640), jnp.float8_e4m3fn if quantized else jnp.bfloat16)}
+    if quantized:
+        cache["k_scale"] = shaped((2, blocks, bs, 1), jnp.float32)
+
+    def step(params, cache, tables, pos, toks, mask):
+        out = ds.deepseek_apply(
+            c, params, toks, paged_kv=cache, block_tables=tables,
+            cache_positions=pos, paged_write_mask=mask,
+        )
+        return (out["paged_kv"], jnp.argmax(out["logits"][:, -1, :], -1).astype(jnp.int32),
+                out["step_counters"])
+
+    b, s = (1, chunk) if program == "prefill" else (slots, 1)
+    operands = [params, cache, shaped((b, table), jnp.int32), shaped((b,), jnp.int32),
+                shaped((b, s), jnp.int32), shaped((b, s), jnp.bool_)]
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(step, donate_argnums=(1,)).lower(*operands).compile()
+    text = compiled.as_text()
+    # two layers' latent kernel, the routed layer's two grouped products
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    assert text.count("latent_attention") >= 2
+    pool, w_in, w_out = 2 * blocks * bs * 640, 16 * 7168 * 4096, 16 * 2048 * 7168
+    assert buffers_moved(text, [pool, pool // 2]) == {"moved": [], "unaliased": []}
+    assert buffers_moved(text, [w_in, w_out])["moved"] == []
+    # the temporaries are activations (a chunk's 128 absorbed queries of 640, the
+    # logits: 336 MB for the chunk), under half a layer's slab of the cell's pool (40,961 blocks: 839 MB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 420e6
+
+
 def test_v5e4_train_step_moves_no_head_sized_array_inside_the_loss_loop(topo):
     """The train cell's step (Mistral-7B widths, 8 x 4,096 tokens, bf16
     compute over float32 parameters, adamw, ``fsdp=4``, ``remat``; ONE
