@@ -498,16 +498,30 @@ def _paged_attention_pallas_sharded(q, k_pool, v_pool, layer, block_tables, idx,
 # latent attention: one cached vector a token for every head (MLA, absorbed)
 # ---------------------------------------------------------------------------
 
-#: table entries a softmax step of the latent kernel takes (``_TILE``'s twin:
-#: chosen on the v5e for one "kv head" of 640 lanes against 128 query heads,
-#: PERF.md section 6, PR 42)
-_LATENT_TILE = 8
+#: table entries a softmax step of the latent kernel takes (``_TILE``'s twin for
+#: one "kv head" of 640 lanes against 128 query heads): 512 positions at blocks
+#: of 16. What a step pays whatever it takes - the float32 accumulator read,
+#: scaled and rewritten, the softmax state, the copies' start and wait - is paid
+#: a quarter as often as at 8. Timed on the v5e (PERF.md sections 5 and 6, PR
+#: 43; a 512-token chunk at 16k of context, ms a layer's call at 8 / 16 / 32 /
+#: 64): 37.0 / 22.0 / 20.6 / 19.3 at a row block of 512; 64 loses at short
+#: contexts what it gains at long ones (a part tile's products run whole), and
+#: a decode row reads best at 32 too, a row of the cell's few live ones within
+#: 0.01 ms of 16
+_LATENT_TILE = 32
 
 #: stacked query rows (heads x queries, head-major) a grid step of the latent
-#: kernel takes: every head of a decode row at once (128), four heads' worth
-#: of a 128-token chunk; what a step keeps in VMEM is this many rows of query,
-#: output and float32 accumulator (1 MB at a rank of 512)
-_LATENT_ROW_BLOCK = 512
+#: kernel takes: every head of a decode row at once (128), four heads' queries
+#: of a 512-token chunk. Every row block walks and copies the row's context
+#: again, and pays a tile's own costs (its copies' loop, its mask, the select
+#: of its values) once more; 512 / 1,024 / 2,048 / 4,096 read 19.7 / 17.0 /
+#: 15.4 / 15.4 ms at 16k and 1.65 / 1.65 / 1.55 / 1.74 at 1k (PERF.md, PR 43)
+_LATENT_ROW_BLOCK = 2048
+
+#: VMEM the latent kernel may take: a row block of 2,048 keeps 28 MB (a
+#: 1,024-token chunk 32: query and output blocks twice, the float32
+#: accumulator, scores and probabilities), over the compiler's 16 MB default
+_LATENT_VMEM_LIMIT = 64 << 20
 
 
 def latent_attention(
@@ -539,9 +553,9 @@ def latent_attention(
 
     Routes as :func:`paged_attention`: ``"pallas"`` (the kernel
     ``latent_attention``: the same walk over a row's own table entries, a
-    tile of ``_TILE`` entries a softmax step, the products in the pool's
-    type with float32 accumulation), ``"lax"`` (a scan over table entries)
-    and ``"gather"`` (the span materialised, then
+    tile of ``_LATENT_TILE`` entries a softmax step, the products in the
+    pool's type with float32 accumulation), ``"lax"`` (a scan over table
+    entries) and ``"gather"`` (the span materialised, then
     :func:`ops.layers.cached_latent_attention`). No mesh: one vector for all
     heads leaves nothing to split over a head axis."""
     if impl is None:
@@ -604,15 +618,20 @@ def _latent_kernel(bt_ref, idx_ref, layer_ref, q_ref, *rest, bs, tile, s, rank, 
     width]`` (head-major: stacked row ``g`` is query ``g % s`` of head ``g //
     s``) and walks the row's live table entries a tile at a time, each live
     entry copied from ``(layer, block)`` of the pool in HBM into its rows of
-    a VMEM buffer, ``_IN_FLIGHT`` tiles ahead. A softmax step is ``[rows,
-    width] x [tile * bs, width]^T`` (``width`` the pool's stored one, whole
-    lane tiles: the query's padding lanes are zeros), one max / exp / sum,
-    and ``[rows, tile * bs] x [tile * bs, rank]``: the buffer's leading lanes
-    are the values. The
-    products take their operands in the query's type (a quantized pool's
-    rows times their scale first) and accumulate in float32. Rows of the
-    buffer that no copy of this tile wrote are zeroed where they are used as
-    values (``0 x NaN``), as there."""
+    a VMEM buffer, ``_IN_FLIGHT`` tiles ahead. The copies of a tile are
+    started by ONE loop of as many trips as the tile has live entries
+    (written out for a whole tile they read 3-6 % faster on a chunk and cost
+    every run of the DeepSeek-V3 cell 4 s of tracing, ``setup_s`` 21.0
+    against 17.2: PERF.md section 6, PR 43), and a whole tile's are waited
+    for at once. A softmax step is ``[rows, width] x [tile * bs, width]^T``
+    (``width`` the pool's stored one, whole lane tiles: the query's padding
+    lanes are zeros), one max / exp / sum, and ``[rows, tile * bs] x [tile *
+    bs, rank]``: the buffer's leading lanes are the values. The products
+    take their operands in the query's type (a quantized pool's rows times
+    their scale first) and accumulate in float32. Rows of the
+    buffer that no copy of this tile wrote (up to ``tile - 1`` entries of
+    the row's last tile) are masked out of the scores and zeroed where they
+    are used as values (``0 x NaN``), as there."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -632,14 +651,36 @@ def _latent_kernel(bt_ref, idx_ref, layer_ref, q_ref, *rest, bs, tile, s, rank, 
     row0 = pl.program_id(1) * rows
 
     def for_live_entries(t, act):
+        """``act`` on the copies of tile ``t``'s live entries, one a pool
+        operand and entry: a loop of as many trips as the tile has live
+        entries, none for a tile past the row's last."""
         slot = t % depth
-        for e in range(tile):
-            @pl.when(t * tile + e < live)
-            def _entry():
-                blk = bt_ref[i, t * tile + e]
-                for a, (pool, buf) in enumerate(zip(layers, bufs)):
-                    act(pltpu.make_async_copy(
-                        pool.at[blk], buf.at[slot, pl.ds(e * bs, bs)], sems.at[a, slot]))
+
+        def _entry(e, carry):
+            blk = bt_ref[i, t * tile + e]
+            for a, (pool, buf) in enumerate(zip(layers, bufs)):
+                act(pltpu.make_async_copy(
+                    pool.at[blk], buf.at[slot, pl.ds(pl.multiple_of(e * bs, bs), bs)],
+                    sems.at[a, slot]))
+            return carry
+
+        jax.lax.fori_loop(0, jnp.clip(live - t * tile, 0, tile), _entry, 0)
+
+    def wait_live_entries(t):
+        """Wait for tile ``t``'s copies: they share a semaphore a pool, so a
+        whole tile's are ONE wait for all their bytes; the row's last, part
+        tile waits entry by entry."""
+        slot = t % depth
+        whole = live - t * tile >= tile
+
+        @pl.when(whole)
+        def _():
+            for a, buf in enumerate(bufs):
+                pltpu.make_async_copy(buf.at[slot], buf.at[slot], sems.at[a, slot]).wait()
+
+        @pl.when(jnp.logical_not(whole))
+        def _():
+            for_live_entries(t, lambda dma: dma.wait())
 
     for t in range(_IN_FLIGHT):
         for_live_entries(t, lambda dma: dma.start())
@@ -652,7 +693,7 @@ def _latent_kernel(bt_ref, idx_ref, layer_ref, q_ref, *rest, bs, tile, s, rank, 
 
     def _step(t, carry):
         for_live_entries(t + _IN_FLIGHT, lambda dma: dma.start())
-        for_live_entries(t, lambda dma: dma.wait())
+        wait_live_entries(t)
         slot = t % depth
         k_pos = t * span + jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
         if s == 1:  # a decode row: every head's one query stands at ``first``
@@ -720,7 +761,9 @@ def _latent_attention_pallas(q, pool, layer, bt, idx, pool_scale, rank, scale, *
                           scale=float(scale), quantized=quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, rows, rank), q.dtype),
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_LATENT_VMEM_LIMIT),
         interpret=interpret,
         name="latent_attention",
     )(bt, idx, layer.reshape(1), stacked, *pools)

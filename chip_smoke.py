@@ -10,8 +10,9 @@ with seeded random weights:
   mode, once with a bf16 KV pool and once with an int8 pool;
 
 and checks the Pallas kernels under them against the repo's pure-JAX
-references on the chip (paged attention vs ``impl="gather"``, flash attention
-forward and gradients vs ``blockwise_attention``). On a host with four chips
+references on the chip (paged attention vs ``impl="gather"``, latent attention
+vs ``impl="lax"``, flash attention forward and gradients vs
+``blockwise_attention``). On a host with four chips
 it also trains under ``fsdp=2,tp=2`` and ``fsdp=4`` and serves under
 ``--mesh`` with ``tp=4``.
 
@@ -85,6 +86,13 @@ HYBRID_RTOL = 3e-2
 #: sides, the hidden activation rounded to bf16 between the two products;
 #: the two routes tile the contraction differently
 MOE_RTOL = 2e-2
+
+#: the latent kernel's row: heads, stored lanes, rank, lanes in use, block
+#: size, table entries and pool blocks at the DeepSeek-V3 cell's widths; the
+#: live decode rows' contexts; a chunk's length and where it ends
+LATENT_GEOMETRY = (128, 640, 512, 576, 16, 1024, 2048)
+LATENT_DECODE = (16000, 8192, 4096, 2048, 1024, 300, 17)
+LATENT_CHUNK = (512, 4096)
 
 _COMPILED = re.compile(r"Finished XLA compilation of (\S+) in ([0-9.]+) sec")
 
@@ -506,6 +514,7 @@ def _kernels() -> None:
             "pallas vs gather", outs["pallas"], outs["gather"], PAGED_ATOL)
 
     ok &= _paged_call_times(rng)
+    ok &= _latent_check(rng)
 
     # flash attention forward and gradients at the train shapes
     for b, s in ((8, 1024), (1, 8192)):
@@ -590,6 +599,77 @@ def _paged_call_times(rng, lives=(2, 11, 22, 33, 64)) -> bool:
                     _paged_ms_a_call(parent, q, pools, tables, idx, block_len), 4)
             print("KERNEL " + json.dumps(row), flush=True)
     return ok
+
+
+def _latent_check(rng) -> bool:
+    """The latent kernel (``ops/paged_attention.py:latent_attention``) at the
+    DeepSeek-V3 cell's widths - 128 heads absorbed over rows stored 640 wide
+    (512 of values, 64 of the shared rotated key, zeros), blocks of 16, 40
+    slots x 1,024 table entries - against the ``lax`` route on the chip: a
+    decode step with 7 of 40 rows live from 17 to 16,000 positions and the
+    rest free slots as the engine dispatches them, and one 512-token chunk
+    that ends at 4,096 (``LATENT_*``); finite, within the paged bound, and
+    the milliseconds a call (a set-up fact of this machine, for ``PERF.md``).
+    Where a builder unpacked a parent commit that has the kernel, it is timed
+    beside it and has to agree within the same bound."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    this = importlib.import_module("accelerate_tpu.ops.paged_attention")
+    parent = _parent_ops("paged_attention")
+    parent = parent if hasattr(parent, "latent_attention") else None
+    nh, width, rank, live_lanes, bs, mb, nb = LATENT_GEOMETRY
+    lanes = jnp.arange(width) < live_lanes
+    keys = jax.random.split(jax.random.PRNGKey(int(rng.integers(1 << 30))), 3)
+    pool = (jax.random.normal(keys[0], (2, nb, bs, width), jnp.float32) * 0.5 * lanes
+            ).astype(jnp.bfloat16)
+    ok, slots = True, 40
+    for label, s, contexts in (
+        (f"decode, {len(LATENT_DECODE)} of {slots} rows live to {LATENT_DECODE[0]}", 1,
+         LATENT_DECODE + (None,) * (slots - len(LATENT_DECODE))),
+        ("a %d-token chunk that ends at %d" % LATENT_CHUNK, LATENT_CHUNK[0], LATENT_CHUNK[1:]),
+    ):
+        tables = np.zeros((len(contexts), mb), np.int32)
+        idx = np.zeros((len(contexts),), np.int32)
+        for i, context in enumerate(contexts):
+            if context is not None:
+                entries = (context - 1) // bs + 1
+                tables[i, :entries] = 1 + rng.permutation(nb - 1)[:entries]
+                idx[i] = context - s
+        q = (jax.random.normal(keys[1 if s == 1 else 2], (len(contexts), s, nh, width),
+                               jnp.float32) * lanes).astype(jnp.bfloat16)
+        fns = {
+            name: functools.partial(jax.jit(
+                lambda q, pool, mod=mod, impl=impl: mod.latent_attention(
+                    q, pool, 1, tables, idx, rank=rank, scale=0.135, impl=impl)), pool=pool)
+            for name, mod, impl in (("pallas", this, "pallas"), ("lax", this, "lax"))
+            + ((("parent", parent, "pallas"),) if parent else ())
+        }
+        label = f"latent attention {list(q.shape)} over rows of {width}, {label}"
+        got = fns["pallas"](q)
+        ok &= _kernel_row(label + ", pallas vs lax", got, fns["lax"](q), PAGED_ATOL)
+        row = {"check": label + ": ms a call", "ok": True,
+               "tile": this.tile_entries(mb, latent=True),
+               "ms_a_call": round(_ms_a_call(fns["pallas"], q), 4)}
+        if parent:
+            ok &= _kernel_row(label + ", pallas vs the parent's", got, fns["parent"](q), PAGED_ATOL)
+            row["parent_ms_a_call"] = round(_ms_a_call(fns["parent"], q), 4)
+        print("KERNEL " + json.dumps(row), flush=True)
+    return ok
+
+
+def _latent() -> None:
+    """The latent kernel's rows of :func:`_kernels` alone."""
+    import numpy as np
+
+    from accelerate_tpu.mesh import configure_compile_cache
+
+    configure_compile_cache()
+    if not _latent_check(np.random.default_rng(0)):
+        sys.exit("the latent kernel disagrees with the scan beyond the stated bound")
 
 
 def _paged_calls() -> None:
@@ -1050,7 +1130,7 @@ def _engine_check() -> None:
 
 _CHILDREN = {
     "_probe": _probe, "_kernels": _kernels, "_paged_calls": _paged_calls, "_experts": _experts,
-    "_train": _train,
+    "_latent": _latent, "_train": _train,
     "_engine_check": _engine_check,
 }
 

@@ -180,6 +180,66 @@ def test_one_decode_and_one_prefill_executable_and_what_stats_says(served):
     assert z["paged_entries_walked_total"] == 0 and z["latent_bytes_per_token"] == 2048
 
 
+def test_paged_tiles_are_counted_at_the_tiles_the_latent_kernel_takes(tiny, monkeypatch):
+    """``paged_tiles_walked_total x paged_tile_entries`` against the entries
+    a served prompt's chunks and its decode steps walked, rounded up to the
+    tile the kernel really takes at each shape (read off a trace of the
+    Pallas route at the engine's decode and chunk shapes, not off the
+    constant): a table of 64 entries of 8 is wider than the tile, a prompt of
+    300 tokens walks 38 of them, and the free slot walks one."""
+    pa = sys.modules["accelerate_tpu.ops.paged_attention"]
+    model, c = tiny
+    bs, chunk, burst, slots = 8, 16, 4, 2
+    engine = _engine(model, num_slots=slots, max_seq_len=512, block_size=bs,
+                     prefill_chunk=chunk, decode_burst=burst, prefix_cache=False)
+    mb, layers, reported = 64, 4, engine.stats()["paged_tile_entries"]
+    assert engine.config.blocks_per_slot == mb > reported
+
+    taken, real = {}, pa._latent_kernel
+
+    def spy(*refs, tile, s, **kw):
+        taken[s] = tile
+        return real(*refs, tile=tile, s=s, **kw)
+
+    monkeypatch.setattr(pa, "_latent_kernel", spy)
+    width, shaped = engine._cache["k"].shape[-1], jax.ShapeDtypeStruct
+    for b, s in ((slots, 1), (1, chunk)):
+        jax.eval_shape(
+            lambda q, pool, bt, idx: pa.latent_attention(
+                q, pool, 0, bt, idx, rank=c.kv_lora_rank, scale=1.0, impl="pallas",
+                interpret=True),
+            shaped((b, s, c.num_attention_heads, width), jnp.float32),
+            shaped(engine._cache["k"].shape, jnp.float32),
+            shaped((b, mb), jnp.int32), shaped((b,), jnp.int32))
+    assert set(taken) == {1, chunk} and all(t % reported == 0 for t in taken.values())
+
+    seen = {"prefill": [], "decode": []}
+
+    def recorded(kind, fn):
+        def call(*args):
+            seen[kind].append(np.array(args[3]))
+            return fn(*args)
+        return call
+
+    engine._prefill_fn = recorded("prefill", engine._prefill_fn)
+    engine._decode_fn = recorded("decode", engine._decode_fn)
+    request = _ask(engine, np.random.default_rng(5).integers(0, 256, size=300).tolist(), 12)
+    engine.run_until_idle()
+    assert len(request.output_tokens) == 12 and len(seen["prefill"]) == 19
+
+    walked = covered = 0
+    rows = [((int(p[0]) + chunk - 1) // bs + 1, chunk) for p in seen["prefill"]]
+    rows += [((int(p) + step) // bs + 1, 1)
+             for pos0 in seen["decode"] for step in range(burst) for p in pos0]
+    for entries, s in rows:
+        walked += layers * entries
+        covered += layers * -(-entries // taken[s]) * taken[s]
+    stats = engine.stats()
+    assert stats["paged_entries_walked_total"] == walked
+    assert stats["paged_tiles_walked_total"] * reported == covered
+    assert walked < covered < 3 * walked  # two tiles for 38 entries; a free slot's one
+
+
 def test_no_compile_at_a_context_the_warm_up_never_saw(tiny):
     """Warmed as the benchmark's driver warms an engine (one prompt of
     ``prefill_chunk + 5`` tokens), then contexts several times as long, more

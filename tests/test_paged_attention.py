@@ -22,7 +22,13 @@ from accelerate_tpu.ops.fp8 import (
     quantize_kv_rows,
 )
 from accelerate_tpu.ops.layers import cached_attention, last_visible, write_paged_kv
-from accelerate_tpu.ops.paged_attention import _TILE, paged_attention
+from accelerate_tpu.ops.paged_attention import (
+    _LATENT_ROW_BLOCK,
+    _TILE,
+    latent_attention,
+    paged_attention,
+    tile_entries,
+)
 
 #: ops-level |fused_quantized - f32_reference| ceilings on attention
 #: outputs (unit-variance inputs). int8 carries ~0.4% relative error per
@@ -330,6 +336,88 @@ def test_pallas_tile_rows_that_no_copy_wrote_do_not_reach_the_output(contexts, s
     ref = np.asarray(paged_attention(q, *pools, 1, bt, idx, *scales, impl="gather"))
     tol = 1e-5 if store is None else 1e-4
     np.testing.assert_allclose(out, ref, rtol=tol, atol=tol)
+
+
+# -- the latent kernel: one cached vector a token for every head ----------------
+
+#: the latent walk's geometry: a pool row of 256 stored lanes - 128 of values,
+#: 64 of the shared rotated key, zeros up to whole lane tiles - in blocks of
+#: 16; a table of two whole tiles of the latent kernel's own and half a third
+LAT_WIDTH, LAT_RANK, LAT_ROPE = 256, 128, 64
+LAT_TILE = tile_entries(1 << 20, latent=True)
+LAT_SPAN = LAT_TILE * WALK_BS
+LAT_MB = 2 * LAT_TILE + LAT_TILE // 2
+
+
+def _latent_case(rng, contexts, s, nh, mb, store):
+    """A stacked latent pool ``[LAYERS, nb, 16, 256]`` of noise (an fp8 pool
+    with its one scale a row) and a block table of ``mb`` entries a row: a row
+    whose last query sits at ``context - 1`` holds blocks of its own for the
+    entries up to that position's, the null block behind them; ``None`` is a
+    free slot (position 0, the null block). Queries and rows carry zeros in
+    the padding lanes, as the model hands them over."""
+    nb = 1 + len(contexts) * mb
+    lanes = np.arange(LAT_WIDTH) < LAT_RANK + LAT_ROPE
+    pool = jnp.asarray(rng.normal(size=(LAYERS, nb, WALK_BS, LAT_WIDTH)) * lanes, jnp.float32)
+    scale = None
+    if store == "fp8":
+        pool, scale = quantize_kv_rows(pool, kv_storage_dtype("fp8")[0])
+        scale = scale[..., None]
+    bt = np.zeros((len(contexts), mb), np.int32)
+    idx = np.zeros((len(contexts),), np.int32)
+    used = iter(rng.permutation(np.arange(1, nb)))
+    for i, context in enumerate(contexts):
+        if context is None:
+            continue
+        idx[i] = context - s
+        for j in range((context - 1) // WALK_BS + 1):
+            bt[i, j] = next(used)
+    q = jnp.asarray(rng.normal(size=(len(contexts), s, nh, LAT_WIDTH)) * lanes, jnp.float32)
+    return q, pool, bt, idx, scale
+
+
+def _latent_shapes():
+    """(queries a row, heads, table entries, the rows' contexts, pool). Decode
+    rows whose contexts end inside the first tile, one short of its end, on
+    it, one past it, several tiles in and at the table's end, a one-entry row
+    first (its tile's other rows are what the scratch held: NaN in the
+    interpreter) and a free slot between live rows; chunks that end at the
+    same places; three heads (stacked rows padded to whole sublanes, and a
+    chunk's to whole row blocks); a table narrower than a tile; an fp8 pool."""
+    ends = (LAT_SPAN - 1, LAT_SPAN, LAT_SPAN + 1, 2 * LAT_SPAN + 40, LAT_MB * WALK_BS)
+    yield pytest.param(1, 4, LAT_MB, (1, LAT_SPAN // 5, None) + ends, None, id="decode")
+    yield pytest.param(1, 3, LAT_MB, (7, None, 2 * LAT_SPAN + 40, LAT_SPAN), None,
+                       id="decode-rows-padded")
+    yield pytest.param(1, 4, LAT_TILE // 2 - 1, (5, None, (LAT_TILE // 2 - 1) * WALK_BS, 33), None,
+                       id="decode-table-narrower-than-a-tile")
+    yield pytest.param(1, 4, LAT_MB, (3, None, LAT_SPAN + 1, 2 * LAT_SPAN + 40), "fp8",
+                       id="decode-fp8")
+    for context in (24, LAT_SPAN // 5) + ends:
+        yield pytest.param(24, 4, LAT_MB, (context,), None, id=f"chunk-ends-{context}")
+    yield pytest.param(24, 4, LAT_MB, (LAT_SPAN + 1, 2 * LAT_SPAN + 40), None, id="chunk-two-rows")
+    blocks = _LATENT_ROW_BLOCK // 3 + 30            # queries a head: two row blocks of three heads
+    yield pytest.param(blocks, 3, LAT_MB, (2 * LAT_SPAN + 40,), None,
+                       id="chunk-rows-padded-to-two-blocks")
+    yield pytest.param(24, 4, LAT_TILE // 2 - 1, (40,), None, id="chunk-table-narrower-than-a-tile")
+    yield pytest.param(24, 4, LAT_MB, (LAT_SPAN + 1,), "fp8", id="chunk-fp8")
+
+
+@pytest.mark.parametrize("reference", ["lax", "gather"])
+@pytest.mark.parametrize("s, nh, mb, contexts, store", _latent_shapes())
+def test_latent_kernel_matches_the_scan_and_the_gathered_span(s, nh, mb, contexts, store,
+                                                               reference):
+    """The kernel ``latent_attention`` in the Pallas interpreter against the
+    scan over table entries and the gathered span: a softmax step takes a
+    tile of the latent kernel's own over a block of stacked (head, query)
+    rows, its live entries copied by a loop of as many trips, and rows of the
+    tile that no copy wrote never reach the output."""
+    q, pool, bt, idx, scale = _latent_case(np.random.default_rng(43), contexts, s, nh, mb, store)
+    run = lambda impl: np.asarray(latent_attention(
+        q, pool, 1, bt, idx, rank=LAT_RANK, scale=0.11, pool_scale=scale, impl=impl,
+        interpret=True))
+    out = run("pallas")
+    assert out.shape == (len(contexts), s, nh, LAT_RANK) and np.isfinite(out).all()
+    np.testing.assert_allclose(out, run(reference), rtol=2e-5, atol=2e-5)
 
 
 # -- block_len: causal from block to block, bidirectional inside a block ---------
