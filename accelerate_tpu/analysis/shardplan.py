@@ -459,7 +459,13 @@ def plan_kv_pool(
     the folded dim goes over ``tp`` — whole kv heads per shard — when
     ``tp`` divides ``num_kv_heads``, else replicated. ``num_blocks``
     defaults to the engine's full-residency default (slots × per-slot max
-    + null block).
+    + null block). A model whose paged layers are of several kinds
+    (``models/cache.py:PagedKind``) is planned a kind at a time: this is the
+    plan of ONE kind's pool (``num_layers`` its layers), which for the first
+    kind is what ``num_blocks`` counts; a kind that keeps a window has a pool
+    of ``CacheSpec.window_pools`` blocks, priced as a fixed cost by
+    ``CacheSpec.window_pool_bytes`` (``engine_preflight``'s ``state_bytes``,
+    ``serve --auto-blocks``).
 
     Quantized storage (``dtype`` of ``int8``/``fp8``/``float8_e4m3fn`` —
     the engine's ``kv_dtype`` policy) adds the two f32 amax scale arrays

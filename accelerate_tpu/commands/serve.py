@@ -200,10 +200,30 @@ def _auto_num_blocks(args, model, mesh) -> int:
     spec = cache_spec_of(inner).with_state_dtype(getattr(args, "state_dtype", "auto"))
     state_bytes = args.num_slots * spec.state_bytes_per_slot(
         "bfloat16" if args.dtype == "bf16" else "float32")
+    # the kinds of paged layer: num_blocks counts the first kind's pool; a
+    # kind that keeps a window has a pool sized by what a slot keeps
+    # resident, a fixed cost like the state (and printed with it)
+    first = spec.paged_kinds[0]
+    window_bytes = 0
+    if spec.window_kinds:
+        from ..ops.fp8 import kv_storage_dtype
+
+        store, quantized = (
+            ("bfloat16" if args.dtype == "bf16" else "float32", False)
+            if args.kv_dtype in (None, "auto") else kv_storage_dtype(args.kv_dtype))
+        chunk = max(args.prefill_chunk, args.decode_burst)
+        window_bytes = spec.window_pool_bytes(
+            args.num_slots, args.max_seq_len, args.block_size, chunk, store, quantized)
+        pools = spec.window_pools(args.num_slots, args.max_seq_len, args.block_size, chunk)
+        print("auto-blocks: " + ", ".join(
+            f"{name} kind {n} blocks ({per_slot} a slot x {args.num_slots} slots + the null "
+            "block)" for name, (per_slot, n) in pools.items())
+            + f", {window_bytes / (1 << 30):.3f} GiB, whatever num_blocks is", file=sys.stderr)
+        state_bytes += window_bytes
     per_block = sum(
         p.bytes_per_device
         for p in plan_kv_pool(
-            num_layers=spec.paged_layers,
+            num_layers=first.layers,
             num_kv_heads=spec.kv_heads,
             head_dim=spec.pool_width // spec.kv_heads,
             num_slots=1,
@@ -225,11 +245,12 @@ def _auto_num_blocks(args, model, mesh) -> int:
         min_blocks=blocks_per_slot + 1,  # one full request + the null block
     )
     gib = 1 << 30
+    fixed = "slot state and window pools" if window_bytes else "slot state"
     print(
         f"auto-blocks: {num_blocks} blocks "
         f"({per_block / 1e6:.2f} MB/block/device; full residency "
         f"{full_residency}) — params {params_bytes / gib:.3f} GiB/device"
-        f"{f' + slot state {state_bytes / gib:.3f}' if state_bytes else ''}, "
+        f"{f' + {fixed} {state_bytes / gib:.3f}' if state_bytes else ''}, "
         f"predicted headroom {headroom / gib:.3f} GiB under the "
         f"{budget_bytes / gib:.3f} GiB budget",
         file=sys.stderr,
@@ -251,7 +272,7 @@ def _make_engine(args):
     num_blocks = args.num_blocks
     if args.auto_blocks:
         num_blocks = _auto_num_blocks(args, model, mesh)
-    return InferenceEngine(
+    engine = InferenceEngine(
         model,
         EngineConfig(
             num_slots=args.num_slots,
@@ -281,6 +302,12 @@ def _make_engine(args):
         ),
         mesh=mesh,
     )
+    for name, n in engine.window_num_blocks.items():
+        # --num-blocks is the first kind's count; a window kind's is derived
+        print(f"serve: {name} kind: {n} blocks ({engine.window_blocks_per_slot[name]} a slot x "
+              f"{args.num_slots} slots + the null block), "
+              f"{engine.window_pool_bytes / (1 << 30):.3f} GiB", file=sys.stderr)
+    return engine
 
 
 def _write_flight_drain(logging_dir, engine, k: int = 32) -> None:

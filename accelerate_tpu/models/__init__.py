@@ -121,7 +121,7 @@ def __getattr__(name):
 #: the ``model_type`` values :func:`config_from_hf_json` maps
 KNOWN_MODEL_TYPES = (
     "llama", "mistral", "gpt2", "bert", "vit", "opt", "gpt_neox", "gptj", "mixtral",
-    "t5", "mt5", "granitemoehybrid", "lfm2_moe", "sdar_moe", "deepseek_v3",
+    "t5", "mt5", "granitemoehybrid", "lfm2_moe", "sdar_moe", "deepseek_v3", "smallthinker",
 )
 
 
@@ -136,6 +136,16 @@ def config_from_hf_json(path: str):
         d = json.load(f)
     mt = d.get("model_type", "llama")
     if mt in ("llama", "mistral"):
+        if d.get("sliding_window") is not None and d.get("use_sliding_window", True):
+            # every layer of models/llama.py attends the whole context; a
+            # published window is not dropped in silence
+            raise ValueError(
+                f"{mt} with sliding_window {d['sliding_window']!r}: models/llama.py attends "
+                "the whole context in every layer and declares one cache kind, so the model "
+                "as published would be served as another one. A window kind exists "
+                "(models/cache.py:PagedKind, as models/smallthinker.py declares it); this "
+                "family is not on it yet. Set sliding_window to null to serve the "
+                "full-attention model (what Mistral-7B-v0.3 publishes)")
         return LlamaConfig(
             head_dim=d.get("head_dim"),
             vocab_size=d.get("vocab_size", 32000),
@@ -318,6 +328,21 @@ def config_from_hf_json(path: str):
         # num_key_value_heads (latent attention has no kv head) are read by nothing
         fields = {f.name for f in dataclasses.fields(DeepseekV3Config)} - {"remat"}
         return DeepseekV3Config(**{k: d[k] for k in fields if d.get(k) is not None})
+    if mt == "smallthinker":
+        from .smallthinker import SmallThinkerConfig
+
+        # the published keys by their names; what cannot be built as published
+        # (a sigmoid router, layouts of another length than the depth, a window
+        # layer first) is refused by the config itself. Keys for the "secondary
+        # experts" the model card speaks of are not in the published file
+        for key, built in (("rope_scaling", None), ("attention_bias", False),
+                           ("hidden_act", "relu"), ("moe_enable_secondary_experts", False)):
+            if d.get(key, built) not in (built, None):
+                raise ValueError(
+                    f"smallthinker with {key} {d[key]!r}: built as published for "
+                    f"SmallThinker-21BA3B-Instruct, {key} {built!r}")
+        fields = {f.name for f in dataclasses.fields(SmallThinkerConfig)} - {"remat"}
+        return SmallThinkerConfig(**{k: d[k] for k in fields if d.get(k) is not None})
     raise ValueError(
         f"unsupported model_type {mt!r} (known: {', '.join(KNOWN_MODEL_TYPES)})"
     )
@@ -345,6 +370,10 @@ def model_factory_for_config(config):
         from .deepseek_v3 import DeepseekV3ForCausalLM
 
         return lambda c, **kw: DeepseekV3ForCausalLM.from_config(c, **kw)
+    if name == "SmallThinkerConfig":
+        from .smallthinker import SmallThinkerForCausalLM
+
+        return lambda c, **kw: SmallThinkerForCausalLM.from_config(c, **kw)
     if name == "GPT2Config":
         from .gpt2 import GPT2LMHeadModel
 

@@ -2,7 +2,9 @@
 allocated by the serving engine.
 
 Three kinds of cache exist: ``paged`` — K and V per token, in blocks of a
-shared pool, for the layers that attend —, ``latent`` — paged too, but ONE
+shared pool, for the layers that attend; a model whose attention layers differ
+in how much of the past they keep declares them as :class:`PagedKind`s, a
+pool and a block table each —, ``latent`` — paged too, but ONE
 vector per token and layer that every query head shares and whose leading
 entries are also the values (multi-head latent attention: no kv head, no
 separate V) — and ``slot_state`` — arrays of a fixed size per slot (a
@@ -46,8 +48,59 @@ class SlotStateLeaf:
 
 
 @dataclass(frozen=True)
+class PagedKind:
+    """One kind of paged layer: ``layers`` of them share a pool ``[layers,
+    num_blocks, block_size, kv_heads * head_dim]`` and a block table a slot.
+    ``window`` 0 keeps the whole past; above 0 a query at position ``p``
+    attends positions ``p - window < j <= p`` and no later query attends an
+    earlier one, so a block that lies wholly behind the window of a slot's
+    oldest query in flight goes back to the kind's allocator. The kind's
+    table addresses a position as every table does (entry ``pos //
+    block_size``); the entries behind the window point at the null block."""
+
+    name: str
+    layers: int
+    kv_heads: int
+    head_dim: int
+    window: int = 0
+
+    def bytes_per_token(self, store_dtype, quantized: bool = False) -> int:
+        """Bytes one cached position costs across this kind's layers (K and
+        V rows, and a float32 scale a row and kv head where quantized)."""
+        return 2 * self.layers * (
+            self.kv_heads * self.head_dim * np.dtype(store_dtype).itemsize
+            + (4 * self.kv_heads if quantized else 0))
+
+    def resident_tokens(self, context: int, chunk: int = 1) -> int:
+        """Positions of a request at ``context`` that this kind keeps while a
+        dispatch of ``chunk`` queries runs: all of them without a window;
+        with one, the ``window - 1`` before the dispatch's first query and
+        the dispatch's own."""
+        if not self.window:
+            return int(context)
+        return int(min(context, self.window - 1 + chunk))
+
+    def blocks_per_slot(self, max_seq_len: int, block_size: int, chunk: int) -> int:
+        """The most blocks of this kind one slot ever holds: the table's
+        width without a window; with one, the blocks ``window - 1 + chunk``
+        consecutive positions can touch (one more than they fill, since the
+        span starts anywhere in a block)."""
+        full = -(-int(max_seq_len) // int(block_size))
+        if not self.window:
+            return full
+        return min(full, -(-self.resident_tokens(max_seq_len, chunk) // int(block_size)) + 1)
+
+    def pool_leaf(self, leaf: str, first: bool) -> str:
+        """The name of this kind's ``"k"`` / ``"v"`` array in the cache dict:
+        the first kind's are ``"k"`` and ``"v"``, as a model of one kind has
+        them; a further kind's carry its name (``"k_window"``)."""
+        return leaf if first else f"{leaf}_{self.name}"
+
+
+@dataclass(frozen=True)
 class CacheSpec:
-    #: layers that hold block-paged K/V (the pool's leading dimension)
+    #: layers that hold block-paged K/V (the pool's leading dimension; with
+    #: ``kinds`` the sum over them)
     paged_layers: int
     kv_heads: int
     head_dim: int
@@ -59,13 +112,40 @@ class CacheSpec:
     #: and its first ``latent_rank`` entries are what attention sums as the
     #: values, so the pool is the one leaf ``"k"`` and nothing is kept twice
     latent_rank: int = 0
+    #: the kinds of paged layer, where they differ (:class:`PagedKind`); empty:
+    #: ONE kind of ``paged_layers`` layers that keeps the whole past, which is
+    #: what every consumer allocates, prices and traces for a model that says
+    #: nothing. The first kind is the one ``num_blocks`` counts and the
+    #: scheduler admits by; it keeps the whole past
+    kinds: tuple = ()
 
     def __post_init__(self):
+        if self.kinds:
+            if self.latent_rank or self.kinds[0].window:
+                raise ValueError(
+                    "kinds of paged layers: the first keeps the whole past (window 0) "
+                    "and none is latent")
+            if sum(k.layers for k in self.kinds) != self.paged_layers:
+                raise ValueError(
+                    f"kinds hold {sum(k.layers for k in self.kinds)} layers, "
+                    f"paged_layers says {self.paged_layers}")
         if self.latent_rank and (self.kv_heads != 1 or not 0 < self.latent_rank <= self.head_dim):
             raise ValueError(
                 f"a latent cache keeps one vector a token for all heads: kv_heads "
                 f"{self.kv_heads} (want 1), latent_rank {self.latent_rank} of head_dim "
                 f"{self.head_dim}")
+
+    @property
+    def paged_kinds(self) -> tuple:
+        """The kinds, the undeclared one kind included."""
+        return self.kinds or (
+            PagedKind("full", self.paged_layers, self.kv_heads, self.head_dim),)
+
+    @property
+    def window_kinds(self) -> tuple:
+        """The kinds that keep a window of the past (none: blocks are the
+        whole of a request's past in every paged layer)."""
+        return tuple(k for k in self.paged_kinds if k.window)
 
     @property
     def pool_leaves(self) -> tuple:
@@ -87,9 +167,34 @@ class CacheSpec:
         """Bytes one cached token costs across the paged layers: every pool
         leaf's stored row, and a float32 scale a row and kv head where the
         pool is quantized."""
+        if self.kinds:
+            # every kind's, as if each kept the position: what a position
+            # costs while it lies inside every window
+            return sum(k.bytes_per_token(store_dtype, quantized) for k in self.kinds)
         return len(self.pool_leaves) * self.paged_layers * (
             self.pool_width * np.dtype(store_dtype).itemsize
             + (4 * self.kv_heads if quantized else 0))
+
+    def window_pools(self, num_slots: int, max_seq_len: int, block_size: int,
+                     chunk: int) -> dict:
+        """``{kind name: (blocks a slot, blocks of the pool)}`` of the kinds
+        that keep a window: a pool holds every slot's window and the null
+        block, whatever ``num_blocks`` (the first kind's count) is. ``chunk``
+        is the most queries one dispatch asks of a row."""
+        out = {}
+        for k in self.window_kinds:
+            per_slot = k.blocks_per_slot(max_seq_len, block_size, chunk)
+            out[k.name] = (per_slot, num_slots * per_slot + 1)
+        return out
+
+    def window_pool_bytes(self, num_slots: int, max_seq_len: int, block_size: int,
+                          chunk: int, store_dtype, quantized: bool = False) -> int:
+        """Bytes of the window kinds' pools: a fixed cost like the per-slot
+        state, priced by what a slot keeps resident (``resident_tokens``
+        rounded up to blocks), not by ``num_blocks``."""
+        pools = self.window_pools(num_slots, max_seq_len, block_size, chunk)
+        return sum(pools[k.name][1] * block_size * k.bytes_per_token(store_dtype, quantized)
+                   for k in self.window_kinds)
 
     def state_bytes_per_slot(self, compute_dtype) -> int:
         return sum(leaf.bytes_per_slot(compute_dtype) for leaf in self.slot_state.values())

@@ -107,7 +107,7 @@ def resolve_flash_blocks(seq_len: int, ctx: AttentionContext) -> tuple[int, int]
     return block_q, block_kv
 
 
-def _flash_sharded(q, k, v, segment_mask, causal, scale, ctx: AttentionContext):
+def _flash_sharded(q, k, v, segment_mask, causal, scale, ctx: AttentionContext, window=0):
     """Run the flash kernel under shard_map: batch over dp/fsdp, heads over
     tp, sequence replicated (cp==1 on this path — cp>1 routes to
     ``context_parallel_attention``). Axes that don't divide the corresponding
@@ -124,7 +124,7 @@ def _flash_sharded(q, k, v, segment_mask, causal, scale, ctx: AttentionContext):
     if batch_entry is None and head_entry is None:
         return flash_attention(
             q, k, v, segment_mask=segment_mask, causal=causal, scale=scale,
-            block_q=block_q, block_kv=block_kv,
+            block_q=block_q, block_kv=block_kv, window=window,
         )
 
     qkv_spec = P(batch_entry, None, head_entry, None)
@@ -140,7 +140,7 @@ def _flash_sharded(q, k, v, segment_mask, causal, scale, ctx: AttentionContext):
             q_, k_, v_,
             segment_mask=mask_[0] if mask_ else None,
             causal=causal, scale=scale,
-            block_q=block_q, block_kv=block_kv,
+            block_q=block_q, block_kv=block_kv, window=window,
         )
 
     args = (q, k, v, segment_mask) if has_mask else (q, k, v)
@@ -154,8 +154,15 @@ def attention(
     segment_mask: jax.Array | None = None,  # [b, s] 1 = valid token
     causal: bool = True,
     scale: float | None = None,
+    window: int = 0,
 ) -> jax.Array:
+    """``window`` (static) above 0 is a causal sliding window: the query at
+    ``p`` sees the keys ``p - window < j <= p``, on the flash, blockwise and
+    reference routes; context parallelism refuses it. At 0 every route
+    traces what it traced before the parameter was there."""
     ctx = _current
+    if window and not causal:
+        raise ValueError("a sliding window is built for causal attention only")
     if (
         ctx.mesh is not None
         and ctx.cp_mode is not None
@@ -163,6 +170,9 @@ def attention(
     ):
         from ..parallel.context import context_parallel_attention
 
+        if window:
+            raise ValueError("a sliding window under context parallelism is not built: "
+                             "the ring's chunks are attended whole")
         return context_parallel_attention(
             q, k, v, segment_mask,
             mesh=ctx.mesh,
@@ -181,18 +191,18 @@ def attention(
             # GSPMD treats the Mosaic custom call as opaque, so on a sharded
             # mesh the kernel must run under shard_map with explicit batch /
             # head partitioning — otherwise XLA replicates q,k,v per device.
-            return _flash_sharded(q, k, v, segment_mask, causal, scale, ctx)
+            return _flash_sharded(q, k, v, segment_mask, causal, scale, ctx, window)
         block_q, block_kv = resolve_flash_blocks(q.shape[1], ctx)
         return flash_attention(
             q, k, v, segment_mask=segment_mask, causal=causal, scale=scale,
-            block_q=block_q, block_kv=block_kv,
+            block_q=block_q, block_kv=block_kv, window=window,
         )
     if impl == "blockwise":
         # the pure-JAX fallback has its own sweet spot — the Pallas-tuned
         # kv block would 8x the materialised score tile on CPU
         return blockwise_attention(
             q, k, v, segment_mask=segment_mask, causal=causal, scale=scale,
-            block_kv=min(max(ctx.block_kv or 1024, 128), 512),
+            block_kv=min(max(ctx.block_kv or 1024, 128), 512), window=window,
         )
     if not causal:
         from .layers import dot_product_attention
@@ -201,4 +211,4 @@ def attention(
         if segment_mask is not None:
             mask = segment_mask[:, None, None, :].astype(bool)
         return dot_product_attention(q, k, v, mask=mask, scale=scale)
-    return causal_attention(q, k, v, segment_mask=segment_mask)
+    return causal_attention(q, k, v, segment_mask=segment_mask, window=window)

@@ -39,6 +39,32 @@ def _compiler_params(n_grid: int):
 # ---------------------------------------------------------------------------
 
 
+def _block_runs(qi, ki, block_q, block_kv, causal, window):
+    """Whether key block ``ki`` holds a key some query of block ``qi`` sees:
+    not strictly above the diagonal (``causal``) and, with a ``window``, not
+    wholly behind the window of the block's first query. ``True`` (static)
+    where neither rule is on."""
+    should_run = True
+    if causal:
+        should_run = (qi + 1) * block_q > ki * block_kv
+    if window:
+        should_run = should_run & ((ki + 1) * block_kv > qi * block_q - window + 1)
+    return should_run
+
+
+def _block_mask(s, qi, ki, block_q, block_kv, causal, window):
+    """``s`` with the keys a query does not see replaced: those after it
+    (``causal``) and, with a ``window``, those ``window`` or more before it."""
+    if causal:
+        rows = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_kv), 0)
+        cols = ki * block_kv + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_kv), 1)
+        mask = rows >= cols
+        if window:
+            mask = mask & (cols > rows - window)
+        s = jnp.where(mask, s, NEG_INF)
+    return s
+
+
 def _fwd_kernel(
     q_ref,  # (1, 1, bq, d)
     k_ref,  # (1, 1, bkv, d)
@@ -57,6 +83,7 @@ def _fwd_kernel(
     block_q: int,
     block_kv: int,
     num_kv_blocks: int,
+    window: int = 0,
 ):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
@@ -67,10 +94,9 @@ def _fwd_kernel(
         l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    # causal: skip blocks strictly above the diagonal
-    should_run = True
-    if causal:
-        should_run = (qi + 1) * block_q > ki * block_kv
+    # causal: skip blocks strictly above the diagonal (and, with a window,
+    # those wholly behind it)
+    should_run = _block_runs(qi, ki, block_q, block_kv, causal, window)
 
     @pl.when(should_run)
     def _compute():
@@ -83,11 +109,7 @@ def _fwd_kernel(
         s = s * scale
         if bias_ref is not None:
             s = s + bias_ref[0, 0, 0, :][None, :].astype(jnp.float32)
-        if causal:
-            rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_kv), 0)
-            cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_kv), 1)
-            mask = (qi * block_q + rows) >= (ki * block_kv + cols)
-            s = jnp.where(mask, s, NEG_INF)
+        s = _block_mask(s, qi, ki, block_q, block_kv, causal, window)
 
         m_prev = m_scr[:, 0][:, None]  # (bq, 1)
         m_cur = jnp.max(s, axis=-1)[:, None]
@@ -125,7 +147,7 @@ def _fwd_kernel(
 def _bwd_dq_kernel(
     q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
     dq_ref, dq_scr,
-    *, scale, causal, block_q, block_kv, num_kv_blocks,
+    *, scale, causal, block_q, block_kv, num_kv_blocks, window=0,
 ):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
@@ -134,9 +156,7 @@ def _bwd_dq_kernel(
     def _init():
         dq_scr[:] = jnp.zeros(dq_scr.shape, jnp.float32)
 
-    should_run = True
-    if causal:
-        should_run = (qi + 1) * block_q > ki * block_kv
+    should_run = _block_runs(qi, ki, block_q, block_kv, causal, window)
 
     @pl.when(should_run)
     def _compute():
@@ -152,11 +172,7 @@ def _bwd_dq_kernel(
         ) * scale
         if bias_ref is not None:
             s = s + bias_ref[0, 0, 0, :][None, :].astype(jnp.float32)
-        if causal:
-            rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_kv), 0)
-            cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_kv), 1)
-            mask = (qi * block_q + rows) >= (ki * block_kv + cols)
-            s = jnp.where(mask, s, NEG_INF)
+        s = _block_mask(s, qi, ki, block_q, block_kv, causal, window)
         # NEG_INF is the finite float32 min, so for a fully-masked row both s
         # and lse are NEG_INF and exp(s - lse) = exp(0) = 1 — zero those rows
         # explicitly (partially-masked entries underflow to 0 on their own).
@@ -178,7 +194,7 @@ def _bwd_dq_kernel(
 def _bwd_dkv_kernel(
     q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
     dk_ref, dv_ref, dk_scr, dv_scr,
-    *, scale, causal, block_q, block_kv, num_q_blocks,
+    *, scale, causal, block_q, block_kv, num_q_blocks, window=0,
 ):
     ki = pl.program_id(2)
     qi = pl.program_id(3)
@@ -188,9 +204,7 @@ def _bwd_dkv_kernel(
         dk_scr[:] = jnp.zeros(dk_scr.shape, jnp.float32)
         dv_scr[:] = jnp.zeros(dv_scr.shape, jnp.float32)
 
-    should_run = True
-    if causal:
-        should_run = (qi + 1) * block_q > ki * block_kv
+    should_run = _block_runs(qi, ki, block_q, block_kv, causal, window)
 
     @pl.when(should_run)
     def _compute():
@@ -206,11 +220,7 @@ def _bwd_dkv_kernel(
         ) * scale
         if bias_ref is not None:
             s = s + bias_ref[0, 0, 0, :][None, :].astype(jnp.float32)
-        if causal:
-            rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_kv), 0)
-            cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_kv), 1)
-            mask = (qi * block_q + rows) >= (ki * block_kv + cols)
-            s = jnp.where(mask, s, NEG_INF)
+        s = _block_mask(s, qi, ki, block_q, block_kv, causal, window)
         # see dq kernel: fully-masked rows have lse == NEG_INF and must give 0
         p = jnp.where(lse == NEG_INF, 0.0, jnp.exp(s - lse))  # (bq, bkv)
         # dv += p^T @ do
@@ -263,7 +273,7 @@ def _fit_block(seq: int, requested: int, align: int) -> int:
     return min(requested, _round_up(int(np.ceil(seq / n_blocks)), align))
 
 
-def _fwd_call(q, k, v, bias, scale, causal, block_q, block_kv, interpret):
+def _fwd_call(q, k, v, bias, scale, causal, block_q, block_kv, interpret, window=0):
     b, h, sq, d = q.shape
     skv = k.shape[2]
     nq = sq // block_q
@@ -291,14 +301,14 @@ def _fwd_call(q, k, v, bias, scale, causal, block_q, block_kv, interpret):
             return _fwd_kernel(
                 q_ref, k_ref, v_ref, None, o_ref, lse_ref, m_scr, l_scr, acc_scr,
                 scale=scale, causal=causal, block_q=block_q, block_kv=block_kv,
-                num_kv_blocks=nkv,
+                num_kv_blocks=nkv, window=window,
             )
     else:
         def kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr):
             return _fwd_kernel(
                 q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
                 scale=scale, causal=causal, block_q=block_q, block_kv=block_kv,
-                num_kv_blocks=nkv,
+                num_kv_blocks=nkv, window=window,
             )
 
     out_shape = [
@@ -327,7 +337,8 @@ def _fwd_call(q, k, v, bias, scale, causal, block_q, block_kv, interpret):
     return o, lse
 
 
-def _bwd_call(q, k, v, bias, o, lse, do, scale, causal, block_q, block_kv, interpret):
+def _bwd_call(q, k, v, bias, o, lse, do, scale, causal, block_q, block_kv, interpret,
+              window=0):
     b, h, sq, d = q.shape
     skv = k.shape[2]
     nq = sq // block_q
@@ -367,14 +378,14 @@ def _bwd_call(q, k, v, bias, o, lse, do, scale, causal, block_q, block_kv, inter
             return _bwd_dq_kernel(
                 q_ref, k_ref, v_ref, None, do_ref, lse_ref, delta_ref, dq_ref, dq_scr,
                 scale=scale, causal=causal, block_q=block_q, block_kv=block_kv,
-                num_kv_blocks=nkv,
+                num_kv_blocks=nkv, window=window,
             )
     else:
         def dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr):
             return _bwd_dq_kernel(
                 q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr,
                 scale=scale, causal=causal, block_q=block_q, block_kv=block_kv,
-                num_kv_blocks=nkv,
+                num_kv_blocks=nkv, window=window,
             )
 
     dq = pl.pallas_call(
@@ -421,7 +432,7 @@ def _bwd_call(q, k, v, bias, o, lse, do, scale, causal, block_q, block_kv, inter
                 q_ref, k_ref, v_ref, None, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr,
                 scale=scale, causal=causal, block_q=block_q, block_kv=block_kv,
-                num_q_blocks=nq,
+                num_q_blocks=nq, window=window,
             )
     else:
         def dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr):
@@ -429,7 +440,7 @@ def _bwd_call(q, k, v, bias, o, lse, do, scale, causal, block_q, block_kv, inter
                 q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr,
                 scale=scale, causal=causal, block_q=block_q, block_kv=block_kv,
-                num_q_blocks=nq,
+                num_q_blocks=nq, window=window,
             )
 
     dk, dv = pl.pallas_call(
@@ -460,21 +471,21 @@ def _bwd_call(q, k, v, bias, o, lse, do, scale, causal, block_q, block_kv, inter
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash_bhsd(q, k, v, bias, scale, causal, block_q, block_kv, interpret):
-    o, _ = _fwd_call(q, k, v, bias, scale, causal, block_q, block_kv, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash_bhsd(q, k, v, bias, scale, causal, block_q, block_kv, interpret, window=0):
+    o, _ = _fwd_call(q, k, v, bias, scale, causal, block_q, block_kv, interpret, window)
     return o
 
 
-def _flash_fwd(q, k, v, bias, scale, causal, block_q, block_kv, interpret):
-    o, lse = _fwd_call(q, k, v, bias, scale, causal, block_q, block_kv, interpret)
+def _flash_fwd(q, k, v, bias, scale, causal, block_q, block_kv, interpret, window=0):
+    o, lse = _fwd_call(q, k, v, bias, scale, causal, block_q, block_kv, interpret, window)
     return o, (q, k, v, bias, o, lse)
 
 
-def _flash_bwd(scale, causal, block_q, block_kv, interpret, res, do):
+def _flash_bwd(scale, causal, block_q, block_kv, interpret, window, res, do):
     q, k, v, bias, o, lse = res
     dq, dk, dv = _bwd_call(
-        q, k, v, bias, o, lse, do, scale, causal, block_q, block_kv, interpret
+        q, k, v, bias, o, lse, do, scale, causal, block_q, block_kv, interpret, window
     )
     dbias = None if bias is None else jnp.zeros_like(bias)
     return dq, dk, dv, dbias
@@ -493,8 +504,12 @@ def flash_attention(
     block_q: int = 512,
     block_kv: int = 1024,
     interpret: bool | None = None,
+    window: int = 0,
 ) -> jax.Array:
     """Flash attention in model layout. GQA handled by repeating KV heads.
+    ``window`` above 0 (with ``causal``): the query at ``p`` sees the keys
+    ``p - window < j <= p``; key blocks wholly behind a query block's window
+    are skipped like those above the diagonal, in all three kernels.
 
     Default blocks (512, 1024): measured 28% faster fwd+bwd than (128, 128)
     on v5e at s=2048/d=64 (fewer grid steps, better MXU occupancy) and well
@@ -538,7 +553,9 @@ def flash_attention(
         valid = _pad_to(valid, skv_p, 1)
         bias = jnp.where(valid, 0.0, NEG_INF).astype(jnp.float32)[:, None, None, :]
 
-    o = _flash_bhsd(qt, kt, vt, bias, scale, causal, block_q, block_kv, interpret)
+    if window and not causal:
+        raise ValueError("a sliding window is built for causal attention only")
+    o = _flash_bhsd(qt, kt, vt, bias, scale, causal, block_q, block_kv, interpret, window)
     return o[:, :, :sq, :].transpose(0, 2, 1, 3)
 
 
@@ -555,6 +572,7 @@ def blockwise_attention(
     causal: bool = True,
     scale: float | None = None,
     block_kv: int = 512,
+    window: int = 0,
 ) -> jax.Array:
     """Online-softmax attention as a ``lax.scan`` over KV blocks: O(s·bkv)
     live memory, fully differentiable through the scan. The same math as the
@@ -590,6 +608,8 @@ def blockwise_attention(
         if causal:
             kv_pos = bidx * block_kv + jnp.arange(block_kv)
             col_mask = col_mask & (q_pos[:, None] >= kv_pos[None, :])[None, None]
+            if window:
+                col_mask = col_mask & (kv_pos[None, :] > q_pos[:, None] - window)[None, None]
         s = jnp.where(col_mask, s, NEG_INF)
         m_cur = jnp.max(s, axis=-1)
         m_new = jnp.maximum(m_run, m_cur)

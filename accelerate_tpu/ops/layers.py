@@ -81,10 +81,16 @@ def causal_mask(q_len: int, kv_len: int, dtype=jnp.bool_) -> jax.Array:
     return jnp.tril(jnp.ones((q_len, kv_len), dtype=dtype), k=kv_len - q_len)
 
 
-def causal_attention(q, k, v, segment_mask=None):
-    """Causal self-attention; ``segment_mask`` [b, s] marks valid tokens."""
+def causal_attention(q, k, v, segment_mask=None, window: int = 0):
+    """Causal self-attention; ``segment_mask`` [b, s] marks valid tokens;
+    ``window`` above 0 keeps, for the query at ``p``, the keys ``p - window <
+    j <= p`` only."""
     s, skv = q.shape[1], k.shape[1]
     mask = causal_mask(s, skv)[None, None, :, :]
+    if window:
+        # key j is behind the window of query i (at key position i + skv - s)
+        # where j <= i + skv - s - window
+        mask = mask & ~jnp.tril(jnp.ones((s, skv), jnp.bool_), k=skv - s - window)[None, None]
     if segment_mask is not None:
         mask = mask & segment_mask[:, None, None, :].astype(bool)
     return dot_product_attention(q, k, v, mask=mask)
@@ -374,7 +380,7 @@ def last_visible(q_pos, block_len: int = 1):
     return (q_pos // block_len + 1) * block_len - 1
 
 
-def cached_attention(q, k_cache, v_cache, idx, block_len: int = 1):
+def cached_attention(q, k_cache, v_cache, idx, block_len: int = 1, window: int = 0):
     """Chunked attention against a KV cache with per-row valid prefix.
 
     q: ``[b, s, nh, hd]`` (``s == 1``: the token being decoded; ``s > 1``:
@@ -383,7 +389,8 @@ def cached_attention(q, k_cache, v_cache, idx, block_len: int = 1):
     position ``j`` of row ``b`` attends cache positions ``<= idx[b]+j`` —
     the per-row prefix plus the causal triangle within the chunk (with
     ``block_len`` > 1, every position up to the end of the query's own
-    block of that many positions: :func:`last_visible`). GQA
+    block of that many positions: :func:`last_visible`; with ``window``
+    above 0, only the ``window`` positions that end at the query's own). GQA
     handled by repeating KV heads. f32 scores/softmax. Shared by every
     model family's decode step (no per-model drift in the masking or
     dtype policy).
@@ -402,6 +409,8 @@ def cached_attention(q, k_cache, v_cache, idx, block_len: int = 1):
     valid = (  # [b, s, max]
         jnp.arange(max_cache)[None, None, :] <= last_visible(q_pos, block_len)[:, :, None]
     )
+    if window:
+        valid = valid & (jnp.arange(max_cache)[None, None, :] > q_pos[:, :, None] - window)
     scores = jnp.einsum(
         "bqnrd,bknd->bnrqk", qg, k_cache.astype(jnp.float32)
     ) / np.sqrt(float(hd))

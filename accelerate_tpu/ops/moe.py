@@ -5,7 +5,8 @@ the top ``k``, and the dropless expert product.
     s = sigmoid(x W_g)   or   softmax(x W_g) over all E    [T, E], float32
     chosen = top_k(s + expert_bias)                      selection only
     weight = s[chosen] / (sum s[chosen] + 1e-6) * scale  the un-biased scores
-    y = sum_i weight_i * W_out[e_i](silu(g) * u),  [g | u] = x W_in[e_i]
+    y = sum_i weight_i * W_out[e_i](act(g) * u),  [g | u] = x W_in[e_i]
+                                                         act: silu, or relu
 
 The (token, choice) pairs are grouped by expert — a stable sort of the
 ``T * k`` expert ids — and each group is multiplied by its own expert's
@@ -38,6 +39,10 @@ _HI = jax.lax.Precision.HIGHEST
 _GMM_TILING = (128, 2048, 512)
 
 
+#: what an expert's gate half may go through (``expert_ffn(activation=)``)
+_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 def default_moe_impl() -> str:
     """The Pallas grouped product on a TPU backend, ``jax.lax.ragged_dot``
     elsewhere — a static choice by platform, like ``default_ssm_impl``."""
@@ -46,7 +51,7 @@ def default_moe_impl() -> str:
 
 def route(x, w_gate, expert_bias, top_k: int, norm_topk_prob: bool = True,
           routed_scaling_factor: float = 1.0, scoring: str = "sigmoid",
-          n_group: int = 1, topk_group: int = 1, norm_eps: float = 1e-6):
+          n_group: int = 1, topk_group: int = 1, norm_eps: float = 1e-6, logits=None):
     """``x [T, h]`` -> ``(experts [T, k] int32, weights [T, k] float32)``.
     The gate's product, the scores and the top-k run in float32 (in
     bfloat16 two scores tie). ``scoring``: ``"sigmoid"``, each expert scored
@@ -58,9 +63,13 @@ def route(x, w_gate, expert_bias, top_k: int, norm_topk_prob: bool = True,
     biased scores, and the top ``k`` are taken among the ``topk_group`` best
     groups; the others' biased scores count as 0, not as minus infinity, as
     the published code has it. At ``n_group`` 1 nothing of that is traced.
-    ``norm_eps`` guards the renormalisation's sum."""
+    ``norm_eps`` guards the renormalisation's sum. ``logits [T, E]``
+    (float32), where the caller has made the gate's product itself — from
+    another tensor than the one the experts multiply, say — take the place of
+    ``x W_g``: ``x`` and ``w_gate`` are then not read (pass ``None``)."""
     f32 = jnp.float32
-    logits = jnp.dot(x.astype(f32), w_gate.astype(f32), precision=_HI)
+    if logits is None:
+        logits = jnp.dot(x.astype(f32), w_gate.astype(f32), precision=_HI)
     if scoring == "sigmoid":
         scores = jax.nn.sigmoid(logits)
     elif scoring == "softmax":
@@ -111,7 +120,7 @@ def grouped_matmul(lhs, rhs, group_sizes, impl: str | None = None,
 
 def expert_ffn(x, experts, weights, w_in, w_out, live=None, layer: int | None = None,
                impl: str | None = None, interpret: bool = False,
-               held: tuple | None = None):
+               held: tuple | None = None, activation: str = "silu"):
     """The dropless expert product. ``x [T, h]``; ``experts`` / ``weights``
     ``[T, k]`` from :func:`route`; ``w_in [E, h, 2f]`` (gate | up),
     ``w_out [E, f, h]``; ``live [T]`` bool (``None``: every token). With
@@ -120,6 +129,8 @@ def expert_ffn(x, experts, weights, w_in, w_out, live=None, layer: int | None = 
     layer's experts are sliced out to be multiplied. Returns ``(y [T, h],
     counts [E] int32)``: the weighted sum of each token's experts, zero for
     a token that is not live, and the pairs each expert was given.
+    ``activation``: what the gate's half goes through before it multiplies
+    the up half, ``"silu"`` or ``"relu"``.
 
     ``held = (first, count)``: this chip's share of an expert-parallel
     layer. The router scored all the experts and ``experts`` names any of
@@ -132,6 +143,9 @@ def expert_ffn(x, experts, weights, w_in, w_out, live=None, layer: int | None = 
     for the other chips or the exchange with them."""
     t, k = experts.shape
     n_experts = w_in.shape[-3]
+    if activation not in _ACTIVATIONS:
+        raise ValueError(f"unknown expert activation {activation!r}: want one of "
+                         f"{', '.join(_ACTIVATIONS)}")
     if held is not None:
         first, count = held
         if count != n_experts:
@@ -159,7 +173,7 @@ def expert_ffn(x, experts, weights, w_in, w_out, live=None, layer: int | None = 
         w_in = w_in.reshape(n_layers * n_experts, *w_in.shape[2:])
         w_out = w_out.reshape(n_layers * n_experts, *w_out.shape[2:])
     gate, up = jnp.split(grouped_matmul(grouped, w_in, sizes, impl, interpret), 2, axis=-1)
-    out = grouped_matmul((jax.nn.silu(gate) * up).astype(x.dtype), w_out, sizes,
+    out = grouped_matmul((_ACTIVATIONS[activation](gate) * up).astype(x.dtype), w_out, sizes,
                          impl, interpret)
     in_a_group = jnp.arange(t * k) < counts.sum()
     out = jnp.where(in_a_group[:, None], out.astype(jnp.float32), 0.0)

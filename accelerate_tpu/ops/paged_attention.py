@@ -99,6 +99,7 @@ def paged_attention(
     impl: str | None = None,
     interpret: bool = False,
     block_len: int = 1,
+    window: int = 0,
 ):
     """Attention of ``q`` against each row's block-table span in layer
     ``layer`` of the stacked pools. Query ``j`` of row ``b`` attends
@@ -110,7 +111,14 @@ def paged_attention(
     ``block_len`` positions, ``< ((idx[b]+j) // block_len + 1) * block_len``
     (:func:`ops.layers.last_visible`), on every route alike; the caller has
     written what of that span a query may see. At 1 each route traces what
-    it traced before the parameter was there. Every route addresses the
+    it traced before the parameter was there. ``window`` (static) above 0
+    is a sliding window: the query at position ``p`` attends ``p - window < j
+    <= p``, ``window`` keys with its own among them, on every route alike;
+    the Pallas kernel starts a row's walk at the first table entry that
+    holds a visible position and never reads the entries behind it (which
+    the engine has given back: they point at the null block). At 0 every
+    route traces what it traced before the parameter was there; with
+    ``block_len`` above 1 it is refused. Every route addresses the
     pool at ``(layer, block)``; none slices the layer out first. ``impl``:
     ``None`` routes via :func:`default_paged_attention_impl`;
     ``"lax"``/``"pallas"``/``"gather"`` force a path (``"gather"`` is the
@@ -119,19 +127,22 @@ def paged_attention(
     the Pallas interpreter — how tests exercise it off-TPU."""
     if impl is None:
         impl = default_paged_attention_impl()
+    if window and block_len != 1:
+        raise ValueError(f"window {window} with block_len {block_len}: a sliding window "
+                         "is built for the causal rule only")
     layer = jnp.asarray(layer, jnp.int32)
     if impl == "lax":
         return _paged_attention_lax(
-            q, k_pool, v_pool, layer, block_tables, idx, k_scale, v_scale, block_len
+            q, k_pool, v_pool, layer, block_tables, idx, k_scale, v_scale, block_len, window
         )
     if impl == "pallas":
         return _paged_attention_pallas_sharded(
             q, k_pool, v_pool, layer, block_tables, idx, k_scale, v_scale,
-            interpret=interpret, block_len=block_len,
+            interpret=interpret, block_len=block_len, window=window,
         )
     if impl == "gather":
         return _paged_attention_gather(
-            q, k_pool, v_pool, layer, block_tables, idx, k_scale, v_scale, block_len
+            q, k_pool, v_pool, layer, block_tables, idx, k_scale, v_scale, block_len, window
         )
     raise ValueError(f"unknown paged attention impl {impl!r}")
 
@@ -148,7 +159,7 @@ def _kv_heads(q, k_pool) -> int:
 
 
 def _paged_attention_lax(q, k_pool, v_pool, layer, block_tables, idx, k_scale, v_scale,
-                         block_len=1):
+                         block_len=1, window=0):
     b, s, nh, hd = q.shape
     bs = k_pool.shape[2]
     n_kv = _kv_heads(q, k_pool)
@@ -175,6 +186,8 @@ def _paged_attention_lax(q, k_pool, v_pool, layer, block_tables, idx, k_scale, v
         sc = jnp.einsum("bsnrd,btnd->bnrst", qg, kb)
         pos = j * bs + jnp.arange(bs, dtype=jnp.int32)   # logical positions
         valid = pos[None, None, :] <= q_last[:, :, None]  # [b, s, bs]
+        if window:
+            valid = valid & (pos[None, None, :] > q_pos[:, :, None] - window)
         vmask = valid[:, None, None, :, :]
         sc = jnp.where(vmask, sc, _NEG_INF)
         m_new = jnp.maximum(m, sc.max(axis=-1))
@@ -202,7 +215,7 @@ def _paged_attention_lax(q, k_pool, v_pool, layer, block_tables, idx, k_scale, v
 
 
 def _paged_attention_gather(q, k_pool, v_pool, layer, block_tables, idx, k_scale, v_scale,
-                            block_len=1):
+                            block_len=1, window=0):
     """Materialise each row's logical cache — ``[b, max_blocks*bs, n_kv,
     hd]`` gathered through the table; logical position ``p`` lands at
     gathered index ``p`` (tables are ordered) — and feed
@@ -223,6 +236,7 @@ def _paged_attention_gather(q, k_pool, v_pool, layer, block_tables, idx, k_scale
     return cached_attention(
         q, span(k_pool, k_scale), span(v_pool, v_scale),
         jnp.asarray(idx, jnp.int32).reshape(b), block_len=block_len,
+        **({"window": window} if window else {}),
     )
 
 
@@ -254,14 +268,32 @@ def tile_entries(table_width: int, latent: bool = False) -> int:
     return min(_LATENT_TILE if latent else _TILE, table_width)
 
 
-def tiles_walked(entries, table_width: int, latent: bool = False):
-    """Softmax steps the kernel takes for rows that walk ``entries`` table
-    entries each (the host's count, ``serving/engine.py``)."""
-    return -(-entries // tile_entries(table_width, latent))
+def tiles_walked(entries, table_width: int, latent: bool = False, first=None):
+    """Softmax steps the kernel takes for rows that walk the table entries
+    up to ``entries`` each (the host's count, ``serving/engine.py``), from
+    entry ``first`` on where a window cuts the walk's start (``None``: from
+    the table's first entry): the tiles are laid from entry 0 whatever the
+    start, so the first and the last one walked may be part full."""
+    tile = tile_entries(table_width, latent)
+    whole = -(-entries // tile)
+    return whole if first is None else whole - first // tile
+
+
+def window_walk(first, queries: int, window: int, block_size: int, table_width: int):
+    """``(first entry, one past the last entry)`` of the table that rows whose
+    first query stands at ``first`` (an integer or an array of them) walk in
+    a layer of ``window`` (0: the whole past) for ``queries`` queries a row:
+    what :func:`_pallas_kernel` reads from the same numbers for a row whose
+    queries are one block of stacked rows."""
+    first = np.asarray(first, np.int64)
+    end = np.minimum((first + queries - 1) // block_size + 1, table_width)
+    if not window:
+        return np.zeros_like(end), end
+    return np.minimum(np.maximum(first - window + 1, 0) // block_size, end), end
 
 
 def _pallas_kernel(bt_ref, idx_ref, layer_ref, q_ref, *rest,
-                   bs, tile, s, quantized, block_len=1):
+                   bs, tile, s, quantized, block_len=1, window=0):
     """Grid ``(b, row blocks)``: step ``(i, r)`` is block ``r`` of row
     ``i``'s stacked queries (one block but for a chunk), and a loop inside
     it walks the row's own table entries ``0 .. n_i - 1``, ``n_i = (idx[i] + s - 1)
@@ -296,7 +328,18 @@ def _pallas_kernel(bt_ref, idx_ref, layer_ref, q_ref, *rest,
 
     Every operand is 2-D where it is computed on: kv heads are folded into
     the lane dimension (``[.., n*hd]`` - how the pool is stored) and kv head
-    ``n`` is the static lane slice ``[n*hd, (n+1)*hd)``, cut once a tile."""
+    ``n`` is the static lane slice ``[n*hd, (n+1)*hd)``, cut once a tile.
+
+    **A window cuts the walk at both ends.** With ``window`` above 0 a query
+    at ``p`` attends ``p - window < j <= p``. A grid step's stacked rows are
+    the queries ``j0 .. j0 + nq - 1`` of the row (one head's, where the row
+    block divides the chunk; all of them otherwise), so it walks the entries
+    ``max(0, idx + j0 - window + 1) // bs`` to ``(idx + j0 + nq - 1) // bs``
+    and no others: the tiles stay where they are laid (tile ``t`` is entries
+    ``t * tile ..``), the loop starts at the first entry's tile, copies of
+    entries outside the span are not made, and the mask adds the lower edge.
+    V's rows outside the span are zeroed like those past the row's end. At
+    ``window`` 0 nothing of this is traced."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -319,13 +362,26 @@ def _pallas_kernel(bt_ref, idx_ref, layer_ref, q_ref, *rest,
     last = last_visible(first + s - 1, block_len)
     live = jnp.minimum(last // bs + 1, mb)
     row0 = pl.program_id(1) * rows
+    if window:
+        # this grid step's queries, and the span of positions they see
+        in_a_head = s % rows == 0
+        j0 = jax.lax.rem(row0, s) if in_a_head else 0
+        lo_pos = jnp.maximum(first + j0 - window + 1, 0)
+        last = jnp.minimum(first + j0 + (rows if in_a_head else s) - 1, last)
+        live = jnp.minimum(last // bs + 1, mb)
+        lo_entry = jnp.minimum(lo_pos // bs, live)
+        t_first = lo_entry // tile
+    else:
+        lo_entry = t_first = 0
 
     def for_live_entries(t, act):
         """``act`` on the DMAs of tile ``t``'s live entries, one a pool
-        operand and entry; nothing for an entry past the row's last."""
+        operand and entry; nothing for an entry past the row's last (or
+        behind its window)."""
         slot = t % depth
         for e in range(tile):
-            @pl.when(t * tile + e < live)
+            @pl.when((t * tile + e < live) & (t * tile + e >= lo_entry) if window
+                     else t * tile + e < live)
             def _entry():
                 blk = bt_ref[i, t * tile + e]
                 for a, (pool, buf) in enumerate(zip(layers, bufs)):
@@ -333,7 +389,7 @@ def _pallas_kernel(bt_ref, idx_ref, layer_ref, q_ref, *rest,
                         pool.at[blk], buf.at[slot, pl.ds(e * bs, bs)], sems.at[a, slot]))
 
     for t in range(_IN_FLIGHT):
-        for_live_entries(t, lambda dma: dma.start())
+        for_live_entries(t_first + t, lambda dma: dma.start())
 
     m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
@@ -346,8 +402,13 @@ def _pallas_kernel(bt_ref, idx_ref, layer_ref, q_ref, *rest,
         k_pos = t * span + jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
         # stacked row g of the group is query g % s of its head
         g = row0 + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
-        valid = k_pos <= last_visible(first + jax.lax.rem(g, s), block_len)  # [rows, span]
-        seen = t * span + jax.lax.broadcasted_iota(jnp.int32, (span, 1), 0) <= last
+        q_pos = first + jax.lax.rem(g, s)
+        valid = k_pos <= last_visible(q_pos, block_len)  # [rows, span]
+        v_pos = t * span + jax.lax.broadcasted_iota(jnp.int32, (span, 1), 0)
+        seen = v_pos <= last
+        if window:
+            valid = valid & (k_pos > q_pos - window)
+            seen = seen & (v_pos >= lo_entry * bs)
         for n in range(n_kv):
             lanes = slice(n * hd, (n + 1) * hd)
             kb = k_buf[slot, :, lanes].astype(jnp.float32)      # [span, hd]
@@ -375,7 +436,7 @@ def _pallas_kernel(bt_ref, idx_ref, layer_ref, q_ref, *rest,
             )
         return carry
 
-    jax.lax.fori_loop(0, (live + tile - 1) // tile, _step, 0)
+    jax.lax.fori_loop(t_first, (live + tile - 1) // tile, _step, 0)
 
     out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
     out_ref[0] = out.astype(out_ref.dtype)
@@ -393,7 +454,7 @@ def _scale_blocks(scale, layer):
 
 
 def _paged_attention_pallas(q, k_pool, v_pool, layer, block_tables, idx,
-                            k_scale, v_scale, *, interpret, block_len=1):
+                            k_scale, v_scale, *, interpret, block_len=1, window=0):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -438,6 +499,7 @@ def _paged_attention_pallas(q, k_pool, v_pool, layer, block_tables, idx,
     out = pl.pallas_call(
         functools.partial(
             _pallas_kernel, bs=bs, tile=tile, s=s, quantized=quantized, block_len=block_len,
+            **({"window": window} if window else {}),
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n_kv, rows, hd), q.dtype),
@@ -456,7 +518,7 @@ def _paged_attention_pallas(q, k_pool, v_pool, layer, block_tables, idx,
 
 
 def _paged_attention_pallas_sharded(q, k_pool, v_pool, layer, block_tables, idx,
-                                    k_scale, v_scale, *, interpret, block_len=1):
+                                    k_scale, v_scale, *, interpret, block_len=1, window=0):
     """The kernel under the active mesh: GSPMD treats a Mosaic call as
     opaque, so with the pool's folded kv-head lanes sharded over the head
     axis (``parallel.sharding.paged_kv_sharding`` — whole heads per shard)
@@ -470,7 +532,7 @@ def _paged_attention_pallas_sharded(q, k_pool, v_pool, layer, block_tables, idx,
 
     ctx = get_attention_context()
     kernel = functools.partial(_paged_attention_pallas, interpret=interpret,
-                               block_len=block_len)
+                               block_len=block_len, **({"window": window} if window else {}))
     extent = 1 if ctx.mesh is None else dict(ctx.mesh.shape).get(ctx.head_axis, 1)
     if extent == 1 or q.shape[2] % extent or _kv_heads(q, k_pool) % extent:
         return kernel(q, k_pool, v_pool, layer, block_tables, idx, k_scale, v_scale)

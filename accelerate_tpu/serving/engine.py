@@ -58,6 +58,7 @@ the spec-armed engine stay token-identical to the non-spec engine.
 
 from __future__ import annotations
 
+import itertools
 import time
 import warnings
 from collections import OrderedDict, deque
@@ -79,7 +80,12 @@ from ..diagnostics.tracing import (
 from ..metrics.ingest import observe_flight
 from ..metrics.registry import get_active_registry
 from ..models.cache import cache_spec_of
-from ..ops.paged_attention import default_paged_attention_impl, tile_entries, tiles_walked
+from ..ops.paged_attention import (
+    default_paged_attention_impl,
+    tile_entries,
+    tiles_walked,
+    window_walk,
+)
 from ..telemetry import get_active_recorder
 from .blocks import NULL_BLOCK, BlockAllocator, blocks_needed
 from .flight import ITERATION_PHASES, PART_NAMES, FlightRecorder, set_active_flight_recorder
@@ -431,6 +437,31 @@ class InferenceEngine:
                     raise ValueError(f"{what} is not supported for {name!r}, which keeps "
                                      f"{kept}: {missing}")
 
+        # layers that keep a window of the past beside layers that keep all of
+        # it (models/cache.py:PagedKind): a request's blocks are no longer its
+        # whole past in every layer, and what assumes they are is refused
+        # here (the prefix cache is left out below with its reason in stats())
+        if spec.window_kinds:
+            name = getattr(inner, "name", type(inner).__name__)
+            kept = ", ".join(f"{k.layers} layers that keep a window of {k.window} positions"
+                             for k in spec.window_kinds)
+            for armed, what, missing in (
+                (cfg.swap_gb and cfg.swap_gb > 0, f"swap_gb={cfg.swap_gb}",
+                 "_swap_out mirrors one pool's blocks, and a request swapped back in would "
+                 "find its window kind's blocks gone; a preempted request is recomputed "
+                 "instead"),
+                (cfg.spec_k, f"spec_k={cfg.spec_k}",
+                 "the early-exit draft reads the first layers of ONE pool, and a rejected "
+                 "draft's rollback by position does not give back the window blocks the "
+                 "round's lookahead freed behind it"),
+                (mesh is not None, "mesh=",
+                 "_place_on_mesh places one pool's leaves; the window kind's pool and its "
+                 "table have no placement yet (ROADMAP Reach 4)"),
+            ):
+                if armed:
+                    raise ValueError(f"{what} is not supported for {name!r}, which has "
+                                     f"{kept}: {missing}")
+
         # a model that generates by diffusion over blocks: the decode
         # executable is the block round, and what cannot hold under parallel
         # unmasking is refused here or at add_request, the reasons in stats()
@@ -555,13 +586,29 @@ class InferenceEngine:
             store_dtype, quantized = kv_storage_dtype(cfg.kv_dtype)
         self._quantized = quantized
         self.kv_dtype = str(np.dtype(store_dtype))
-        shape = (spec.paged_layers, num_blocks, cfg.block_size, spec.pool_width)
-        scale_shape = (spec.paged_layers, num_blocks, cfg.block_size, n_kv)
+        #: the paged kinds (models/cache.py): the first is the one num_blocks
+        #: counts, the scheduler admits by and every model of one kind has;
+        #: a kind that keeps a window gets a pool of its own, sized so that
+        #: every slot's window is resident (num_slots x its blocks a slot +
+        #: the null block: nothing to admit by, nothing to preempt for)
+        self._kinds = spec.paged_kinds
+        self._window_kinds = spec.window_kinds
+        lookahead = max(cfg.prefill_chunk, self._decode_lookahead)
+        #: ``{kind name: blocks}``: a window kind's most blocks a slot, and its pool's
+        pools = spec.window_pools(cfg.num_slots, cfg.max_seq_len, cfg.block_size, lookahead)
+        self.window_blocks_per_slot = {name: n[0] for name, n in pools.items()}
+        self.window_num_blocks = {name: n[1] for name, n in pools.items()}
+        shape = (self._kinds[0].layers, num_blocks, cfg.block_size, spec.pool_width)
+        scale_shape = (shape[0], num_blocks, cfg.block_size, n_kv)
         #: bytes one cached token costs across the layers that hold paged KV
         #: (K + V payload, or a latent pool's one stored row, plus the f32
         #: scales when quantized) — the decode-bandwidth and slot-capacity
         #: headline number
         self.kv_bytes_per_token = spec.bytes_per_token(store_dtype, quantized)
+        #: bytes of the window kinds' pools: a fixed cost beside the
+        #: parameters (priced by what a slot keeps resident, not by num_blocks)
+        self.window_pool_bytes = spec.window_pool_bytes(
+            cfg.num_slots, cfg.max_seq_len, cfg.block_size, lookahead, store_dtype, quantized)
         #: bytes of per-slot state one slot costs, whatever its sequence's
         #: length (0 for a model whose blocks are all of a request's past)
         self.state_bytes_per_slot = spec.state_bytes_per_slot(dtype)
@@ -580,7 +627,14 @@ class InferenceEngine:
         #: why no prefix cache was built although one was asked for (None:
         #: it was built, or not asked for)
         self.prefix_cache_off_reason = None
-        if cfg.prefix_cache and spec.slot_state:
+        if cfg.prefix_cache and spec.window_kinds:
+            self.prefix_cache_off_reason = (
+                f"{sum(k.layers for k in spec.window_kinds)} layers keep a window of the "
+                "past and give back the blocks behind it: a block-boundary hit would map "
+                "the full kind's K/V and nothing for the window kind (a prefix cache over "
+                "freed window blocks is not built, ROADMAP Reach 4)"
+            )
+        elif cfg.prefix_cache and spec.slot_state:
             self.prefix_cache_off_reason = (
                 f"the model keeps per-slot state ({', '.join(spec.slot_state)}) in "
                 f"{spec.state_layers} layers: a block-boundary hit would map K/V for "
@@ -589,8 +643,11 @@ class InferenceEngine:
             )
         self.radix = (
             RadixCache(self.allocator, cfg.block_size)
-            if cfg.prefix_cache and not spec.slot_state else None
+            if cfg.prefix_cache and not spec.slot_state and not spec.window_kinds else None
         )
+        #: ``{kind name: allocator}`` of the window kinds' pools
+        self.window_allocators = {
+            name: BlockAllocator(n) for name, n in self.window_num_blocks.items()}
         self._swap = (
             SwapPool(
                 num_layers=shape[0], block_size=cfg.block_size,
@@ -608,6 +665,7 @@ class InferenceEngine:
             cfg.num_slots, self.allocator, cfg.block_size, cfg.max_seq_len,
             radix=self.radix, usage=self.usage,
             prefix_granule=self._block.block_length if self._block else 1,
+            window_allocators=self.window_allocators,
         )
         #: everything the step programs keep for the sequences, in ONE dict
         #: that every one of them takes donated and hands back whole: "k" /
@@ -621,6 +679,16 @@ class InferenceEngine:
         #: the pool's arrays by name, payload and scales: what a block-granular
         #: edit (copy-on-write) has to touch, all of it
         self._pool_arrays = tuple(self._cache)
+        # a further kind's pool (and scales) under its own names, beside the
+        # first's: the model's step reads each by name
+        for n, kind in enumerate(self._kinds[1:], start=1):
+            k_shape = (kind.layers, self.window_num_blocks[kind.name], cfg.block_size,
+                       kind.kv_heads * kind.head_dim)
+            for leaf in ("k", "v"):
+                self._cache[kind.pool_leaf(leaf, False)] = jnp.zeros(k_shape, store_dtype)
+                if quantized:
+                    self._cache[kind.pool_leaf(leaf + "_scale", False)] = jnp.ones(
+                        (*k_shape[:3], kind.kv_heads), jnp.float32)
         for name, leaf in spec.slot_state.items():
             self._cache[name] = jnp.zeros(
                 leaf.array_shape(cfg.num_slots), leaf.dtype or dtype)
@@ -660,7 +728,10 @@ class InferenceEngine:
         # backend takes an aligned numpy operand without copying it, and a
         # chunk or a round still in flight would read the rows the host
         # rewrites for the next one (_sync_block_table zeroes a row first)
-        self._block_tables = np.zeros((cfg.num_slots, self._mb), np.int32)
+        # (a table a kind where the model has more than one: [slots, kinds, mb])
+        self._block_tables = np.zeros(
+            (cfg.num_slots, self._mb) if len(self._kinds) == 1
+            else (cfg.num_slots, len(self._kinds), self._mb), np.int32)
         self._pending_tok = np.zeros((cfg.num_slots,), np.int32)
 
         # counters (the *_traces counters increment inside the traced
@@ -769,6 +840,13 @@ class InferenceEngine:
         self._paged_entries_walked = 0
         self._paged_entries_table = 0
         self._paged_tiles_walked = 0
+        # the same walk by kind, where the model has window kinds (monotone
+        # totals; the sums above hold every kind's): entries walked in the
+        # layers that keep all of the past and in those that keep a window,
+        # and the entries of live positions that a window layer's walk did
+        # not visit, which one kind for every layer would have walked
+        self._paged_by_kind = dict.fromkeys(("full", "window", "behind_window"), 0)
+        self._window_blocks_freed = 0
         # what the slot-state kernels' work follows (monotone totals, counted
         # at the decode dispatch from the host's mask): the slot states a
         # dispatch has to step - live lanes x steps x state layers - and
@@ -942,7 +1020,10 @@ class InferenceEngine:
             swap_gb=self.config.swap_gb or None,
             draft_layers=self._spec.layers if self._spec else None,
             stacked_prefix=getattr(inner, "stacked_params_prefix", "layers"),
-            state_bytes=self.state_bytes_per_slot * self.config.num_slots,
+            # fixed costs beside the parameters: the per-slot state, and the
+            # pools of the kinds that keep a window (resident by construction)
+            state_bytes=(self.state_bytes_per_slot * self.config.num_slots
+                         + self.window_pool_bytes),
             pool_leaves=self._cache_spec.pool_leaves,
         )
         self.hbm_preflight = report
@@ -1625,6 +1706,7 @@ class InferenceEngine:
                     **{name: int(total) for name, total in self._step_counters.items()
                        if not total.shape},
                     **self._block_stats(),
+                    **self._window_stats(totals_only=True),
                 },
                 **phases,
             )
@@ -1706,6 +1788,8 @@ class InferenceEngine:
         self._ttft_sum_s = self._ttft_queue_sum_s = self._ttft_own_prefill_sum_s = 0.0
         self._ttft_prefill_iterations_sum = 0
         self._paged_entries_walked = self._paged_entries_table = self._paged_tiles_walked = 0
+        self._paged_by_kind = dict.fromkeys(self._paged_by_kind, 0)
+        self._window_blocks_freed = 0
         self._state_slots_live = self._state_slots_held = 0
         self._pick_dispatches = self._pick_draw_dispatches = 0
         self._block_totals = dict.fromkeys(_BLOCK_TOTALS, 0)
@@ -1775,6 +1859,45 @@ class InferenceEngine:
             "block_denoise_forwards_total": rounds * self._denoise_steps,
             "block_commit_forwards_total": rounds,
             "block_forwards_total": rounds * (self._denoise_steps + 1),
+        }
+
+    def _window_stats(self, totals_only: bool = False) -> dict:
+        """What ``stats()`` says of a model whose paged layers are of two
+        kinds (empty for a model of one): the fixed numbers, the pools'
+        occupancy by kind, and the monotone totals - which the flight entries
+        carry too (``totals_only``), so that a reader can difference them
+        over a span."""
+        if not self._window_kinds:
+            return {}
+        totals = {
+            "window_blocks_freed_total": self._window_blocks_freed,
+            "paged_entries_walked_full_total": self._paged_by_kind["full"],
+            "paged_entries_walked_window_total": self._paged_by_kind["window"],
+            "paged_entries_behind_window_total": self._paged_by_kind["behind_window"],
+        }
+        if totals_only:
+            return totals
+        full = [k for k in self._kinds if not k.window]
+        window = self._window_kinds
+        store = np.dtype(self.kv_dtype)
+        held = sum(a.allocated_count for a in self.window_allocators.values())
+        return {
+            "kv_window": window[0].window,
+            "kv_full_layers": sum(k.layers for k in full),
+            "kv_window_layers": sum(k.layers for k in window),
+            "kv_bytes_per_position_full": sum(
+                k.bytes_per_token(store, self._quantized) for k in full),
+            "kv_bytes_per_position_window": sum(
+                k.bytes_per_token(store, self._quantized) for k in window),
+            "window_blocks_per_slot": sum(self.window_blocks_per_slot.values()),
+            "window_num_blocks": sum(self.window_num_blocks.values()),
+            "window_pool_bytes": self.window_pool_bytes,
+            # the totals above count the first kind's pool alone, as num_blocks does
+            "allocated_blocks_full": self.allocator.allocated_count,
+            "allocated_blocks_window": held,
+            "free_blocks_window": sum(a.free_count for a in self.window_allocators.values()),
+            "cached_blocks_full": 0, "cached_blocks_window": 0,  # no prefix cache
+            **totals,
         }
 
     def _spec_stats(self) -> dict:
@@ -1851,6 +1974,9 @@ class InferenceEngine:
             # which layers keep what (models/cache.py): paged K/V by token,
             # slot state by slot, whatever the sequence's length
             "kv_layers": self._cache_spec.paged_layers,
+            # the kinds of paged layer (1: every one keeps the whole past; the
+            # kinds' own numbers follow where there are more)
+            "kv_kinds": len(self._kinds),
             # a latent pool (0: K and V per kv head): the entries of a row that
             # are also the values, and what a token's rows cost as stored
             # (576 values kept 640 wide: kv_bytes_per_token says the same)
@@ -1928,6 +2054,7 @@ class InferenceEngine:
             # lacks the round in flight and the chunks dispatched since
             out.update(self._model_stats)
             out.update({name: total.tolist() for name, total in self._step_counters.items()})
+        out.update(self._window_stats())
         out.update(self._spec_stats())
         out.update(self._sampling_stats())
         out.update(self._hbm_watermarks())
@@ -2495,6 +2622,7 @@ class InferenceEngine:
             return True
         self.allocator.decref(victim.blocks)
         victim.blocks = []
+        self.scheduler.release_window_blocks(victim)  # every kind's go back
         victim.prefill_pos = 0
         self.scheduler.requeue_preempted(victim, recompute=True)
         self._preemptions += 1
@@ -2518,6 +2646,7 @@ class InferenceEngine:
                 self.allocator.decref(retained)
             req.swap_plan = []
         req.blocks = []
+        self.scheduler.release_window_blocks(req)
         if self.usage is not None:
             self.usage.update_blocks(req)
 
@@ -2535,9 +2664,48 @@ class InferenceEngine:
         self.scheduler.evict_finished()
 
     def _sync_block_table(self, req: Request) -> None:
+        """The slot's tables from what the request holds, every kind's in
+        step: the first kind's blocks in order from entry 0; a window kind's
+        at the entries it holds, the null block behind the window and ahead
+        of what is allocated."""
         row = self._block_tables[req.slot]
         row[:] = 0
-        row[: len(req.blocks)] = req.blocks
+        if len(self._kinds) == 1:
+            row[: len(req.blocks)] = req.blocks
+            return
+        row[0, : len(req.blocks)] = req.blocks
+        for n, kind in enumerate(self._kinds[1:], start=1):
+            held = req.window_blocks.get(kind.name)
+            if held:
+                row[n, list(held)] = list(held.values())
+
+    def _advance_windows(self, req: Request, first: int, end: int) -> None:
+        """Before a dispatch whose queries of ``req`` stand at ``first .. end
+        - 1``: every window kind holds a block for each table entry from the
+        one that holds the oldest visible position (``first - window + 1``)
+        to the one that holds ``end - 1``, and gives back the entries wholly
+        behind that span. No query of this dispatch or of a later one sees a
+        freed position (queries only move on), and every dispatch that could
+        was handed to the device before this one: a decode round was
+        harvested before the next is built, and a prompt's earlier chunk is
+        ahead of whatever program rewrites the block, in the device's order.
+        A kind's pool holds every slot's window (``window_num_blocks``), so
+        the allocation cannot fail."""
+        bs = self.config.block_size
+        for kind in self._window_kinds:
+            held = req.window_blocks.setdefault(kind.name, {})
+            alloc = self.window_allocators[kind.name]
+            lo = max(first - kind.window + 1, 0) // bs
+            hi = min((end - 1) // bs, self._mb - 1)
+            # ``held`` is a run of consecutive entries in rising order (what
+            # is added below, less what went from its front)
+            behind = list(itertools.takewhile(lambda j: j < lo, held))
+            if behind:
+                alloc.free([held.pop(j) for j in behind])
+                self._window_blocks_freed += len(behind)
+            missing = range(max(lo, next(reversed(held), lo - 1) + 1), hi + 1)
+            if missing:
+                held.update(zip(missing, alloc.allocate(len(missing))))
 
     def _count_paged_entries(self, first, queries: int, layers: int, calls: int = 1) -> None:
         """Book one dispatch's paged-attention calls: ``first`` holds the
@@ -2549,12 +2717,25 @@ class InferenceEngine:
         model's chunks and rounds end on a block's end, where the last
         query's last visible position is the last query), a tile of them a
         softmax step (``tiles_walked``)."""
-        last = np.asarray(first, np.int64) + queries - 1
-        walked = np.minimum(last // self.config.block_size + 1, self._mb)
-        self._paged_entries_walked += int(walked.sum()) * layers * calls
-        self._paged_entries_table += walked.size * self._mb * layers * calls
-        self._paged_tiles_walked += int(tiles_walked(
-            walked, self._mb, bool(self._cache_spec.latent_rank)).sum()) * layers * calls
+        first = np.asarray(first, np.int64)
+        bs, mb, latent = self.config.block_size, self._mb, bool(self._cache_spec.latent_rank)
+        # a model with window kinds runs every kind's layers (``layers`` is
+        # their sum): each walks from the entry that holds a row's oldest
+        # visible position (``window_walk``: entry 0 without a window), and
+        # the entries a window layer's walk left behind are booked beside
+        by_kind = bool(self._window_kinds)
+        walks = [(k.window, k.layers) for k in self._kinds] if by_kind else [(0, layers)]
+        for window, n in walks:
+            lo, end = window_walk(first, queries, window, bs, mb)
+            n *= calls
+            walked = int((end - lo).sum()) * n
+            self._paged_entries_walked += walked
+            self._paged_entries_table += first.size * mb * n
+            self._paged_tiles_walked += int(tiles_walked(
+                end, mb, latent, first=lo if window else None).sum()) * n
+            if by_kind:
+                self._paged_by_kind["window" if window else "full"] += walked
+                self._paged_by_kind["behind_window"] += int(lo.sum()) * n
 
     def _count_state_slots(self, active) -> None:
         """Book one decode dispatch's slot-state steps from the mask the
@@ -2611,6 +2792,8 @@ class InferenceEngine:
         chunk[0, : max(end - start, 0)] = seq[start:end]
         valid = np.zeros((1, c), bool)
         valid[0, : max(end - start, 0)] = True
+        if self._window_kinds and end > start:
+            self._advance_windows(req, start, end)
         self._sync_block_table(req)
         is_final = end >= total
         last_idx = np.int32((total - 1) - start if is_final else 0)
@@ -2688,7 +2871,9 @@ class InferenceEngine:
         # (so does a block model's where there is no swap tier: every token it
         # emitted is in its replayed prefill or its open block, none pending)
         # and so does a latent pool's, whose rows the swap tier cannot mirror
-        recompute = bool(self._cache_spec.slot_state or self._cache_spec.latent_rank) or (
+        # and so does a model with window kinds, whose swap tier is refused
+        recompute = bool(self._cache_spec.slot_state or self._cache_spec.latent_rank
+                         or self._window_kinds) or (
             self._block is not None and self._swap is None)
         preempt = self._preempt_by_recompute if recompute else self._swap_out
         while not sched.grow_for_decode(req, tokens_ahead=self._decode_lookahead):
@@ -2756,6 +2941,11 @@ class InferenceEngine:
             # budget scatter into the null block and are dropped host-side
             if req.slot is None or req.state is not RequestState.DECODE:
                 continue
+            if self._window_kinds:
+                # as far as this dispatch writes and the request's budget goes
+                self._advance_windows(req, req.context_len, min(
+                    req.context_len + self._decode_lookahead,
+                    req.prompt_len + req.max_new_tokens, cfg.max_seq_len))
             self._sync_block_table(req)
             pos0[req.slot] = req.context_len
             toks[req.slot, 0] = self._pending_tok[req.slot]

@@ -114,6 +114,13 @@ class Request:
     #: per-token logprob dicts when the request asked for them
     logprobs: list | None = None
     blocks: list[int] = field(default_factory=list)
+    #: blocks of the cache kinds that keep a window of the past
+    #: (``models/cache.py:PagedKind``): ``{kind name: {table entry: block}}``,
+    #: the entries a dispatch may still read and no others; the engine
+    #: allocates ahead of each dispatch and gives back what lies behind the
+    #: window (``InferenceEngine._advance_windows``). Empty for a model whose
+    #: blocks are the whole of a request's past
+    window_blocks: dict = field(default_factory=dict)
     prefill_pos: int = 0  # prompt tokens whose K/V are already cached
     first_token_time: float | None = None
     finish_time: float | None = None
@@ -177,9 +184,14 @@ class SlotScheduler:
     the block allocator, and (optionally) the radix prefix cache."""
 
     def __init__(self, num_slots: int, allocator: BlockAllocator, block_size: int,
-                 max_seq_len: int, radix=None, usage=None, prefix_granule: int = 1):
+                 max_seq_len: int, radix=None, usage=None, prefix_granule: int = 1,
+                 window_allocators: dict | None = None):
         self.num_slots = int(num_slots)
         self.allocator = allocator
+        #: ``{kind name: allocator}`` of the cache kinds that keep a window
+        #: (each sized so that every slot's window is resident: allocation
+        #: from one never fails and is no part of admission)
+        self.window_allocators = dict(window_allocators or {})
         self.block_size = int(block_size)
         self.max_seq_len = int(max_seq_len)
         self.radix = radix
@@ -277,6 +289,16 @@ class SlotScheduler:
         request.preemptions += 1
         self.waiting[request.priority].appendleft(request)
 
+    def release_window_blocks(self, request: Request) -> int:
+        """Give back every block ``request`` holds of the window kinds (it
+        finished, or is preempted and will be recomputed). Returns how many."""
+        n = 0
+        for kind, held in request.window_blocks.items():
+            self.window_allocators[kind].free(list(held.values()))
+            n += len(held)
+        request.window_blocks = {}
+        return n
+
     def evict_finished(self) -> list[Request]:
         """Release slots + blocks of finished requests (engine marks them).
         Blocks are decref'd: a block the radix cache (or another request)
@@ -286,6 +308,8 @@ class SlotScheduler:
             if req is not None and req.state is RequestState.FINISHED:
                 self.allocator.decref(req.blocks)
                 req.blocks = []
+                if req.window_blocks:
+                    self.release_window_blocks(req)
                 if self.usage is not None:
                     self.usage.update_blocks(req)
                 req.slot = None

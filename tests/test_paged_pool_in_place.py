@@ -519,6 +519,74 @@ def test_v5e_compiled_latent_step_leaves_the_one_pool_in_place(one_chip, monkeyp
     assert compiled.memory_analysis().temp_size_in_bytes < 420e6
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill", "decode_fp8"])
+def test_v5e_compiled_two_kind_step_leaves_both_pools_in_place(one_chip, monkeypatch, program):
+    """SmallThinker-21BA3B's widths (GQA 28/4 of 128; 64 ReLU experts of 2560
+    x 768 behind a router that reads the layer's input), one period of its
+    layers (full, window, window, window), 40 slots x 1,024 table entries a
+    kind as the benchmark's cell dispatches them, the window kind's pool at
+    the cell's 12,841 blocks, the vocabulary cut to 8192: a decode step, a
+    1,024-token chunk and a decode step over fp8 pools compile for the v5e
+    with the ``paged_attention`` kernel - the windowed walk at 4,096 in three
+    layers of four - and the grouped product in the program, BOTH kinds'
+    pools aliased through it, nothing of their size or of a layer's slab
+    produced."""
+    import sys
+
+    from accelerate_tpu.models import smallthinker as st
+
+    monkeypatch.setattr(
+        sys.modules["accelerate_tpu.ops.paged_attention"],
+        "default_paged_attention_impl", lambda: "pallas",
+    )
+    monkeypatch.setattr(sys.modules["accelerate_tpu.ops.moe"], "default_moe_impl", lambda: "gmm")
+    slots, blocks, window_blocks, bs, table, chunk = 40, 3000, 40 * 321 + 1, 16, 1024, 1024
+    c = st.SmallThinkerConfig(vocab_size=8192, num_hidden_layers=4, rope_layout=(0, 1, 1, 1),
+                              sliding_window_layout=(0, 1, 1, 1))
+    spec = st.cache_spec(c)
+    assert spec.window_pools(slots, 16384, bs, chunk) == {"window": (321, window_blocks)}
+    shaped = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: shaped(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: st.init_smallthinker_params(jax.random.PRNGKey(0), c)),
+    )
+    quantized = program == "decode_fp8"
+    dtype = jnp.float8_e4m3fn if quantized else jnp.bfloat16
+    cache = {}
+    for n, (kind, count) in enumerate(zip(spec.paged_kinds, (blocks, window_blocks))):
+        for leaf in ("k", "v"):
+            cache[kind.pool_leaf(leaf, n == 0)] = shaped((kind.layers, count, bs, 512), dtype)
+            if quantized:
+                cache[kind.pool_leaf(leaf + "_scale", n == 0)] = shaped(
+                    (kind.layers, count, bs, 4), jnp.float32)
+
+    def step(params, cache, tables, pos, toks, mask):
+        out = st.smallthinker_apply(
+            c, params, toks, paged_kv=cache, block_tables=tables,
+            cache_positions=pos, paged_write_mask=mask,
+        )
+        return (out["paged_kv"], jnp.argmax(out["logits"][:, -1, :], -1).astype(jnp.int32),
+                out["step_counters"])
+
+    b, s = (1, chunk) if program == "prefill" else (slots, 1)
+    operands = [params, cache, shaped((b, 2, table), jnp.int32), shaped((b,), jnp.int32),
+                shaped((b, s), jnp.int32), shaped((b, s), jnp.bool_)]
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(step, donate_argnums=(1,)).lower(*operands).compile()
+    text = compiled.as_text()
+    # four layers' paged kernel, and each layer's two grouped products
+    assert text.count('custom_call_target="tpu_custom_call"') == 12
+    assert text.count("paged_attention") >= 4
+    full, window = blocks * bs * 512, 3 * window_blocks * bs * 512
+    assert buffers_moved(text, [full, window, window // 3]) == {"moved": [], "unaliased": []}
+    w_in, w_out = 64 * 2560 * 1536, 64 * 768 * 2560
+    assert buffers_moved(text, [w_in, w_out, 3 * w_in, 3 * w_out])["moved"] == []
+    # the temporaries are activations (a chunk's 6,144 pairs through the experts, the
+    # logits), under a third of one window layer's slab of the cell's pool (210 MB)
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        320e6 if quantized else 210e6)
+
+
 def test_v5e4_train_step_moves_no_head_sized_array_inside_the_loss_loop(topo):
     """The train cell's step (Mistral-7B widths, 8 x 4,096 tokens, bf16
     compute over float32 parameters, adamw, ``fsdp=4``, ``remat``; ONE
