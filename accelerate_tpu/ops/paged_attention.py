@@ -39,9 +39,13 @@ step that consumes it (``_IN_FLIGHT``). The trip count is an operand, not a
 shape: one executable serves every occupancy.
 
 **A softmax step of the kernel is a tile against a query group.** The loop
-takes ``_TILE`` consecutive table entries a step (``_TILE x block_size`` key
-positions, each live entry copied into its ``block_size`` rows of one VMEM
-buffer and none past the row's last), against ALL the query heads of a kv
+takes a tile of consecutive table entries a step - ``_TILE`` of them
+(``_TILE x block_size`` key positions) in a call whose stacked query rows
+fit one grid step (a decode step, a block round, a verify round),
+``_CHUNK_TILE`` in a chunk's call, whose step also takes a taller block of
+rows (:func:`_step_geometry`: the geometry is chosen from the call's own
+static shape) - each live entry copied into its ``block_size`` rows of one
+VMEM buffer and none past the row's last, against ALL the query heads of a kv
 head at once: the query is handed in grouped, a kv head's ``rep`` heads
 stacked along the rows. One entry against one query head at a time made a
 score tile 16 of a vreg's 1,024 elements and paid the fixed cost of two
@@ -51,7 +55,9 @@ whatever the scratch held, so V's rows are selected by key position
 (``0 x NaN`` is NaN). ``serving/engine.py`` counts the same entries and
 tiles on the host (``stats()`` ``paged_entries_walked_total`` against
 ``paged_entries_table_total``; entries over ``paged_tiles_walked_total x
-_TILE`` is how full the tiles are).
+_TILE`` is how full the tiles are: a chunk's wide step is booked as the
+``_TILE``-entry tiles it holds, and ``paged_chunk_steps_total`` counts those
+steps themselves).
 """
 
 from __future__ import annotations
@@ -254,27 +260,86 @@ _TILE = 8
 _IN_FLIGHT = 1
 
 #: stacked query rows a grid step takes: a kv head's whole group where it is
-#: smaller (decode, a block round, a verify round), one head of a 256-token
-#: chunk where it is not - a second grid axis walks a chunk's blocks of rows,
-#: each over the row's tiles again, so the kernel's VMEM (query, output, the
-#: softmax state: lane-padded, 20 MB for a whole chunk at once) does not grow
-#: with the chunk; a score tile of 256 rows is 32 vregs
+#: smaller (decode, a block round, a verify round: ONE block, and the program of
+#: such a call is what it was before a chunk had a geometry of its own). A call
+#: of more stacked rows is a chunk's, and takes ``_CHUNK_TILE`` and
+#: ``_CHUNK_ROWS`` instead
 _ROW_BLOCK = 256
 
+#: a chunk's call (more than ``_ROW_BLOCK`` stacked rows, ``rep x s``): table
+#: entries a softmax step takes (1,024 positions at blocks of 16), and stacked
+#: rows x kv heads a grid step holds (the rows are split evenly over as few grid
+#: steps as that allows, in whole blocks of ``_ROW_BLOCK`` that the step's body
+#: takes one at a time: a SmallThinker chunk of 7 x 1,024 rows at 4 kv heads
+#: goes in 4 steps of 1,792 where it went in 28 of 256, a Mistral chunk of 4 x
+#: 256 at 8 kv heads in one). Every block of rows walks and copies the row's
+#: span again, and every step of a walk pays, a kv head and block, the float32
+#: accumulator read, scaled and rewritten, the softmax state, the query's
+#: conversion and the masks whatever the tile holds; the tile's copies are ONE
+#: loop over its live entries and a whole tile's are waited for at once, as the
+#: latent kernel's. Timed on the v5e (PERF.md sections 5 and 6, PR 45; ms a
+#: layer's call, tile 8 / 16 / 32 / 64 at 8,192 rows x kv heads, the parent's
+#: geometry first). SmallThinker's chunk (28 / 4 heads of 128, 1,024 tokens)
+#: that ends at 4,096: 2.52 | 2.04 / 1.71 / 0.91 / 0.62 with the whole past,
+#: 2.30 | 2.04 / 1.69 / 0.90 / 0.66 behind a window of 4,096; at 16,384: 9.87 |
+#: 8.01 / 6.67 / 3.48 / 2.30 and 2.70 | 2.53 / 2.10 / 1.11 / 0.81; a first
+#: chunk (1,024): 0.68 | 0.55 / 0.46 / 0.26 / 0.19. Mistral's (32 / 8 of 128,
+#: 256 tokens) at 256 / 1,536 / 3,072: 0.069 / 0.218 / 0.401 | tile 16 0.059 /
+#: 0.207 / 0.385, 32 0.062 / 0.125 / 0.214, 64 0.068 / 0.113 / 0.155; LFM2's
+#: (heads of 64): 0.071 / 0.281 / 0.536 | 64: 0.073 / 0.119 / 0.169: a first
+#: chunk of 256 tokens reads at 64 what it read at the parent's 8, and 0.006 ms
+#: over 32; every later one gains. Rows x kv heads at tile 64, 1,024 / 2,048 /
+#: 4,096 / 8,192 (SmallThinker at 4,096, whole past): 0.82 / 0.74 / 0.65 / 0.62.
+#: The body written out for a whole block of rows took the compiler 6-13 s a
+#: kernel (compiled for the v5e, not run); a block of rows at a time it takes
+#: 2-4 s (the parent's 0.6-0.9). Constants of the kernel, chosen by the call's
+#: shape
+_CHUNK_TILE = 64
+_CHUNK_ROWS = 8192
 
-def tile_entries(table_width: int, latent: bool = False) -> int:
-    """Table entries a softmax step takes of a table this wide (``latent``:
-    of the latent kernel, which has a tile of its own)."""
-    return min(_LATENT_TILE if latent else _TILE, table_width)
+#: VMEM a chunk's call may take, over the compiler's 16 MB default (as
+#: ``_LATENT_VMEM_LIMIT``): query and output blocks twice, the float32
+#: accumulator and the lane-padded softmax state of ``_CHUNK_ROWS`` rows x kv
+#: heads (21 MB at heads of 128), the tiles in flight (4 MB at 4 kv heads of
+#: 128, 34 at 32) and one block of rows' scores and probabilities: 30-36 MB in
+#: the benchmark's cells, 70 at 32 kv heads
+_CHUNK_VMEM_LIMIT = 96 << 20
 
 
-def tiles_walked(entries, table_width: int, latent: bool = False, first=None):
+def _step_geometry(stacked: int, table_width: int, n_kv: int = 1):
+    """``(table entries a softmax step, stacked rows a grid step, chunk)`` of a
+    call whose kv heads' query groups stack to ``stacked`` rows (``rep x s``):
+    what fits one block of ``_ROW_BLOCK`` keeps ``_TILE`` and one block of its
+    rows (padded to whole sublanes); a chunk takes ``_CHUNK_TILE`` and its
+    rows split evenly over the fewest grid steps of at most ``_CHUNK_ROWS``
+    rows x kv heads, in whole blocks of ``_ROW_BLOCK`` (the step's body takes
+    them one at a time)."""
+    if stacked <= _ROW_BLOCK:
+        return min(_TILE, table_width), -(-stacked // 8) * 8, False
+    steps = -(-stacked // max(_CHUNK_ROWS // n_kv, _ROW_BLOCK))
+    return (min(_CHUNK_TILE, table_width),
+            -(-stacked // (_ROW_BLOCK * steps)) * _ROW_BLOCK, True)
+
+
+def tile_entries(table_width: int, latent: bool = False, stacked: int = 1) -> int:
+    """Table entries a softmax step takes of a table this wide in a call of
+    ``stacked`` stacked query rows (``rep x s``; the default is a decode
+    row's, the unit ``stats()`` hands out as ``paged_tile_entries``).
+    ``latent``: of the latent kernel, which has one tile for every call."""
+    if latent:
+        return min(_LATENT_TILE, table_width)
+    return _step_geometry(stacked, table_width)[0]
+
+
+def tiles_walked(entries, table_width: int, latent: bool = False, first=None,
+                 stacked: int = 1):
     """Softmax steps the kernel takes for rows that walk the table entries
     up to ``entries`` each (the host's count, ``serving/engine.py``), from
     entry ``first`` on where a window cuts the walk's start (``None``: from
-    the table's first entry): the tiles are laid from entry 0 whatever the
-    start, so the first and the last one walked may be part full."""
-    tile = tile_entries(table_width, latent)
+    the table's first entry), at the tile of a call of ``stacked`` stacked
+    rows: the tiles are laid from entry 0 whatever the start, so the first
+    and the last one walked may be part full."""
+    tile = tile_entries(table_width, latent, stacked)
     whole = -(-entries // tile)
     return whole if first is None else whole - first // tile
 
@@ -293,7 +358,7 @@ def window_walk(first, queries: int, window: int, block_size: int, table_width: 
 
 
 def _pallas_kernel(bt_ref, idx_ref, layer_ref, q_ref, *rest,
-                   bs, tile, s, quantized, block_len=1, window=0):
+                   bs, tile, s, quantized, block_len=1, window=0, chunk=False):
     """Grid ``(b, row blocks)``: step ``(i, r)`` is block ``r`` of row
     ``i``'s stacked queries (one block but for a chunk), and a loop inside
     it walks the row's own table entries ``0 .. n_i - 1``, ``n_i = (idx[i] + s - 1)
@@ -339,7 +404,17 @@ def _pallas_kernel(bt_ref, idx_ref, layer_ref, q_ref, *rest,
     ``t * tile ..``), the loop starts at the first entry's tile, copies of
     entries outside the span are not made, and the mask adds the lower edge.
     V's rows outside the span are zeroed like those past the row's end. At
-    ``window`` 0 nothing of this is traced."""
+    ``window`` 0 nothing of this is traced.
+
+    **A chunk's call** (``chunk``: more than ``_ROW_BLOCK`` stacked rows,
+    :func:`_step_geometry`) takes a wide tile against a tall block of rows -
+    the same products in fewer and larger steps - and rolls a tile's copies
+    into ONE loop of as many trips as the tile has live entries (behind a
+    window: from the span's first entry), a whole tile's waited for with one
+    wait a pool for all their bytes and a part tile's entry by entry, as
+    ``_latent_kernel`` does: 32 copies written out cost the tracing of every
+    run (PR 37, PR 43). Without ``chunk`` the kernel traces what it traced
+    before a chunk had a geometry of its own."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -377,8 +452,20 @@ def _pallas_kernel(bt_ref, idx_ref, layer_ref, q_ref, *rest,
     def for_live_entries(t, act):
         """``act`` on the DMAs of tile ``t``'s live entries, one a pool
         operand and entry; nothing for an entry past the row's last (or
-        behind its window)."""
+        behind its window). A chunk's: one loop over them."""
         slot = t % depth
+        if chunk:
+            def _entry(e, carry):
+                blk = bt_ref[i, t * tile + e]
+                for a, (pool, buf) in enumerate(zip(layers, bufs)):
+                    act(pltpu.make_async_copy(
+                        pool.at[blk], buf.at[slot, pl.ds(pl.multiple_of(e * bs, bs), bs)],
+                        sems.at[a, slot]))
+                return carry
+
+            jax.lax.fori_loop(jnp.clip(lo_entry - t * tile, 0, tile) if window else 0,
+                              jnp.clip(live - t * tile, 0, tile), _entry, 0)
+            return
         for e in range(tile):
             @pl.when((t * tile + e < live) & (t * tile + e >= lo_entry) if window
                      else t * tile + e < live)
@@ -388,6 +475,26 @@ def _pallas_kernel(bt_ref, idx_ref, layer_ref, q_ref, *rest,
                     act(pltpu.make_async_copy(
                         pool.at[blk], buf.at[slot, pl.ds(e * bs, bs)], sems.at[a, slot]))
 
+    def wait_live_entries(t):
+        """Wait for tile ``t``'s copies. A chunk's share a semaphore a pool,
+        so a whole tile's are ONE wait for all their bytes; its part tiles
+        (the row's last, a window's first) wait entry by entry."""
+        if not chunk:
+            return for_live_entries(t, lambda dma: dma.wait())
+        slot = t % depth
+        whole = live - t * tile >= tile
+        if window:
+            whole &= lo_entry <= t * tile
+
+        @pl.when(whole)
+        def _():
+            for a, buf in enumerate(bufs):
+                pltpu.make_async_copy(buf.at[slot], buf.at[slot], sems.at[a, slot]).wait()
+
+        @pl.when(jnp.logical_not(whole))
+        def _():
+            for_live_entries(t, lambda dma: dma.wait())
+
     for t in range(_IN_FLIGHT):
         for_live_entries(t_first + t, lambda dma: dma.start())
 
@@ -395,19 +502,48 @@ def _pallas_kernel(bt_ref, idx_ref, layer_ref, q_ref, *rest,
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    def visible(g0, n, k_pos):
+        """Which of a tile's key positions the ``n`` stacked rows from ``g0``
+        on may see by the causal (or block) rule, ``[n, span]``, and the
+        rows' positions: stacked row g of the group is query g % s of its head."""
+        g = g0 + jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)
+        q_pos = first + jax.lax.rem(g, s)
+        return k_pos <= last_visible(q_pos, block_len), q_pos
+
+    def attend(n, at, kb, vb, valid):
+        """One softmax step of kv head ``n``'s stacked rows ``at`` (an index
+        behind the head's: nothing for all of the block's) against the tile's
+        keys and values ``[span, hd]``, float32."""
+        qg = q_ref[(0, n) + at].astype(jnp.float32) / np.sqrt(float(hd))
+        sc = jax.lax.dot_general(                            # contract hd
+            qg, kb, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        sc = jnp.where(valid, sc, _NEG_INF)
+        m_prev, l_prev = m_ref[(n,) + at], l_ref[(n,) + at]  # [rows, 1]
+        m_new = jnp.maximum(m_prev, sc.max(axis=-1, keepdims=True))
+        # while every position so far is masked, m_new == _NEG_INF
+        # and sc - m_new == 0 - the mask keeps those lanes at p = 0
+        p = jnp.where(valid, jnp.exp(sc - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        m_ref[(n,) + at] = m_new
+        l_ref[(n,) + at] = l_prev * alpha + p.sum(axis=-1, keepdims=True)
+        acc_ref[(n,) + at] = acc_ref[(n,) + at] * alpha + jnp.dot(
+            p, vb, preferred_element_type=jnp.float32
+        )
+
     def _step(t, carry):
         for_live_entries(t + _IN_FLIGHT, lambda dma: dma.start())
-        for_live_entries(t, lambda dma: dma.wait())
+        wait_live_entries(t)
         slot = t % depth
         k_pos = t * span + jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
-        # stacked row g of the group is query g % s of its head
-        g = row0 + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
-        q_pos = first + jax.lax.rem(g, s)
-        valid = k_pos <= last_visible(q_pos, block_len)  # [rows, span]
+        if not chunk:
+            valid, q_pos = visible(row0, rows, k_pos)         # [rows, span]
         v_pos = t * span + jax.lax.broadcasted_iota(jnp.int32, (span, 1), 0)
         seen = v_pos <= last
         if window:
-            valid = valid & (k_pos > q_pos - window)
+            if not chunk:
+                valid = valid & (k_pos > q_pos - window)
             seen = seen & (v_pos >= lo_entry * bs)
         for n in range(n_kv):
             lanes = slice(n * hd, (n + 1) * hd)
@@ -417,23 +553,21 @@ def _pallas_kernel(bt_ref, idx_ref, layer_ref, q_ref, *rest,
                 kb = kb * scale_bufs[0][slot, :, n:n + 1]
                 vb = vb * scale_bufs[1][slot, :, n:n + 1]
             vb = jnp.where(seen, vb, 0.0)
-            qg = q_ref[0, n].astype(jnp.float32) / np.sqrt(float(hd))
-            sc = jax.lax.dot_general(                            # contract hd
-                qg, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            sc = jnp.where(valid, sc, _NEG_INF)
-            m_prev, l_prev = m_ref[n], l_ref[n]                  # [rows, 1]
-            m_new = jnp.maximum(m_prev, sc.max(axis=-1, keepdims=True))
-            # while every position so far is masked, m_new == _NEG_INF
-            # and sc - m_new == 0 - the mask keeps those lanes at p = 0
-            p = jnp.where(valid, jnp.exp(sc - m_new), 0.0)
-            alpha = jnp.exp(m_prev - m_new)
-            m_ref[n] = m_new
-            l_ref[n] = l_prev * alpha + p.sum(axis=-1, keepdims=True)
-            acc_ref[n] = acc_ref[n] * alpha + jnp.dot(
-                p, vb, preferred_element_type=jnp.float32
-            )
+            if not chunk:
+                attend(n, (), kb, vb, valid)
+                continue
+
+            # a chunk's tall block, ``_ROW_BLOCK`` rows at a time: the body the
+            # compiler writes out stays a decode step's, whatever the block holds
+            def _rows(r, carry, n=n, kb=kb, vb=vb):
+                r0 = pl.multiple_of(r * _ROW_BLOCK, _ROW_BLOCK)
+                valid, q_pos = visible(row0 + r0, _ROW_BLOCK, k_pos)
+                if window:
+                    valid = valid & (k_pos > q_pos - window)
+                attend(n, (pl.ds(r0, _ROW_BLOCK),), kb, vb, valid)
+                return carry
+
+            jax.lax.fori_loop(0, rows // _ROW_BLOCK, _rows, 0)
         return carry
 
     jax.lax.fori_loop(t_first, (live + tile - 1) // tile, _step, 0)
@@ -463,11 +597,10 @@ def _paged_attention_pallas(q, k_pool, v_pool, layer, block_tables, idx,
     n_kv = width // hd
     rep = nh // n_kv
     quantized = k_scale is not None
-    tile = tile_entries(block_tables.shape[1])
     # a kv head's query group stacked along the rows, head-major, padded to whole
-    # sublanes, in blocks of at most _ROW_BLOCK rows: a reshape of the small
-    # query outside the kernel
-    block_rows = min(-(-rep * s // 8) * 8, _ROW_BLOCK)
+    # sublanes, in ONE block of rows or a chunk's blocks (``_step_geometry``): a
+    # reshape of the small query outside the kernel
+    tile, block_rows, chunk = _step_geometry(rep * s, block_tables.shape[1], n_kv)
     rows = -(-rep * s // block_rows) * block_rows
     grouped = q.reshape(b, s, n_kv, rep, hd).transpose(0, 2, 3, 1, 4).reshape(b, n_kv, rep * s, hd)
     grouped = jnp.pad(grouped, [(0, 0), (0, 0), (0, rows - rep * s), (0, 0)])
@@ -499,11 +632,13 @@ def _paged_attention_pallas(q, k_pool, v_pool, layer, block_tables, idx,
     out = pl.pallas_call(
         functools.partial(
             _pallas_kernel, bs=bs, tile=tile, s=s, quantized=quantized, block_len=block_len,
-            **({"window": window} if window else {}),
+            **({"window": window} if window else {}), **({"chunk": True} if chunk else {}),
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n_kv, rows, hd), q.dtype),
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            **({"vmem_limit_bytes": _CHUNK_VMEM_LIMIT} if chunk else {})),
         interpret=interpret,
         name="paged_attention",
     )(

@@ -576,6 +576,8 @@ class InferenceEngine:
         self._cache_spec = spec
         n_kv = spec.kv_heads
         self._kv_heads = n_kv
+        #: query heads: with the kv heads, the stacked rows of a paged call
+        self._query_heads = int(getattr(mcfg, "num_attention_heads", n_kv))
         embed = jax.tree.leaves(self._params)[0]
         dtype = embed.dtype if jnp.issubdtype(embed.dtype, jnp.floating) else jnp.float32
         if cfg.kv_dtype in (None, "auto"):
@@ -840,6 +842,7 @@ class InferenceEngine:
         self._paged_entries_walked = 0
         self._paged_entries_table = 0
         self._paged_tiles_walked = 0
+        self._paged_chunk_steps = 0
         # the same walk by kind, where the model has window kinds (monotone
         # totals; the sums above hold every kind's): entries walked in the
         # layers that keep all of the past and in those that keep a window,
@@ -1788,6 +1791,7 @@ class InferenceEngine:
         self._ttft_sum_s = self._ttft_queue_sum_s = self._ttft_own_prefill_sum_s = 0.0
         self._ttft_prefill_iterations_sum = 0
         self._paged_entries_walked = self._paged_entries_table = self._paged_tiles_walked = 0
+        self._paged_chunk_steps = 0
         self._paged_by_kind = dict.fromkeys(self._paged_by_kind, 0)
         self._window_blocks_freed = 0
         self._state_slots_live = self._state_slots_held = 0
@@ -2028,9 +2032,12 @@ class InferenceEngine:
             "paged_entries_table_total": self._paged_entries_table,
             # the kernel's softmax steps over the walked entries, a tile of
             # ops/paged_attention.py's _TILE entries each (a row's last one
-            # part full): walked / (tiles x paged_tile_entries) is the tiles' fill
+            # part full): walked / (tiles x paged_tile_entries) is the tiles' fill.
+            # A chunk's call takes a wider tile a step: the step is booked as the
+            # tiles of paged_tile_entries it holds, and counted beside them
             "paged_tiles_walked_total": self._paged_tiles_walked,
             "paged_tile_entries": tile_entries(self._mb, bool(self._cache_spec.latent_rank)),
+            "paged_chunk_steps_total": self._paged_chunk_steps,
             # the slot state's work: states a decode dispatch had to step
             # (live lanes x burst x state layers: what ops/ssm.py's kernel
             # walks) against those the cache holds for every slot
@@ -2716,23 +2723,33 @@ class InferenceEngine:
         ``ops/paged_attention.py`` reads from the same positions (a block
         model's chunks and rounds end on a block's end, where the last
         query's last visible position is the last query), a tile of them a
-        softmax step (``tiles_walked``)."""
+        softmax step (``tiles_walked``) - the tile of a call of this many
+        stacked query rows, ``queries`` x the query heads a kv head: a
+        chunk's wide step is booked as the ``paged_tile_entries``-entry tiles
+        it holds, and in ``paged_chunk_steps_total`` as itself."""
         first = np.asarray(first, np.int64)
         bs, mb, latent = self.config.block_size, self._mb, bool(self._cache_spec.latent_rank)
+        narrow = tile_entries(mb, latent)
         # a model with window kinds runs every kind's layers (``layers`` is
         # their sum): each walks from the entry that holds a row's oldest
         # visible position (``window_walk``: entry 0 without a window), and
         # the entries a window layer's walk left behind are booked beside
         by_kind = bool(self._window_kinds)
-        walks = [(k.window, k.layers) for k in self._kinds] if by_kind else [(0, layers)]
-        for window, n in walks:
+        walks = ([(k.window, k.layers, k.kv_heads) for k in self._kinds] if by_kind
+                 else [(0, layers, self._kv_heads)])
+        for window, n, kv_heads in walks:
             lo, end = window_walk(first, queries, window, bs, mb)
             n *= calls
             walked = int((end - lo).sum()) * n
             self._paged_entries_walked += walked
             self._paged_entries_table += first.size * mb * n
-            self._paged_tiles_walked += int(tiles_walked(
-                end, mb, latent, first=lo if window else None).sum()) * n
+            stacked = queries * (self._query_heads // kv_heads)
+            steps = int(tiles_walked(
+                end, mb, latent, first=lo if window else None, stacked=stacked).sum()) * n
+            wide = tile_entries(mb, latent, stacked)
+            self._paged_tiles_walked += steps * -(-wide // narrow)
+            if wide > narrow:
+                self._paged_chunk_steps += steps
             if by_kind:
                 self._paged_by_kind["window" if window else "full"] += walked
                 self._paged_by_kind["behind_window"] += int(lo.sum()) * n

@@ -93,6 +93,13 @@ MOE_RTOL = 2e-2
 LATENT_GEOMETRY = (128, 640, 512, 576, 16, 1024, 2048)
 LATENT_DECODE = (16000, 8192, 4096, 2048, 1024, 300, 17)
 LATENT_CHUNK = (512, 4096)
+#: the chat cells' chunk calls of the paged kernel: (name, the call's shape, table
+#: entries, where the chunk ends, windows)
+PAGED_CHUNKS = (
+    ("smallthinker", dict(s=1024, nh=28, hd=128, n_kv=4), 1024, (1024, 4096, 16384), (0, 4096)),
+    ("mistral", dict(s=256, nh=32, hd=128, n_kv=8), 256, (256, 1536, 3072), (0,)),
+    ("lfm2 / hybrid", dict(s=256, nh=32, hd=64, n_kv=8), 256, (256, 1536, 3072), (0,)),
+)
 
 _COMPILED = re.compile(r"Finished XLA compilation of (\S+) in ([0-9.]+) sec")
 
@@ -514,6 +521,7 @@ def _kernels() -> None:
             "pallas vs gather", outs["pallas"], outs["gather"], PAGED_ATOL)
 
     ok &= _paged_call_times(rng)
+    ok &= _paged_chunk_times(rng)
     ok &= _latent_check(rng)
 
     # flash attention forward and gradients at the train shapes
@@ -531,7 +539,7 @@ def _kernels() -> None:
         sys.exit("a kernel disagrees with its reference beyond the stated bound")
 
 
-def _paged_ms_a_call(mod, q, pools, tables, idx, block_len, calls=24, reps=5) -> float:
+def _paged_ms_a_call(mod, q, pools, tables, idx, block_len, calls=24, reps=5, window=0) -> float:
     """Milliseconds a call of ``mod``'s Pallas paged kernel: ``calls`` of them
     in one program, layer after layer as a decode step makes them."""
     import jax
@@ -540,7 +548,7 @@ def _paged_ms_a_call(mod, q, pools, tables, idx, block_len, calls=24, reps=5) ->
     def many(q, kp, vp):
         def one(i, acc):
             out = mod.paged_attention(q, kp, vp, i % 2, tables, idx, impl="pallas",
-                                      block_len=block_len)
+                                      block_len=block_len, window=window)
             return acc + out[0, 0, 0, 0].astype(jnp.float32)
         return jax.lax.fori_loop(0, calls, one, jnp.float32(0))
 
@@ -598,6 +606,49 @@ def _paged_call_times(rng, lives=(2, 11, 22, 33, 64)) -> bool:
                 row["parent_ms_a_call"] = round(
                     _paged_ms_a_call(parent, q, pools, tables, idx, block_len), 4)
             print("KERNEL " + json.dumps(row), flush=True)
+    return ok
+
+
+def _paged_chunk_times(rng) -> bool:
+    """A chunk's call of the paged kernel - more stacked query rows than one
+    grid step's block, so a wide tile against a tall block of rows
+    (``ops/paged_attention.py:_step_geometry``) - at the chat cells' chunk
+    shapes: SmallThinker's (28 / 4 heads of 128, 1,024 tokens, a table of
+    1,024 entries; the whole past and a window of 4,096), Mistral's (32 / 8 of
+    128, 256 tokens, 256 entries) and LFM2's / the hybrid's (32 / 8 of 64).
+    Finite, against ``gather``, the milliseconds a layer's call, and with the
+    parent unpacked under ``_chip_tmp/parent`` its kernel beside it."""
+    import importlib
+
+    import jax
+
+    this = importlib.import_module("accelerate_tpu.ops.paged_attention")
+    parent = _parent_ops("paged_attention")
+    ok = True
+    for name, shape, mb, ends, windows in PAGED_CHUNKS:
+        for end in ends:
+            q, pools, tables, idx, _ = _paged_case(
+                rng, b=1, bs=16, mb=mb, store="bf16", deepest=end, **shape)
+            for window in windows:
+                run = lambda mod, impl: jax.jit(lambda q, kp, vp: mod.paged_attention(
+                    q, kp, vp, 1, tables, idx, impl=impl, window=window))(q, *pools)
+                got = run(this, "pallas")
+                label = (f"paged attention, {name} chunk {list(q.shape)} that ends at {end}"
+                         + (f", window {window}" if window else ""))
+                ok &= _kernel_row(label + ", pallas vs gather", got, run(this, "gather"),
+                                  PAGED_ATOL)
+                geometry = this._step_geometry(
+                    shape["s"] * shape["nh"] // shape["n_kv"], mb, shape["n_kv"])
+                row = {"check": label + ": ms a call", "ok": True, "tile": geometry[0],
+                       "rows_a_grid_step": geometry[1],
+                       "ms_a_call": round(_paged_ms_a_call(
+                           this, q, pools, tables, idx, 1, calls=8, reps=3, window=window), 4)}
+                if parent:
+                    ok &= _kernel_row(label + ", pallas vs the parent's", got,
+                                      run(parent, "pallas"), PAGED_ATOL)
+                    row["parent_ms_a_call"] = round(_paged_ms_a_call(
+                        parent, q, pools, tables, idx, 1, calls=8, reps=3, window=window), 4)
+                print("KERNEL " + json.dumps(row), flush=True)
     return ok
 
 
@@ -679,7 +730,8 @@ def _paged_calls() -> None:
     from accelerate_tpu.mesh import configure_compile_cache
 
     configure_compile_cache()
-    if not _paged_call_times(np.random.default_rng(0)):
+    rng = np.random.default_rng(0)
+    if not (_paged_call_times(rng) & _paged_chunk_times(rng)):
         sys.exit("the paged kernel disagrees with its reference beyond the stated bound")
 
 

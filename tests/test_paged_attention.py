@@ -10,6 +10,8 @@ gate the engine-level matrix in ``tests/test_serving.py`` and are quoted in
 ``docs/source/usage_guides/serving.md``).
 """
 
+import importlib
+
 import numpy as np
 import pytest
 
@@ -23,12 +25,18 @@ from accelerate_tpu.ops.fp8 import (
 )
 from accelerate_tpu.ops.layers import cached_attention, last_visible, write_paged_kv
 from accelerate_tpu.ops.paged_attention import (
+    _CHUNK_TILE,
     _LATENT_ROW_BLOCK,
+    _ROW_BLOCK,
     _TILE,
+    _step_geometry,
     latent_attention,
     paged_attention,
     tile_entries,
 )
+
+#: the module (the package exports the function under the same name)
+paged_module = importlib.import_module("accelerate_tpu.ops.paged_attention")
 
 #: ops-level |fused_quantized - f32_reference| ceilings on attention
 #: outputs (unit-variance inputs). int8 carries ~0.4% relative error per
@@ -159,8 +167,12 @@ def _ragged_case(rng, contexts, s, hd, store, tail, mb=WALK_MB, rep=2):
     n_kv, nh, nb = 2, 2 * rep, 2 + len(contexts) * mb
     shape = (LAYERS, nb, WALK_BS, n_kv * hd)
     scales = []
-    if store == "int8":
-        pools = [jnp.asarray(rng.integers(-127, 128, size=shape), jnp.int8) for _ in range(2)]
+    if store in ("int8", "fp8"):
+        if store == "int8":
+            pools = [jnp.asarray(rng.integers(-127, 128, size=shape), jnp.int8) for _ in range(2)]
+        else:
+            pools = [jnp.asarray(rng.normal(size=shape) * 60.0, jnp.float32)
+                     .astype(jnp.float8_e4m3fn) for _ in range(2)]
         scales = [jnp.asarray(rng.random(shape[:-1] + (n_kv,)) * 0.02 + 0.005, jnp.float32)
                   for _ in range(2)]
         scales = [x.at[:, 1].set(jnp.nan) for x in scales]
@@ -336,6 +348,187 @@ def test_pallas_tile_rows_that_no_copy_wrote_do_not_reach_the_output(contexts, s
     ref = np.asarray(paged_attention(q, *pools, 1, bt, idx, *scales, impl="gather"))
     tol = 1e-5 if store is None else 1e-4
     np.testing.assert_allclose(out, ref, rtol=tol, atol=tol)
+
+
+# -- a chunk's call: a step of its own size -------------------------------------
+
+#: a chunk's geometry: a call of more than ``_ROW_BLOCK`` stacked rows takes
+#: ``_CHUNK_TILE`` table entries a softmax step, ``CHUNK_SPAN`` key positions,
+#: its copies one loop over the tile's live entries. ``CHUNK_MB`` entries are
+#: two whole wide tiles and half a third
+CHUNK_SPAN = _CHUNK_TILE * WALK_BS
+CHUNK_MB = 2 * _CHUNK_TILE + _CHUNK_TILE // 2
+#: 72 queries x 4 heads a kv head: 288 stacked rows, one grid step of two blocks
+CHUNK_S, CHUNK_REP = 72, 4
+#: contexts that end inside the first wide tile, one short of its end, on it,
+#: in the tile after, in the third, and at the table's end
+CHUNK_ENDS = (CHUNK_S, CHUNK_SPAN - 1, CHUNK_SPAN, CHUNK_SPAN + 1, 2 * CHUNK_SPAN + 40,
+              CHUNK_MB * WALK_BS)
+
+
+@pytest.mark.parametrize("reference", ["gather", "lax"])
+@pytest.mark.parametrize("store", [None, "int8", "fp8"])
+@pytest.mark.parametrize("hd", [64, 128])
+@pytest.mark.parametrize("window", [
+    pytest.param(0, id="whole-past"),
+    pytest.param(CHUNK_SPAN // 2 - 3, id="window-under-a-wide-tile"),
+    pytest.param(2 * CHUNK_SPAN + 50, id="window-over-two-wide-tiles"),
+])
+def test_pallas_chunk_takes_a_wide_tile_and_matches_the_references(window, hd, store, reference):
+    """A call of more stacked rows than ``_ROW_BLOCK`` (a chunk) at the
+    kernel's own constants: ``_CHUNK_TILE`` entries a softmax step, the tile's
+    copies one loop, a whole tile waited for at once and a part tile entry by
+    entry. Contexts that end inside a wide tile, on its end and in the tile
+    after; the whole past, a window shorter than one wide tile (a tile that is
+    part full at BOTH ends) and one longer than two; both head sizes; bf16-like,
+    int8 and fp8 pools; against the gathered span and the scan."""
+    assert CHUNK_S * CHUNK_REP > _ROW_BLOCK and _step_geometry(
+        CHUNK_S * CHUNK_REP, CHUNK_MB, 2) == (_CHUNK_TILE, 2 * _ROW_BLOCK, True)
+    rng = np.random.default_rng(45)
+    q, pools, bt, idx, scales = _ragged_case(
+        rng, CHUNK_ENDS, CHUNK_S, hd, store, tail=0, mb=CHUNK_MB, rep=CHUNK_REP)
+    run = lambda impl: np.asarray(paged_attention(
+        q, *pools, 1, bt, idx, *scales, impl=impl, interpret=True, window=window))
+    out = run("pallas")
+    tol = 1e-5 if store is None else 1e-4
+    np.testing.assert_allclose(out, run(reference), rtol=tol, atol=tol)
+    assert np.isfinite(out).all()
+
+
+@pytest.fixture
+def small_chunks(monkeypatch):
+    """The kernel's constants shrunk so that tiny calls take a chunk's paths:
+    more than 32 stacked rows are a chunk, whose tile is 4 entries (64
+    positions); the test sets how many rows x kv heads a grid step holds."""
+    monkeypatch.setattr(paged_module, "_ROW_BLOCK", 32)
+    monkeypatch.setattr(paged_module, "_CHUNK_TILE", 4)
+    return lambda rows: monkeypatch.setattr(paged_module, "_CHUNK_ROWS", rows)
+
+
+@pytest.mark.parametrize("store", [None, "int8"])
+@pytest.mark.parametrize("window", [0, 40, 150])
+@pytest.mark.parametrize("head_rows, block_rows", [
+    pytest.param(64, 32, id="half-a-heads-chunk"),       # j0 is 0 and 32 in turn
+    pytest.param(128, 64, id="one-heads-whole-chunk"),
+    pytest.param(192, 96, id="not-in-a-head"),           # 256 rows in 3 blocks of 96
+])
+def test_pallas_chunk_row_blocks_in_a_head_and_across_heads(
+        small_chunks, head_rows, block_rows, window, store):
+    """A chunk of 64 queries x 4 heads a kv head (2 kv heads) whose blocks of
+    rows are half a head's chunk (a grid step's queries start at ``j0`` 0 or
+    32: the window's walk starts and ends where THEY see), one head's whole
+    chunk, and a block that straddles heads (it walks for all the queries):
+    windows shorter than a wide tile of 64 positions and longer than two,
+    contexts around a tile's edges, against the gathered span."""
+    small_chunks(head_rows)
+    s, rep, mb = 64, 4, 10
+    assert paged_module._step_geometry(s * rep, mb, 2) == (4, block_rows, True)
+    rng = np.random.default_rng(54)
+    q, pools, bt, idx, scales = _ragged_case(
+        rng, (64, 127, 128, 129, 160), s, 64, store, tail=0, mb=mb, rep=rep)
+    run = lambda impl: np.asarray(paged_attention(
+        q, *pools, 1, bt, idx, *scales, impl=impl, interpret=True, window=window))
+    out = run("pallas")
+    tol = 1e-5 if store is None else 1e-4
+    np.testing.assert_allclose(out, run("gather"), rtol=tol, atol=tol)
+    assert np.isfinite(out).all()
+
+
+@pytest.mark.parametrize("window", [0, 70])
+def test_pallas_chunk_walk_touches_no_entry_outside_its_span(small_chunks, window):
+    """A chunk's loop of copies, like the written-out ones: every entry past
+    the row's last live one - and every entry wholly behind the window of the
+    row's first query - points at a block of NaN, and the output is bit-equal
+    to the run where they point at the null block."""
+    small_chunks(128)
+    outs = []
+    for tail in (0, 1):
+        q, pools, bt, idx, scales = _ragged_case(
+            np.random.default_rng(8), (200, 230), 64, 64, None, tail, mb=16, rep=4)
+        if window:
+            behind = np.maximum(idx - window + 1, 0) // WALK_BS
+            for i, n in enumerate(behind):
+                bt[i, :n] = tail
+            assert behind.min() > 0
+        outs.append(np.asarray(paged_attention(
+            q, *pools, 2, bt, idx, *scales, impl="pallas", interpret=True, window=window)))
+    assert (bt == 1).any() and np.isfinite(outs[1]).all()
+    np.testing.assert_array_equal(outs[1], outs[0])
+
+
+#: what the calls below trace to, printed by a process of its own: the jaxpr of
+#: a call (the kernel's body and the call's parameters are in it) also shows
+#: process-wide settings other tests change (the default matmul precision)
+_ONE_BLOCK_SCRIPT = """
+import hashlib, importlib, json
+import jax, jax.numpy as jnp
+ops = importlib.import_module("accelerate_tpu.ops.paged_attention")
+S = jax.ShapeDtypeStruct
+
+def text(b, s, rep, block_len=1, window=0, store=jnp.float32, hd=64, n_kv=2, mb=24, nb=64):
+    pools = [S((3, nb, 16, n_kv * hd), store)] * 2
+    scales = [S((3, nb, 16, n_kv), jnp.float32)] * 2 if store != jnp.float32 else []
+    return str(jax.make_jaxpr(lambda q, bt, idx, *ps: ops.paged_attention(
+        q, *ps[:2], 1, bt, idx, *ps[2:], impl="pallas", block_len=block_len, window=window))(
+            S((b, s, n_kv * rep, hd), jnp.float32), S((b, mb), jnp.int32), S((b,), jnp.int32),
+            *pools, *scales))
+
+calls = {
+    "decode": dict(b=3, s=1, rep=4),
+    "round": dict(b=3, s=4, rep=8, block_len=4),
+    "window-int8": dict(b=2, s=1, rep=7, window=40, store=jnp.int8),
+    "full-block": dict(b=1, s=64, rep=4),
+    "chunk": dict(b=1, s=72, rep=4),
+}
+out = {}
+for name, shape in calls.items():
+    t = text(**shape)
+    out[name] = {"sha256": hashlib.sha256(t.encode()).hexdigest(), "loops": t.count("while["),
+                 "vmem_limit": t.split("vmem_limit_bytes=")[1].split()[0].strip(",)")}
+print("PROGRAMS " + json.dumps(out))
+"""
+
+#: sha256 of those jaxprs for the calls whose stacked rows fit one block, taken
+#: on the parent commit 410bbdf: a decode step, a block round, a windowed decode
+#: step over an int8 pool, a chunk of exactly ``_ROW_BLOCK`` rows. A chunk's
+#: geometry must not move them
+ONE_BLOCK_PROGRAMS = {
+    "decode": (1 * 4, "38fb1e2436b24a1e1dead5db76d8971a0767fa132de380662dd493a58db77ecc"),
+    "round": (4 * 8, "c157430a484474df39ec9df906a33770179fe9d86ba1b9e741a3f8b5fa5ce9a2"),
+    "window-int8": (1 * 7, "6e7a8fb337e7a76e1e495ab0a4f867c1dd9ab9eb42234e97ab30caa19a5db6a3"),
+    "full-block": (64 * 4, "d26257e9dd943e535dac3afda2b7ad922912349c28b1655f4f4feba875310f2b"),
+}
+
+
+@pytest.fixture(scope="module")
+def traced_programs():
+    import json
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    done = subprocess.run(
+        [sys.executable, "-c", _ONE_BLOCK_SCRIPT], cwd=root, capture_output=True, text=True,
+        timeout=600, env={**os.environ, "JAX_PLATFORMS": "cpu", "XLA_FLAGS": ""})
+    assert done.returncode == 0, done.stderr[-2000:]
+    return json.loads(next(l for l in done.stdout.splitlines() if l.startswith("PROGRAMS "))[9:])
+
+
+@pytest.mark.parametrize("name", list(ONE_BLOCK_PROGRAMS))
+def test_a_call_of_one_block_of_rows_is_the_parents_program(traced_programs, name):
+    """``rep x s <= _ROW_BLOCK``: the geometry is ``(_TILE, one block of the
+    call's rows)``, the constants are the parent's, and what the call traces
+    is the parent's program to the letter - its copies written out under
+    ``pl.when``, ONE loop (the walk), no limit on its VMEM - where a chunk's
+    holds the loops of its copies and of its blocks of rows inside the walk's."""
+    stacked, digest = ONE_BLOCK_PROGRAMS[name]
+    assert (_TILE, _ROW_BLOCK) == (8, 256)
+    assert _step_geometry(stacked, 1024, 8) == (8, -(-stacked // 8) * 8, False)
+    assert _step_geometry(_ROW_BLOCK + 1, 1024, 8)[2]
+    assert traced_programs[name] == {"sha256": digest, "loops": 1, "vmem_limit": "None"}
+    chunk = traced_programs["chunk"]
+    assert chunk["loops"] > 2 and chunk["vmem_limit"] == str(paged_module._CHUNK_VMEM_LIMIT)
 
 
 # -- the latent kernel: one cached vector a token for every head ----------------

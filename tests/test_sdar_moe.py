@@ -169,18 +169,22 @@ def test_one_decode_and_one_prefill_executable_and_what_stats_counts(served):
     assert s["prefix_cache"] is True
 
 
-@pytest.mark.parametrize("tile", [2, 8])
-def test_block_rounds_book_the_paged_kernels_tiles(tiny, tile, monkeypatch):
+@pytest.mark.parametrize("tile, chunk", [(2, 16), (8, 16), (8, 144)])
+def test_block_rounds_book_the_paged_kernels_tiles(tiny, tile, chunk, monkeypatch):
     """``paged_tiles_walked_total`` under block-round dispatch, against the
     positions the executables were handed: every forward of every round
     (``denoise_steps`` + 1 a round) asks a block of queries a row from the
     block's start, a chunk its whole blocks, and a row's entries are taken
-    ``tile`` a softmax step (clamped to the table's width), in every layer."""
+    ``tile`` a softmax step (clamped to the table's width), in every layer.
+    A chunk of 144 queries x 2 heads a kv head is more than one block of
+    stacked rows: its steps are ``_CHUNK_TILE`` entries wide (the table's 64
+    allow it), booked as the tiles they hold and counted as
+    ``paged_chunk_steps_total``; a round's four queries never are."""
     import importlib
 
-    monkeypatch.setattr(importlib.import_module("accelerate_tpu.ops.paged_attention"),
-                        "_TILE", tile)
-    engine = _engine(tiny[0])
+    ops = importlib.import_module("accelerate_tpu.ops.paged_attention")
+    monkeypatch.setattr(ops, "_TILE", tile)
+    engine = _engine(tiny[0], prefill_chunk=chunk, max_seq_len=512 if chunk > 16 else 128)
     bs, burst, b, t = engine.config.block_size, engine.config.decode_burst, 4, 2
     mb, layers = engine.config.blocks_per_slot, engine.stats()["kv_layers"]
     seen = {"prefill": [], "decode": []}
@@ -194,15 +198,18 @@ def test_block_rounds_book_the_paged_kernels_tiles(tiny, tile, monkeypatch):
     engine._prefill_fn = recorded("prefill", engine._prefill_fn)
     engine._decode_fn = recorded("decode", engine._decode_fn)
     rng = np.random.default_rng(1)
-    for n in (37, 5, 50):
+    for n in (37, 5, 50) if chunk == 16 else (330, 5):
         _ask(engine, rng.integers(0, 250, size=n).tolist())
     engine.run_until_idle()
     width = min(tile, mb)
-    walked = tiles = 0
+    wide = min(ops._CHUNK_TILE, mb) if 2 * chunk > ops._ROW_BLOCK else width
+    walked = tiles = chunk_steps = 0
     for pos0, queries in seen["prefill"]:
         rows = [min((int(pos0[0]) + queries - 1) // bs + 1, mb)]
         walked += layers * sum(rows)
-        tiles += layers * sum(-(-n // width) for n in rows)
+        steps = layers * sum(-(-n // wide) for n in rows)
+        tiles += steps * -(-wide // width)
+        chunk_steps += steps if wide > width else 0
     for pos0, _ in seen["decode"]:
         for r in range(burst):
             rows = [min((int(p) + b * r + b - 1) // bs + 1, mb) for p in pos0]
@@ -212,9 +219,12 @@ def test_block_rounds_book_the_paged_kernels_tiles(tiny, tile, monkeypatch):
     assert seen["prefill"] and len(seen["decode"]) > 2
     assert stats["paged_entries_walked_total"] == walked
     assert stats["paged_tiles_walked_total"] == tiles
+    assert stats["paged_chunk_steps_total"] == chunk_steps
+    assert (chunk_steps > 0) == (chunk > 16) and stats["paged_tile_entries"] == width
     assert walked / width <= tiles < walked
     engine.reset_stats()
     assert engine.stats()["paged_tiles_walked_total"] == 0
+    assert engine.stats()["paged_chunk_steps_total"] == 0
 
 
 def test_a_flight_entry_carries_the_block_totals_as_of_its_harvest(tiny):

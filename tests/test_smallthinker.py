@@ -36,6 +36,8 @@ from accelerate_tpu.models.cache import CacheSpec, PagedKind  # noqa: E402
 from accelerate_tpu.ops.moe import expert_ffn, route  # noqa: E402
 from accelerate_tpu.ops.paged_attention import (  # noqa: E402
     paged_attention,
+    _CHUNK_TILE,
+    tile_entries,
     tiles_walked,
     window_walk,
 )
@@ -390,6 +392,8 @@ def test_stats_count_the_walk_by_kind_as_the_positions_say(tiny):
     assert s["paged_entries_behind_window_total"] == behind > window
     assert s["paged_entries_walked_total"] == full + window
     assert s["paged_tiles_walked_total"] == tiles
+    # chunks of 16 queries x 2 heads a kv head and decode rows: one block of rows each
+    assert s["paged_chunk_steps_total"] == 0
     assert s["paged_entries_table_total"] == len(rows) * mb * (n_full + n_window)
     assert (s["kv_window"], s["kv_full_layers"], s["kv_window_layers"]) == (WINDOW, 2, 3)
     # float32 K and V of 2 kv heads of 16: 256 B a position and layer
@@ -416,6 +420,66 @@ def test_window_walk_and_tiles_walked_are_what_the_kernel_reads():
     lo, end = window_walk(np.asarray([100]), 1, WINDOW, BLOCK, 64)
     assert (lo.tolist(), end.tolist()) == ([22], [26])
     assert tiles_walked(end, 64, first=lo).tolist() == [2]  # entries 22-25: tiles 2 and 3
+    # a chunk's call (more than 256 stacked rows) walks the same entries a wide
+    # tile a step, laid from entry 0 too; 256 rows are still a decode row's tile
+    wide = tile_entries(256, stacked=257)
+    assert (tile_entries(256), tile_entries(256, stacked=256), wide) == (8, 8, _CHUNK_TILE)
+    assert tile_entries(20, stacked=257) == 20 and tile_entries(256, latent=True, stacked=257) == 32
+    lo, end = window_walk(np.asarray([400, 0]), 300, WINDOW, BLOCK, 256)
+    assert (lo.tolist(), end.tolist()) == ([97, 0], [175, 75])
+    assert tiles_walked(end, 256, first=lo, stacked=600).tolist() == [
+        -(-175 // wide) - 97 // wide, -(-75 // wide)]
+    assert tiles_walked(end, 256, stacked=600).tolist() == [-(-175 // wide), -(-75 // wide)]
+
+
+def test_a_chunks_dispatch_books_its_wide_steps_and_the_tiles_they_hold(tiny):
+    """Chunks of 136 queries x 2 heads a kv head are 272 stacked rows, more
+    than a grid step's one block: the kernel takes them ``_CHUNK_TILE``
+    entries a softmax step, ``paged_chunk_steps_total`` counts those steps
+    (layers x steps, by kind from where its walk starts) and
+    ``paged_tiles_walked_total`` books each as the ``paged_tile_entries``-entry
+    tiles it holds beside the decode rows' own; a reset zeroes both."""
+    model, c = tiny
+    chunk = 136
+    engine = _engine(model, num_slots=2, max_seq_len=512, prefill_chunk=chunk)
+    seen = {"prefill": [], "decode": []}
+
+    def recorded(kind, fn):
+        def call(*args):
+            seen[kind].append(np.array(args[3]))
+            return fn(*args)
+        return call
+
+    engine._prefill_fn = recorded("prefill", engine._prefill_fn)
+    engine._decode_fn = recorded("decode", engine._decode_fn)
+    request = _ask(engine, np.random.default_rng(6).integers(0, 256, size=300).tolist(), 5)
+    engine.run_until_idle()
+    assert len(request.output_tokens) == 5
+    assert [int(p[0]) for p in seen["prefill"]] == [0, 136, 272]
+
+    mb, n_full, n_window = 128, 2, 3
+    wide, narrow = tile_entries(mb, stacked=2 * chunk), tile_entries(mb)
+    assert (wide, narrow) == (_CHUNK_TILE, 8) and wide % narrow == 0
+
+    def steps(first, queries, tile):
+        end = min((first + queries - 1) // BLOCK + 1, mb)
+        lo = min(max(first - WINDOW + 1, 0) // BLOCK, end)
+        return n_full * -(-end // tile) + n_window * (-(-end // tile) - lo // tile)
+
+    chunk_steps = sum(steps(int(p[0]), chunk, wide) for p in seen["prefill"])
+    decode_tiles = sum(steps(int(p) + step, 1, narrow)
+                       for pos0 in seen["decode"] for step in range(BURST) for p in pos0)
+    s = engine.stats()
+    assert s["paged_tile_entries"] == narrow
+    assert s["paged_chunk_steps_total"] == chunk_steps > 0
+    assert s["paged_tiles_walked_total"] == chunk_steps * (wide // narrow) + decode_tiles
+    fill = s["paged_entries_walked_total"] / (s["paged_tiles_walked_total"] * narrow)
+    assert 0 < fill <= 1
+    engine.reset_stats()
+    assert engine.stats()["paged_chunk_steps_total"] == 0
+    # an engine that only decodes from here on books none
+    engine._count_paged_entries(np.asarray([300, 0]), 1, n_full + n_window)
+    assert engine.stats()["paged_chunk_steps_total"] == 0 < engine.stats()["paged_tiles_walked_total"]
 
 
 # -- one kind of layer: the programs and the defaults are what they were -------------
