@@ -73,6 +73,7 @@ from ..ops.layers import (
     causal_mask,
     dot_product_attention,
     fused_cross_entropy,
+    logit_rows,
     rms_norm,
     shift_labels,
     write_paged_latent,
@@ -471,6 +472,7 @@ def deepseek_apply(
     block_tables=None,
     cache_positions=None,
     paged_write_mask=None,
+    logit_positions=None,
 ):
     """Forward pass: whole sequences in the expanded form (training / eval),
     or — with ``paged_kv`` — one absorbed step against the engine's latent
@@ -478,7 +480,7 @@ def deepseek_apply(
     c = config
     if paged_kv is not None:
         return _paged_step(c, params, input_ids, paged_kv, block_tables,
-                           cache_positions, paged_write_mask)
+                           cache_positions, paged_write_mask, logit_positions)
     b, s = input_ids.shape
     valid = None if attention_mask is None else attention_mask.astype(bool)
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None, :], (b, s))
@@ -502,14 +504,17 @@ def deepseek_apply(
     return out
 
 
-def _paged_step(c, params, input_ids, cache, block_tables, cache_positions, write_mask):
+def _paged_step(c, params, input_ids, cache, block_tables, cache_positions, write_mask,
+                logit_positions=None):
     """One step against the cache ``{"k"[, "k_scale"]}`` — the latent pool
     ``[layers, num_blocks, block_size, pool_width]``, no ``"v"`` —: ``s``
     tokens a row from ``cache_positions`` (every slot's one token, or a
     prefill chunk). The rows' vectors are written first, then every query
     attends what is written up to itself. A lane that ``write_mask`` switches
     off leaves the pool as it was and routes to no expert. The cache comes
-    back whole, and beside the logits the step's ``step_counters``."""
+    back whole, and beside the logits (of ``logit_positions`` alone where the
+    caller names them: :func:`~..ops.layers.logit_rows`) the step's
+    ``step_counters``."""
     b, s = input_ids.shape
     idx = jnp.asarray(cache_positions, jnp.int32).reshape(b)
     positions = idx[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
@@ -528,7 +533,7 @@ def _paged_step(c, params, input_ids, cache, block_tables, cache_positions, writ
                 pairs.append(layer_pairs)
                 elsewhere.append(layer_elsewhere)
     with jax.named_scope("head"):
-        x = rms_norm(x, params["norm"], c.rms_norm_eps)
+        x = rms_norm(logit_rows(x, logit_positions), params["norm"], c.rms_norm_eps)
     out = ModelOutput(logits=_head(x, params["lm_head"]), paged_kv=cache)
     if pairs:
         out["step_counters"] = {

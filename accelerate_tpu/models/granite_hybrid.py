@@ -39,6 +39,7 @@ from ..ops.attention import attention
 from ..ops.fp8 import dense
 from ..ops.layers import (
     fused_cross_entropy,
+    logit_rows,
     rms_norm,
     shift_labels,
     write_paged_kv,
@@ -370,6 +371,7 @@ def granite_hybrid_apply(
     cache_positions=None,
     paged_write_mask=None,
     state_slots=None,
+    logit_positions=None,
 ):
     """Forward pass: whole sequences (training / eval, every layer from a
     zero state), or — with ``paged_kv`` — one step against the engine's
@@ -377,7 +379,7 @@ def granite_hybrid_apply(
     c = config
     if paged_kv is not None:
         return _paged_step(c, params, input_ids, paged_kv, block_tables,
-                           cache_positions, paged_write_mask, state_slots)
+                           cache_positions, paged_write_mask, state_slots, logit_positions)
     valid = None if attention_mask is None else attention_mask.astype(bool)
     x = _embed(c, params, input_ids)
     mamba = remat_wrap(lambda x, layer: (mamba_layer_apply(c, layer, x, valid), None), c.remat)
@@ -399,7 +401,7 @@ def granite_hybrid_apply(
 
 
 def _paged_step(c, params, input_ids, cache, block_tables, cache_positions,
-                write_mask, state_slots):
+                write_mask, state_slots, logit_positions=None):
     """One step against the cache ``{"k", "v"[, "k_scale", "v_scale"],
     "ssm", "conv"}``: ``s == 1`` token for every slot (``state_slots``
     ``None``: row ``i`` is slot ``i``, and the recurrence is the
@@ -408,7 +410,9 @@ def _paged_step(c, params, input_ids, cache, block_tables, cache_positions,
     chunked scan from the slot's own state, left with the outgoing state
     and the last valid inputs of the convolution). A lane that
     ``write_mask`` switches off leaves K/V, state and tail as they were.
-    The cache travels in the layer loop's carry and comes back whole."""
+    The cache travels in the layer loop's carry and comes back whole; the
+    logits are those of ``logit_positions`` alone where the caller names
+    them (:func:`~..ops.layers.logit_rows`)."""
     b, s = input_ids.shape
     idx = jnp.asarray(cache_positions, jnp.int32).reshape(b)
     positions = idx[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
@@ -482,7 +486,7 @@ def _paged_step(c, params, input_ids, cache, block_tables, cache_positions,
 
     with jax.named_scope("layers"):
         x, cache = _run_layers(c, params, (x, dict(cache)), mamba_body, attention_body)
-    _, logits = _final_norm_and_head(c, params, x)
+    _, logits = _final_norm_and_head(c, params, logit_rows(x, logit_positions))
     return ModelOutput(logits=logits, paged_kv=cache)
 
 

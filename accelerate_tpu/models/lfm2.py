@@ -55,7 +55,13 @@ from jax.sharding import PartitionSpec as P
 from ..modules import Model, ModelOutput
 from ..ops.attention import attention
 from ..ops.fp8 import dense
-from ..ops.layers import fused_cross_entropy, rms_norm, shift_labels, write_paged_kv
+from ..ops.layers import (
+    fused_cross_entropy,
+    logit_rows,
+    rms_norm,
+    shift_labels,
+    write_paged_kv,
+)
 from ..ops.moe import expert_ffn, route
 from ..ops.paged_attention import paged_attention
 from ..ops.ssm import conv_with_tail
@@ -370,6 +376,7 @@ def lfm2_apply(
     cache_positions=None,
     paged_write_mask=None,
     state_slots=None,
+    logit_positions=None,
 ):
     """Forward pass: whole sequences (training / eval, every convolution
     from an empty tail), or — with ``paged_kv`` — one step against the
@@ -377,7 +384,7 @@ def lfm2_apply(
     c = config
     if paged_kv is not None:
         return _paged_step(c, params, input_ids, paged_kv, block_tables,
-                           cache_positions, paged_write_mask, state_slots)
+                           cache_positions, paged_write_mask, state_slots, logit_positions)
     b, s = input_ids.shape
     valid = None if attention_mask is None else attention_mask.astype(bool)
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None, :], (b, s))
@@ -420,14 +427,16 @@ def lfm2_apply(
 
 
 def _paged_step(c, params, input_ids, cache, block_tables, cache_positions,
-                write_mask, state_slots):
+                write_mask, state_slots, logit_positions=None):
     """One step against the cache ``{"k", "v"[, "k_scale", "v_scale"],
     "conv"}``: ``s == 1`` token for every slot (``state_slots`` ``None``:
     row ``i`` is slot ``i``), or a prefill chunk of ``s`` tokens for the
     slots ``state_slots [b]``, each continuing from its own tail. A lane
     that ``write_mask`` switches off leaves K/V and tail as they were and
     routes to no expert. The cache comes back whole, and beside the logits
-    the step's ``step_counters`` (:func:`step_counter_shapes`)."""
+    (of ``logit_positions`` alone where the caller names them:
+    :func:`~..ops.layers.logit_rows`) the step's ``step_counters``
+    (:func:`step_counter_shapes`)."""
     b, s = input_ids.shape
     idx = jnp.asarray(cache_positions, jnp.int32).reshape(b)
     positions = idx[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
@@ -476,7 +485,7 @@ def _paged_step(c, params, input_ids, cache, block_tables, cache_positions,
             else:
                 x, layer_pairs = _routed_ff(c, stacks["moe"], ff_index, x, valid)
                 pairs.append(layer_pairs)
-    _, logits = _final_norm_and_head(c, params, x)
+    _, logits = _final_norm_and_head(c, params, logit_rows(x, logit_positions))
     out = ModelOutput(logits=logits, paged_kv=cache)
     if pairs:
         out["step_counters"] = _step_counters(pairs)

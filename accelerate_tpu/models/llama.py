@@ -34,6 +34,7 @@ from ..ops.layers import (
     cached_attention,
     cross_entropy_loss,
     fused_cross_entropy,
+    logit_rows,
     rms_norm,
     rope_cached_attention_block,
     rope_frequencies,
@@ -295,6 +296,7 @@ def llama_apply(
     block_tables: jax.Array | None = None,  # [b, max_blocks] pool block ids
     cache_positions: jax.Array | None = None,  # [b] first new token position
     paged_write_mask: jax.Array | None = None,  # [b, s] real-token mask
+    logit_positions: jax.Array | None = None,  # [b, r] of 0..s-1 (paged step)
 ):
     """Forward pass; four modes:
 
@@ -313,7 +315,9 @@ def llama_apply(
       and block (:func:`_llama_paged_step`).
       One compiled ``[num_slots, 1]`` program serves every decode iteration
       for the lifetime of the engine; ``s > 1`` with a ``paged_write_mask``
-      is a chunked-prefill slice of one prompt.
+      is a chunked-prefill slice of one prompt. ``logit_positions`` names
+      the positions of each row whose logits the caller reads: the head runs
+      on those rows alone (:func:`~..ops.layers.logit_rows`).
     """
     c = config
     b, s = input_ids.shape
@@ -332,7 +336,7 @@ def llama_apply(
     if paged_kv is not None:
         return _llama_paged_step(
             c, params, input_ids, paged_kv, block_tables, cache_positions,
-            paged_write_mask, cos, sin,
+            paged_write_mask, cos, sin, logit_positions,
         )
     if kv_cache is not None:
         return _llama_decode_step(c, params, input_ids, kv_cache, cache_index, cos, sin)
@@ -439,7 +443,7 @@ def _llama_decode_step(c, params, input_ids, kv_cache, cache_index, cos, sin):
 
 def _llama_paged_step(
     c, params, input_ids, paged_kv, block_tables, cache_positions,
-    paged_write_mask, cos, sin,
+    paged_write_mask, cos, sin, logit_positions=None,
 ):
     """One step against the block-paged KV pool: ``s == 1`` token per slot
     (the engine's single compiled decode program) or an ``s``-token prefill
@@ -485,7 +489,7 @@ def _llama_paged_step(
             body, (x, tuple(paged_kv[n] for n in names)),
             (params["layers"], jnp.arange(n_layers, dtype=jnp.int32)),
         )
-    _, _, logits = _final_norm_and_head(c, params, x)
+    _, _, logits = _final_norm_and_head(c, params, logit_rows(x, logit_positions))
     return ModelOutput(logits=logits, paged_kv=dict(zip(names, pools)))
 
 

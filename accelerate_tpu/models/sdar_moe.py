@@ -54,6 +54,7 @@ from ..ops.layers import (
     dot_product_attention,
     fused_cross_entropy,
     last_visible,
+    logit_rows,
     rms_norm,
     shift_labels,
     write_paged_kv,
@@ -265,13 +266,14 @@ def sdar_apply(
     block_tables=None,
     cache_positions=None,
     paged_write_mask=None,
+    logit_positions=None,
 ):
     """Forward pass: whole sequences (training / eval), or — with
     ``paged_kv`` — one step against the engine's cache (:func:`_paged_step`)."""
     c = config
     if paged_kv is not None:
         return _paged_step(c, params, input_ids, paged_kv, block_tables,
-                           cache_positions, paged_write_mask)
+                           cache_positions, paged_write_mask, logit_positions)
     b, s = input_ids.shape
     valid = None if attention_mask is None else attention_mask.astype(bool)
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None, :], (b, s))
@@ -299,7 +301,8 @@ def sdar_apply(
     return out
 
 
-def _paged_step(c, params, input_ids, cache, block_tables, cache_positions, write_mask):
+def _paged_step(c, params, input_ids, cache, block_tables, cache_positions, write_mask,
+                logit_positions=None):
     """One step against the cache ``{"k", "v"[, "k_scale", "v_scale"]}``:
     ``s`` tokens a row starting at ``cache_positions`` (a prefill chunk of
     one prompt, or the ``block_length`` positions of every slot's open
@@ -307,7 +310,8 @@ def _paged_step(c, params, input_ids, cache, block_tables, cache_positions, writ
     written first, then every query attends what is written before the end
     of its own block. A lane that ``write_mask`` switches off leaves K/V as
     they were and routes to no expert. The cache comes back whole, and
-    beside the logits the step's ``step_counters``."""
+    beside the logits (of ``logit_positions`` alone where the caller names
+    them: :func:`~..ops.layers.logit_rows`) the step's ``step_counters``."""
     b, s = input_ids.shape
     idx = jnp.asarray(cache_positions, jnp.int32).reshape(b)
     positions = idx[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
@@ -336,7 +340,7 @@ def _paged_step(c, params, input_ids, cache, block_tables, cache_positions, writ
             x, layer_pairs = _routed_ff(c, stack, i, x, valid)
             pairs.append(layer_pairs)
     with jax.named_scope("head"):
-        x = rms_norm(x, params["norm"], c.rms_norm_eps)
+        x = rms_norm(logit_rows(x, logit_positions), params["norm"], c.rms_norm_eps)
     return ModelOutput(logits=_head(x, params["lm_head"]), paged_kv=cache,
                        step_counters=_step_counters(pairs))
 

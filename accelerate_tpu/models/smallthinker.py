@@ -54,7 +54,13 @@ from jax.sharding import PartitionSpec as P
 from ..modules import Model, ModelOutput
 from ..ops.attention import attention
 from ..ops.fp8 import dense
-from ..ops.layers import fused_cross_entropy, rms_norm, shift_labels, write_paged_kv
+from ..ops.layers import (
+    fused_cross_entropy,
+    logit_rows,
+    rms_norm,
+    shift_labels,
+    write_paged_kv,
+)
 from ..ops.moe import expert_ffn, route
 from ..ops.paged_attention import paged_attention
 from ..parallel.pipeline import remat_wrap
@@ -305,6 +311,7 @@ def smallthinker_apply(
     block_tables=None,
     cache_positions=None,
     paged_write_mask=None,
+    logit_positions=None,
 ):
     """Forward pass: whole sequences (training / eval / ``generate``), or -
     with ``paged_kv`` - one step against the engine's cache
@@ -312,7 +319,7 @@ def smallthinker_apply(
     c = config
     if paged_kv is not None:
         return _paged_step(c, params, input_ids, paged_kv, block_tables,
-                           cache_positions, paged_write_mask)
+                           cache_positions, paged_write_mask, logit_positions)
     b, s = input_ids.shape
     valid = None if attention_mask is None else attention_mask.astype(bool)
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None, :], (b, s))
@@ -343,7 +350,8 @@ def smallthinker_apply(
     return out
 
 
-def _paged_step(c, params, input_ids, cache, block_tables, cache_positions, write_mask):
+def _paged_step(c, params, input_ids, cache, block_tables, cache_positions, write_mask,
+                logit_positions=None):
     """One step against the cache ``{"k", "v", "k_window", "v_window"[, and
     a ``_scale`` beside each]}``: ``s`` tokens a row starting at
     ``cache_positions`` (a prefill chunk of one prompt, or one token of every
@@ -351,8 +359,9 @@ def _paged_step(c, params, input_ids, cache, block_tables, cache_positions, writ
     window kind's. A layer writes the rows' keys and values into its kind's
     pool, then every query attends what its kind lets it see. A lane that
     ``write_mask`` switches off leaves K/V as they were and routes to no
-    expert. The cache comes back whole, and beside the logits the step's
-    ``step_counters``."""
+    expert. The cache comes back whole, and beside the logits (of
+    ``logit_positions`` alone where the caller names them:
+    :func:`~..ops.layers.logit_rows`) the step's ``step_counters``."""
     b, s = input_ids.shape
     idx = jnp.asarray(cache_positions, jnp.int32).reshape(b)
     positions = idx[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
@@ -388,7 +397,7 @@ def _paged_step(c, params, input_ids, cache, block_tables, cache_positions, writ
             x, layer_pairs = _experts(c, stack, i, x, experts, weights, valid)
             pairs.append(layer_pairs)
     with jax.named_scope("head"):
-        x = rms_norm(x, params["norm"], c.rms_norm_eps)
+        x = rms_norm(logit_rows(x, logit_positions), params["norm"], c.rms_norm_eps)
     return ModelOutput(logits=_head(x, params["lm_head"]), paged_kv=cache,
                        step_counters=_step_counters(pairs))
 

@@ -25,6 +25,19 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-6) -> jax.Array:
     return (normed * weight.astype(jnp.float32)).astype(dtype)
 
 
+@jax.named_scope("head")
+def logit_rows(x: jax.Array, logit_positions) -> jax.Array:
+    """The hidden rows a paged step's caller reads logits of: ``x [b, s, h]``
+    at ``logit_positions [b, r]`` (each row's own positions in ``0..s-1``) is
+    ``[b, r, h]``, taken before the final norm and the head, which are
+    row-wise: the step's ``logits`` come back ``[b, r, vocab]``. ``None`` is
+    every position, and the program without the argument."""
+    if logit_positions is None:
+        return x
+    rows = jnp.asarray(logit_positions, jnp.int32)
+    return jnp.take_along_axis(x, rows[:, :, None], axis=1)
+
+
 def rope_frequencies(head_dim: int, max_seq_len: int, theta: float = 10000.0):
     """Precomputed RoPE cos/sin tables [max_seq, head_dim//2]."""
     inv_freq = 1.0 / (theta ** (np.arange(0, head_dim, 2) / head_dim))

@@ -1343,12 +1343,14 @@ class InferenceEngine:
            against the pool. The paged step scatters the block's keys and
            values at its positions first and every query then attends
            everything written before the END of its block, so the block
-           sees itself as of this pass. The pick runs over ``[num_slots *
-           B]`` rows with each slot's lanes broadcast (a sampled lane draws
-           every position from its own row, keyed by its output position);
-           pass ``t`` fixes the still-masked positions of sub-block ``t``,
-           ``[t*B/T, (t+1)*B/T)``, and a position's log-probability is
-           that of the pass that fixed it;
+           sees itself as of this pass. Pass ``t`` fixes the still-masked
+           positions of sub-block ``t``, ``[t*B/T, (t+1)*B/T)``, and reads
+           no other row: the step is asked for the logits of those ``B/T``
+           positions of every slot, so the head and the pick run over
+           ``[num_slots * B/T]`` rows with each slot's lanes broadcast (a
+           sampled lane draws every position from its own row, keyed by
+           its output position). A position's log-probability is that of
+           the pass that fixed it;
         2. **the commit pass**: one forward over the clean block. What it
            writes at ``[pos, pos + B)`` is what later blocks attend: it
            overwrites the denoise passes' rows before any other query reads
@@ -1372,51 +1374,52 @@ class InferenceEngine:
         b, t_steps, slots = blk.block_length, self._denoise_steps, cfg.num_slots
         sub = b // t_steps
         mask_id = jnp.int32(blk.mask_token_id)
-        col = jnp.arange(b, dtype=jnp.int32)
+        col = jnp.arange(sub, dtype=jnp.int32)
 
         def decode_block_rounds(params, cache, block_tables, pos0, toks, known, active,
                                 lanes, gmask, base_key):
             self._decode_traces += 1  # traced-body side effect: cache misses only
-            rows = {name: jnp.repeat(lane, b, axis=0) for name, lane in lanes.items()}
+            rows = {name: jnp.repeat(lane, sub, axis=0) for name, lane in lanes.items()}
             write = jnp.broadcast_to(active, (slots, b))
 
-            def forward(cache, x, pos):
+            def forward(cache, x, pos, logit_positions=None):
                 return apply_fn(
                     params, input_ids=x, paged_kv=cache, block_tables=block_tables,
                     cache_positions=pos,
                     paged_write_mask=write,  # PREFILL/free lanes must not scribble
+                    logit_positions=logit_positions,
                 )
 
             def one_round(carry, n):
                 cache, x, known, pos = carry
-                # a row's output position: lanes["pos"] counts from the
-                # block's first position (the known ones lie before it)
-                row_lanes = dict(rows, pos=(
-                    (lanes["pos"] + n * b)[:, None] + col[None, :]).reshape(slots * b))
-                logp = jnp.zeros((slots, b), jnp.float32)
-                tvals = jnp.zeros((slots, b, n_top), jnp.float32)
-                tids = jnp.zeros((slots, b, n_top), jnp.int32)
-                counters = []
+                # what each pass reports of its sub-block (zeros at known positions)
+                logp, tvals, tids, counters = [], [], [], []
                 for t in range(t_steps):
+                    lo, hi = t * sub, (t + 1) * sub
                     with jax.named_scope("denoise_pass"):
-                        out = forward(cache, x, pos)
+                        out = forward(cache, x, pos, jnp.broadcast_to(lo + col, (slots, sub)))
                     cache = out["paged_kv"]
                     if counted:
                         counters.append(out["step_counters"])
+                    # a row's output position: lanes["pos"] counts from the
+                    # block's first position (the known ones lie before it)
+                    row_lanes = dict(rows, pos=(
+                        (lanes["pos"] + n * b + lo)[:, None] + col[None, :]
+                    ).reshape(slots * sub))
                     tok, lp, tv, ti = pick_tokens(
-                        out["logits"].reshape(slots * b, -1), row_lanes,
+                        out["logits"].reshape(slots * sub, -1), row_lanes,
                         row_lanes["dfa_state"], jnp.int32(0), gmask, base_key,
                         eos_id=eos_id, logprobs_topn=topn,
                     )
-                    fix = ~known & (col >= t * sub) & (col < (t + 1) * sub)
-                    x = jnp.where(fix, tok.reshape(slots, b), x)
-                    logp = jnp.where(fix, lp.reshape(slots, b), logp)
-                    tvals = jnp.where(fix[..., None], tv.reshape(slots, b, n_top), tvals)
-                    tids = jnp.where(fix[..., None], ti.reshape(slots, b, n_top), tids)
-                    known = known | fix
+                    fix = ~known[:, lo:hi]
+                    x = x.at[:, lo:hi].set(
+                        jnp.where(fix, tok.reshape(slots, sub), x[:, lo:hi]))
+                    logp.append(jnp.where(fix, lp.reshape(slots, sub), 0.0))
+                    tvals.append(jnp.where(fix[..., None], tv.reshape(slots, sub, n_top), 0.0))
+                    tids.append(jnp.where(fix[..., None], ti.reshape(slots, sub, n_top), 0))
                 with jax.named_scope("commit_pass"):
                     out = forward(cache, x, pos)
-                ys = (x, logp, tvals, tids)
+                ys = (x, *(jnp.concatenate(parts, axis=1) for parts in (logp, tvals, tids)))
                 if counted:
                     counters.append(out["step_counters"])
                     ys += (jax.tree.map(lambda *c: jnp.stack(c), *counters),)
@@ -1431,9 +1434,16 @@ class InferenceEngine:
         return jax.jit(decode_block_rounds, donate_argnums=(1,))
 
     def _build_prefill_fn(self):
+        """A prompt's chunk. The head runs on the one row somebody reads:
+        the prompt's last real position, which the first token is picked
+        from (``last_idx``; only meaningful on the final chunk — the host
+        ignores the row otherwise). No token comes of a block model's
+        prefill, so its chunk asks for no logits and returns none: the head
+        falls out of the compiled program."""
         apply_fn = self._apply_fn
         has_state = bool(self._cache_spec.slot_state)
         counted = bool(self._step_counters)
+        reads_last = self._block is None
 
         def prefill(params, cache, block_table, start, chunk, valid,
                     last_idx, slot):
@@ -1448,15 +1458,15 @@ class InferenceEngine:
                 block_tables=block_table,  # [1, mb]
                 cache_positions=start,  # [1]
                 paged_write_mask=valid,  # drops the padded tail
+                logit_positions=last_idx.reshape(1, 1) if reads_last else None,
                 **state_kw,
             )
-            # the logits of the prompt's last real position, which the
-            # first token is picked from — only meaningful on the final
-            # chunk; the host ignores them otherwise
-            last = jnp.take(out["logits"][0], last_idx, axis=0)
+            done = (out["paged_kv"],)
+            if reads_last:
+                done += (out["logits"][0, 0],)
             if counted:
-                return out["paged_kv"], last, out["step_counters"]
-            return out["paged_kv"], last
+                done += (out["step_counters"],)
+            return done
 
         return jax.jit(prefill, donate_argnums=(1,))
 
@@ -2047,13 +2057,19 @@ class InferenceEngine:
             # lane samples (the only ones that sort the vocabulary and draw)
             "pick_dispatches_total": self._pick_dispatches,
             "pick_draw_dispatches_total": self._pick_draw_dispatches,
+            # the rows of a prompt's chunk the head runs on: the one the
+            # first token is picked from, or none (a block model's chunk)
+            "head_rows_per_chunk": int(self._block is None),
         }
         if self._block is not None:
             # tokens are counted as emitted and forwards as run: a round is
             # denoise_steps + 1 forwards and commits block_length positions
-            # a live lane, of which EOS and length cuts emit fewer
+            # a live lane, of which EOS and length cuts emit fewer; the head
+            # and the pick of a denoise pass run on its sub-block of every slot
             out.update(block_length=self._block.block_length,
                        denoise_steps=self._denoise_steps,
+                       head_rows_per_pass=(self.config.num_slots * self._block.block_length
+                                           // self._denoise_steps),
                        block_round_refuses=dict(self.block_round_refuses),
                        **self._block_stats())
         if self._step_counters:
@@ -2820,11 +2836,13 @@ class InferenceEngine:
             table = self._block_tables[req.slot : req.slot + 1].copy()
             pos0, slot = np.asarray([start], np.int32), np.asarray([req.slot], np.int32)
             self._fl_part("prefill/call")
-            self._cache, logits, *counters = self._prefill_fn(
+            self._cache, *rest = self._prefill_fn(
                 self._params, self._cache, table, pos0, chunk, valid, last_idx, slot)
             self._fl_part(None)
+            if blk is None:  # (a block model's chunk hands back no logits)
+                logits = rest.pop(0)
             # a chunk's step counters stay on the device until the next harvest
-            self._pending_counters.extend(counters)
+            self._pending_counters.extend(rest)
         req.prefill_pos = end
         req.prefill_iterations += 1
         lp_entry = None

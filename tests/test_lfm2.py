@@ -342,21 +342,27 @@ def test_state_dtype_bf16_is_refused_there_is_no_leaf_at_a_precision_of_its_own(
 #: before. A PR that changes these programs on purpose takes the new digests.
 PARENT_PROGRAMS = {
     "llama": {"decode": "a75288acbd793f5b9ccf1f009d154c0f8019fc3d1df65876c1ebe506ee837729",
-              "prefill": "d4655201652ba387c4674794762519f6ba3e8cd8ba7bb5e36d8c9beeb20083cc"},
+              "prefill": "e594c3b623ec5bf1e9a060bc05fa603db2a18378056928ce059edf1767779368"},
     "hybrid": {"decode": "65630f0e129fc5e7a5dc1faba0de6280350efc41bb2a1d29cc2283efae433fae",
-               "prefill": "500256f0843884714b072f4827ca179432452cf4075174b910d169d88cb825e9"},
+               "prefill": "0c510225ac2b234194e42ac32b93c15f4420d77415366687abb66744dabdc240"},
     # taken on 2a01d1b (the parent of PR 38, which gave the paged kernel its
     # ``block_len`` and the router its ``scoring``): at ``block_len`` 1 and
     # ``scoring="sigmoid"`` this model's programs, counters and all, are that commit's
     "lfm2": {"decode": "a66962a90db2b7a2b3b5764c3f9e93417827405a1aa95e95574284d18ad839c3",
-             "prefill": "19583c71ee12fee661ae51399c0d2d793d44faae5ee80458f0469fea67548f25"},
+             "prefill": "315cfca96229288ec1db9a01e713cb174fb85815e01b0de1cab1c6cde6384bfd"},
     # taken on 6756b7a (the parent of PR 42, which gave the cache spec a latent
     # kind, the router its groups and the expert product its held range): the
     # block round and the chunk of the fourth served family; the llama digests
     # above are the Mistral cell's programs (one model class)
-    "sdar": {"decode": "4a440ed4b36f0295b667c14fadca5ec50035888714bb9a666df461abeb79af01",
-             "prefill": "39e3a380ab93198d18faae1c80665d01905300cac146cb515ace4326dae3ab13"},
+    "sdar": {"decode": "86dec0d7ac67dffd6771d2ac0f08953ff38f85107a49b72e544cf087d9177402",
+             "prefill": "195ee03170d3dc92ca790b54049075c6a5c9e90173e348d3df988a28a9bee818"},
 }
+# PR 46 took five of these on purpose, on the tree it built on 42d3b0d: every
+# ``prefill`` (a chunk's step is asked for the one row the first token is picked
+# from, ``logit_positions``; SDAR's chunk for none and hands back no logits) and
+# SDAR's ``decode`` (a denoise pass asks for its sub-block's rows and picks over
+# them). The ``decode`` digests of llama, hybrid and lfm2 are the commits' named
+# above, untouched: a step that is not handed the argument is the parent's program
 
 
 _DIGEST_SCRIPT = """

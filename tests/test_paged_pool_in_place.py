@@ -14,6 +14,8 @@ the paged step at Mistral-7B widths compiled for a described v5e chip, bf16
 and int8 pools, with the Pallas kernel in it — no chip is needed to compile.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -434,9 +436,16 @@ def test_v5e_compiled_block_step_walks_to_the_blocks_end(one_chip, monkeypatch, 
     pool = shaped((2, blocks, bs, 4 * 128), jnp.bfloat16)
 
     def step(params, cache, tables, pos, toks, mask):
+        if program == "prefill":  # as the engine runs it: no logits are read (ISSUE 46)
+            out = sdar_moe.sdar_apply(
+                c, params, toks, paged_kv=cache, block_tables=tables,
+                cache_positions=pos, paged_write_mask=mask)
+            return out["paged_kv"], out["step_counters"]
+        # a denoise pass at denoise_steps 2: the logits of its sub-block of every slot
+        rows = jnp.broadcast_to(jnp.arange(2, dtype=jnp.int32), (slots, 2))
         out = sdar_moe.sdar_apply(
             c, params, toks, paged_kv=cache, block_tables=tables,
-            cache_positions=pos, paged_write_mask=mask,
+            cache_positions=pos, paged_write_mask=mask, logit_positions=rows,
         )
         return (out["paged_kv"], jnp.argmax(out["logits"], -1).astype(jnp.int32),
                 out["step_counters"])
@@ -447,8 +456,18 @@ def test_v5e_compiled_block_step_walks_to_the_blocks_end(one_chip, monkeypatch, 
     with jax.default_matmul_precision("default"):
         compiled = jax.jit(step, donate_argnums=(1,)).lower(*operands).compile()
     text = compiled.as_text()
-    # two layers x (the paged kernel + two grouped products)
-    assert text.count('custom_call_target="tpu_custom_call"') == 6
+    # two layers x (the paged kernel + two grouped products); nobody reads what
+    # the last layer of a block model's chunk hands on, so its two products go
+    # with the head (its keys and values are written before them)
+    assert text.count('custom_call_target="tpu_custom_call"') == (6 if program == "round" else 4)
+    # the head on the rows that are read: 128 of a pass's 256, none of a chunk's
+    # ([2048, 8192] is the head's matrix, a parameter)
+    vocab_wide = set(re.findall(r"\[[0-9,]*8192\]", text)) - {"[2048,8192]"}
+    if program == "round":
+        assert vocab_wide & {"[128,8192]", "[64,2,8192]"}
+        assert not vocab_wide & {"[256,8192]", "[64,4,8192]"}
+    else:
+        assert vocab_wide == set()
     w_in, w_out = 2 * 128 * 2048 * 1536, 2 * 128 * 768 * 2048
     found = buffers_moved(text, [w_in, w_in // 2, w_out, w_out // 2, 2 * blocks * bs * 512,
                                  blocks * bs * 512])
@@ -561,9 +580,11 @@ def test_v5e_compiled_two_kind_step_leaves_both_pools_in_place(one_chip, monkeyp
                     (kind.layers, count, bs, 4), jnp.float32)
 
     def step(params, cache, tables, pos, toks, mask):
+        # a chunk as the engine asks for it: the logits of one row (ISSUE 46)
+        rows = jnp.full((1, 1), chunk - 1, jnp.int32) if program == "prefill" else None
         out = st.smallthinker_apply(
             c, params, toks, paged_kv=cache, block_tables=tables,
-            cache_positions=pos, paged_write_mask=mask,
+            cache_positions=pos, paged_write_mask=mask, logit_positions=rows,
         )
         return (out["paged_kv"], jnp.argmax(out["logits"][:, -1, :], -1).astype(jnp.int32),
                 out["step_counters"])
@@ -581,8 +602,10 @@ def test_v5e_compiled_two_kind_step_leaves_both_pools_in_place(one_chip, monkeyp
     assert buffers_moved(text, [full, window, window // 3]) == {"moved": [], "unaliased": []}
     w_in, w_out = 64 * 2560 * 1536, 64 * 768 * 2560
     assert buffers_moved(text, [w_in, w_out, 3 * w_in, 3 * w_out])["moved"] == []
-    # the temporaries are activations (a chunk's 6,144 pairs through the experts, the
-    # logits), under a third of one window layer's slab of the cell's pool (210 MB)
+    # a chunk's head runs on the row that is read: no [chunk, vocab] array
+    assert f"[{chunk},8192]" not in text and f"[1,{chunk},8192]" not in text
+    # the temporaries are activations (a chunk's 6,144 pairs through the experts),
+    # under a third of one window layer's slab of the cell's pool (210 MB)
     assert compiled.memory_analysis().temp_size_in_bytes < (
         320e6 if quantized else 210e6)
 

@@ -554,9 +554,11 @@ print("DIGESTS " + json.dumps(out))
 #: spec its kinds). ``tests/test_lfm2.py`` keeps the llama (the Mistral cell's),
 #: hybrid, LFM2 and SDAR programs; here are the DeepSeek-V3 engine's two and the
 #: routed product at its defaults. A PR that changes these programs on purpose
-#: takes the new digests
+#: takes the new digests. PR 46 took ``deepseek`` ``prefill`` (on the tree it built
+#: on 42d3b0d: a chunk's step is asked for the one row the first token is picked
+#: from); ``deepseek`` ``decode`` and ``defaults`` ``routed`` are the parent's still
 PARENT_PROGRAMS = {
-    "deepseek": {"decode": "16dba12fa31d7584643a755dab50ede39ac9e852bcc147cfb5a22234288a17a7", "prefill": "1dd6c45e0d066ce670a29d0d3138210a3bfb906c36127172ce3097a8eba4b88b"},
+    "deepseek": {"decode": "16dba12fa31d7584643a755dab50ede39ac9e852bcc147cfb5a22234288a17a7", "prefill": "f73eb985c0a741f6fd4b87ff141bd43af133a5988ea6c9f17c6090527fc61f6c"},
     "defaults": {"routed": "6f9888adb74f24f71c5651d3cacd2f50b1e7b1d15237f28f42442efb55fe7cd5"},
 }
 
