@@ -24,9 +24,8 @@ from jax.sharding import PartitionSpec as P
 from ..modules import Model, ModelOutput
 from ..ops.attention import attention
 from ..ops.fp8 import dense
-from ..ops.layers import rms_norm
+from ..ops.layers import mesh_constrain as _constrain, residual_spec, rms_norm
 from ..parallel.pipeline import remat_wrap
-from .llama import _constrain, residual_spec
 
 
 @dataclass

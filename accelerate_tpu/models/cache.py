@@ -97,6 +97,18 @@ class PagedKind:
         return leaf if first else f"{leaf}_{self.name}"
 
 
+def pool_leaf_names(cache, kind: PagedKind | None = None, first: bool = True) -> tuple:
+    """The names in the cache dict ``cache`` of one paged kind's pool arrays,
+    in the order :func:`~..ops.layers.write_paged_kv` returns them: ``"k"``,
+    ``"v"`` and, where the pool is quantized, the float32 ``"k_scale"``,
+    ``"v_scale"`` beside them - under the kind's own names
+    (:meth:`PagedKind.pool_leaf`) for a ``kind`` that is not the ``first``.
+    A step takes ``[cache[n] for n in names]`` out and puts
+    ``zip(names, leaves)`` back."""
+    leaves = ("k", "v", "k_scale", "v_scale")[: 4 if "k_scale" in cache else 2]
+    return leaves if kind is None else tuple(kind.pool_leaf(leaf, first) for leaf in leaves)
+
+
 @dataclass(frozen=True)
 class CacheSpec:
     #: layers that hold block-paged K/V (the pool's leading dimension; with

@@ -68,23 +68,27 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..modules import Model, ModelOutput
+from ..ops import moe
 from ..ops.fp8 import dense
 from ..ops.layers import (
+    attention_out,
     causal_mask,
     dot_product_attention,
+    embed_tokens,
     fused_cross_entropy,
+    layer_at,
     logit_rows,
+    paged_step_frame,
     rms_norm,
     shift_labels,
+    untied_head,
     write_paged_latent,
     yarn_frequencies,
     yarn_mscale,
 )
-from ..ops.moe import expert_ffn, route
 from ..ops.paged_attention import latent_attention
 from ..parallel.pipeline import remat_wrap
 from .cache import CacheSpec
-from .lfm2 import _step_counters
 
 #: what a YaRN ``rope_scaling`` group has to state (the betas and the two
 #: ``mscale`` keys have the published code's defaults)
@@ -239,16 +243,10 @@ def cache_spec(config: DeepseekV3Config) -> CacheSpec:
 
 
 def step_counter_shapes(config: DeepseekV3Config) -> dict:
-    """:func:`.lfm2.step_counter_shapes` over the experts held, and the
-    pairs the router sent to experts held elsewhere."""
-    return {
-        "moe_expert_pairs": (config.n_moe, config.n_routed_experts),
-        "moe_dispatches_total": (),
-        "moe_pairs_routed_total": (),
-        "moe_experts_touched_total": (),
-        "moe_load_max_total": (),
-        "moe_pairs_elsewhere_total": (),
-    }
+    """Over the experts held, and the pairs the router sent to experts held
+    elsewhere."""
+    return moe.step_counter_shapes(config.n_moe, config.n_routed_experts,
+                                   extra=("moe_pairs_elsewhere_total",))
 
 
 def init_deepseek_params(key, config: DeepseekV3Config, dtype=jnp.float32):
@@ -305,20 +303,6 @@ def init_deepseek_params(key, config: DeepseekV3Config, dtype=jnp.float32):
 # -- the parts, each under the scope the trace files it by ---------------------
 
 
-@jax.named_scope("embed")
-def _embed(params, input_ids):
-    return params["embed_tokens"][input_ids]
-
-
-@jax.named_scope("head")
-def _head(x, lm_head):
-    return dense(x, lm_head)
-
-
-def _at(stack, i):
-    return {name: leaf[i] for name, leaf in stack.items()}
-
-
 def _rope(c, x, positions):
     """Rotate ``x [b, s, ..., rope]`` by ``positions [b, s]``: rotate-half
     over the rope lanes, YaRN's frequencies, the angles in float32 and the
@@ -367,12 +351,6 @@ def _wkv_b(c, layer):
     return w[..., :c.qk_nope_head_dim], w[..., c.qk_nope_head_dim:]
 
 
-@jax.named_scope("attn_proj")
-def _attn_out(layer, x, attn):
-    b, s = attn.shape[:2]
-    return x + dense(attn.reshape(b, s, -1), layer["wo"])
-
-
 def _expanded_attention(c, layer, x, positions, attention_mask):
     """Whole sequences: every head's keys and values expanded through
     ``W_kvb``, a causal softmax over ``nope + rope`` wide scores."""
@@ -391,7 +369,7 @@ def _expanded_attention(c, layer, x, positions, attention_mask):
         if attention_mask is not None:
             mask = mask & attention_mask[:, None, None, :].astype(bool)
         attn = dot_product_attention(q, k, v, mask=mask, scale=c.softmax_scale)
-    return _attn_out(layer, x, attn)
+    return attention_out(layer, x, attn)
 
 
 def _absorbed_attention(c, layer, i, x, positions, idx, cache, block_tables, valid):
@@ -418,7 +396,7 @@ def _absorbed_attention(c, layer, i, x, positions, idx, cache, block_tables, val
     with jax.named_scope("mla_absorb"):
         attn = jnp.einsum("bshc,chd->bshd", a_lat, w_v)
     cache = {**cache, **dict(zip(("k", "k_scale"), written))}
-    return _attn_out(layer, x, attn), cache
+    return attention_out(layer, x, attn), cache
 
 
 @jax.named_scope("mlp")
@@ -437,13 +415,13 @@ def _routed_ff(c, stack, norm, i, x, live):
     k = c.num_experts_per_tok
     with jax.named_scope("moe_router"):
         y = rms_norm(x, norm, c.rms_norm_eps).reshape(b * s, h)
-        experts, weights = route(
+        experts, weights = moe.route(
             y, stack["gate"][i], stack["expert_bias"][i], k, c.norm_topk_prob,
             c.routed_scaling_factor, n_group=c.n_group, topk_group=c.topk_group,
             norm_eps=1e-20)
     with jax.named_scope("moe_experts"):
         flat_live = None if live is None else live.reshape(b * s)
-        out, pairs = expert_ffn(y, experts, weights, stack["w_in"], stack["w_out"],
+        out, pairs = moe.expert_ffn(y, experts, weights, stack["w_in"], stack["w_out"],
                                 live=flat_live, layer=i, held=c.held)
         n_live = b * s if flat_live is None else flat_live.sum(dtype=jnp.int32)
         elsewhere = n_live * k - pairs.sum()
@@ -458,7 +436,7 @@ def _feed_forward(c, stacks, i, x, live):
     """Layer ``i``'s feed-forward: ``(x, pairs or None, elsewhere or None)``."""
     norm = stacks["attn"]["ffn_norm"][i]
     if i < c.n_dense:
-        return _dense_ff(c, _at(stacks["dense"], i), norm, x), None, None
+        return _dense_ff(c, layer_at(stacks["dense"], i), norm, x), None, None
     return _routed_ff(c, stacks["moe"], norm, i - c.n_dense, x, live)
 
 
@@ -487,57 +465,49 @@ def deepseek_apply(
     stacks = params["layers"]
 
     def one_layer(x, i):
-        x = _expanded_attention(c, _at(stacks["attn"], i), x, positions, attention_mask)
+        x = _expanded_attention(c, layer_at(stacks["attn"], i), x, positions, attention_mask)
         return _feed_forward(c, stacks, i, x, valid)[0]
 
-    x = _embed(params, input_ids)
+    x = embed_tokens(params, input_ids)
     with jax.named_scope("layers"):
         for i in range(c.num_hidden_layers):
             x = remat_wrap(functools.partial(one_layer, i=i), c.remat)(x)
     with jax.named_scope("head"):
         x = rms_norm(x, params["norm"], c.rms_norm_eps)
-    out = ModelOutput(logits=_head(x, params["lm_head"]))
+    out = ModelOutput(logits=untied_head(x, params["lm_head"]))
     if labels is not None:
         out["loss"] = fused_cross_entropy(
             x, params["lm_head"], shift_labels(labels),
-            dense_fn=lambda x_chunk, head: _head(x_chunk, head))
+            dense_fn=untied_head)
     return out
 
 
 def _paged_step(c, params, input_ids, cache, block_tables, cache_positions, write_mask,
                 logit_positions=None):
     """One step against the cache ``{"k"[, "k_scale"]}`` — the latent pool
-    ``[layers, num_blocks, block_size, pool_width]``, no ``"v"`` —: ``s``
-    tokens a row from ``cache_positions`` (every slot's one token, or a
-    prefill chunk). The rows' vectors are written first, then every query
-    attends what is written up to itself. A lane that ``write_mask`` switches
-    off leaves the pool as it was and routes to no expert. The cache comes
-    back whole, and beside the logits (of ``logit_positions`` alone where the
-    caller names them: :func:`~..ops.layers.logit_rows`) the step's
-    ``step_counters``."""
-    b, s = input_ids.shape
-    idx = jnp.asarray(cache_positions, jnp.int32).reshape(b)
-    positions = idx[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
-    valid = jnp.ones((b, s), bool) if write_mask is None else jnp.broadcast_to(
-        jnp.asarray(write_mask, bool), (b, s))
+    ``[layers, num_blocks, block_size, pool_width]``, no ``"v"`` — (the
+    contract: :func:`~..ops.layers.paged_step_frame`): every slot's one
+    token, or a prefill chunk. A lane that is off routes to no expert; beside
+    the logits come the step's ``step_counters``."""
+    idx, positions, valid = paged_step_frame(input_ids, cache_positions, write_mask)
     stacks = params["layers"]
     cache = dict(cache)
     pairs, elsewhere = [], []
-    x = _embed(params, input_ids)
+    x = embed_tokens(params, input_ids)
     with jax.named_scope("layers"):
         for i in range(c.num_hidden_layers):
             x, cache = _absorbed_attention(
-                c, _at(stacks["attn"], i), i, x, positions, idx, cache, block_tables, valid)
+                c, layer_at(stacks["attn"], i), i, x, positions, idx, cache, block_tables, valid)
             x, layer_pairs, layer_elsewhere = _feed_forward(c, stacks, i, x, valid)
             if layer_pairs is not None:
                 pairs.append(layer_pairs)
                 elsewhere.append(layer_elsewhere)
     with jax.named_scope("head"):
         x = rms_norm(logit_rows(x, logit_positions), params["norm"], c.rms_norm_eps)
-    out = ModelOutput(logits=_head(x, params["lm_head"]), paged_kv=cache)
+    out = ModelOutput(logits=untied_head(x, params["lm_head"]), paged_kv=cache)
     if pairs:
         out["step_counters"] = {
-            **_step_counters(pairs),
+            **moe.step_counters(pairs),
             "moe_pairs_elsewhere_total": jnp.stack(elsewhere).sum().astype(jnp.int32),
         }
     return out

@@ -20,9 +20,15 @@ from jax.sharding import PartitionSpec as P
 from ..modules import Model, ModelOutput
 from ..ops.attention import attention
 from ..ops.fp8 import dense
-from ..ops.layers import cached_attention, cross_entropy_loss, write_kv_cache
+from ..ops.layers import (
+    cached_attention,
+    cross_entropy_loss,
+    layer_norm,
+    mesh_constrain as _constrain,
+    residual_spec,
+    write_kv_cache,
+)
 from ..parallel.pipeline import remat_wrap
-from .llama import _constrain, residual_spec
 
 
 @dataclass
@@ -69,15 +75,6 @@ GPT2_PARTITION_RULES = [
     (r"layers\.(b_proj|b_out)", P()),
     (r"ln_f_(g|b)", P()),
 ]
-
-
-def layer_norm(x, g, b, eps):
-    """True LayerNorm (GPT-2 centers the mean, unlike llama's RMSNorm)."""
-    x32 = x.astype(jnp.float32)
-    mu = jnp.mean(x32, axis=-1, keepdims=True)
-    var = jnp.mean((x32 - mu) ** 2, axis=-1, keepdims=True)
-    out = (x32 - mu) * jax.lax.rsqrt(var + eps) * g.astype(jnp.float32) + b.astype(jnp.float32)
-    return out.astype(x.dtype)
 
 
 def init_gpt2_params(key: jax.Array, config: GPT2Config, dtype=jnp.float32):
@@ -378,17 +375,8 @@ class GPT2LMHeadModel:
         model.stacked_params_prefix = "layers"
         model.segments = gpt2_segments(config)
         model.tied_parameters = []
-        model.convert_state_dict = lambda flat: _flatten(
-            convert_hf_gpt2_state_dict(flat, config)
-        )
+        model.convert_state_dict = lambda flat: {
+            jax.tree_util.keystr(path, simple=True, separator="."): leaf
+            for path, leaf in jax.tree_util.tree_flatten_with_path(
+                convert_hf_gpt2_state_dict(flat, config))[0]}
         return model
-
-
-def _flatten(tree) -> dict:
-    flat = {}
-    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
-        key = ".".join(
-            str(getattr(p, "key", getattr(p, "idx", p))) for p in path
-        )
-        flat[key] = leaf
-    return flat

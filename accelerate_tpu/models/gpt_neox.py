@@ -36,12 +36,13 @@ from ..ops.layers import (
     apply_rope,
     cached_attention,
     cross_entropy_loss,
+    layer_norm,
+    mesh_constrain as _constrain,
+    residual_spec,
     rope_frequencies,
     write_kv_cache,
 )
 from ..parallel.pipeline import remat_wrap
-from .gpt2 import layer_norm
-from .llama import _constrain, residual_spec
 
 
 @dataclass
@@ -563,7 +564,6 @@ class GPTNeoXForCausalLM:
         import dataclasses as _dc
 
         from ..big_modeling import is_empty_init
-        from .gpt2 import _flatten
 
         # private copy: apply_fn closes over it (see GPT2LMHeadModel)
         config = _dc.replace(config)
@@ -593,5 +593,7 @@ class GPTNeoXForCausalLM:
         model.stacked_params_prefix = "layers"
         model.segments = gpt_neox_segments(config)
         model.tied_parameters = []
-        model.convert_state_dict = lambda flat: _flatten(convert(flat, config))
+        model.convert_state_dict = lambda flat: {
+            jax.tree_util.keystr(path, simple=True, separator="."): leaf
+            for path, leaf in jax.tree_util.tree_flatten_with_path(convert(flat, config))[0]}
         return model

@@ -40,14 +40,15 @@ from ..ops.fp8 import dense
 from ..ops.layers import (
     fused_cross_entropy,
     logit_rows,
+    paged_step_frame,
+    paged_write_attend,
     rms_norm,
     shift_labels,
-    write_paged_kv,
+    slot_state_frame,
 )
-from ..ops.paged_attention import paged_attention
 from ..ops.ssm import conv_with_tail, live_slots, ssd_chunk_scan, ssm_state_update
 from ..parallel.pipeline import remat_wrap
-from .cache import CacheSpec, SlotStateLeaf
+from .cache import CacheSpec, SlotStateLeaf, pool_leaf_names
 
 _PERIOD = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
 
@@ -403,33 +404,22 @@ def granite_hybrid_apply(
 def _paged_step(c, params, input_ids, cache, block_tables, cache_positions,
                 write_mask, state_slots, logit_positions=None):
     """One step against the cache ``{"k", "v"[, "k_scale", "v_scale"],
-    "ssm", "conv"}``: ``s == 1`` token for every slot (``state_slots``
-    ``None``: row ``i`` is slot ``i``, and the recurrence is the
-    :func:`~..ops.ssm.ssm_state_update` kernel on the stacked state), or a
-    prefill chunk of ``s`` tokens for the slots ``state_slots [b]`` (the
-    chunked scan from the slot's own state, left with the outgoing state
-    and the last valid inputs of the convolution). A lane that
-    ``write_mask`` switches off leaves K/V, state and tail as they were.
-    The cache travels in the layer loop's carry and comes back whole; the
-    logits are those of ``logit_positions`` alone where the caller names
-    them (:func:`~..ops.layers.logit_rows`)."""
+    "ssm", "conv"}`` (the contract: :func:`~..ops.layers.paged_step_frame`):
+    ``s == 1`` token for every slot (``state_slots`` ``None``: row ``i`` is
+    slot ``i``, and the recurrence is the :func:`~..ops.ssm.ssm_state_update`
+    kernel on the stacked state), or a prefill chunk of ``s`` tokens for the
+    slots ``state_slots [b]`` (the chunked scan from the slot's own state,
+    left with the outgoing state and the last valid inputs of the
+    convolution). A lane that is off leaves state and tail too as they were.
+    The cache travels in the layer loop's carry."""
     b, s = input_ids.shape
-    idx = jnp.asarray(cache_positions, jnp.int32).reshape(b)
-    positions = idx[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
-    valid = jnp.ones((b, s), bool) if write_mask is None else jnp.broadcast_to(
-        jnp.asarray(write_mask, bool), (b, s))
-    n_valid = valid.sum(axis=1).astype(jnp.int32)
-    decode = state_slots is None
-    if decode and (s != 1 or cache["ssm"].shape[1] != b):
-        raise ValueError(
-            f"a step without state_slots is the decode step of every slot: got "
-            f"[{b}, {s}] tokens for {cache['ssm'].shape[1]} slots"
-        )
-    slots = None if decode else jnp.asarray(state_slots, jnp.int32).reshape(b)
+    idx, positions, valid = paged_step_frame(input_ids, cache_positions, write_mask)
+    n_valid, slots = slot_state_frame(valid, state_slots, cache["ssm"].shape[1])
+    decode = slots is None
     # what the state kernel walks: one mask a step, so one list for every layer
     live = live_slots(valid[:, 0]) if decode else None
     nh, nkv, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
-    quantized = "k_scale" in cache
+    names = pool_leaf_names(cache)
     x = _embed(c, params, input_ids)
 
     def mamba_body(carry, layer, i):
@@ -470,19 +460,12 @@ def _paged_step(c, params, input_ids, cache, block_tables, cache_positions,
             q = q.reshape(b, s, nh, hd)
             k = dense(y, layer["wk"]).reshape(b, s, nkv, hd)
             v = dense(y, layer["wv"]).reshape(b, s, nkv, hd)
-        scales = (cache["k_scale"], cache["v_scale"]) if quantized else (None, None)
-        with jax.named_scope("kv_write"):
-            pools = write_paged_kv(
-                cache["k"], cache["v"], i, k, v, block_tables, positions,
-                write_mask=valid, k_scale=scales[0], v_scale=scales[1],
-            )
-        with jax.named_scope("attn_kernel"):
-            attn = paged_attention(q, pools[0], pools[1], i, block_tables, idx, *pools[2:])
+        attn, held = paged_write_attend(
+            q, k, v, [cache[n] for n in names], i, block_tables, positions, idx, valid)
         with jax.named_scope("attn_proj"):
             out = dense(attn.reshape(b, s, nh * hd), layer["wo"])
             x = x + out * jnp.asarray(c.residual_multiplier, out.dtype)
-        names = ("k", "v", "k_scale", "v_scale")[: len(pools)]
-        return _shared_mlp(c, layer, x), {**cache, **dict(zip(names, pools))}
+        return _shared_mlp(c, layer, x), {**cache, **dict(zip(names, held))}
 
     with jax.named_scope("layers"):
         x, cache = _run_layers(c, params, (x, dict(cache)), mamba_body, attention_body)

@@ -53,20 +53,25 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..modules import Model, ModelOutput
+from ..ops import moe
 from ..ops.attention import attention
 from ..ops.fp8 import dense
 from ..ops.layers import (
+    attention_out,
+    embed_tokens,
     fused_cross_entropy,
+    layer_at,
     logit_rows,
+    paged_step_frame,
+    paged_write_attend,
+    qk_normed_rotary_qkv,
     rms_norm,
     shift_labels,
-    write_paged_kv,
+    slot_state_frame,
 )
-from ..ops.moe import expert_ffn, route
-from ..ops.paged_attention import paged_attention
 from ..ops.ssm import conv_with_tail
 from ..parallel.pipeline import remat_wrap
-from .cache import CacheSpec, SlotStateLeaf
+from .cache import CacheSpec, SlotStateLeaf, pool_leaf_names
 
 _PUBLISHED_ATTENTION = (2, 6, 10, 14, 18, 21)
 
@@ -188,15 +193,7 @@ def cache_spec(config: Lfm2MoeConfig) -> CacheSpec:
 
 
 def step_counter_shapes(config: Lfm2MoeConfig) -> dict:
-    """What a step against the cache hands back beside its logits, name ->
-    shape (int32): the engine sums each over the steps it dispatched."""
-    return {
-        "moe_expert_pairs": (config.n_moe, config.num_experts),
-        "moe_dispatches_total": (),
-        "moe_pairs_routed_total": (),
-        "moe_experts_touched_total": (),
-        "moe_load_max_total": (),
-    }
+    return moe.step_counter_shapes(config.n_moe, config.num_experts)
 
 
 def init_lfm2_params(key, config: Lfm2MoeConfig, dtype=jnp.float32):
@@ -254,11 +251,6 @@ def init_lfm2_params(key, config: Lfm2MoeConfig, dtype=jnp.float32):
 # -- the parts, each under the scope the trace files it by ---------------------
 
 
-@jax.named_scope("embed")
-def _embed(params, input_ids):
-    return params["embed_tokens"][input_ids]
-
-
 @jax.named_scope("head")
 def _tied_head(x, embed):
     return jnp.einsum("...h,vh->...v", x, embed)
@@ -270,11 +262,6 @@ def _final_norm_and_head(c, params, x):
     return x, _tied_head(x, params["embed_tokens"])
 
 
-def _at(stack, i):
-    """Layer ``i`` (static) of every leaf of a stack."""
-    return {name: leaf[i] for name, leaf in stack.items()}
-
-
 @jax.named_scope("mlp")
 def _dense_ff(c, layer, x):
     y = rms_norm(x, layer["ffn_norm"], c.norm_eps)
@@ -283,20 +270,10 @@ def _dense_ff(c, layer, x):
 
 
 def _routed_ff(c, stack, i, x, live):
-    """The routed feed-forward of layer ``i`` of the ``moe`` stack over
-    ``x [b, s, h]``; ``live [b, s]`` (or ``None``) keeps padding and dead
-    lanes out of every expert. Returns ``(x, pairs [E] int32)``."""
-    b, s, h = x.shape
-    with jax.named_scope("moe_router"):
-        y = rms_norm(x, stack["ffn_norm"][i], c.norm_eps).reshape(b * s, h)
-        experts, weights = route(
-            y, stack["gate"][i], stack["expert_bias"][i], c.num_experts_per_tok,
-            c.norm_topk_prob, c.routed_scaling_factor)
-    with jax.named_scope("moe_experts"):
-        out, pairs = expert_ffn(
-            y, experts, weights, stack["w_in"], stack["w_out"],
-            live=None if live is None else live.reshape(b * s), layer=i)
-        return x + out.reshape(b, s, h), pairs
+    """Layer ``i`` of the ``moe`` stack: a sigmoid router whose
+    ``expert_bias`` moves the selection. ``(x, pairs [E] int32)``."""
+    return moe.routed_ffn(stack, i, x, live, c.norm_eps, c.num_experts_per_tok,
+                          c.norm_topk_prob, c.routed_scaling_factor)
 
 
 @jax.named_scope("conv_proj")
@@ -321,48 +298,10 @@ def _conv_out(layer, x, mixed):
     return x + dense(mixed, layer["out_proj"])
 
 
-def _rope(x, positions, theta: float):
-    """Rotate ``x [b, s, heads, hd]`` by ``positions [b, s]``: rotate-half
-    over the whole head, the angles in float32, the rotation in ``x``'s
-    dtype (as :func:`..ops.layers.apply_rope`, without a table of
-    ``max_position_embeddings`` rows)."""
-    hd = x.shape[-1]
-    inv_freq = 1.0 / (theta ** (np.arange(0, hd, 2, dtype=np.float64) / hd))
-    angles = positions[..., None].astype(jnp.float32) * jnp.asarray(inv_freq, jnp.float32)
-    cos = jnp.cos(angles)[:, :, None, :].astype(x.dtype)
-    sin = jnp.sin(angles)[:, :, None, :].astype(x.dtype)
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-
-
-@jax.named_scope("attn_proj")
 def _qkv(c, layer, x, positions):
-    """q, k (each head normed, then rotated) and v of the normed residual."""
-    b, s, _ = x.shape
-    nh, nkv, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
-    y = rms_norm(x, layer["operator_norm"], c.norm_eps)
-    q = rms_norm(dense(y, layer["wq"]).reshape(b, s, nh, hd), layer["q_norm"], c.norm_eps)
-    k = rms_norm(dense(y, layer["wk"]).reshape(b, s, nkv, hd), layer["k_norm"], c.norm_eps)
-    v = dense(y, layer["wv"]).reshape(b, s, nkv, hd)
-    return _rope(q, positions, c.rope_theta), _rope(k, positions, c.rope_theta), v
-
-
-@jax.named_scope("attn_proj")
-def _attn_out(layer, x, attn):
-    b, s = attn.shape[:2]
-    return x + dense(attn.reshape(b, s, -1), layer["wo"])
-
-
-def _step_counters(pairs) -> dict:
-    """``pairs [moe layers, E]`` of one step -> what the engine sums."""
-    pairs = jnp.stack(pairs).astype(jnp.int32)
-    return {
-        "moe_expert_pairs": pairs,
-        "moe_dispatches_total": jnp.ones((), jnp.int32),
-        "moe_pairs_routed_total": pairs.sum(),
-        "moe_experts_touched_total": (pairs > 0).sum(dtype=jnp.int32),
-        "moe_load_max_total": pairs.max(axis=1).sum(),
-    }
+    return qk_normed_rotary_qkv(
+        layer, x, layer["operator_norm"], positions, c.num_attention_heads,
+        c.num_key_value_heads, c.head_dim, c.norm_eps, c.rope_theta)
 
 
 def lfm2_apply(
@@ -400,18 +339,18 @@ def lfm2_apply(
         q, k, v = _qkv(c, layer, x, positions)
         with jax.named_scope("attn_kernel"):
             attn = attention(q, k, v, segment_mask=attention_mask, causal=True)
-        return _attn_out(layer, x, attn)
+        return attention_out(layer, x, attn)
 
     def one_layer(x, op_kind, op_index, ff_kind, ff_index):
         if op_kind == "conv":
-            x = conv_op(x, _at(stacks["conv"], op_index))
+            x = conv_op(x, layer_at(stacks["conv"], op_index))
         else:
-            x = attention_op(x, _at(stacks["attention"], op_index))
+            x = attention_op(x, layer_at(stacks["attention"], op_index))
         if ff_kind == "dense":
-            return _dense_ff(c, _at(stacks["dense"], ff_index), x)
+            return _dense_ff(c, layer_at(stacks["dense"], ff_index), x)
         return _routed_ff(c, stacks["moe"], ff_index, x, valid)[0]
 
-    x = _embed(params, input_ids)
+    x = embed_tokens(params, input_ids)
     with jax.named_scope("layers"):
         for op_kind, op_index, ff_kind, ff_index in c.layer_plan():
             layer = functools.partial(one_layer, op_kind=op_kind, op_index=op_index,
@@ -429,37 +368,25 @@ def lfm2_apply(
 def _paged_step(c, params, input_ids, cache, block_tables, cache_positions,
                 write_mask, state_slots, logit_positions=None):
     """One step against the cache ``{"k", "v"[, "k_scale", "v_scale"],
-    "conv"}``: ``s == 1`` token for every slot (``state_slots`` ``None``:
-    row ``i`` is slot ``i``), or a prefill chunk of ``s`` tokens for the
-    slots ``state_slots [b]``, each continuing from its own tail. A lane
-    that ``write_mask`` switches off leaves K/V and tail as they were and
-    routes to no expert. The cache comes back whole, and beside the logits
-    (of ``logit_positions`` alone where the caller names them:
-    :func:`~..ops.layers.logit_rows`) the step's ``step_counters``
-    (:func:`step_counter_shapes`)."""
-    b, s = input_ids.shape
-    idx = jnp.asarray(cache_positions, jnp.int32).reshape(b)
-    positions = idx[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
-    valid = jnp.ones((b, s), bool) if write_mask is None else jnp.broadcast_to(
-        jnp.asarray(write_mask, bool), (b, s))
-    n_valid = valid.sum(axis=1).astype(jnp.int32)
-    decode = state_slots is None
-    if decode and (s != 1 or cache["conv"].shape[1] != b):
-        raise ValueError(
-            f"a step without state_slots is the decode step of every slot: got "
-            f"[{b}, {s}] tokens for {cache['conv'].shape[1]} slots"
-        )
-    slots = None if decode else jnp.asarray(state_slots, jnp.int32).reshape(b)
-    quantized = "k_scale" in cache
+    "conv"}`` (the contract: :func:`~..ops.layers.paged_step_frame`): ``s ==
+    1`` token for every slot (``state_slots`` ``None``: row ``i`` is slot
+    ``i``), or a prefill chunk of ``s`` tokens for the slots ``state_slots
+    [b]``, each continuing from its own tail. A lane that is off leaves the
+    tail too as it was and routes to no expert; beside the logits come the
+    step's ``step_counters`` (:func:`step_counter_shapes`)."""
+    idx, positions, valid = paged_step_frame(input_ids, cache_positions, write_mask)
+    n_valid, slots = slot_state_frame(valid, state_slots, cache["conv"].shape[1])
+    decode = slots is None
+    names = pool_leaf_names(cache)
     stacks = params["layers"]
     cache = dict(cache)
     pairs = []
-    x = _embed(params, input_ids)
+    x = embed_tokens(params, input_ids)
     with jax.named_scope("layers"):
         for op_kind, op_index, ff_kind, ff_index in c.layer_plan():
             i = op_index
             if op_kind == "conv":
-                layer = _at(stacks["conv"], i)
+                layer = layer_at(stacks["conv"], i)
                 g, c_gate = _conv_in(c, layer, x)
                 conv = cache["conv"]
                 tail = conv[i] if decode else conv[i, slots]
@@ -468,27 +395,21 @@ def _paged_step(c, params, input_ids, cache, block_tables, cache_positions,
                     cache["conv"] = conv.at[i].set(tail) if decode else conv.at[i, slots].set(tail)
                 x = _conv_out(layer, x, mixed)
             else:
-                layer = _at(stacks["attention"], i)
+                layer = layer_at(stacks["attention"], i)
                 q, k, v = _qkv(c, layer, x, positions)
-                scales = (cache["k_scale"], cache["v_scale"]) if quantized else (None, None)
-                with jax.named_scope("kv_write"):
-                    pools = write_paged_kv(
-                        cache["k"], cache["v"], i, k, v, block_tables, positions,
-                        write_mask=valid, k_scale=scales[0], v_scale=scales[1],
-                    )
-                with jax.named_scope("attn_kernel"):
-                    attn = paged_attention(q, pools[0], pools[1], i, block_tables, idx, *pools[2:])
-                cache.update(zip(("k", "v", "k_scale", "v_scale"), pools))
-                x = _attn_out(layer, x, attn)
+                attn, held = paged_write_attend(
+                    q, k, v, [cache[n] for n in names], i, block_tables, positions, idx, valid)
+                cache.update(zip(names, held))
+                x = attention_out(layer, x, attn)
             if ff_kind == "dense":
-                x = _dense_ff(c, _at(stacks["dense"], ff_index), x)
+                x = _dense_ff(c, layer_at(stacks["dense"], ff_index), x)
             else:
                 x, layer_pairs = _routed_ff(c, stacks["moe"], ff_index, x, valid)
                 pairs.append(layer_pairs)
     _, logits = _final_norm_and_head(c, params, logit_rows(x, logit_positions))
     out = ModelOutput(logits=logits, paged_kv=cache)
     if pairs:
-        out["step_counters"] = _step_counters(pairs)
+        out["step_counters"] = moe.step_counters(pairs)
     return out
 
 

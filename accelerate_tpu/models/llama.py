@@ -35,11 +35,15 @@ from ..ops.layers import (
     cross_entropy_loss,
     fused_cross_entropy,
     logit_rows,
+    mesh_constrain as _constrain,
+    residual_spec,
     rms_norm,
     rope_cached_attention_block,
     rope_frequencies,
+    rope_paged_attention_block,
     shift_labels,
 )
+from .cache import pool_leaf_names
 
 
 @dataclass
@@ -227,33 +231,6 @@ def _block(config: LlamaConfig, cos, sin, positions, attention_mask):
         return llama_layer_apply(config, layer, x, cos, sin, positions, attention_mask), None
 
     return remat_wrap(body, config.remat)
-
-
-def _constrain(x, spec):
-    """Sharding constraint that is a no-op outside a mesh context where the
-    axes don't exist (keeps the model runnable on a bare single device)."""
-    try:
-        return jax.lax.with_sharding_constraint(x, spec)
-    except Exception:
-        return x
-
-
-def residual_spec() -> P:
-    """Spec for norm/residual-region activations ``[b, s, h]``: batch over
-    dp/fsdp, sequence over cp — and ALSO over tp under Megatron-style
-    sequence parallelism (``MegatronLMPlugin(sequence_parallelism=True)``
-    with tp>1; reference forwards the flag to Megatron at
-    ``utils/dataclasses.py:1916-1919,2112``, where LayerNorm/dropout
-    activations shard along sequence within the TP group). Between the
-    matmul regions (which are head/ff-sharded on tp, full-sequence) GSPMD
-    inserts the all-gather in / reduce-scatter out that Megatron's fused
-    kernels code by hand, and per-device activation bytes in the norm
-    regions shrink by the tp extent."""
-    from ..ops.attention import get_attention_context
-
-    if get_attention_context().megatron_sp:
-        return P(("dp", "fsdp"), ("cp", "tp"), None)
-    return P(("dp", "fsdp"), "cp", None)
 
 
 def _pipeline_mesh():
@@ -465,19 +442,15 @@ def _llama_paged_step(
     layers ``0..N-1`` of the target's own pool by index. The layer loop is
     a plain scan — the serving engine is a single-host path (no pp stage
     pipeline)."""
-    from ..ops.layers import rope_paged_attention_block
-
-    b, s = input_ids.shape
-    idx = jnp.asarray(cache_positions, jnp.int32).reshape(b)
     x = _embed(params, input_ids)
-    names = ("k", "v", "k_scale", "v_scale") if "k_scale" in paged_kv else ("k", "v")
+    names = pool_leaf_names(paged_kv)
     n_layers = jax.tree.leaves(params["layers"])[0].shape[0]
 
     def body(carry, layer_and_index):
         x, pools = carry
         layer, layer_idx = layer_and_index
         x, *pools = rope_paged_attention_block(
-            layer, x, pools[0], pools[1], layer_idx, cos, sin, block_tables, idx,
+            layer, x, pools[0], pools[1], layer_idx, cos, sin, block_tables, cache_positions,
             c.num_attention_heads, c.num_key_value_heads, c.head_dim,
             c.rms_norm_eps, write_mask=paged_write_mask,
             **dict(zip(("k_scale", "v_scale"), pools[2:])),

@@ -32,9 +32,15 @@ from jax.sharding import PartitionSpec as P
 from ..modules import Model, ModelOutput
 from ..ops.attention import attention
 from ..ops.fp8 import dense
-from ..ops.layers import apply_rope, cross_entropy_loss, rms_norm, rope_frequencies
+from ..ops.layers import (
+    apply_rope,
+    cross_entropy_loss,
+    mesh_constrain as _constrain,
+    residual_spec,
+    rms_norm,
+    rope_frequencies,
+)
 from ..parallel.pipeline import remat_wrap
-from .llama import _constrain, residual_spec
 
 
 @dataclass

@@ -28,10 +28,15 @@ from jax.sharding import PartitionSpec as P
 from ..modules import Model, ModelOutput
 from ..ops.attention import attention
 from ..ops.fp8 import dense
-from ..ops.layers import cached_attention, cross_entropy_loss, write_kv_cache
+from ..ops.layers import (
+    cached_attention,
+    cross_entropy_loss,
+    layer_norm,
+    mesh_constrain as _constrain,
+    residual_spec,
+    write_kv_cache,
+)
 from ..parallel.pipeline import remat_wrap
-from .gpt2 import layer_norm
-from .llama import _constrain, residual_spec
 
 
 @dataclass
@@ -394,7 +399,6 @@ class OPTForCausalLM:
         import dataclasses as _dc
 
         from ..big_modeling import is_empty_init
-        from .gpt2 import _flatten
 
         # private copy: apply_fn closes over it (see GPT2LMHeadModel)
         config = _dc.replace(config)
@@ -419,7 +423,8 @@ class OPTForCausalLM:
         model.stacked_params_prefix = "layers"
         model.segments = opt_segments(config)
         model.tied_parameters = []
-        model.convert_state_dict = lambda flat: _flatten(
-            convert_hf_opt_state_dict(flat, config)
-        )
+        model.convert_state_dict = lambda flat: {
+            jax.tree_util.keystr(path, simple=True, separator="."): leaf
+            for path, leaf in jax.tree_util.tree_flatten_with_path(
+                convert_hf_opt_state_dict(flat, config))[0]}
         return model

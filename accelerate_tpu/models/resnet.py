@@ -25,8 +25,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..modules import Model, ModelOutput
-from ..ops.layers import cross_entropy_loss
-from .llama import _constrain
+from ..ops.layers import cross_entropy_loss, mesh_constrain as _constrain, to_nhwc
 
 
 @dataclass
@@ -153,18 +152,6 @@ def _bottleneck_d(config, block, x, stride):
         shortcut = _bn(shortcut, block["gp_gamma"], block["gp_beta"], c.bn_eps)
     out = jax.nn.relu(y + shortcut)
     return _constrain(out, P(("dp", "fsdp"), None, None, "tp"))
-
-
-def to_nhwc(pixel_values, in_channels: int):
-    """Normalise image input to NHWC: append a channel dim to grayscale
-    ``[b, h, w]`` and accept torch's NCHW layout (shared by every image
-    model in the zoo)."""
-    x = jnp.asarray(pixel_values)
-    if x.ndim == 3:
-        x = x[..., None]
-    if x.shape[-1] != in_channels and x.shape[1] == in_channels:
-        x = jnp.moveaxis(x, 1, -1)
-    return x
 
 
 def resnet_apply(config: ResNetConfig, params, pixel_values=None, labels=None, **kw):

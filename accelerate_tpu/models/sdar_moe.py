@@ -29,7 +29,7 @@ One stack ``layers.<leaf>`` over the layers; the layer loop is unrolled so
 that the expert product addresses ``(layer, expert)`` of the stacked
 matrices in place (:mod:`..ops.moe`). Every layer holds block-paged K/V and
 no layer keeps per-slot state. A step against the cache hands back the
-``step_counters`` of :mod:`.lfm2`'s schema.
+``step_counters`` of :func:`..ops.moe.step_counter_shapes`.
 
 The published training objective (masked denoising of noised blocks) is not
 built: ``labels`` give the next-token loss of the backbone the family was
@@ -49,21 +49,24 @@ from jax.sharding import PartitionSpec as P
 
 from ..modules import Model, ModelOutput
 from ..ops.attention import attention
-from ..ops.fp8 import dense
+from ..ops import moe
 from ..ops.layers import (
+    attention_out,
     dot_product_attention,
+    embed_tokens,
     fused_cross_entropy,
     last_visible,
+    layer_at,
     logit_rows,
+    paged_step_frame,
+    paged_write_attend,
+    qk_normed_rotary_qkv,
     rms_norm,
     shift_labels,
-    write_paged_kv,
+    untied_head,
 )
-from ..ops.moe import expert_ffn, route
-from ..ops.paged_attention import paged_attention
 from ..parallel.pipeline import remat_wrap
-from .cache import BlockDecode, CacheSpec
-from .lfm2 import _rope, _step_counters
+from .cache import BlockDecode, CacheSpec, pool_leaf_names
 
 
 @dataclass
@@ -145,14 +148,7 @@ def block_decode(config: SdarMoeConfig) -> BlockDecode | None:
 
 
 def step_counter_shapes(config: SdarMoeConfig) -> dict:
-    """As :func:`.lfm2.step_counter_shapes`: a forward counts one dispatch."""
-    return {
-        "moe_expert_pairs": (config.num_hidden_layers, config.num_experts),
-        "moe_dispatches_total": (),
-        "moe_pairs_routed_total": (),
-        "moe_experts_touched_total": (),
-        "moe_load_max_total": (),
-    }
+    return moe.step_counter_shapes(config.num_hidden_layers, config.num_experts)
 
 
 def init_sdar_params(key, config: SdarMoeConfig, dtype=jnp.float32):
@@ -193,54 +189,17 @@ def init_sdar_params(key, config: SdarMoeConfig, dtype=jnp.float32):
 # -- the parts, each under the scope the trace files it by ---------------------
 
 
-@jax.named_scope("embed")
-def _embed(params, input_ids):
-    return params["embed_tokens"][input_ids]
-
-
-@jax.named_scope("head")
-def _head(x, lm_head):
-    return dense(x, lm_head)
-
-
-def _at(stack, i):
-    """Layer ``i`` (static) of the small leaves of the stack; the experts'
-    matrices stay stacked and are addressed at ``(i, expert)``."""
-    return {name: leaf[i] for name, leaf in stack.items() if name not in ("w_in", "w_out")}
-
-
-@jax.named_scope("attn_proj")
 def _qkv(c, layer, x, positions):
-    """q, k (each head normed, then rotated) and v of the normed residual."""
-    b, s, _ = x.shape
-    nh, nkv, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
-    y = rms_norm(x, layer["attn_norm"], c.rms_norm_eps)
-    q = rms_norm(dense(y, layer["wq"]).reshape(b, s, nh, hd), layer["q_norm"], c.rms_norm_eps)
-    k = rms_norm(dense(y, layer["wk"]).reshape(b, s, nkv, hd), layer["k_norm"], c.rms_norm_eps)
-    v = dense(y, layer["wv"]).reshape(b, s, nkv, hd)
-    return _rope(q, positions, c.rope_theta), _rope(k, positions, c.rope_theta), v
-
-
-@jax.named_scope("attn_proj")
-def _attn_out(layer, x, attn):
-    b, s = attn.shape[:2]
-    return x + dense(attn.reshape(b, s, -1), layer["wo"])
+    return qk_normed_rotary_qkv(
+        layer, x, layer["attn_norm"], positions, c.num_attention_heads,
+        c.num_key_value_heads, c.head_dim, c.rms_norm_eps, c.rope_theta)
 
 
 def _routed_ff(c, stack, i, x, live):
-    """The routed feed-forward of layer ``i`` over ``x [b, s, h]``; ``live
-    [b, s]`` (or ``None``) keeps padding and dead lanes out of every
-    expert. Returns ``(x, pairs [E] int32)``."""
-    b, s, h = x.shape
-    with jax.named_scope("moe_router"):
-        y = rms_norm(x, stack["ffn_norm"][i], c.rms_norm_eps).reshape(b * s, h)
-        experts, weights = route(y, stack["gate"][i], None, c.num_experts_per_tok,
-                                 c.norm_topk_prob, scoring="softmax")
-    with jax.named_scope("moe_experts"):
-        out, pairs = expert_ffn(
-            y, experts, weights, stack["w_in"], stack["w_out"],
-            live=None if live is None else live.reshape(b * s), layer=i)
-        return x + out.reshape(b, s, h), pairs
+    """Layer ``i``'s experts: a softmax router over all of them, no bias.
+    ``(x, pairs [E] int32)``."""
+    return moe.routed_ffn(stack, i, x, live, c.rms_norm_eps, c.num_experts_per_tok,
+                          c.norm_topk_prob, scoring="softmax")
 
 
 def _block_causal_attention(c, q, k, v, attention_mask):
@@ -280,69 +239,56 @@ def sdar_apply(
     stack = params["layers"]
 
     def one_layer(x, i):
-        layer = _at(stack, i)
+        layer = layer_at(stack, i, but=("w_in", "w_out"))
         q, k, v = _qkv(c, layer, x, positions)
         with jax.named_scope("attn_kernel"):
             attn = _block_causal_attention(c, q, k, v, attention_mask)
-        x = _attn_out(layer, x, attn)
+        x = attention_out(layer, x, attn)
         return _routed_ff(c, stack, i, x, valid)[0]
 
-    x = _embed(params, input_ids)
+    x = embed_tokens(params, input_ids)
     with jax.named_scope("layers"):
         for i in range(c.num_hidden_layers):
             x = remat_wrap(functools.partial(one_layer, i=i), c.remat)(x)
     with jax.named_scope("head"):
         x = rms_norm(x, params["norm"], c.rms_norm_eps)
-    out = ModelOutput(logits=_head(x, params["lm_head"]))
+    out = ModelOutput(logits=untied_head(x, params["lm_head"]))
     if labels is not None:
         out["loss"] = fused_cross_entropy(
             x, params["lm_head"], shift_labels(labels),
-            dense_fn=lambda x_chunk, head: _head(x_chunk, head))
+            dense_fn=untied_head)
     return out
 
 
 def _paged_step(c, params, input_ids, cache, block_tables, cache_positions, write_mask,
                 logit_positions=None):
-    """One step against the cache ``{"k", "v"[, "k_scale", "v_scale"]}``:
-    ``s`` tokens a row starting at ``cache_positions`` (a prefill chunk of
-    one prompt, or the ``block_length`` positions of every slot's open
-    block; ``s == 1`` at ``block_length`` 1). The rows' keys and values are
-    written first, then every query attends what is written before the end
-    of its own block. A lane that ``write_mask`` switches off leaves K/V as
-    they were and routes to no expert. The cache comes back whole, and
-    beside the logits (of ``logit_positions`` alone where the caller names
-    them: :func:`~..ops.layers.logit_rows`) the step's ``step_counters``."""
-    b, s = input_ids.shape
-    idx = jnp.asarray(cache_positions, jnp.int32).reshape(b)
-    positions = idx[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
-    valid = jnp.ones((b, s), bool) if write_mask is None else jnp.broadcast_to(
-        jnp.asarray(write_mask, bool), (b, s))
-    quantized = "k_scale" in cache
+    """One step against the cache ``{"k", "v"[, "k_scale", "v_scale"]}`` (the
+    contract: :func:`~..ops.layers.paged_step_frame`): ``s`` tokens a row (a
+    prefill chunk of one prompt, or the ``block_length`` positions of every
+    slot's open block; ``s == 1`` at ``block_length`` 1), every query
+    attending what is written before the end of its own block. A lane that is
+    off routes to no expert; beside the logits come the ``step_counters``."""
+    idx, positions, valid = paged_step_frame(input_ids, cache_positions, write_mask)
+    names = pool_leaf_names(cache)
     stack = params["layers"]
     cache = dict(cache)
     pairs = []
-    x = _embed(params, input_ids)
+    x = embed_tokens(params, input_ids)
     with jax.named_scope("layers"):
         for i in range(c.num_hidden_layers):
-            layer = _at(stack, i)
+            layer = layer_at(stack, i, but=("w_in", "w_out"))
             q, k, v = _qkv(c, layer, x, positions)
-            scales = (cache["k_scale"], cache["v_scale"]) if quantized else (None, None)
-            with jax.named_scope("kv_write"):
-                pools = write_paged_kv(
-                    cache["k"], cache["v"], i, k, v, block_tables, positions,
-                    write_mask=valid, k_scale=scales[0], v_scale=scales[1],
-                )
-            with jax.named_scope("attn_kernel"):
-                attn = paged_attention(q, pools[0], pools[1], i, block_tables, idx, *pools[2:],
-                                       block_len=c.block_length)
-            cache.update(zip(("k", "v", "k_scale", "v_scale"), pools))
-            x = _attn_out(layer, x, attn)
+            attn, held = paged_write_attend(
+                q, k, v, [cache[n] for n in names], i, block_tables, positions, idx, valid,
+                block_len=c.block_length)
+            cache.update(zip(names, held))
+            x = attention_out(layer, x, attn)
             x, layer_pairs = _routed_ff(c, stack, i, x, valid)
             pairs.append(layer_pairs)
     with jax.named_scope("head"):
         x = rms_norm(logit_rows(x, logit_positions), params["norm"], c.rms_norm_eps)
-    return ModelOutput(logits=_head(x, params["lm_head"]), paged_kv=cache,
-                       step_counters=_step_counters(pairs))
+    return ModelOutput(logits=untied_head(x, params["lm_head"]), paged_kv=cache,
+                       step_counters=moe.step_counters(pairs))
 
 
 class SdarMoeForCausalLM:

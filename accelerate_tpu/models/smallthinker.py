@@ -37,7 +37,7 @@ dispatch's first query and the dispatch's own (``"k_window"`` /
 ``"v_window"``): the engine gives a window block back once it lies wholly
 behind the window. ``block_tables`` come in as ``[b, 2, max_blocks]``, the
 kinds in the spec's order. A step against the cache hands back the
-``step_counters`` of :mod:`.lfm2`'s schema.
+``step_counters`` of :func:`..ops.moe.step_counter_shapes`.
 """
 
 from __future__ import annotations
@@ -53,19 +53,23 @@ from jax.sharding import PartitionSpec as P
 
 from ..modules import Model, ModelOutput
 from ..ops.attention import attention
+from ..ops import moe
 from ..ops.fp8 import dense
 from ..ops.layers import (
+    attention_out,
+    embed_tokens,
     fused_cross_entropy,
+    layer_at,
     logit_rows,
+    paged_step_frame,
+    paged_write_attend,
     rms_norm,
+    rotate_rope,
     shift_labels,
-    write_paged_kv,
+    untied_head,
 )
-from ..ops.moe import expert_ffn, route
-from ..ops.paged_attention import paged_attention
 from ..parallel.pipeline import remat_wrap
-from .cache import CacheSpec, PagedKind
-from .lfm2 import _rope, _step_counters
+from .cache import CacheSpec, PagedKind, pool_leaf_names
 
 _HI = jax.lax.Precision.HIGHEST
 
@@ -186,14 +190,7 @@ def cache_spec(config: SmallThinkerConfig) -> CacheSpec:
 
 
 def step_counter_shapes(config: SmallThinkerConfig) -> dict:
-    """As :func:`.lfm2.step_counter_shapes`: a forward counts one dispatch."""
-    return {
-        "moe_expert_pairs": (config.num_hidden_layers, config.moe_num_primary_experts),
-        "moe_dispatches_total": (),
-        "moe_pairs_routed_total": (),
-        "moe_experts_touched_total": (),
-        "moe_load_max_total": (),
-    }
+    return moe.step_counter_shapes(config.num_hidden_layers, config.moe_num_primary_experts)
 
 
 def init_smallthinker_params(key, config: SmallThinkerConfig, dtype=jnp.float32):
@@ -236,22 +233,6 @@ def init_smallthinker_params(key, config: SmallThinkerConfig, dtype=jnp.float32)
 # -- the parts, each under the scope the trace files it by ---------------------
 
 
-@jax.named_scope("embed")
-def _embed(params, input_ids):
-    return params["embed_tokens"][input_ids]
-
-
-@jax.named_scope("head")
-def _head(x, lm_head):
-    return dense(x, lm_head)
-
-
-def _at(stack, i):
-    """Layer ``i`` (static) of the small leaves of a stack; the experts'
-    matrices stay stacked and are addressed at ``(i, expert)``."""
-    return {name: leaf[i] for name, leaf in stack.items() if name not in ("w_in", "w_out")}
-
-
 @jax.named_scope("moe_router")
 def _route(c, layer, x):
     """The choice of a layer's experts from its INPUT ``x [b, s, h]`` (the
@@ -262,7 +243,7 @@ def _route(c, layer, x):
                      layer["gate"].astype(jnp.float32), precision=_HI)
     # softmax over all the experts, the top k of it, renormalised: equal to
     # the softmax over the k chosen logits (the sum is never 0: no guard)
-    return route(None, None, None, c.moe_num_active_primary_experts, True,
+    return moe.route(None, None, None, c.moe_num_active_primary_experts, True,
                  scoring="softmax", norm_eps=0.0, logits=logits)
 
 
@@ -277,14 +258,8 @@ def _qkv(c, layer, x, positions, rotated: bool):
     k = dense(y, layer["wk"]).reshape(b, s, nkv, hd)
     v = dense(y, layer["wv"]).reshape(b, s, nkv, hd)
     if rotated:
-        q, k = _rope(q, positions, c.rope_theta), _rope(k, positions, c.rope_theta)
+        q, k = rotate_rope(q, positions, c.rope_theta), rotate_rope(k, positions, c.rope_theta)
     return q, k, v
-
-
-@jax.named_scope("attn_proj")
-def _attn_out(layer, x, attn):
-    b, s = attn.shape[:2]
-    return x + dense(attn.reshape(b, s, -1), layer["wo"])
 
 
 @jax.named_scope("moe_experts")
@@ -295,7 +270,7 @@ def _experts(c, stack, i, x, experts, weights, live):
     ``(x + y, pairs [E] int32)``."""
     b, s, h = x.shape
     y = rms_norm(x, stack["ffn_norm"][i], c.rms_norm_eps).reshape(b * s, h)
-    out, pairs = expert_ffn(
+    out, pairs = moe.expert_ffn(
         y, experts, weights, stack["w_in"], stack["w_out"],
         live=None if live is None else live.reshape(b * s), layer=i, activation="relu")
     return x + out.reshape(b, s, h), pairs
@@ -326,80 +301,65 @@ def smallthinker_apply(
 
     def one_layer(x, kind, i, rotated):
         stack = params["layers"][kind]
-        layer = _at(stack, i)
+        layer = layer_at(stack, i, but=("w_in", "w_out"))
         experts, weights = _route(c, layer, x)
         q, k, v = _qkv(c, layer, x, positions, rotated)
         with jax.named_scope("attn_kernel_" + kind):
             attn = attention(q, k, v, segment_mask=attention_mask, causal=True,
                              window=c.sliding_window_size if kind == "window" else 0)
-        x = _attn_out(layer, x, attn)
+        x = attention_out(layer, x, attn)
         return _experts(c, stack, i, x, experts, weights, valid)[0]
 
-    x = _embed(params, input_ids)
+    x = embed_tokens(params, input_ids)
     with jax.named_scope("layers"):
         for kind, i, rotated in layer_plan(c):
             x = remat_wrap(
                 functools.partial(one_layer, kind=kind, i=i, rotated=rotated), c.remat)(x)
     with jax.named_scope("head"):
         x = rms_norm(x, params["norm"], c.rms_norm_eps)
-    out = ModelOutput(logits=_head(x, params["lm_head"]))
+    out = ModelOutput(logits=untied_head(x, params["lm_head"]))
     if labels is not None:
         out["loss"] = fused_cross_entropy(
             x, params["lm_head"], shift_labels(labels),
-            dense_fn=lambda x_chunk, head: _head(x_chunk, head))
+            dense_fn=untied_head)
     return out
 
 
 def _paged_step(c, params, input_ids, cache, block_tables, cache_positions, write_mask,
                 logit_positions=None):
     """One step against the cache ``{"k", "v", "k_window", "v_window"[, and
-    a ``_scale`` beside each]}``: ``s`` tokens a row starting at
-    ``cache_positions`` (a prefill chunk of one prompt, or one token of every
-    slot), ``block_tables [b, 2, max_blocks]`` the full kind's table and the
-    window kind's. A layer writes the rows' keys and values into its kind's
-    pool, then every query attends what its kind lets it see. A lane that
-    ``write_mask`` switches off leaves K/V as they were and routes to no
-    expert. The cache comes back whole, and beside the logits (of
-    ``logit_positions`` alone where the caller names them:
-    :func:`~..ops.layers.logit_rows`) the step's ``step_counters``."""
-    b, s = input_ids.shape
-    idx = jnp.asarray(cache_positions, jnp.int32).reshape(b)
-    positions = idx[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
-    valid = jnp.ones((b, s), bool) if write_mask is None else jnp.broadcast_to(
-        jnp.asarray(write_mask, bool), (b, s))
+    a ``_scale`` beside each]}`` (the contract:
+    :func:`~..ops.layers.paged_step_frame`): a prefill chunk of one prompt, or
+    one token of every slot; ``block_tables [b, 2, max_blocks]`` the full
+    kind's table and the window kind's. A layer writes into its kind's pool
+    and attends what its kind lets it see. A lane that is off routes to no
+    expert; beside the logits come the step's ``step_counters``."""
+    idx, positions, valid = paged_step_frame(input_ids, cache_positions, write_mask)
     tables = jnp.asarray(block_tables, jnp.int32)
     cache = dict(cache)
-    quantized = "k_scale" in cache
-    # a kind's pool leaves by their names in the cache dict
-    leaves = ("k", "v", "k_scale", "v_scale")[: 4 if quantized else 2]
-    names = {kind.name: tuple(kind.pool_leaf(leaf, n == 0) for leaf in leaves)
+    names = {kind.name: pool_leaf_names(cache, kind, n == 0)
              for n, kind in enumerate(cache_spec(c).paged_kinds)}
     pairs = []
-    x = _embed(params, input_ids)
+    x = embed_tokens(params, input_ids)
     with jax.named_scope("layers"):
         for kind, i, rotated in layer_plan(c):
             stack = params["layers"][kind]
-            layer = _at(stack, i)
+            layer = layer_at(stack, i, but=("w_in", "w_out"))
             table = tables[:, KINDS.index(kind)]
             experts, weights = _route(c, layer, x)
             q, k, v = _qkv(c, layer, x, positions, rotated)
-            held = [cache[name] for name in names[kind]]
-            with jax.named_scope("kv_write"):
-                pools = write_paged_kv(
-                    held[0], held[1], i, k, v, table, positions, write_mask=valid,
-                    **(dict(k_scale=held[2], v_scale=held[3]) if quantized else {}))
-            with jax.named_scope("attn_kernel_" + kind):
-                attn = paged_attention(
-                    q, pools[0], pools[1], i, table, idx, *pools[2:],
-                    window=c.sliding_window_size if kind == "window" else 0)
-            cache.update(zip(names[kind], pools))
-            x = _attn_out(layer, x, attn)
+            attn, held = paged_write_attend(
+                q, k, v, [cache[n] for n in names[kind]], i, table, positions, idx, valid,
+                scope="attn_kernel_" + kind,
+                window=c.sliding_window_size if kind == "window" else 0)
+            cache.update(zip(names[kind], held))
+            x = attention_out(layer, x, attn)
             x, layer_pairs = _experts(c, stack, i, x, experts, weights, valid)
             pairs.append(layer_pairs)
     with jax.named_scope("head"):
         x = rms_norm(logit_rows(x, logit_positions), params["norm"], c.rms_norm_eps)
-    return ModelOutput(logits=_head(x, params["lm_head"]), paged_kv=cache,
-                       step_counters=_step_counters(pairs))
+    return ModelOutput(logits=untied_head(x, params["lm_head"]), paged_kv=cache,
+                       step_counters=moe.step_counters(pairs))
 
 
 class SmallThinkerForCausalLM:

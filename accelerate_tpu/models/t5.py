@@ -34,9 +34,8 @@ from jax.sharding import PartitionSpec as P
 
 from ..modules import Model, ModelOutput
 from ..ops.fp8 import dense
-from ..ops.layers import cross_entropy_loss, rms_norm
+from ..ops.layers import cross_entropy_loss, mesh_constrain as _constrain, rms_norm
 from ..parallel.pipeline import remat_wrap
-from .llama import _constrain
 
 
 @dataclass

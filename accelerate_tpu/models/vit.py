@@ -28,10 +28,7 @@ from jax.sharding import PartitionSpec as P
 from ..modules import Model, ModelOutput
 from ..ops.attention import attention
 from ..ops.fp8 import dense
-from ..ops.layers import cross_entropy_loss
-from .gpt2 import layer_norm
-from .llama import _constrain
-from .resnet import to_nhwc
+from ..ops.layers import cross_entropy_loss, layer_norm, mesh_constrain as _constrain, to_nhwc
 
 
 @dataclass

@@ -14,6 +14,9 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
+
+from .fp8 import dense, quantize_kv_rows
 
 
 def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-6) -> jax.Array:
@@ -23,6 +26,54 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-6) -> jax.Array:
     var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
     normed = x32 * jax.lax.rsqrt(var + eps)
     return (normed * weight.astype(jnp.float32)).astype(dtype)
+
+
+def layer_norm(x, g, b, eps):
+    """True LayerNorm (GPT-2 centers the mean, unlike llama's RMSNorm)."""
+    x32 = x.astype(jnp.float32)
+    mu = jnp.mean(x32, axis=-1, keepdims=True)
+    var = jnp.mean((x32 - mu) ** 2, axis=-1, keepdims=True)
+    out = (x32 - mu) * jax.lax.rsqrt(var + eps) * g.astype(jnp.float32) + b.astype(jnp.float32)
+    return out.astype(x.dtype)
+
+
+def mesh_constrain(x, spec):
+    """Sharding constraint that is a no-op outside a mesh context where the
+    axes don't exist (keeps the model runnable on a bare single device)."""
+    try:
+        return jax.lax.with_sharding_constraint(x, spec)
+    except Exception:
+        return x
+
+
+def residual_spec() -> P:
+    """Spec for norm/residual-region activations ``[b, s, h]``: batch over
+    dp/fsdp, sequence over cp — and ALSO over tp under Megatron-style
+    sequence parallelism (``MegatronLMPlugin(sequence_parallelism=True)``
+    with tp>1; reference forwards the flag to Megatron at
+    ``utils/dataclasses.py:1916-1919,2112``, where LayerNorm/dropout
+    activations shard along sequence within the TP group). Between the
+    matmul regions (which are head/ff-sharded on tp, full-sequence) GSPMD
+    inserts the all-gather in / reduce-scatter out that Megatron's fused
+    kernels code by hand, and per-device activation bytes in the norm
+    regions shrink by the tp extent."""
+    from .attention import get_attention_context
+
+    if get_attention_context().megatron_sp:
+        return P(("dp", "fsdp"), ("cp", "tp"), None)
+    return P(("dp", "fsdp"), "cp", None)
+
+
+def to_nhwc(pixel_values, in_channels: int):
+    """Normalise image input to NHWC: append a channel dim to grayscale
+    ``[b, h, w]`` and accept torch's NCHW layout (shared by every image
+    model in the zoo)."""
+    x = jnp.asarray(pixel_values)
+    if x.ndim == 3:
+        x = x[..., None]
+    if x.shape[-1] != in_channels and x.shape[1] == in_channels:
+        x = jnp.moveaxis(x, 1, -1)
+    return x
 
 
 @jax.named_scope("head")
@@ -61,6 +112,20 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array, positions: jax.Arra
     dtype = x.dtype
     cos = cos[positions][:, :, None, :].astype(dtype)  # [b, s, 1, hd/2]
     sin = sin[positions][:, :, None, :].astype(dtype)
+    x1, x2 = jnp.split(x, 2, axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+
+
+def rotate_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+    """:func:`apply_rope` without a table of ``max_position_embeddings``
+    rows: ``x [b, s, heads, hd]`` rotated by ``positions [b, s]``,
+    rotate-half over the whole head, the angles in float32, the rotation in
+    ``x``'s dtype."""
+    hd = x.shape[-1]
+    inv_freq = 1.0 / (theta ** (np.arange(0, hd, 2, dtype=np.float64) / hd))
+    angles = positions[..., None].astype(jnp.float32) * jnp.asarray(inv_freq, jnp.float32)
+    cos = jnp.cos(angles)[:, :, None, :].astype(x.dtype)
+    sin = jnp.sin(angles)[:, :, None, :].astype(x.dtype)
     x1, x2 = jnp.split(x, 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
@@ -351,8 +416,6 @@ def rope_cached_attention_block(
     projection residual. gpt2 keeps its own (LayerNorm, fused QKV, learned
     positions). Returns ``(x + attn_out, kc_l, vc_l)``; ``pp_manual``: see
     :func:`write_kv_cache`."""
-    from .fp8 import dense
-
     b, s, _ = x.shape
     positions = idx[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]  # [b, s]
     y = rms_norm(x, layer["attn_norm"], eps)
@@ -537,8 +600,6 @@ def write_paged_latent(pool, layer, rows, block_tables, positions, write_mask=No
     put = _paged_row_scatter(pool, layer, block_tables, positions, write_mask)
     if scale is None:
         return (put(pool, rows.astype(pool.dtype)),)
-    from .fp8 import quantize_kv_rows
-
     rows, row_scale = quantize_kv_rows(rows, pool.dtype)        # [b, s, width] + [b, s]
     return put(pool, rows), put(scale, row_scale[..., None])
 
@@ -574,13 +635,71 @@ def write_paged_kv(
     Returns 4 arrays in that case."""
     put = _paged_row_scatter(k_pool, layer, block_tables, positions, write_mask)
     if k_scale is not None:
-        from .fp8 import quantize_kv_rows
-
         k, k_sc = quantize_kv_rows(k, k_pool.dtype)   # [b,s,n_kv,hd] + [b,s,n_kv]
         v, v_sc = quantize_kv_rows(v, v_pool.dtype)
         return put(k_pool, k), put(v_pool, v), put(k_scale, k_sc), put(v_scale, v_sc)
     # e.g. bf16 storage under f32 compute
     return put(k_pool, k.astype(k_pool.dtype)), put(v_pool, v.astype(v_pool.dtype))
+
+
+def paged_step_frame(rows, cache_positions, write_mask):
+    """What every model's step against the paged cache (``*_apply(...,
+    paged_kv=, block_tables=, cache_positions=, paged_write_mask=[,
+    state_slots=], logit_positions=)``) starts from: ``rows [b, s, ...]`` (the
+    step's tokens, or their hidden rows) begin at ``cache_positions [b]``, and
+    ``(idx [b], positions [b, s], valid [b, s])`` are each row's first cache
+    position, every token's absolute position, and the lanes ``write_mask``
+    leaves on (all of them without one). The contract the step keeps: a
+    layer's rows are written before its queries attend
+    (:func:`paged_write_attend`); a lane that is off leaves the cache as it
+    was; the cache dict comes back whole as ``paged_kv``; and the ``logits``
+    are those of ``logit_positions`` alone where the caller names them
+    (:func:`logit_rows`)."""
+    b, s = rows.shape[:2]
+    idx = jnp.asarray(cache_positions, jnp.int32).reshape(b)
+    positions = idx[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
+    valid = jnp.ones((b, s), bool) if write_mask is None else jnp.broadcast_to(
+        jnp.asarray(write_mask, bool), (b, s))
+    return idx, positions, valid
+
+
+def slot_state_frame(valid, state_slots, num_slots: int):
+    """The per-slot-state half of the frame: ``(n_valid [b], slots)`` - how
+    many of a row's lanes are on, and the slots ``state_slots [b]`` whose state
+    the rows continue, or ``None``: the decode step, row ``i`` is slot ``i``
+    of the ``num_slots`` the state arrays hold."""
+    b, s = valid.shape
+    n_valid = valid.sum(axis=1).astype(jnp.int32)
+    if state_slots is not None:
+        return n_valid, jnp.asarray(state_slots, jnp.int32).reshape(b)
+    if s != 1 or num_slots != b:
+        raise ValueError(
+            f"a step without state_slots is the decode step of every slot: got "
+            f"[{b}, {s}] tokens for {num_slots} slots"
+        )
+    return n_valid, None
+
+
+def paged_write_attend(q, k, v, leaves, layer, table, positions, idx, valid,
+                       scope: str = "attn_kernel", **visibility):
+    """Write the rows, then attend through the table: ``k`` / ``v [b, s, n_kv,
+    hd]`` scattered into layer ``layer`` of ONE paged kind's pools ``leaves``
+    (``(k_pool, v_pool)``, and ``(k_scale, v_scale)`` behind them where the
+    pool is quantized: :func:`write_paged_kv`) under ``kv_write``, then
+    :func:`~.paged_attention.paged_attention` of ``q`` over what the kind's
+    ``table`` holds, under ``scope`` (the kernel's name in a trace);
+    ``visibility`` is that function's ``block_len=`` / ``window=`` /
+    ``impl=``. Returns ``(attention [b, s, n_heads, hd], leaves)``, the leaves
+    updated, in the order given."""
+    from .paged_attention import paged_attention
+
+    with jax.named_scope("kv_write"):
+        leaves = write_paged_kv(
+            leaves[0], leaves[1], layer, k, v, table, positions, valid, *leaves[2:])
+    with jax.named_scope(scope):
+        attn = paged_attention(
+            q, leaves[0], leaves[1], layer, table, idx, *leaves[2:], **visibility)
+    return attn, leaves
 
 
 def rope_paged_attention_block(
@@ -593,18 +712,14 @@ def rope_paged_attention_block(
     ``layer_idx`` of the stacked pools through the block table
     (quantize-on-scatter when scale arrays ride along) → **fused paged
     attention** walking the block table directly at ``(layer_idx, block)``
-    (:func:`ops.paged_attention.paged_attention` — neither the layer's slab
-    nor the gathered ``[b, max_blocks*bs, ...]`` span is ever
-    materialised) → output projection residual. ``s == 1`` is the engine's
-    decode step; ``s > 1`` a prefill chunk (``write_mask`` drops its padded
-    tail). Returns ``(x, k_pool, v_pool)`` — the whole pools, updated —
-    and the scale arrays too when quantized."""
-    from .fp8 import dense
-    from .paged_attention import paged_attention
-
+    (:func:`paged_write_attend` — neither the layer's slab nor the gathered
+    ``[b, max_blocks*bs, ...]`` span is ever materialised) → output
+    projection residual. ``s == 1`` is the engine's decode step; ``s > 1`` a
+    prefill chunk (``write_mask`` drops its padded tail). Returns ``(x,
+    k_pool, v_pool)`` — the whole pools, updated — and the scale arrays too
+    when quantized."""
     b, s, _ = x.shape
-    idx = jnp.asarray(idx, jnp.int32).reshape(b)
-    positions = idx[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]  # [b, s]
+    idx, positions, valid = paged_step_frame(x, idx, write_mask)
     # scopes: the same names llama_layer_apply gives the training block
     with jax.named_scope("attn_proj"):
         y = rms_norm(x, layer["attn_norm"], eps)
@@ -615,16 +730,47 @@ def rope_paged_attention_block(
             dense(y, layer["wk"]).reshape(b, s, n_kv_heads, head_dim), cos, sin, positions
         )
         v = dense(y, layer["wv"]).reshape(b, s, n_kv_heads, head_dim)
-    with jax.named_scope("kv_write"):
-        pools = write_paged_kv(
-            k_pool, v_pool, layer_idx, k, v, block_tables, positions,
-            write_mask=write_mask, k_scale=k_scale, v_scale=v_scale,
-        )
-    with jax.named_scope("attn_kernel"):
-        attn = paged_attention(
-            q, pools[0], pools[1], layer_idx, block_tables, idx,
-            *pools[2:], impl=attn_impl,
-        )
-    with jax.named_scope("attn_proj"):
-        x = x + dense(attn.reshape(b, s, n_heads * head_dim), layer["wo"])
-    return (x, *pools)
+    leaves = (k_pool, v_pool) if k_scale is None else (k_pool, v_pool, k_scale, v_scale)
+    attn, leaves = paged_write_attend(
+        q, k, v, leaves, layer_idx, block_tables, positions, idx, valid, impl=attn_impl)
+    return (attention_out(layer, x, attn), *leaves)
+
+
+# -- what the served families' files share beside the block --------------------
+
+
+@jax.named_scope("embed")
+def embed_tokens(params, input_ids):
+    return params["embed_tokens"][input_ids]
+
+
+@jax.named_scope("head")
+def untied_head(x, lm_head):
+    return dense(x, lm_head)
+
+
+def layer_at(stack, i, but=()):
+    """Layer ``i`` (static) of every leaf of a stack but those named in
+    ``but`` (the experts' matrices, which stay stacked and are addressed at
+    ``(i, expert)``)."""
+    return {name: leaf[i] for name, leaf in stack.items() if name not in but}
+
+
+@jax.named_scope("attn_proj")
+def qk_normed_rotary_qkv(layer, x, norm, positions, n_heads: int, n_kv_heads: int,
+                         head_dim: int, eps: float, theta: float):
+    """q, k (each head normed by ``q_norm`` / ``k_norm``, then rotated) and v
+    of ``RMSNorm(x, norm)``: bias-free grouped-query projections."""
+    b, s, _ = x.shape
+    y = rms_norm(x, norm, eps)
+    q = rms_norm(dense(y, layer["wq"]).reshape(b, s, n_heads, head_dim), layer["q_norm"], eps)
+    k = rms_norm(dense(y, layer["wk"]).reshape(b, s, n_kv_heads, head_dim), layer["k_norm"], eps)
+    v = dense(y, layer["wv"]).reshape(b, s, n_kv_heads, head_dim)
+    return rotate_rope(q, positions, theta), rotate_rope(k, positions, theta), v
+
+
+@jax.named_scope("attn_proj")
+def attention_out(layer, x, attn):
+    """``x + attn Wo``, the heads of ``attn [b, s, heads, hd]`` folded."""
+    b, s = attn.shape[:2]
+    return x + dense(attn.reshape(b, s, -1), layer["wo"])

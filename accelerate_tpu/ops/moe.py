@@ -27,6 +27,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from .layers import rms_norm
+
 _HI = jax.lax.Precision.HIGHEST
 
 #: rows, contraction and columns of one grid step of the grouped product on
@@ -181,3 +183,46 @@ def expert_ffn(x, experts, weights, w_in, w_out, live=None, layer: int | None = 
     # back to (token, choice) order, then the sum over a token's choices
     back = jnp.argsort(order)
     return out[back].reshape(t, k, -1).sum(axis=1).astype(x.dtype), counts
+
+
+def routed_ffn(stack, i, x, live, eps: float, *router, **router_kw):
+    """A layer's routed feed-forward as LFM2 and SDAR have it, the residual
+    added: ``RMSNorm(x, ffn_norm)`` routed by ``gate`` (and ``expert_bias``,
+    where the stack has one) under ``moe_router`` - ``router`` /
+    ``router_kw`` are :func:`route`'s arguments from ``top_k`` on -, then
+    layer ``i``'s experts of the stack under ``moe_experts``. ``x [b, s, h]``;
+    ``live [b, s]`` (or ``None``) keeps padding and dead lanes out of every
+    expert. Returns ``(x, pairs [E] int32)``."""
+    b, s, h = x.shape
+    with jax.named_scope("moe_router"):
+        y = rms_norm(x, stack["ffn_norm"][i], eps).reshape(b * s, h)
+        gate = stack["gate"][i]
+        bias = stack["expert_bias"][i] if "expert_bias" in stack else None
+        experts, weights = route(y, gate, bias, *router, **router_kw)
+    with jax.named_scope("moe_experts"):
+        out, pairs = expert_ffn(
+            y, experts, weights, stack["w_in"], stack["w_out"],
+            live=None if live is None else live.reshape(b * s), layer=i)
+        return x + out.reshape(b, s, h), pairs
+
+
+def step_counter_shapes(moe_layers: int, experts: int, extra=()) -> dict:
+    """What a routed model's step against the cache hands back beside its
+    logits (``step_counters``), name -> shape (int32): the serving engine sums
+    each over the steps it dispatched. ``extra``: a family's further totals."""
+    totals = ("moe_dispatches_total", "moe_pairs_routed_total",
+              "moe_experts_touched_total", "moe_load_max_total", *extra)
+    return {"moe_expert_pairs": (moe_layers, experts), **dict.fromkeys(totals, ())}
+
+
+def step_counters(pairs) -> dict:
+    """``pairs``, :func:`expert_ffn`'s counts ``[E]`` of each routed layer of
+    one step -> the counters of :func:`step_counter_shapes`."""
+    pairs = jnp.stack(pairs).astype(jnp.int32)
+    return {
+        "moe_expert_pairs": pairs,
+        "moe_dispatches_total": jnp.ones((), jnp.int32),
+        "moe_pairs_routed_total": pairs.sum(),
+        "moe_experts_touched_total": (pairs > 0).sum(dtype=jnp.int32),
+        "moe_load_max_total": pairs.max(axis=1).sum(),
+    }

@@ -26,6 +26,16 @@ if _TEST_BACKEND == "cpu":
         # window, which ABORTS the process. Give the scheduler room.
         flags = (flags + " --xla_cpu_collective_call_terminate_timeout_seconds=600").strip()
     os.environ["XLA_FLAGS"] = flags
+    # XLA sizes the CPU client's thread pools to the machine's cores
+    # (``DefaultThreadPoolSize``), and each of the 8 virtual devices blocks one
+    # pool thread while its program waits in a collective's rendezvous: on a
+    # host of 8 cores or fewer a second program in flight then finds no thread
+    # to run on and the rendezvous never completes ("Expected 8 threads to
+    # join"; then the 600 s abort above). ``NPROC`` is the size XLA takes
+    # instead of the core count; with room above the device count the
+    # examples that hung 4 runs in 10 beside a busy machine hung 0 in 22
+    # (ISSUE 47). Children a test starts inherit it.
+    os.environ.setdefault("NPROC", "32")
 
 # hermetic runs: no test (and no child a test starts) reads or fills the
 # persistent compile cache, so a run never depends on what an earlier one

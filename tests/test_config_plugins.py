@@ -262,7 +262,7 @@ def test_megatron_sp_shards_residual_activations_on_tp():
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from accelerate_tpu.models import LlamaConfig, LlamaForCausalLM
-    from accelerate_tpu.models.llama import _constrain, residual_spec
+    from accelerate_tpu.ops.layers import mesh_constrain as _constrain, residual_spec
     from accelerate_tpu.state import AcceleratorState, GradientState
     from accelerate_tpu.utils.dataclasses import MegatronLMPlugin
 

@@ -563,7 +563,7 @@ def _rehearse(monkeypatch, how):
             scores = jax.nn.sigmoid(x.astype(jnp.float32) @ w_gate.astype(jnp.float32)) + bias
             top, experts = jax.lax.top_k(scores, top_k)
             return experts, top / (top.sum(-1, keepdims=True) + 1e-6) * scale
-        monkeypatch.setattr(lfm2, "route", leaky)
+        monkeypatch.setattr(lfm2.moe, "route", leaky)  # where the layer looks it up
     ctx = common.Ctx(cell=cell, config=config, traffic=traffic, seed=3_600_000_021,
                      seconds=2.0, trace=False, rehearse=True, **extra)
     return common.load_driver("serve_engine").run(ctx)

@@ -164,7 +164,7 @@ def served(request, tiny):
     model, c = tiny
     impl = request.param
     mp = pytest.MonkeyPatch()
-    mp.setattr(st, "paged_attention",
+    mp.setattr(sys.modules["accelerate_tpu.ops.paged_attention"], "paged_attention",
                functools.partial(paged_attention, impl=impl, interpret=True))
     try:
         engine = _engine(model)
@@ -548,6 +548,12 @@ for program in ("decode", "prefill"):
 print("DIGESTS " + json.dumps(out))
 """
 
+_SMALLTHINKER_SCRIPT = _ENGINE_SCRIPT.replace(
+    "deepseek_v3 import DeepseekV3Config, DeepseekV3ForCausalLM",
+    "smallthinker import SmallThinkerConfig, SmallThinkerForCausalLM").replace(
+    "DeepseekV3ForCausalLM.from_config(DeepseekV3Config.tiny()",
+    "SmallThinkerForCausalLM.from_config(SmallThinkerConfig.tiny()")
+
 #: sha256 of the StableHLO text of programs that this PR's parameters must not
 #: move, taken on the parent commit (8c4cc1f: before ``route`` took ``logits``,
 #: ``expert_ffn`` an ``activation``, the paged kernel a ``window`` and the cache
@@ -559,12 +565,16 @@ print("DIGESTS " + json.dumps(out))
 #: from); ``deepseek`` ``decode`` and ``defaults`` ``routed`` are the parent's still
 PARENT_PROGRAMS = {
     "deepseek": {"decode": "16dba12fa31d7584643a755dab50ede39ac9e852bcc147cfb5a22234288a17a7", "prefill": "f73eb985c0a741f6fd4b87ff141bd43af133a5988ea6c9f17c6090527fc61f6c"},
+    # taken on 987753d (the parent of PR 47, which gave the six families' steps
+    # one frame and one write-then-attend block): the two-kind engine's programs
+    "smallthinker": {"decode": "656361d222ffd8304af2d55a13afd9540ec731f850f3f4ec83bd4739181ee48b", "prefill": "295eeb5d0f5f6fdc6b58867a0c5702dcaeed912905d2672614df8a9d5ee11f95"},
     "defaults": {"routed": "6f9888adb74f24f71c5651d3cacd2f50b1e7b1d15237f28f42442efb55fe7cd5"},
 }
 
 
 @pytest.mark.parametrize("name, script", [("deepseek", _ENGINE_SCRIPT),
-                                          ("defaults", _DEFAULTS_SCRIPT)])
+                                          ("defaults", _DEFAULTS_SCRIPT),
+                                          ("smallthinker", _SMALLTHINKER_SCRIPT)])
 def test_a_model_of_one_kind_and_the_defaults_compile_the_parents_programs(name, script):
     """In a process of its own, as ``tests/test_lfm2.py`` says why."""
     import subprocess
